@@ -8,7 +8,8 @@ cd "$(dirname "$0")"
 
 export JAX_PLATFORMS=cpu
 export XLA_FLAGS="--xla_force_host_platform_device_count=8 ${XLA_FLAGS:-}"
-export JAX_COMPILATION_CACHE_DIR="${JAX_COMPILATION_CACHE_DIR:-/tmp/jax_ci_cache}"
+# the same default the package's resolver uses (utils/compile_cache.py)
+export JAX_COMPILATION_CACHE_DIR="${JAX_COMPILATION_CACHE_DIR:-$PWD/.jax_cache}"
 
 # invariant linter (ISSUE 13): the codebase's cross-cutting contracts —
 # host-sync-free hot paths, config-hash knob coverage, journal write

@@ -22,10 +22,6 @@ import numpy as np
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 import jax  # noqa: E402
-
-if os.environ.get("JAX_PLATFORMS"):  # the TPU shim may override the env var
-    jax.config.update("jax_platforms", os.environ["JAX_PLATFORMS"])
-
 import jax.numpy as jnp  # noqa: E402
 
 from spark_timeseries_tpu.ops import seqparallel as sp  # noqa: E402

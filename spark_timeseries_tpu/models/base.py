@@ -221,21 +221,11 @@ def align_mode_on_host(yb) -> str:
     # each probe is a device round-trip (host sync); counted so drivers can
     # verify a sliced chunk walk really paid ONE probe, not one per chunk
     obs.counter("align.host_probes").inc()
-    try:
-        nan_any, nan_last = _nan_probe(yb)
-    except RuntimeError:
-        # some backends cannot run even this tiny probe on the panel (e.g.
-        # jax 0.4 CPU refuses multiprocess computations on process-spanning
-        # sharded arrays): degrade to the always-correct general path
-        # rather than failing the fit.  The degraded mode still enters the
-        # cache below — repeat fits on the same panel must not re-pay a
-        # probe that is known to fail on this array
-        mode = "general"
+    nan_any, nan_last = _nan_probe(yb)
+    if not bool(nan_any):
+        mode = "dense"
     else:
-        if not bool(nan_any):
-            mode = "dense"
-        else:
-            mode = "no-trailing" if not bool(nan_last) else "general"
+        mode = "no-trailing" if not bool(nan_last) else "general"
     try:
         ref = weakref.ref(yb)
     except TypeError:  # not weak-referenceable (e.g. plain numpy scalarlike)

@@ -3,7 +3,7 @@
 Spark's executor heartbeats shipped per-task metric maps (shuffle bytes,
 GC time, spill counts) to the driver, which aggregated them per stage; our
 single-process rebuild needs only a process-local registry, but the same
-taxonomy: monotonically increasing **counters** (ladder-rung rescues, OOM
+vocabulary: monotonically increasing **counters** (ladder-rung rescues, OOM
 backoff halvings, kernel-cache hits), point-in-time **gauges** (peak device
 memory, chunk size in effect), and **histograms** of repeated measurements
 (journal commit latency, span wall times) summarized as

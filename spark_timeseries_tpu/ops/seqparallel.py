@@ -29,13 +29,8 @@ from typing import Dict, Tuple
 
 import jax
 import jax.numpy as jnp
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-
-try:  # jax >= 0.6 top-level export
-    from jax import shard_map
-except ImportError:  # older builds: the experimental module
-    from jax.experimental.shard_map import shard_map
 
 from .. import obs
 from ..parallel.mesh import SERIES_AXIS, TIME_AXIS
@@ -70,12 +65,9 @@ def _axis_index():
 
 
 def _axis_size():
-    # lax.axis_size landed after jax 0.4; psum of the literal 1 is the
-    # classic spelling and constant-folds to the same STATIC python int
-    # (several callers build ppermute tables with range() over it)
-    if hasattr(lax, "axis_size"):
-        return lax.axis_size(TIME_AXIS)
-    return lax.psum(1, TIME_AXIS)
+    # a STATIC python int: several callers build ppermute tables with
+    # range() over it
+    return lax.axis_size(TIME_AXIS)
 
 
 def sp_moments(block: jax.Array) -> Dict[str, jax.Array]:

@@ -294,10 +294,7 @@ def init_distributed(
     re-initializing); single-process callers get the local-devices mesh,
     so code written against this entry point runs unchanged on one chip.
     """
-    try:
-        initialized = jax.distributed.is_initialized()
-    except AttributeError:  # very old jax
-        initialized = False
+    initialized = jax.distributed.is_initialized()
     explicit = coordinator_address is not None or num_processes is not None
     if not initialized and (explicit or _on_cloud_tpu_pod()):
         kwargs = {}

@@ -224,7 +224,6 @@ class FitServer:
                  prom_interval_s: float = 2.0,
                  degraded_window_s: float = 5.0,
                  walk_kwargs: Optional[dict] = None,
-                 compile_cache_dir: Optional[str] = None,
                  _commit_hook: Optional[Callable] = None):
         self.root = os.path.abspath(root)
         self._requests_dir = os.path.join(self.root, "requests")
@@ -284,8 +283,6 @@ class FitServer:
         # (keyed by panel geometry — a pool's buffers are [*, T] dtype)
         self._pools: Dict[tuple, source_mod.StagingPool] = {}
         self._pools_lock = threading.Lock()
-        if compile_cache_dir:
-            compile_cache.enable_compile_cache(compile_cache_dir)
         # prom sink (obs.promsink): rewritten after every batch + idle tick
         self._prom = None
         self._prom_interval_s = float(prom_interval_s)

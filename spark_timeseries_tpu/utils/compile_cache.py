@@ -1,28 +1,18 @@
-"""Opt-in persistent JAX compilation cache for restart-heavy workloads.
+"""Where jax's persistent compilation cache lives, and program-reuse counts.
 
-The journal (``reliability.journal``) makes a killed panel job resume
-without recomputing committed chunks — but the restarted PROCESS still
-repaid the full trace+compile of every fit program before touching the
-first pending chunk, which at north-star scale is tens of seconds of pure
-recompilation of programs an identical process already built.  JAX ships a
-persistent compilation cache (serialized XLA executables keyed by HLO +
-compile options) that turns that cost into a disk read; this module is the
-library's one switch for it, so the bench, CI, and serving entry points
-agree on how it is enabled:
+Compiling is not small here: the production ARIMA(1,1,1) fit program for a
+v5e at ``[131072, 1000]`` takes tens of seconds to build, and a restarted
+process re-pays it before its first chunk.  JAX ships a persistent cache
+(serialized executables keyed by HLO, compile options AND the cache path)
+that turns that into a disk read.  :func:`configure` is the ONE place the
+directory is decided, called before first backend use by ``chip_smoke.py``,
+``bench.py``, ``tests/conftest.py`` and the test workers:
 
-- :func:`enable_compile_cache` — point JAX at a cache directory and relax
-  the min-size/min-compile-time gates so small fit programs cache too.
-  Safe to call more than once; returns the directory in effect or ``None``
-  when this jax build has no cache support (the call degrades to a no-op
-  rather than failing the fit — same contract as the obs plane).
-- ``STSTPU_COMPILE_CACHE=<dir>`` — environment opt-in honored by
-  :func:`enable_from_env` (wired into ``bench.py``; ``ci.sh`` exports
-  ``JAX_COMPILATION_CACHE_DIR`` which jax honors natively).
-
-Deliberately OPT-IN: a shared default directory would let one user's cache
-poison another's benchmark numbers (first-run compile time is a published
-measurement), and stale caches across jax upgrades are evicted by jax's
-own key, not by us.
+- ``JAX_COMPILATION_CACHE_DIR`` set: jax reads it natively; nothing is set
+  in code, so whoever launches the process places the cache.
+- unset: ``<checkout>/.jax_cache`` — a fixed path derived from this
+  package's location (never a temp name, pid or time: the path is part of
+  the cache key, so a directory that moves never hits).
 
 This module also owns the PROGRAM-reuse counters (``compile_cache.hit`` /
 ``compile_cache.miss`` in the obs registry, fed by ``models.base.
@@ -35,13 +25,13 @@ from __future__ import annotations
 
 import os
 import threading as _threading
-from typing import Optional
 
-__all__ = ["enable_compile_cache", "enable_from_env", "note_hit",
-           "note_miss", "program_cache_stats"]
+__all__ = ["configure", "note_hit", "note_miss", "program_cache_stats"]
 
-_ENV_VAR = "STSTPU_COMPILE_CACHE"
-_enabled_dir: Optional[str] = None
+_ENV_VAR = "JAX_COMPILATION_CACHE_DIR"
+_DEFAULT_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__)))), ".jax_cache")
 
 # -- program-reuse accounting (ISSUE 9 satellite) ----------------------------
 #
@@ -102,55 +92,14 @@ def program_cache_stats() -> dict:
     }
 
 
-def enable_compile_cache(cache_dir: str) -> Optional[str]:
-    """Enable jax's persistent compilation cache under ``cache_dir``.
+def configure() -> str:
+    """Place jax's persistent compilation cache; returns the directory.
 
-    Returns the directory on success, ``None`` when this jax build lacks
-    the cache (never raises: a missing cache only costs recompiles).  The
-    min-entry-size and min-compile-time gates are relaxed so the chunked
-    fit programs — compiled once per (config, chunk-rows) — are cached
-    regardless of size, which is the whole point for journaled resumes.
+    Call before the first backend use (jax latches the cache decision at
+    the first compile).  See the module docstring for the rule.
     """
-    global _enabled_dir
-    try:
-        import jax
+    import jax
 
-        cache_dir = os.path.abspath(cache_dir)
-        os.makedirs(cache_dir, exist_ok=True)
-        jax.config.update("jax_compilation_cache_dir", cache_dir)
-        # cache every program: the default gates skip small/fast compiles,
-        # but a resumed north-star walk re-pays dozens of them at once
-        for knob, v in (("jax_persistent_cache_min_entry_size_bytes", -1),
-                        ("jax_persistent_cache_min_compile_time_secs", 0.0)):
-            try:
-                jax.config.update(knob, v)
-            except Exception:  # noqa: BLE001 - knob renamed/absent: defaults ok
-                pass
-        # jax latches the cache decision per backend at first use: a dir
-        # set AFTER the backend initialized is silently ignored (verified
-        # on jax 0.4.37) — reset the latch so mid-process enabling (bench
-        # main, a serving process flipping the knob) actually takes effect
-        try:
-            from jax.experimental.compilation_cache import (
-                compilation_cache as _cc)
-
-            _cc.reset_cache()
-        except Exception:  # noqa: BLE001 - moved/absent: fresh-process only
-            pass
-        _enabled_dir = cache_dir
-        return cache_dir
-    except Exception:  # noqa: BLE001 - no cache support in this build
-        return None
-
-
-def enable_from_env() -> Optional[str]:
-    """Honor ``STSTPU_COMPILE_CACHE=<dir>`` (no-op when unset)."""
-    d = os.environ.get(_ENV_VAR)
-    if not d:
-        return None
-    return enable_compile_cache(d)
-
-
-def enabled_dir() -> Optional[str]:
-    """The cache directory enabled through this module, if any."""
-    return _enabled_dir
+    if not os.environ.get(_ENV_VAR):
+        jax.config.update("jax_compilation_cache_dir", _DEFAULT_DIR)
+    return jax.config.jax_compilation_cache_dir

@@ -48,8 +48,6 @@ import sys
 import tempfile
 
 os.environ.setdefault("JAX_PLATFORMS", "cpu")
-# amortize the 8-device compiles across the smoke's worker processes
-os.environ.setdefault("JAX_COMPILATION_CACHE_DIR", "/tmp/jax_pytest_cache")
 flags = os.environ.get("XLA_FLAGS", "")
 if "xla_force_host_platform_device_count" not in flags:
     os.environ["XLA_FLAGS"] = (
@@ -74,12 +72,13 @@ def make_panel() -> np.ndarray:
 
 def run_fit(directory: str, kill_after: int | None, single: bool,
             out: str | None, lane_kill: int | None = None) -> None:
-    import jax
-
-    jax.config.update("jax_platforms", "cpu")
     from spark_timeseries_tpu import reliability as rel
     from spark_timeseries_tpu.models import arima
     from spark_timeseries_tpu.reliability import faultinject as fi
+    from spark_timeseries_tpu.utils import compile_cache
+
+    # amortize the 8-device compiles across the smoke's worker processes
+    compile_cache.configure()
 
     hook = None
     if kill_after is not None:

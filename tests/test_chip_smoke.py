@@ -1,0 +1,169 @@
+"""``chip_smoke.py`` and the bench's device honesty (ISSUE 22).
+
+Tier-1 pins that nothing on the chip path succeeds without a chip: the
+smoke and a full-size ``bench.py`` refuse the CPU, a parity-gate trip
+fails the bench, an unknown ``device_kind`` has no HBM peak.  Marked
+``slow``: the smoke's ``--rehearse`` control flow end to end on forced CPU
+devices, and an AOT compile of every Pallas kernel family for a
+compile-only v5e topology — run that one before editing
+``ops/pallas_kernels.py``, so a Mosaic refusal shows up in the sandbox.
+"""
+
+import json
+import os
+import subprocess
+import sys
+import types
+
+import pytest
+
+import jax
+import jax.numpy as jnp
+
+REPO_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path.insert(0, REPO_ROOT)  # bench.py and chip_smoke.py live at the root
+
+
+def _run(args, **env):
+    return subprocess.run(
+        [sys.executable, *args], cwd=REPO_ROOT,
+        env={**os.environ, "JAX_PLATFORMS": "cpu", **env},
+        capture_output=True, text=True, timeout=1500)
+
+
+def test_smoke_refuses_cpu(tmp_path):
+    r = _run(["chip_smoke.py", "--out", str(tmp_path)])
+    assert r.returncode != 0
+    assert "device gate FAILED" in r.stderr and "'cpu'" in r.stderr
+    assert r.stdout == ""  # no leg ran, no result line, nothing compiled
+
+
+def test_bench_refuses_cpu_without_quick():
+    r = _run(["bench.py"])
+    assert r.returncode != 0
+    assert "no TPU" in r.stderr and "'cpu'" in r.stderr
+    assert r.stdout == ""  # nothing measured
+
+
+def test_bench_parity_failure_is_fatal(monkeypatch):
+    import bench
+
+    def trip(jnp_, on_tpu):
+        raise RuntimeError("forced parity trip")
+
+    monkeypatch.setattr(bench, "check_backend_parity", trip)
+    monkeypatch.setattr(sys, "argv", ["bench.py", "--quick"])
+    with pytest.raises(RuntimeError, match="forced parity trip"):
+        bench.main()
+
+
+def test_bench_unknown_device_kind_is_an_error():
+    import bench
+
+    v5e = types.SimpleNamespace(platform="tpu", device_kind="TPU v5 lite")
+    assert bench._hbm_peak_gbps(v5e) == 819.0
+    unknown = types.SimpleNamespace(platform="tpu", device_kind="TPU v99")
+    with pytest.raises(RuntimeError, match="TPU v99"):
+        bench._hbm_peak_gbps(unknown)
+
+
+@pytest.mark.slow
+def test_smoke_rehearsal_end_to_end(tmp_path):
+    r = _run(["chip_smoke.py", "--rehearse", "--chips", "4",
+              "--out", str(tmp_path)],
+             XLA_FLAGS="--xla_force_host_platform_device_count=4")
+    assert r.returncode == 0, r.stderr[-4000:]
+    lines = [json.loads(ln) for ln in r.stdout.splitlines()]
+    assert all(ln["rehearsal"] is True for ln in lines)
+    assert lines[-1]["ok"] is True
+    legs = [ln["leg"] for ln in lines[:-1]]
+    assert legs == ["device_gate", "backend_gate", "parity", "panel", "walk",
+                    "resume", "forecast", "server", "sharded_walk",
+                    "time_sharded", "done"]
+    assert all(ln["claim"] is None for ln in lines[:-1])
+
+
+@pytest.mark.slow
+def test_pallas_kernels_compile_for_v5e(monkeypatch):
+    """Every kernel family lowers through Mosaic and compiles for a
+    compile-only ``v5e:2x2`` topology — no chip needed, none taken."""
+    from jax._src import xla_bridge
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+
+    from spark_timeseries_tpu.models import arima, ewma, garch
+    from spark_timeseries_tpu.models import holtwinters as hw
+    from spark_timeseries_tpu.ops import pallas_kernels as pk
+
+    # building the topology loads libtpu, which by default takes the
+    # machine's libtpu lockfile for the life of the process: on a host WITH
+    # a chip no other process could then open it.  With this variable the
+    # load takes no lock (checked on a v5e host, PR 22: a second process
+    # ran on the chip while this one held a compiled kernel).
+    monkeypatch.setenv("ALLOW_MULTIPLE_LIBTPU_LOAD", "1")
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 - no libtpu: nothing to compile for
+        reason = f"compile-only v5e topology unavailable: {e!r}"
+        print(reason)
+        pytest.skip(reason)
+    sharding = SingleDeviceSharding(topo.devices[0])
+    assert topo.devices[0].device_kind == "TPU v5 lite"
+
+    def arg(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.float32, sharding=sharding)
+
+    B, T, TOL = 1024, 200, 1e-4
+    o111, o212 = (1, 1, 1), (2, 1, 2)
+    programs = {}
+    for mode in ("dense", "general"):
+        programs[f"arima111 stage1 {mode}"] = (
+            arima._fit_stage1_program(o111, True, "pallas", 60, TOL, False,
+                                      mode), [arg(4096, T)])
+        programs[f"arima111 inline {mode}"] = (
+            arima._fit_program(o111, True, "css-lbfgs", "pallas", 60, TOL,
+                               False, mode), [arg(B, T)])
+        programs[f"garch {mode}"] = (
+            garch._fit_program(60, TOL, "pallas", mode), [arg(B, T)])
+    programs["arima212 inline"] = (
+        arima._fit_program(o212, True, "css-lbfgs", "pallas", 60, TOL,
+                           False, "dense"), [arg(B, T)])
+    programs["arima111 T=2500 (multi-chunk)"] = (
+        arima._fit_program(o111, True, "css-lbfgs", "pallas", 60, TOL,
+                           False, "dense"), [arg(B, 2500)])
+    programs["arima111 forecast (tail kernel)"] = (
+        arima._forecast_program(o111, 24, True, "pallas", "dense"),
+        [arg(B, 3), arg(B, T)])
+    programs["argarch"] = (
+        garch._fit_argarch_program(60, TOL, "pallas", True, "dense"),
+        [arg(B, T)])
+    programs["ewma"] = (ewma._fit_program(60, TOL, "pallas", "dense"),
+                        [arg(B, T)])
+    programs["hw additive m=24"] = (
+        hw._fit_program(24, False, 60, TOL, "pallas", "dense"),
+        [arg(B, 192)])
+    programs["hw multiplicative m=24"] = (
+        hw._fit_program(24, True, 60, TOL, "pallas", "dense", False, True, 3),
+        [arg(B, 192)])
+    programs["hw additive m=168 T=2016"] = (
+        hw._fit_program(168, False, 60, TOL, "pallas", "dense"),
+        [arg(B, 2016)])
+    programs["fill_linear"] = (jax.jit(pk.fill_linear), [arg(B, T)])
+    programs["fill_linear_chain"] = (jax.jit(pk.fill_linear_chain),
+                                     [arg(B, T)])
+    programs["batch_autocorr(10)"] = (
+        jax.jit(lambda x: pk.batch_autocorr(x, 10)), [arg(B, T)])
+
+    # x64 off, as on the chip (chip_smoke.py, bench.py): under the suite's
+    # jax_enable_x64 the Mosaic lowering of the kernels' int32 loop-index
+    # convert recurses without end in jax 0.9.0
+    with jax.enable_x64(False):
+        for name, (program, args) in programs.items():
+            try:
+                program.lower(*args).compile()
+            except Exception as e:  # noqa: BLE001 - name the family, then fail
+                pytest.fail(f"{name} does not compile for v5e: "
+                            f"{type(e).__name__}: {str(e)[:2000]}")
+    # a compile-only topology is not a client: the chip stays free
+    assert "tpu" not in xla_bridge._backends

@@ -11,6 +11,8 @@ the opt-in persistent compilation cache.
 
 import json
 import os
+import subprocess
+import sys
 
 import numpy as np
 import pytest
@@ -25,7 +27,8 @@ from spark_timeseries_tpu.compat import sparkts
 from spark_timeseries_tpu.models import arima
 from spark_timeseries_tpu.reliability import FitStatus
 from spark_timeseries_tpu.reliability import faultinject as fi
-from spark_timeseries_tpu.utils import compile_cache
+
+REPO_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
 def _ar_panel(b=32, t=120, seed=7, phi=0.6):
@@ -380,36 +383,37 @@ class TestOverlapAccounting:
 
 
 class TestCompileCache:
-    def _restore(self, old):
-        import jax
+    """The ONE resolver of the cache directory (``compile_cache.configure``):
+    the environment places the cache; unset, it is ``<checkout>/.jax_cache``."""
 
-        try:
-            jax.config.update("jax_compilation_cache_dir", old)
-        except Exception:
-            pass
+    @staticmethod
+    def _resolve(cache_env):
+        """``configure()`` in a fresh interpreter (jax reads the variable at
+        import) -> [returned dir, jax's config value, config.update calls]."""
+        code = (
+            "import jax\n"
+            "calls = []\n"
+            "real = jax.config.update\n"
+            "jax.config.update = lambda *a, **k: (calls.append(a), "
+            "real(*a, **k))[1]\n"
+            "from spark_timeseries_tpu.utils import compile_cache\n"
+            "print(compile_cache.configure())\n"
+            "print(jax.config.jax_compilation_cache_dir)\n"
+            "print(len(calls))\n")
+        env = {k: v for k, v in os.environ.items()
+               if k != "JAX_COMPILATION_CACHE_DIR"}
+        r = subprocess.run(
+            [sys.executable, "-c", code], cwd=REPO_ROOT,
+            env={**env, "JAX_PLATFORMS": "cpu", **cache_env},
+            capture_output=True, text=True, timeout=120)
+        assert r.returncode == 0, r.stderr
+        return r.stdout.split()
 
-    def test_enable_compile_cache(self, tmp_path):
-        import jax
+    def test_env_places_cache_and_resolver_sets_nothing(self, tmp_path):
+        want = str(tmp_path / "placed")
+        assert self._resolve({"JAX_COMPILATION_CACHE_DIR": want}) == [
+            want, want, "0"]
 
-        old = jax.config.jax_compilation_cache_dir
-        try:
-            d = compile_cache.enable_compile_cache(str(tmp_path / "cc"))
-            assert d is not None and os.path.isdir(d)
-            assert jax.config.jax_compilation_cache_dir == d
-            assert compile_cache.enabled_dir() == d
-        finally:
-            self._restore(old)
-
-    def test_enable_from_env(self, tmp_path, monkeypatch):
-        import jax
-
-        old = jax.config.jax_compilation_cache_dir
-        try:
-            monkeypatch.delenv("STSTPU_COMPILE_CACHE", raising=False)
-            assert compile_cache.enable_from_env() is None
-            want = str(tmp_path / "cc2")
-            monkeypatch.setenv("STSTPU_COMPILE_CACHE", want)
-            got = compile_cache.enable_from_env()
-            assert got == os.path.abspath(want)
-        finally:
-            self._restore(old)
+    def test_default_is_checkout_jax_cache(self):
+        want = os.path.join(REPO_ROOT, ".jax_cache")
+        assert self._resolve({}) == [want, want, "1"]

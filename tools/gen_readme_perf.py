@@ -303,7 +303,7 @@ def build_table(metrics: dict, artifact: pathlib.Path,
         footer.append(
             f"† PROVISIONAL: the driver artifact keeps only the output tail, "
             f"so these rows come from the self-recorded `{local_name}` "
-            "(same machine, same chip, un-audited)."
+            "(un-audited)."
         )
     if salvaged:
         footer.append(
