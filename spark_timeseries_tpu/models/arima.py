@@ -32,6 +32,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
+from .. import obs
 from ..ops import univariate as uv
 from ..utils import optim
 from ..utils.linalg import ols as _ols
@@ -509,18 +510,25 @@ def fit(
         run1 = _fit_stage1_program(
             order, include_intercept, backend, max_iters, float(tol),
             init_params is not None, align_mode)
-        if init_params is None:
-            out, aux = run1(yb)
-        else:
-            out, aux = run1(yb, jnp.asarray(init_params))
-        # host gate: tiny scalar sync; stage 2 shares stage 1's iteration
-        # budget, so an exhausted budget skips the dispatch entirely (the
-        # scatter of unchanged state would be an identity)
-        if int(aux["carry"].undone) > 0 and int(aux["carry"].k) < max_iters:
+        # fit.stage1: the dispatch of stage 1 and the host's wait for it at
+        # the gate below; fit.stage2 only when the gate dispatches
+        with obs.span("fit.stage1", rows=bsz) as stage1:
+            if init_params is None:
+                out, aux = run1(yb)
+            else:
+                out, aux = run1(yb, jnp.asarray(init_params))
+            # host gate: tiny scalar sync; stage 2 shares stage 1's iteration
+            # budget, so an exhausted budget skips the dispatch entirely (the
+            # scatter of unchanged state would be an identity)
+            undone = int(aux["carry"].undone)
+            if obs.enabled():
+                stage1.set(iters=int(aux["carry"].k), undone=undone)
+        if undone > 0 and int(aux["carry"].k) < max_iters:
             run2 = _fit_stage2_program(
                 order, include_intercept, backend, max_iters, float(tol),
                 int(yb.shape[1] - d))
-            out = run2(aux)
+            with obs.span("fit.stage2", rows=optim.compaction_cap(bsz)):
+                out = run2(aux)
         return debatch_fit(out, single, False)
     run = _fit_program(
         order, include_intercept, method, backend, max_iters, float(tol),

@@ -20,6 +20,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from .. import obs
 from ..utils import optim
 from .base import (FitResult, align_right, debatch,
                    debatch_fit, derive_status,
@@ -195,16 +196,26 @@ def fit(
             and bsz >= _COMPACT_MIN_BATCH
             and optim.compaction_cap(bsz) < bsz)
     if lazy:
-        out, aux = _fit_stage1_program(
-            period, multiplicative, max_iters, float(tol), backend,
-            align_mode, n_starts)(yb)
+        # fit.stage1: the dispatch of every start's stage 1 and the host's
+        # wait for it at the first gate below (the later starts' gates find
+        # their scalars ready); fit.stage2 only around a dispatch
+        with obs.span("fit.stage1", rows=bsz) as stage1:
+            out, aux = _fit_stage1_program(
+                period, multiplicative, max_iters, float(tol), backend,
+                align_mode, n_starts)(yb)
+            undone = [int(a["carry"].undone) for a in aux["starts"]]
+            if obs.enabled():
+                stage1.set(iters=max(int(a["carry"].k)
+                                     for a in aux["starts"]),
+                           undone=sum(undone))
         finished, redo = [], False
-        for a in aux["starts"]:
-            c = a["carry"]
-            if int(c.undone) > 0 and int(c.k) < max_iters:
-                finished.append(_fit_stage2_program(
-                    period, multiplicative, max_iters, float(tol),
-                    backend)(a))
+        for a, n_undone in zip(aux["starts"], undone):
+            if n_undone > 0 and int(a["carry"].k) < max_iters:
+                with obs.span("fit.stage2",
+                              rows=optim.compaction_cap(bsz)):
+                    finished.append(_fit_stage2_program(
+                        period, multiplicative, max_iters, float(tol),
+                        backend)(a))
                 redo = True
             else:
                 finished.append(a["res"])

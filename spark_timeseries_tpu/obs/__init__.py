@@ -13,7 +13,12 @@ and leaves fit results bitwise-identical to the uninstrumented code:
   (``obs.span("chunk", lo=...)``), first-dispatch tagging that separates
   trace+compile time from steady-state execute time, run summaries, and
   failure dumps; ``profile=True`` mirrors spans into ``jax.profiler``
-  annotations.
+  annotations.  Every span line carries its identity (schema v3, ISSUE
+  25): ``id`` (one per-run counter), ``parent`` (the span that caused it,
+  across threads too: ``span_link()`` at a hand-over, ``span(...,
+  parent=link)`` or ``span_scope(link)`` on the other side) and ``walk``
+  (the sequence number all spans of one ``fit_chunked`` call share;
+  ``walk_span()`` opens the root).
 - :mod:`.metrics` — the **registry** of counters / gauges / histograms
   the instrumented paths feed: ladder-rung counts per ``FitStatus``,
   sanitizer actions, OOM backoff halvings, watchdog timeouts, journal
@@ -48,6 +53,16 @@ Usage::
     res.meta["telemetry"]             # per-chunk spans, counters, peak mem
     obs.disable()                     # final metrics snapshot -> JSONL
 
+The walk path, root to leaf (``obs.recorder`` lists the attributes):
+``walk`` > ``walk.open``, then per chunk ``chunk.plan``, ``chunk`` >
+``sanitize``, ``fit.primary`` > ``fit.stage1`` / ``fit.stage2`` (the lazy
+optimizer's stage gate, with the carry's ``iters`` and ``undone``),
+``fit.readback`` (with the rows' ``iters_max`` / ``iters_sum``), the
+ladder's ``fit.rung.*``, then ``chunk.submit`` > ``commit.overlap`` on the
+committer thread, ``stage.overlap`` on the prefetcher thread; last
+``walk.close``.  ``benchmark/span_idle.py`` splits the device's idle time
+by these names.
+
 Instrumented surfaces: ``reliability.fit_chunked`` / ``resilient_fit`` /
 ``sanitize`` / ``journal`` / ``watchdog`` / the pipelined ``committer``
 (queue-depth gauge, per-commit ``commit.overlap`` spans, hidden-commit
@@ -69,10 +84,11 @@ header).
 """
 
 from . import core, memory, metrics, promsink, recorder, tracing
-from .core import (NULL_SPAN, Span, counter, disable, dump_failure,
-                   dump_on_failure, emit_metrics, enable, enable_from_env,
-                   enabled, event, first_dispatch, gauge, histogram,
-                   last_crash_dump, snapshot, span, stream_path, summary)
+from .core import (NULL_SPAN, Span, counter, current_span, disable,
+                   dump_failure, dump_on_failure, emit_metrics, enable,
+                   enable_from_env, enabled, event, first_dispatch, gauge,
+                   histogram, last_crash_dump, snapshot, span, span_link,
+                   span_scope, stream_path, summary, walk_span)
 from .memory import PeakMemory, peak_memory, register_staging_pool
 from .metrics import Counter, Gauge, Histogram, MetricsRegistry
 from .promsink import PromTextfileSink
@@ -95,6 +111,7 @@ __all__ = [
     "TraceContext",
     "core",
     "counter",
+    "current_span",
     "current_trace",
     "disable",
     "dump_failure",
@@ -116,6 +133,8 @@ __all__ = [
     "register_staging_pool",
     "snapshot",
     "span",
+    "span_link",
+    "span_scope",
     "stream_path",
     "summary",
     "trace_for_request",
@@ -123,6 +142,7 @@ __all__ = [
     "trace_scope",
     "trace_to_wire",
     "tracing",
+    "walk_span",
 ]
 
 # bench / CI opt-in without code changes (no-op unless STSTPU_OBS=1)

@@ -18,15 +18,28 @@ chunk driver label the first dispatch of each (fit, shape) pair as
 steady-state calls pay execute only, and conflating the two is the classic
 way to misread a cold chunk as a regression.
 
+Every span has an identity (schema v3): ``id``, an integer from one
+per-run counter; ``parent``, the id of the span open beneath it on the same
+thread, or the link handed over with work that crosses threads
+(:func:`span_link` at the hand-over, then ``span(..., parent=link)`` for one
+span or :func:`span_scope` around code that opens its own); and ``walk``,
+the sequence number every span of one ``fit_chunked`` call shares
+(:func:`walk_span` opens the root and draws it; children and links inherit
+it).  So a ``commit.overlap`` line on the committer thread names the chunk
+that submitted it, and two walks in one run stay apart.
+
 ``profile=True`` additionally wraps every span in a
-``jax.profiler.TraceAnnotation`` of the same name, so a
-``jax.profiler.trace(...)`` capture shows the exact spans the JSONL
-reports — one vocabulary across both tools.
+``jax.profiler.TraceAnnotation`` of the same name (with ``span_id=`` the
+span's id among the event's stats), so a ``jax.profiler.trace(...)``
+capture shows the exact spans the JSONL reports — one vocabulary and one
+clock across both tools.
 """
 
 from __future__ import annotations
 
+import contextlib
 import functools
+import itertools
 import os
 import tempfile
 import threading
@@ -42,6 +55,7 @@ from .recorder import SCHEMA_VERSION, FlightRecorder
 __all__ = [
     "Span",
     "counter",
+    "current_span",
     "disable",
     "dump_failure",
     "dump_on_failure",
@@ -56,27 +70,35 @@ __all__ = [
     "last_crash_dump",
     "snapshot",
     "span",
+    "span_link",
+    "span_scope",
     "stream_path",
     "summary",
+    "walk_span",
 ]
 
 
 class _State:
-    __slots__ = ("enabled", "run_id", "metrics", "recorder", "profile",
+    __slots__ = ("enabled", "run_id", "metrics", "recorder", "annotation",
                  "crash_dump_dir", "seen_programs", "last_crash",
-                 "crash_seq", "last_dumped_error")
+                 "crash_seq", "last_dumped_error", "span_ids", "walk_ids")
 
     def __init__(self):
         self.enabled = False
         self.run_id = None
         self.metrics = MetricsRegistry()
         self.recorder: Optional[FlightRecorder] = None
-        self.profile = False
+        # jax.profiler.TraceAnnotation while enable(profile=True), else None
+        self.annotation = None
         self.crash_dump_dir = None
         self.seen_programs = set()
         self.last_crash = None
         self.crash_seq = 0
         self.last_dumped_error = None
+        # per-run counters (next() on a count is atomic under the GIL):
+        # span ids and walk sequence numbers, deterministic within a run
+        self.span_ids = itertools.count(1)
+        self.walk_ids = itertools.count(1)
 
 
 _STATE = _State()
@@ -110,7 +132,9 @@ def enable(jsonl_path: Optional[str] = None, *, ring_size: int = 4096,
         _STATE.metrics = MetricsRegistry()
         _STATE.recorder = FlightRecorder(_STATE.run_id, ring_size=ring_size,
                                          jsonl_path=jsonl_path)
-        _STATE.profile = bool(profile)
+        _STATE.annotation = _trace_annotation() if profile else None
+        _STATE.span_ids = itertools.count(1)
+        _STATE.walk_ids = itertools.count(1)
         _STATE.crash_dump_dir = crash_dump_dir
         _STATE.seen_programs = set()
         _STATE.crash_seq = 0
@@ -135,7 +159,17 @@ def disable() -> None:
             rec.emit({"kind": "metrics", **_STATE.metrics.snapshot()})
             rec.close()
         _STATE.recorder = None
-        _STATE.profile = False
+        _STATE.annotation = None
+
+
+def _trace_annotation():
+    """``jax.profiler.TraceAnnotation``, imported once per ``enable`` (not
+    in every span's ``__enter__``); None where profiling cannot work."""
+    try:
+        from jax.profiler import TraceAnnotation
+    except Exception:  # noqa: BLE001 - profiling is best-effort
+        return None
+    return TraceAnnotation
 
 
 def enable_from_env() -> None:
@@ -241,18 +275,24 @@ class Span:
 
     After the block, ``wall_s`` / ``process_s`` hold the measured times —
     instrumented drivers read them to embed per-chunk numbers in result
-    metadata without re-measuring.
+    metadata without re-measuring.  ``id`` / ``parent`` / ``walk`` are the
+    span's identity (module docstring), fixed at ``__enter__``.
     """
 
     __slots__ = ("name", "attrs", "t0", "wall_s", "process_s", "depth",
-                 "_p0", "_ts0", "_ann")
+                 "id", "parent", "walk", "_link", "_p0", "_ts0", "_ann")
 
-    def __init__(self, name: str, attrs: dict):
+    def __init__(self, name: str, attrs: dict, link: Optional[tuple] = None,
+                 walk: Optional[int] = None):
         self.name = name
         self.attrs = attrs
         self.wall_s = None
         self.process_s = None
         self.depth = 0
+        self.id = None
+        self.parent = None
+        self.walk = walk  # set only on a walk root (walk_span)
+        self._link = link  # explicit (parent id, walk) from a hand-over
         self._ann = None
 
     def set(self, **attrs) -> "Span":
@@ -264,12 +304,17 @@ class Span:
         if stack is None:
             stack = _TLS.stack = []
         self.depth = len(stack)
+        link = self._link or _inherited_link()
+        if link is not None:
+            self.parent = link[0]
+            if self.walk is None:
+                self.walk = link[1]
+        self.id = next(_STATE.span_ids)
         stack.append(self)
-        if _STATE.profile:
+        annotation = _STATE.annotation
+        if annotation is not None:
             try:
-                from jax.profiler import TraceAnnotation
-
-                self._ann = TraceAnnotation(self.name)
+                self._ann = annotation(self.name, span_id=self.id)
                 self._ann.__enter__()
             except Exception:  # noqa: BLE001 - profiling is best-effort
                 self._ann = None
@@ -279,6 +324,13 @@ class Span:
         return self
 
     def __exit__(self, exc_type, exc, tb):
+        stack = getattr(_TLS, "stack", None)
+        if stack and stack[-1] is not self and self in stack:
+            # spans entered by hand (fit_chunked's walk.open / walk.close)
+            # and left open by an exception: close them innermost first, so
+            # their lines are written and the stack is this span's again
+            while stack[-1] is not self:
+                stack[-1].__exit__(exc_type, exc, tb)
         self.wall_s = time.perf_counter() - self.t0
         self.process_s = time.process_time() - self._p0
         if self._ann is not None:
@@ -286,17 +338,17 @@ class Span:
                 self._ann.__exit__(exc_type, exc, tb)
             except Exception:  # noqa: BLE001
                 pass
-        stack = getattr(_TLS, "stack", None)
         if stack and stack[-1] is self:
             stack.pop()
-        elif stack and self in stack:  # out-of-order exit: stay consistent
-            stack.remove(self)
         st = _STATE
         rec = st.recorder  # local capture: disable() may null it between
         if st.enabled and rec is not None:  # the check and the emit
             ev = {"kind": "span", "name": self.name, "t0": self._ts0,
                   "wall_s": round(self.wall_s, 6),
-                  "process_s": round(self.process_s, 6), "depth": self.depth}
+                  "process_s": round(self.process_s, 6), "depth": self.depth,
+                  "id": self.id, "parent": self.parent}
+            if self.walk is not None:
+                ev["walk"] = self.walk
             if self.attrs:
                 ev["attrs"] = self.attrs
             if exc_type is not None:
@@ -316,6 +368,9 @@ class _NullSpan:
     wall_s = None
     process_s = None
     depth = 0
+    id = None
+    parent = None
+    walk = None
 
     def set(self, **attrs) -> "_NullSpan":
         return self
@@ -330,14 +385,67 @@ class _NullSpan:
 NULL_SPAN = _NullSpan()
 
 
-def span(name: str, **attrs):
+def _inherited_link() -> Optional[tuple]:
+    """``(id, walk)`` of the innermost span open on this thread, else the
+    link a :func:`span_scope` adopted for it, else None."""
+    stack = getattr(_TLS, "stack", None)
+    if stack:
+        return stack[-1].id, stack[-1].walk
+    return getattr(_TLS, "base", None)
+
+
+def span(name: str, parent: Optional[tuple] = None, **attrs):
     """Open a nested timing span: ``with obs.span("chunk", lo=0): ...``.
 
+    ``parent=`` is a :func:`span_link` taken where the work was handed to
+    this thread; without it the parent is the span open beneath this one.
     Disabled plane -> the shared no-op span (no allocation beyond the
     kwargs dict at the call site)."""
     if not _STATE.enabled:
         return NULL_SPAN
-    return Span(name, attrs)
+    return Span(name, attrs, link=parent)
+
+
+def walk_span(**attrs):
+    """The root span of one ``fit_chunked`` call, named ``walk``: it draws
+    the run's next walk sequence number, and every span opened beneath it
+    (on this thread, or on another through a link) carries that number as
+    ``walk``."""
+    if not _STATE.enabled:
+        return NULL_SPAN
+    return Span("walk", attrs, walk=next(_STATE.walk_ids))
+
+
+def current_span():
+    """The innermost span open on this thread (the shared no-op span when
+    disabled or outside every span): how ``fit_chunked``'s body reaches
+    the ``walk`` root its decorator opened, to set its attributes."""
+    if not _STATE.enabled:
+        return NULL_SPAN
+    stack = getattr(_TLS, "stack", None)
+    return stack[-1] if stack else NULL_SPAN
+
+
+def span_link() -> Optional[tuple]:
+    """``(id, walk)`` of the innermost span open on this thread — what a
+    hand-over to another thread carries (the committer's item, the
+    prefetcher's slot, the watchdog's worker) so the span opened there
+    names its cause.  None when disabled or outside every span."""
+    return _inherited_link() if _STATE.enabled else None
+
+
+@contextlib.contextmanager
+def span_scope(link: Optional[tuple]):
+    """Adopt ``link`` (a :func:`span_link`) as the parent of the outermost
+    spans this thread opens inside the block: the watchdog's worker runs a
+    chunk's fit for the driver's ``chunk`` span, and its ``sanitize`` /
+    ``fit.primary`` must name that span, not start a new root."""
+    prev = getattr(_TLS, "base", None)
+    _TLS.base = link
+    try:
+        yield
+    finally:
+        _TLS.base = prev
 
 
 # -- run summary / failure dumps --------------------------------------------

@@ -12,13 +12,14 @@ rebuild's analog is two-layered:
   path, every event is also appended (and flushed — a SIGKILL loses at most
   the current line) to a file ``tools/obs_report.py`` renders.
 
-One event = one flat JSON object.  Schema (``SCHEMA_VERSION``, v2):
+One event = one flat JSON object.  Schema (``SCHEMA_VERSION``, v3):
 
 - every line: ``ts`` (epoch seconds) and ``kind`` in
   ``meta | span | event | metrics``;
 - ``meta``: first line of a stream — ``schema``, ``run_id``, ``pid``;
 - ``span``: ``name``, ``t0``, ``wall_s``, ``process_s``, ``depth``,
-  ``attrs`` (a closed span; emitted at exit);
+  ``attrs`` (a closed span; emitted at exit), and since v3 its identity
+  ``id`` / ``parent`` / ``walk`` (below);
 - ``event``: ``name``, ``attrs`` (a point event: journal commit, OOM
   backoff, watchdog timeout, fit failure);
 - ``metrics``: a full registry snapshot (``counters`` / ``gauges`` /
@@ -41,6 +42,37 @@ Schema v2 (ISSUE 18) adds an OPTIONAL top-level ``trace`` object on
 v1 streams (no ``trace`` anywhere) remain readable by every consumer;
 ``tools/obs_report.py --check`` accepts an absent ``trace`` and FAILS a
 malformed one (wrong type, bad id shape) instead of letting it vanish.
+
+Schema v3 (ISSUE 25) gives every ``span`` line three top-level fields
+(:mod:`.core` sets them; the serving ``trace`` object above is untouched):
+
+- ``id``: a positive integer from one per-run counter, unique within the
+  run (the mirrored ``jax.profiler`` annotation carries it as the stat
+  ``span_id``);
+- ``parent``: the ``id`` of the span that caused this one — the span open
+  beneath it on its thread, or the span that handed the work over
+  (``commit.overlap`` -> the ``chunk.submit`` that queued it,
+  ``stage.overlap`` -> the ``chunk`` that scheduled it, a watchdog
+  worker's ``sanitize`` / ``fit.primary`` -> its ``chunk``); ``null`` on a
+  root.  A span closes after its children, so a parent's line FOLLOWS
+  theirs;
+- ``walk`` (present inside a walk only): the run's sequence number of the
+  ``fit_chunked`` call, shared by every span of that call, across threads.
+
+The walk path's spans, root to leaf: ``walk`` (attrs ``rows``,
+``chunk_rows``, ``lanes``, ``journaled``) > ``walk.open``, then per chunk
+``chunk.plan`` (``lo``, ``hi``), ``chunk`` > ``sanitize``, ``fit.primary``
+> ``fit.stage1`` (``rows``, ``iters``, ``undone``) and ``fit.stage2``
+(``rows``), ``fit.readback`` (``rows``, ``iters_max``, ``iters_sum``,
+``failed``), the ladder's ``fit.rung.*``, then ``chunk.submit`` (``lo``,
+``hi``) > ``commit.overlap`` on the committer thread; ``stage.overlap`` on
+the prefetcher thread under its ``chunk``; last ``walk.close``.
+
+v2 lines (no ``id``) stay readable: ``--check`` takes a span line with or
+without the identity, and FAILS one whose ``id`` / ``parent`` / ``walk`` is
+not a positive integer or whose ``id`` repeats within its run (a stream cut
+by SIGKILL ends with children whose parents never closed, so that a
+``parent`` resolves is a test's business, not the gate's).
 """
 
 from __future__ import annotations
@@ -54,7 +86,7 @@ from typing import Optional
 
 __all__ = ["SCHEMA_VERSION", "FlightRecorder"]
 
-SCHEMA_VERSION = 2
+SCHEMA_VERSION = 3
 
 
 class FlightRecorder:

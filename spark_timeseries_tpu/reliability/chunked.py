@@ -95,7 +95,23 @@ from .status import STATUS_DTYPE, FitStatus, status_counts
 __all__ = ["OOMBackoffExceeded", "is_resource_exhausted", "fit_chunked"]
 
 
+def _under_walk_span(fn):
+    """Run ``fn`` (``fit_chunked``) under the ``walk`` root span: the owner
+    of the walk id every span of the call shares.  Leaving the span also
+    closes ``walk.open`` / ``walk.close`` if an exception left one open."""
+
+    @functools.wraps(fn)
+    def wrapped(*args, **kwargs):
+        if not obs.enabled():
+            return fn(*args, **kwargs)
+        with obs.walk_span():
+            return fn(*args, **kwargs)
+
+    return wrapped
+
+
 @obs.dump_on_failure("fit_chunked")
+@_under_walk_span
 def fit_chunked(
     fit_fn: Callable,
     y,
@@ -326,7 +342,11 @@ def fit_chunked(
     hashed fit kwargs; the coordinate only labels where in the search
     the work happened.
 
-    **Telemetry** (``obs.enable()``): each chunk dispatch runs under an
+    **Telemetry** (``obs.enable()``): the call is one ``walk`` span (the
+    root of the span tree and owner of the walk id) over ``walk.open``
+    (everything up to the lanes' start), the lanes' chunks and
+    ``walk.close`` (merge, assembly, manifest); each chunk dispatch runs
+    under an
     ``obs.span("chunk")`` whose first dispatch per (fit, shape, dtype) is
     tagged ``compile+execute`` (JAX pays trace+compile there) and the rest
     ``execute``; backoffs, timeouts, and per-row status totals feed the
@@ -346,6 +366,11 @@ def fit_chunked(
     # the walk reaches it — the panel NEVER fully resides on device.  A
     # DeviceChunkSource unwraps to the resident-array walk, byte-identical
     # to passing the array itself.
+    walk_sp = obs.current_span()  # the `walk` root (_under_walk_span)
+    # entered by hand, not `with`: walk.open and walk.close cover the two
+    # long halves of this body around the lanes; an exception that leaves
+    # one open is closed by the walk root's exit
+    open_sp = obs.span("walk.open").__enter__()
     src = None
     chunk_rows_from_source = False
     if isinstance(y, source_mod.ChunkSource):
@@ -891,6 +916,9 @@ def fit_chunked(
             and int(process_index or 0) == 0):
         warmer = journal_mod.MergeWarmer(checkpoint_dir, len(spans))
     elastic_meta = None
+    walk_sp.set(rows=int(b), chunk_rows=chunk0, lanes=len(lane_specs),
+                journaled=journals is not None)
+    open_sp.__exit__(None, None, None)
     try:
         if elastic:
             # elastic supervision (ISSUE 11): lanes pull spans from the
@@ -951,6 +979,7 @@ def fit_chunked(
         raise
 
     # -- merge lanes ---------------------------------------------------------
+    close_sp = obs.span("walk.close").__enter__()
     # results arrive one per WALKED SPAN (an elastic lane can walk several);
     # spans are disjoint and each result's pieces ascend, so the sort by
     # span lo yields globally ascending pieces either way
@@ -1161,6 +1190,7 @@ def fit_chunked(
                     "process_index": int(process_index or 0)}
         acct["chunks_resumed"] = sum(j.resumed_entries for j in journals)
         meta["journal"] = acct
+    close_sp.__exit__(None, None, None)
     return ResilientFitResult(params, nll, conv, iters, status, meta)
 
 

@@ -76,6 +76,7 @@ class _Item(NamedTuple):
     piece: object
     wall_s: float
     info: dict  # extra manifest-entry fields captured at submit time
+    link: object  # obs.span_link() of the submitting span (None: obs off)
 
 
 class ChunkCommitter:
@@ -145,7 +146,8 @@ class ChunkCommitter:
 
     def _commit_one(self, item: _Item):
         t0 = time.perf_counter()
-        with obs.span("commit.overlap", lo=item.lo, hi=item.hi):
+        with obs.span("commit.overlap", parent=item.link, lo=item.lo,
+                      hi=item.hi):
             arrays = self._fetch(item.piece)
             info = dict(item.info)
             if self._probe is not None:
@@ -207,7 +209,8 @@ class ChunkCommitter:
         self.check()
         if self._closed:
             raise RuntimeError("submit() on a closed ChunkCommitter")
-        item = _Item(int(lo), int(hi), piece, float(wall_s), info)
+        item = _Item(int(lo), int(hi), piece, float(wall_s), info,
+                     obs.span_link())
         t0 = time.perf_counter()
         while True:
             try:

@@ -638,94 +638,104 @@ class LaneRunner:
         fit_fn, fit_kwargs = self.fit_fn, self.fit_kwargs
         lo = spec.lo
         while True:
-            if self.committer is not None:
-                err = self.committer.take_error()
-                if err is not None:
-                    lo, self.chunk = self._rollback(err)
-                    continue
-            if lo >= self.hi:
-                # final drain: a commit of one of the last chunks may still
-                # fail (or OOM at fetch) — that must surface (or roll the
-                # walk back) BEFORE assembly reads the pieces
-                err = self._drain_for_journal_write()
-                if err is not None:
-                    lo, self.chunk = self._rollback(err)
-                    continue
-                break
-            if journal is not None:
-                entry = journal.committed(lo)
-                if entry is not None:
-                    piece = journal.load_chunk(entry)
-                    if piece is not None:
-                        self._note_busy(int(entry["hi"]))  # not stealable
-                        if self.sink is not None:
-                            # resume re-emits the chunk through the sink:
-                            # the durable re-write replaces any torn or
-                            # missing output shard with the same bytes,
-                            # which is what makes a killed-and-resumed
-                            # sink directory finalize bitwise-identical
-                            self.sink.write(lo, int(entry["hi"]),
-                                            _commit_arrays(piece))
-                            piece = _SunkChunk(lo, int(entry["hi"]))
-                        self.pieces.append((lo, int(entry["hi"]), piece))
-                        if tele:
-                            self.tele_chunks.append(
-                                {"lo": lo, "hi": int(entry["hi"]),
-                                 "phase": "resumed", **self.tag})
-                        lo = entry["hi"]
-                        # replay the backoff state in effect when the chunk
-                        # committed, so the resumed walk visits the SAME
-                        # boundaries the uninterrupted run would have
-                        self.chunk = int(entry.get("chunk_rows_after",
-                                                   self.chunk))
+            # chunk.plan: the driver's work before a dispatch (committer
+            # error poll, resume lookup, boundary decision, deadline check);
+            # the walk's last turn, the final drain, has no `hi`
+            with obs.span("chunk.plan", lo=lo, **self.tag) as plan_sp:
+                if self.committer is not None:
+                    err = self.committer.take_error()
+                    if err is not None:
+                        lo, self.chunk = self._rollback(err)
                         continue
-                    self.lost_boundaries[lo] = (
-                        int(entry["hi"]),
-                        int(entry.get("chunk_rows_after", self.chunk)))
-            forced = self.lost_boundaries.get(lo)
-            # the chunk boundary is decided and PUBLISHED (as _busy_hi)
-            # under the span lock, so a concurrent try_steal can never
-            # split inside a chunk this iteration is about to dispatch
-            with self._mu:
-                hi = forced[0] if forced else min(lo + self.chunk, self._hi)
-                if journal is not None and not forced:
-                    # keep the walk on the committed grid: after an OOM
-                    # backoff whose halving does not divide the original
-                    # chunk size, a free-running hi would sail past the next
-                    # committed chunk's lo, orphaning it (never matched
-                    # again) and double-counting its rows in the manifest —
-                    # clamp to the boundary instead
-                    nxt = journal.next_committed_lo(lo)
-                    if nxt is not None and nxt < hi:
-                        hi = nxt
-                if hi > self._busy_hi:
-                    self._busy_hi = hi
-            if deadline.exceeded():
-                err = self._drain_for_journal_write()
-                if err is not None:
-                    lo, self.chunk = self._rollback(err)
-                    continue
-                if forced:
-                    self.chunk = forced[1]
-                    self.lost_boundaries.pop(lo, None)
-                self.timeout_events.append({
-                    "at_row": lo, "chunk_rows": hi - lo, "dispatched": False,
-                    "budget_s": deadline.budget_s, "scope": "job"})
-                obs.counter("chunked.timeouts.job").inc()
-                obs.event("chunk.timeout", lo=lo, hi=hi, scope="job",
-                          dispatched=False, **self.tag)
-                if tele:
-                    self.tele_chunks.append({"lo": lo, "hi": hi,
-                                             "phase": "timeout",
-                                             "scope": "job", **self.tag})
-                self.pieces.append((lo, hi, _TimeoutChunk(lo, hi)))
+                if lo >= self.hi:
+                    # final drain: a commit of one of the last chunks may
+                    # still fail (or OOM at fetch) — that must surface (or
+                    # roll the walk back) BEFORE assembly reads the pieces
+                    err = self._drain_for_journal_write()
+                    if err is not None:
+                        lo, self.chunk = self._rollback(err)
+                        continue
+                    break
                 if journal is not None:
-                    journal.mark_timeout(lo, hi, scope="job",
-                                         budget_s=deadline.budget_s,
-                                         chunk_rows_after=self.chunk,
-                                         **self._owner)
-                lo = hi
-                continue
+                    entry = journal.committed(lo)
+                    if entry is not None:
+                        piece = journal.load_chunk(entry)
+                        if piece is not None:
+                            # not stealable:
+                            self._note_busy(int(entry["hi"]))
+                            if self.sink is not None:
+                                # resume re-emits the chunk through the
+                                # sink: the durable re-write replaces any
+                                # torn or missing output shard with the same
+                                # bytes, which is what makes a killed-and-
+                                # resumed sink directory finalize
+                                # bitwise-identical
+                                self.sink.write(lo, int(entry["hi"]),
+                                                _commit_arrays(piece))
+                                piece = _SunkChunk(lo, int(entry["hi"]))
+                            self.pieces.append(
+                                (lo, int(entry["hi"]), piece))
+                            if tele:
+                                self.tele_chunks.append(
+                                    {"lo": lo, "hi": int(entry["hi"]),
+                                     "phase": "resumed", **self.tag})
+                            lo = entry["hi"]
+                            # replay the backoff state in effect when the
+                            # chunk committed, so the resumed walk visits the
+                            # SAME boundaries the uninterrupted run would have
+                            self.chunk = int(entry.get("chunk_rows_after",
+                                                       self.chunk))
+                            continue
+                        self.lost_boundaries[lo] = (
+                            int(entry["hi"]),
+                            int(entry.get("chunk_rows_after", self.chunk)))
+                forced = self.lost_boundaries.get(lo)
+                # the chunk boundary is decided and PUBLISHED (as _busy_hi)
+                # under the span lock, so a concurrent try_steal can never
+                # split inside a chunk this iteration is about to dispatch
+                with self._mu:
+                    hi = (forced[0] if forced
+                          else min(lo + self.chunk, self._hi))
+                    if journal is not None and not forced:
+                        # keep the walk on the committed grid: after an OOM
+                        # backoff whose halving does not divide the original
+                        # chunk size, a free-running hi would sail past the
+                        # next committed chunk's lo, orphaning it (never
+                        # matched again) and double-counting its rows in the
+                        # manifest — clamp to the boundary instead
+                        nxt = journal.next_committed_lo(lo)
+                        if nxt is not None and nxt < hi:
+                            hi = nxt
+                    if hi > self._busy_hi:
+                        self._busy_hi = hi
+                if deadline.exceeded():
+                    err = self._drain_for_journal_write()
+                    if err is not None:
+                        lo, self.chunk = self._rollback(err)
+                        continue
+                    if forced:
+                        self.chunk = forced[1]
+                        self.lost_boundaries.pop(lo, None)
+                    self.timeout_events.append({
+                        "at_row": lo, "chunk_rows": hi - lo,
+                        "dispatched": False,
+                        "budget_s": deadline.budget_s, "scope": "job"})
+                    obs.counter("chunked.timeouts.job").inc()
+                    obs.event("chunk.timeout", lo=lo, hi=hi, scope="job",
+                              dispatched=False, **self.tag)
+                    if tele:
+                        self.tele_chunks.append({"lo": lo, "hi": hi,
+                                                 "phase": "timeout",
+                                                 "scope": "job", **self.tag})
+                    self.pieces.append((lo, hi, _TimeoutChunk(lo, hi)))
+                    if journal is not None:
+                        journal.mark_timeout(lo, hi, scope="job",
+                                             budget_s=deadline.budget_s,
+                                             chunk_rows_after=self.chunk,
+                                             **self._owner)
+                    lo = hi
+                    continue
+                plan_sp.set(hi=hi)
 
             def run_chunk(lo=lo, hi=hi, chunk=self.chunk):
                 # lo/hi/chunk are DEFAULT-ARG SNAPSHOTS, not closure reads:
@@ -854,69 +864,78 @@ class LaneRunner:
                     ) from e
                 self.chunk = self._record_oom(lo, self.chunk, e)
                 continue
-            if forced:  # torn-shard recompute done: restore the recorded walk
-                self.chunk = forced[1]
-                self.lost_boundaries.pop(lo, None)
-            if tele:
-                self.tele_chunks.append({"lo": lo, "hi": hi, "phase": phase,
-                                         **self.tag, **_span_times(sp)})
-            if journal is not None:
-                wall_s = round(time.perf_counter() - t0, 4)
-                owner = self._owner
-                if self.committer is not None and not forced:
-                    # background commit: the fetch + shard + manifest update
-                    # overlap the next chunk's dispatch/compute.  chunk_rows
-                    # _after is captured NOW (not at commit time) so the
-                    # recorded backoff state matches the serial walk exactly
-                    try:
-                        self.committer.submit(lo, hi, piece, wall_s=wall_s,
-                                              chunk_rows_after=self.chunk,
-                                              **owner)
-                    except BaseException as se:
-                        err = self.committer.take_error()
-                        # only the worker's OWN re-raised error enters the
-                        # rollback path: an unrelated exception (e.g. a
-                        # Ctrl-C landing while submit blocked) must abort,
-                        # not be converted into an OOM retry
-                        if err is None or err[0] is not se:
-                            raise
-                        lo, self.chunk = self._rollback(err)
-                        continue
+            # chunk.submit: from the chunk span's end to the next turn —
+            # the telemetry row, the hand-over to the committer (its
+            # backpressure included) or the synchronous commit
+            with obs.span("chunk.submit", lo=lo, hi=hi, **self.tag):
+                if forced:
+                    # torn-shard recompute done: restore the recorded walk
+                    self.chunk = forced[1]
+                    self.lost_boundaries.pop(lo, None)
+                if tele:
+                    self.tele_chunks.append(
+                        {"lo": lo, "hi": hi, "phase": phase, **self.tag,
+                         **_span_times(sp)})
+                if journal is not None:
+                    wall_s = round(time.perf_counter() - t0, 4)
+                    owner = self._owner
+                    if self.committer is not None and not forced:
+                        # background commit: the fetch + shard + manifest
+                        # update overlap the next chunk's dispatch/compute.
+                        # chunk_rows_after is captured NOW (not at commit
+                        # time) so the recorded backoff state matches the
+                        # serial walk exactly
+                        try:
+                            self.committer.submit(lo, hi, piece, wall_s=wall_s,
+                                                  chunk_rows_after=self.chunk,
+                                                  **owner)
+                        except BaseException as se:
+                            err = self.committer.take_error()
+                            # only the worker's OWN re-raised error enters the
+                            # rollback path: an unrelated exception (e.g. a
+                            # Ctrl-C landing while submit blocked) must abort,
+                            # not be converted into an OOM retry
+                            if err is None or err[0] is not se:
+                                raise
+                            lo, self.chunk = self._rollback(err)
+                            continue
+                    else:
+                        # forced torn-shard recommits stay synchronous: they
+                        # are rare, their boundaries are pinned by the
+                        # journal, and the serial path keeps their edge
+                        # semantics exact
+                        err = self._drain_for_journal_write()
+                        if err is not None:
+                            lo, self.chunk = self._rollback(err)
+                            continue
+                        arrays = _commit_arrays(piece)
+                        pm = obs.peak_memory()
+                        journal.commit_chunk(
+                            lo, hi, arrays,
+                            wall_s=wall_s,
+                            peak_hbm_bytes=pm.bytes,
+                            peak_hbm_source=pm.source,
+                            chunk_rows_after=self.chunk,
+                            status_counts=status_counts(arrays["status"]),
+                            # host-resident walks: the staging RAM behind the
+                            # device peak, so oversubscribed post-mortems see
+                            # the job's whole footprint (obs.memory)
+                            **({"peak_staging_pool_bytes":
+                                pm.staging_pool_bytes}
+                               if pm.staging_pool_bytes is not None else {}),
+                            **owner,
+                        )
+                        if self.sink is not None:
+                            self.sink.write(lo, hi, arrays)
+                if self.sink is not None:
+                    # the committer (or the serial path above) owns the real
+                    # piece until its arrays are durable in the sink; the walk
+                    # keeps only the boundaries
+                    self.pieces.append((lo, hi, _SunkChunk(lo, hi)))
                 else:
-                    # forced torn-shard recommits stay synchronous: they are
-                    # rare, their boundaries are pinned by the journal, and
-                    # the serial path keeps their edge semantics exact
-                    err = self._drain_for_journal_write()
-                    if err is not None:
-                        lo, self.chunk = self._rollback(err)
-                        continue
-                    arrays = _commit_arrays(piece)
-                    pm = obs.peak_memory()
-                    journal.commit_chunk(
-                        lo, hi, arrays,
-                        wall_s=wall_s,
-                        peak_hbm_bytes=pm.bytes,
-                        peak_hbm_source=pm.source,
-                        chunk_rows_after=self.chunk,
-                        status_counts=status_counts(arrays["status"]),
-                        # host-resident walks: the staging RAM behind the
-                        # device peak, so oversubscribed post-mortems see
-                        # the job's whole footprint (obs.memory)
-                        **({"peak_staging_pool_bytes": pm.staging_pool_bytes}
-                           if pm.staging_pool_bytes is not None else {}),
-                        **owner,
-                    )
-                    if self.sink is not None:
-                        self.sink.write(lo, hi, arrays)
-            if self.sink is not None:
-                # the committer (or the serial path above) owns the real
-                # piece until its arrays are durable in the sink; the walk
-                # keeps only the boundaries
-                self.pieces.append((lo, hi, _SunkChunk(lo, hi)))
-            else:
-                self.pieces.append((lo, hi, piece))
-            with self._mu:
-                self._rows_done += hi - lo
+                    self.pieces.append((lo, hi, piece))
+                with self._mu:
+                    self._rows_done += hi - lo
             lo = hi
 
 

@@ -159,7 +159,7 @@ class ChunkPrefetcher:
             item = self._q.get()
             if item is _STOP:
                 return
-            lo, hi, slot = item
+            lo, hi, slot, link = item
             # drop the tuple's slice reference immediately: the worker
             # blocks in q.get() between requests, and a lingering local
             # would pin the previous staged buffer (= one chunk of HBM)
@@ -171,7 +171,7 @@ class ChunkPrefetcher:
                 continue
             t0 = time.perf_counter()
             try:
-                with obs.span("stage.overlap", lo=lo, hi=hi):
+                with obs.span("stage.overlap", parent=link, lo=lo, hi=hi):
                     # the SAME slice expression the serial driver uses:
                     # identical compiled program, identical bytes
                     vals = self._panel[lo:hi]
@@ -209,7 +209,9 @@ class ChunkPrefetcher:
                 return
             slot = _Slot()
             self._slots[(lo, hi)] = slot
-        self._q.put((lo, hi, slot))
+        # the scheduling span's link rides along, so stage.overlap names
+        # the chunk that asked for the slice
+        self._q.put((lo, hi, slot, obs.span_link()))
         obs.gauge("prefetch.queue_depth").set(len(self._slots))
 
     def take(self, lo: int, hi: int):

@@ -200,16 +200,25 @@ def resilient_fit(
 
     with obs.span("fit.primary", rows=b):
         res = fit_fn(y_clean, **fit_kwargs)
-    params = np.array(res.params)
-    nll = np.array(res.neg_log_likelihood)
-    conv = np.array(res.converged)
-    iters = np.array(res.iters)
-    excluded = (status == FitStatus.EXCLUDED) | _structurally_excluded(res)
-    status = np.maximum(
-        status, np.where(excluded, FitStatus.EXCLUDED, 0)
-    ).astype(STATUS_DTYPE)
+    # fit.readback: the first host read of the result waits for the device,
+    # and the next chunk is not dispatched before these passes are done
+    with obs.span("fit.readback", rows=b) as readback:
+        params = np.array(res.params)
+        nll = np.array(res.neg_log_likelihood)
+        conv = np.array(res.converged)
+        iters = np.array(res.iters)
+        excluded = (status == FitStatus.EXCLUDED) | _structurally_excluded(res)
+        status = np.maximum(
+            status, np.where(excluded, FitStatus.EXCLUDED, 0)
+        ).astype(STATUS_DTYPE)
 
-    failed = _failed_mask(res) & ~excluded
+        failed = _failed_mask(res) & ~excluded
+        if obs.enabled():
+            # what the lockstep optimizer spent: every row of the chunk
+            # rides along for iters_max iterations, iters_sum of them useful
+            readback.set(iters_max=int(iters.max(initial=0)),
+                         iters_sum=int(iters.sum()),
+                         failed=int(failed.sum()))
     # ladder size cap: rows past the cap skip the ladder entirely (they
     # stay in ``failed`` and are flagged DIVERGED below), bounding the
     # worst-case ladder cost on mass-non-convergence panels

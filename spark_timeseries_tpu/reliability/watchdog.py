@@ -153,6 +153,7 @@ def call_with_deadline(fn: Callable, budget_s: Optional[float] = None,
         lane = current_lane()
     req = current_request()  # serving request tag survives the hop too
     tctx = obs.current_trace()  # and so does the trace context (ISSUE 18)
+    link = obs.span_link()  # and the open span: the worker's spans name it
     if budget_s is None:
         with lane_context(lane):
             return fn()
@@ -162,7 +163,7 @@ def call_with_deadline(fn: Callable, budget_s: Optional[float] = None,
     def worker():
         try:
             with lane_context(lane), request_context(req), \
-                    obs.trace_scope(tctx):
+                    obs.trace_scope(tctx), obs.span_scope(link):
                 box["result"] = fn()
         except BaseException as e:  # noqa: BLE001 - re-raised in the caller
             box["error"] = e

@@ -400,3 +400,325 @@ class TestInstrumentation:
                  if json.loads(l).get("kind") == "span"]
         assert any(s["name"] == "compat.fit_model"
                    and s["attrs"]["model"] == "EWMA" for s in spans)
+
+
+# ---------------------------------------------------------------------------
+# span identity and the walk path's spans (ISSUE 25, schema v3)
+# ---------------------------------------------------------------------------
+
+
+def _span_lines(path):
+    return [ev for ev in map(json.loads, open(path)) if ev["kind"] == "span"]
+
+
+def _traced_walk(tmp_path, name="ev.jsonl", **kw):
+    """One journaled two-chunk walk with the plane on: ``(result, span
+    lines)``."""
+    p = str(tmp_path / name)
+    obs.enable(p)
+    res = _fit(_ar_panel(b=8), str(tmp_path / (name + ".journal")), **kw)
+    obs.disable()
+    return res, _span_lines(p)
+
+
+_CHUNK_PHASES = ["chunk.plan", "chunk", "fit.readback", "chunk.submit"]
+
+
+class TestSpanIdentity:
+    def test_ids_unique_and_every_parent_in_the_same_walk(self, tmp_path):
+        _, spans = _traced_walk(tmp_path)
+        by_id = {s["id"]: s for s in spans}
+        assert len(by_id) == len(spans)
+        assert all(isinstance(i, int) and i > 0 for i in by_id)
+        roots = [s for s in spans if s["parent"] is None]
+        assert [s["name"] for s in roots] == ["walk"]
+        for s in spans:
+            assert s["walk"] == roots[0]["walk"]
+            if s["parent"] is not None:
+                assert by_id[s["parent"]]["walk"] == s["walk"], s
+
+    def test_link_and_scope_carry_parent_and_walk_across_threads(self):
+        import threading
+
+        from spark_timeseries_tpu.obs import core
+
+        obs.enable()
+        with obs.walk_span() as root, obs.span("submitter") as sub:
+            link = obs.span_link()
+
+            def work():
+                with obs.span("handed", parent=link):
+                    pass
+                with obs.span_scope(link), obs.span("adopted"):
+                    with obs.span("nested"):
+                        pass
+                with obs.span("stray"):
+                    pass
+
+            th = threading.Thread(target=work)
+            th.start()
+            th.join(timeout=30)
+            assert not th.is_alive()
+        got = {s["name"]: s for s in core._STATE.recorder.tail()
+               if s["kind"] == "span"}
+        assert link == (sub.id, root.walk)
+        assert got["handed"]["parent"] == got["adopted"]["parent"] == sub.id
+        assert got["nested"]["parent"] == got["adopted"]["id"]
+        assert got["handed"]["walk"] == got["nested"]["walk"] == root.walk
+        assert got["stray"]["parent"] is None and "walk" not in got["stray"]
+
+    def test_a_span_left_open_is_closed_by_its_parent(self):
+        from spark_timeseries_tpu.obs import core
+
+        obs.enable()
+        with pytest.raises(RuntimeError):
+            with obs.span("outer") as outer:
+                obs.span("entered.by.hand").__enter__()
+                raise RuntimeError("boom")
+        got = {s["name"]: s for s in core._STATE.recorder.tail()
+               if s["kind"] == "span"}
+        assert got["entered.by.hand"]["parent"] == outer.id
+        assert got["entered.by.hand"]["error"] == "RuntimeError"
+        assert obs.current_span() is obs.NULL_SPAN  # the stack is empty
+        with obs.span("next") as nxt:
+            assert nxt.parent is None
+
+    def test_two_walks_in_one_enable_get_two_walk_ids(self, tmp_path):
+        p = str(tmp_path / "ev.jsonl")
+        obs.enable(p)
+        y = _ar_panel(b=8)
+        _fit(y)
+        _fit(y, str(tmp_path / "j"))
+        obs.disable()
+        spans = _span_lines(p)
+        roots = [s for s in spans if s["name"] == "walk"]
+        assert [r["walk"] for r in roots] == [1, 2]
+        assert [r["attrs"]["journaled"] for r in roots] == [False, True]
+        assert roots[0]["attrs"] == {"rows": 8, "chunk_rows": 4, "lanes": 1,
+                                     "journaled": False}
+        for r in roots:
+            mine = [s for s in spans if s.get("walk") == r["walk"]]
+            assert sum(s["name"] == "chunk" for s in mine) == 2
+            assert {s["name"] for s in mine} >= {"walk.open", "walk.close"}
+
+    def test_profile_annotation_carries_the_span_id(self, monkeypatch):
+        from spark_timeseries_tpu.obs import core
+
+        made = []
+
+        class Annotation:
+            def __init__(self, name, **stats):
+                made.append((name, stats))
+
+            def __enter__(self):
+                return self
+
+            def __exit__(self, *exc):
+                return False
+
+        monkeypatch.setattr(core, "_trace_annotation", lambda: Annotation)
+        obs.enable(profile=True)
+        with obs.span("chunk") as sp:
+            pass
+        assert made == [("chunk", {"span_id": sp.id})]
+
+    @pytest.mark.parametrize("line,ok", [
+        # a v2 line: no identity at all
+        ({"kind": "span", "name": "chunk", "t0": 1.0, "wall_s": 0.1,
+          "process_s": 0.1, "depth": 0}, True),
+        ({"kind": "span", "name": "chunk", "t0": 1.0, "wall_s": 0.1,
+          "process_s": 0.1, "depth": 0, "id": 7, "parent": None,
+          "walk": 1}, True),
+        ({"kind": "span", "name": "chunk", "t0": 1.0, "wall_s": 0.1,
+          "process_s": 0.1, "depth": 0, "id": "7", "parent": None}, False),
+        ({"kind": "span", "name": "chunk", "t0": 1.0, "wall_s": 0.1,
+          "process_s": 0.1, "depth": 0, "id": 7, "parent": 0}, False),
+        ({"kind": "span", "name": "chunk", "t0": 1.0, "wall_s": 0.1,
+          "process_s": 0.1, "depth": 0, "parent": 3}, False),
+    ], ids=["v2", "v3", "id-not-int", "parent-not-positive",
+            "parent-without-id"])
+    def test_obs_report_check_takes_v2_and_v3_lines(self, tmp_path, line, ok):
+        p = str(tmp_path / "ev.jsonl")
+        with open(p, "w") as f:
+            f.write(json.dumps({"kind": "meta", "schema": 2 if "id" not in
+                                line else 3, "run_id": "r", "pid": 1,
+                                "ts": 1.0}) + "\n")
+            f.write(json.dumps(dict(line, ts=1.0)) + "\n")
+        out = subprocess.run(
+            [sys.executable, os.path.join(_ROOT, "tools", "obs_report.py"),
+             p, "--check"], capture_output=True, text=True, timeout=120)
+        assert (out.returncode == 0) == ok, out.stderr
+
+    def test_obs_report_check_refuses_a_repeated_id(self, tmp_path):
+        _, spans = _traced_walk(tmp_path)
+        p = str(tmp_path / "ev.jsonl")
+        with open(p, "a") as f:
+            f.write(json.dumps(spans[0]) + "\n")
+        out = subprocess.run(
+            [sys.executable, os.path.join(_ROOT, "tools", "obs_report.py"),
+             p, "--check"], capture_output=True, text=True, timeout=120)
+        assert out.returncode == 1 and "repeats" in out.stderr
+
+
+class TestWalkPathSpans:
+    def test_each_chunk_has_its_four_phases_in_order(self, tmp_path):
+        _, spans = _traced_walk(tmp_path)
+        root = next(s for s in spans if s["name"] == "walk")
+        # a span's line is written when it closes, so the driver's own
+        # lines (the walk's direct children and fit.readback) are in order
+        driver = [s for s in sorted(spans, key=lambda s: s["t0"])
+                  if s["name"] in _CHUNK_PHASES]
+
+        def chunk_lo(s):
+            if s["name"] == "fit.readback":
+                return _chunk_of(s, spans)["attrs"]["lo"]
+            return s["attrs"]["lo"] if "hi" in s["attrs"] else None
+
+        for lo, hi in ((0, 4), (4, 8)):
+            mine = [s for s in driver if chunk_lo(s) == lo]
+            assert [s["name"] for s in mine] == _CHUNK_PHASES
+            plan, chunk, readback, submit = mine
+            assert plan["attrs"]["hi"] == chunk["attrs"]["hi"] == hi
+            assert submit["attrs"] == {"lo": lo, "hi": hi}
+            assert plan["parent"] == chunk["parent"] == submit["parent"] \
+                == root["id"]
+            assert readback["parent"] == chunk["id"]
+            assert readback["attrs"]["rows"] == 4
+        # the walk's last turn (the final drain) plans nothing
+        last = [s for s in driver if s["name"] == "chunk.plan"
+                and "hi" not in s["attrs"]]
+        assert [s["attrs"] for s in last] == [{"lo": 8}]
+
+    def test_commit_overlap_names_its_submitter(self, tmp_path):
+        _, spans = _traced_walk(tmp_path)
+        by_id = {s["id"]: s for s in spans}
+        commits = [s for s in spans if s["name"] == "commit.overlap"]
+        assert len(commits) == 2
+        for c in commits:
+            sub = by_id[c["parent"]]
+            assert sub["name"] == "chunk.submit"
+            assert sub["attrs"] == c["attrs"]  # the same [lo, hi)
+            assert c["depth"] == 0  # first on the committer thread's stack
+
+    def test_stage_overlap_names_the_chunk_that_scheduled_it(self, tmp_path):
+        _, spans = _traced_walk(tmp_path)
+        by_id = {s["id"]: s for s in spans}
+        staged = [s for s in spans if s["name"] == "stage.overlap"]
+        assert [s["attrs"] for s in staged] == [{"lo": 4, "hi": 8}]
+        assert by_id[staged[0]["parent"]]["name"] == "chunk"
+        assert by_id[staged[0]["parent"]]["attrs"]["lo"] == 0
+
+    def test_watchdog_worker_spans_name_their_chunk(self, tmp_path):
+        _, spans = _traced_walk(tmp_path, chunk_budget_s=120.0)
+        by_id = {s["id"]: s for s in spans}
+        inner = [s for s in spans
+                 if s["name"] in ("sanitize", "fit.primary", "fit.readback")]
+        assert len(inner) == 6
+        for s in inner:
+            assert by_id[s["parent"]]["name"] == "chunk"
+            assert s["depth"] == 0  # the worker thread's own stack
+            assert s["walk"] == 1
+
+    def test_readback_counts_the_rows_iterations(self, tmp_path):
+        res, spans = _traced_walk(tmp_path)
+        iters = np.asarray(res.iters)
+        for s in (s for s in spans if s["name"] == "fit.readback"):
+            lo = _chunk_of(s, spans)["attrs"]["lo"]
+            mine = iters[lo:lo + 4]
+            assert s["attrs"] == {"rows": 4, "iters_max": int(mine.max()),
+                                  "iters_sum": int(mine.sum()), "failed": 0}
+
+    def test_walk_off_emits_nothing_and_is_bitwise(self, tmp_path):
+        """The invariance contract over the new sites: the walk that
+        emitted the tree above, with the plane off, touches no recorder
+        and returns the same bytes."""
+        from spark_timeseries_tpu.obs import core
+
+        on, spans = _traced_walk(tmp_path, chunk_budget_s=120.0)
+        assert {s["name"] for s in spans} >= {
+            "walk", "walk.open", "chunk.plan", "chunk", "fit.readback",
+            "chunk.submit", "commit.overlap", "walk.close"}
+        off = _fit(_ar_panel(b=8), str(tmp_path / "off"),
+                   chunk_budget_s=120.0)
+        assert core._STATE.recorder is None
+        assert obs.span_link() is None
+        assert obs.current_span() is obs.walk_span() is obs.NULL_SPAN
+        _assert_bitwise(on, off)
+        assert "telemetry" not in off.meta
+
+    def test_failed_open_closes_the_walk_tree(self, tmp_path):
+        p = str(tmp_path / "ev.jsonl")
+        obs.enable(p)
+        with pytest.raises(ValueError):
+            _fit(_ar_panel(b=8), grid=(3, 2))  # refused inside walk.open
+        assert obs.current_span() is obs.NULL_SPAN
+        obs.disable()
+        spans = {s["name"]: s for s in _span_lines(p)}
+        assert set(spans) == {"walk", "walk.open"}
+        assert spans["walk.open"]["error"] == spans["walk"]["error"] \
+            == "ValueError"
+
+
+def _chunk_of(span, spans):
+    by_id = {s["id"]: s for s in spans}
+    while span["name"] != "chunk":
+        span = by_id[span["parent"]]
+    return span
+
+
+class TestStageGateSpans:
+    """``fit.stage1`` / ``fit.stage2`` on the lazy path the chip runs
+    (pallas, batch >= the compaction gate): here the interpreted kernel at
+    the smallest batch the gate admits."""
+
+    @pytest.fixture()
+    def lazy(self, monkeypatch):
+        monkeypatch.setattr(arima, "_COMPACT_MIN_BATCH", 2048)
+        seen = []
+        real = arima._fit_stage1_program
+
+        def spy(*static):
+            run = real(*static)
+
+            def run1(*args):
+                out, aux = run(*args)
+                seen.append(aux["carry"])
+                return out, aux
+
+            return run1
+
+        monkeypatch.setattr(arima, "_fit_stage1_program", spy)
+        rng = np.random.default_rng(0)
+        y = jnp.asarray(np.cumsum(rng.normal(size=(2048, 40)),
+                                  axis=1).astype(np.float32))
+        return y, seen
+
+    # 14 iterations let stage 1 stop at the cap with budget left (stage 2
+    # runs); 8 exhaust the budget with rows undone (the gate skips it)
+    @pytest.mark.parametrize("max_iters,stage2", [(14, True), (8, False)])
+    def test_stage_spans_carry_the_gates_numbers(self, lazy, tmp_path,
+                                                 max_iters, stage2):
+        y, seen = lazy
+        fit = lambda: arima.fit(y, (1, 1, 1), backend="pallas-interpret",  # noqa: E731
+                                max_iters=max_iters)
+        off = fit()
+        p = str(tmp_path / "ev.jsonl")
+        obs.enable(p)
+        with obs.span("fit.primary") as primary:
+            on = fit()
+        obs.disable()
+        _assert_bitwise(on, off)
+        spans = {s["name"]: s for s in _span_lines(p)}
+        carry = seen[-1]
+        s1 = spans["fit.stage1"]
+        assert s1["attrs"] == {"rows": 2048, "iters": int(carry.k),
+                               "undone": int(carry.undone)}
+        assert all(type(s1["attrs"][k]) is int for k in ("iters", "undone"))
+        assert s1["parent"] == primary.id
+        assert s1["attrs"]["undone"] > 0
+        assert (s1["attrs"]["iters"] < max_iters) == stage2
+        assert ("fit.stage2" in spans) == stage2
+        if stage2:
+            assert spans["fit.stage2"]["attrs"] == {
+                "rows": optim.compaction_cap(2048)}
+            assert spans["fit.stage2"]["parent"] == primary.id

@@ -112,6 +112,38 @@ def validate_trace_stamp(i: int, ev: dict, errors: list) -> None:
                       f"{sorted(extra)}")
 
 
+def _is_id(v) -> bool:
+    return isinstance(v, int) and not isinstance(v, bool) and v > 0
+
+
+def validate_span_identity(i: int, ev: dict, seen: set, errors: list) -> None:
+    """Schema v3 (ISSUE 25): a span line MAY carry ``id`` / ``parent`` /
+    ``walk`` — absent is fine (v1/v2 streams), malformed or repeated
+    fails.  ``seen`` holds the ids of the run so far.  That a ``parent``
+    resolves is NOT gated here: a SIGKILLed process's stream ends with
+    children whose parents never closed, and ``--fleet --check`` must read
+    those (``tests/test_obs.py`` holds a finished walk to it instead)."""
+    if "id" not in ev:
+        for f in ("parent", "walk"):
+            if f in ev:
+                errors.append(f"line {i}: span carries {f} but no id")
+        return
+    if not _is_id(ev["id"]):
+        errors.append(f"line {i}: span id is not a positive integer: "
+                      f"{ev['id']!r}")
+    elif ev["id"] in seen:
+        errors.append(f"line {i}: span id {ev['id']} repeats within its run")
+    else:
+        seen.add(ev["id"])
+    parent = ev.get("parent")
+    if parent is not None and not _is_id(parent):
+        errors.append(f"line {i}: span parent is not a positive integer "
+                      f"or null: {parent!r}")
+    if "walk" in ev and not _is_id(ev["walk"]):
+        errors.append(f"line {i}: span walk is not a positive integer: "
+                      f"{ev['walk']!r}")
+
+
 def validate_events(events, errors) -> list:
     """Schema check (see obs.recorder docstring); appends to ``errors``."""
     if not events and not errors:
@@ -119,6 +151,7 @@ def validate_events(events, errors) -> list:
         return errors
     if events and events[0][1].get("kind") != "meta":
         errors.append("first event is not kind=meta")
+    span_ids = set()  # of the run so far: ids restart with every meta line
     for i, ev in events:
         kind = ev.get("kind")
         if kind not in KINDS:
@@ -131,7 +164,9 @@ def validate_events(events, errors) -> list:
         if kind == "meta":
             if not ev.get("run_id") or not isinstance(ev.get("schema"), int):
                 errors.append(f"line {i}: meta missing run_id/schema")
+            span_ids = set()
         elif kind == "span":
+            validate_span_identity(i, ev, span_ids, errors)
             if not isinstance(ev.get("name"), str):
                 errors.append(f"line {i}: span missing name")
             for f in ("wall_s", "process_s"):
