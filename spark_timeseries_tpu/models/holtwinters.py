@@ -252,30 +252,32 @@ def _fit_program(period, multiplicative, max_iters, tol, backend,
             seeds = pk.hw_seeds(
                 ya, period, multiplicative,
                 None if align_mode == "dense" else nv)
+            # ... and so is the kernel layout: fold the panel and its seeds
+            # ONCE, outside every while_loop (XLA does not hoist the [B, T]
+            # relayout out of the line search); all starts share it
+            folded = pk.hw_prefold(ya, seeds)
 
             def fb(u):
                 nat = optim.sigmoid_to_interval(u, 0.0, 1.0)
-                return pk.hw_sse_seeded(
-                    nat, ya, seeds, period, multiplicative, interpret=interp
+                return pk.hw_sse_folded(
+                    nat, folded, period, multiplicative, interpret=interp
                 ) / n_err
 
-            # straggler compaction (utils.optim): the objective closes over
-            # the NATURAL-layout panel + per-row seed state, so the subset
-            # gather is a plain row gather of each
+            # straggler compaction (utils.optim): the subset gather repacks
+            # folded COLUMNS (series ride the lanes), grid-aligned by the cap
             bsz = ya.shape[0]
             cap = optim.compaction_cap(bsz)
             straggler_fun = None
             if compact and bsz >= _COMPACT_MIN_BATCH:
 
                 def straggler_fun(idxc):
-                    yas = ya[idxc]
-                    seeds_s = tuple(s[idxc] for s in seeds)
+                    folded_s = folded.take(idxc)
                     nes = n_err[idxc]
 
                     def fb_s(u):
                         nat = optim.sigmoid_to_interval(u, 0.0, 1.0)
-                        return pk.hw_sse_seeded(
-                            nat, yas, seeds_s, period, multiplicative,
+                        return pk.hw_sse_folded(
+                            nat, folded_s, period, multiplicative,
                             interpret=interp) / nes
 
                     return fb_s
@@ -405,16 +407,17 @@ def _fit_stage1_program(period, multiplicative, max_iters, tol, backend,
         from ..ops import pallas_kernels as pk
 
         interp = backend == "pallas-interpret"
-        # seeds are data-only: compute ONCE and share across every start
-        # (same contract as the inline program)
-        seeds = pk.hw_seeds(
+        # seeds are data-only: compute and fold ONCE, before the first
+        # start, and share across every start (same contract as the inline
+        # program)
+        folded = pk.hw_prefold(ya, pk.hw_seeds(
             ya, period, multiplicative,
-            None if align_mode == "dense" else nv)
+            None if align_mode == "dense" else nv))
 
         def fb(u):
             nat = optim.sigmoid_to_interval(u, 0.0, 1.0)
-            return pk.hw_sse_seeded(
-                nat, ya, seeds, period, multiplicative, interpret=interp
+            return pk.hw_sse_folded(
+                nat, folded, period, multiplicative, interpret=interp
             ) / n_err
 
         bsz = ya.shape[0]
@@ -427,14 +430,14 @@ def _fit_stage1_program(period, multiplicative, max_iters, tol, backend,
                 (bsz, 3))
             res1, carry = optim.lbfgs_batched_stage1(
                 fb, u0, straggler_cap=cap, max_iters=max_iters, tol=tol)
-            # gather the compacted objective data HERE (plain row gathers
-            # of the natural-layout panel + per-row seed state) so the
-            # stage-2 program is a pure function of its inputs and keeps
-            # stable shapes across starts — ONE compiled stage-2 program
-            # serves every start that needs it
+            # gather the compacted objective data HERE (the same folded-
+            # COLUMN gather the inline straggler_fun performs) so the
+            # stage-2 program is a pure function of its inputs, folds
+            # nothing and keeps stable shapes across starts — ONE compiled
+            # stage-2 program serves every start that needs it
             starts_aux.append({
-                "carry": carry, "res": res1, "yas": ya[carry.idxc],
-                "seeds_s": tuple(x[carry.idxc] for x in seeds),
+                "carry": carry, "res": res1,
+                "folded_s": folded.take(carry.idxc),
                 "nes": n_err[carry.idxc]})
             results.append(res1)
         ok = nv >= 2 * period
@@ -457,8 +460,8 @@ def _fit_stage2_program(period, multiplicative, max_iters, tol, backend):
 
         def fb_s(u):
             nat = optim.sigmoid_to_interval(u, 0.0, 1.0)
-            return pk.hw_sse_seeded(
-                nat, aux_s["yas"], aux_s["seeds_s"], period, multiplicative,
+            return pk.hw_sse_folded(
+                nat, aux_s["folded_s"], period, multiplicative,
                 interpret=interp) / aux_s["nes"]
 
         return optim.lbfgs_batched_stage2(

@@ -55,6 +55,7 @@ rewrite must cover every mode of a kernel at once, not just the hot one.
 
 from __future__ import annotations
 
+import dataclasses
 import functools
 from typing import Tuple
 
@@ -1465,26 +1466,53 @@ def _hw_bwd_kernel(m, mult, t_limit, cs, nchunk, hp, *refs):
     gpar_ref[2] = gpar_ref[2] + dg
 
 
-def _hw_fwd_call(interpret, m, mult, save_resid, params, y, l0, t0, s0, zb):
-    b, t = y.shape
-    tp, cs, nchunk = _time_layout(t)
-    y3 = _fold(jnp.pad(y, ((0, 0), (0, tp - t))))
+@functools.partial(
+    jax.tree_util.register_dataclass,
+    data_fields=["y3", "l03", "t03", "s03", "zb3"], meta_fields=["t"])
+@dataclasses.dataclass(frozen=True)
+class HWFolded:
+    """A Holt-Winters panel and its seed state in kernel layout
+    (:func:`hw_prefold`): ``y3 [Tp, Bp/128, 128]`` zero-padded by
+    :func:`_time_layout`, the level / trend seeds and ``zb`` as
+    ``[1, Bp/128, 128]`` planes, the pre-rotated ring ``s03 [m, Bp/128,
+    128]``; ``t`` is the true series length (static: it rides the treedef
+    through a ``jit`` boundary)."""
+
+    y3: jax.Array
+    l03: jax.Array
+    t03: jax.Array
+    s03: jax.Array
+    zb3: jax.Array
+    t: int
+
+    def take(self, idxc):
+        """The series ``idxc`` (a multiple of 1024 of them) as folded
+        COLUMNS — series ride the lanes, so a row gather of the natural
+        layout is a column gather here and nothing is re-folded (the
+        straggler compaction, as ``models.arima`` repacks ``y3``)."""
+        nb = idxc.shape[0] // _LANES
+        return jax.tree_util.tree_map(
+            lambda x3: x3.reshape(x3.shape[0], -1)[:, idxc].reshape(
+                x3.shape[0], nb, _LANES), self)
+
+
+def _hw_fwd_call_f(interpret, m, mult, save_resid, params, f: HWFolded):
+    # pre-FOLDED entry (see _css_fwd_call_f): only the [B, 3] parameters are
+    # folded per call; the panel and its seeds arrive in kernel layout
+    _, cs, nchunk = _time_layout(f.t)
+    y3 = f.y3
     par3 = _fold(params)
-    l03 = _fold(l0[:, None].astype(y.dtype))
-    t03 = _fold(t0[:, None].astype(y.dtype))
-    s03 = _fold(s0)
-    zb3 = _fold(zb.astype(y.dtype)[:, None])
     nblk = y3.shape[1] // _SUBL
     ss_spec = _bs(1, _fixed)
-    ss_shape = jax.ShapeDtypeStruct((1, y3.shape[1], _LANES), y.dtype)
+    ss_shape = jax.ShapeDtypeStruct((1, y3.shape[1], _LANES), y3.dtype)
     if save_resid:  # e + replay trajectories for the adjoint + the SSE
         out_specs = [_bs(cs, _cur)] * 4 + [ss_spec]
-        out_shape = [jax.ShapeDtypeStruct(y3.shape, y.dtype)] * 4 + [ss_shape]
+        out_shape = [jax.ShapeDtypeStruct(y3.shape, y3.dtype)] * 4 + [ss_shape]
     else:  # per-series SSE only
         out_specs = [ss_spec]
         out_shape = [ss_shape]
     outs = pl.pallas_call(
-        functools.partial(_hw_fwd_kernel, m, mult, save_resid, t, cs),
+        functools.partial(_hw_fwd_kernel, m, mult, save_resid, f.t, cs),
         grid=(nblk, nchunk),
         in_specs=[_bs(cs, _cur), _bs(3, _fixed), _bs(1, _fixed),
                   _bs(1, _fixed), _bs(m, _fixed), _bs(1, _fixed)],
@@ -1496,46 +1524,42 @@ def _hw_fwd_call(interpret, m, mult, save_resid, params, y, l0, t0, s0, zb):
         ],
         compiler_params=_VMEM_PARAMS,
         interpret=interpret,
-    )(y3, par3, l03, t03, s03, zb3)
-    return outs, (y3, par3, l03, t03, zb3, b, t)
+    )(y3, par3, f.l03, f.t03, f.s03, f.zb3)
+    return outs, par3
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1, 2))
-def _hw_ss(interpret: bool, m: int, mult: bool, params, y, l0, t0, s0, zb):
-    """Per-series one-step-ahead SSE ``[B]``.
+def _hw_ss_f(interpret: bool, m: int, mult: bool, params, f: HWFolded):
+    """Per-series one-step-ahead SSE ``[B]`` from the FOLDED layout (the
+    true unpadded sizes are ``params.shape[0]`` and ``f.t``).
 
     Primal (no-gradient) path: sum-only kernel — a linesearch objective
     evaluation pays one panel read and no error/trajectory stores.  The vjp
-    path saves the replay trajectories and reuses the hand-derived adjoint.
+    path saves the errors and replay trajectories, all folded, and reuses
+    the hand-derived adjoint.  The unfolded API (:func:`hw_sse_seeded`) is
+    a thin fold-then-delegate wrapper: ONE forward call, ONE adjoint.
     """
-    (ss3,), (_, _, _, _, _, b, t) = _hw_fwd_call(
-        interpret, m, mult, False, params, y, l0, t0, s0, zb
-    )
-    return _unfold(ss3, b)[:, 0]
+    (ss3,), _ = _hw_fwd_call_f(interpret, m, mult, False, params, f)
+    return _unfold(ss3, params.shape[0])[:, 0]
 
 
-def _hw_ss_fwd(interpret, m, mult, params, y, l0, t0, s0, zb):
-    (e3, lv3, tr3, so3, ss3), (y3, par3, l03, t03, zb3, b, t) = _hw_fwd_call(
-        interpret, m, mult, True, params, y, l0, t0, s0, zb
-    )
-    e = _unfold(e3, b)[:, :t]
-    res = (y3, par3, l03, t03, zb3, lv3, tr3, so3, b, t)
+def _hw_ss_f_fwd(interpret, m, mult, params, f):
+    (e3, lv3, tr3, so3, ss3), par3 = _hw_fwd_call_f(
+        interpret, m, mult, True, params, f)
     # the value is accumulated in the same in-kernel order as the primal
-    # variant — see _css_ss_fwd: mixed accumulation orders stall rows
-    return _unfold(ss3, b)[:, 0], (res, e)
+    # variant — see _css_ss_f: mixed accumulation orders stall rows
+    return (_unfold(ss3, params.shape[0])[:, 0],
+            (f, par3, e3, lv3, tr3, so3))
 
 
-def _hw_ss_bwd(interpret, m, mult, resid, gbar):
-    res, e = resid
-    g_e = 2.0 * e * gbar[:, None]
-    return _hw_e_bwd(interpret, m, mult, res, g_e)
-
-
-def _hw_e_bwd(interpret, m, mult, res, g):
-    y3, par3, l03, t03, zb3, lv3, tr3, so3, b, t = res
-    tp = y3.shape[0]
+def _hw_ss_f_bwd(interpret, m, mult, resid, gbar):
+    f, par3, e3, lv3, tr3, so3 = resid
+    y3, l03, t03, zb3, t, b = f.y3, f.l03, f.t03, f.zb3, f.t, gbar.shape[0]
+    # the error cotangent stays IN the folded layout (see _css_ss_f_bwd):
+    # no unfold / refold panel passes per gradient; padded series carry a
+    # zero gbar, padded time a zero error
+    g3 = 2.0 * e3 * _fold(gbar[:, None].astype(e3.dtype))
     _, cs, nchunk = _time_layout(t)
-    g3 = _fold(jnp.pad(g, ((0, 0), (0, tp - t))))
     nblk = y3.shape[1] // _SUBL
     hp = nchunk > 1
     if hp:
@@ -1556,7 +1580,7 @@ def _hw_e_bwd(interpret, m, mult, res, g):
         grid=(nblk, nchunk),
         in_specs=ins,
         out_specs=_bs(3, _fixed),
-        out_shape=jax.ShapeDtypeStruct(par3.shape, g.dtype),
+        out_shape=jax.ShapeDtypeStruct(par3.shape, g3.dtype),
         scratch_shapes=[
             pltpu.VMEM((m, _SUBL, _LANES), jnp.float32),
             pltpu.VMEM((2, _SUBL, _LANES), jnp.float32),
@@ -1564,17 +1588,11 @@ def _hw_e_bwd(interpret, m, mult, res, g):
         compiler_params=_VMEM_PARAMS,
         interpret=interpret,
     )(*args)
-    return (
-        _unfold(gpar3, b),
-        jnp.zeros((b, t), g.dtype),
-        jnp.zeros((b,), g.dtype),
-        jnp.zeros((b,), g.dtype),
-        jnp.zeros((b, m), g.dtype),
-        jnp.zeros((b,), g.dtype),
-    )
+    # seeds and data are constants of the objective: zero cotangents
+    return _unfold(gpar3, b), jax.tree_util.tree_map(jnp.zeros_like, f)
 
 
-_hw_ss.defvjp(_hw_ss_fwd, _hw_ss_bwd)
+_hw_ss_f.defvjp(_hw_ss_f_fwd, _hw_ss_f_bwd)
 
 
 # ---------------------------------------------------------------------------
@@ -2102,7 +2120,7 @@ def batch_autocorr_folded(fp, num_lags: int, *, interpret: bool = False):
 
 
 def hw_seeds(y, period: int, multiplicative: bool = False, n_valid=None):
-    """Level/trend/seasonal-ring seeds for :func:`hw_sse_seeded`.
+    """Level/trend/seasonal-ring seeds for :func:`hw_prefold`.
 
     Returns ``(l0, t0, s0r, zb)``: the first-two-valid-seasons seed scheme
     shared with the scan path (``models.holtwinters._init_state`` — pallas/
@@ -2141,16 +2159,38 @@ def hw_seeds(y, period: int, multiplicative: bool = False, n_valid=None):
     return l0, t0, s0r, start.astype(y.dtype)
 
 
+def hw_prefold(y, seeds) -> HWFolded:
+    """Fold a panel and its :func:`hw_seeds` into the Holt-Winters kernel
+    layout ONCE -> the operand of :func:`hw_sse_folded`.
+
+    The fit objective runs hundreds of evaluations inside ``lax.while_loop``
+    bodies (the iteration loop and the line search in it), and XLA does not
+    hoist the [B, T] pad + layout transpose out of them: a fit folds once,
+    before its first start, and closes over the result."""
+    l0, t0, s0r, zb = seeds
+    t = y.shape[1]
+    tp, _, _ = _time_layout(t)
+    return HWFolded(
+        _fold(jnp.pad(y, ((0, 0), (0, tp - t)))),
+        _fold(l0[:, None].astype(y.dtype)),
+        _fold(t0[:, None].astype(y.dtype)),
+        _fold(s0r),
+        _fold(zb.astype(y.dtype)[:, None]),
+        t,
+    )
+
+
 @_scoped("pallas.hw_sse")
-def hw_sse_seeded(params, y, seeds, period: int,
+def hw_sse_folded(params, folded: HWFolded, period: int,
                   multiplicative: bool = False, *, interpret: bool = False):
-    """Batched Holt-Winters one-step-ahead SSE ``[B]`` on a fused kernel,
-    with precomputed :func:`hw_seeds` — the fit-loop entry point.
+    """Batched Holt-Winters one-step-ahead SSE ``[B]`` on a fused kernel
+    from a pre-folded panel (:func:`hw_prefold`) — the fit-loop entry point.
 
     Matches ``models.holtwinters.sse`` (vmapped) for additive AND
     multiplicative seasonality with a right-aligned valid span (the invalid
     prefix of ``y`` must already be zeroed — ``base.align_right``).
-    Differentiable in ``params``; the seeds are constants of the objective.
+    Differentiable in ``params``; data and seeds are constants of the
+    objective.
     """
     m = period
     if not hw_structural_ok(m):
@@ -2158,14 +2198,22 @@ def hw_sse_seeded(params, y, seeds, period: int,
             f"fused Holt-Winters kernel supports period <= {_CHUNK_T} "
             f"(got {m}); use backend='scan'"
         )
-    l0, t0, s0r, zb = seeds
-    return _hw_ss(interpret, m, multiplicative, params, y, l0, t0, s0r, zb)
+    return _hw_ss_f(interpret, m, multiplicative, params, folded)
+
+
+def hw_sse_seeded(params, y, seeds, period: int,
+                  multiplicative: bool = False, *, interpret: bool = False):
+    """:func:`hw_sse_folded` on a natural-layout panel: folds per call.
+    Inside an optimizer loop use :func:`hw_prefold` + :func:`hw_sse_folded`."""
+    return hw_sse_folded(params, hw_prefold(y, seeds), period, multiplicative,
+                         interpret=interpret)
 
 
 def hw_sse(params, y, period: int, multiplicative: bool = False,
            n_valid=None, *, interpret: bool = False):
     """One-shot entry: compute seeds then the SSE (tests / single calls).
-    Inside an optimizer loop use :func:`hw_seeds` + :func:`hw_sse_seeded`."""
+    Inside an optimizer loop use :func:`hw_seeds`, :func:`hw_prefold` and
+    :func:`hw_sse_folded`."""
     if not hw_structural_ok(period):  # before seeds: a clear error, not a
         raise ValueError(             # dynamic_slice TypeError from the seed
             f"fused Holt-Winters kernel supports period <= {_CHUNK_T} "

@@ -159,6 +159,17 @@ def test_pallas_kernels_compile_for_v5e(monkeypatch):
     # jax_enable_x64 the Mosaic lowering of the kernels' int32 loop-index
     # convert recurses without end in jax 0.9.0
     with jax.enable_x64(False):
+        # the lazy pair the walk runs: stage 2 takes what stage 1 hands it
+        # (the folded straggler columns), so its shapes come from stage 1
+        hw_s1 = hw._fit_stage1_program(24, False, 60, TOL, "pallas", "dense",
+                                       1)
+        programs["hw additive stage1"] = (hw_s1, [arg(4096, 192)])
+        programs["hw additive stage2"] = (
+            hw._fit_stage2_program(24, False, 60, TOL, "pallas"),
+            [jax.tree_util.tree_map(
+                lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                               sharding=sharding),
+                jax.eval_shape(hw_s1, arg(4096, 192))[1]["starts"][0])])
         for name, (program, args) in programs.items():
             try:
                 program.lower(*args).compile()

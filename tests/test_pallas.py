@@ -574,6 +574,171 @@ def test_hw_ragged_sse_and_grad_matches_scan(mult):
     np.testing.assert_allclose(np.asarray(g_got), np.asarray(g_ref), rtol=1e-3, atol=1e-2)
 
 
+@pytest.mark.parametrize("mult,ragged,t", [
+    (False, False, 80), (False, True, 80), (True, False, 80),
+    (True, True, 80),
+    (False, True, 1100),  # two time chunks: the adjoint's ``hp`` path
+])
+def test_hw_sse_folded_matches_unfolded(mult, ragged, t):
+    # the pre-folded objective (hw_prefold + hw_sse_folded) is the fit hot
+    # path; it must agree with the fold-per-call API bit-for-bit, and its
+    # straggler gather (folded COLUMNS) with a row gather of the panel
+    b, m = 5, 6
+    y = _seasonal_panel(b, t, m, seed=51) + (25.0 if mult else 0.0)
+    nv = None
+    if ragged:
+        nv = jnp.asarray([t, t - 11, t - 29, t - 3, t - 1], jnp.int32)
+        y = jnp.where(jnp.arange(t)[None, :] >= (t - nv)[:, None], y, 0.0)
+    rng = np.random.default_rng(52)
+    params = jnp.asarray(rng.uniform(0.05, 0.9, (b, 3)).astype(np.float32))
+    seeds = pk.hw_seeds(y, m, mult, nv)
+    folded = pk.hw_prefold(y, seeds)
+    ref = pk.hw_sse_seeded(params, y, seeds, m, mult, interpret=True)
+    got = pk.hw_sse_folded(params, folded, m, mult, interpret=True)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(ref))
+    g_ref = jax.grad(lambda P: jnp.sum(
+        pk.hw_sse_seeded(P, y, seeds, m, mult, interpret=True)))(params)
+    g_got = jax.grad(lambda P: jnp.sum(
+        pk.hw_sse_folded(P, folded, m, mult, interpret=True)))(params)
+    np.testing.assert_allclose(np.asarray(g_got), np.asarray(g_ref),
+                               rtol=1e-6, atol=1e-6)
+    idx = jnp.asarray(rng.integers(0, b, 1024))
+    ref_s = pk.hw_sse_seeded(params[idx], y[idx],
+                             tuple(x[idx] for x in seeds), m, mult,
+                             interpret=True)
+    got_s = pk.hw_sse_folded(params[idx], folded.take(idx), m, mult,
+                             interpret=True)
+    np.testing.assert_array_equal(np.asarray(got_s), np.asarray(ref_s))
+
+
+def _panel_relayouts_in_loops(jaxpr, n_panel, in_loop=False):
+    """``(primitive, operand shape)`` of every ``transpose`` / ``pad`` of an
+    operand with at least ``n_panel`` elements inside a ``while`` of
+    ``jaxpr`` (kernel bodies aside: a ``pallas_call`` works on blocks)."""
+    found = []
+    for eqn in jaxpr.eqns:
+        if (in_loop and eqn.primitive.name in ("transpose", "pad")
+                and eqn.invars[0].aval.size >= n_panel):
+            found.append((eqn.primitive.name, eqn.invars[0].aval.shape))
+        if eqn.primitive.name == "pallas_call":
+            continue
+        inner = in_loop or eqn.primitive.name == "while"
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            found += _panel_relayouts_in_loops(sub, n_panel, inner)
+    return found
+
+
+@pytest.mark.parametrize("align_mode", ["dense", "general"])
+@pytest.mark.parametrize("model_type", ["additive", "multiplicative"])
+def test_hw_fit_programs_fold_outside_their_loops(monkeypatch, align_mode,
+                                                  model_type):
+    # the CPU's stand-in for "``copy`` left the optimizer's loops" (PERF.md
+    # S6, PR 26): the panel is folded once per fit program, so no while
+    # body of stage 1, stage 2 or the inline program (with its straggler
+    # compaction) relayouts a panel-sized operand
+    from spark_timeseries_tpu.models import holtwinters as hw
+
+    monkeypatch.setattr(hw, "_COMPACT_MIN_BATCH", 2048)
+    b, t, m = 2048, 48, 6
+    mult = model_type == "multiplicative"
+    n_starts = 3 if mult else 1
+    y = jax.ShapeDtypeStruct((b, t), jnp.float32)
+    static = (m, mult, 13, 1e-4, "pallas-interpret")
+    stage1 = hw._fit_stage1_program.__wrapped__(*static, align_mode, n_starts)
+    inline = hw._fit_program.__wrapped__(*static, align_mode, False, True,
+                                         n_starts)
+    stage2 = hw._fit_stage2_program.__wrapped__(*static)
+    aux = jax.eval_shape(stage1, y)[1]["starts"][0]
+    cap = optim.compaction_cap(b)
+    assert aux["folded_s"].y3.shape == (t, cap // 128, 128)
+    for fn, arg, n_panel in ((stage1, y, b * t), (inline, y, b * t),
+                             (stage2, aux, cap * t)):
+        jaxpr = jax.make_jaxpr(fn)(arg).jaxpr
+        assert any(e.primitive.name == "while" for e in jaxpr.eqns)
+        assert _panel_relayouts_in_loops(jaxpr, n_panel) == []
+    # the detector sees what it is for: the fold-per-call API in a loop
+    f32 = jnp.float32
+    seeds = pk.hw_seeds(jnp.ones((b, t), f32), m, mult, None)
+    per_call = jax.make_jaxpr(lambda yv: jax.lax.while_loop(
+        lambda acc: acc[0] < 1.0, lambda acc: acc + pk.hw_sse_seeded(
+            jnp.full((b, 3), 0.5, f32), yv, seeds, m, mult, interpret=True),
+        jnp.zeros((b,), f32)))(y).jaxpr
+    assert ("transpose", (b, t)) in _panel_relayouts_in_loops(per_call, b * t)
+
+
+def _hw_pin_fit(path, model_type, backend="pallas-interpret"):
+    """One fit of the fit-level pin: ``inline`` (24 rows, under the
+    compaction gate), ``ragged`` (the same with NaN heads and a NaN tail:
+    ``align_mode="general"``) or ``lazy`` (2048 rows through stage 1 /
+    stage 2; the caller lowers the gate)."""
+    from spark_timeseries_tpu.models import holtwinters as hw
+
+    b, t, m = (2048, 48, 8) if path == "lazy" else (24, 72, 6)
+    rng = np.random.default_rng(7)
+    tt = np.arange(t, dtype=np.float32)
+    amp = rng.uniform(0.5, 3.0, size=(b, 1))
+    y = (10.0 + 0.05 * tt[None, :] + amp * np.sin(2 * np.pi * tt[None, :] / m)
+         + rng.uniform(0.05, 0.6, size=(b, 1)) * rng.normal(size=(b, t)))
+    y = (y + (25.0 if model_type == "multiplicative" else 0.0)).astype(
+        np.float32)
+    if path == "ragged":
+        y[1, :13] = np.nan
+        y[3, -9:] = np.nan
+        y[5, :3] = np.nan
+    return hw.fit(jnp.asarray(y), m, model_type, backend=backend)
+
+
+def _hw_pin_digest(r):
+    import hashlib
+
+    sha = lambda a: hashlib.sha256(  # noqa: E731
+        np.ascontiguousarray(np.asarray(a)).tobytes()).hexdigest()[:16]
+    return (sha(r.params), sha(r.neg_log_likelihood),
+            int(np.sum(np.asarray(r.converged))),
+            int(np.sum(np.asarray(r.iters))))
+
+
+# recorded on the PARENT of PR 26 (commit 31c2558: the objective folded the
+# panel on every call), f32 under this suite's jax_enable_x64, XLA:CPU of
+# this container
+_HW_PIN = {  # params sha, objective sha, rows converged, sum of iters
+    "inline-additive": ("22442193b2ba36d9", "3aacc24fed3e51c3", 24, 179),
+    "inline-multiplicative": ("b7fb28aff75e13cd", "9df77601ac081e4e", 24, 177),
+    "ragged-additive": ("9227f7f8898068d1", "70b6f731343fb122", 24, 179),
+    "ragged-multiplicative": ("425b60129f439f96", "2aaefd5267317d00", 24, 180),
+    "lazy-additive": ("be22edd45bafdb3b", "2c89d60dc07122c1", 2015, 22905),
+    "lazy-multiplicative": ("21be488db7502e0b", "0e15cce7da45c373", 2048, 19054),
+}
+# the scan backend's digest of inline-additive there: no Pallas code in it,
+# so it tells the recording's code generator from another
+_HW_PIN_HOST = ("f1b7f4abc1c47fbd", "656b298ad31a845d", 24, 178)
+
+
+@pytest.mark.parametrize("path", ["inline", "ragged", "lazy"])
+@pytest.mark.parametrize("model_type", ["additive", "multiplicative"])
+def test_hw_fit_pinned_to_the_fold_per_call_parent(monkeypatch, path,
+                                                   model_type):
+    # PR 26 moved the fold out of the optimizer's loops; the kernels, their
+    # operands and the adjoint's product are the same, so a fit takes the
+    # same path through the optimizer: params and objective bit-equal to
+    # the parent's, row for row the same iterations.  (The lazy panel is
+    # one where XLA:CPU compiles the interpreted kernel alike for both
+    # placements of the fold: on 3 of 4 other additive panels tried, f
+    # after the first iteration differed in its last bit at the SAME x,
+    # and tracing the fold back into the loop reproduced the parent's bits
+    # with the new adjoint -- the CPU compiler's contraction choice, which
+    # a Mosaic kernel on the chip does not share.)
+    from spark_timeseries_tpu.models import holtwinters as hw
+
+    host = _hw_pin_digest(_hw_pin_fit("inline", "additive", "scan"))
+    if host != _HW_PIN_HOST:
+        pytest.skip("another XLA:CPU code generator than the recording's")
+    if path == "lazy":
+        monkeypatch.setattr(hw, "_COMPACT_MIN_BATCH", 2048)
+    assert _hw_pin_digest(_hw_pin_fit(path, model_type)) == _HW_PIN[
+        f"{path}-{model_type}"]
+
+
 @pytest.mark.slow  # tier-1 budget: the big grid runs in ci.sh's unfiltered pass
 def test_hw_fit_multiplicative_and_ragged_pallas_matches_scan():
     from spark_timeseries_tpu.models import holtwinters as hw
