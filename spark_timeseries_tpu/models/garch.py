@@ -22,6 +22,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from .. import obs
 from ..utils import optim
 from .base import (FitResult, align_right, debatch,
                    debatch_fit, derive_status,
@@ -169,14 +170,35 @@ def fit(r, *, max_iters: int = 80, tol: Optional[float] = None,
             and bsz >= _COMPACT_MIN_BATCH
             and optim.compaction_cap(bsz) < bsz)
     if lazy:
-        out, aux = _fit_stage1_program(
-            max_iters, float(tol), backend, align_mode)(rb)
-        if int(aux["carry"].undone) > 0 and int(aux["carry"].k) < max_iters:
-            out = _fit_stage2_program(max_iters, float(tol), backend)(aux)
+        out = _run_lazy_stages(
+            _fit_stage1_program(max_iters, float(tol), backend, align_mode),
+            lambda: _fit_stage2_program(max_iters, float(tol), backend),
+            rb, max_iters)
         return debatch_fit(out, single, False)
     out = _fit_program(max_iters, float(tol), backend, align_mode,
                        count_evals, compact)(rb)
     return debatch_fit(out, single, count_evals)
+
+
+def _run_lazy_stages(run1, stage2_program, xb, max_iters: int):
+    """The lazy path's dispatch, host gate and stage-2 dispatch, shared by
+    :func:`fit` and :func:`fit_argarch`, under the spans of
+    ``models.arima.fit``: ``fit.stage1`` is the dispatch of stage 1 and the
+    host's wait for it at the gate, ``fit.stage2`` opens only when the gate
+    dispatches.  ``stage2_program()`` looks the stage-2 program up, and is
+    called only then."""
+    bsz = xb.shape[0]
+    with obs.span("fit.stage1", rows=bsz) as stage1:
+        out, aux = run1(xb)
+        # host gate: tiny scalar sync; stage 2 shares stage 1's iteration
+        # budget, so an exhausted budget skips the dispatch entirely
+        undone, iters = int(aux["carry"].undone), int(aux["carry"].k)
+        if obs.enabled():
+            stage1.set(iters=iters, undone=undone)
+    if undone > 0 and iters < max_iters:
+        with obs.span("fit.stage2", rows=optim.compaction_cap(bsz)):
+            out = stage2_program()(aux)
+    return out
 
 
 def _garch_prep(rb, align_mode: str):
@@ -473,11 +495,12 @@ def fit_argarch(y, *, max_iters: int = 100, tol: Optional[float] = None,
             and bsz >= _COMPACT_MIN_BATCH
             and optim.compaction_cap(bsz) < bsz)
     if lazy:
-        out, aux = _fit_argarch_stage1_program(
-            max_iters, float(tol), backend, align_mode)(yb)
-        if int(aux["carry"].undone) > 0 and int(aux["carry"].k) < max_iters:
-            out = _fit_argarch_stage2_program(
-                max_iters, float(tol), backend)(aux)
+        out = _run_lazy_stages(
+            _fit_argarch_stage1_program(max_iters, float(tol), backend,
+                                        align_mode),
+            lambda: _fit_argarch_stage2_program(max_iters, float(tol),
+                                                backend),
+            yb, max_iters)
         return debatch(out, single)
     return debatch(
         _fit_argarch_program(max_iters, float(tol), backend, compact,
