@@ -219,6 +219,19 @@ def _garch_prep(rb, align_mode: str):
     return ra, nv, u0, n_eff
 
 
+def _garch_fb(folded, n_eff, interp):
+    """The fused GARCH objective over a pre-folded panel
+    (``pallas_kernels.garch_prefold``), in transformed space — shared by
+    the inline program, its straggler subset, and both lazy stages."""
+    from ..ops import pallas_kernels as pk
+
+    def fb(u):
+        nat = jax.vmap(_to_natural)(u)
+        return pk.garch_neg_loglik_folded(nat, folded, interpret=interp) / n_eff
+
+    return fb
+
+
 @jit_program
 def _fit_program(max_iters, tol, backend, align_mode="general",
                  count_evals=False, compact=True):
@@ -228,28 +241,20 @@ def _fit_program(max_iters, tol, backend, align_mode="general",
             from ..ops import pallas_kernels as pk
 
             interp = backend == "pallas-interpret"
+            # folded ONCE, before the optimizer: XLA does not hoist the
+            # re-tiling of the folded panel out of the while loops
+            folded = pk.garch_prefold(ra, nv)
+            fb = _garch_fb(folded, n_eff, interp)
 
-            def fb(u):
-                nat = jax.vmap(_to_natural)(u)
-                return pk.garch_neg_loglik(nat, ra, nv, interpret=interp) / n_eff
-
-            # straggler compaction (utils.optim): the objective closes over
-            # the NATURAL-layout panel (the kernel folds internally), so the
-            # subset gather is a plain row gather
+            # straggler compaction (utils.optim): the subset is a gather of
+            # folded COLUMNS (series ride the lanes), grid-aligned by the cap
             bsz = ra.shape[0]
             cap = optim.compaction_cap(bsz)
             straggler_fun = None
             if compact and bsz >= _COMPACT_MIN_BATCH:
 
                 def straggler_fun(idxc):
-                    ras, nvs, nes = ra[idxc], nv[idxc], n_eff[idxc]
-
-                    def fb_s(u):
-                        nat = jax.vmap(_to_natural)(u)
-                        return pk.garch_neg_loglik(
-                            nat, ras, nvs, interpret=interp) / nes
-
-                    return fb_s
+                    return _garch_fb(folded.take(idxc), n_eff[idxc], interp)
 
             res = optim.minimize_lbfgs_batched(
                 fb, u0, max_iters=max_iters, tol=tol, count_evals=count_evals,
@@ -298,25 +303,21 @@ def _fit_stage1_program(max_iters, tol, backend, align_mode="general"):
     gather, stage 2 compiled only when needed.  Pallas backends only."""
 
     def run(rb):
-        ra, nv, u0, n_eff = _garch_prep(rb, align_mode)
         from ..ops import pallas_kernels as pk
 
-        interp = backend == "pallas-interpret"
-
-        def fb(u):
-            nat = jax.vmap(_to_natural)(u)
-            return pk.garch_neg_loglik(nat, ra, nv, interpret=interp) / n_eff
-
+        ra, nv, u0, n_eff = _garch_prep(rb, align_mode)
+        folded = pk.garch_prefold(ra, nv)  # once: see _fit_program
         cap = optim.compaction_cap(ra.shape[0])
         res1, carry = optim.lbfgs_batched_stage1(
-            fb, u0, straggler_cap=cap, max_iters=max_iters, tol=tol)
+            _garch_fb(folded, n_eff, backend == "pallas-interpret"), u0,
+            straggler_cap=cap, max_iters=max_iters, tol=tol)
         ok = nv >= 10
-        # the objective closes over the NATURAL-layout panel, so the
-        # compacted problem's data is a plain row gather, done here so the
-        # stage-2 program is a pure function of its inputs
-        aux = {"carry": carry, "res": res1, "ras": ra[carry.idxc],
-               "nvs": nv[carry.idxc], "nes": n_eff[carry.idxc],
-               "ok": ok, "n_eff": n_eff}
+        # the compacted problem's data is gathered HERE (folded columns: no
+        # re-fold), so the stage-2 program is a pure function of its inputs
+        # and folds nothing
+        aux = {"carry": carry, "res": res1,
+               "folded_s": folded.take(carry.idxc),
+               "nes": n_eff[carry.idxc], "ok": ok, "n_eff": n_eff}
         return _finalize_garch_fit(res1, ok, n_eff), aux
 
     return run
@@ -329,15 +330,9 @@ def _fit_stage2_program(max_iters, tol, backend):
     interp = backend == "pallas-interpret"
 
     def run(aux):
-        from ..ops import pallas_kernels as pk
-
-        def fb_s(u):
-            nat = jax.vmap(_to_natural)(u)
-            return pk.garch_neg_loglik(
-                nat, aux["ras"], aux["nvs"], interpret=interp) / aux["nes"]
-
         res = optim.lbfgs_batched_stage2(
-            fb_s, aux["res"], aux["carry"], max_iters=max_iters, tol=tol)
+            _garch_fb(aux["folded_s"], aux["nes"], interp), aux["res"],
+            aux["carry"], max_iters=max_iters, tol=tol)
         return _finalize_garch_fit(res, aux["ok"], aux["n_eff"])
 
     return run

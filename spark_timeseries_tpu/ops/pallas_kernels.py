@@ -62,7 +62,8 @@ from typing import Tuple
 import jax
 import jax.numpy as jnp
 from jax import lax
-from jax.custom_derivatives import SymbolicZero
+from jax.custom_derivatives import (SymbolicZero,
+                                    custom_vjp_primal_tree_values)
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -174,6 +175,17 @@ def _unfold(x3d, b: int):
     """Inverse of :func:`_fold`: ``[n, Bp/128, 128] -> [B, n]``."""
     n = x3d.shape[0]
     return x3d.reshape(n, -1).T[:b]
+
+
+def _take_series(folded, idxc):
+    """The series ``idxc`` (a multiple of 1024 of them) of every leaf of a
+    folded pytree: series ride the lanes, so a row gather of the natural
+    layout is a column gather here and nothing is re-folded (the straggler
+    compaction, as ``models.arima`` repacks ``y3``)."""
+    nb = idxc.shape[0] // _LANES
+    return jax.tree_util.tree_map(
+        lambda x3: x3.reshape(x3.shape[0], -1)[:, idxc].reshape(
+            x3.shape[0], nb, _LANES), folded)
 
 
 def _bs(n0: int, imap):
@@ -705,7 +717,18 @@ def css_neg_loglik(params, yd, order: Order, include_intercept: bool,
 #   dL/dh0     = lam_zb * (alpha + beta) + sum_{dead t} gbar_t
 # Cotangents flow to r^2 and h0 as well as the parameters so callers that
 # build the returns from model parameters (ARGARCH's AR(1) mean) get exact
-# gradients; ``zb`` is a constant of the objective.
+# gradients; ``zb`` is a constant of the objective.  The two data cotangents
+# cost a [B, T] write, so the adjoint emits them only when the data is
+# perturbed (symbolic_zeros on the likelihood's custom_vjp, as EWMA's ``x``
+# below): ``garch.fit`` differentiates in the parameters alone and never
+# pays them.
+#
+# ONE forward call and ONE adjoint call, both on FOLDED operands
+# (:class:`GarchFolded`).  A fit folds its panel once, before the optimizer
+# (:func:`garch_prefold`), and evaluates :func:`garch_neg_loglik_folded`;
+# the natural-layout entries (:func:`garch_neg_loglik`,
+# :func:`garch_variances`) fold per call and delegate, so JAX differentiates
+# through the fold for callers whose returns depend on the iterate.
 
 
 def _garch_fwd_kernel(t_limit, cs, hp, mode, *refs):
@@ -761,14 +784,16 @@ def _garch_fwd_kernel(t_limit, cs, hp, mode, *refs):
         ll_ref[0] = ll_ref[0] + acc
 
 
-def _garch_bwd_kernel(t_limit, cs, nchunk, hpv, *refs):
-    if hpv:
-        (r2_ref, r2p_ref, par_ref, h0_ref, zb_ref, h_ref, hp_ref,
-         g_ref, gpar_ref, gr2_ref, gh0_ref, cl_ref) = refs
-    else:
-        (r2_ref, par_ref, h0_ref, zb_ref, h_ref,
-         g_ref, gpar_ref, gr2_ref, gh0_ref, cl_ref) = refs
-        r2p_ref = hp_ref = None
+def _garch_bwd_kernel(t_limit, cs, nchunk, hpv, want_gdata, *refs):
+    # ``want_gdata``: also emit the cotangents of r^2 (panel-sized) and h0
+    refs = list(refs)
+    r2_ref = refs.pop(0)
+    r2p_ref = refs.pop(0) if hpv else None
+    par_ref, h0_ref, zb_ref, h_ref = (refs.pop(0) for _ in range(4))
+    hp_ref = refs.pop(0) if hpv else None
+    g_ref, gpar_ref = refs.pop(0), refs.pop(0)
+    gr2_ref, gh0_ref = (refs.pop(0), refs.pop(0)) if want_gdata else (None, None)
+    cl_ref = refs.pop(0)
     c = pl.program_id(1)
     base = (nchunk - 1 - c) * cs
     zb = zb_ref[0]
@@ -781,21 +806,21 @@ def _garch_bwd_kernel(t_limit, cs, nchunk, hpv, *refs):
         cl_ref[0] = _ZERO()
         for r in range(3):
             gpar_ref[r] = _ZERO()
-        gh0_ref[0] = _ZERO()
+        if want_gdata:
+            gh0_ref[0] = _ZERO()
 
     def body(i, carry):
-        lam_next, dw, da, db, dh0 = carry
+        lam_next, dw, da, db = carry[:4]
         tl = cs - 1 - i
         t = base + tl
         tf = t.astype(jnp.float32)
         live = (tf >= zb) & (t < t_limit)
-        # r2_t feeds h_{t+1} unless t+1 is the seed (which uses h0 instead)
-        next_live = (tf + 1.0 > zb) & (t + 1 < t_limit)
-        gr2_ref[tl] = jnp.where(next_live, alpha * lam_next, 0.0)
+        if want_gdata:
+            # r2_t feeds h_{t+1} unless t+1 is the seed (which uses h0)
+            next_live = (tf + 1.0 > zb) & (t + 1 < t_limit)
+            gr2_ref[tl] = jnp.where(next_live, alpha * lam_next, 0.0)
         lam = g_ref[tl] + beta * lam_next
         lam = jnp.where(live, lam, 0.0)
-        # dead positions emit h0 directly
-        dh0 = dh0 + jnp.where(live, 0.0, g_ref[tl])
         seed = tf == zb
         hfar = hp_ref[cs - 1] if hpv else 0.0
         hprev = jnp.where(tl - 1 >= 0, h_ref[jnp.maximum(tl - 1, 0)], hfar)
@@ -807,42 +832,68 @@ def _garch_bwd_kernel(t_limit, cs, nchunk, hpv, *refs):
         dw = dw + lam
         da = da + lam * r2p_eff
         db = db + lam * hprev
+        if not want_gdata:
+            return lam, dw, da, db
+        # dead positions emit h0 directly
+        dh0 = carry[4] + jnp.where(live, 0.0, g_ref[tl])
         # h0 enters the seed step through BOTH recursion inputs
         hp_is_h0 = tf - 1.0 < zb
         dh0 = dh0 + jnp.where(live & seed, alpha * lam, 0.0)
         dh0 = dh0 + jnp.where(live & hp_is_h0, beta * lam, 0.0)
         return lam, dw, da, db, dh0
 
-    lam, dw, da, db, dh0 = lax.fori_loop(
-        0, cs, body, (cl_ref[0], _ZERO(), _ZERO(), _ZERO(), _ZERO())
+    out = lax.fori_loop(
+        0, cs, body, (cl_ref[0],) + (_ZERO(),) * (4 if want_gdata else 3)
     )
-    cl_ref[0] = lam
-    gpar_ref[0] = gpar_ref[0] + dw
-    gpar_ref[1] = gpar_ref[1] + da
-    gpar_ref[2] = gpar_ref[2] + db
-    gh0_ref[0] = gh0_ref[0] + dh0
+    cl_ref[0] = out[0]
+    for r in range(3):
+        gpar_ref[r] = gpar_ref[r] + out[1 + r]
+    if want_gdata:
+        gh0_ref[0] = gh0_ref[0] + out[4]
 
 
-def _garch_fwd_call(interpret, mode, params, r2, h0, zb):
-    b, t = r2.shape
-    tp, cs, nchunk = _time_layout(t)
-    r23 = _fold(jnp.pad(r2, ((0, 0), (0, tp - t))))
+@functools.partial(
+    jax.tree_util.register_dataclass,
+    data_fields=["r23", "h03", "zb3"], meta_fields=["t"])
+@dataclasses.dataclass(frozen=True)
+class GarchFolded:
+    """A returns panel in the GARCH kernel layout (:func:`garch_prefold`):
+    the squared, masked returns ``r23 [Tp, Bp/128, 128]`` zero-padded by
+    :func:`_time_layout`, the start variance ``h03`` and the first live
+    position ``zb3`` as ``[1, Bp/128, 128]`` planes; ``t`` is the true
+    series length (static: it rides the treedef through a ``jit``
+    boundary)."""
+
+    r23: jax.Array
+    h03: jax.Array
+    zb3: jax.Array
+    t: int
+
+    def take(self, idxc):
+        """The series ``idxc`` (a multiple of 1024 of them) as folded
+        COLUMNS: the straggler compaction re-folds nothing."""
+        return _take_series(self, idxc)
+
+
+def _garch_fwd_call_f(interpret, mode, params, f: GarchFolded):
+    # pre-FOLDED entry (see _css_fwd_call_f): only the [B, 3] parameters are
+    # folded per call; the panel and its seeds arrive in kernel layout
+    _, cs, nchunk = _time_layout(f.t)
+    r23 = f.r23
     par3 = _fold(params)
-    h03 = _fold(h0[:, None].astype(r2.dtype))
-    zb3 = _fold(zb.astype(r2.dtype)[:, None])
     nblk = r23.shape[1] // _SUBL
     hp = nchunk > 1
     out_specs, out_shape = [], []
     if mode != "sum":
         out_specs.append(_bs(cs, _cur))
-        out_shape.append(jax.ShapeDtypeStruct(r23.shape, r2.dtype))
+        out_shape.append(jax.ShapeDtypeStruct(r23.shape, r23.dtype))
     if mode != "e":
         out_specs.append(_bs(1, _fixed))
         out_shape.append(
-            jax.ShapeDtypeStruct((1, r23.shape[1], _LANES), r2.dtype)
+            jax.ShapeDtypeStruct((1, r23.shape[1], _LANES), r23.dtype)
         )
     outs = pl.pallas_call(
-        functools.partial(_garch_fwd_kernel, t, cs, hp, mode),
+        functools.partial(_garch_fwd_kernel, f.t, cs, hp, mode),
         grid=(nblk, nchunk),
         in_specs=([_bs(cs, _cur)] + ([_bs(cs, _prev)] if hp else [])
                   + [_bs(3, _fixed), _bs(1, _fixed), _bs(1, _fixed)]),
@@ -851,8 +902,45 @@ def _garch_fwd_call(interpret, mode, params, r2, h0, zb):
         scratch_shapes=[pltpu.VMEM((1, _SUBL, _LANES), jnp.float32)],
         compiler_params=_VMEM_PARAMS,
         interpret=interpret,
-    )(*((r23, r23) if hp else (r23,)), par3, h03, zb3)
-    return outs, (r23, par3, h03, zb3)
+    )(*((r23, r23) if hp else (r23,)), par3, f.h03, f.zb3)
+    return outs, par3
+
+
+def _garch_bwd_call_f(interpret, f: GarchFolded, par3, h3, g3, want_gdata):
+    """The adjoint on FOLDED operands: ``g3`` is the cotangent of the
+    variance path ``h3`` -> ``(gpar3, gr23, gh03)``, the two data
+    cotangents ``None`` unless ``want_gdata`` (two more kernel outputs, one
+    of them panel-sized)."""
+    _, cs, nchunk = _time_layout(f.t)
+    nblk = f.r23.shape[1] // _SUBL
+    hp = nchunk > 1
+    if hp:
+        ins = [_bs(cs, _rev(nchunk)), _bs(cs, _rev_prev(nchunk)),
+               _bs(3, _fixed), _bs(1, _fixed), _bs(1, _fixed),
+               _bs(cs, _rev(nchunk)), _bs(cs, _rev_prev(nchunk)),
+               _bs(cs, _rev(nchunk))]
+        args = (f.r23, f.r23, par3, f.h03, f.zb3, h3, h3, g3)
+    else:
+        ins = [_bs(cs, _rev(nchunk)), _bs(3, _fixed), _bs(1, _fixed),
+               _bs(1, _fixed), _bs(cs, _rev(nchunk)), _bs(cs, _rev(nchunk))]
+        args = (f.r23, par3, f.h03, f.zb3, h3, g3)
+    out_specs = [_bs(3, _fixed)]
+    out_shape = [jax.ShapeDtypeStruct(par3.shape, g3.dtype)]
+    if want_gdata:
+        out_specs += [_bs(cs, _rev(nchunk)), _bs(1, _fixed)]
+        out_shape += [jax.ShapeDtypeStruct(f.r23.shape, g3.dtype),
+                      jax.ShapeDtypeStruct(f.h03.shape, g3.dtype)]
+    outs = pl.pallas_call(
+        functools.partial(_garch_bwd_kernel, f.t, cs, nchunk, hp, want_gdata),
+        grid=(nblk, nchunk),
+        in_specs=ins,
+        out_specs=out_specs,
+        out_shape=out_shape,
+        scratch_shapes=[pltpu.VMEM((1, _SUBL, _LANES), jnp.float32)],
+        compiler_params=_VMEM_PARAMS,
+        interpret=interpret,
+    )(*args)
+    return tuple(outs) if want_gdata else (outs[0], None, None)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
@@ -863,43 +951,22 @@ def _garch_h(interpret: bool, params, r2, h0, zb):
 
 def _garch_h_fwd(interpret, params, r2, h0, zb):
     b, t = r2.shape
-    (h3,), (r23, par3, h03, zb3) = _garch_fwd_call(
-        interpret, "e", params, r2, h0, zb
+    tp, _, _ = _time_layout(t)
+    f = GarchFolded(
+        _fold(jnp.pad(r2, ((0, 0), (0, tp - t)))),
+        _fold(h0[:, None].astype(r2.dtype)),
+        _fold(zb.astype(r2.dtype)[:, None]),
+        t,
     )
-    return _unfold(h3, b)[:, :t], (r23, par3, h03, zb3, h3, b, t)
+    (h3,), par3 = _garch_fwd_call_f(interpret, "e", params, f)
+    return _unfold(h3, b)[:, :t], (f, par3, h3)
 
 
 def _garch_h_bwd(interpret, res, g):
-    r23, par3, h03, zb3, h3, b, t = res
-    tp = r23.shape[0]
-    _, cs, nchunk = _time_layout(t)
-    g3 = _fold(jnp.pad(g, ((0, 0), (0, tp - t))))
-    nblk = r23.shape[1] // _SUBL
-    hp = nchunk > 1
-    if hp:
-        ins = [_bs(cs, _rev(nchunk)), _bs(cs, _rev_prev(nchunk)),
-               _bs(3, _fixed), _bs(1, _fixed), _bs(1, _fixed),
-               _bs(cs, _rev(nchunk)), _bs(cs, _rev_prev(nchunk)),
-               _bs(cs, _rev(nchunk))]
-        args = (r23, r23, par3, h03, zb3, h3, h3, g3)
-    else:
-        ins = [_bs(cs, _rev(nchunk)), _bs(3, _fixed), _bs(1, _fixed),
-               _bs(1, _fixed), _bs(cs, _rev(nchunk)), _bs(cs, _rev(nchunk))]
-        args = (r23, par3, h03, zb3, h3, g3)
-    gpar3, gr23, gh03 = pl.pallas_call(
-        functools.partial(_garch_bwd_kernel, t, cs, nchunk, hp),
-        grid=(nblk, nchunk),
-        in_specs=ins,
-        out_specs=[_bs(3, _fixed), _bs(cs, _rev(nchunk)), _bs(1, _fixed)],
-        out_shape=[
-            jax.ShapeDtypeStruct(par3.shape, g.dtype),
-            jax.ShapeDtypeStruct(r23.shape, g.dtype),
-            jax.ShapeDtypeStruct(h03.shape, g.dtype),
-        ],
-        scratch_shapes=[pltpu.VMEM((1, _SUBL, _LANES), jnp.float32)],
-        compiler_params=_VMEM_PARAMS,
-        interpret=interpret,
-    )(*args)
+    f, par3, h3 = res
+    b, t = g.shape
+    g3 = _fold(jnp.pad(g, ((0, 0), (0, h3.shape[0] - t))))
+    gpar3, gr23, gh03 = _garch_bwd_call_f(interpret, f, par3, h3, g3, True)
     return (
         _unfold(gpar3, b),
         _unfold(gr23, b)[:, :t],
@@ -923,59 +990,70 @@ def garch_variances(params, r, h0, zb, *, interpret: bool = False):
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
-def _garch_ll(interpret: bool, params, rz, h0, zb):
-    """Unscaled Gaussian log-likelihood sum ``[B]`` of the GARCH recursion:
-    ``sum_t mask (log 2 pi h_t + r_t^2 / h_t)``.
+def _garch_ll_f(interpret: bool, params, f: GarchFolded):
+    """Unscaled Gaussian log-likelihood sum ``[B]`` of the GARCH recursion
+    from the FOLDED layout: ``sum_t mask (log 2 pi h_t + r_t^2 / h_t)``
+    (the true unpadded sizes are ``params.shape[0]`` and ``f.t``).
 
     Primal path: sum-only kernel (the variance path never reaches HBM);
-    vjp path saves the variances and chains the likelihood partials into
-    the hand-derived recursion adjoint, with the VALUE accumulated in the
-    identical in-kernel order (see ``_css_ss_f``).
+    vjp path saves the variances, folded, and chains the likelihood
+    partials into the hand-derived recursion adjoint, with the VALUE
+    accumulated in the identical in-kernel order (see ``_css_ss_f``).
+    Differentiable in ``params`` and in ``f.r23`` / ``f.h03``; the data
+    cotangents are computed only when the data is perturbed.
     """
-    b, t = rz.shape
-    (ll3,), _ = _garch_fwd_call(interpret, "sum", params, rz * rz, h0, zb)
-    return _unfold(ll3, b)[:, 0]
+    (ll3,), _ = _garch_fwd_call_f(interpret, "sum", params, f)
+    return _unfold(ll3, params.shape[0])[:, 0]
 
 
-def _garch_ll_fwd(interpret, params, rz, h0, zb):
-    b, t = rz.shape
-    (h3, ll3), (r23, par3, h03, zb3) = _garch_fwd_call(
-        interpret, "both", params, rz * rz, h0, zb
-    )
-    return _unfold(ll3, b)[:, 0], (r23, par3, h03, zb3, h3, rz, zb, b, t)
+def _garch_ll_f_fwd(interpret, params, f):
+    # symbolic_zeros: the leaves are CustomVJPPrimal (see _ewma_s_fwd); the
+    # marker is structural (None vs ()) so bwd branches at trace time
+    marker = () if f.r23.perturbed or f.h03.perturbed else None
+    params, f = custom_vjp_primal_tree_values((params, f))
+    (h3, ll3), par3 = _garch_fwd_call_f(interpret, "both", params, f)
+    return _unfold(ll3, params.shape[0])[:, 0], (f, par3, h3, marker)
 
 
-def _garch_ll_bwd(interpret, resid, gbar):
-    r23, par3, h03, zb3, h3, rz, zb, b, t = resid
-    h = _unfold(h3, b)[:, :t]
-    t_idx = jnp.arange(t, dtype=rz.dtype)
-    mask = t_idx[None, :] >= zb[:, None]
-    hc = jnp.maximum(h, 1e-12)
+def _garch_ll_f_bwd(interpret, resid, gbar):
+    f, par3, h3, marker = resid
+    b = gbar.shape[0]
+    zeros = jax.tree_util.tree_map(jnp.zeros_like, f)
+    if isinstance(gbar, SymbolicZero):  # output provably unused
+        return jnp.zeros((b, 3), h3.dtype), zeros
+    # the likelihood's cotangent is formed IN the folded layout (see
+    # _css_ss_f_bwd): gbar [B] folds to a plane that broadcasts over time,
+    # so a gradient pays no unfold / refold panel passes; padded series
+    # carry a zero gbar, and padded time is dead (its g would reach gh0)
+    gb3 = _fold(gbar[:, None].astype(h3.dtype))
+    t_idx = jnp.arange(h3.shape[0], dtype=h3.dtype)[:, None, None]
+    live = (t_idx >= f.zb3) & (t_idx < f.t)
+    hc3 = jnp.maximum(h3, 1e-12)
     # d ll_t / d h_t = 1/h - r^2/h^2 (zero through the eps clamp)
-    g_h = jnp.where(mask & (h >= 1e-12),
-                    gbar[:, None] * (1.0 / hc - (rz * rz) / (hc * hc)), 0.0)
-    gpar, g_r2, g_h0, _ = _garch_h_bwd(
-        interpret, (r23, par3, h03, zb3, h3, b, t), g_h
-    )
-    # r feeds the likelihood through the recursion (r^2 chain) AND directly
-    g_rz = g_r2 * 2.0 * rz + jnp.where(
-        mask, gbar[:, None] * 2.0 * rz / hc, 0.0
-    )
-    return gpar, g_rz, g_h0, jnp.zeros_like(zb)
+    g3 = jnp.where(live & (h3 >= 1e-12),
+                   gb3 * (1.0 / hc3 - f.r23 / (hc3 * hc3)), 0.0)
+    gpar3, gr23, gh03 = _garch_bwd_call_f(
+        interpret, f, par3, h3, g3, marker is not None)
+    if marker is None:  # params-only: the fit hot path
+        return _unfold(gpar3, b), zeros
+    # r^2 feeds the likelihood through the recursion AND directly
+    gr23 = gr23 + jnp.where(live, gb3 / hc3, 0.0)
+    return _unfold(gpar3, b), dataclasses.replace(zeros, r23=gr23, h03=gh03)
 
 
-_garch_ll.defvjp(_garch_ll_fwd, _garch_ll_bwd)
+_garch_ll_f.defvjp(_garch_ll_f_fwd, _garch_ll_f_bwd, symbolic_zeros=True)
 
 
-@_scoped("pallas.garch_neg_loglik")
-def garch_neg_loglik(params, r, n_valid=None, *, interpret: bool = False):
-    """Batched GARCH(1,1) Gaussian negative log-likelihood ``[B]``.
+def garch_prefold(r, n_valid=None) -> GarchFolded:
+    """Mask a returns panel, seed its variance and fold it into the GARCH
+    kernel layout ONCE -> the operand of :func:`garch_neg_loglik_folded`.
 
-    Matches ``models.garch.neg_log_likelihood`` (vmapped) to float tolerance:
-    h0 is the masked sample variance of the valid span, the prefix is dead,
-    and the likelihood sums over valid steps.  Differentiable in ``params``
-    and (through the returns/variance seed) in ``r``.
-    """
+    ``h0`` is the masked sample variance of the valid span and the prefix
+    is dead, as ``models.garch.neg_log_likelihood`` has them.  The fit
+    objective runs hundreds of evaluations inside ``lax.while_loop`` bodies
+    and XLA does not hoist the re-tiling of the folded panel out of them
+    (see :func:`hw_prefold`): a fit folds once, before the optimizer, and
+    closes over the result.  Differentiable in ``r``."""
     b, n = r.shape
     nv = (
         jnp.full((b,), n, jnp.int32)
@@ -989,7 +1067,37 @@ def garch_neg_loglik(params, r, n_valid=None, *, interpret: bool = False):
     nvf = jnp.maximum(nv, 1).astype(r.dtype)
     mean = jnp.sum(rz, axis=1) / nvf
     h0 = jnp.sum(jnp.where(mask, (rz - mean[:, None]) ** 2, 0.0), axis=1) / nvf
-    return 0.5 * _garch_ll(interpret, params, rz, h0, start)
+    tp, _, _ = _time_layout(n)
+    return GarchFolded(
+        _fold(jnp.pad(rz * rz, ((0, 0), (0, tp - n)))),
+        _fold(h0[:, None]),
+        _fold(start[:, None]),
+        n,
+    )
+
+
+@_scoped("pallas.garch_neg_loglik")
+def garch_neg_loglik_folded(params, folded: GarchFolded, *,
+                            interpret: bool = False):
+    """Batched GARCH(1,1) Gaussian negative log-likelihood ``[B]`` from a
+    pre-folded panel (:func:`garch_prefold`) — the fit-loop entry point.
+    Matches :func:`garch_neg_loglik` exactly."""
+    return 0.5 * _garch_ll_f(interpret, params, folded)
+
+
+@_scoped("pallas.garch_neg_loglik")
+def garch_neg_loglik(params, r, n_valid=None, *, interpret: bool = False):
+    """Batched GARCH(1,1) Gaussian negative log-likelihood ``[B]``.
+
+    Matches ``models.garch.neg_log_likelihood`` (vmapped) to float tolerance:
+    h0 is the masked sample variance of the valid span, the prefix is dead,
+    and the likelihood sums over valid steps.  Differentiable in ``params``
+    and (through the returns/variance seed) in ``r``.  Folds per call:
+    inside an optimizer loop whose returns are constant use
+    :func:`garch_prefold` + :func:`garch_neg_loglik_folded`.
+    """
+    return garch_neg_loglik_folded(params, garch_prefold(r, n_valid),
+                                   interpret=interpret)
 
 
 # ---------------------------------------------------------------------------
@@ -1487,13 +1595,8 @@ class HWFolded:
 
     def take(self, idxc):
         """The series ``idxc`` (a multiple of 1024 of them) as folded
-        COLUMNS — series ride the lanes, so a row gather of the natural
-        layout is a column gather here and nothing is re-folded (the
-        straggler compaction, as ``models.arima`` repacks ``y3``)."""
-        nb = idxc.shape[0] // _LANES
-        return jax.tree_util.tree_map(
-            lambda x3: x3.reshape(x3.shape[0], -1)[:, idxc].reshape(
-                x3.shape[0], nb, _LANES), self)
+        COLUMNS: the straggler compaction re-folds nothing."""
+        return _take_series(self, idxc)
 
 
 def _hw_fwd_call_f(interpret, m, mult, save_resid, params, f: HWFolded):

@@ -255,3 +255,39 @@ def test_lazy_spans_carry_the_gates_numbers(monkeypatch, tmp_path, fit,
         assert spans["fit.stage2"]["attrs"] == {
             "rows": optim.compaction_cap(LAZY_ROWS)}
         assert spans["fit.stage2"]["parent"] == primary.id
+
+
+@pytest.mark.parametrize("ragged", [False, True], ids=["dense", "ragged"])
+def test_stage1_hands_stage2_its_stragglers_folded(monkeypatch, ragged):
+    """The panel is folded once, in stage 1 (ISSUE 29): what stage 2 is
+    given is the stragglers' COLUMNS of that fold — the masked squared
+    returns, the variance seed and the first live day of exactly the rows
+    ``carry.idxc`` names — so it folds nothing, and finishing them through
+    it is the lazy fit."""
+    from spark_timeseries_tpu.ops import pallas_kernels as pk
+
+    monkeypatch.setattr(garch, "_COMPACT_MIN_BATCH", LAZY_ROWS)
+    y = np.array(panel(LAZY_ROWS, 96, seed=9))
+    mode = "dense"
+    if ragged:
+        y[5, :17] = np.nan
+        y[40, -8:] = np.nan
+        mode = "general"
+    y = jnp.asarray(y)
+    static = (80, 1e-4, "pallas-interpret")
+    _, aux = garch._fit_stage1_program(*static, mode)(y)
+    assert 0 < int(aux["carry"].undone) and int(aux["carry"].k) < 80
+    idxc = aux["carry"].idxc
+    assert idxc.shape == (optim.compaction_cap(LAZY_ROWS),)
+    aligned, n_valid = base.maybe_align(y, mode)
+    want = pk.garch_prefold(aligned[idxc], n_valid[idxc])
+    got = aux["folded_s"]
+    assert got.t == want.t == 96
+    assert np.array_equal(np.asarray(got.r23), np.asarray(want.r23))
+    assert np.array_equal(np.asarray(got.zb3), np.asarray(want.zb3))
+    np.testing.assert_allclose(np.asarray(got.h03), np.asarray(want.h03),
+                               rtol=1e-6)
+    out = garch._fit_stage2_program(*static)(aux)
+    fit = garch.fit(y, backend="pallas-interpret")
+    for a, b in zip(out, fit):
+        assert np.array_equal(np.asarray(a), np.asarray(b), equal_nan=True)

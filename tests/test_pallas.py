@@ -384,6 +384,201 @@ def test_garch_fit_backend_pallas_matches_scan():
     )
 
 
+def _garch_params(b, seed):
+    rng = np.random.default_rng(seed)
+    return jnp.asarray(np.column_stack([
+        rng.uniform(1e-5, 2e-4, b), rng.uniform(0.05, 0.2, b),
+        rng.uniform(0.5, 0.8, b)]).astype(np.float32))
+
+
+def _scan_nll(params, rz, nv):
+    from spark_timeseries_tpu.models import garch
+
+    return jax.vmap(garch.neg_log_likelihood)(params, rz, nv)
+
+
+def _scan_nll_sum(params, rz, nv):
+    return jnp.sum(_scan_nll(params, rz, nv))
+
+
+@pytest.mark.parametrize("ragged,t", [
+    (False, 80), (True, 80),
+    (True, 1100),  # two time chunks: the adjoint's ``hp`` path
+])
+def test_garch_neg_loglik_folded_matches_unfolded(ragged, t):
+    # the pre-folded objective (garch_prefold + garch_neg_loglik_folded) is
+    # the fit hot path; it must agree with the fold-per-call API bit for
+    # bit, both with the scan, and its straggler gather (folded COLUMNS)
+    # with a row gather of the panel
+    b = 5
+    r = _returns_panel(b, t, seed=61)
+    nv = jnp.full((b,), t, jnp.int32)
+    if ragged:
+        nv = jnp.asarray([t, t - 11, t - 29, t - 3, t - 1], jnp.int32)
+        r = jnp.where(jnp.arange(t)[None, :] >= (t - nv)[:, None], r, 0.0)
+    params = _garch_params(b, 62)
+    folded = pk.garch_prefold(r, nv if ragged else None)
+    assert folded.t == t and folded.r23.shape[1:] == (8, 128)
+    ref = pk.garch_neg_loglik(params, r, nv, interpret=True)
+    got = pk.garch_neg_loglik_folded(params, folded, interpret=True)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(ref))
+    np.testing.assert_allclose(np.asarray(got),
+                               np.asarray(_scan_nll(params, r, nv)), rtol=3e-5)
+    g_ref = jax.grad(lambda P: jnp.sum(
+        pk.garch_neg_loglik(P, r, nv, interpret=True)))(params)
+    g_got = jax.grad(lambda P: jnp.sum(
+        pk.garch_neg_loglik_folded(P, folded, interpret=True)))(params)
+    np.testing.assert_allclose(np.asarray(g_got), np.asarray(g_ref),
+                               rtol=1e-6, atol=1e-6)
+    g_scan = np.asarray(jax.grad(_scan_nll_sum)(params, r, nv))
+    np.testing.assert_allclose(np.asarray(g_got), g_scan, rtol=2e-3,
+                               atol=2e-3 * np.abs(g_scan).max())
+    idx = jnp.asarray(np.random.default_rng(63).integers(0, b, 1024))
+    ref_s = pk.garch_neg_loglik(params[idx], r[idx], nv[idx], interpret=True)
+    got_s = pk.garch_neg_loglik_folded(params[idx], folded.take(idx),
+                                       interpret=True)
+    np.testing.assert_array_equal(np.asarray(got_s), np.asarray(ref_s))
+
+
+def _pallas_call_outputs(jaxpr):
+    """The output shapes of every ``pallas_call`` of ``jaxpr``, in order."""
+    found = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            found.append([v.aval.shape for v in eqn.outvars])
+            continue
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            found += _pallas_call_outputs(sub)
+    return found
+
+
+@pytest.mark.parametrize("t", [80, 1100])
+def test_garch_data_cotangent_only_on_demand(t):
+    # garch.fit differentiates in the parameters alone: its adjoint kernel
+    # has ONE output and writes no panel; a caller whose returns depend on
+    # what it differentiates (ARGARCH's AR(1) mean) gets the cotangents of
+    # r^2 and h0 from the same adjoint, exact against the scan's autodiff
+    b = 4
+    r = _returns_panel(b, t, seed=71)
+    nv = jnp.asarray([t, t - 7, t - 2, t], jnp.int32)
+    rz = jnp.where(jnp.arange(t)[None, :] >= (t - nv)[:, None], r, 0.0)
+    params = _garch_params(b, 72)
+    folded = pk.garch_prefold(rz, nv)
+    panel, plane = folded.r23.shape, folded.h03.shape
+    par3 = (3,) + plane[1:]
+
+    def loss(P, f):
+        return jnp.sum(pk.garch_neg_loglik_folded(P, f, interpret=True))
+
+    calls = _pallas_call_outputs(
+        jax.make_jaxpr(jax.grad(loss))(params, folded).jaxpr)
+    assert calls == [[panel, plane], [par3]]  # forward (h3, ll3); adjoint
+    calls = _pallas_call_outputs(
+        jax.make_jaxpr(jax.grad(loss, argnums=(0, 1)))(params, folded).jaxpr)
+    assert calls == [[panel, plane], [par3, panel, plane]]
+    # the natural-layout entry differentiates through the fold
+    g_p, g_r = jax.grad(lambda P, rv: jnp.sum(pk.garch_neg_loglik(
+        P, rv, nv, interpret=True)), argnums=(0, 1))(params, rz)
+    s_p, s_r = jax.grad(_scan_nll_sum, argnums=(0, 1))(params, rz, nv)
+    live = np.asarray(jnp.arange(t)[None, :] >= (t - nv)[:, None])
+    scale = np.abs(np.asarray(s_r)).max()
+    np.testing.assert_allclose(np.where(live, np.asarray(g_r), 0.0) / scale,
+                               np.where(live, np.asarray(s_r), 0.0) / scale,
+                               atol=2e-3)
+    np.testing.assert_allclose(np.asarray(g_p), np.asarray(s_p), rtol=2e-3,
+                               atol=2e-3 * np.abs(np.asarray(s_p)).max())
+
+
+@pytest.mark.parametrize("align_mode", ["dense", "general"])
+def test_garch_fit_programs_fold_outside_their_loops(monkeypatch, align_mode):
+    # the CPU's stand-in for "``copy`` left the optimizer's loops" (PERF.md
+    # S6, PR 29): the panel is folded once per fit program, so no while
+    # body of stage 1, stage 2 or the inline program (with its straggler
+    # compaction) relayouts a panel-sized operand
+    from spark_timeseries_tpu.models import garch
+
+    monkeypatch.setattr(garch, "_COMPACT_MIN_BATCH", 2048)
+    b, t = 2048, 48
+    y = jax.ShapeDtypeStruct((b, t), jnp.float32)
+    static = (13, 1e-4, "pallas-interpret")
+    stage1 = garch._fit_stage1_program.__wrapped__(*static, align_mode)
+    inline = garch._fit_program.__wrapped__(*static, align_mode, False, True)
+    stage2 = garch._fit_stage2_program.__wrapped__(*static)
+    aux = jax.eval_shape(stage1, y)[1]
+    cap = optim.compaction_cap(b)
+    assert aux["folded_s"].r23.shape == (t, cap // 128, 128)
+    assert "ras" not in aux and "nvs" not in aux
+    for fn, arg, n_panel in ((stage1, y, b * t), (inline, y, b * t),
+                             (stage2, aux, cap * t)):
+        jaxpr = jax.make_jaxpr(fn)(arg).jaxpr
+        assert any(e.primitive.name == "while" for e in jaxpr.eqns)
+        assert _panel_relayouts_in_loops(jaxpr, n_panel) == []
+    # the detector sees what it is for: the fold-per-call API in a loop
+    f32 = jnp.float32
+    per_call = jax.make_jaxpr(lambda yv: jax.lax.while_loop(
+        lambda acc: acc[0] < 1.0, lambda acc: acc + pk.garch_neg_loglik(
+            jnp.full((b, 3), 0.1, f32), yv, interpret=True),
+        jnp.zeros((b,), f32)))(y).jaxpr
+    assert ("transpose", (b, t)) in _panel_relayouts_in_loops(per_call, b * t)
+
+
+def _garch_pin_fit(path, backend="pallas-interpret"):
+    """One fit of the fit-level pin: ``inline-*`` (24 rows, under the
+    compaction gate) or ``lazy-*`` (2048 rows through stage 1 / stage 2;
+    the caller lowers the gate), ``*-dense`` or ``*-ragged`` (NaN heads and
+    a NaN tail: ``align_mode="general"``)."""
+    from spark_timeseries_tpu.models import garch
+
+    b, t = (2048, 64) if path.startswith("lazy") else (24, 120)
+    rng = np.random.default_rng(29)
+    omega = rng.uniform(1e-5, 6e-5, size=b)
+    alpha = rng.uniform(0.03, 0.25, size=b)
+    beta = rng.uniform(0.4, 0.7, size=b)
+    z = rng.normal(size=(b, t))
+    r = np.zeros((b, t))
+    h = omega / (1.0 - alpha - beta)
+    for i in range(t):
+        r[:, i] = np.sqrt(h) * z[:, i]
+        h = omega + alpha * r[:, i] ** 2 + beta * h
+    r = r.astype(np.float32)
+    if path.endswith("ragged"):
+        r[1, :13] = np.nan
+        r[3, -9:] = np.nan
+        r[5, :3] = np.nan
+    return garch.fit(jnp.asarray(r), backend=backend)
+
+
+# recorded on the PARENT of PR 29 (commit ebc6e06: the objective masked,
+# seeded and folded the panel on every call and formed its cotangent in
+# [B, T]), f32 under this suite's jax_enable_x64, XLA:CPU of this container
+_GARCH_PIN = {  # params sha, objective sha, rows converged, sum of iters
+    "inline-dense": ("b20b71591a795f6a", "d32eee6ca477aed0", 24, 285),
+    "inline-ragged": ("14ec30eca25da049", "d1abbeb9b417a981", 24, 278),
+    "lazy-dense": ("fb5390df47ce9daa", "82d0db5b5ca228ba", 2042, 22242),
+    "lazy-ragged": ("ed357e134934bd45", "93bb130fb6acf24d", 2042, 22243),
+}
+# the scan backend's digest of inline-dense there (see _HW_PIN_HOST)
+_GARCH_PIN_HOST = ("dc07ad11499b9407", "1535d07ed973ef1a", 24, 285)
+
+
+@pytest.mark.parametrize("path", sorted(_GARCH_PIN))
+def test_garch_fit_pinned_to_the_fold_per_call_parent(monkeypatch, path):
+    # PR 29 moved the mask, the variance seed and the fold out of the
+    # optimizer's loops and the likelihood's cotangent into the folded
+    # layout; the kernels' arithmetic, their operands and the cotangent's
+    # formula are the same, so a fit takes the same path through the
+    # optimizer: params and objective bit-equal to the parent's, row for row
+    # the same iterations (HW's twin below says what a miss would mean)
+    from spark_timeseries_tpu.models import garch
+
+    host = _fit_pin_digest(_garch_pin_fit("inline-dense", "scan"))
+    if host != _GARCH_PIN_HOST:
+        pytest.skip("another XLA:CPU code generator than the recording's")
+    if path.startswith("lazy"):
+        monkeypatch.setattr(garch, "_COMPACT_MIN_BATCH", 2048)
+    assert _fit_pin_digest(_garch_pin_fit(path)) == _GARCH_PIN[path]
+
+
 # ---------------------------------------------------------------------------
 # EWMA fused objective
 # ---------------------------------------------------------------------------
@@ -612,12 +807,13 @@ def test_hw_sse_folded_matches_unfolded(mult, ragged, t):
 
 
 def _panel_relayouts_in_loops(jaxpr, n_panel, in_loop=False):
-    """``(primitive, operand shape)`` of every ``transpose`` / ``pad`` of an
-    operand with at least ``n_panel`` elements inside a ``while`` of
-    ``jaxpr`` (kernel bodies aside: a ``pallas_call`` works on blocks)."""
+    """``(primitive, operand shape)`` of every ``transpose`` / ``pad`` /
+    ``copy`` of an operand with at least ``n_panel`` elements inside a
+    ``while`` of ``jaxpr`` (kernel bodies aside: a ``pallas_call`` works on
+    blocks)."""
     found = []
     for eqn in jaxpr.eqns:
-        if (in_loop and eqn.primitive.name in ("transpose", "pad")
+        if (in_loop and eqn.primitive.name in ("transpose", "pad", "copy")
                 and eqn.invars[0].aval.size >= n_panel):
             found.append((eqn.primitive.name, eqn.invars[0].aval.shape))
         if eqn.primitive.name == "pallas_call":
@@ -688,7 +884,7 @@ def _hw_pin_fit(path, model_type, backend="pallas-interpret"):
     return hw.fit(jnp.asarray(y), m, model_type, backend=backend)
 
 
-def _hw_pin_digest(r):
+def _fit_pin_digest(r):
     import hashlib
 
     sha = lambda a: hashlib.sha256(  # noqa: E731
@@ -730,12 +926,12 @@ def test_hw_fit_pinned_to_the_fold_per_call_parent(monkeypatch, path,
     # a Mosaic kernel on the chip does not share.)
     from spark_timeseries_tpu.models import holtwinters as hw
 
-    host = _hw_pin_digest(_hw_pin_fit("inline", "additive", "scan"))
+    host = _fit_pin_digest(_hw_pin_fit("inline", "additive", "scan"))
     if host != _HW_PIN_HOST:
         pytest.skip("another XLA:CPU code generator than the recording's")
     if path == "lazy":
         monkeypatch.setattr(hw, "_COMPACT_MIN_BATCH", 2048)
-    assert _hw_pin_digest(_hw_pin_fit(path, model_type)) == _HW_PIN[
+    assert _fit_pin_digest(_hw_pin_fit(path, model_type)) == _HW_PIN[
         f"{path}-{model_type}"]
 
 
