@@ -24,6 +24,7 @@ Parameter vector layout (matching the reference's ``coefficients``):
 
 from __future__ import annotations
 
+import dataclasses
 import functools
 from typing import NamedTuple, Optional, Tuple
 
@@ -32,11 +33,11 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
-from .. import obs
 from ..ops import univariate as uv
 from ..utils import optim
 from ..utils.linalg import ols as _ols
 from ..utils.linalg import ridge_solve as _ridge_solve
+from . import lockstep
 from .base import (FitResult, align_mode_on_host, align_right, debatch,
                    debatch_fit, derive_status, ensure_batched, jit_program,
                    maybe_align, require_pallas_for_count_evals,
@@ -44,10 +45,6 @@ from .base import (FitResult, align_mode_on_host, align_right, debatch,
 
 Order = Tuple[int, int, int]
 Seasonal = Tuple[int, int, int, int]  # (P, D, Q, s)
-
-# module-level so tests can monkeypatch the gate per model; the value and
-# the cap sizing live with the compaction feature (utils.optim)
-_COMPACT_MIN_BATCH = optim.COMPACT_MIN_BATCH
 
 
 def _n_params(order: Order, include_intercept: bool) -> int:
@@ -436,14 +433,13 @@ def fit(
 
     ``count_evals=True`` (pallas backend only) returns ``(FitResult, info)``
     where ``info`` is the optimizer's pass-accounting dict
-    (``utils.optim.minimize_lbfgs_batched``) — the benchmark publishes it so
-    "how many objective passes does a fit spend" is a recorded number, not
-    an estimate.
+    (``utils.optim.minimize_lbfgs_batched``), so "how many objective passes
+    does a fit spend" is a recorded number, not an estimate; the fit that is
+    counted is the fit that runs without the flag.
 
     ``compact=False`` disables straggler compaction (``utils.optim``) for
     run-to-run reproducibility: compaction engages automatically on the
-    pallas backend at batches >= ``utils.optim.COMPACT_MIN_BATCH`` (4096;
-    tests may monkeypatch the module-level ``_COMPACT_MIN_BATCH`` gate)
+    pallas backend at batches >= ``utils.optim.COMPACT_MIN_BATCH`` (4096)
     and — while parity-gated at the distribution level — is a different
     compiled program, so individual rows on flat/non-convex stretches can
     reach different (equally valid) optima than an uncompacted run.
@@ -479,7 +475,6 @@ def fit(
             align_mode=align_mode)
     p, d, q = order
     yb, single = ensure_batched(y)
-    k = _n_params(order, include_intercept)
     if tol is None:
         # f32 gradients of a ~1k-term CSS bottom out near 1e-4 relative noise
         tol = 1e-6 if yb.dtype == jnp.float64 else 1e-4
@@ -489,107 +484,92 @@ def fit(
                               structural_ok=pk.css_structural_ok(p, q))
     require_pallas_for_count_evals(count_evals, backend)
 
-    bsz = yb.shape[0]
-    # lazy straggler compile (utils.optim stage-1/stage-2 split): compact
-    # fits run stage 1 as their own program and only dispatch — and
-    # therefore only ever trace+compile — the stage-2 straggler program
-    # when stage 1 actually leaves unconverged rows (ADVICE r5: the inline
-    # two-stage program roughly doubles compile time for batches that never
-    # need it).  count_evals keeps the inline driver (pass accounting
-    # instruments it); the gate mirrors the inline compaction gate.
-    # traced inputs (fit called under an outer jit) cannot host-check the
-    # straggler count — they keep the fully traceable inline program, same
-    # as align_mode_on_host's tracer branch
-    lazy = (compact and not count_evals and method != "hannan-rissanen"
-            and backend in ("pallas", "pallas-interpret")
-            and not isinstance(yb, jax.core.Tracer)
-            and bsz >= _COMPACT_MIN_BATCH
-            and optim.compaction_cap(bsz) < bsz)
     align_mode = resolve_align_mode(yb, align_mode)
-    if lazy:
-        run1 = _fit_stage1_program(
-            order, include_intercept, backend, max_iters, float(tol),
-            init_params is not None, align_mode)
-        # fit.stage1: the dispatch of stage 1 and the host's wait for it at
-        # the gate below; fit.stage2 only when the gate dispatches
-        with obs.span("fit.stage1", rows=bsz) as stage1:
-            if init_params is None:
-                out, aux = run1(yb)
-            else:
-                out, aux = run1(yb, jnp.asarray(init_params))
-            # host gate: tiny scalar sync; stage 2 shares stage 1's iteration
-            # budget, so an exhausted budget skips the dispatch entirely (the
-            # scatter of unchanged state would be an identity)
-            undone = int(aux["carry"].undone)
-            if obs.enabled():
-                stage1.set(iters=int(aux["carry"].k), undone=undone)
-        if undone > 0 and int(aux["carry"].k) < max_iters:
-            run2 = _fit_stage2_program(
-                order, include_intercept, backend, max_iters, float(tol),
-                int(yb.shape[1] - d))
-            with obs.span("fit.stage2", rows=optim.compaction_cap(bsz)):
-                out = run2(aux)
-        return debatch_fit(out, single, False)
-    run = _fit_program(
-        order, include_intercept, method, backend, max_iters, float(tol),
-        init_params is not None, align_mode, count_evals,
-        compact,
-    )
-    if init_params is None:
-        out = run(yb)
-    else:
-        out = run(yb, jnp.asarray(init_params))
+    has_init = init_params is not None
+    static = (order, include_intercept, backend, max_iters, float(tol))
+    out = lockstep.fit(
+        (yb, jnp.asarray(init_params)) if has_init else (yb,),
+        backend=backend, max_iters=max_iters,
+        compact=compact and method != "hannan-rissanen",
+        inline=lambda: _fit_program(
+            order, include_intercept, method, backend, max_iters, float(tol),
+            has_init, align_mode, count_evals, compact),
+        stage1=lambda: _fit_stage1_program(*static, has_init, align_mode,
+                                           count_evals),
+        stage2=lambda: _fit_stage2_program(*static))
     return debatch_fit(out, single, count_evals)
 
 
-def _css_prep(yb, init_params, order: Order, include_intercept: bool,
-              backend: str, align_mode: str, has_init: bool):
-    """Shared front half of every CSS fit program: align + difference, the
-    one-time folded layout (pallas backends), the Hannan-Rissanen (or
-    caller-provided) init, the identifiability gate, and the mean-scaling
-    denominator.  ONE implementation serves the inline `_fit_program` and
-    the lazy `_fit_stage1_program` — the `ok` eligibility formulas must
-    never diverge between them (the lazy path serves large batches, the
-    inline one everything else, and the same panel content must get the
-    same eligibility regardless of batch size)."""
-    p, d, q = order
-    k = _n_params(order, include_intercept)
-    with jax.named_scope("arima.align_and_difference"):
-        ya, nv0 = maybe_align(yb, align_mode)  # ragged: NaN head/tail
-        yd = jax.vmap(lambda v: _difference(v, d))(ya)
-        nvd = nv0 - d  # valid length after differencing
+@functools.partial(jax.tree_util.register_dataclass,
+                   data_fields=["y3", "zb3"], meta_fields=["t"])
+@dataclasses.dataclass(frozen=True)
+class _CssFolded:
+    """A differenced panel in the CSS kernel layout
+    (``pallas_kernels.css_prefold``); ``t`` is its true length (static: it
+    rides the treedef through a ``jit`` boundary)."""
+
+    y3: jax.Array
+    zb3: jax.Array
+    t: int
+
+
+def _css_family(order: Order, include_intercept: bool, backend: str,
+                has_init: bool = False,
+                align_mode: Optional[str] = None) -> lockstep.Family:
     from ..ops import pallas_kernels as _pk
 
-    y3 = zb3 = None
-    if backend in ("pallas", "pallas-interpret"):
-        # fold ONCE per fit: the init sweeps and every optimizer
-        # evaluation share this layout (css_prefold)
-        y3, zb3 = _pk.css_prefold(yd, order, nvd)
-    with jax.named_scope("arima.hannan_rissanen_init"):
-        if has_init:
-            init = jnp.broadcast_to(init_params, (yd.shape[0], k))
-        elif y3 is not None and _pk.hr_structural_ok(p, q):
-            # fused two-sweep moment kernels: same normal equations,
-            # ~15x less HBM traffic than the shifted-reduce construction
-            init = _pk.hr_init(yd, order, include_intercept, nvd,
-                               interpret=backend == "pallas-interpret",
-                               y3=y3)
-        else:
-            init = hannan_rissanen_batched(yd, order, include_intercept, nvd)
-    # too-short series cannot be fit: need lags + a few dof
-    ok = nvd >= p + q + max(p + q + 1, 1) + k + 2
-    if not has_init:
-        # Hannan-Rissanen's long-AR order m = min(p+q+1, n//4) is static
-        # (shapes), so it is computed from the PADDED length; requiring
-        # nvd >= 4*(p+q+1) ensures m would be p+q+1 either way, keeping
-        # padded and trimmed inits identical inside the supported region
-        ok = ok & (nvd >= 4 * (p + q + 1))
-    # optimize the MEAN log-likelihood (nll / effective obs): same argmin,
-    # but gradients are O(1) so the relative grad-norm stopping rule is
-    # reachable at f32 instead of stalling on the accumulation noise floor
-    # of a ~1k-term sum (the reported nll is unscaled)
-    n_eff = jnp.maximum(nvd - p, 1).astype(yd.dtype)
-    return yd, nvd, y3, zb3, init, ok, n_eff
+    p, d, q = order
+    k = _n_params(order, include_intercept)
+    interp = backend == "pallas-interpret"
+
+    def prep(yb, init_params=None):
+        with jax.named_scope("arima.align_and_difference"):
+            ya, nv0 = maybe_align(yb, align_mode)  # ragged: NaN head/tail
+            yd = jax.vmap(lambda v: _difference(v, d))(ya)
+            nvd = nv0 - d  # valid length after differencing
+        folded = ()
+        if backend in lockstep.PALLAS:
+            # the init sweeps and every optimizer evaluation share this
+            # layout
+            folded = _CssFolded(*_pk.css_prefold(yd, order, nvd),
+                                yd.shape[1])
+        with jax.named_scope("arima.hannan_rissanen_init"):
+            if has_init:
+                init = jnp.broadcast_to(init_params, (yd.shape[0], k))
+            elif backend in lockstep.PALLAS and _pk.hr_structural_ok(p, q):
+                # fused two-sweep moment kernels: same normal equations,
+                # ~15x less HBM traffic than the shifted-reduce construction
+                init = _pk.hr_init(yd, order, include_intercept, nvd,
+                                   interpret=interp, y3=folded.y3)
+            else:
+                init = hannan_rissanen_batched(yd, order, include_intercept,
+                                               nvd)
+        # too-short series cannot be fit: need lags + a few dof.  The gate
+        # reads the panel's content only, so a row's eligibility does not
+        # depend on the batch it arrives in
+        ok = nvd >= p + q + max(p + q + 1, 1) + k + 2
+        if not has_init:
+            # Hannan-Rissanen's long-AR order m = min(p+q+1, n//4) is static
+            # (shapes), so it is computed from the PADDED length; requiring
+            # nvd >= 4*(p+q+1) ensures m would be p+q+1 either way, keeping
+            # padded and trimmed inits identical inside the supported region
+            ok = ok & (nvd >= 4 * (p + q + 1))
+        n_eff = jnp.maximum(nvd - p, 1).astype(yd.dtype)
+        return lockstep.Prepared((init,), ok, n_eff, (yd, nvd), folded,
+                                 (nvd,))
+
+    def objective(folded, rows):
+        (nvd,) = rows
+        return lambda P: _pk.css_neg_loglik_folded(
+            P, folded.y3, folded.zb3, folded.t, order, include_intercept,
+            nvd, interpret=interp)
+
+    def scan_objective(pr, data):
+        yv, n = data
+        return css_neg_loglik(pr, yv, order, include_intercept, n)
+
+    return lockstep.Family(backend, prep, objective, scan_objective,
+                           lambda x: x)
 
 
 @jit_program
@@ -597,153 +577,38 @@ def _fit_program(order: Order, include_intercept: bool, method: str,
                  backend: str, max_iters: int, tol: float, has_init: bool,
                  align_mode: str = "general", count_evals: bool = False,
                  compact: bool = True):
-    p, d, q = order
-    k = _n_params(order, include_intercept)
+    family = _css_family(order, include_intercept, backend, has_init,
+                         align_mode)
+    if method != "hannan-rissanen":
+        return lockstep.fit_program(family, max_iters, tol, count_evals,
+                                    compact)
 
     def run(yb, init_params=None):
-        yd, nvd, y3, zb3, init, ok, n_eff = _css_prep(
-            yb, init_params, order, include_intercept, backend, align_mode,
-            has_init)
-        if method == "hannan-rissanen":
-            nll = jax.vmap(
-                lambda pr, v, n: css_neg_loglik(pr, v, order, include_intercept, n)
-            )(init, yd, nvd)
-            z = jnp.zeros((yd.shape[0],), jnp.int32)
-            params = jnp.where(ok[:, None], init, jnp.nan)
-            return FitResult(params, jnp.where(ok, nll, jnp.nan), ok, z,
-                             derive_status(ok, ok, params))
-        info = None
-        if backend in ("pallas", "pallas-interpret"):
-            from ..ops import pallas_kernels as _pk
-
-            interp = backend == "pallas-interpret"
-            bsz, T = yd.shape
-
-            # straggler compaction (utils.optim): after most rows converge,
-            # lockstep passes still stream the whole panel; gather the tail
-            # into a 1/8-size problem instead.  The gather repacks folded
-            # COLUMNS (series ride the lanes), grid-aligned by the cap
-            cap = optim.compaction_cap(bsz)
-            straggler_fun = None
-            if compact and bsz >= _COMPACT_MIN_BATCH:
-                tp = y3.shape[0]
-
-                def straggler_fun(idxc, _y3=y3, _zb3=zb3):
-                    y3s = _y3.reshape(tp, -1)[:, idxc].reshape(
-                        tp, cap // 128, 128)
-                    zb3s = _zb3.reshape(1, -1)[:, idxc].reshape(
-                        1, cap // 128, 128)
-                    nvs = nvd[idxc]
-                    nes = n_eff[idxc]
-                    return lambda P: _pk.css_neg_loglik_folded(
-                        P, y3s, zb3s, T, order, include_intercept, nvs,
-                        interpret=interp
-                    ) / nes
-
-            res = optim.minimize_lbfgs_batched(
-                lambda P: _pk.css_neg_loglik_folded(
-                    P, y3, zb3, T, order, include_intercept, nvd,
-                    interpret=interp
-                ) / n_eff,
-                init,
-                max_iters=max_iters,
-                tol=tol,
-                straggler_fun=straggler_fun,
-                straggler_cap=cap,
-                count_evals=count_evals,
-            )
-            if count_evals:
-                res, info = res
-        else:
-            res = optim.batched_minimize(
-                lambda pr, data: css_neg_loglik(
-                    pr, data[0], order, include_intercept, data[1]
-                ) / data[2],
-                init,
-                (yd, nvd, n_eff),
-                max_iters=max_iters,
-                tol=tol,
-            )
-        params = jnp.where(ok[:, None], res.x, jnp.nan)
-        out = FitResult(
-            params, jnp.where(ok, res.f * n_eff, jnp.nan),
-            res.converged & ok, res.iters,
-            derive_status(ok, res.converged, params),
-        )
-        return (out, info) if count_evals else out
-
-    return run
-
-
-def _finalize_css_fit(res, ok, n_eff):
-    """Optimizer result -> FitResult (same ops as the inline program)."""
-    params = jnp.where(ok[:, None], res.x, jnp.nan)
-    return FitResult(
-        params, jnp.where(ok, res.f * n_eff, jnp.nan),
-        res.converged & ok, res.iters,
-        derive_status(ok, res.converged, params),
-    )
-
-
-@jit_program
-def _fit_stage1_program(order, include_intercept, backend, max_iters, tol,
-                        has_init, align_mode="general"):
-    """Stage 1 of the lazily compiled compact fit (ADVICE r5): the full
-    prep + lockstep L-BFGS with the straggler early-exit, returning the
-    finalized as-if-done result PLUS the compacted carry — so the stage-2
-    program is only traced/compiled when ``carry.undone`` says rows
-    actually remain.  Pallas backends only (the gate lives in ``fit``)."""
-    def run(yb, init_params=None):
-        yd, nvd, y3, zb3, init, ok, n_eff = _css_prep(
-            yb, init_params, order, include_intercept, backend, align_mode,
-            has_init)
-        from ..ops import pallas_kernels as _pk
-
-        interp = backend == "pallas-interpret"
-        bsz, T = yd.shape
-        cap = optim.compaction_cap(bsz)
-        res1, carry = optim.lbfgs_batched_stage1(
-            lambda P: _pk.css_neg_loglik_folded(
-                P, y3, zb3, T, order, include_intercept, nvd,
-                interpret=interp
-            ) / n_eff,
-            init, straggler_cap=cap, max_iters=max_iters, tol=tol)
-        # repack the compacted objective data HERE (the same folded-COLUMN
-        # gather the inline straggler_fun performs — series ride the lanes,
-        # grid-aligned by the cap), so the stage-2 program is a pure
-        # function of its inputs and compiles against stable shapes
-        tp = y3.shape[0]
-        y3s = y3.reshape(tp, -1)[:, carry.idxc].reshape(tp, cap // 128, 128)
-        zb3s = zb3.reshape(1, -1)[:, carry.idxc].reshape(1, cap // 128, 128)
-        aux = {"carry": carry, "res": res1, "y3s": y3s, "zb3s": zb3s,
-               "nvs": nvd[carry.idxc], "nes": n_eff[carry.idxc],
-               "ok": ok, "n_eff": n_eff}
-        return _finalize_css_fit(res1, ok, n_eff), aux
+        prepared = family.prep(yb, init_params)
+        (init,), ok, (yd, nvd) = prepared.x0s, prepared.ok, prepared.series
+        nll = jax.vmap(
+            lambda pr, v, n: css_neg_loglik(pr, v, order, include_intercept, n)
+        )(init, yd, nvd)
+        z = jnp.zeros((yd.shape[0],), jnp.int32)
+        params = jnp.where(ok[:, None], init, jnp.nan)
+        return FitResult(params, jnp.where(ok, nll, jnp.nan), ok, z,
+                         derive_status(ok, ok, params))
 
     return run
 
 
 @jit_program
-def _fit_stage2_program(order, include_intercept, backend, max_iters, tol,
-                        t_len):
-    """Stage 2 of the lazy compact fit: finish the gathered stragglers on
-    the compacted objective and scatter back — compiled only on the first
-    call where stage 1 left unconverged rows (per static config)."""
-    interp = backend == "pallas-interpret"
+def _fit_stage1_program(order, include_intercept, backend, max_iters, tol,
+                        has_init, align_mode="general", count_evals=False):
+    return lockstep.stage1_program(
+        _css_family(order, include_intercept, backend, has_init, align_mode),
+        max_iters, tol, count_evals)
 
-    def run(aux):
-        from ..ops import pallas_kernels as _pk
 
-        def fb_s(P):
-            return _pk.css_neg_loglik_folded(
-                P, aux["y3s"], aux["zb3s"], t_len, order, include_intercept,
-                aux["nvs"], interpret=interp) / aux["nes"]
-
-        res = optim.lbfgs_batched_stage2(
-            fb_s, aux["res"], aux["carry"], max_iters=max_iters, tol=tol)
-        return _finalize_css_fit(res, aux["ok"], aux["n_eff"])
-
-    return run
+@jit_program
+def _fit_stage2_program(order, include_intercept, backend, max_iters, tol):
+    return lockstep.stage2_program(
+        _css_family(order, include_intercept, backend), max_iters, tol)
 
 
 def _fit_seasonal(
@@ -819,7 +684,7 @@ def _fit_sarima_program(order, seasonal, include_intercept, max_iters, tol,
                 # short-memory (p, q) warm start on the fully differenced
                 # series; the P+Q seasonal terms start at 0 so the init is
                 # deterministic and the gate below keeps HR's long-AR order
-                # static (same nvd >= 4*(p+q+1) contract as _css_prep)
+                # static (same nvd >= 4*(p+q+1) contract as _css_family's prep)
                 base = hannan_rissanen_batched(
                     yd, (p, 0, q), include_intercept, nvd)
                 init = jnp.concatenate(
@@ -827,7 +692,7 @@ def _fit_sarima_program(order, seasonal, include_intercept, max_iters, tol,
         ok = nvd >= p_full + q_full + max(p_full + q_full + 1, 1) + k + 2
         if not has_init:
             ok = ok & (nvd >= 4 * (p + q + 1))
-        # optimize the MEAN log-likelihood (same rationale as _css_prep)
+        # optimize the MEAN log-likelihood (lockstep.Prepared.scale)
         n_eff = jnp.maximum(nvd - p_full, 1).astype(yd.dtype)
         res = optim.batched_minimize(
             lambda pr, data: sarima_neg_loglik(
@@ -838,7 +703,7 @@ def _fit_sarima_program(order, seasonal, include_intercept, max_iters, tol,
             max_iters=max_iters,
             tol=tol,
         )
-        return _finalize_css_fit(res, ok, n_eff)
+        return lockstep.finalize(res, ok, n_eff)
 
     return run
 
@@ -1090,7 +955,7 @@ def _grid_fit_program(specs, include_intercept, max_iters, tol,
             pf, qf, k = info["p_full"], info["q_full"], info["k"]
             ok = nvd >= pf + qf + max(pf + qf + 1, 1) + k + 2
             ok = ok & (nvd >= 4 * (p + q + 1))
-            # optimize the MEAN log-likelihood (same rationale as _css_prep)
+            # optimize the MEAN log-likelihood (lockstep.Prepared.scale)
             n_eff = jnp.maximum(nvd - pf, 1).astype(yd.dtype)
             inits.append(init)
             oks.append(ok)

@@ -22,13 +22,12 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from .. import obs
 from ..utils import optim
-from .base import (FitResult, align_right, debatch,
-                   debatch_fit, derive_status,
-                   require_pallas_for_count_evals,
-                   ensure_batched, maybe_align,
-                   jit_program, resolve_align_mode, resolve_backend)
+from . import lockstep
+from .base import (FitResult, align_right, debatch, debatch_fit,
+                   ensure_batched, jit_program, maybe_align,
+                   require_pallas_for_count_evals, resolve_align_mode,
+                   resolve_backend)
 
 
 # -- transforms -------------------------------------------------------------
@@ -128,10 +127,6 @@ def neg_log_likelihood(params, r, n_valid=None):
 
 # -- fitting ----------------------------------------------------------------
 
-# module-level so tests can monkeypatch the gate per model (sizing lives
-# with the compaction feature: utils.optim)
-_COMPACT_MIN_BATCH = optim.COMPACT_MIN_BATCH
-
 
 def fit(r, *, max_iters: int = 80, tol: Optional[float] = None,
         backend: str = "auto", count_evals: bool = False,
@@ -139,7 +134,8 @@ def fit(r, *, max_iters: int = 80, tol: Optional[float] = None,
     """Fit GARCH(1,1) per series -> natural params ``[batch?, 3]``.
 
     ``count_evals=True`` (pallas backend only) returns ``(FitResult, info)``
-    with the optimizer's pass-accounting dict (``utils.optim``).
+    with the optimizer's pass-accounting dict (``utils.optim``); the fit
+    that is counted is the fit that runs without the flag.
 
     ``compact=False`` disables straggler compaction for run-to-run
     reproducibility (it engages on the pallas backend at batches >=
@@ -156,186 +152,66 @@ def fit(r, *, max_iters: int = 80, tol: Optional[float] = None,
         tol = 1e-7 if rb.dtype == jnp.float64 else 1e-4
     backend = resolve_backend(backend, rb.dtype, rb.shape[1])
     require_pallas_for_count_evals(count_evals, backend)
-    bsz = rb.shape[0]
     align_mode = resolve_align_mode(rb, align_mode)
-    # lazy straggler compile (utils.optim stage-1/stage-2 split, ADVICE r5):
-    # the compacted stage-2 program is traced/compiled only when stage 1
-    # actually leaves unconverged rows — same gate and host check as
-    # models.arima.fit.  count_evals keeps the inline instrumented driver.
-    # traced inputs keep the fully traceable inline program (the lazy gate
-    # needs a host check of the straggler count) — see models.arima.fit
-    lazy = (compact and not count_evals
-            and backend in ("pallas", "pallas-interpret")
-            and not isinstance(rb, jax.core.Tracer)
-            and bsz >= _COMPACT_MIN_BATCH
-            and optim.compaction_cap(bsz) < bsz)
-    if lazy:
-        out = _run_lazy_stages(
-            _fit_stage1_program(max_iters, float(tol), backend, align_mode),
-            lambda: _fit_stage2_program(max_iters, float(tol), backend),
-            rb, max_iters)
-        return debatch_fit(out, single, False)
-    out = _fit_program(max_iters, float(tol), backend, align_mode,
-                       count_evals, compact)(rb)
+    static = (max_iters, float(tol), backend)
+    out = lockstep.fit(
+        (rb,), backend=backend, compact=compact, max_iters=max_iters,
+        inline=lambda: _fit_program(*static, align_mode, count_evals,
+                                    compact),
+        stage1=lambda: _fit_stage1_program(*static, align_mode, count_evals),
+        stage2=lambda: _fit_stage2_program(*static))
     return debatch_fit(out, single, count_evals)
 
 
-def _run_lazy_stages(run1, stage2_program, xb, max_iters: int):
-    """The lazy path's dispatch, host gate and stage-2 dispatch, shared by
-    :func:`fit` and :func:`fit_argarch`, under the spans of
-    ``models.arima.fit``: ``fit.stage1`` is the dispatch of stage 1 and the
-    host's wait for it at the gate, ``fit.stage2`` opens only when the gate
-    dispatches.  ``stage2_program()`` looks the stage-2 program up, and is
-    called only then."""
-    bsz = xb.shape[0]
-    with obs.span("fit.stage1", rows=bsz) as stage1:
-        out, aux = run1(xb)
-        # host gate: tiny scalar sync; stage 2 shares stage 1's iteration
-        # budget, so an exhausted budget skips the dispatch entirely
-        undone, iters = int(aux["carry"].undone), int(aux["carry"].k)
-        if obs.enabled():
-            stage1.set(iters=iters, undone=undone)
-    if undone > 0 and iters < max_iters:
-        with obs.span("fit.stage2", rows=optim.compaction_cap(bsz)):
-            out = stage2_program()(aux)
-    return out
-
-
-def _garch_prep(rb, align_mode: str):
-    """Shared front half of both GARCH fit programs (inline + lazy
-    stage-1): alignment, the moment-ish start (omega = 0.1*var, alpha=0.1,
-    beta=0.8) in transformed space, and the mean-nll denominator (see
-    models.arima: same argmin, O(1) gradients keep the relative stopping
-    rule reachable at f32).  ONE implementation so the seeds can never
-    diverge between the two paths."""
-    ra, nv = maybe_align(rb, align_mode)
-    var0 = jax.vmap(_masked_var)(ra, nv)
-    nat0 = jnp.stack(
-        [0.1 * jnp.maximum(var0, 1e-10), jnp.full_like(var0, 0.1),
-         jnp.full_like(var0, 0.8)], axis=1
-    )
-    u0 = jax.vmap(_from_natural)(nat0)
-    n_eff = jnp.maximum(nv, 1).astype(ra.dtype)
-    return ra, nv, u0, n_eff
-
-
-def _garch_fb(folded, n_eff, interp):
-    """The fused GARCH objective over a pre-folded panel
-    (``pallas_kernels.garch_prefold``), in transformed space — shared by
-    the inline program, its straggler subset, and both lazy stages."""
+def _garch_family(backend, align_mode=None) -> lockstep.Family:
     from ..ops import pallas_kernels as pk
 
-    def fb(u):
-        nat = jax.vmap(_to_natural)(u)
-        return pk.garch_neg_loglik_folded(nat, folded, interpret=interp) / n_eff
+    def prep(rb):
+        ra, nv = maybe_align(rb, align_mode)
+        # moment-ish start: omega = 0.1*var, alpha = 0.1, beta = 0.8
+        var0 = jax.vmap(_masked_var)(ra, nv)
+        nat0 = jnp.stack(
+            [0.1 * jnp.maximum(var0, 1e-10), jnp.full_like(var0, 0.1),
+             jnp.full_like(var0, 0.8)], axis=1
+        )
+        u0 = jax.vmap(_from_natural)(nat0)
+        n_eff = jnp.maximum(nv, 1).astype(ra.dtype)
+        folded = ()
+        if backend in lockstep.PALLAS:
+            folded = pk.garch_prefold(ra, nv)
+        # GARCH needs a handful of observations to identify
+        return lockstep.Prepared((u0,), nv >= 10, n_eff, (ra, nv), folded)
 
-    return fb
+    def objective(folded, _):
+        return lambda u: pk.garch_neg_loglik_folded(
+            jax.vmap(_to_natural)(u), folded,
+            interpret=backend == "pallas-interpret")
+
+    def scan_objective(u, data):
+        rv, n = data
+        return neg_log_likelihood(_to_natural(u), rv, n)
+
+    return lockstep.Family(backend, prep, objective, scan_objective,
+                           jax.vmap(_to_natural))
 
 
 @jit_program
 def _fit_program(max_iters, tol, backend, align_mode="general",
                  count_evals=False, compact=True):
-    def run(rb):
-        ra, nv, u0, n_eff = _garch_prep(rb, align_mode)
-        if backend in ("pallas", "pallas-interpret"):
-            from ..ops import pallas_kernels as pk
-
-            interp = backend == "pallas-interpret"
-            # folded ONCE, before the optimizer: XLA does not hoist the
-            # re-tiling of the folded panel out of the while loops
-            folded = pk.garch_prefold(ra, nv)
-            fb = _garch_fb(folded, n_eff, interp)
-
-            # straggler compaction (utils.optim): the subset is a gather of
-            # folded COLUMNS (series ride the lanes), grid-aligned by the cap
-            bsz = ra.shape[0]
-            cap = optim.compaction_cap(bsz)
-            straggler_fun = None
-            if compact and bsz >= _COMPACT_MIN_BATCH:
-
-                def straggler_fun(idxc):
-                    return _garch_fb(folded.take(idxc), n_eff[idxc], interp)
-
-            res = optim.minimize_lbfgs_batched(
-                fb, u0, max_iters=max_iters, tol=tol, count_evals=count_evals,
-                straggler_fun=straggler_fun, straggler_cap=cap)
-            info = None
-            if count_evals:
-                res, info = res
-        else:
-            def objective(u, data):
-                rv, n, ne = data
-                return neg_log_likelihood(_to_natural(u), rv, n) / ne
-
-            res = optim.batched_minimize(
-                objective, u0, (ra, nv, n_eff), max_iters=max_iters, tol=tol
-            )
-        ok = nv >= 10  # GARCH needs a handful of observations to identify
-        params = jnp.where(ok[:, None], jax.vmap(_to_natural)(res.x), jnp.nan)
-        out = FitResult(
-            params,
-            jnp.where(ok, res.f * n_eff, jnp.nan),
-            res.converged & ok,
-            res.iters,
-            derive_status(ok, res.converged, params),
-        )
-        return (out, info) if count_evals else out
-
-    return run
-
-
-def _finalize_garch_fit(res, ok, n_eff):
-    """Optimizer result -> FitResult (same ops as the inline program)."""
-    params = jnp.where(ok[:, None], jax.vmap(_to_natural)(res.x), jnp.nan)
-    return FitResult(
-        params,
-        jnp.where(ok, res.f * n_eff, jnp.nan),
-        res.converged & ok,
-        res.iters,
-        derive_status(ok, res.converged, params),
-    )
+    return lockstep.fit_program(_garch_family(backend, align_mode),
+                                max_iters, tol, count_evals, compact)
 
 
 @jit_program
-def _fit_stage1_program(max_iters, tol, backend, align_mode="general"):
-    """Stage 1 of the lazily compiled compact GARCH fit (see
-    ``models.arima._fit_stage1_program``): lockstep loop + straggler
-    gather, stage 2 compiled only when needed.  Pallas backends only."""
-
-    def run(rb):
-        from ..ops import pallas_kernels as pk
-
-        ra, nv, u0, n_eff = _garch_prep(rb, align_mode)
-        folded = pk.garch_prefold(ra, nv)  # once: see _fit_program
-        cap = optim.compaction_cap(ra.shape[0])
-        res1, carry = optim.lbfgs_batched_stage1(
-            _garch_fb(folded, n_eff, backend == "pallas-interpret"), u0,
-            straggler_cap=cap, max_iters=max_iters, tol=tol)
-        ok = nv >= 10
-        # the compacted problem's data is gathered HERE (folded columns: no
-        # re-fold), so the stage-2 program is a pure function of its inputs
-        # and folds nothing
-        aux = {"carry": carry, "res": res1,
-               "folded_s": folded.take(carry.idxc),
-               "nes": n_eff[carry.idxc], "ok": ok, "n_eff": n_eff}
-        return _finalize_garch_fit(res1, ok, n_eff), aux
-
-    return run
+def _fit_stage1_program(max_iters, tol, backend, align_mode="general",
+                        count_evals=False):
+    return lockstep.stage1_program(_garch_family(backend, align_mode),
+                                   max_iters, tol, count_evals)
 
 
 @jit_program
 def _fit_stage2_program(max_iters, tol, backend):
-    """Stage 2 of the lazy compact GARCH fit: finish the gathered
-    stragglers and scatter back (compiled on first actual need)."""
-    interp = backend == "pallas-interpret"
-
-    def run(aux):
-        res = optim.lbfgs_batched_stage2(
-            _garch_fb(aux["folded_s"], aux["nes"], interp), aux["res"],
-            aux["carry"], max_iters=max_iters, tol=tol)
-        return _finalize_garch_fit(res, aux["ok"], aux["n_eff"])
-
-    return run
+    return lockstep.stage2_program(_garch_family(backend), max_iters, tol)
 
 
 def forecast(params, r, n_future: int):
@@ -480,177 +356,98 @@ def fit_argarch(y, *, max_iters: int = 100, tol: Optional[float] = None,
     if tol is None:
         tol = 1e-7 if yb.dtype == jnp.float64 else 1e-4
     backend = resolve_backend(backend, yb.dtype, yb.shape[1])
-    bsz = yb.shape[0]
     align_mode = resolve_align_mode(yb, align_mode)
-    # lazy straggler compile: same stage-1/stage-2 split (and gate) as
-    # fit() above — the compacted stage-2 program is traced/compiled only
-    # when stage 1 actually leaves unconverged rows (ROADMAP follow-on)
-    lazy = (compact and backend in ("pallas", "pallas-interpret")
-            and not isinstance(yb, jax.core.Tracer)
-            and bsz >= _COMPACT_MIN_BATCH
-            and optim.compaction_cap(bsz) < bsz)
-    if lazy:
-        out = _run_lazy_stages(
-            _fit_argarch_stage1_program(max_iters, float(tol), backend,
-                                        align_mode),
-            lambda: _fit_argarch_stage2_program(max_iters, float(tol),
-                                                backend),
-            yb, max_iters)
-        return debatch(out, single)
-    return debatch(
-        _fit_argarch_program(max_iters, float(tol), backend, compact,
-                             align_mode)(yb),
-        single)
+    static = (max_iters, float(tol), backend)
+    out = lockstep.fit(
+        (yb,), backend=backend, compact=compact, max_iters=max_iters,
+        inline=lambda: _fit_argarch_program(*static, compact, align_mode),
+        stage1=lambda: _fit_argarch_stage1_program(*static, align_mode),
+        stage2=lambda: _fit_argarch_stage2_program(*static))
+    return debatch(out, single)
 
 
-def _argarch_prep(yb, align_mode: str):
-    """Shared front half of the ARGARCH fit programs (inline + lazy
-    stage-1): alignment, the AR(1)-by-autocorrelation + GARCH-moment init
-    in transformed space, and the mean-nll denominator.  ONE implementation
-    so the seeds can never diverge between the two paths (see
-    :func:`_garch_prep`)."""
-    ya, nv = maybe_align(yb, align_mode)
+def _argarch_family(backend, align_mode=None) -> lockstep.Family:
+    def prep(yb):
+        ya, nv = maybe_align(yb, align_mode)
 
-    # init: OLS-ish AR(1) by autocorrelation, then GARCH moments on resid
-    # (masked over each right-aligned valid span)
-    T = ya.shape[1]
-    m = (jnp.arange(T)[None, :] >= (T - nv)[:, None]).astype(ya.dtype)
-    nvf = jnp.maximum(nv, 1).astype(ya.dtype)
-    mean = jnp.sum(ya * m, axis=1) / nvf
-    yc = (ya - mean[:, None]) * m
-    phi0 = jnp.sum(yc[:, 1:] * yc[:, :-1], axis=1) / jnp.maximum(
-        jnp.sum(yc * yc, axis=1), 1e-12
-    )
-    phi0 = jnp.clip(phi0, -0.95, 0.95)
-    c0 = mean * (1.0 - phi0)
-    resid = (ya[:, 1:] - c0[:, None] - phi0[:, None] * ya[:, :-1]) * m[:, 1:]
-    resid_var = jnp.sum(resid**2, axis=1) / nvf
-    nat0 = jnp.stack(
-        [
-            c0,
-            phi0,
-            0.1 * jnp.maximum(resid_var, 1e-8),
-            jnp.full_like(c0, 0.1),
-            jnp.full_like(c0, 0.8),
-        ],
-        axis=1,
-    )
-    u0 = jax.vmap(_argarch_from_natural)(nat0)
-    n_eff = jnp.maximum(nv - 1, 1).astype(ya.dtype)
-    return ya, nv, u0, n_eff
+        # init: OLS-ish AR(1) by autocorrelation, then GARCH moments on resid
+        # (masked over each right-aligned valid span)
+        T = ya.shape[1]
+        m = (jnp.arange(T)[None, :] >= (T - nv)[:, None]).astype(ya.dtype)
+        nvf = jnp.maximum(nv, 1).astype(ya.dtype)
+        mean = jnp.sum(ya * m, axis=1) / nvf
+        yc = (ya - mean[:, None]) * m
+        phi0 = jnp.sum(yc[:, 1:] * yc[:, :-1], axis=1) / jnp.maximum(
+            jnp.sum(yc * yc, axis=1), 1e-12
+        )
+        phi0 = jnp.clip(phi0, -0.95, 0.95)
+        c0 = mean * (1.0 - phi0)
+        resid = (ya[:, 1:] - c0[:, None] - phi0[:, None] * ya[:, :-1]) * m[:, 1:]
+        resid_var = jnp.sum(resid**2, axis=1) / nvf
+        nat0 = jnp.stack(
+            [
+                c0,
+                phi0,
+                0.1 * jnp.maximum(resid_var, 1e-8),
+                jnp.full_like(c0, 0.1),
+                jnp.full_like(c0, 0.8),
+            ],
+            axis=1,
+        )
+        u0 = jax.vmap(_argarch_from_natural)(nat0)
+        n_eff = jnp.maximum(nv - 1, 1).astype(ya.dtype)
+        rows = ()
+        if backend in lockstep.PALLAS:
+            # the objective reads the NATURAL-layout panel (its residuals
+            # depend on the parameters, so nothing can be folded ahead):
+            # a straggler subset is a plain row gather of each array
+            prev = jnp.concatenate([ya[:, :1], ya[:, :-1]], axis=1)
+            rows = (ya, prev, nv)
+        return lockstep.Prepared((u0,), nv >= 12, n_eff, (ya, nv), (), rows)
 
+    def objective(_, rows):
+        from ..ops import pallas_kernels as pk
 
-def _finalize_argarch_fit(res, ok, n_eff):
-    """Optimizer result -> FitResult (same ops as the inline program)."""
-    params = jnp.where(
-        ok[:, None], jax.vmap(_argarch_to_natural)(res.x), jnp.nan)
-    return FitResult(
-        params,
-        jnp.where(ok, res.f * n_eff, jnp.nan),
-        res.converged & ok,
-        res.iters,
-        derive_status(ok, res.converged, params),
-    )
+        ya, prev, nv = rows
+        t_idx = jnp.arange(ya.shape[1])
+        start = ya.shape[1] - nv
 
+        def fb(u):
+            nat = jax.vmap(_argarch_to_natural)(u)
+            r = ya - nat[:, 0:1] - nat[:, 1:2] * prev
+            # condition on the first valid observation (see
+            # argarch_neg_log_likelihood): its residual is excluded
+            r = jnp.where(t_idx[None, :] <= start[:, None], 0.0, r)
+            return pk.garch_neg_loglik(
+                nat[:, 2:], r, nv - 1,
+                interpret=backend == "pallas-interpret")
 
-def _argarch_fb(ya, prev, nv, n_eff, interp):
-    """The fused ARGARCH objective over the natural-layout panel — shared
-    by the inline program, its straggler subset, and both lazy stages (the
-    compacted data is a plain row gather of each closed-over array)."""
-    from ..ops import pallas_kernels as pk
+        return fb
 
-    t_idx = jnp.arange(ya.shape[1])
-    start = ya.shape[1] - nv
+    def scan_objective(u, data):
+        yv, n = data
+        return argarch_neg_log_likelihood(_argarch_to_natural(u), yv, n)
 
-    def fb(u):
-        nat = jax.vmap(_argarch_to_natural)(u)
-        r = ya - nat[:, 0:1] - nat[:, 1:2] * prev
-        # condition on the first valid observation (see
-        # argarch_neg_log_likelihood): its residual is excluded
-        r = jnp.where(t_idx[None, :] <= start[:, None], 0.0, r)
-        return pk.garch_neg_loglik(nat[:, 2:], r, nv - 1,
-                                   interpret=interp) / n_eff
-
-    return fb
+    return lockstep.Family(backend, prep, objective, scan_objective,
+                           jax.vmap(_argarch_to_natural))
 
 
 @jit_program
 def _fit_argarch_program(max_iters, tol, backend, compact=True,
                          align_mode="general"):
-    def run(yb):
-        ya, nv, u0, n_eff = _argarch_prep(yb, align_mode)
-        if backend in ("pallas", "pallas-interpret"):
-            interp = backend == "pallas-interpret"
-            prev = jnp.concatenate([ya[:, :1], ya[:, :-1]], axis=1)
-            fb = _argarch_fb(ya, prev, nv, n_eff, interp)
-
-            # straggler compaction: row gathers, as in fit()
-            bsz = ya.shape[0]
-            cap = optim.compaction_cap(bsz)
-            straggler_fun = None
-            if compact and bsz >= _COMPACT_MIN_BATCH:
-
-                def straggler_fun(idxc):
-                    return _argarch_fb(ya[idxc], prev[idxc], nv[idxc],
-                                       n_eff[idxc], interp)
-
-            res = optim.minimize_lbfgs_batched(
-                fb, u0, max_iters=max_iters, tol=tol,
-                straggler_fun=straggler_fun, straggler_cap=cap)
-        else:
-            def obj_scaled(u, data):
-                yv, n, ne = data
-                return argarch_neg_log_likelihood(_argarch_to_natural(u), yv, n) / ne
-
-            res = optim.batched_minimize(
-                obj_scaled, u0, (ya, nv, n_eff), max_iters=max_iters, tol=tol
-            )
-        ok = nv >= 12
-        return _finalize_argarch_fit(res, ok, n_eff)
-
-    return run
+    return lockstep.fit_program(_argarch_family(backend, align_mode),
+                                max_iters, tol, compact=compact)
 
 
 @jit_program
 def _fit_argarch_stage1_program(max_iters, tol, backend, align_mode="general"):
-    """Stage 1 of the lazily compiled compact ARGARCH fit (see
-    ``models.arima._fit_stage1_program``): lockstep loop + straggler
-    gather, stage 2 compiled only when needed.  Pallas backends only."""
-
-    def run(yb):
-        ya, nv, u0, n_eff = _argarch_prep(yb, align_mode)
-        interp = backend == "pallas-interpret"
-        prev = jnp.concatenate([ya[:, :1], ya[:, :-1]], axis=1)
-        fb = _argarch_fb(ya, prev, nv, n_eff, interp)
-        cap = optim.compaction_cap(ya.shape[0])
-        res1, carry = optim.lbfgs_batched_stage1(
-            fb, u0, straggler_cap=cap, max_iters=max_iters, tol=tol)
-        ok = nv >= 12
-        # the objective closes over the NATURAL-layout panel, so the
-        # compacted problem's data is a plain row gather of each array,
-        # done here so the stage-2 program is a pure function of its inputs
-        aux = {"carry": carry, "res": res1, "yas": ya[carry.idxc],
-               "prevs": prev[carry.idxc], "nvs": nv[carry.idxc],
-               "nes": n_eff[carry.idxc], "ok": ok, "n_eff": n_eff}
-        return _finalize_argarch_fit(res1, ok, n_eff), aux
-
-    return run
+    return lockstep.stage1_program(_argarch_family(backend, align_mode),
+                                   max_iters, tol)
 
 
 @jit_program
 def _fit_argarch_stage2_program(max_iters, tol, backend):
-    """Stage 2 of the lazy compact ARGARCH fit: finish the gathered
-    stragglers and scatter back (compiled on first actual need)."""
-    interp = backend == "pallas-interpret"
-
-    def run(aux):
-        fb_s = _argarch_fb(aux["yas"], aux["prevs"], aux["nvs"],
-                           aux["nes"], interp)
-        res = optim.lbfgs_batched_stage2(
-            fb_s, aux["res"], aux["carry"], max_iters=max_iters, tol=tol)
-        return _finalize_argarch_fit(res, aux["ok"], aux["n_eff"])
-
-    return run
+    return lockstep.stage2_program(_argarch_family(backend), max_iters, tol)
 
 
 def argarch_sample(params, key, n: int):

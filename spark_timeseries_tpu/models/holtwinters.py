@@ -20,13 +20,11 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from .. import obs
 from ..utils import optim
-from .base import (FitResult, align_right, debatch,
-                   debatch_fit, derive_status,
-                   require_pallas_for_count_evals,
-                   ensure_batched, maybe_align,
-                   jit_program, resolve_align_mode, resolve_backend)
+from . import lockstep
+from .base import (FitResult, align_right, debatch_fit, ensure_batched,
+                   jit_program, maybe_align, require_pallas_for_count_evals,
+                   resolve_align_mode, resolve_backend)
 
 
 def _init_state(y, period: int, multiplicative: bool, start=None):
@@ -98,10 +96,6 @@ def sse(params, y, period: int, multiplicative: bool, n_valid=None):
     return jnp.sum(err * err)
 
 
-# module-level so tests can monkeypatch the gate per model (sizing lives
-# with the compaction feature: utils.optim)
-_COMPACT_MIN_BATCH = optim.COMPACT_MIN_BATCH
-
 # seeded multi-start inits (natural (alpha, beta, gamma) space), probed in
 # order: the long-standing default first, then two deterministic probes at
 # opposite corners of the smoothing cube.  The multiplicative SSE surface is
@@ -137,7 +131,8 @@ def fit(
 
     ``count_evals=True`` (pallas backend only) returns ``(FitResult, info)``
     with the optimizer's pass-accounting dict (``utils.optim``; multi-start
-    fits report the FIRST start's passes plus an ``n_starts`` multiplier).
+    fits report the FIRST start's passes plus an ``n_starts`` multiplier);
+    the fit that is counted is the fit that runs without the flag.
 
     ``compact=False`` disables straggler compaction for run-to-run
     reproducibility (it engages on the pallas backend at batches >=
@@ -181,149 +176,90 @@ def fit(
                               structural_ok=pk.hw_structural_ok(period))
     require_pallas_for_count_evals(count_evals, backend)
     align_mode = resolve_align_mode(yb, align_mode)
-    bsz = yb.shape[0]
-    # lazy straggler compile (utils.optim stage-1/stage-2 split): the
-    # compacted stage-2 program is traced/compiled only when a start's
-    # stage 1 actually leaves unconverged rows — same gate and host check
-    # as models.arima.fit, extended with a PER-START carry: the seeded
-    # multi-start runs several optimizer passes per fit, and each start
-    # gates its own stage-2 dispatch; the ONE stage-2 program (stable
-    # shapes across starts) is shared by every start that needs it, and
-    # the basin selection re-merges only when some start re-ran.
-    lazy = (compact and not count_evals
-            and backend in ("pallas", "pallas-interpret")
-            and not isinstance(yb, jax.core.Tracer)
-            and bsz >= _COMPACT_MIN_BATCH
-            and optim.compaction_cap(bsz) < bsz)
-    if lazy:
-        # fit.stage1: the dispatch of every start's stage 1 and the host's
-        # wait for it at the first gate below (the later starts' gates find
-        # their scalars ready); fit.stage2 only around a dispatch
-        with obs.span("fit.stage1", rows=bsz) as stage1:
-            out, aux = _fit_stage1_program(
-                period, multiplicative, max_iters, float(tol), backend,
-                align_mode, n_starts)(yb)
-            undone = [int(a["carry"].undone) for a in aux["starts"]]
-            if obs.enabled():
-                stage1.set(iters=max(int(a["carry"].k)
-                                     for a in aux["starts"]),
-                           undone=sum(undone))
-        finished, redo = [], False
-        for a, n_undone in zip(aux["starts"], undone):
-            if n_undone > 0 and int(a["carry"].k) < max_iters:
-                with obs.span("fit.stage2",
-                              rows=optim.compaction_cap(bsz)):
-                    finished.append(_fit_stage2_program(
-                        period, multiplicative, max_iters, float(tol),
-                        backend)(a))
-                redo = True
-            else:
-                finished.append(a["res"])
-        if redo:
-            out = _merge_starts_program(n_starts)(
-                tuple(finished), aux["ok"], aux["n_err"])
-        return debatch_fit(out, single, False)
-    out = _fit_program(period, multiplicative, max_iters, float(tol), backend,
-                       align_mode, count_evals, compact,
-                       n_starts)(yb)
+    static = (period, multiplicative, max_iters, float(tol), backend)
+    out = lockstep.fit(
+        (yb,), backend=backend, compact=compact, max_iters=max_iters,
+        inline=lambda: _fit_program(*static, align_mode, count_evals,
+                                    compact, n_starts),
+        stage1=lambda: _fit_stage1_program(*static, align_mode, n_starts,
+                                           count_evals),
+        stage2=lambda: _fit_stage2_program(*static),
+        merge=lambda: _merge_starts_program(*static))
+    if count_evals:
+        out = (out[0], {**out[1], "n_starts": n_starts})
     return debatch_fit(out, single, count_evals)
+
+
+def _hw_family(period, multiplicative, backend, align_mode=None,
+               n_starts=1) -> lockstep.Family:
+    from ..ops import pallas_kernels as pk
+
+    def to_natural(u):
+        return optim.sigmoid_to_interval(u, 0.0, 1.0)
+
+    def prep(yb):
+        ya, nv = maybe_align(yb, align_mode)
+        n_err = jnp.maximum(nv - period, 1).astype(yb.dtype)
+        folded = ()
+        if backend in lockstep.PALLAS:
+            # seeds are data-only: computed and folded ONCE, before the
+            # first start, and shared by every start (vmapped seed slices
+            # are batched gathers — recomputed per objective call they
+            # dominate an evaluation at panel scale; the dense mode takes
+            # the gather-free static-slice path)
+            folded = pk.hw_prefold(ya, pk.hw_seeds(
+                ya, period, multiplicative,
+                None if align_mode == "dense" else nv))
+        x0s = tuple(
+            jnp.broadcast_to(
+                optim.interval_to_sigmoid(
+                    jnp.asarray(nat0, yb.dtype), 0.0, 1.0),
+                (yb.shape[0], 3))
+            for nat0 in _MULTISTART_NATS[:n_starts])
+        # the seed needs two full seasons of real data
+        return lockstep.Prepared(x0s, nv >= 2 * period, n_err, (ya, nv),
+                                 folded)
+
+    def objective(folded, _):
+        return lambda u: pk.hw_sse_folded(
+            to_natural(u), folded, period, multiplicative,
+            interpret=backend == "pallas-interpret")
+
+    def scan_objective(u, data):
+        yv, n = data
+        return sse(to_natural(u), yv, period, multiplicative, n)
+
+    return lockstep.Family(backend, prep, objective, scan_objective,
+                           to_natural, _select_best_start)
 
 
 @jit_program
 def _fit_program(period, multiplicative, max_iters, tol, backend,
                  align_mode="general", count_evals=False, compact=True,
                  n_starts=1):
-    def run(yb):
-        ya, nv = maybe_align(yb, align_mode)
+    return lockstep.fit_program(
+        _hw_family(period, multiplicative, backend, align_mode, n_starts),
+        max_iters, tol, count_evals, compact)
 
-        # optimize the MEAN one-step squared error: same argmin as the SSE,
-        # but the gradient scale is O(1), so the relative grad-norm stopping
-        # rule fires when the fit is actually done instead of never
-        n_err = jnp.maximum(nv - period, 1).astype(yb.dtype)
-        if backend in ("pallas", "pallas-interpret"):
-            from ..ops import pallas_kernels as pk
 
-            interp = backend == "pallas-interpret"
+@jit_program
+def _fit_stage1_program(period, multiplicative, max_iters, tol, backend,
+                        align_mode="general", n_starts=1, count_evals=False):
+    return lockstep.stage1_program(
+        _hw_family(period, multiplicative, backend, align_mode, n_starts),
+        max_iters, tol, count_evals)
 
-            # seeds are data-only: compute ONCE, not per objective call or
-            # per start (vmapped seed slices are batched gathers — recomputed
-            # inside the loop they dominate an objective evaluation at panel
-            # scale; the dense mode takes the gather-free static-slice path)
-            seeds = pk.hw_seeds(
-                ya, period, multiplicative,
-                None if align_mode == "dense" else nv)
-            # ... and so is the kernel layout: fold the panel and its seeds
-            # ONCE, outside every while_loop (XLA does not hoist the [B, T]
-            # relayout out of the line search); all starts share it
-            folded = pk.hw_prefold(ya, seeds)
 
-            def fb(u):
-                nat = optim.sigmoid_to_interval(u, 0.0, 1.0)
-                return pk.hw_sse_folded(
-                    nat, folded, period, multiplicative, interpret=interp
-                ) / n_err
+@jit_program
+def _fit_stage2_program(period, multiplicative, max_iters, tol, backend):
+    return lockstep.stage2_program(
+        _hw_family(period, multiplicative, backend), max_iters, tol)
 
-            # straggler compaction (utils.optim): the subset gather repacks
-            # folded COLUMNS (series ride the lanes), grid-aligned by the cap
-            bsz = ya.shape[0]
-            cap = optim.compaction_cap(bsz)
-            straggler_fun = None
-            if compact and bsz >= _COMPACT_MIN_BATCH:
 
-                def straggler_fun(idxc):
-                    folded_s = folded.take(idxc)
-                    nes = n_err[idxc]
-
-                    def fb_s(u):
-                        nat = optim.sigmoid_to_interval(u, 0.0, 1.0)
-                        return pk.hw_sse_folded(
-                            nat, folded_s, period, multiplicative,
-                            interpret=interp) / nes
-
-                    return fb_s
-
-            def one_start(nat0, want_info):
-                u0 = jnp.broadcast_to(
-                    optim.interval_to_sigmoid(
-                        jnp.asarray(nat0, yb.dtype), 0.0, 1.0),
-                    (yb.shape[0], 3))
-                r = optim.minimize_lbfgs_batched(
-                    fb, u0, max_iters=max_iters, tol=tol,
-                    count_evals=want_info,
-                    straggler_fun=straggler_fun, straggler_cap=cap)
-                return r if want_info else (r, None)
-        else:
-            def objective(u, data):
-                yv, n, ne = data
-                nat = optim.sigmoid_to_interval(u, 0.0, 1.0)
-                return sse(nat, yv, period, multiplicative, n) / ne
-
-            def one_start(nat0, want_info):
-                u0 = jnp.broadcast_to(
-                    optim.interval_to_sigmoid(
-                        jnp.asarray(nat0, yb.dtype), 0.0, 1.0),
-                    (yb.shape[0], 3))
-                r = optim.batched_minimize(
-                    objective, u0, (ya, nv, n_err), max_iters=max_iters,
-                    tol=tol)
-                return r, None
-
-        # seeded multi-start: run the optimizer from each init and keep,
-        # per row, the best basin (_select_best_start).  Pass accounting
-        # (count_evals) reports the first start's passes; n_starts rides
-        # in the info dict as a multiplier.
-        res, info = one_start(_MULTISTART_NATS[0], count_evals)
-        if info is not None:
-            info = {**info, "n_starts": n_starts}
-        if n_starts > 1:
-            starts = [res] + [one_start(_MULTISTART_NATS[s], False)[0]
-                              for s in range(1, n_starts)]
-            res = _select_best_start(starts)
-        ok = nv >= 2 * period  # seed needs two full seasons of real data
-        out = _finalize_hw_fit(res, ok, n_err)
-        return (out, info) if count_evals else out
-
-    return run
+@jit_program
+def _merge_starts_program(period, multiplicative, max_iters, tol, backend):
+    return lockstep.merge_program(
+        _hw_family(period, multiplicative, backend))
 
 
 def _select_best_start(starts):
@@ -342,9 +278,6 @@ def _select_best_start(starts):
        alpha+beta+gamma; basins sit far apart in parameter space, so this
        comparison is float-noise-robust), ties to the earliest start.
 
-    ONE implementation serves the inline multi-start program and the lazy
-    stage-1/stage-2 split's re-merge — the basin choice must never diverge
-    between them.
     """
     if len(starts) == 1:
         return starts[0]
@@ -374,111 +307,6 @@ def _select_best_start(starts):
     if hasattr(res, "grad_norm"):
         merged["grad_norm"] = take("grad_norm")
     return res._replace(**merged)
-
-
-def _finalize_hw_fit(res, ok, n_err):
-    """Optimizer result -> FitResult (same ops as the inline program);
-    the reported objective is the unscaled SSE."""
-    params = jnp.where(
-        ok[:, None], optim.sigmoid_to_interval(res.x, 0.0, 1.0), jnp.nan)
-    return FitResult(
-        params,
-        jnp.where(ok, res.f * n_err, jnp.nan),
-        res.converged & ok,
-        res.iters,
-        derive_status(ok, res.converged, params),
-    )
-
-
-@jit_program
-def _fit_stage1_program(period, multiplicative, max_iters, tol, backend,
-                        align_mode="general", n_starts=1):
-    """Stage 1 of the lazily compiled compact Holt-Winters fit: the full
-    prep (alignment + one-time seed state) and, PER SEEDED START, the
-    lockstep L-BFGS with the straggler early-exit — returning the
-    finalized as-if-done merged result PLUS one compacted carry per start,
-    so the stage-2 program is traced/compiled only when some start's
-    ``carry.undone`` says rows actually remain (and dispatched only for
-    those starts).  Pallas backends only (the gate lives in ``fit``)."""
-
-    def run(yb):
-        ya, nv = maybe_align(yb, align_mode)
-        n_err = jnp.maximum(nv - period, 1).astype(yb.dtype)
-        from ..ops import pallas_kernels as pk
-
-        interp = backend == "pallas-interpret"
-        # seeds are data-only: compute and fold ONCE, before the first
-        # start, and share across every start (same contract as the inline
-        # program)
-        folded = pk.hw_prefold(ya, pk.hw_seeds(
-            ya, period, multiplicative,
-            None if align_mode == "dense" else nv))
-
-        def fb(u):
-            nat = optim.sigmoid_to_interval(u, 0.0, 1.0)
-            return pk.hw_sse_folded(
-                nat, folded, period, multiplicative, interpret=interp
-            ) / n_err
-
-        bsz = ya.shape[0]
-        cap = optim.compaction_cap(bsz)
-        results, starts_aux = [], []
-        for s in range(n_starts):
-            u0 = jnp.broadcast_to(
-                optim.interval_to_sigmoid(
-                    jnp.asarray(_MULTISTART_NATS[s], yb.dtype), 0.0, 1.0),
-                (bsz, 3))
-            res1, carry = optim.lbfgs_batched_stage1(
-                fb, u0, straggler_cap=cap, max_iters=max_iters, tol=tol)
-            # gather the compacted objective data HERE (the same folded-
-            # COLUMN gather the inline straggler_fun performs) so the
-            # stage-2 program is a pure function of its inputs, folds
-            # nothing and keeps stable shapes across starts — ONE compiled
-            # stage-2 program serves every start that needs it
-            starts_aux.append({
-                "carry": carry, "res": res1,
-                "folded_s": folded.take(carry.idxc),
-                "nes": n_err[carry.idxc]})
-            results.append(res1)
-        ok = nv >= 2 * period
-        out = _finalize_hw_fit(_select_best_start(results), ok, n_err)
-        return out, {"starts": tuple(starts_aux), "ok": ok, "n_err": n_err}
-
-    return run
-
-
-@jit_program
-def _fit_stage2_program(period, multiplicative, max_iters, tol, backend):
-    """Stage 2 of the lazy compact Holt-Winters fit: finish ONE start's
-    gathered stragglers on the compacted objective and scatter back into
-    that start's full-batch result — compiled on the first call where any
-    start left unconverged rows, then reused by every such start."""
-    interp = backend == "pallas-interpret"
-
-    def run(aux_s):
-        from ..ops import pallas_kernels as pk
-
-        def fb_s(u):
-            nat = optim.sigmoid_to_interval(u, 0.0, 1.0)
-            return pk.hw_sse_folded(
-                nat, aux_s["folded_s"], period, multiplicative,
-                interpret=interp) / aux_s["nes"]
-
-        return optim.lbfgs_batched_stage2(
-            fb_s, aux_s["res"], aux_s["carry"], max_iters=max_iters, tol=tol)
-
-    return run
-
-
-@jit_program
-def _merge_starts_program(n_starts):
-    """Re-merge the per-start results after lazy stage-2 dispatches: the
-    same basin selection + finalize the inline program applies."""
-
-    def run(results, ok, n_err):
-        return _finalize_hw_fit(_select_best_start(list(results)), ok, n_err)
-
-    return run
 
 
 def forecast(params, y, period: int, n_future: int, model_type: str = "additive"):

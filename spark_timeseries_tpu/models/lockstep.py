@@ -1,0 +1,249 @@
+"""The lockstep fit driver (L4): one assembly of a family's fit programs.
+
+ARIMA-CSS, Holt-Winters, GARCH and ARGARCH fit a panel the same way: prepare
+the panel once, minimize a batched objective with the lockstep L-BFGS of
+``utils.optim`` from one or several starts, and turn the optimizer's result
+into a :class:`~.base.FitResult`.  A family declares what is its own
+(:class:`Family`); this module builds the compiled programs from it and owns
+the straggler compaction's host side: the predicate that chooses the lazily
+compiled stage-1 / stage-2 pair, the gate between the two stages, and the
+``fit.stage1`` / ``fit.stage2`` spans around them.
+"""
+
+from __future__ import annotations
+
+from typing import Any, Callable, NamedTuple, Optional
+
+import jax
+import jax.numpy as jnp
+
+from .. import obs
+from ..utils import optim
+from .base import FitResult, derive_status
+
+PALLAS = ("pallas", "pallas-interpret")
+
+
+class Prepared(NamedTuple):
+    """A panel made ready for the optimizer (``Family.prep``)."""
+
+    x0s: tuple  # one [B, d] initial point per start, in optimizer space
+    ok: jax.Array  # [B] structural gate: enough observations to fit the row
+    # [B] effective observations: the optimizer minimizes the MEAN objective
+    # (the family's objective / scale) — same argmin as the sum, but O(1)
+    # gradients keep the relative stopping rule reachable at f32 instead of
+    # stalling on the accumulation noise floor of a ~1k-term sum (the
+    # reported objective is the sum, f * scale)
+    scale: jax.Array
+    series: tuple  # per-row natural-layout data of the scan objective
+    # kernel-layout pytree of the pallas objective (series ride the lanes):
+    # folded ONCE here, outside every while_loop — XLA does not hoist the
+    # [B, T] relayout out of the line search; a straggler subset is a gather
+    # of its COLUMNS (grid-aligned by the cap), so nothing is re-folded
+    folded: Any = ()
+    rows: tuple = ()  # [B, ...] arrays that objective reads row by row
+
+
+class Family(NamedTuple):
+    """What a model family declares about its fit, each thing once."""
+
+    backend: str  # resolved: "scan" | "pallas" | "pallas-interpret"
+    prep: Callable[..., Prepared]  # (panel, *extra) -> Prepared
+    # (folded, rows) -> fb(x[B, d]) -> f[B]: the fused objective (the sum,
+    # unscaled), over the whole prepared panel or a straggler subset of it
+    objective: Callable[[Any, tuple], Callable]
+    # (x[d], one row of Prepared.series) -> f: the portable per-series
+    # objective (the retry ladder's fallback rung, the tests' reference)
+    scan_objective: Callable
+    to_natural: Callable  # optimizer space [B, d] -> reported params [B, k]
+    # per-row choice among the starts' results; None declares ONE start,
+    # whose stage 2 finalizes in its own program
+    merge: Optional[Callable] = None
+
+
+def finalize(res, ok, scale, to_natural=lambda x: x) -> FitResult:
+    """Optimizer result -> FitResult: natural-space params (NaN where the
+    row could not be fit) and the unscaled objective."""
+    params = jnp.where(ok[:, None], to_natural(res.x), jnp.nan)
+    return FitResult(
+        params,
+        jnp.where(ok, res.f * scale, jnp.nan),
+        res.converged & ok,
+        res.iters,
+        derive_status(ok, res.converged, params),
+    )
+
+
+def _merged(family: Family, results):
+    return family.merge(results) if family.merge else results[0]
+
+
+def _mean_objective(family: Family, folded, rows, scale):
+    fb = family.objective(folded, rows)
+    return lambda x: fb(x) / scale
+
+
+def _stragglers(p: Prepared, idxc):
+    """The rows ``idxc`` of the pallas objective's data and scale."""
+    from ..ops import pallas_kernels as pk
+
+    return (pk.take_series(p.folded, idxc),
+            tuple(a[idxc] for a in p.rows), p.scale[idxc])
+
+
+def fit_program(family: Family, max_iters: int, tol: float,
+                count_evals: bool = False, compact: bool = True):
+    """The whole fit as ONE traceable program: small batches,
+    ``compact=False``, the scan backend, and a fit traced under a caller's
+    ``jit`` (which cannot check the straggler count on the host).  Batches
+    at or above ``optim.COMPACT_MIN_BATCH`` compact in-trace."""
+
+    def run(xb, *extra):
+        p = family.prep(xb, *extra)
+        info = None
+        if family.backend in PALLAS:
+            fb = _mean_objective(family, p.folded, p.rows, p.scale)
+            bsz = xb.shape[0]
+            straggler_fun = None
+            if compact and bsz >= optim.COMPACT_MIN_BATCH:
+
+                def straggler_fun(idxc):
+                    return _mean_objective(family, *_stragglers(p, idxc))
+
+            results = []
+            for s, x0 in enumerate(p.x0s):
+                # pass accounting reports the first start's passes
+                counted = count_evals and s == 0
+                res = optim.minimize_lbfgs_batched(
+                    fb, x0, max_iters=max_iters, tol=tol,
+                    count_evals=counted, straggler_fun=straggler_fun,
+                    straggler_cap=optim.compaction_cap(bsz))
+                if counted:
+                    res, info = res
+                results.append(res)
+        else:
+            def mean_scan(x, row):
+                return family.scan_objective(x, row[:-1]) / row[-1]
+
+            results = [
+                optim.batched_minimize(mean_scan, x0, (*p.series, p.scale),
+                                       max_iters=max_iters, tol=tol)
+                for x0 in p.x0s]
+        out = finalize(_merged(family, results), p.ok, p.scale,
+                       family.to_natural)
+        return (out, info) if count_evals else out
+
+    return run
+
+
+def stage1_program(family: Family, max_iters: int, tol: float,
+                   count_evals: bool = False):
+    """Stage 1 of the lazily compiled compact fit: the prep and, per start,
+    the lockstep loop with the straggler early exit -> the finalized
+    as-if-done result and ``{"starts": (per start: carry, res, sub), "fin":
+    (ok, scale)}``.  Pallas backends only (:func:`fit` holds the gate)."""
+
+    def run(xb, *extra):
+        p = family.prep(xb, *extra)
+        fb = _mean_objective(family, p.folded, p.rows, p.scale)
+        cap = optim.compaction_cap(xb.shape[0])
+        results, starts = [], []
+        for s, x0 in enumerate(p.x0s):
+            res1, carry = optim.lbfgs_batched_stage1(
+                fb, x0, straggler_cap=cap, max_iters=max_iters, tol=tol,
+                count_evals=count_evals and s == 0)
+            # the compacted problem's data is gathered HERE, so stage 2 is a
+            # pure function of its inputs, folds nothing, and keeps stable
+            # shapes: ONE compiled stage 2 serves every start that needs it
+            starts.append({"carry": carry, "res": res1,
+                           "sub": _stragglers(p, carry.idxc)})
+            results.append(res1)
+        out = finalize(_merged(family, results), p.ok, p.scale,
+                       family.to_natural)
+        return out, {"starts": tuple(starts), "fin": (p.ok, p.scale)}
+
+    return run
+
+
+def stage2_program(family: Family, max_iters: int, tol: float):
+    """Stage 2 of the lazy compact fit: finish ONE start's gathered
+    stragglers on the compacted objective and scatter back — compiled on
+    the first call where a stage 1 left unconverged rows.  A one-start
+    family finalizes here; with several starts the result goes to
+    :func:`merge_program`."""
+
+    def run(start, fin=None):
+        res = optim.lbfgs_batched_stage2(
+            _mean_objective(family, *start["sub"]), start["res"],
+            start["carry"],
+            max_iters=max_iters, tol=tol)
+        if family.merge is not None:
+            return res
+        if start["carry"].ls_hist is None:
+            return finalize(res, *fin, family.to_natural)
+        return finalize(res[0], *fin, family.to_natural), res[1]
+
+    return run
+
+
+def merge_program(family: Family):
+    """Re-merge the per-start results after a stage 2 ran."""
+
+    def run(results, fin):
+        return finalize(family.merge(list(results)), *fin,
+                        family.to_natural)
+
+    return run
+
+
+def fit(args: tuple, *, backend: str, compact: bool, max_iters: int,
+        inline: Callable, stage1: Callable, stage2: Callable,
+        merge: Optional[Callable] = None):
+    """Fit the panel ``args[0]`` with a family's compiled programs, each
+    given as a thunk that looks it up (only the programs that run are looked
+    up, and stage 2 is traced and compiled only when a stage 1 leaves
+    unconverged rows).  Returns what the programs return: a ``FitResult``,
+    or ``(FitResult, info)`` from programs built with ``count_evals``.
+
+    The lazy pair runs on the pallas backends when the batch is concrete
+    and large enough for the compaction to pay (``optim.COMPACT_MIN_BATCH``,
+    and a cap below the batch).  ``fit.stage1`` spans the dispatch of every
+    start's stage 1 and the host's wait for it at the first gate (the later
+    starts' gates find their scalars ready); ``fit.stage2`` opens only
+    around a dispatch.
+    """
+    xb = args[0]
+    bsz = xb.shape[0]
+    if not (compact and backend in PALLAS
+            and not isinstance(xb, jax.core.Tracer)
+            and bsz >= optim.COMPACT_MIN_BATCH
+            and optim.compaction_cap(bsz) < bsz):
+        return inline()(*args)
+    run1 = stage1()
+    with obs.span("fit.stage1", rows=bsz) as span:
+        out, aux = run1(*args)
+        starts = aux["starts"]
+        # the gate: a tiny scalar sync per start
+        undone = [int(s["carry"].undone) for s in starts]
+        if obs.enabled():
+            span.set(iters=max(int(s["carry"].k) for s in starts),
+                     undone=sum(undone))
+    results, reran, info = [], False, None
+    for start, n_undone in zip(starts, undone):
+        carry, res = start["carry"], start["res"]
+        counted = carry.ls_hist is not None
+        if counted:
+            info = optim.pass_info(carry)
+        # stage 2 shares stage 1's iteration budget, so an exhausted budget
+        # skips the dispatch (the scatter of unchanged state is an identity)
+        if n_undone > 0 and int(carry.k) < max_iters:
+            with obs.span("fit.stage2", rows=optim.compaction_cap(bsz)):
+                res = (stage2()(start) if merge
+                       else stage2()(start, aux["fin"]))
+            if counted:
+                res, info = res
+            reran = True
+        results.append(res)
+    if reran:
+        out = merge()(tuple(results), aux["fin"]) if merge else results[0]
+    return out if info is None else (out, info)

@@ -177,11 +177,11 @@ def _unfold(x3d, b: int):
     return x3d.reshape(n, -1).T[:b]
 
 
-def _take_series(folded, idxc):
+def take_series(folded, idxc):
     """The series ``idxc`` (a multiple of 1024 of them) of every leaf of a
     folded pytree: series ride the lanes, so a row gather of the natural
     layout is a column gather here and nothing is re-folded (the straggler
-    compaction, as ``models.arima`` repacks ``y3``)."""
+    compaction of ``models.lockstep``)."""
     nb = idxc.shape[0] // _LANES
     return jax.tree_util.tree_map(
         lambda x3: x3.reshape(x3.shape[0], -1)[:, idxc].reshape(
@@ -872,7 +872,7 @@ class GarchFolded:
     def take(self, idxc):
         """The series ``idxc`` (a multiple of 1024 of them) as folded
         COLUMNS: the straggler compaction re-folds nothing."""
-        return _take_series(self, idxc)
+        return take_series(self, idxc)
 
 
 def _garch_fwd_call_f(interpret, mode, params, f: GarchFolded):
@@ -1596,7 +1596,7 @@ class HWFolded:
     def take(self, idxc):
         """The series ``idxc`` (a multiple of 1024 of them) as folded
         COLUMNS: the straggler compaction re-folds nothing."""
-        return _take_series(self, idxc)
+        return take_series(self, idxc)
 
 
 def _hw_fwd_call_f(interpret, m, mult, save_resid, params, f: HWFolded):

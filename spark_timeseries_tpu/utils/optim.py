@@ -387,9 +387,7 @@ def _make_linesearch_b(fb, *, ftol, max_linesearch, c1):
 
 
 def _make_step_b(fb, *, m, dtype, tol, ftol, max_linesearch, c1):
-    """One lockstep L-BFGS iteration over a batched objective ``fb`` —
-    shared by the inline two-stage driver (:func:`minimize_lbfgs_batched`)
-    and the lazily compiled stage-1/stage-2 split."""
+    """One lockstep L-BFGS iteration over a batched objective ``fb``."""
     vg_fb = _make_vg_b(fb)
     linesearch = _make_linesearch_b(fb, ftol=ftol,
                                     max_linesearch=max_linesearch, c1=c1)
@@ -481,6 +479,48 @@ def _make_step_b(fb, *, m, dtype, tol, ftol, max_linesearch, c1):
     return step
 
 
+def _lockstep(fun_batched, x0, cap, count_evals, *, max_iters, history, tol,
+              ftol, max_linesearch, c1):
+    """Run the lockstep loop from ``x0`` until the budget is spent or at
+    most ``cap`` rows remain unconverged (``cap=None``: until none does)
+    -> ``(state, iters, ls_hist)``; ``ls_hist`` is ``None`` unless
+    ``count_evals``."""
+    bsz, _ = x0.shape
+    dtype = x0.dtype
+    if ftol is None:
+        ftol = 1e-9 if dtype == jnp.float64 else 1e-6
+    knobs = dict(m=history, dtype=dtype, tol=tol, ftol=ftol,
+                 max_linesearch=max_linesearch, c1=c1)
+    vg = _make_vg_b(fun_batched)
+    init = _init_state_b(vg, x0, history, tol)
+    iters0 = jnp.zeros((bsz,), jnp.int32)
+    ls0 = jnp.zeros((max_iters,), jnp.int32) if count_evals else None
+    step_full = _make_step_b(fun_batched, **knobs)
+
+    def cond_full(carry):
+        state, _, _ = carry
+        undone = ~(state.converged | state.failed)
+        if cap is None:
+            live = jnp.any(undone)
+            return (state.k < max_iters) & live
+        n_undone = jnp.sum(undone)
+        # keep lockstepping only while the stragglers outnumber the cap
+        return (state.k < max_iters) & (n_undone > cap)
+
+    return lax.while_loop(cond_full, step_full, (init, iters0, ls0))
+
+
+def _result_b(state, iters):
+    """(x, f, grad_norm) all refer to the best-seen iterate per row."""
+    return LBFGSResult(
+        x=state.bx,
+        f=state.bf,
+        converged=state.converged & jnp.isfinite(state.bf),
+        iters=iters,
+        grad_norm=_rownorm(state.bg),
+    )
+
+
 def minimize_lbfgs_batched(
     fun_batched: Callable[[jax.Array], jax.Array],
     x0: jax.Array,
@@ -506,159 +546,76 @@ def minimize_lbfgs_batched(
     lockstep (as they do under ``vmap`` of a ``while_loop``); finished rows
     freeze their state.
 
-    **Straggler compaction** (VERDICT r4 item 2): every lockstep pass costs
-    a full-batch objective evaluation even when most rows have converged —
-    the tail of the fit pays O(B) per iteration for O(B/8) live rows.  When
-    ``straggler_fun`` is given, the lockstep loop exits as soon as at most
-    ``straggler_cap`` rows remain unconverged; those rows (and their whole
-    optimizer state) are gathered into a ``[cap, d]`` problem whose
-    objective is ``straggler_fun(row_indices)``, the loop continues on the
-    small batch for the remaining iteration budget, and the results scatter
-    back.  In exact arithmetic per-row trajectories are identical to the
-    uncompacted run (the step-size carry, accept tests, and convergence
+    **Straggler compaction**: every lockstep pass costs a full-batch
+    objective evaluation even when most rows have converged — the tail of
+    the fit pays O(B) per iteration for O(B/8) live rows.  When
+    ``straggler_fun`` is given and ``straggler_cap`` (default
+    ``max(128, B // 8)``) is below ``B``, the fit is
+    :func:`lbfgs_batched_stage1` followed, in the same trace, by
+    :func:`lbfgs_batched_stage2` on ``straggler_fun(row_indices)``: the
+    lockstep loop exits as soon as at most ``straggler_cap`` rows remain
+    unconverged, those rows (and their whole optimizer state) continue as a
+    ``[cap, d]`` problem for the remaining iteration budget, and the results
+    scatter back.  In exact arithmetic per-row trajectories are identical to
+    the uncompacted run (the step-size carry, accept tests, and convergence
     tests are all per-row, and batched objectives compute rows
     independently) — but the compacted program IS a different compiled
     program, so f32 fusion differences exist, and rows sitting on flat or
     non-convex stretches can amplify them into different (equally valid)
     optima.  Callers should hold compaction to the same distribution-level
     parity bar as any backend change (see the bench parity gates), not to
-    bitwise equality.  ``straggler_cap`` defaults to ``max(128, B // 8)``.
+    bitwise equality.
 
-    ``count_evals=True`` (diagnostics, e.g. ``tools/profile_headline.py``)
-    additionally returns ``(result, info)`` with ``info["ls_evals"]``
-    (``[max_iters] int32`` — linesearch objective evaluations per outer
-    iteration), ``info["compact_at"]`` (iteration at which compaction
-    engaged, == iterations run when it never did), and ``info["cap"]`` —
-    the profiler instruments the REAL optimizer instead of a fork of it.
+    ``count_evals=True`` additionally returns ``(result, info)`` with
+    ``info["ls_evals"]`` (``[max_iters] int32`` — linesearch objective
+    evaluations per outer iteration), ``info["compact_at"]`` (iteration at
+    which compaction engaged, == iterations run when it never did), and
+    ``info["cap"]`` (0 when uncompacted).  The counts ride the loops' carry:
+    the optimizer that runs is the same with the flag on or off.
     """
-    bsz, d = x0.shape
-    m = history
-    dtype = x0.dtype
-    if ftol is None:
-        ftol = 1e-9 if dtype == jnp.float64 else 1e-6
-    cap = straggler_cap if straggler_cap is not None else max(128, bsz // 8)
-    compact = straggler_fun is not None and cap < bsz
-
-    knobs = dict(m=m, dtype=dtype, tol=tol, ftol=ftol,
+    bsz = x0.shape[0]
+    knobs = dict(max_iters=max_iters, history=history, tol=tol, ftol=ftol,
                  max_linesearch=max_linesearch, c1=c1)
-    vg = _make_vg_b(fun_batched)
-    init = _init_state_b(vg, x0, m, tol)
-    iters0 = jnp.zeros((bsz,), jnp.int32)
-
-    def undone_count(state):
-        return jnp.sum(~(state.converged | state.failed))
-
-    ls0 = jnp.zeros((max_iters,), jnp.int32) if count_evals else None
-    step_full = _make_step_b(fun_batched, **knobs)
-
-    def cond_full(carry):
-        state, _, _ = carry
-        live = jnp.any(~(state.converged | state.failed))
-        if compact:
-            # keep lockstepping only while the stragglers outnumber the cap
-            live = live & (undone_count(state) > cap)
-        return (state.k < max_iters) & live
-
-    stage1, iters, ls_hist = lax.while_loop(
-        cond_full, step_full, (init, iters0, ls0))
-    final = stage1
-    compact_at = stage1.k
-
-    if compact:
-        # gather the (at most cap) unconverged rows and their whole state;
-        # out-of-range fill indices read row bsz-1 and are dropped on the
-        # scatter, so duplicates never corrupt live rows.
-        #
-        # TRUNCATION CONTRACT (ADVICE r5): when stage 1 exits at max_iters
-        # with MORE than cap rows undone, this size=cap gather silently
-        # drops the excess — benign only because stage 2 shares the same
-        # exhausted iteration budget (cond_sub tests state.k <
-        # stage2_max_iters == max_iters), so the sub-loop runs zero steps
-        # and the dropped rows' state is unchanged by the scatter.  Any
-        # change that gives stage 2 its OWN budget must first make this
-        # gather lossless — the assert below is the tripwire.
-        stage2_max_iters = max_iters
-        assert stage2_max_iters == max_iters, (
-            "stage-2 straggler budget must equal max_iters while the "
-            "size=cap gather can truncate at max_iters (ADVICE r5: make "
-            "the gather lossless before giving stage 2 its own budget)")
-        # this Python block runs once per TRACE of the enclosing fit
-        # program (lru-cached jit per static config), so the counter counts
-        # stage-2 COMPILE trips, not steady-state dispatches
-        obs.counter("optim.stage2_compact_traces").inc()
-        undone1 = ~(stage1.converged | stage1.failed)
-        idx = jnp.nonzero(undone1, size=cap, fill_value=bsz)[0]
-        idxc = jnp.minimum(idx, bsz - 1)
-        take = lambda a: a[idxc]
-        sub = _State(
-            k=stage1.k,
-            x=take(stage1.x), f=take(stage1.f), g=take(stage1.g),
-            s_hist=take(stage1.s_hist), y_hist=take(stage1.y_hist),
-            rho_hist=take(stage1.rho_hist),
-            converged=take(stage1.converged), failed=take(stage1.failed),
-            tprev=take(stage1.tprev),
-            bx=take(stage1.bx), bf=take(stage1.bf), bg=take(stage1.bg),
-        )
-        step_sub = _make_step_b(straggler_fun(idxc), **knobs)
-
-        def cond_sub(carry):
-            state, _, _ = carry
-            return (state.k < stage2_max_iters) & jnp.any(
-                ~(state.converged | state.failed))
-
-        sub_f, sub_iters, ls_hist = lax.while_loop(
-            cond_sub, step_sub, (sub, take(iters), ls_hist))
-        put = lambda full, s: full.at[idx].set(s, mode="drop")
-        final = stage1._replace(
-            k=sub_f.k,
-            converged=put(stage1.converged, sub_f.converged),
-            failed=put(stage1.failed, sub_f.failed),
-            bx=put(stage1.bx, sub_f.bx),
-            bf=put(stage1.bf, sub_f.bf),
-            bg=put(stage1.bg, sub_f.bg),
-        )
-        iters = put(iters, sub_iters)
-
-    # (x, f, grad_norm) all refer to the best-seen iterate per row
-    result = LBFGSResult(
-        x=final.bx,
-        f=final.bf,
-        converged=final.converged & jnp.isfinite(final.bf),
-        iters=iters,
-        grad_norm=_rownorm(final.bg),
-    )
+    cap = straggler_cap if straggler_cap is not None else max(128, bsz // 8)
+    if straggler_fun is not None and cap < bsz:
+        res1, carry = lbfgs_batched_stage1(
+            fun_batched, x0, straggler_cap=cap, count_evals=count_evals,
+            **knobs)
+        return lbfgs_batched_stage2(
+            straggler_fun(carry.idxc), res1, carry, **knobs)
+    final, iters, ls_hist = _lockstep(
+        fun_batched, x0, None, count_evals, **knobs)
+    result = _result_b(final, iters)
     if not count_evals:
         return result
-    return result, {"ls_evals": ls_hist, "compact_at": compact_at,
-                    "cap": cap if compact else 0}
+    return result, {"ls_evals": ls_hist, "compact_at": final.k, "cap": 0}
 
 
-# -- lazily compiled straggler compaction (stage-1 / stage-2 split) ----------
+# -- straggler compaction: the stage-1 / stage-2 split ------------------------
 #
-# The inline driver above traces and compiles the compacted stage-2 program
-# into every compact fit — even when stage 1 converges all rows and the
-# sub-loop would run zero iterations, roughly doubling fit compile time for
-# batches that never need it (ADVICE r5).  The split below lets a model fit
-# run stage 1 as its own compiled program that ALSO returns the compacted
-# straggler state; the host then checks the (tiny) undone count and only
-# dispatches — and therefore only ever traces/compiles — the stage-2 program
-# when stragglers actually remain.  The decision is a pure function of the
+# Stage 1 is the lockstep loop with the early exit plus the gather of the
+# straggler state; stage 2 finishes the gathered rows and scatters them back.
+# Traced together (minimize_lbfgs_batched) they are one program.  A model fit
+# that can check a scalar on the host runs stage 1 as its own compiled
+# program and dispatches — and therefore only ever traces and compiles —
+# stage 2 when stragglers actually remain, which roughly halves compile time
+# for batches that never need it.  The decision is a pure function of the
 # fit's inputs (same data -> same undone count -> same programs), so
 # journaled resumes stay bitwise-reproducible per config.
 
 
 class StragglerCarry(NamedTuple):
-    """Stage-1 exit state a lazily compiled stage 2 resumes from.
+    """Stage-1 exit state that stage 2 resumes from.
 
     ``state`` is the full optimizer state of the (at most ``cap``)
-    unconverged rows, gathered exactly as the inline driver gathers them;
-    ``idx`` are the scatter indices (fill value ``bsz`` -> dropped on
-    scatter), ``idxc`` the clamped gather indices model code uses to
-    repack the objective's data for the compacted problem.  ``undone``
+    unconverged rows; ``idx`` are the scatter indices (fill value ``bsz`` ->
+    dropped on scatter), ``idxc`` the clamped gather indices model code uses
+    to repack the objective's data for the compacted problem.  ``undone``
     and ``k`` are the host-checkable dispatch gate: stage 2 is worth
-    dispatching iff ``undone > 0`` and ``k < max_iters`` (the shared
-    budget — see the truncation-contract tripwire in
-    :func:`minimize_lbfgs_batched`)."""
+    dispatching iff ``undone > 0`` and ``k < max_iters`` (the shared budget
+    — see the truncation contract in :func:`lbfgs_batched_stage2`).
+    ``ls_hist`` is the pass accounting of ``count_evals`` (``None`` when
+    off: no leaf, so the compiled programs are those of an uncounted fit)."""
 
     state: _State  # compacted [cap, ...] optimizer state
     idx: jax.Array  # [cap] scatter indices (fill = bsz: dropped)
@@ -666,6 +623,14 @@ class StragglerCarry(NamedTuple):
     iters: jax.Array  # [bsz] per-row iteration counts at stage-1 exit
     undone: jax.Array  # [] int32 unconverged-row count at stage-1 exit
     k: jax.Array  # [] int32 stage-1 exit iteration
+    ls_hist: "jax.Array | None" = None  # [max_iters] int32 linesearch evals
+
+
+def pass_info(carry: StragglerCarry, ls_hist=None) -> dict:
+    """The ``count_evals`` dict of a compacted fit, from its carry (and from
+    stage 2's ``ls_hist`` once that has run)."""
+    return {"ls_evals": carry.ls_hist if ls_hist is None else ls_hist,
+            "compact_at": carry.k, "cap": carry.idx.shape[0]}
 
 
 def lbfgs_batched_stage1(
@@ -679,56 +644,38 @@ def lbfgs_batched_stage1(
     ftol: float | None = None,
     max_linesearch: int = 20,
     c1: float = 1e-4,
+    count_evals: bool = False,
 ) -> "tuple[LBFGSResult, StragglerCarry]":
     """Stage 1 of the compacted batched L-BFGS, as a standalone traceable.
 
-    Runs the lockstep loop with the same early exit as the inline driver
-    (stop once at most ``straggler_cap`` rows remain unconverged), then
-    gathers the straggler state into the ``[cap, ...]`` layout and returns
-    ``(result_as_if_done, carry)``.  When no rows remain unconverged the
-    result IS the final answer (the inline stage-2 loop would have run
-    zero iterations and scattered the state back unchanged); otherwise the
-    caller dispatches :func:`lbfgs_batched_stage2` — compiled only then —
-    with a compacted objective built from ``carry.idxc``.
+    Runs the lockstep loop until at most ``straggler_cap`` rows remain
+    unconverged, then gathers the straggler state into the ``[cap, ...]``
+    layout and returns ``(result_as_if_done, carry)``.  When no rows remain
+    unconverged the result IS the final answer (stage 2 would run zero
+    iterations and scatter the state back unchanged); otherwise the caller
+    runs :func:`lbfgs_batched_stage2` with a compacted objective built from
+    ``carry.idxc``.
 
     ``straggler_cap`` must be < the batch size (callers gate on
-    :func:`compaction_cap`); semantics otherwise match
-    :func:`minimize_lbfgs_batched` (no ``count_evals``: pass accounting
-    stays on the inline driver, which the profiler instruments).
+    :func:`compaction_cap`).  ``count_evals`` threads the linesearch history
+    through the loop and hands it on as ``carry.ls_hist``
+    (:func:`pass_info`).
     """
     bsz, _ = x0.shape
-    m = history
-    dtype = x0.dtype
-    if ftol is None:
-        ftol = 1e-9 if dtype == jnp.float64 else 1e-6
     cap = int(straggler_cap)
     if cap >= bsz:
         raise ValueError(
             f"straggler_cap {cap} must be < batch {bsz} (an uncompacted fit "
             "has no stage 2 to defer — use minimize_lbfgs_batched)")
-    knobs = dict(m=m, dtype=dtype, tol=tol, ftol=ftol,
-                 max_linesearch=max_linesearch, c1=c1)
-    vg = _make_vg_b(fun_batched)
-    init = _init_state_b(vg, x0, m, tol)
-    iters0 = jnp.zeros((bsz,), jnp.int32)
-    step_full = _make_step_b(fun_batched, **knobs)
-
-    def cond_full(carry):
-        state, _, _ = carry
-        undone = jnp.sum(~(state.converged | state.failed))
-        # keep lockstepping only while the stragglers outnumber the cap
-        return (state.k < max_iters) & (undone > cap)
-
-    stage1, iters, _ = lax.while_loop(cond_full, step_full,
-                                      (init, iters0, None))
+    stage1, iters, ls_hist = _lockstep(
+        fun_batched, x0, cap, count_evals, max_iters=max_iters,
+        history=history, tol=tol, ftol=ftol, max_linesearch=max_linesearch,
+        c1=c1)
     undone1 = ~(stage1.converged | stage1.failed)
-    # same gather as the inline driver: out-of-range fill indices read row
-    # bsz-1 and are dropped on the scatter.  The TRUNCATION CONTRACT
-    # (ADVICE r5) carries over unchanged: at stage-1 exit with k == max_iters
-    # and more than cap rows undone this gather drops the excess — benign
-    # only because stage 2 shares the exhausted budget, which here is
-    # enforced twice: the tripwire assert in lbfgs_batched_stage2 AND the
-    # host gate (carry.k < max_iters) that skips the dispatch entirely.
+    # out-of-range fill indices read row bsz-1 and are dropped on the
+    # scatter, so duplicates never corrupt live rows.  At k == max_iters with
+    # more than cap rows undone this size=cap gather drops the excess: see
+    # the truncation contract in lbfgs_batched_stage2.
     idx = jnp.nonzero(undone1, size=cap, fill_value=bsz)[0]
     idxc = jnp.minimum(idx, bsz - 1)
     take = lambda a: a[idxc]
@@ -741,16 +688,10 @@ def lbfgs_batched_stage1(
         tprev=take(stage1.tprev),
         bx=take(stage1.bx), bf=take(stage1.bf), bg=take(stage1.bg),
     )
-    result = LBFGSResult(
-        x=stage1.bx,
-        f=stage1.bf,
-        converged=stage1.converged & jnp.isfinite(stage1.bf),
-        iters=iters,
-        grad_norm=_rownorm(stage1.bg),
-    )
+    result = _result_b(stage1, iters)
     carry = StragglerCarry(state=sub, idx=idx, idxc=idxc, iters=iters,
                            undone=jnp.sum(undone1).astype(jnp.int32),
-                           k=stage1.k)
+                           k=stage1.k, ls_hist=ls_hist)
     return result, carry
 
 
@@ -765,52 +706,51 @@ def lbfgs_batched_stage2(
     ftol: float | None = None,
     max_linesearch: int = 20,
     c1: float = 1e-4,
-) -> LBFGSResult:
-    """Stage 2 of the lazy split: finish the compacted stragglers.
+) -> "LBFGSResult | tuple[LBFGSResult, dict]":
+    """Stage 2: finish the compacted stragglers and scatter them back.
 
     ``fun_sub_batched`` is the compacted objective over the ``[cap, d]``
-    problem (the model builds it from ``carry.idxc`` — e.g. a row gather
-    of the panel, or the folded-column repack for the ARIMA kernel);
-    ``full`` is stage 1's as-if-done result, into which the finished
-    straggler rows are scattered.  Budget is SHARED with stage 1
-    (``carry.k`` continues counting toward the same ``max_iters``) —
-    see the truncation-contract tripwire below.
+    problem (the model builds it from ``carry.idxc`` — a row gather of the
+    panel, or a column gather of a folded one); ``full`` is stage 1's
+    as-if-done result, into which the finished straggler rows are
+    scattered: per scattered row, ``converged & isfinite(f)`` and the grad
+    norm come from the sub state, untouched rows keep stage 1's values
+    verbatim.  Returns ``(result, info)`` when stage 1 counted passes
+    (``carry.ls_hist``), else the result.
+
+    TRUNCATION CONTRACT: the budget is SHARED with stage 1 (``carry.k``
+    continues counting toward the same ``max_iters``).  The stage-1
+    ``size=cap`` gather silently drops the excess when stage 1 exits at
+    ``max_iters`` with more than ``cap`` rows undone — benign only because
+    the sub-loop then runs zero steps and the dropped rows' state is
+    unchanged by the scatter.  Any change that gives stage 2 its OWN budget
+    must first make the gather lossless.
     """
-    m = history
     dtype = carry.state.x.dtype
     if ftol is None:
         ftol = 1e-9 if dtype == jnp.float64 else 1e-6
-    # TRUNCATION CONTRACT (ADVICE r5): the stage-1 size=cap gather silently
-    # drops the excess when stage 1 exits at max_iters with more than cap
-    # rows undone — benign only because stage 2 shares the same exhausted
-    # iteration budget.  Any change that gives stage 2 its OWN budget must
-    # first make the gather lossless — this assert is the tripwire.
     stage2_max_iters = max_iters
     assert stage2_max_iters == max_iters, (
         "stage-2 straggler budget must equal max_iters while the size=cap "
-        "gather can truncate at max_iters (ADVICE r5: make the gather "
-        "lossless before giving stage 2 its own budget)")
-    # this Python block runs once per TRACE of the stage-2 program — which,
-    # unlike the inline driver, only ever happens when stragglers actually
-    # remained — so the counter now counts NEEDED stage-2 compiles
+        "gather can truncate at max_iters (make the gather lossless before "
+        "giving stage 2 its own budget)")
+    # runs once per TRACE of the enclosing program, so the counter counts
+    # stage-2 compile trips, not steady-state dispatches
     obs.counter("optim.stage2_compact_traces").inc()
-    knobs = dict(m=m, dtype=dtype, tol=tol, ftol=ftol,
-                 max_linesearch=max_linesearch, c1=c1)
-    step_sub = _make_step_b(fun_sub_batched, **knobs)
+    step_sub = _make_step_b(
+        fun_sub_batched, m=history, dtype=dtype, tol=tol, ftol=ftol,
+        max_linesearch=max_linesearch, c1=c1)
 
     def cond_sub(c):
         state, _, _ = c
         return (state.k < stage2_max_iters) & jnp.any(
             ~(state.converged | state.failed))
 
-    sub_f, sub_iters, _ = lax.while_loop(
-        cond_sub, step_sub, (carry.state, carry.iters[carry.idxc], None))
+    sub_f, sub_iters, ls_hist = lax.while_loop(
+        cond_sub, step_sub,
+        (carry.state, carry.iters[carry.idxc], carry.ls_hist))
     put = lambda a, s: a.at[carry.idx].set(s, mode="drop")
-    # scatter semantics match the inline driver's state scatter followed by
-    # its finalize: per scattered row, converged & isfinite(bf) and the
-    # grad norm are computed from the SUB state, untouched rows keep stage
-    # 1's values verbatim
-    return LBFGSResult(
+    result = LBFGSResult(
         x=put(full.x, sub_f.bx),
         f=put(full.f, sub_f.bf),
         converged=put(full.converged,
@@ -818,6 +758,9 @@ def lbfgs_batched_stage2(
         iters=put(full.iters, sub_iters),
         grad_norm=put(full.grad_norm, _rownorm(sub_f.bg)),
     )
+    if ls_hist is None:
+        return result
+    return result, pass_info(carry, ls_hist)
 
 
 def batched_minimize(

@@ -172,12 +172,13 @@ def test_pallas_kernels_compile_for_v5e(monkeypatch):
                 jax.eval_shape(hw_s1, arg(4096, 192))[1]["starts"][0])])
         garch_s1 = garch._fit_stage1_program(60, TOL, "pallas", "dense")
         programs["garch stage1"] = (garch_s1, [arg(4096, T)])
+        garch_aux = jax.tree_util.tree_map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                           sharding=sharding),
+            jax.eval_shape(garch_s1, arg(4096, T))[1])
         programs["garch stage2"] = (
             garch._fit_stage2_program(60, TOL, "pallas"),
-            [jax.tree_util.tree_map(
-                lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
-                                               sharding=sharding),
-                jax.eval_shape(garch_s1, arg(4096, T))[1])])
+            [garch_aux["starts"][0], garch_aux["fin"]])
         for name, (program, args) in programs.items():
             try:
                 program.lower(*args).compile()

@@ -103,7 +103,7 @@ def lazy_walk(tmp_path_factory):
     tmp = tmp_path_factory.mktemp("garch11_walk")
     y = panel(LAZY_ROWS, 256)
     with pytest.MonkeyPatch.context() as patch:
-        patch.setattr(garch, "_COMPACT_MIN_BATCH", LAZY_ROWS)
+        patch.setattr(optim, "COMPACT_MIN_BATCH", LAZY_ROWS)
         obs.enable(str(tmp / "ev.jsonl"))
         try:
             res = rel.fit_chunked(garch.fit, y, chunk_rows=LAZY_ROWS,
@@ -212,7 +212,7 @@ def test_lazy_spans_carry_the_gates_numbers(monkeypatch, tmp_path, fit,
     """Both lazy paths of ``models/garch.py`` open the spans of
     ``models.arima.fit``, with the gate's own scalars, and tracing leaves
     the result bitwise."""
-    monkeypatch.setattr(garch, "_COMPACT_MIN_BATCH", LAZY_ROWS)
+    monkeypatch.setattr(optim, "COMPACT_MIN_BATCH", LAZY_ROWS)
     seen = []
     stage1_name = ("_fit_stage1_program" if fit is garch.fit
                    else "_fit_argarch_stage1_program")
@@ -223,7 +223,7 @@ def test_lazy_spans_carry_the_gates_numbers(monkeypatch, tmp_path, fit,
 
         def run1(xb):
             out, aux = run(xb)
-            seen.append(aux["carry"])
+            seen.append(aux["starts"][0]["carry"])
             return out, aux
 
         return run1
@@ -266,7 +266,7 @@ def test_stage1_hands_stage2_its_stragglers_folded(monkeypatch, ragged):
     it is the lazy fit."""
     from spark_timeseries_tpu.ops import pallas_kernels as pk
 
-    monkeypatch.setattr(garch, "_COMPACT_MIN_BATCH", LAZY_ROWS)
+    monkeypatch.setattr(optim, "COMPACT_MIN_BATCH", LAZY_ROWS)
     y = np.array(panel(LAZY_ROWS, 96, seed=9))
     mode = "dense"
     if ragged:
@@ -276,18 +276,19 @@ def test_stage1_hands_stage2_its_stragglers_folded(monkeypatch, ragged):
     y = jnp.asarray(y)
     static = (80, 1e-4, "pallas-interpret")
     _, aux = garch._fit_stage1_program(*static, mode)(y)
-    assert 0 < int(aux["carry"].undone) and int(aux["carry"].k) < 80
-    idxc = aux["carry"].idxc
+    (start,) = aux["starts"]
+    assert 0 < int(start["carry"].undone) and int(start["carry"].k) < 80
+    idxc = start["carry"].idxc
     assert idxc.shape == (optim.compaction_cap(LAZY_ROWS),)
     aligned, n_valid = base.maybe_align(y, mode)
     want = pk.garch_prefold(aligned[idxc], n_valid[idxc])
-    got = aux["folded_s"]
+    got = start["sub"][0]
     assert got.t == want.t == 96
     assert np.array_equal(np.asarray(got.r23), np.asarray(want.r23))
     assert np.array_equal(np.asarray(got.zb3), np.asarray(want.zb3))
     np.testing.assert_allclose(np.asarray(got.h03), np.asarray(want.h03),
                                rtol=1e-6)
-    out = garch._fit_stage2_program(*static)(aux)
+    out = garch._fit_stage2_program(*static)(start, aux["fin"])
     fit = garch.fit(y, backend="pallas-interpret")
     for a, b in zip(out, fit):
         assert np.array_equal(np.asarray(a), np.asarray(b), equal_nan=True)

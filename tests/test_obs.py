@@ -673,7 +673,7 @@ class TestStageGateSpans:
 
     @pytest.fixture()
     def lazy(self, monkeypatch):
-        monkeypatch.setattr(arima, "_COMPACT_MIN_BATCH", 2048)
+        monkeypatch.setattr(optim, "COMPACT_MIN_BATCH", 2048)
         seen = []
         real = arima._fit_stage1_program
 
@@ -682,7 +682,7 @@ class TestStageGateSpans:
 
             def run1(*args):
                 out, aux = run(*args)
-                seen.append(aux["carry"])
+                seen.append(aux["starts"][0]["carry"])
                 return out, aux
 
             return run1
@@ -722,3 +722,85 @@ class TestStageGateSpans:
             assert spans["fit.stage2"]["attrs"] == {
                 "rows": optim.compaction_cap(2048)}
             assert spans["fit.stage2"]["parent"] == primary.id
+
+    @pytest.mark.parametrize("family", ["arima", "holtwinters", "garch"])
+    def test_count_evals_instruments_the_fit_that_runs(self, monkeypatch,
+                                                       tmp_path, family):
+        # the flag selects no program: a counted fit takes the lazy pair,
+        # returns the uncounted fit's bits, and its info is the gate's
+        monkeypatch.setattr(optim, "COMPACT_MIN_BATCH", 2048)
+        fit = _LAZY_FITS[family]()
+        plain = fit()
+        p = str(tmp_path / "ev.jsonl")
+        obs.enable(p)
+        counted, info = fit(count_evals=True)
+        obs.disable()
+        _assert_bitwise(counted, plain)
+        spans = {s["name"]: s for s in _span_lines(p)}
+        at = spans["fit.stage1"]["attrs"]["iters"]
+        assert "fit.stage2" in spans
+        assert int(info["cap"]) == optim.compaction_cap(2048)
+        assert int(info["compact_at"]) == at
+        evals = np.asarray(info["ls_evals"])
+        assert evals[:at].min() >= 1 and evals[at:].any()
+
+    def test_fit_under_a_callers_jit_is_the_composed_program(
+            self, monkeypatch, tmp_path):
+        # a traced panel cannot be gated on the host: the fit runs stage 1
+        # and stage 2 in one trace, with no stage spans, to the eager lazy
+        # fit's answer (another compiled program: the slow groups' rule)
+        import jax
+
+        from test_pallas import _dist_parity
+
+        monkeypatch.setattr(optim, "COMPACT_MIN_BATCH", 2048)
+        rng = np.random.default_rng(0)
+        y = jnp.asarray(np.cumsum(rng.normal(size=(2048, 40)),
+                                  axis=1).astype(np.float32))
+        fit = lambda v: arima.fit(v, (1, 1, 1), max_iters=13,  # noqa: E731
+                                  backend="pallas-interpret",
+                                  align_mode="dense")
+        eager = fit(y)
+        p = str(tmp_path / "ev.jsonl")
+        obs.enable(p)
+        stage2 = obs.counter("optim.stage2_compact_traces")
+        before = stage2.value
+        traced = jax.jit(fit)(y)
+        assert stage2.value == before + 1  # stage 2 is in that one trace
+        obs.disable()
+        assert not any(s["name"].startswith("fit.stage")
+                       for s in _span_lines(p))
+        _dist_parity(eager, traced, conv_floor=0.3)
+
+
+def _lazy_arima():
+    rng = np.random.default_rng(0)
+    y = jnp.asarray(np.cumsum(rng.normal(size=(2048, 40)),
+                              axis=1).astype(np.float32))
+    return lambda **kw: arima.fit(y, (1, 1, 1), backend="pallas-interpret",
+                                  max_iters=14, **kw)
+
+
+def _lazy_holtwinters():
+    from spark_timeseries_tpu.models import holtwinters as hw
+
+    rng = np.random.default_rng(32)
+    tt = np.arange(48, dtype=np.float32)
+    w = (10 + 0.02 * tt[None, :] + 2 * np.sin(2 * np.pi * tt[None, :] / 12)
+         + 0.3 * rng.normal(size=(2048, 48))).astype(np.float32)
+    w = jnp.asarray(w)
+    return lambda **kw: hw.fit(w, 12, backend="pallas-interpret",
+                               max_iters=13, **kw)
+
+
+def _lazy_garch():
+    from spark_timeseries_tpu.models import garch
+
+    rng = np.random.default_rng(31)
+    r = jnp.asarray((rng.normal(size=(2048, 64)) * 0.1).astype(np.float32))
+    return lambda **kw: garch.fit(r, backend="pallas-interpret",
+                                  max_iters=13, **kw)
+
+
+_LAZY_FITS = {"arima": _lazy_arima, "holtwinters": _lazy_holtwinters,
+              "garch": _lazy_garch}

@@ -192,12 +192,11 @@ class TestStragglerCompaction:
 
 
 class TestLazyStage2Split:
-    """The stage-1/stage-2 split (ISSUE 4 satellite, ADVICE r5) must
-    reproduce the inline compacted driver: stage 1 is the same lockstep
-    loop with the same early exit, the gather is the same gather, and a
-    dispatched stage 2 continues the same trajectories — only WHERE the
-    stage-2 program is traced/compiled moves (to the first call that
-    actually has stragglers)."""
+    """``minimize_lbfgs_batched`` with a ``straggler_fun`` IS stage 1
+    followed by stage 2 in one trace; run apart (a model fit's lazily
+    compiled pair) they must return the same bits and the same pass
+    counts — only WHERE the stage-2 program is traced/compiled moves (to
+    the first call that actually has stragglers)."""
 
     def test_split_matches_inline_compaction(self):
         fun, straggler_fun, x0, _ = _straggler_problem()
@@ -222,6 +221,40 @@ class TestLazyStage2Split:
         np.testing.assert_allclose(np.asarray(ref.grad_norm),
                                    np.asarray(got.grad_norm),
                                    rtol=0, atol=0)
+
+    def test_split_counts_the_passes_of_the_composed_fit(self):
+        # count_evals adds a carry element and selects no program: the
+        # counted split returns the uncounted split's result, and stage 1's
+        # history, handed on by the carry and finished by stage 2, is the
+        # info of the composed call
+        fun, straggler_fun, x0, _ = _straggler_problem()
+        ref, info = optim.minimize_lbfgs_batched(
+            fun, x0, max_iters=80, straggler_fun=straggler_fun,
+            straggler_cap=16, count_evals=True)
+        plain1, plain_carry = optim.lbfgs_batched_stage1(
+            fun, x0, straggler_cap=16, max_iters=80)
+        assert plain_carry.ls_hist is None
+        plain = optim.lbfgs_batched_stage2(
+            straggler_fun(plain_carry.idxc), plain1, plain_carry,
+            max_iters=80)
+        res1, carry = optim.lbfgs_batched_stage1(
+            fun, x0, straggler_cap=16, max_iters=80, count_evals=True)
+        at = int(carry.k)
+        hist1 = np.asarray(carry.ls_hist)
+        assert hist1[:at].min() >= 1 and not hist1[at:].any()
+        assert set(optim.pass_info(carry)) == set(info)
+        got, got_info = optim.lbfgs_batched_stage2(
+            straggler_fun(carry.idxc), res1, carry, max_iters=80)
+        for a, b, c in zip(ref, got, plain):
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(c))
+        assert int(got_info["cap"]) == int(info["cap"]) == 16
+        assert int(got_info["compact_at"]) == int(info["compact_at"]) == at
+        np.testing.assert_array_equal(np.asarray(got_info["ls_evals"]),
+                                      np.asarray(info["ls_evals"]))
+        hist = np.asarray(got_info["ls_evals"])
+        np.testing.assert_array_equal(hist[:at], hist1[:at])
+        assert hist[at:].any()  # stage 2's passes are counted too
 
     def test_no_stragglers_means_no_stage2(self):
         # uniform conditioning: every row converges on the same iteration,

@@ -497,7 +497,7 @@ def test_garch_fit_programs_fold_outside_their_loops(monkeypatch, align_mode):
     # compaction) relayouts a panel-sized operand
     from spark_timeseries_tpu.models import garch
 
-    monkeypatch.setattr(garch, "_COMPACT_MIN_BATCH", 2048)
+    monkeypatch.setattr(optim, "COMPACT_MIN_BATCH", 2048)
     b, t = 2048, 48
     y = jax.ShapeDtypeStruct((b, t), jnp.float32)
     static = (13, 1e-4, "pallas-interpret")
@@ -505,12 +505,14 @@ def test_garch_fit_programs_fold_outside_their_loops(monkeypatch, align_mode):
     inline = garch._fit_program.__wrapped__(*static, align_mode, False, True)
     stage2 = garch._fit_stage2_program.__wrapped__(*static)
     aux = jax.eval_shape(stage1, y)[1]
-    cap = optim.compaction_cap(b)
-    assert aux["folded_s"].r23.shape == (t, cap // 128, 128)
-    assert "ras" not in aux and "nvs" not in aux
-    for fn, arg, n_panel in ((stage1, y, b * t), (inline, y, b * t),
-                             (stage2, aux, cap * t)):
-        jaxpr = jax.make_jaxpr(fn)(arg).jaxpr
+    (start,), cap = aux["starts"], optim.compaction_cap(b)
+    folded_s, rows_s, scale_s = start["sub"]
+    assert folded_s.r23.shape == (t, cap // 128, 128)
+    # beside the fold stage 2 is handed one row vector, no panel
+    assert rows_s == () and scale_s.shape == (cap,)
+    for fn, args, n_panel in ((stage1, (y,), b * t), (inline, (y,), b * t),
+                              (stage2, (start, aux["fin"]), cap * t)):
+        jaxpr = jax.make_jaxpr(fn)(*args).jaxpr
         assert any(e.primitive.name == "while" for e in jaxpr.eqns)
         assert _panel_relayouts_in_loops(jaxpr, n_panel) == []
     # the detector sees what it is for: the fold-per-call API in a loop
@@ -575,7 +577,7 @@ def test_garch_fit_pinned_to_the_fold_per_call_parent(monkeypatch, path):
     if host != _GARCH_PIN_HOST:
         pytest.skip("another XLA:CPU code generator than the recording's")
     if path.startswith("lazy"):
-        monkeypatch.setattr(garch, "_COMPACT_MIN_BATCH", 2048)
+        monkeypatch.setattr(optim, "COMPACT_MIN_BATCH", 2048)
     assert _fit_pin_digest(_garch_pin_fit(path)) == _GARCH_PIN[path]
 
 
@@ -834,7 +836,7 @@ def test_hw_fit_programs_fold_outside_their_loops(monkeypatch, align_mode,
     # compaction) relayouts a panel-sized operand
     from spark_timeseries_tpu.models import holtwinters as hw
 
-    monkeypatch.setattr(hw, "_COMPACT_MIN_BATCH", 2048)
+    monkeypatch.setattr(optim, "COMPACT_MIN_BATCH", 2048)
     b, t, m = 2048, 48, 6
     mult = model_type == "multiplicative"
     n_starts = 3 if mult else 1
@@ -846,7 +848,7 @@ def test_hw_fit_programs_fold_outside_their_loops(monkeypatch, align_mode,
     stage2 = hw._fit_stage2_program.__wrapped__(*static)
     aux = jax.eval_shape(stage1, y)[1]["starts"][0]
     cap = optim.compaction_cap(b)
-    assert aux["folded_s"].y3.shape == (t, cap // 128, 128)
+    assert aux["sub"][0].y3.shape == (t, cap // 128, 128)
     for fn, arg, n_panel in ((stage1, y, b * t), (inline, y, b * t),
                              (stage2, aux, cap * t)):
         jaxpr = jax.make_jaxpr(fn)(arg).jaxpr
@@ -930,7 +932,7 @@ def test_hw_fit_pinned_to_the_fold_per_call_parent(monkeypatch, path,
     if host != _HW_PIN_HOST:
         pytest.skip("another XLA:CPU code generator than the recording's")
     if path == "lazy":
-        monkeypatch.setattr(hw, "_COMPACT_MIN_BATCH", 2048)
+        monkeypatch.setattr(optim, "COMPACT_MIN_BATCH", 2048)
     assert _fit_pin_digest(_hw_pin_fit(path, model_type)) == _HW_PIN[
         f"{path}-{model_type}"]
 
@@ -1334,7 +1336,7 @@ def test_arima_fit_straggler_compaction_parity(monkeypatch):
     # program; max_iters=14 is unique to this test so jit_program's cache
     # cannot hand either fit a program traced under the other's threshold
     ref = arima.fit(y, (1, 1, 1), backend="pallas-interpret", max_iters=14)
-    monkeypatch.setattr(arima, "_COMPACT_MIN_BATCH", 2048)
+    monkeypatch.setattr(optim, "COMPACT_MIN_BATCH", 2048)
     (got, info) = arima.fit(y, (1, 1, 1), backend="pallas-interpret",
                             max_iters=14, count_evals=True)
     assert int(info["cap"]) == 1024
@@ -1368,7 +1370,7 @@ def test_arima_lazy_stage2_split_parity(monkeypatch):
     y = jnp.asarray(_arma_panel(b, t, seed=78))
     ref = arima.fit(y, (1, 1, 1), backend="pallas-interpret", max_iters=15,
                     compact=False)
-    monkeypatch.setattr(arima, "_COMPACT_MIN_BATCH", 2048)
+    monkeypatch.setattr(optim, "COMPACT_MIN_BATCH", 2048)
     got = arima.fit(y, (1, 1, 1), backend="pallas-interpret", max_iters=15)
     _dist_parity(ref, got)
 
@@ -1395,12 +1397,20 @@ def test_garch_fit_straggler_compaction_parity(monkeypatch):
     rng = np.random.default_rng(31)
     r = jnp.asarray((rng.normal(size=(2048, 96)) * 0.1).astype(np.float32))
     ref = garch.fit(r, backend="pallas-interpret", max_iters=13)
-    monkeypatch.setattr(garch, "_COMPACT_MIN_BATCH", 2048)
+    monkeypatch.setattr(optim, "COMPACT_MIN_BATCH", 2048)
     got, info = garch.fit(r, backend="pallas-interpret", max_iters=13,
                           count_evals=True)
     assert int(info["cap"]) == 1024
     assert int(info["compact_at"]) < 13
     _dist_parity(ref, got)
+    _traced_fit_parity(got, lambda v: garch.fit(
+        v, backend="pallas-interpret", max_iters=13, align_mode="dense"), r)
+
+
+def _traced_fit_parity(lazy, fit, panel, **kw):
+    # the same fit under a caller's jit (the panel a Tracer: stage 1 and
+    # stage 2 composed in one trace) against the eager lazy pair
+    _dist_parity(lazy, jax.jit(fit)(panel), **kw)
 
 
 @pytest.mark.slow  # minutes-scale interpret-mode sweep: tier-2 (`-m slow`), see pyproject markers
@@ -1413,7 +1423,7 @@ def test_hw_fit_straggler_compaction_parity(monkeypatch):
          + 0.3 * rng.normal(size=(2048, 96))).astype(np.float32)
     w = jnp.asarray(w)
     ref = hw.fit(w, 24, "additive", backend="pallas-interpret", max_iters=13)
-    monkeypatch.setattr(hw, "_COMPACT_MIN_BATCH", 2048)
+    monkeypatch.setattr(optim, "COMPACT_MIN_BATCH", 2048)
     got, info = hw.fit(w, 24, "additive", backend="pallas-interpret",
                        max_iters=13, count_evals=True)
     assert int(info["cap"]) == 1024
@@ -1439,9 +1449,12 @@ def test_hw_lazy_stage2_split_parity(monkeypatch, model_type):
     w = jnp.asarray(w)
     ref = hw.fit(w, 24, model_type, backend="pallas-interpret", max_iters=13,
                  compact=False)
-    monkeypatch.setattr(hw, "_COMPACT_MIN_BATCH", 2048)
+    monkeypatch.setattr(optim, "COMPACT_MIN_BATCH", 2048)
     got = hw.fit(w, 24, model_type, backend="pallas-interpret", max_iters=13)
     _dist_parity(ref, got)
+    _traced_fit_parity(got, lambda v: hw.fit(
+        v, 24, model_type, backend="pallas-interpret", max_iters=13,
+        align_mode="dense"), w)
 
 
 @pytest.mark.slow  # minutes-scale interpret-mode sweep: tier-2 (`-m slow`), see pyproject markers
@@ -1454,12 +1467,15 @@ def test_argarch_lazy_stage2_split_parity(monkeypatch):
     y = jnp.asarray((rng.normal(size=(2048, 96)) * 0.1).astype(np.float32))
     ref = garch.fit_argarch(y, backend="pallas-interpret", max_iters=13,
                             compact=False)
-    monkeypatch.setattr(garch, "_COMPACT_MIN_BATCH", 2048)
+    monkeypatch.setattr(optim, "COMPACT_MIN_BATCH", 2048)
     got = garch.fit_argarch(y, backend="pallas-interpret", max_iters=13)
     # the 5-param AR(1)+GARCH objective converges ~37% of rows in a
     # 13-iteration test budget (~760 rows both-converged — still a
     # meaningful parity sample; the quality gates carry the claim)
     _dist_parity(ref, got, conv_floor=0.30)
+    _traced_fit_parity(got, lambda v: garch.fit_argarch(
+        v, backend="pallas-interpret", max_iters=13, align_mode="dense"), y,
+        conv_floor=0.30)
 
 
 @pytest.mark.parametrize("mult", [False, True])
