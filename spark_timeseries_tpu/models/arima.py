@@ -496,7 +496,9 @@ def fit(
             has_init, align_mode, count_evals, compact),
         stage1=lambda: _fit_stage1_program(*static, has_init, align_mode,
                                            count_evals),
-        stage2=lambda: _fit_stage2_program(*static))
+        stage2=lambda: _fit_stage2_program(*static),
+        series_block=lambda rows: pk.css_series_block(
+            rows, yb.shape[1] - d, order))
     return debatch_fit(out, single, count_evals)
 
 

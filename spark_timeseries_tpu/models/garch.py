@@ -159,8 +159,15 @@ def fit(r, *, max_iters: int = 80, tol: Optional[float] = None,
         inline=lambda: _fit_program(*static, align_mode, count_evals,
                                     compact),
         stage1=lambda: _fit_stage1_program(*static, align_mode, count_evals),
-        stage2=lambda: _fit_stage2_program(*static))
+        stage2=lambda: _fit_stage2_program(*static),
+        series_block=lambda rows: _garch_series_block(rows, rb.shape[1]))
     return debatch_fit(out, single, count_evals)
+
+
+def _garch_series_block(rows, t):
+    from ..ops import pallas_kernels as pk
+
+    return pk.garch_series_block(rows, t)
 
 
 def _garch_family(backend, align_mode=None) -> lockstep.Family:
@@ -362,7 +369,8 @@ def fit_argarch(y, *, max_iters: int = 100, tol: Optional[float] = None,
         (yb,), backend=backend, compact=compact, max_iters=max_iters,
         inline=lambda: _fit_argarch_program(*static, compact, align_mode),
         stage1=lambda: _fit_argarch_stage1_program(*static, align_mode),
-        stage2=lambda: _fit_argarch_stage2_program(*static))
+        stage2=lambda: _fit_argarch_stage2_program(*static),
+        series_block=lambda rows: _garch_series_block(rows, yb.shape[1]))
     return debatch(out, single)
 
 

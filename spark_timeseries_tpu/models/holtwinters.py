@@ -184,7 +184,9 @@ def fit(
         stage1=lambda: _fit_stage1_program(*static, align_mode, n_starts,
                                            count_evals),
         stage2=lambda: _fit_stage2_program(*static),
-        merge=lambda: _merge_starts_program(*static))
+        merge=lambda: _merge_starts_program(*static),
+        series_block=lambda rows: pk.hw_series_block(
+            rows, yb.shape[1], period))
     if count_evals:
         out = (out[0], {**out[1], "n_starts": n_starts})
     return debatch_fit(out, single, count_evals)

@@ -12,7 +12,10 @@ round trips — per time step.
 These kernels fuse the recursion into grid steps whose series block lives in
 VMEM: series are folded to ``[time, 8, 128]`` tiles (sublane x lane = 1024
 series per block), the natural f32 vector-register shape, so every time step
-is a handful of full-width VPU ops instead of an XLA loop iteration.
+is a handful of full-width VPU ops instead of an XLA loop iteration.  The
+forward fit-objective kernels (CSS, GARCH, Holt-Winters) take ``R`` such
+tiles per grid step (:func:`series_rows`): ``R`` independent recurrence
+chains share one loop iteration.
 
 SERIES LENGTH IS UNBOUNDED: the grid is ``(series_block, time_chunk)`` with
 the chunk axis innermost (TPU iterates it sequentially), each chunk holding
@@ -79,15 +82,69 @@ _VMEM_PARAMS = pltpu.CompilerParams(vmem_limit_bytes=100 * 1024 * 1024)
 
 _ZERO = lambda: jnp.zeros((_SUBL, _LANES), jnp.float32)  # noqa: E731
 
+# -- the series-block width of the forward fit-objective kernels ------------
+#
+# A recurrence kernel's time step is a chain of dependent vector ops on ONE
+# register of 1,024 series: the VPU waits out each op's latency with nothing
+# else to issue.  The forward kernels of the three fit objectives therefore
+# take R registers of series per step — blocks of ``(cs, 8 * R, 128)`` — so
+# R independent chains share one loop iteration and one basic block.  No
+# series' arithmetic or accumulation order changes, series never mix, and
+# the HBM arrays keep their layout: only the grid and the block shape differ.
+_R_CHOICES = (4, 2)  # tried widest first; 1 is today's block
+_TILE_BYTES = _SBLK * 4  # one (8, 128) f32 tile
+# what one call's pipelined blocks and scratch may take of _VMEM_PARAMS'
+# 100 MB; the rest is Mosaic's own (its internal scratch, spilled vregs)
+_VMEM_BLOCK_BUDGET = 88 * 1024 * 1024
+
+
+def _vmem_bytes(layout, r: int = 1) -> int:
+    """What a call of ``layout`` = ``(ins, outs, scratch)`` holds in VMEM at
+    width ``r``: every input and output block twice (the pipeline's double
+    buffer, the ``_prev`` neighbour blocks included), scratch once.  ``ins``
+    / ``outs``: ``(leading size, index map)`` pairs of ``(n, 8 r, 128)``
+    blocks; ``scratch``: leading sizes."""
+    ins, outs, scratch = layout
+    return (2 * sum(n for n, _ in ins + outs) + sum(scratch)) * r * _TILE_BYTES
+
+
+def series_rows(nsub: int, layout, r_best: int) -> int:
+    """R, the vector registers of series a forward objective kernel takes
+    per time step — from static facts only: ``nsub`` (= ``Bp / 128``, the
+    folded panel's sublane rows) divisible by ``8 * R``; the call's VMEM
+    (:func:`_vmem_bytes` of its ``layout``) inside ``_VMEM_BLOCK_BUDGET``;
+    and at most ``r_best``, the width the chip showed best for this kernel
+    and mode (``_CSS_R`` / ``_GARCH_R`` / ``_HW_R``).  A 256-row serving
+    batch, a padded retry bucket, a compaction cap that is 1,024- but not
+    2,048-aligned: R = 1, today's program."""
+    for r in _R_CHOICES:
+        if (r <= r_best and nsub % (_SUBL * r) == 0
+                and _vmem_bytes(layout, r) <= _VMEM_BLOCK_BUDGET):
+            return r
+    return 1
+
+
+def _nsub(rows: int) -> int:
+    return (rows + _pad_to(rows, _SBLK)) // _LANES
+
+
+def _plane_zero(ref):
+    """A zero ``(8 * R, 128)`` plane: one time step of ``ref``'s block."""
+    return jnp.zeros(ref.shape[1:], jnp.float32)
+
 
 def _fori(n, body, init, unroll: int = 1):
     """Sequential time loop with the index coerced to int32: under
     ``jax_enable_x64`` the loop variable would otherwise trace as int64,
-    which pallas ref indexing cannot lower.  (Unrolling was measured to buy
-    nothing for the RECURSION kernels — their true data dependencies, not
-    loop overhead, bound each step — but the fill sweeps' dependency chains
-    are one select deep, and there loop machinery dominates: pass
-    ``unroll`` > 1 for those.)"""
+    which pallas ref indexing cannot lower.  (Unrolling buys nothing for the
+    RECURSION kernels: a step waits for the step before it, so the next
+    iteration's ops cannot fill the idle slots.  What fills them is a second
+    INDEPENDENT chain in the same iteration — :func:`series_rows`; on a v5e
+    a step of the CSS / Holt-Winters / GARCH value-only kernels costs 17.6 /
+    21.0 / 29.6 ns on one register of series and 22.9 / 28.2 / 35.4 ns on
+    four, PERF.md §6, PR 31.  The fill sweeps' dependency chains are one
+    select deep, and there loop machinery dominates: pass ``unroll`` > 1
+    for those.)"""
 
     def body32(i, carry):
         return body(jnp.asarray(i, jnp.int32), carry)
@@ -188,8 +245,8 @@ def take_series(folded, idxc):
             x3.shape[0], nb, _LANES), folded)
 
 
-def _bs(n0: int, imap):
-    return pl.BlockSpec((n0, _SUBL, _LANES), imap)
+def _bs(n0: int, imap, r: int = 1):
+    return pl.BlockSpec((n0, _SUBL * r, _LANES), imap)
 
 
 def _cur(blk, c):  # current time chunk
@@ -259,12 +316,14 @@ def _css_fwd_kernel(p, q, t_limit, cs, hp, mode, *refs):
     base = c * cs
     zb = zb_ref[0]
 
+    zero = _plane_zero(zb_ref)
+
     @pl.when(c == 0)
     def _():
         for j in range(max(q, 1)):
-            ce_ref[j] = _ZERO()
+            ce_ref[j] = zero
         if css_ref is not None:
-            css_ref[0] = _ZERO()
+            css_ref[0] = zero
 
     def body(tl, acc):
         t = base + tl
@@ -287,9 +346,10 @@ def _css_fwd_kernel(p, q, t_limit, cs, hp, mode, *refs):
         return (acc + e * e) if css_ref is not None else acc
 
     # (a guarded-prologue / unguarded-steady-state split was measured to buy
-    # nothing: the recursion's serial data dependency, not the boundary
-    # selects, bounds each step)
-    acc = _fori(cs, body, _ZERO() if css_ref is not None else 0)
+    # nothing: a step is 20 bundles that wait on one another with most
+    # slots empty, 21 for two registers of series and 26 for four — the
+    # block width, not the boundary selects, is what a step's cost divides by)
+    acc = _fori(cs, body, zero if css_ref is not None else 0)
     if css_ref is not None:
         css_ref[0] = css_ref[0] + acc
     if tail_ref is not None:
@@ -433,46 +493,74 @@ def _css_fwd_call(p, q, interpret, mode, params, yd, zb):
     return _css_fwd_call_f(p, q, interpret, mode, params, y3, zb3, t)
 
 
-def _css_fwd_call_f(p, q, interpret, mode, params, y3, zb3, t):
+# the width the chip showed best, per mode (PERF.md §6, PR 31: ms a call
+# over [131072, 1000] at R = 1 / 2 / 4 — sum 2.25 / 1.15 / 0.73, both 2.27 /
+# 1.58 / 1.56, e 2.00 / 1.56 / 1.56, the last two at the HBM's pace; "tail"
+# writes no panel either and rides with "sum")
+_CSS_R = {"sum": 4, "both": 4, "e": 4, "tail": 4}
+
+
+def _css_fwd_layout(p, q, mode, t):
+    """The forward CSS call's blocks -> ``(ins, outs, scratch)``
+    (:func:`_vmem_bytes`)."""
+    _, cs, nchunk = _time_layout(t)
+    ins = ([(cs, _cur)] + ([(cs, _prev)] if nchunk > 1 else [])
+           + [(1 + p + q, _fixed), (1, _fixed)])
+    outs = []
+    if mode in ("e", "both"):
+        outs.append((cs, _cur))
+    if mode in ("sum", "both"):
+        outs.append((1, _fixed))
+    if mode == "tail":
+        outs.append((max(q, 1), _fixed))
+    # errors live in VMEM only; the cross-chunk error carry
+    scratch = ([cs] if mode in ("sum", "tail") and q > 0 else []) + [max(q, 1)]
+    return ins, outs, scratch
+
+
+def css_series_block(rows: int, t: int, order: Order, mode: str = "sum") -> int:
+    """Series per grid step of the forward CSS kernel over ``rows`` series
+    of (differenced) length ``t``: ``1024 * R`` (:func:`series_rows`)."""
+    p, _, q = order
+    return _SBLK * series_rows(
+        _nsub(rows), _css_fwd_layout(p, q, mode, t), _CSS_R[mode])
+
+
+def _fwd_call(kernel, layout, r, interpret, args):
+    """One forward ``pallas_call`` over ``(cs, 8 * r, 128)`` blocks of the
+    folded operands ``args``, the panel ``[tp, nsub, 128]`` first; a
+    ``_cur`` output is a panel too, any other a few planes."""
+    ins, outs, scratch = layout
+    tp, nsub, _ = args[0].shape
+    return pl.pallas_call(
+        kernel,
+        grid=(nsub // (_SUBL * r), tp // ins[0][0]),
+        in_specs=[_bs(n, im, r) for n, im in ins],
+        out_specs=[_bs(n, im, r) for n, im in outs],
+        out_shape=[jax.ShapeDtypeStruct(
+            (tp if im is _cur else n, nsub, _LANES), args[0].dtype)
+            for n, im in outs],
+        scratch_shapes=[pltpu.VMEM((n, _SUBL * r, _LANES), jnp.float32)
+                        for n in scratch],
+        compiler_params=_VMEM_PARAMS,
+        interpret=interpret,
+    )(*args)
+
+
+def _css_fwd_call_f(p, q, interpret, mode, params, y3, zb3, t, _r=None):
     # pre-FOLDED entry: y3/zb3 already in kernel layout.  The fit objective
     # is evaluated hundreds of times inside one lax.while_loop, and XLA does
     # not reliably hoist the [B, T] zero-mask + fold transpose out of the
     # loop body — callers that fold once (css_prefold) skip that cost on
-    # every evaluation.
-    k = 1 + p + q
+    # every evaluation.  ``_r`` forces the block width (tests only).
     par3 = _fold(params)  # [B, k]: trivially small
-    tp, cs, nchunk = _time_layout(t)
-    nblk = y3.shape[1] // _SUBL
+    _, cs, nchunk = _time_layout(t)
     hp = nchunk > 1
-    out_specs, out_shape = [], []
-    if mode in ("e", "both"):
-        out_specs.append(_bs(cs, _cur))
-        out_shape.append(jax.ShapeDtypeStruct(y3.shape, y3.dtype))
-    if mode in ("sum", "both"):
-        out_specs.append(_bs(1, _fixed))
-        out_shape.append(
-            jax.ShapeDtypeStruct((1, y3.shape[1], _LANES), y3.dtype)
-        )
-    if mode == "tail":
-        out_specs.append(_bs(max(q, 1), _fixed))
-        out_shape.append(
-            jax.ShapeDtypeStruct((max(q, 1), y3.shape[1], _LANES), y3.dtype)
-        )
-    scratch = []
-    if mode in ("sum", "tail") and q > 0:  # errors live in VMEM only
-        scratch.append(pltpu.VMEM((cs, _SUBL, _LANES), jnp.float32))
-    scratch.append(pltpu.VMEM((max(q, 1), _SUBL, _LANES), jnp.float32))
-    outs = pl.pallas_call(
+    layout = _css_fwd_layout(p, q, mode, t)
+    r = _r or series_rows(y3.shape[1], layout, _CSS_R[mode])
+    outs = _fwd_call(
         functools.partial(_css_fwd_kernel, p, q, t, cs, hp, mode),
-        grid=(nblk, nchunk),
-        in_specs=([_bs(cs, _cur)] + ([_bs(cs, _prev)] if hp else [])
-                  + [_bs(k, _fixed), _bs(1, _fixed)]),
-        out_specs=out_specs,
-        out_shape=out_shape,
-        scratch_shapes=scratch,
-        compiler_params=_VMEM_PARAMS,
-        interpret=interpret,
-    )(*((y3, y3) if hp else (y3,)), par3, zb3)
+        layout, r, interpret, (*((y3, y3) if hp else (y3,)), par3, zb3))
     return outs, (y3, par3, zb3)
 
 
@@ -749,11 +837,13 @@ def _garch_fwd_kernel(t_limit, cs, hp, mode, *refs):
     zb = zb_ref[0]
     h0 = h0_ref[0]
 
+    zero = _plane_zero(zb_ref)
+
     @pl.when(c == 0)
     def _():
         ch_ref[0] = h0
         if mode != "e":
-            ll_ref[0] = _ZERO()
+            ll_ref[0] = zero
 
     def body(tl, carry):
         hprev_c, acc = carry
@@ -778,7 +868,7 @@ def _garch_fwd_kernel(t_limit, cs, hp, mode, *refs):
             )
         return hval, acc
 
-    hlast, acc = _fori(cs, body, (ch_ref[0], _ZERO()))
+    hlast, acc = _fori(cs, body, (ch_ref[0], zero))
     ch_ref[0] = hlast
     if mode != "e":
         ll_ref[0] = ll_ref[0] + acc
@@ -875,34 +965,41 @@ class GarchFolded:
         return take_series(self, idxc)
 
 
-def _garch_fwd_call_f(interpret, mode, params, f: GarchFolded):
+# see _CSS_R: sum 3.79 / 2.00 / 1.13 ms, both 3.81 / 2.06 / 1.59, e 1.75 /
+# 1.56 / 1.55
+_GARCH_R = {"sum": 4, "both": 4, "e": 4}
+
+
+def _garch_fwd_layout(mode, t):
+    """The forward GARCH call's blocks (see :func:`_css_fwd_layout`)."""
+    _, cs, nchunk = _time_layout(t)
+    ins = ([(cs, _cur)] + ([(cs, _prev)] if nchunk > 1 else [])
+           + [(3, _fixed), (1, _fixed), (1, _fixed)])
+    outs = (([(cs, _cur)] if mode != "sum" else [])
+            + ([(1, _fixed)] if mode != "e" else []))
+    return ins, outs, [1]  # scratch: the cross-chunk variance carry
+
+
+def garch_series_block(rows: int, t: int, mode: str = "sum") -> int:
+    """Series per grid step of the forward GARCH kernel (see
+    :func:`css_series_block`)."""
+    return _SBLK * series_rows(
+        _nsub(rows), _garch_fwd_layout(mode, t), _GARCH_R[mode])
+
+
+def _garch_fwd_call_f(interpret, mode, params, f: GarchFolded, _r=None):
     # pre-FOLDED entry (see _css_fwd_call_f): only the [B, 3] parameters are
     # folded per call; the panel and its seeds arrive in kernel layout
     _, cs, nchunk = _time_layout(f.t)
     r23 = f.r23
     par3 = _fold(params)
-    nblk = r23.shape[1] // _SUBL
     hp = nchunk > 1
-    out_specs, out_shape = [], []
-    if mode != "sum":
-        out_specs.append(_bs(cs, _cur))
-        out_shape.append(jax.ShapeDtypeStruct(r23.shape, r23.dtype))
-    if mode != "e":
-        out_specs.append(_bs(1, _fixed))
-        out_shape.append(
-            jax.ShapeDtypeStruct((1, r23.shape[1], _LANES), r23.dtype)
-        )
-    outs = pl.pallas_call(
+    layout = _garch_fwd_layout(mode, f.t)
+    r = _r or series_rows(r23.shape[1], layout, _GARCH_R[mode])
+    outs = _fwd_call(
         functools.partial(_garch_fwd_kernel, f.t, cs, hp, mode),
-        grid=(nblk, nchunk),
-        in_specs=([_bs(cs, _cur)] + ([_bs(cs, _prev)] if hp else [])
-                  + [_bs(3, _fixed), _bs(1, _fixed), _bs(1, _fixed)]),
-        out_specs=out_specs,
-        out_shape=out_shape,
-        scratch_shapes=[pltpu.VMEM((1, _SUBL, _LANES), jnp.float32)],
-        compiler_params=_VMEM_PARAMS,
-        interpret=interpret,
-    )(*((r23, r23) if hp else (r23,)), par3, f.h03, f.zb3)
+        layout, r, interpret,
+        (*((r23, r23) if hp else (r23,)), par3, f.h03, f.zb3))
     return outs, par3
 
 
@@ -1449,7 +1546,7 @@ def _hw_fwd_kernel(m, mult, save_resid, t_limit, cs, y_ref, par_ref, l0_ref,
             seas_ref[j] = s0_ref[j]
         clt_ref[0] = l0_ref[0]
         clt_ref[1] = t0_ref[0]
-        ss_ref[0] = _ZERO()
+        ss_ref[0] = _plane_zero(zb_ref)
 
     def body(tl, carry):
         level, trend, acc = carry
@@ -1481,7 +1578,8 @@ def _hw_fwd_kernel(m, mult, save_resid, t_limit, cs, y_ref, par_ref, l0_ref,
             tr_ref[tl] = nt_o
         return nl_o, nt_o, acc + e * e
 
-    level, trend, acc = _fori(cs, body, (clt_ref[0], clt_ref[1], _ZERO()))
+    level, trend, acc = _fori(
+        cs, body, (clt_ref[0], clt_ref[1], _plane_zero(zb_ref)))
     clt_ref[0] = level
     clt_ref[1] = trend
     ss_ref[0] = ss_ref[0] + acc
@@ -1599,35 +1697,43 @@ class HWFolded:
         return take_series(self, idxc)
 
 
-def _hw_fwd_call_f(interpret, m, mult, save_resid, params, f: HWFolded):
+# by ``save_resid``; see _CSS_R: value-only 2.58 / 1.43 / 0.87 ms over
+# [131072, 960]; save_resid moves 2.5 GB at the HBM's pace whatever the
+# width (3.65 / 3.58 ms) and its ten buffers do not fit VMEM at R = 4
+_HW_R = {False: 4, True: 2}
+
+
+def _hw_fwd_layout(m, save_resid, t):
+    """The forward Holt-Winters call's blocks (see
+    :func:`_css_fwd_layout`)."""
+    _, cs, _ = _time_layout(t)
+    ins = [(cs, _cur), (3, _fixed), (1, _fixed), (1, _fixed), (m, _fixed),
+           (1, _fixed)]
+    # e + the replay trajectories for the adjoint; the per-series SSE
+    outs = ([(cs, _cur)] * 4 if save_resid else []) + [(1, _fixed)]
+    return ins, outs, [m, 2]  # scratch: the seasonal ring, level / trend
+
+
+def hw_series_block(rows: int, t: int, period: int,
+                    save_resid: bool = False) -> int:
+    """Series per grid step of the forward Holt-Winters kernel (see
+    :func:`css_series_block`)."""
+    return _SBLK * series_rows(
+        _nsub(rows), _hw_fwd_layout(period, save_resid, t),
+        _HW_R[save_resid])
+
+
+def _hw_fwd_call_f(interpret, m, mult, save_resid, params, f: HWFolded,
+                   _r=None):
     # pre-FOLDED entry (see _css_fwd_call_f): only the [B, 3] parameters are
     # folded per call; the panel and its seeds arrive in kernel layout
-    _, cs, nchunk = _time_layout(f.t)
-    y3 = f.y3
+    _, cs, _ = _time_layout(f.t)
     par3 = _fold(params)
-    nblk = y3.shape[1] // _SUBL
-    ss_spec = _bs(1, _fixed)
-    ss_shape = jax.ShapeDtypeStruct((1, y3.shape[1], _LANES), y3.dtype)
-    if save_resid:  # e + replay trajectories for the adjoint + the SSE
-        out_specs = [_bs(cs, _cur)] * 4 + [ss_spec]
-        out_shape = [jax.ShapeDtypeStruct(y3.shape, y3.dtype)] * 4 + [ss_shape]
-    else:  # per-series SSE only
-        out_specs = [ss_spec]
-        out_shape = [ss_shape]
-    outs = pl.pallas_call(
+    layout = _hw_fwd_layout(m, save_resid, f.t)
+    r = _r or series_rows(f.y3.shape[1], layout, _HW_R[save_resid])
+    outs = _fwd_call(
         functools.partial(_hw_fwd_kernel, m, mult, save_resid, f.t, cs),
-        grid=(nblk, nchunk),
-        in_specs=[_bs(cs, _cur), _bs(3, _fixed), _bs(1, _fixed),
-                  _bs(1, _fixed), _bs(m, _fixed), _bs(1, _fixed)],
-        out_specs=out_specs,
-        out_shape=out_shape,
-        scratch_shapes=[
-            pltpu.VMEM((m, _SUBL, _LANES), jnp.float32),
-            pltpu.VMEM((2, _SUBL, _LANES), jnp.float32),
-        ],
-        compiler_params=_VMEM_PARAMS,
-        interpret=interpret,
-    )(y3, par3, f.l03, f.t03, f.s03, f.zb3)
+        layout, r, interpret, (f.y3, par3, f.l03, f.t03, f.s03, f.zb3))
     return outs, par3
 
 

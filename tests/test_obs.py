@@ -25,6 +25,7 @@ import jax.numpy as jnp
 from spark_timeseries_tpu import obs
 from spark_timeseries_tpu import reliability as rel
 from spark_timeseries_tpu.models import arima
+from spark_timeseries_tpu.ops import pallas_kernels as pk
 from spark_timeseries_tpu.reliability import faultinject as fi
 from spark_timeseries_tpu.utils import optim
 
@@ -711,8 +712,13 @@ class TestStageGateSpans:
         spans = {s["name"]: s for s in _span_lines(p)}
         carry = seen[-1]
         s1 = spans["fit.stage1"]
-        assert s1["attrs"] == {"rows": 2048, "iters": int(carry.k),
-                               "undone": int(carry.undone)}
+        # series_block: what the value-only CSS kernel takes per grid
+        # step over these rows, from the kernel file's own rule
+        assert s1["attrs"] == {
+            "rows": 2048, "iters": int(carry.k),
+            "undone": int(carry.undone),
+            "series_block": pk.css_series_block(2048, 39, (1, 1, 1))}
+        assert s1["attrs"]["series_block"] in (1024, 2048)
         assert all(type(s1["attrs"][k]) is int for k in ("iters", "undone"))
         assert s1["parent"] == primary.id
         assert s1["attrs"]["undone"] > 0
@@ -720,7 +726,7 @@ class TestStageGateSpans:
         assert ("fit.stage2" in spans) == stage2
         if stage2:
             assert spans["fit.stage2"]["attrs"] == {
-                "rows": optim.compaction_cap(2048)}
+                "rows": optim.compaction_cap(2048), "series_block": 1024}
             assert spans["fit.stage2"]["parent"] == primary.id
 
     @pytest.mark.parametrize("family", ["arima", "holtwinters", "garch"])
@@ -739,6 +745,9 @@ class TestStageGateSpans:
         spans = {s["name"]: s for s in _span_lines(p)}
         at = spans["fit.stage1"]["attrs"]["iters"]
         assert "fit.stage2" in spans
+        # every family names its objective kernel's block on both stages
+        assert spans["fit.stage1"]["attrs"]["series_block"] in (1024, 2048)
+        assert spans["fit.stage2"]["attrs"]["series_block"] == 1024
         assert int(info["cap"]) == optim.compaction_cap(2048)
         assert int(info["compact_at"]) == at
         evals = np.asarray(info["ls_evals"])

@@ -1493,3 +1493,218 @@ def test_hw_seeds_dense_path_matches_general(mult):
     for d, g in zip(dense, general):
         np.testing.assert_allclose(np.asarray(d), np.asarray(g),
                                    rtol=1e-6, atol=1e-6)
+
+
+# ---------------------------------------------------------------------------
+# The forward objective kernels' series-block width (pk.series_rows): R
+# vector registers of series a time step is the SAME arithmetic per series
+# ---------------------------------------------------------------------------
+
+
+def _block_width_cases():
+    for ragged in (False, True):
+        for nchunk in (1, 2):
+            tag = f"{'ragged' if ragged else 'dense'}-nchunk{nchunk}"
+            for mode in ("sum", "both", "e", "tail"):
+                yield pytest.param("css", mode, False, ragged, nchunk,
+                                   id=f"css-{mode}-{tag}")
+            for mode in ("sum", "both", "e"):
+                yield pytest.param("garch", mode, False, ragged, nchunk,
+                                   id=f"garch-{mode}-{tag}")
+            for mult in (False, True):
+                for mode in ("sum", "save_resid"):
+                    yield pytest.param(
+                        "hw", mode, mult, ragged, nchunk,
+                        id=f"hw-{'mult' if mult else 'add'}-{mode}-{tag}")
+
+
+def _block_width_runner(family, mode, mult, ragged, nchunk):
+    """-> run(r): every output of the forward call at forced width ``r``
+    and, where the mode saves residuals, the gradient through the adjoint."""
+    # R = 4 needs Bp / 128 divisible by 32: 4096 series.  Chunks of 16
+    # steps (patched by the caller) keep the interpreted loops short
+    b, m = 4096, 4
+    t = 13 if nchunk == 1 else 29
+    rng = np.random.default_rng(71)
+    nv = None
+    if ragged:
+        nv = jnp.asarray(rng.integers(t - 4, t + 1, b), jnp.int32)
+    gbar = jnp.asarray(rng.normal(size=b).astype(np.float32))
+    if family == "css":
+        y = jnp.asarray(rng.normal(size=(b, t)).astype(np.float32))
+        par = jnp.asarray(rng.normal(size=(b, 3)).astype(np.float32) * 0.3)
+        y3, zb3 = pk.css_prefold(y, (1, 0, 1), nv)
+
+        def run(r):
+            outs, (_, par3, _) = pk._css_fwd_call_f(
+                1, 1, True, mode, par, y3, zb3, t, _r=r)
+            if mode != "both":
+                return list(outs)
+            return list(outs) + list(pk._css_ss_f_bwd(
+                1, 1, True, t, b, (y3, par3, zb3, outs[0], None), gbar))
+    elif family == "garch":
+        r_ = jnp.asarray(0.01 * rng.normal(size=(b, t)).astype(np.float32))
+        par = jnp.asarray(np.stack(
+            [rng.uniform(1e-6, 1e-5, b), rng.uniform(0.03, 0.15, b),
+             rng.uniform(0.7, 0.8, b)], axis=1).astype(np.float32))
+        f = pk.garch_prefold(r_, nv)
+
+        def run(r):
+            outs, par3 = pk._garch_fwd_call_f(True, mode, par, f, _r=r)
+            if mode != "both":
+                return list(outs)
+            gpar, _ = pk._garch_ll_f_bwd(True, (f, par3, outs[0], None), gbar)
+            return list(outs) + [gpar]
+    else:
+        y = _seasonal_panel(b, t, m, seed=72) + (25.0 if mult else 0.0)
+        if ragged:
+            y = jnp.where(jnp.arange(t)[None, :] >= (t - nv)[:, None], y, 0.0)
+        par = jnp.asarray(rng.uniform(0.05, 0.9, (b, 3)).astype(np.float32))
+        f = pk.hw_prefold(y, pk.hw_seeds(y, m, mult, nv))
+
+        def run(r):
+            save = mode == "save_resid"
+            outs, par3 = pk._hw_fwd_call_f(True, m, mult, save, par, f, _r=r)
+            if not save:
+                return list(outs)
+            gpar, _ = pk._hw_ss_f_bwd(True, m, mult, (f, par3, *outs[:4]),
+                                      gbar)
+            return list(outs) + [gpar]
+
+    return run
+
+
+@pytest.mark.parametrize("family,mode,mult,ragged,nchunk",
+                         list(_block_width_cases()))
+def test_forward_block_width_is_bit_equal(monkeypatch, family, mode, mult,
+                                          ragged, nchunk):
+    # value, saved residuals and the gradient through the unchanged adjoint
+    # at forced R = 2 and R = 4 against R = 1, bit for bit
+    monkeypatch.setattr(pk, "_CHUNK_T", 16)
+    run = _block_width_runner(family, mode, mult, ragged, nchunk)
+    ref = [np.asarray(x) for x in run(1)]
+    assert all(np.isfinite(x).all() for x in ref)
+    assert any(np.abs(x).max() > 0 for x in ref)
+    for r in (2, 4):
+        got = [np.asarray(x) for x in run(r)]
+        assert len(got) == len(ref)
+        for x, y in zip(got, ref):
+            assert x.shape == y.shape and x.tobytes() == y.tobytes(), r
+
+
+@pytest.mark.parametrize("what,block,layout", [
+    # a 256-row serving batch pads to one 1,024-series block
+    ("serving-256", lambda: pk.css_series_block(256, 999, (1, 1, 1)), None),
+    ("ladder-1-row", lambda: pk.hw_series_block(1, 960, 24), None),
+    # a compaction cap that is 1,024- but not 2,048-aligned
+    ("cap-3072", lambda: pk.garch_series_block(3072, 1000), None),
+    ("cap-2048-takes-2", lambda: pk.css_series_block(2048, 999, (1, 1, 1)),
+     None),
+    # the cells' chunk and stage-2 compaction take the widest block
+    ("arima-chunk", lambda: pk.css_series_block(131072, 999, (1, 1, 1)),
+     lambda: pk._css_fwd_layout(1, 1, "sum", 999)),
+    ("arima-chunk-both",
+     lambda: pk.css_series_block(131072, 999, (1, 1, 1), "both"),
+     lambda: pk._css_fwd_layout(1, 1, "both", 999)),
+    ("garch-stage2", lambda: pk.garch_series_block(16384, 1000),
+     lambda: pk._garch_fwd_layout("sum", 1000)),
+    # HW save_resid: 1 input + 4 outputs, 10 buffers of 3.9 MB: never 4
+    ("hw-save-resid", lambda: pk.hw_series_block(131072, 960, 24, True),
+     lambda: pk._hw_fwd_layout(24, True, 960)),
+    # the ring is m x 4 KB x R, thrice (input twice, scratch once)
+    ("hw-m1024-T4096", lambda: pk.hw_series_block(131072, 4096, 1024),
+     lambda: pk._hw_fwd_layout(1024, False, 4096)),
+    ("hw-m1024-T4096-save",
+     lambda: pk.hw_series_block(131072, 4096, 1024, True),
+     lambda: pk._hw_fwd_layout(1024, True, 4096)),
+    # series past one chunk: the _prev neighbour doubles the input buffers
+    ("css-T4096-both",
+     lambda: pk.css_series_block(131072, 4096, (1, 1, 1), "both"),
+     lambda: pk._css_fwd_layout(1, 1, "both", 4096)),
+])
+def test_series_block_rule_on_shapes(what, block, layout):
+    # the width rule from static facts alone: no kernel runs
+    sb = block()
+    r = sb // pk._SBLK
+    assert sb == r * pk._SBLK and r in (1, 2, 4)
+    if what in ("serving-256", "ladder-1-row", "cap-3072"):
+        assert r == 1
+    if what == "cap-2048-takes-2":
+        assert r == min(2, pk._CSS_R["sum"])
+    if what == "hw-save-resid":
+        assert r <= 2
+    if layout is not None:
+        assert pk._vmem_bytes(layout(), r) <= pk._VMEM_BLOCK_BUDGET
+        assert pk._VMEM_BLOCK_BUDGET < pk._VMEM_PARAMS.vmem_limit_bytes
+        # the next wider block is refused for a stated reason
+        wider = {1: 2, 2: 4}.get(r)
+        if wider and what != "garch-stage2":
+            best = {"arima-chunk": pk._CSS_R["sum"],
+                    "arima-chunk-both": pk._CSS_R["both"],
+                    "css-T4096-both": pk._CSS_R["both"],
+                    "hw-save-resid": pk._HW_R[True],
+                    "hw-m1024-T4096": pk._HW_R[False],
+                    "hw-m1024-T4096-save": pk._HW_R[True]}[what]
+            assert (wider > best or pk._vmem_bytes(layout(), wider)
+                    > pk._VMEM_BLOCK_BUDGET)
+
+
+def test_series_rows_is_a_function_of_static_facts():
+    # divisibility, the VMEM budget, the chip's best: in that order of refusal
+    lay = pk._css_fwd_layout(1, 1, "sum", 999)
+    tiles = 2 * (1000 + 3 + 1 + 1) + 1000 + 1
+    assert pk._vmem_bytes(lay) == tiles * 4096
+    assert pk._vmem_bytes(lay, 4) == 4 * tiles * 4096
+    assert pk.series_rows(1024, lay, 4) == 4
+    assert pk.series_rows(1024, lay, 2) == 2
+    assert pk.series_rows(1024, lay, 1) == 1
+    assert pk.series_rows(16, lay, 4) == 2  # 2,048 series
+    assert pk.series_rows(24, lay, 4) == 1  # 3,072 series
+    assert pk.series_rows(8, lay, 4) == 1
+    over = pk._VMEM_BLOCK_BUDGET // pk._TILE_BYTES
+    scratch_only = lambda n: ([], [], [n])  # noqa: E731
+    assert pk.series_rows(1024, scratch_only(over // 4), 4) == 4
+    assert pk.series_rows(1024, scratch_only(over // 4 + 1), 4) == 2
+    assert pk.series_rows(1024, scratch_only(over // 2 + 1), 4) == 1
+
+
+def test_forward_call_grid_follows_the_rule():
+    # the pallas_call the fit objective traces takes the rule's block: at
+    # 4,096 series one grid step of (cs, 8 R, 128) where R = 1 takes four
+    b, t = 4096, 40
+    y3 = jnp.zeros((t, b // 128, 128), jnp.float32)
+    zb3 = jnp.ones((1, b // 128, 128), jnp.float32)
+    par = jnp.zeros((b, 3), jnp.float32)
+
+    def grid(**kw):
+        jaxpr = jax.make_jaxpr(lambda P: pk._css_fwd_call_f(
+            1, 1, True, "sum", P, y3, zb3, t, **kw)[0])(par)
+        (eqn,) = [e for e in jaxpr.eqns if e.primitive.name == "pallas_call"]
+        gm = eqn.params["grid_mapping"]
+        return tuple(gm.grid), gm.block_mappings[0].block_shape
+
+    r = pk.css_series_block(b, t, (1, 0, 1)) // pk._SBLK
+    g, blk = grid()
+    assert g == (4 // r, 1)
+    assert tuple(getattr(x, "block_size", x) for x in blk) == (40, 8 * r, 128)
+    assert grid(_r=1)[0] == (4, 1) and grid(_r=4)[0] == (1, 1)
+
+
+def test_kernel_block_sweep_cases_trace():
+    # tools/kernel_block_sweep.py (the chip-side R sweep): every case's
+    # arguments and call trace at every width, on shapes alone
+    import functools
+
+    from tools import kernel_block_sweep as sweep
+
+    seen = set()
+    for name, mode, rows, t, make, call in sweep.cases():
+        args = jax.eval_shape(make, jax.random.key(0))
+        tp, _, _ = pk._time_layout(t)
+        for r in (1, 2, 4):
+            outs = jax.eval_shape(functools.partial(call, r), *args)
+            assert outs[-1].shape[1:] == (rows // 128, 128)
+            assert all(o.shape[0] in (1, tp) for o in outs)
+        seen.add((name, mode))
+    assert len(seen) == 8 and {n for n, _ in seen} == {
+        "css_neg_loglik", "hw_sse", "garch_neg_loglik"}

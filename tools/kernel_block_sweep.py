@@ -1,0 +1,163 @@
+#!/usr/bin/env python3
+"""Time the forward fit-objective kernels ALONE at forced series-block widths.
+
+    chiprun -- python tools/kernel_block_sweep.py            # on the chip
+    python tools/kernel_block_sweep.py --compile-only        # here: Mosaic + VMEM
+
+For each kernel (CSS ARIMA(1,1,1), Holt-Winters additive m=24, GARCH(1,1)),
+mode and panel shape of the benchmark's cells (the stage-1 chunk and the
+stage-2 compaction), R = 1, 2, 4 (``pallas_kernels.series_rows`` is bypassed
+through the call functions' private ``_r``): milliseconds a call, ns a time
+step and 1,024-series block, and whether every output is BIT-equal to R = 1's.
+One line a case to ``chiprun_out/kernel_block_sweep.jsonl`` and to stdout.
+``--compile-only`` compiles every case for a described v5e and runs nothing
+(no time is reported from it).
+"""
+
+from __future__ import annotations
+
+import argparse
+import functools
+import json
+import os
+import sys
+import time
+
+sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+
+import jax  # noqa: E402
+import jax.numpy as jnp  # noqa: E402
+import numpy as np  # noqa: E402
+
+from spark_timeseries_tpu.ops import pallas_kernels as pk  # noqa: E402
+
+ROWS = (131072, 16384)
+
+
+def _planes(key, n, nsub, scale=1.0, loc=0.0):
+    return loc + scale * jax.random.normal(key, (n, nsub, pk._LANES),
+                                           jnp.float32)
+
+
+def cases():
+    """-> (name, mode, rows, t, make_args(key), call(r, *args))."""
+    for rows in ROWS:
+        nsub = rows // pk._LANES
+
+        def css_args(key, nsub=nsub, rows=rows):
+            k1, k2 = jax.random.split(key)
+            par = (jnp.asarray([0.01, 0.5, 0.3], jnp.float32)
+                   + 0.05 * jax.random.normal(k2, (rows, 3), jnp.float32))
+            return (par, _planes(k1, 1000, nsub),
+                    jnp.ones((1, nsub, pk._LANES), jnp.float32))
+
+        for mode in ("sum", "both", "e"):
+            yield ("css_neg_loglik", mode, rows, 999, css_args,
+                   lambda r, par, y3, zb3, mode=mode: pk._css_fwd_call_f(
+                       1, 1, False, mode, par, y3, zb3, 999, _r=r)[0])
+
+        def hw_args(key, nsub=nsub, rows=rows):
+            k1, k2, k3 = jax.random.split(key, 3)
+            par = (jnp.asarray([0.3, 0.1, 0.2], jnp.float32)
+                   + 0.02 * jax.random.normal(k2, (rows, 3), jnp.float32))
+            one = jnp.ones((1, nsub, pk._LANES), jnp.float32)
+            return par, pk.HWFolded(
+                _planes(k1, 960, nsub, loc=10.0), 10.0 * one, 0.0 * one,
+                _planes(k3, 24, nsub, scale=0.5), 0.0 * one, 960)
+
+        for save in (False, True):
+            yield ("hw_sse", "save_resid" if save else "sum", rows, 960,
+                   hw_args,
+                   lambda r, par, f, save=save: pk._hw_fwd_call_f(
+                       False, 24, False, save, par, f, _r=r)[0])
+
+        def garch_args(key, nsub=nsub, rows=rows):
+            k1, k2 = jax.random.split(key)
+            par = jnp.asarray([1e-6, 0.08, 0.9], jnp.float32) * (
+                1.0 + 0.05 * jax.random.normal(k2, (rows, 3), jnp.float32))
+            one = jnp.ones((1, nsub, pk._LANES), jnp.float32)
+            r23 = (0.01 * _planes(k1, 1000, nsub)) ** 2
+            return par, pk.GarchFolded(r23, 1e-4 * one, 0.0 * one, 1000)
+
+        for mode in ("sum", "both", "e"):
+            yield ("garch_neg_loglik", mode, rows, 1000, garch_args,
+                   lambda r, par, f, mode=mode: pk._garch_fwd_call_f(
+                       False, mode, par, f, _r=r)[0])
+
+
+def _time(fn, args, calls, reps=3):
+    best = float("inf")
+    for _ in range(reps):
+        t0 = time.perf_counter()
+        for _ in range(calls):
+            out = fn(*args)
+        jax.block_until_ready(out)
+        best = min(best, (time.perf_counter() - t0) / calls)
+    return best
+
+
+def main():
+    ap = argparse.ArgumentParser()
+    ap.add_argument("--compile-only", action="store_true")
+    ap.add_argument("--calls", type=int, default=40)
+    ap.add_argument("--only", default="")
+    ap.add_argument("--r", type=int, nargs="*", default=[1, 2, 4])
+    ap.add_argument("--rows", type=int, nargs="*", default=list(ROWS))
+    a = ap.parse_args()
+    sharding = None
+    if a.compile_only:
+        os.environ.setdefault("TPU_LOG_DIR", "disabled")
+        os.environ.setdefault("ALLOW_MULTIPLE_LIBTPU_LOAD", "1")
+        from jax.experimental import topologies
+        from jax.sharding import SingleDeviceSharding
+
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+        sharding = SingleDeviceSharding(topo.devices[0])
+    elif jax.devices()[0].platform != "tpu":
+        sys.exit("no TPU: a time comes from the chip only "
+                 "(--compile-only compiles for a described v5e)")
+    out_dir = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "chiprun_out")
+    os.makedirs(out_dir, exist_ok=True)
+    out = open(os.path.join(out_dir, "kernel_block_sweep.jsonl"), "a")
+    for name, mode, rows, t, make, call in cases():
+        if (a.only and a.only not in f"{name}.{mode}") or rows not in a.rows:
+            continue
+        tp, _, _ = pk._time_layout(t)
+        blocks = rows // pk._SBLK
+        ref = None
+        args = None if a.compile_only else make(jax.random.key(rows + t))
+        for r in a.r:
+            rec = {"kernel": name, "mode": mode, "rows": rows, "t": t, "r": r,
+                   "device": ("described v5e (compile only)" if a.compile_only
+                              else jax.devices()[0].device_kind)}
+            fn = jax.jit(functools.partial(call, r))
+            try:
+                if a.compile_only:
+                    shapes = jax.tree_util.tree_map(
+                        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype,
+                                                       sharding=sharding),
+                        jax.eval_shape(make, jax.random.key(0)))
+                    fn.lower(*shapes).compile()
+                    rec["compiled"] = True
+                else:
+                    host = [np.asarray(x) for x in fn(*args)]
+                    if ref is None:  # the first width asked for: R = 1
+                        ref = host
+                    rec["bit_equal_r1"] = all(
+                        x.tobytes() == y.tobytes() for x, y in zip(host, ref))
+                    del host
+                    s = _time(fn, args, a.calls)
+                    rec["ms_call"] = s * 1e3
+                    rec["ns_step_block"] = s * 1e9 / (tp * blocks)
+            except Exception as e:  # noqa: BLE001 - a refused width is a reading
+                rec["error"] = f"{type(e).__name__}: {str(e)[:300]}"
+            line = json.dumps(rec)
+            print(line, flush=True)
+            out.write(line + "\n")
+            out.flush()
+
+
+if __name__ == "__main__":
+    main()
