@@ -208,14 +208,10 @@ def check(run, state: dict, result: dict) -> dict:
         cfg["model"].get("kwargs", {}),
         [state["pool"][int(sched["offset"][i]) + r] for i, r in picks],
         [answers[i].params[r] for i, r in picks])
-    ok_share = float(np.mean(gaps <= float(ref["loglik_gap_max"])))
-    flags["reference"] = bool(ok_share >= float(ref.get("min_share", 1.0)))
-    rec = refcheck.recovery(
-        np.concatenate([answers[i].params for i in answered]),
-        cfg.get("recovery", []))
-    flags["recovery"] = all(r["ok"] for r in rec)
-    run.log("check", **flags, reference_gap_max=float(np.max(gaps)),
-            reference_ok_share=ok_share, recovered=rec,
+    ref_flags, ref_log = walk_kind.against_reference(
+        run, gaps, np.concatenate([answers[i].params for i in answered]))
+    flags.update(ref_flags)
+    run.log("check", **flags, **ref_log,
             refused=result["refused"], errors=result["errors"],
             counters=result["counters"], knobs=result["knobs"],
             queue_at_end=result["queue_at_end"])

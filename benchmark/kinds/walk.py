@@ -109,8 +109,9 @@ def setup(run) -> dict:
     cfg, mix = run.cell.config, run.cell.traffic
     fit, fit_kwargs = model_fit(cfg)
     panel = make_panel(run)
+    panel_s = run.since_device_s()
     run.log("panel", shape=list(panel.shape), dtype=str(panel.dtype),
-            sharding=str(panel.sharding))
+            sharding=str(panel.sharding), since_device_s=panel_s)
     target = panel
     if mix.get("residency", "device") == "host":
         panel = np.asarray(panel)  # the device copy is dropped
@@ -132,9 +133,16 @@ def setup(run) -> dict:
     res = walk(warm_dir)
     warm = _record(res, warm_dir, time.perf_counter() - t0, n_chunks)
     _read_journals([warm])
+    # setup_s in three stretches, one reader each (layer_metrics/setup_*):
+    # the device mark to the panel ready, the warm-up walk's first chunk
+    # (the slowest lane's), the rest of that walk
+    first = max(lane[0] for lane in warm["chunk_walls"].values())
+    state.update(setup_panel_s=panel_s, setup_first_chunk_s=first,
+                 setup_warm_walk_rest_s=warm["wall_s"] - first)
     run.log("warmup_walk", wall_s=warm["wall_s"],
             chunk_walls=warm["chunk_walls"],
-            status_counts=warm["status_counts"])
+            status_counts=warm["status_counts"],
+            since_device_s=run.since_device_s())
     return state
 
 
@@ -200,13 +208,17 @@ def check(run, state: dict, result: dict) -> dict:
     and the compile count)."""
     cfg = run.cell.config
     res, journal_dir = result["last"]
-    flags = {"walks_sound": all(w["sound"] for w in result["walks"])}
+    flags = {"walks_sound": run.compare(
+        "walks_unsound", sum(not w["sound"] for w in result["walks"]),
+        "==", 0)}
 
     again = state["walk"](journal_dir)  # re-read from its journal
-    flags["resume_bitwise"] = bool(
-        again.meta["journal"].get("chunks_resumed") == state["n_chunks"]
-        and all(np.array_equal(np.asarray(a), np.asarray(b), equal_nan=True)
-                for a, b in zip(res[:-1], again[:-1])))
+    reread = run.compare("resume_chunks_reread", int(
+        again.meta["journal"].get("chunks_resumed") or 0), "==",
+        state["n_chunks"])
+    flags["resume_bitwise"] = run.compare("resume_arrays_differing", sum(
+        not np.array_equal(np.asarray(a), np.asarray(b), equal_nan=True)
+        for a, b in zip(res[:-1], again[:-1])), "==", 0) and reread
     shutil.rmtree(journal_dir, ignore_errors=True)
 
     ref = cfg["reference"]
@@ -217,11 +229,27 @@ def check(run, state: dict, result: dict) -> dict:
     gaps = refcheck.loglik_gaps(
         run.cell.plugin("reference", ref["module"]),
         cfg["model"].get("kwargs", {}), sample, np.asarray(res.params)[idx])
-    ok_share = float(np.mean(gaps <= float(ref["loglik_gap_max"])))
-    flags["reference"] = bool(ok_share >= float(ref.get("min_share", 1.0)))
-    rec = refcheck.recovery(res.params, cfg.get("recovery", []))
-    flags["recovery"] = all(r["ok"] for r in rec)
-    run.log("check", **flags, reference_gap_max=float(np.max(gaps)),
-            reference_gap_median=float(np.median(gaps)),
-            reference_ok_share=ok_share, recovered=rec)
+    ref_flags, ref_log = against_reference(run, gaps, res.params)
+    flags.update(ref_flags)
+    run.log("check", **flags, reference_gap_median=float(np.median(gaps)),
+            **ref_log)
     return flags
+
+
+def against_reference(run, gaps, params) -> tuple:
+    """``(flags, what the check line says)``: the ``reference`` and
+    ``recovery`` parts of ``correct`` from the sampled rows' gaps and the
+    fitted parameters, each number beside the configuration's limit."""
+    cfg = run.cell.config
+    ref = cfg["reference"]
+    ok_share = float(np.mean(gaps <= float(ref["loglik_gap_max"])))
+    rec = refcheck.recovery(params, cfg.get("recovery", []))
+    flags = {
+        "reference": run.compare(
+            f"reference_share_within_gap_{ref['loglik_gap_max']}", ok_share,
+            ">=", float(ref.get("min_share", 1.0))),
+        "recovery": all([run.compare(  # a list: every entry is compared
+            f"recovery_{r['name']}_abs_err", abs(r["median"] - r["value"]),
+            "<=", r["tol"]) for r in rec])}
+    return flags, {"reference_gap_max": float(np.max(gaps)),
+                   "reference_ok_share": ok_share, "recovered": rec}

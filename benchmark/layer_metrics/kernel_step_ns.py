@@ -1,9 +1,15 @@
-"""Objective kernel (``ops/pallas_kernels.py``): nanoseconds one time step
-of the recurrence takes in an objective-kernel event at the cell's chunk
-size — kernel time over (events x padded time steps).  The recurrence is
-serial in time; an event walks the chunk's 1,024-series blocks one after
-another, each step a handful of vector operations on a tiny carry, so this
-is the latency floor VERDICT r5 asked for, not a bandwidth figure."""
+"""Objective kernel (``ops/pallas_kernels.py``): kernel time over (events x
+padded time steps), in nanoseconds — the mean time step of an
+objective-kernel event, over ALL the events of the traced window.  The
+recurrence is serial in time; an event walks the batch in grid steps of
+R x 1,024 series (R = 1, 2 or 4 vector registers a time step, the kernel
+file's rule), each time step a handful of vector operations on a tiny
+carry.  Stage 2's events run on the compacted stragglers, an eighth of the
+chunk's rows, and are counted like stage 1's full-chunk events, so this is
+NOT one event's time over T (a stage-1 value-only event of HW took 2.58 ms
+where this metric times T gave 1.74; PR 31): it moves with the kernels'
+pace and with the mix of the two stages.  A latency figure, not a
+bandwidth one; the ``*_roofline`` metrics are those."""
 
 from benchmark import roofline
 

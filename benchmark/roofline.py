@@ -3,14 +3,19 @@ chip's roofline.  Kept with the benchmark so that no PR that changes a
 kernel changes what it is measured against.
 
 The objective kernels (``ops/pallas_kernels.py``) fold a ``[rows, time]``
-chunk into ``[time, rows/128, 128]`` and walk blocks of 1,024 series
-(8 sublanes x 128 lanes) one after another, ``time`` serial steps to a
-block.  Time is padded to a multiple of 8 up to 1,024 steps and to a
-multiple of 1,024 beyond (``_time_layout``), rows to a multiple of 1,024.
+chunk into ``[time, rows/128, 128]`` and walk it in ``time`` serial steps,
+a grid step taking R vector registers of 1,024 series (8 sublanes x 128
+lanes) each: R = 1, 2 or 4, the kernel file's own rule (``series_rows``;
+the adjoint kernels take 1).  Time is padded to a multiple of 8 up to
+1,024 steps and to a multiple of 1,024 beyond (``_time_layout``), rows to a
+multiple of 1,024.  The bytes and steps counted here are the panel's and
+do not depend on R.
 """
 
 from __future__ import annotations
 
+# one vector register of series: the unit rows are padded to, whatever
+# number of registers a kernel takes a time step
 SERIES_PER_BLOCK = 1024
 CHUNK_T = 1024
 

@@ -4,15 +4,29 @@
     python3 benchmark/run.py --workload <cell> --seed <n> --seconds <s> --trace <0|1>
 
 ONE process, the only one to touch JAX.  It builds the cell's inputs from
-``--seed`` on the device, warms up the cell's own shapes (all of that is
-``setup_s``), measures for ``--seconds``, checks the outputs against the
-plain reference, and prints as the LAST line of stdout one JSON object with
-``correct``, ``attempted``, ``failed``, ``metrics`` and ``device`` (plus
-``breakdown`` when traced).  ``--trace 0`` reports the cell's end-to-end
+``--seed`` on the device, warms up the cell's own shapes, measures for
+``--seconds``, checks the outputs against the plain reference, and prints
+as the LAST line of stdout one JSON object with ``correct``, ``attempted``,
+``failed``, ``metrics`` and ``device`` (plus ``breakdown`` when traced, and
+last ``checks``: every number compared beside its limit, which are also
+the last lines of stderr).  ``--trace 0`` reports the cell's end-to-end
 metrics with ``obs`` off, as users have it; ``--trace 1`` enables ``obs``
 with ``profile=True``, profiles a few seconds inside the window and reports
 the cell's per-layer metrics.  Earlier lines (also kept under ``--out``)
 carry versions, per-walk walls, the journal's filesystem and the checks.
+
+Two marks.  ``_T0`` is this file's first statement; every detail line's
+``at_s`` counts from it.  The device mark is taken in :func:`prepare` the
+moment ``jax.devices()`` has returned and the device gate has passed.
+``setup_s`` is the wall from the DEVICE mark to the statement before the
+window opens: the panel made on the device, every compile or
+compile-cache load, the whole warm-up, ``obs.enable`` on a traced run —
+the stretch a PR can move work into.  What precedes the mark (the
+interpreter, the imports, the runtime's start: the machine's, 10-20 s
+that differ by half from run to run) is ``process_start_s`` and
+``process_start_cpu_s``, per-layer metrics and fields of the ``start`` and
+``done`` lines; ``setup_end_at_s`` there is the end point's ``at_s``, which
+is what ``setup_s`` measured until PR 33.
 
 Without a TPU, or with fewer chips than the cell asks for, it exits
 non-zero and prints no result.  ``--rehearse`` is the exception: tiny sizes
@@ -20,10 +34,11 @@ on whatever backend there is, control flow only, every line stamped
 ``"rehearsal": true`` and every time, rate and share left null.
 """
 
-_T0 = __import__("time").time()  # set-up runs from process start
+_T0 = __import__("time").time()  # every line's at_s counts from here
 
 import argparse  # noqa: E402
 import json  # noqa: E402
+import operator  # noqa: E402
 import os  # noqa: E402
 import shutil  # noqa: E402
 import sys  # noqa: E402
@@ -43,6 +58,7 @@ BACKEND_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
 # reduction does not read — raise it to look at a gap under "no span" by hand
 HOST_TRACER_LEVEL = 1
 MEASURED_SOURCES = ("device_trace", "host_clock", "program_span")
+RULES = {"<=": operator.le, ">=": operator.ge, "==": operator.eq}
 
 
 class Tracer:
@@ -111,8 +127,17 @@ class Compiles:
 class Run:
     """What a traffic kind and a layer-metric reader are handed."""
 
-    def __init__(self, cell, args, devices, out_dir):
+    def __init__(self, cell, args, devices, out_dir, device_mark):
         self.cell, self.seed = cell, int(args.seed)
+        # the device mark: time.time() and os.times() when the device was
+        # held.  setup_s counts from it; what precedes it is the process's
+        # start, the machine's more than the program's
+        self.device_mark_t, cpu = device_mark
+        self.process_start_s = self.device_mark_t - _T0
+        # what time.process_time() reads: user + system, every thread
+        self.process_start_cpu_s = cpu.user + cpu.system
+        self.process_start_cpu_user_s = cpu.user
+        self.setup_s = self.setup_end_at_s = None
         self.seconds, self.rehearse = float(args.seconds), args.rehearse
         self.devices = devices
         self.out_dir = out_dir
@@ -126,7 +151,26 @@ class Run:
         self.result = None     # the kind's measured window
         self.trace = None      # trace_reduce.Trace of the traced window
         self.spans = []        # obs span events of the traced run
+        self.compared = {}     # every number `correct` compared, by name
         self._log_path = os.path.join(out_dir, "run.jsonl")
+
+    def since_device_s(self) -> float:
+        """Seconds of set-up so far (wall since the device mark)."""
+        return time.time() - self.device_mark_t
+
+    def close_setup(self) -> None:
+        """The statement before the window opens: ``setup_s`` ends here."""
+        end = time.time()
+        self.setup_s = end - self.device_mark_t
+        self.setup_end_at_s = end - _T0  # setup_s as it was until PR 33
+
+    def compare(self, name: str, value, rule: str, limit) -> bool:
+        """One number of ``correct`` beside its limit (``rule`` is ``<=``,
+        ``>=`` or ``==``); kept for the result line's ``checks``."""
+        ok = bool(RULES[rule](value, limit))
+        self.compared[name] = {"value": value, "rule": rule, "limit": limit,
+                               "ok": ok}
+        return ok
 
     def log(self, what: str, **fields) -> None:
         line = {"what": what, "workload": self.cell.name, "seed": self.seed,
@@ -266,11 +310,13 @@ def prepare(args):
     if len(devs) < cell.chips:
         raise SystemExit(f"benchmark: {cell.name} needs {cell.chips} chips, "
                          f"jax.devices() has {len(devs)}")
+    # the device is held: the cell's set-up, and setup_s, start here
+    device_mark = (time.time(), os.times())
 
     out_dir = os.path.join(os.path.abspath(args.out), cell.name)
     shutil.rmtree(out_dir, ignore_errors=True)
     os.makedirs(os.path.join(out_dir, "work"))
-    run = Run(cell, args, devs[:cell.chips], out_dir)
+    run = Run(cell, args, devs[:cell.chips], out_dir, device_mark)
     run.device = device
     run.peaks = None if args.rehearse and device["platform"] != "tpu" \
         else _load_peaks(device["kind"])
@@ -281,7 +327,9 @@ def prepare(args):
             cache_entries=len(os.listdir(cache_dir))
             if os.path.isdir(cache_dir) else 0,
             journal_fs=_fs_type(run.work_dir), config=cell.config_name,
-            traffic=cell.traffic_name)
+            traffic=cell.traffic_name, process_start_s=run.process_start_s,
+            process_start_cpu_s=run.process_start_cpu_s,
+            process_start_cpu_user_s=run.process_start_cpu_user_s)
     return run, cell.plugin("kinds", cell.traffic["kind"])
 
 
@@ -299,7 +347,7 @@ def main(argv=None) -> int:
         run.state = kind.setup(run)
         if args.trace:
             obs.enable(obs_path, profile=True)
-        run.setup_s = time.time() - _T0
+        run.close_setup()
         run.compiles.open_window()
         run.result = kind.measure(run, run.state)
         run.compiles.close_window()
@@ -314,7 +362,8 @@ def main(argv=None) -> int:
         if run.state is not None and hasattr(kind, "teardown"):
             kind.teardown(run, run.state)
 
-    flags["no_compile_in_window"] = run.compiles_in_window == 0
+    flags["no_compile_in_window"] = run.compare(
+        "compiles_in_window", run.compiles_in_window, "==", 0)
     device["memory_peak_bytes"] = _memory_peak(run.devices)
     flags["device"] = args.rehearse or (
         device["platform"] == "tpu" and device["memory_peak_bytes"] is not None)
@@ -338,6 +387,9 @@ def main(argv=None) -> int:
     line["metrics"] = _metric_lines(run, bool(args.trace))
     line["device"] = device
     run.log("done", flags=flags, setup_s=run.setup_s,
+            setup_end_at_s=run.setup_end_at_s,
+            process_start_s=run.process_start_s,
+            process_start_cpu_s=run.process_start_cpu_s,
             compiles_in_window=run.compiles_in_window,
             compiles_total=len(run.compiles.events),
             program_cache=compile_cache.program_cache_stats(),
@@ -346,6 +398,11 @@ def main(argv=None) -> int:
     shutil.rmtree(run.work_dir, ignore_errors=True)
     if args.rehearse:
         line["rehearsal"] = True
+    # every number compared beside its limit: last on stderr, last in the line
+    line["checks"] = run.compared
+    for name, c in run.compared.items():
+        print(f"check {name}: {c['value']!r} {c['rule']} {c['limit']!r}"
+              f"{'' if c['ok'] else '  <-- NOT MET'}", file=sys.stderr)
     print(json.dumps(line), flush=True)
     return 0
 

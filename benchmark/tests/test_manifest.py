@@ -52,6 +52,54 @@ def test_contract_shape(manifest):
                if m["name"].endswith("_roofline"))
 
 
+SETUP_SPLIT = {"process_start_s": "process", "process_start_cpu_s": "process",
+               "process_start_cpu_user_s": "process",
+               "setup_panel_s": "walk_driver",
+               "setup_first_chunk_s": "walk_driver",
+               "setup_warm_walk_rest_s": "walk_driver"}
+
+
+@pytest.mark.parametrize("name,layer", sorted(SETUP_SPLIT.items()))
+def test_setup_split_entries(manifest, name, layer):
+    """PR 33: ``setup_s`` counts from the device mark; what left it and the
+    three stretches it is made of are per-layer metrics of every cell."""
+    e2e = {m["name"]: m for m in manifest["end_to_end"]}
+    cells = {w["name"] for w in manifest["workloads"]}
+    entry = next(m for m in manifest["per_layer"] if m["name"] == name)
+    assert entry == {"name": name, "unit": "s", "better": "lower",
+                     "source": "host_clock", "layer": layer,
+                     "moves": "setup_s", "workloads": entry["workloads"]}
+    assert entry["moves"] in e2e
+    assert {"arima111.walk-dense", "hw-add24.walk-dense",
+            "garch11.walk-dense"} <= set(entry["workloads"]) <= cells
+    reader = mf.load_plugin(manifest, mf.ROOT, "layer_metrics", name)
+    assert callable(reader.read) and "setup_s" in reader.__doc__
+    # the metric was re-pointed, not loosened: every cell, the same bound
+    assert e2e["setup_s"] == {"name": "setup_s", "unit": "s",
+                              "better": "lower", "bound": 0.1,
+                              "source": "host_clock"}
+
+
+def test_setup_readers_find_nothing_without_a_warm_up_walk():
+    """A kind that records no panel mark and no warm-up walk: the readers
+    return ``None`` and the metrics are left out of the line."""
+    manifest = mf.load_manifest()
+
+    class Run:
+        state = {"server": object()}
+
+    for name, layer in SETUP_SPLIT.items():
+        if layer == "walk_driver":
+            reader = mf.load_plugin(manifest, mf.ROOT, "layer_metrics", name)
+            assert reader.read(Run) is None
+    Run.state = {"setup_panel_s": 0.75, "setup_first_chunk_s": 7.0,
+                 "setup_warm_walk_rest_s": 1.0}
+    got = {name: mf.load_plugin(manifest, mf.ROOT, "layer_metrics",
+                                name).read(Run)
+           for name, layer in SETUP_SPLIT.items() if layer == "walk_driver"}
+    assert got == Run.state
+
+
 def test_cells_and_budget(manifest):
     cells = manifest["workloads"]
     assert 2 <= len(cells) <= 24

@@ -11,10 +11,13 @@ import pytest
 from benchmark import manifest as mf
 
 CELLS = [w["name"] for w in mf.load_manifest()["workloads"]]
-KEYS = {"correct", "attempted", "failed", "metrics", "device"}
+KEYS = {"correct", "attempted", "failed", "metrics", "device", "checks"}
+PROCESS_METRICS = {"process_start_s", "process_start_cpu_s"}
+SETUP_METRICS = {"setup_panel_s", "setup_first_chunk_s",
+                 "setup_warm_walk_rest_s"}
 
 
-def rehearse(cell, trace, tmp_path, *more):
+def rehearse(cell, trace, tmp_path, *more, detail=False):
     env = dict(os.environ, JAX_PLATFORMS="cpu")
     proc = subprocess.run(
         [sys.executable, os.path.join(mf.BENCH_DIR, "run.py"), "--workload",
@@ -25,6 +28,8 @@ def rehearse(cell, trace, tmp_path, *more):
     lines = [json.loads(ln) for ln in proc.stdout.splitlines()
              if ln.startswith("{")]
     assert all(ln.get("rehearsal") is True for ln in lines)
+    if detail:
+        return lines[-1], {ln["what"]: ln for ln in lines[:-1]}, proc.stderr
     return lines[-1]
 
 
@@ -121,6 +126,12 @@ def check_line(line, resolved, trace):
 def test_last_line(cell, trace, tmp_path):
     line = rehearse(cell, trace, tmp_path)
     check_line(line, mf.resolve_cell(mf.load_manifest(), cell), trace)
+    if trace:
+        # what PR 33 took out of setup_s and the three stretches it is made
+        # of: read in every cell, nulled here like every measured metric
+        for name in (PROCESS_METRICS | SETUP_METRICS
+                     | {"process_start_cpu_user_s"}):
+            assert line["metrics"][name] == {"value": None, "unit": "s"}
 
 
 @pytest.mark.parametrize("cell,trace", [
@@ -140,6 +151,74 @@ def test_cells_kept_for_later_arrive_as_data(later, cell, trace, tmp_path):
         assert line["metrics"]["rescued_row_share"]["value"] > 0
     if cell == "arima111.serve-bursty":
         assert line["attempted"] == 12
+
+
+def test_setup_counts_from_the_device_mark(tmp_path):
+    """``setup_s`` is the wall from the device mark to the window's
+    opening, and the stretch before the mark is on the ``start`` and
+    ``done`` lines of an untraced run too."""
+    line, detail, stderr = rehearse("hw-add24.walk-dense", 0, tmp_path,
+                                    detail=True)
+    start, done = detail["start"], detail["done"]
+    for name in PROCESS_METRICS:
+        assert start[name] == done[name] > 0
+    assert 0 < start["process_start_cpu_user_s"] \
+        <= start["process_start_cpu_s"]
+    # the start line is written right after the mark
+    assert 0 <= start["at_s"] - start["process_start_s"] < 1.0
+    assert done["setup_s"] > 0
+    assert abs(done["setup_end_at_s"] - done["process_start_s"]
+               - done["setup_s"]) < 0.005
+    # the three stretches in order, inside setup_s
+    panel, warm = detail["panel"], detail["warmup_walk"]
+    assert 0 < panel["since_device_s"] < warm["since_device_s"] \
+        <= done["setup_s"]
+    assert panel["since_device_s"] + warm["wall_s"] \
+        <= warm["since_device_s"]
+    assert line["metrics"]["setup_s"] == {"value": None, "unit": "s"}
+    # every number compared stands beside its limit: last in the line,
+    # and the last lines of stderr
+    assert list(line)[-1] == "checks" and line["correct"]
+    assert {"walks_unsound", "resume_arrays_differing",
+            "compiles_in_window"} <= set(line["checks"])
+    assert all(set(c) == {"value", "rule", "limit", "ok"} and c["ok"]
+               for c in line["checks"].values())
+    tail = [ln for ln in stderr.splitlines() if ln.startswith("check ")]
+    assert len(tail) == len(line["checks"])
+    assert stderr.splitlines()[-len(tail):] == tail
+
+
+def test_broken_timed_path_is_not_correct(tmp_path):
+    """The whole of a run but the look for a chip, over a fit whose
+    answers are altered where they are produced: ``correct`` is false, and
+    the number that failed stands beside its limit."""
+    root = tmp_path / "root"
+    (root / "broken" / "configs").mkdir(parents=True)
+    os.symlink(mf.BENCH_DIR, root / "benchmark")
+    m = mf.load_manifest()
+    cfg = mf.resolve_cell(m, "arima111.walk-dense").config
+    cfg["model"] = {**cfg["model"],
+                    "fit": "benchmark.tests.broken_fits:arima_params_shifted"}
+    (root / "broken" / "configs" / "arima111-broken.json").write_text(
+        json.dumps({**cfg, "name": "arima111-broken"}))
+    m["paths"].append("broken")
+    m["configs"].append({
+        "name": "arima111-broken", "source": "a test", "reduced": [],
+        "file": "broken/configs/arima111-broken.json", "why": "example"})
+    m["workloads"].append({
+        "name": "arima111-broken.walk-dense", "config": "arima111-broken",
+        "traffic": "walk-dense", "chips": 1, "why": "example"})
+    for e in m["end_to_end"] + m["per_layer"]:
+        if "arima111.walk-dense" in e.get("workloads", ()):
+            e["workloads"].append("arima111-broken.walk-dense")
+    (root / "BENCHMARK.json").write_text(json.dumps(m))
+    line, _, stderr = rehearse(
+        "arima111-broken.walk-dense", 0, tmp_path / "out", "--manifest",
+        str(root / "BENCHMARK.json"), detail=True)
+    assert line["correct"] is False and line["failed"] == 0
+    failed = {k for k, c in line["checks"].items() if not c["ok"]}
+    assert failed == {"reference_share_within_gap_0.1"}
+    assert "NOT MET" in stderr.splitlines()[-2]
 
 
 def test_no_tpu_no_result(tmp_path):
