@@ -174,9 +174,15 @@ def approx_aic(params, yd, order: Order, include_intercept: bool):
 # EXPANDED into plain lag-coefficient vectors (static shapes: p+P*s AR lags,
 # q+Q*s MA lags) and run through the exact `_css_errors_poly` scan the
 # non-seasonal fit uses — one recursion, one conditioning rule, one
-# concentrated-variance likelihood.  Seasonal fits run on the portable scan
-# backend (the fused Pallas kernel's folded layout has no seasonal lag
-# structure); `auto_fit` (models.auto) is the intended high-volume caller.
+# concentrated-variance likelihood.  That scan is the `scan` backend and the
+# reference.  On the Pallas backends the same recursion runs in the CSS
+# kernels over the product polynomials' LIVE lags only (`seasonal_lag_sets`:
+# the airline model (0,1,1)(0,1,1)_24 has the MA lags 1, 24 and 25 among the
+# 25 of its expanded vector), the parameters reaching the kernel's planes
+# through `_seasonal_kernel_params`; a seasonal fit takes the lockstep driver
+# like a plain one (`_sarima_family`).  `fit_grid` and `models.auto`'s
+# searches stay on the scan: their fused objective zero-pads every order to
+# the grid's depth.
 
 
 def _validate_seasonal(seasonal) -> Optional[Seasonal]:
@@ -269,6 +275,67 @@ def _sarima_css_errors(params, yd, order: Order, seasonal: Seasonal,
     theta_full = _expand_seasonal_poly(theta, stheta, s, 1.0)
     return _css_errors_poly(c, phi_full, theta_full, yd,
                             condition=condition, n_valid=n_valid)
+
+
+def _live_lags(n: int, N: int, s: int) -> dict:
+    """``lag -> [(i, j), ...]``: the terms of ``(1 -+ sum_i v_i L^i)(1 -+
+    sum_j w_j L^(js))`` that land on each lag, ascending — ``(i, 0)`` is
+    ``v_i``, ``(0, j)`` is ``w_j``, ``(i, j)`` their product at ``js + i``
+    (several terms on one lag once ``n >= s``).  Static: the lags
+    :func:`_expand_seasonal_poly` can make non-zero."""
+    terms: dict = {}
+    for j in range(N + 1):
+        for i in range(n + 1):
+            if i or j:
+                terms.setdefault(j * s + i, []).append((i, j))
+    return dict(sorted(terms.items()))
+
+
+def seasonal_lag_sets(order: Order, seasonal: Optional[Seasonal]):
+    """``(AR lags, MA lags)`` of the expanded recursion that can carry a
+    non-zero coefficient, each ascending — the lag sets the CSS kernels
+    take in place of the dense ranges ``1..p + P s`` / ``1..q + Q s``
+    (which they are without a seasonal part)."""
+    p, _, q = order
+    P, _, Q, s = seasonal or (0, 0, 0, 1)
+    return tuple(_live_lags(p, P, s)), tuple(_live_lags(q, Q, s))
+
+
+def _live_coefs(vals, svals, s: int, cross: float):
+    """:func:`_expand_seasonal_poly`'s map restricted to its live lags, on
+    batches: ``vals [B, n]``, ``svals [B, N]`` -> ``[B, lags]``, a column
+    per lag of :func:`_live_lags`."""
+    def term(i, j):
+        if not j:
+            return vals[:, i - 1]
+        if not i:
+            return svals[:, j - 1]
+        return cross * vals[:, i - 1] * svals[:, j - 1]
+
+    cols = [sum(term(i, j) for i, j in terms) for terms in
+            _live_lags(vals.shape[1], svals.shape[1], s).values()]
+    return jnp.stack(cols, axis=1)
+
+
+def _seasonal_kernel_params(params, order: Order, seasonal: Seasonal,
+                            include_intercept: bool):
+    """The model's parameters ``[B, k]`` -> the CSS kernel's planes ``[B, 1
+    + |A| + |M|]`` = ``[c, a_i (i in A), b_j (j in M)]`` over the lag sets
+    of :func:`seasonal_lag_sets`: the product map.  For the airline model
+    ``(b_1, b_24, b_25) = (theta, THETA, theta THETA)``, so the chain rule
+    JAX takes through it is ``d/dtheta = g_1 + THETA g_25``, ``d/dTHETA =
+    g_24 + theta g_25``: the map's transpose applied to the kernel's
+    gradient."""
+    s = seasonal[3]
+    c, phi, theta, sphi, stheta = jax.vmap(
+        lambda row: _split_params_seasonal(row, order, seasonal,
+                                           include_intercept))(params)
+    cols = [c[:, None]]
+    if phi.shape[1] + sphi.shape[1]:
+        cols.append(_live_coefs(phi, sphi, s, -1.0))
+    if theta.shape[1] + stheta.shape[1]:
+        cols.append(_live_coefs(theta, stheta, s, 1.0))
+    return jnp.concatenate(cols, axis=1)
 
 
 def seasonal_lag_span(order: Order, seasonal: Optional[Seasonal]
@@ -453,11 +520,12 @@ def fit(
     under ``"no-trailing"``), never as silently wrong estimates.
 
     ``seasonal=(P, D, Q, s)`` extends the recursion with multiplicative
-    seasonal terms (SARIMA): the seasonal polynomials are expanded into
-    plain lag coefficients and run through the SAME CSS scan, with the
-    parameter layout ``[c?, phi_1..p, theta_1..q, PHI_1..P, THETA_1..Q]``.
-    Seasonal fits run on the portable scan backend only (``backend`` must
-    resolve away from pallas) and support the optimizing CSS methods.
+    seasonal terms (SARIMA), with the parameter layout ``[c?, phi_1..p,
+    theta_1..q, PHI_1..P, THETA_1..Q]``: both differencings, then the same
+    driver, backends and flags as a plain fit.  On ``"scan"`` the seasonal
+    polynomials are expanded into plain lag coefficients and run through
+    the SAME CSS scan; the Pallas kernels take the expansion's live lags
+    only (:func:`seasonal_lag_sets`).  The optimizing CSS methods only.
 
     ``FitResult.status`` reports per-row ``reliability.FitStatus`` codes
     (OK / DIVERGED / EXCLUDED for a plain fit).
@@ -467,38 +535,49 @@ def fit(
     if count_evals and method == "hannan-rissanen":
         raise ValueError("count_evals requires an optimizing method")
     seasonal = _validate_seasonal(seasonal)
-    if seasonal is not None:
-        return _fit_seasonal(
-            y, order, seasonal, include_intercept, method=method,
-            init_params=init_params, max_iters=max_iters, tol=tol,
-            backend=backend, count_evals=count_evals,
-            align_mode=align_mode)
-    p, d, q = order
+    # the expanded recursion's reach and total differencing (the order's
+    # own p, q, d without a seasonal part)
+    p_full, q_full, d_full = seasonal_lag_span(order, seasonal)
     yb, single = ensure_batched(y)
+    n = yb.shape[1] - d_full
+    if seasonal is not None:
+        if method == "hannan-rissanen":
+            raise ValueError(
+                "seasonal orders require an optimizing CSS method "
+                "(hannan-rissanen has no seasonal init stage)")
+        if n < max(p_full + q_full + 2, 2):
+            raise ValueError(
+                f"series of length {yb.shape[1]} too short for seasonal "
+                f"order {order} x {seasonal} (needs > "
+                f"{d_full + p_full + q_full + 2} observations)")
     if tol is None:
         # f32 gradients of a ~1k-term CSS bottom out near 1e-4 relative noise
         tol = 1e-6 if yb.dtype == jnp.float64 else 1e-4
     from ..ops import pallas_kernels as pk
 
-    backend = resolve_backend(backend, yb.dtype, yb.shape[1] - d,
-                              structural_ok=pk.css_structural_ok(p, q))
+    backend = resolve_backend(
+        backend, yb.dtype, n,
+        structural_ok=pk.css_structural_ok(p_full, q_full))
     require_pallas_for_count_evals(count_evals, backend)
 
     align_mode = resolve_align_mode(yb, align_mode)
     has_init = init_params is not None
     static = (order, include_intercept, backend, max_iters, float(tol))
+    # what a kernel step pays: the live lag terms, and how far they reach
+    ar, ma = seasonal_lag_sets(order, seasonal)
     out = lockstep.fit(
         (yb, jnp.asarray(init_params)) if has_init else (yb,),
         backend=backend, max_iters=max_iters,
         compact=compact and method != "hannan-rissanen",
         inline=lambda: _fit_program(
             order, include_intercept, method, backend, max_iters, float(tol),
-            has_init, align_mode, count_evals, compact),
+            has_init, align_mode, count_evals, compact, seasonal),
         stage1=lambda: _fit_stage1_program(*static, has_init, align_mode,
-                                           count_evals),
-        stage2=lambda: _fit_stage2_program(*static),
-        series_block=lambda rows: pk.css_series_block(
-            rows, yb.shape[1] - d, order))
+                                           count_evals, seasonal),
+        stage2=lambda: _fit_stage2_program(*static, seasonal),
+        series_block=lambda rows: pk.css_series_block(rows, n, (ar, 0, ma)),
+        stage_attrs={"lag_terms": len(ar) + len(ma),
+                     "lag_span": max(ar + ma, default=0)})
     return debatch_fit(out, single, count_evals)
 
 
@@ -574,13 +653,82 @@ def _css_family(order: Order, include_intercept: bool, backend: str,
                            lambda x: x)
 
 
+def _sarima_family(order: Order, seasonal: Seasonal, include_intercept: bool,
+                   backend: str, has_init: bool = False,
+                   align_mode: Optional[str] = None) -> lockstep.Family:
+    """The seasonal fit, as :func:`_css_family` states the plain one: align,
+    both differencings, ONE fold; the non-seasonal Hannan-Rissanen warm
+    start on the fully differenced panel (the ``P + Q`` seasonal terms start
+    at 0: the optimizer owns them, the init is deterministic, and HR's
+    long-AR order stays static under the same ``nvd >= 4 (p + q + 1)``
+    contract); the CSS kernels over the live lags under the product map;
+    the expanded-polynomial scan as the portable objective."""
+    from ..ops import pallas_kernels as _pk
+
+    p, d, q = order
+    P, D, Q, s = seasonal
+    k = _n_params_seasonal(order, seasonal, include_intercept)
+    p_full, q_full, d_full = seasonal_lag_span(order, seasonal)
+    ar, ma = seasonal_lag_sets(order, seasonal)
+    interp = backend == "pallas-interpret"
+
+    def prep(yb, init_params=None):
+        with jax.named_scope("arima.sarima_align_and_difference"):
+            ya, nv0 = maybe_align(yb, align_mode)  # ragged: NaN head/tail
+            yd = jax.vmap(
+                lambda v: _difference_seasonal(_difference(v, d), D, s))(ya)
+            nvd = nv0 - d_full  # valid length after both differencings
+        folded = ()
+        if backend in lockstep.PALLAS:
+            folded = _CssFolded(
+                *_pk.css_prefold(yd, (p_full, 0, q_full), nvd), yd.shape[1])
+        with jax.named_scope("arima.sarima_init"):
+            if has_init:
+                init = jnp.broadcast_to(init_params, (yd.shape[0], k))
+            else:
+                if backend in lockstep.PALLAS and _pk.hr_structural_ok(p, q):
+                    base = _pk.hr_init(yd, (p, 0, q), include_intercept, nvd,
+                                       interpret=interp, y3=folded.y3)
+                else:
+                    base = hannan_rissanen_batched(
+                        yd, (p, 0, q), include_intercept, nvd)
+                init = jnp.concatenate(
+                    [base, jnp.zeros((yd.shape[0], P + Q), yd.dtype)], axis=1)
+        ok = nvd >= p_full + q_full + max(p_full + q_full + 1, 1) + k + 2
+        if not has_init:
+            ok = ok & (nvd >= 4 * (p + q + 1))
+        n_eff = jnp.maximum(nvd - p_full, 1).astype(yd.dtype)
+        return lockstep.Prepared((init,), ok, n_eff, (yd, nvd), folded,
+                                 (nvd,))
+
+    def objective(folded, rows):
+        (nvd,) = rows
+        return lambda X: _pk.css_seasonal_neg_loglik_folded(
+            _seasonal_kernel_params(X, order, seasonal, include_intercept),
+            folded.y3, folded.zb3, folded.t, ar, ma, nvd, interpret=interp)
+
+    def scan_objective(pr, data):
+        yv, n = data
+        return sarima_neg_loglik(pr, yv, order, seasonal, include_intercept,
+                                 n)
+
+    return lockstep.Family(backend, prep, objective, scan_objective,
+                           lambda x: x)
+
+
+def _family(order: Order, seasonal: Optional[Seasonal], *args):
+    if seasonal is None:
+        return _css_family(order, *args)
+    return _sarima_family(order, seasonal, *args)
+
+
 @jit_program
 def _fit_program(order: Order, include_intercept: bool, method: str,
                  backend: str, max_iters: int, tol: float, has_init: bool,
                  align_mode: str = "general", count_evals: bool = False,
-                 compact: bool = True):
-    family = _css_family(order, include_intercept, backend, has_init,
-                         align_mode)
+                 compact: bool = True, seasonal: Optional[Seasonal] = None):
+    family = _family(order, seasonal, include_intercept, backend, has_init,
+                     align_mode)
     if method != "hannan-rissanen":
         return lockstep.fit_program(family, max_iters, tol, count_evals,
                                     compact)
@@ -601,113 +749,19 @@ def _fit_program(order: Order, include_intercept: bool, method: str,
 
 @jit_program
 def _fit_stage1_program(order, include_intercept, backend, max_iters, tol,
-                        has_init, align_mode="general", count_evals=False):
+                        has_init, align_mode="general", count_evals=False,
+                        seasonal=None):
     return lockstep.stage1_program(
-        _css_family(order, include_intercept, backend, has_init, align_mode),
+        _family(order, seasonal, include_intercept, backend, has_init,
+                align_mode),
         max_iters, tol, count_evals)
 
 
 @jit_program
-def _fit_stage2_program(order, include_intercept, backend, max_iters, tol):
+def _fit_stage2_program(order, include_intercept, backend, max_iters, tol,
+                        seasonal=None):
     return lockstep.stage2_program(
-        _css_family(order, include_intercept, backend), max_iters, tol)
-
-
-def _fit_seasonal(
-    y,
-    order: Order,
-    seasonal: Seasonal,
-    include_intercept: bool,
-    *,
-    method: str,
-    init_params: Optional[jax.Array],
-    max_iters: int,
-    tol: Optional[float],
-    backend: str,
-    count_evals: bool,
-    align_mode: Optional[str],
-) -> FitResult:
-    """Seasonal branch of :func:`fit` (validated ``seasonal`` only)."""
-    if method == "hannan-rissanen":
-        raise ValueError(
-            "seasonal orders require an optimizing CSS method "
-            "(hannan-rissanen has no seasonal init stage)")
-    if count_evals:
-        raise ValueError(
-            "count_evals instruments the fused pallas objective; seasonal "
-            "fits run on the scan backend")
-    if backend not in ("auto", "scan"):
-        raise ValueError(
-            f"seasonal orders run on the portable scan backend (the fused "
-            f"kernel's folded layout has no seasonal lag structure); got "
-            f"backend={backend!r}")
-    p_full, q_full, d_full = seasonal_lag_span(order, seasonal)
-    yb, single = ensure_batched(y)
-    if yb.shape[1] - d_full < max(p_full + q_full + 2, 2):
-        raise ValueError(
-            f"series of length {yb.shape[1]} too short for seasonal order "
-            f"{order} x {seasonal} (needs > {d_full + p_full + q_full + 2} "
-            "observations)")
-    if tol is None:
-        tol = 1e-6 if yb.dtype == jnp.float64 else 1e-4
-    align_mode = resolve_align_mode(yb, align_mode)
-    run = _fit_sarima_program(order, seasonal, include_intercept, max_iters,
-                              float(tol), init_params is not None, align_mode)
-    if init_params is None:
-        out = run(yb)
-    else:
-        out = run(yb, jnp.asarray(init_params))
-    return debatch_fit(out, single, False)
-
-
-@jit_program
-def _fit_sarima_program(order, seasonal, include_intercept, max_iters, tol,
-                        has_init, align_mode="general"):
-    """One compiled program per (order, seasonal, ...) static config —
-    align + both differencings, the non-seasonal Hannan-Rissanen warm
-    start (seasonal terms start at 0: the optimizer owns them), the
-    identifiability gate, and the vmapped L-BFGS on the expanded-
-    polynomial CSS objective."""
-    p, d, q = order
-    P, D, Q, s = seasonal
-    k = _n_params_seasonal(order, seasonal, include_intercept)
-    p_full, q_full, d_full = seasonal_lag_span(order, seasonal)
-
-    def run(yb, init_params=None):
-        with jax.named_scope("arima.sarima_align_and_difference"):
-            ya, nv0 = maybe_align(yb, align_mode)  # ragged: NaN head/tail
-            yd = jax.vmap(
-                lambda v: _difference_seasonal(_difference(v, d), D, s))(ya)
-            nvd = nv0 - d_full  # valid length after both differencings
-        with jax.named_scope("arima.sarima_init"):
-            if has_init:
-                init = jnp.broadcast_to(init_params, (yd.shape[0], k))
-            else:
-                # short-memory (p, q) warm start on the fully differenced
-                # series; the P+Q seasonal terms start at 0 so the init is
-                # deterministic and the gate below keeps HR's long-AR order
-                # static (same nvd >= 4*(p+q+1) contract as _css_family's prep)
-                base = hannan_rissanen_batched(
-                    yd, (p, 0, q), include_intercept, nvd)
-                init = jnp.concatenate(
-                    [base, jnp.zeros((yd.shape[0], P + Q), yd.dtype)], axis=1)
-        ok = nvd >= p_full + q_full + max(p_full + q_full + 1, 1) + k + 2
-        if not has_init:
-            ok = ok & (nvd >= 4 * (p + q + 1))
-        # optimize the MEAN log-likelihood (lockstep.Prepared.scale)
-        n_eff = jnp.maximum(nvd - p_full, 1).astype(yd.dtype)
-        res = optim.batched_minimize(
-            lambda pr, data: sarima_neg_loglik(
-                pr, data[0], order, seasonal, include_intercept, data[1]
-            ) / data[2],
-            init,
-            (yd, nvd, n_eff),
-            max_iters=max_iters,
-            tol=tol,
-        )
-        return lockstep.finalize(res, ok, n_eff)
-
-    return run
+        _family(order, seasonal, include_intercept, backend), max_iters, tol)
 
 
 # ---------------------------------------------------------------------------
@@ -942,7 +996,7 @@ def _grid_fit_program(specs, include_intercept, max_iters, tol,
             with jax.named_scope("arima.grid_init"):
                 # non-seasonal HR warm start on the (fully) differenced
                 # panel; seasonal terms start at 0 (same contract as
-                # _fit_sarima_program).  Inside the ok region the
+                # _sarima_family's prep).  Inside the ok region the
                 # embedding cannot change HR's static long-AR order m
                 # (the nvd >= 4*(p+q+1) gate pins m = p+q+1 either way).
                 base = hannan_rissanen_batched(

@@ -78,16 +78,25 @@ def resolve_backend(backend: str, dtype, n_time: int,
     parameters fit the kernel's chunked layout (``structural_ok`` — e.g.
     ``pk.css_structural_ok(p, q)``), else the portable ``lax.scan`` path.
     An explicitly requested ``"pallas"`` with violating structure raises at
-    the kernel entry point instead.  Shared by every model family so the
+    the kernel entry point instead, and one the platform or dtype cannot
+    run natively is refused here.  Shared by every model family so the
     backend vocabulary cannot drift between them.
     """
     if backend not in ("auto", "scan", "pallas", "pallas-interpret"):
         raise ValueError(f"unknown backend {backend!r}")
-    if backend != "auto":
+    if backend in ("scan", "pallas-interpret"):
         return backend
     from ..ops import pallas_kernels as pk
 
-    return "pallas" if structural_ok and pk.supported(dtype, n_time) else "scan"
+    native = pk.supported(dtype, n_time)
+    if backend == "pallas":
+        if not native:
+            raise ValueError(
+                "backend='pallas' runs the fused kernels natively: float32 "
+                "on a TPU; elsewhere use 'pallas-interpret' or the scan "
+                "backend")
+        return backend
+    return "pallas" if structural_ok and native else "scan"
 
 
 class FitResult(NamedTuple):

@@ -196,27 +196,33 @@ def merge_program(family: Family):
     return run
 
 
-def _block_attr(series_block, rows: int) -> dict:
-    """A stage span's ``series_block`` attribute: the series the objective's
-    value-only kernel takes per grid step over ``rows`` series (1,024 x the
-    ``pallas_kernels.series_rows`` of its shapes), computed on the host and
-    only when the tracing plane is on."""
-    if series_block is None or not obs.enabled():
+def _kernel_attrs(series_block, stage_attrs, rows: int) -> dict:
+    """What a stage span says of the objective kernel: ``series_block``,
+    the series its value-only kernel takes per grid step over ``rows``
+    series (1,024 x the ``pallas_kernels.series_rows`` of its shapes), and
+    the family's own static ``stage_attrs``; computed on the host and only
+    when the tracing plane is on."""
+    if not obs.enabled():
         return {}
-    return {"series_block": series_block(rows)}
+    block = {} if series_block is None else {
+        "series_block": series_block(rows)}
+    return {**block, **(stage_attrs or {})}
 
 
 def fit(args: tuple, *, backend: str, compact: bool, max_iters: int,
         inline: Callable, stage1: Callable, stage2: Callable,
         merge: Optional[Callable] = None,
-        series_block: Optional[Callable[[int], int]] = None):
+        series_block: Optional[Callable[[int], int]] = None,
+        stage_attrs: Optional[dict] = None):
     """Fit the panel ``args[0]`` with a family's compiled programs, each
     given as a thunk that looks it up (only the programs that run are looked
     up, and stage 2 is traced and compiled only when a stage 1 leaves
     unconverged rows).  Returns what the programs return: a ``FitResult``,
     or ``(FitResult, info)`` from programs built with ``count_evals``.
     ``series_block`` is the family's ``rows -> series per grid step`` of its
-    objective kernel (reported on the stage spans; it chooses nothing).
+    objective kernel and ``stage_attrs`` what else it has to say of a
+    kernel step (ARIMA: ``lag_terms``, ``lag_span``); both are reported on
+    the stage spans and choose nothing.
 
     The lazy pair runs on the pallas backends when the batch is concrete
     and large enough for the compaction to pay (``optim.COMPACT_MIN_BATCH``,
@@ -240,7 +246,8 @@ def fit(args: tuple, *, backend: str, compact: bool, max_iters: int,
         undone = [int(s["carry"].undone) for s in starts]
         if obs.enabled():
             span.set(iters=max(int(s["carry"].k) for s in starts),
-                     undone=sum(undone), **_block_attr(series_block, bsz))
+                     undone=sum(undone),
+                     **_kernel_attrs(series_block, stage_attrs, bsz))
     results, reran, info = [], False, None
     for start, n_undone in zip(starts, undone):
         carry, res = start["carry"], start["res"]
@@ -252,7 +259,7 @@ def fit(args: tuple, *, backend: str, compact: bool, max_iters: int,
         if n_undone > 0 and int(carry.k) < max_iters:
             cap = optim.compaction_cap(bsz)
             with obs.span("fit.stage2", rows=cap,
-                          **_block_attr(series_block, cap)):
+                          **_kernel_attrs(series_block, stage_attrs, cap)):
                 res = (stage2()(start) if merge
                        else stage2()(start, aux["fin"]))
             if counted:

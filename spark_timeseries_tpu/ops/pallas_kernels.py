@@ -176,14 +176,30 @@ def supported(dtype, n_time: int) -> bool:
     return platform == "tpu" and jnp.dtype(dtype) == jnp.dtype(jnp.float32)
 
 
-def css_structural_ok(p: int, q: int) -> bool:
+def _lags(n) -> Tuple[int, ...]:
+    """A static lag set, ascending: an int ``n`` is the dense set ``1..n``
+    (the plain ARMA orders), anything else the set itself (the live lags of
+    a seasonal product polynomial: ``(1, 24, 25)`` for the airline model)."""
+    if isinstance(n, int):
+        return tuple(range(1, n + 1))
+    return tuple(sorted(int(lag) for lag in n))
+
+
+def _span(lags) -> int:
+    """The largest lag of a set: what the carries and the mask reach back."""
+    return max(lags, default=0)
+
+
+def css_structural_ok(p, q) -> bool:
     """The CSS kernels' chunked layout: lag reads reach back at most one
     chunk (the neighbor input block), and the cross-chunk adjoint/error
-    stashes interleave their reads (positions ``>= cs - order``) with their
-    writes (positions ``< order``) inside one chunk, which is race-free only
-    while ``order <= chunk/2`` — so both orders must stay under
+    stashes interleave their reads (positions ``>= cs - lag``) with their
+    writes (positions ``< lag``) inside one chunk, which is race-free only
+    while ``lag <= chunk/2`` — so the LARGEST lag of either side (``p`` /
+    ``q``: an order or a lag set, :func:`_lags`) must stay under
     ``_CHUNK_T // 2``."""
-    return 0 <= p <= _CHUNK_T // 2 and 0 <= q <= _CHUNK_T // 2
+    return all((not isinstance(n, int) or n >= 0)
+               and _span(_lags(n)) <= _CHUNK_T // 2 for n in (p, q))
 
 
 def hw_structural_ok(period: int) -> bool:
@@ -273,24 +289,30 @@ def _rev_prev(nchunk):  # previous TIME chunk while walking backward
 # ARMA CSS one-step-ahead prediction errors (forward + hand-derived adjoint)
 # ---------------------------------------------------------------------------
 #
-# Per series (reference ARIMAModel.logLikelihoodCSSARMA):
-#   u_t = y_t - c - sum_i phi_i * y_{t-i} - sum_j theta_j * e_{t-j}
+# Per series (reference ARIMAModel.logLikelihoodCSSARMA), over STATIC lag
+# sets A (AR side) and M (MA side) with one coefficient plane per live lag
+# — the plain ARMA(p, q) is the dense sets 1..p and 1..q, a multiplicative
+# seasonal model the few non-zero lags of its product polynomials (the
+# airline model (0,1,1)(0,1,1)_24: M = {1, 24, 25}, three lag terms a step
+# where the dense range pays 25):
+#   u_t = y_t - c - sum_{i in A} a_i * y_{t-i} - sum_{j in M} b_j * e_{t-j}
 #   e_t = m_t * u_t        with m_t = [zb <= t < t_limit], y_{<0} = e_{<0} = 0
 #
 # Adjoint (reference gradientLogLikelihoodCSSARMA, generalized to an
 # arbitrary upstream cotangent gbar of e):
-#   a_t         = m_t * (gbar_t - sum_j theta_j * a_{t+j})      (t descending)
-#   dL/dc       = -sum_t a_t
-#   dL/dphi_i   = -sum_t y_{t-i} * a_t
-#   dL/dtheta_j = -sum_t e_{t-j} * a_t
+#   al_t      = m_t * (gbar_t - sum_{j in M} b_j * al_{t+j})    (t descending)
+#   dL/dc     = -sum_t al_t
+#   dL/da_i   = -sum_t y_{t-i} * al_t
+#   dL/db_j   = -sum_t e_{t-j} * al_t
 #
-# Cross-chunk state: the forward carries the last q errors (scratch); the
-# backward carries the adjoints of the first q positions of the next-later
-# chunk (scratch) and accumulates the k parameter gradients in the revisited
-# output block.
+# Parameter planes: [c, a_i (i in A, ascending), b_j (j in M, ascending)].
+# Cross-chunk state: the forward carries the last max(M) errors (scratch);
+# the backward carries the adjoints of the first max(M) positions of the
+# next-later chunk (scratch; max(A) more for the data cotangent) and
+# accumulates the k parameter gradients in the revisited output block.
 
 
-def _css_fwd_kernel(p, q, t_limit, cs, hp, mode, *refs):
+def _css_fwd_kernel(ar, ma, t_limit, cs, hp, mode, *refs):
     # mode "e":    errors out (the css_errors vjp building block)
     # mode "sum":  ONLY the per-series sum of squares leaves the kernel
     #              (linesearch evaluations: the [B, T] error write + re-read
@@ -301,6 +323,7 @@ def _css_fwd_kernel(p, q, t_limit, cs, hp, mode, *refs):
     # mode "tail": ONLY the last q errors leave the kernel (the forecast
     #              carry rebuild: a read-only pass over y instead of a full
     #              [B, T] error write the caller immediately discards)
+    p, q = len(ar), _span(ma)  # AR planes; the MA side's reach
     refs = list(refs)
     y_ref = refs.pop(0)
     yp_ref = refs.pop(0) if hp else None
@@ -328,17 +351,17 @@ def _css_fwd_kernel(p, q, t_limit, cs, hp, mode, *refs):
     def body(tl, acc):
         t = base + tl
         pred = par_ref[0]
-        for i in range(1, p + 1):
+        for n, i in enumerate(ar, 1):
             far = yp_ref[jnp.clip(cs + tl - i, 0, cs - 1)] if hp else 0.0
             yv = jnp.where(tl - i >= 0, y_ref[jnp.maximum(tl - i, 0)], far)
-            pred += par_ref[i] * jnp.where(t - i >= 0, yv, 0.0)
-        for j in range(1, q + 1):
+            pred += par_ref[n] * jnp.where(t - i >= 0, yv, 0.0)
+        for n, j in enumerate(ma, 1):
             ev = jnp.where(
                 tl - j >= 0,
                 e_ref[jnp.maximum(tl - j, 0)],
                 ce_ref[jnp.clip(q + tl - j, 0, max(q - 1, 0))],
             )
-            pred += par_ref[p + j] * jnp.where(t - j >= 0, ev, 0.0)
+            pred += par_ref[p + n] * jnp.where(t - j >= 0, ev, 0.0)
         live = (t.astype(jnp.float32) >= zb) & (t < t_limit)
         e = jnp.where(live, y_ref[tl] - pred, 0.0)
         if e_ref is not None:  # sum mode with q == 0 never reads errors back
@@ -367,7 +390,10 @@ def _css_fwd_kernel(p, q, t_limit, cs, hp, mode, *refs):
         ce_ref[j] = e_ref[cs - q + j]
 
 
-def _css_bwd_kernel(p, q, t_limit, cs, nchunk, hp, want_gy, *refs):
+def _css_bwd_kernel(ar, ma, t_limit, cs, nchunk, hp, want_gy, *refs):
+    # p / q: the reach of each side (the carries' depth); the parameter
+    # planes number one per LIVE lag
+    p, q, npar = _span(ar), _span(ma), len(ar)
     refs = list(refs)
     y_ref = refs.pop(0)
     yp_ref = refs.pop(0) if hp else None
@@ -384,7 +410,7 @@ def _css_bwd_kernel(p, q, t_limit, cs, nchunk, hp, want_gy, *refs):
     c = pl.program_id(1)
     base = (nchunk - 1 - c) * cs
     zb = zb_ref[0]
-    k = 1 + p + q
+    k = 1 + npar + len(ma)
 
     @pl.when(c == 0)
     def _():
@@ -404,10 +430,10 @@ def _css_bwd_kernel(p, q, t_limit, cs, nchunk, hp, want_gy, *refs):
         live = (t.astype(jnp.float32) >= zb) & (t < t_limit)
         aval = adj_ref[tl]
         # contributions from a_{t+j} that live in the next-later chunk
-        for j in range(1, q + 1):
+        for n, j in enumerate(ma, 1):
             aval = aval - jnp.where(
                 tl + j >= cs,
-                par_ref[p + j] * ca_ref[jnp.clip(tl + j - cs, 0, max(q - 1, 0))],
+                par_ref[npar + n] * ca_ref[jnp.clip(tl + j - cs, 0, max(q - 1, 0))],
                 0.0,
             )
         a = jnp.where(live, aval, 0.0)
@@ -416,17 +442,17 @@ def _css_bwd_kernel(p, q, t_limit, cs, nchunk, hp, want_gy, *refs):
             # and every theta adjustment targeting it landed before its own
             # iteration, so the slot is dead — overwrite it with the FINAL
             # adjoint a_s and read it back for the data cotangent
-            #   dL/dy_t = a_t - sum_i phi_i a_{t+i}
-            # (a_{t+i} in the next-later chunk comes from the cap carry)
+            #   dL/dy_t = a_t - sum_{i in A} a_i al_{t+i}
+            # (al_{t+i} in the next-later chunk comes from the cap carry)
             adj_ref[tl] = a
             gy = a
-            for i_ in range(1, p + 1):
+            for n, i_ in enumerate(ar, 1):
                 far = (cap_ref[jnp.clip(tl + i_ - cs, 0, max(p - 1, 0))]
                        if hp else 0.0)
                 av = jnp.where(
                     tl + i_ < cs, adj_ref[jnp.clip(tl + i_, 0, cs - 1)], far
                 )
-                gy = gy - par_ref[i_] * av
+                gy = gy - par_ref[n] * av
             gy_ref[tl] = gy
             if hp and p > 0:
                 # stash a for the chunk below: writes hit tl < p, reads need
@@ -435,21 +461,21 @@ def _css_bwd_kernel(p, q, t_limit, cs, nchunk, hp, want_gy, *refs):
                 cap_ref[jnp.clip(tl, 0, max(p - 1, 0))] = jnp.where(
                     tl < p, a, curc
                 )
-        for j in range(1, q + 1):
+        for n, j in enumerate(ma, 1):
             idx = jnp.maximum(tl - j, 0)
-            contrib = jnp.where(tl - j >= 0, par_ref[p + j] * a, 0.0)
+            contrib = jnp.where(tl - j >= 0, par_ref[npar + n] * a, 0.0)
             adj_ref[idx] = adj_ref[idx] - contrib
         new = [accs[0] - a]
-        for i_ in range(1, p + 1):
+        for n, i_ in enumerate(ar, 1):
             far = yp_ref[jnp.clip(cs + tl - i_, 0, cs - 1)] if hp else 0.0
             yv = jnp.where(tl - i_ >= 0, y_ref[jnp.maximum(tl - i_, 0)], far)
             yv = jnp.where(t - i_ >= 0, yv, 0.0)
-            new.append(accs[i_] - yv * a)
-        for j in range(1, q + 1):
+            new.append(accs[n] - yv * a)
+        for n, j in enumerate(ma, 1):
             far = ep_ref[jnp.clip(cs + tl - j, 0, cs - 1)] if hp else 0.0
             ev = jnp.where(tl - j >= 0, e_ref[jnp.maximum(tl - j, 0)], far)
             ev = jnp.where(t - j >= 0, ev, 0.0)
-            new.append(accs[p + j] - ev * a)
+            new.append(accs[npar + n] - ev * a)
         # stash a for the chunk below: writes hit tl < q, reads need
         # tl >= cs - q; disjoint because cs >= 2q
         cur = ca_ref[jnp.clip(tl, 0, max(q - 1, 0))]
@@ -462,11 +488,13 @@ def _css_bwd_kernel(p, q, t_limit, cs, nchunk, hp, want_gy, *refs):
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1, 2))
-def css_errors(p: int, q: int, interpret: bool, params, yd, zb):
+def css_errors(p, q, interpret: bool, params, yd, zb):
     """Batched ARMA(p, q) CSS errors ``[B, T]`` on a fused TPU kernel.
 
-    ``params``: ``[B, 1 + p + q]`` rows ``[c, phi_1..p, theta_1..q]`` (models
-    without an intercept pass ``c = 0``); ``yd``: ``[B, T]`` differenced
+    ``p`` / ``q``: an order (the dense lags ``1..p``) or a static lag set
+    (:func:`_lags`).  ``params``: ``[B, 1 + p + q]`` rows ``[c, phi_1..p,
+    theta_1..q]``, one column per live lag (models without an intercept
+    pass ``c = 0``); ``yd``: ``[B, T]`` differenced
     series with any invalid prefix already zeroed; ``zb``: ``[B]`` float —
     errors before this position are forced to zero (``start + p`` for the
     conditional likelihood).  Differentiable in ``params`` AND ``yd`` (the
@@ -474,18 +502,22 @@ def css_errors(p: int, q: int, interpret: bool, params, yd, zb):
     backward-kernel output computed only when ``yd`` is perturbed, so the
     params-only fit path pays nothing for it — ADVICE r4).
     """
-    if not css_structural_ok(p, q):
-        raise ValueError(
-            f"fused CSS kernel supports p, q <= {_CHUNK_T // 2} (got p={p}, q={q}); "
-            "use backend='scan'"
-        )
+    _require_css_structure(p, q)
     e, _ = _css_errors_primal(p, q, interpret, params, yd, zb)
     return e
 
 
+def _require_css_structure(p, q):
+    if not css_structural_ok(p, q):
+        raise ValueError(
+            f"fused CSS kernel supports lags <= {_CHUNK_T // 2} (got p={p}, "
+            f"q={q}); use backend='scan'"
+        )
+
+
 def _css_fwd_call(p, q, interpret, mode, params, yd, zb):
     b, t = yd.shape
-    k = 1 + p + q
+    k = 1 + len(_lags(p)) + len(_lags(q))
     assert params.shape == (b, k), (params.shape, (b, k))
     tp, cs, nchunk = _time_layout(t)
     y3 = _fold(jnp.pad(yd, ((0, 0), (0, tp - t))))
@@ -502,10 +534,13 @@ _CSS_R = {"sum": 4, "both": 4, "e": 4, "tail": 4}
 
 def _css_fwd_layout(p, q, mode, t):
     """The forward CSS call's blocks -> ``(ins, outs, scratch)``
-    (:func:`_vmem_bytes`)."""
+    (:func:`_vmem_bytes`): a parameter plane per live lag, the error carry
+    and the tail as deep as the largest MA lag."""
+    ar, ma = _lags(p), _lags(q)
+    q = _span(ma)
     _, cs, nchunk = _time_layout(t)
     ins = ([(cs, _cur)] + ([(cs, _prev)] if nchunk > 1 else [])
-           + [(1 + p + q, _fixed), (1, _fixed)])
+           + [(1 + len(ar) + len(ma), _fixed), (1, _fixed)])
     outs = []
     if mode in ("e", "both"):
         outs.append((cs, _cur))
@@ -520,7 +555,8 @@ def _css_fwd_layout(p, q, mode, t):
 
 def css_series_block(rows: int, t: int, order: Order, mode: str = "sum") -> int:
     """Series per grid step of the forward CSS kernel over ``rows`` series
-    of (differenced) length ``t``: ``1024 * R`` (:func:`series_rows`)."""
+    of (differenced) length ``t``: ``1024 * R`` (:func:`series_rows`).
+    ``order``'s ``p`` / ``q`` may be lag sets (:func:`_lags`)."""
     p, _, q = order
     return _SBLK * series_rows(
         _nsub(rows), _css_fwd_layout(p, q, mode, t), _CSS_R[mode])
@@ -559,7 +595,8 @@ def _css_fwd_call_f(p, q, interpret, mode, params, y3, zb3, t, _r=None):
     layout = _css_fwd_layout(p, q, mode, t)
     r = _r or series_rows(y3.shape[1], layout, _CSS_R[mode])
     outs = _fwd_call(
-        functools.partial(_css_fwd_kernel, p, q, t, cs, hp, mode),
+        functools.partial(_css_fwd_kernel, _lags(p), _lags(q), t, cs, hp,
+                          mode),
         layout, r, interpret, (*((y3, y3) if hp else (y3,)), par3, zb3))
     return outs, (y3, par3, zb3)
 
@@ -584,8 +621,9 @@ def _css_errors_fwd(p, q, interpret, params, yd, zb):
 
 
 @_scoped("pallas.css_last_errors")
-def css_last_errors(p: int, q: int, interpret: bool, params, yd, zb):
-    """The last ``q`` one-step CSS errors ``[B, q]`` (oldest first).
+def css_last_errors(p, q, interpret: bool, params, yd, zb):
+    """The last ``q`` one-step CSS errors ``[B, q]`` (oldest first; for a
+    lag set ``q`` as many as its largest lag).
 
     The forecast carry rebuild (``models.arima.forecast``) needs only the
     trailing ``q`` errors; this runs the same recursion as
@@ -594,14 +632,11 @@ def css_last_errors(p: int, q: int, interpret: bool, params, yd, zb):
     Not differentiable (forecasting is a post-fit read-only path; use the
     scan backend for gradients through forecasts).
     """
-    if not css_structural_ok(p, q):
-        raise ValueError(
-            f"fused CSS kernel supports p, q <= {_CHUNK_T // 2} (got p={p}, q={q}); "
-            "use backend='scan'"
-        )
-    if q == 0:
+    _require_css_structure(p, q)
+    reach = _span(_lags(q))
+    if reach == 0:
         return jnp.zeros((yd.shape[0], 0), yd.dtype)
-    if yd.shape[1] < q:
+    if yd.shape[1] < reach:
         raise ValueError(f"series length {yd.shape[1]} < q={q}")
     b, t = yd.shape
     (tail3,), _ = _css_fwd_call(p, q, interpret, "tail", params, yd, zb)
@@ -609,8 +644,7 @@ def css_last_errors(p: int, q: int, interpret: bool, params, yd, zb):
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1, 2, 3, 4))
-def _css_ss_f(p: int, q: int, interpret: bool, t: int, b: int,
-              params, y3, zb3):
+def _css_ss_f(p, q, interpret: bool, t: int, b: int, params, y3, zb3):
     """Per-series CSS sum of squared errors ``[B]`` from the FOLDED layout
     (differentiable in ``params`` and ``y3`` — the data cotangent is computed
     only when the data is perturbed; ``t``/``b`` are the true unpadded
@@ -637,7 +671,7 @@ def _css_ss_f_fwd(p, q, interpret, t, b, params, y3, zb3):
 
 def _css_ss_f_bwd(p, q, interpret, t, b, resid, gbar):
     y3, par3, zb3, e3, marker = resid
-    k = 1 + p + q
+    k = par3.shape[0]
     if isinstance(gbar, SymbolicZero):  # output provably unused
         return (jnp.zeros((b, k), e3.dtype), jnp.zeros(y3.shape, y3.dtype),
                 jnp.zeros(zb3.shape, zb3.dtype))
@@ -669,9 +703,10 @@ def css_prefold(yd, order: Order, n_valid=None):
     The fit objective runs hundreds of evaluations inside one
     ``lax.while_loop``; folding outside the loop keeps the [B, T]
     zero-mask + layout transpose off every evaluation (XLA does not
-    reliably hoist them out of the loop body).
+    reliably hoist them out of the loop body).  ``order``'s ``p`` may be a
+    lag set: the conditioning depth is its largest lag.
     """
-    p, _, q = order
+    p = _span(_lags(order[0]))
     b, n = yd.shape
     nv = jnp.full((b,), n, yd.dtype) if n_valid is None else n_valid.astype(yd.dtype)
     start = n - nv
@@ -691,23 +726,44 @@ def css_neg_loglik_folded(params, y3, zb3, n: int, order: Order,
     (:func:`css_prefold`).  Matches :func:`css_neg_loglik` exactly."""
     p, _, q = order
     b = params.shape[0]
-    nv = (jnp.full((b,), n, params.dtype) if n_valid is None
-          else n_valid.astype(params.dtype))
     if include_intercept:
         params_k = params
     else:  # kernel layout always carries an intercept slot
         params_k = jnp.concatenate(
             [jnp.zeros((b, 1), params.dtype), params], axis=1
         )
+    return _css_nll_f(p, q, interpret, n, params_k, y3, zb3, n_valid)
+
+
+def _css_nll_f(p, q, interpret, n, params_k, y3, zb3, n_valid):
+    """The concentrated Gaussian likelihood of the kernel's sum of squares,
+    ``n_eff`` the valid length less the AR side's reach."""
+    b = params_k.shape[0]
+    nv = (jnp.full((b,), n, params_k.dtype) if n_valid is None
+          else n_valid.astype(params_k.dtype))
     css = _css_ss_f(p, q, interpret, n, b, params_k, y3, zb3)
-    n_eff = nv - p
+    n_eff = nv - _span(_lags(p))
     sigma2 = css / n_eff
     return 0.5 * n_eff * (jnp.log(2.0 * jnp.pi * sigma2) + 1.0)
 
 
+@_scoped("pallas.css_seasonal_neg_loglik")
+def css_seasonal_neg_loglik_folded(params_k, y3, zb3, n: int, ar, ma,
+                                   n_valid=None, *, interpret: bool = False):
+    """The same likelihood over the lag sets ``ar`` / ``ma`` of a seasonal
+    product polynomial, from a panel folded with the sets' reach
+    (``css_prefold(yd, (max(ar), 0, max(ma)), n_valid)``); ``params_k``:
+    ``[B, 1 + len(ar) + len(ma)]`` kernel planes ``[c, a.., b..]`` over the
+    LIVE lags (``models.arima`` owns the map from the model's parameters
+    and its chain rule).  A scope of its own, so that a trace tells a
+    seasonal fit's kernel events from a plain ARMA's."""
+    return _css_nll_f(tuple(ar), tuple(ma), interpret, n, params_k, y3, zb3,
+                      n_valid)
+
+
 def _css_errors_bwd(p, q, interpret, res, g):
     y3, par3, zb3, e3, b, t, marker = res
-    k = 1 + p + q
+    k = par3.shape[0]
     if isinstance(g, SymbolicZero):  # output provably unused: all-zero grads
         return (jnp.zeros((b, k), e3.dtype), jnp.zeros((b, t), e3.dtype),
                 jnp.zeros((b,), e3.dtype))
@@ -731,7 +787,8 @@ def _css_errors_bwd_f(p, q, interpret, res, g3, b, t, want_gy=False):
     the folded layout (an extra kernel output only callers that perturb the
     data pay for — see ``_css_ss_f_fwd``)."""
     y3, par3, zb3, e3 = res
-    k = 1 + p + q
+    ar, ma = _lags(p), _lags(q)
+    k = par3.shape[0]
     _, cs, nchunk = _time_layout(t)
     nblk = y3.shape[1] // _SUBL
     hp = nchunk > 1
@@ -751,12 +808,14 @@ def _css_errors_bwd_f(p, q, interpret, res, g3, b, t, want_gy=False):
         out_shape.append(jax.ShapeDtypeStruct(y3.shape, g3.dtype))
     scratch = [
         pltpu.VMEM((cs, _SUBL, _LANES), jnp.float32),
-        pltpu.VMEM((max(q, 1), _SUBL, _LANES), jnp.float32),
+        pltpu.VMEM((max(_span(ma), 1), _SUBL, _LANES), jnp.float32),
     ]
     if want_gy:
-        scratch.append(pltpu.VMEM((max(p, 1), _SUBL, _LANES), jnp.float32))
+        scratch.append(
+            pltpu.VMEM((max(_span(ar), 1), _SUBL, _LANES), jnp.float32))
     outs = pl.pallas_call(
-        functools.partial(_css_bwd_kernel, p, q, t, cs, nchunk, hp, want_gy),
+        functools.partial(_css_bwd_kernel, ar, ma, t, cs, nchunk, hp,
+                          want_gy),
         grid=(nblk, nchunk),
         in_specs=ins,
         out_specs=out_specs,

@@ -132,6 +132,18 @@ def test_pallas_kernels_compile_for_v5e(monkeypatch):
     programs["arima111 T=2500 (multi-chunk)"] = (
         arima._fit_program(o111, True, "css-lbfgs", "pallas", 60, TOL,
                            False, "dense"), [arg(B, 2500)])
+    # the seasonal family: the CSS kernels over the lag sets {1, 24, 25},
+    # one chunk at the benchmark's length and two past it
+    airline = ((0, 1, 1), True, "pallas", 60, TOL, False, "dense", False,
+               (0, 1, 1, 24))
+    programs["sarima airline24 stage1 T=960"] = (
+        arima._fit_stage1_program(*airline), [arg(4096, 960)])
+    programs["sarima airline24 stage1 T=2500 (multi-chunk)"] = (
+        arima._fit_stage1_program(*airline), [arg(4096, 2500)])
+    programs["sarima (1,0,1)(1,0,1)_4 inline general"] = (
+        arima._fit_program((1, 0, 1), True, "css-lbfgs", "pallas", 60, TOL,
+                           False, "general", False, True, (1, 0, 1, 4)),
+        [arg(B, T)])
     programs["arima111 forecast (tail kernel)"] = (
         arima._forecast_program(o111, 24, True, "pallas", "dense"),
         [arg(B, 3), arg(B, T)])

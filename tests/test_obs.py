@@ -714,10 +714,13 @@ class TestStageGateSpans:
         s1 = spans["fit.stage1"]
         # series_block: what the value-only CSS kernel takes per grid
         # step over these rows, from the kernel file's own rule
+        # lag_terms / lag_span: the live lag terms a CSS kernel step pays
+        # (phi_1 and theta_1) and how far they reach
         assert s1["attrs"] == {
             "rows": 2048, "iters": int(carry.k),
             "undone": int(carry.undone),
-            "series_block": pk.css_series_block(2048, 39, (1, 1, 1))}
+            "series_block": pk.css_series_block(2048, 39, (1, 1, 1)),
+            "lag_terms": 2, "lag_span": 1}
         assert s1["attrs"]["series_block"] in (1024, 2048)
         assert all(type(s1["attrs"][k]) is int for k in ("iters", "undone"))
         assert s1["parent"] == primary.id
@@ -726,10 +729,39 @@ class TestStageGateSpans:
         assert ("fit.stage2" in spans) == stage2
         if stage2:
             assert spans["fit.stage2"]["attrs"] == {
-                "rows": optim.compaction_cap(2048), "series_block": 1024}
+                "rows": optim.compaction_cap(2048), "series_block": 1024,
+                "lag_terms": 2, "lag_span": 1}
             assert spans["fit.stage2"]["parent"] == primary.id
 
-    @pytest.mark.parametrize("family", ["arima", "holtwinters", "garch"])
+    def test_seasonal_fit_opens_the_same_spans(self, monkeypatch, tmp_path):
+        # a seasonal order takes the same gate (ISSUE 34): the stage spans
+        # with the gate's numbers, and what its kernel step pays — the
+        # airline model's three live MA lags 1, 4, 5 of the five the
+        # expanded polynomial has
+        monkeypatch.setattr(optim, "COMPACT_MIN_BATCH", 2048)
+        fit = _lazy_sarima()
+        off = fit()
+        p = str(tmp_path / "ev.jsonl")
+        obs.enable(p)
+        with obs.span("fit.primary") as primary:
+            on = fit()
+        obs.disable()
+        _assert_bitwise(on, off)
+        spans = {s["name"]: s for s in _span_lines(p)}
+        s1, s2 = spans["fit.stage1"], spans["fit.stage2"]
+        assert set(s1["attrs"]) == {"rows", "iters", "undone",
+                                    "series_block", "lag_terms", "lag_span"}
+        assert s1["attrs"]["rows"] == 2048 and s1["attrs"]["undone"] > 0
+        assert s1["attrs"]["series_block"] == pk.css_series_block(
+            2048, 55, ((), 0, (1, 4, 5)))
+        assert s2["attrs"] == {"rows": optim.compaction_cap(2048),
+                               "series_block": 1024, "lag_terms": 3,
+                               "lag_span": 5}
+        assert (s1["attrs"]["lag_terms"], s1["attrs"]["lag_span"]) == (3, 5)
+        assert s1["parent"] == s2["parent"] == primary.id
+
+    @pytest.mark.parametrize("family", ["arima", "sarima", "holtwinters",
+                                        "garch"])
     def test_count_evals_instruments_the_fit_that_runs(self, monkeypatch,
                                                        tmp_path, family):
         # the flag selects no program: a counted fit takes the lazy pair,
@@ -790,6 +822,20 @@ def _lazy_arima():
                                   max_iters=14, **kw)
 
 
+def _lazy_sarima():
+    # (1-L)(1-L^4) y = (1 - 0.4 L)(1 - 0.6 L^4) e: the airline model at s = 4
+    rng = np.random.default_rng(34)
+    e = rng.normal(size=(2048, 68))
+    w = e[:, 5:] - 0.4 * e[:, 4:-1] - 0.6 * e[:, 1:-4] + 0.24 * e[:, :-5]
+    y = np.cumsum(w, axis=1)[:, 3:]
+    for i in range(4, y.shape[1]):
+        y[:, i] += y[:, i - 4]
+    y = jnp.asarray(y.astype(np.float32))
+    return lambda **kw: arima.fit(y, (0, 1, 1), seasonal=(0, 1, 1, 4),
+                                  backend="pallas-interpret", max_iters=14,
+                                  **kw)
+
+
 def _lazy_holtwinters():
     from spark_timeseries_tpu.models import holtwinters as hw
 
@@ -811,5 +857,6 @@ def _lazy_garch():
                                   max_iters=13, **kw)
 
 
-_LAZY_FITS = {"arima": _lazy_arima, "holtwinters": _lazy_holtwinters,
+_LAZY_FITS = {"arima": _lazy_arima, "sarima": _lazy_sarima,
+              "holtwinters": _lazy_holtwinters,
               "garch": _lazy_garch}
