@@ -577,7 +577,8 @@ def fit(
         stage2=lambda: _fit_stage2_program(*static, seasonal),
         series_block=lambda rows: pk.css_series_block(rows, n, (ar, 0, ma)),
         stage_attrs={"lag_terms": len(ar) + len(ma),
-                     "lag_span": max(ar + ma, default=0)})
+                     "lag_span": max(ar + ma, default=0),
+                     "adjoint_panels": pk.CSS_ADJOINT_PANELS})
     return debatch_fit(out, single, count_evals)
 
 

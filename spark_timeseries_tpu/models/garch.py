@@ -160,14 +160,17 @@ def fit(r, *, max_iters: int = 80, tol: Optional[float] = None,
                                     compact),
         stage1=lambda: _fit_stage1_program(*static, align_mode, count_evals),
         stage2=lambda: _fit_stage2_program(*static),
-        series_block=lambda rows: _garch_series_block(rows, rb.shape[1]))
+        **_garch_kernel_attrs(rb.shape[1]))
     return debatch_fit(out, single, count_evals)
 
 
-def _garch_series_block(rows, t):
+def _garch_kernel_attrs(t):
+    """What the stage spans say of the GARCH kernels (``lockstep.fit``'s
+    ``series_block`` / ``stage_attrs``)."""
     from ..ops import pallas_kernels as pk
 
-    return pk.garch_series_block(rows, t)
+    return {"series_block": lambda rows: pk.garch_series_block(rows, t),
+            "stage_attrs": {"adjoint_panels": pk.GARCH_ADJOINT_PANELS}}
 
 
 def _garch_family(backend, align_mode=None) -> lockstep.Family:
@@ -370,7 +373,7 @@ def fit_argarch(y, *, max_iters: int = 100, tol: Optional[float] = None,
         inline=lambda: _fit_argarch_program(*static, compact, align_mode),
         stage1=lambda: _fit_argarch_stage1_program(*static, align_mode),
         stage2=lambda: _fit_argarch_stage2_program(*static),
-        series_block=lambda rows: _garch_series_block(rows, yb.shape[1]))
+        **_garch_kernel_attrs(yb.shape[1]))
     return debatch(out, single)
 
 

@@ -186,7 +186,8 @@ def fit(
         stage2=lambda: _fit_stage2_program(*static),
         merge=lambda: _merge_starts_program(*static),
         series_block=lambda rows: pk.hw_series_block(
-            rows, yb.shape[1], period))
+            rows, yb.shape[1], period),
+        stage_attrs={"adjoint_panels": pk.HW_ADJOINT_PANELS})
     if count_evals:
         out = (out[0], {**out[1], "n_starts": n_starts})
     return debatch_fit(out, single, count_evals)

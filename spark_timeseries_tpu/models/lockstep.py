@@ -221,8 +221,9 @@ def fit(args: tuple, *, backend: str, compact: bool, max_iters: int,
     or ``(FitResult, info)`` from programs built with ``count_evals``.
     ``series_block`` is the family's ``rows -> series per grid step`` of its
     objective kernel and ``stage_attrs`` what else it has to say of a
-    kernel step (ARIMA: ``lag_terms``, ``lag_span``); both are reported on
-    the stage spans and choose nothing.
+    kernel step (every family ``adjoint_panels``, the panel-sized operands
+    of its objective's adjoint call; ARIMA also ``lag_terms``,
+    ``lag_span``); both are reported on the stage spans and choose nothing.
 
     The lazy pair runs on the pallas backends when the batch is concrete
     and large enough for the compaction to pay (``optim.COMPACT_MIN_BATCH``,

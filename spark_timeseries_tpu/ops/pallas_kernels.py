@@ -390,9 +390,12 @@ def _css_fwd_kernel(ar, ma, t_limit, cs, hp, mode, *refs):
         ce_ref[j] = e_ref[cs - q + j]
 
 
-def _css_bwd_kernel(ar, ma, t_limit, cs, nchunk, hp, want_gy, *refs):
+def _css_bwd_kernel(ar, ma, t_limit, cs, nchunk, hp, want_gy, g_plane,
+                    *refs):
     # p / q: the reach of each side (the carries' depth); the parameter
-    # planes number one per LIVE lag
+    # planes number one per LIVE lag.  ``g_plane``: ``g_ref`` is not the
+    # cotangent of e as a panel but the plane gbar of the sum of squares'
+    # cotangent, and the kernel forms ``2 e gbar`` from the errors it reads
     p, q, npar = _span(ar), _span(ma), len(ar)
     refs = list(refs)
     y_ref = refs.pop(0)
@@ -422,7 +425,7 @@ def _css_bwd_kernel(ar, ma, t_limit, cs, nchunk, hp, want_gy, *refs):
             for i_ in range(max(p, 1)):
                 cap_ref[i_] = _ZERO()
 
-    adj_ref[:] = g_ref[:]
+    adj_ref[:] = 2.0 * e_ref[:] * g_ref[0] if g_plane else g_ref[:]
 
     def body(i, accs):
         tl = cs - 1 - i
@@ -675,20 +678,21 @@ def _css_ss_f_bwd(p, q, interpret, t, b, resid, gbar):
     if isinstance(gbar, SymbolicZero):  # output provably unused
         return (jnp.zeros((b, k), e3.dtype), jnp.zeros(y3.shape, y3.dtype),
                 jnp.zeros(zb3.shape, zb3.dtype))
-    # the error cotangent stays IN the folded layout: gbar [B] folds to a
-    # [1, Bp/128, 128] plane that broadcasts over the time axis, so the
-    # gradient evaluation pays no unfold/refold panel passes (this runs
-    # once per optimizer iteration on the fit hot path)
+    # gbar [B] folds to a [1, Bp/128, 128] plane, and the plane is all the
+    # adjoint kernel is handed: it forms the error cotangent 2 e gbar from
+    # the errors it reads anyway, so a gradient makes no panel-sized XLA
+    # pass between its forward and its adjoint (this runs once per
+    # optimizer iteration on the fit hot path)
     gb3 = _fold(gbar[:, None].astype(e3.dtype))
-    g_e3 = 2.0 * e3 * gb3
     if marker is not None:
         # data perturbed: the backward kernel additionally emits the folded
         # data cotangent (an output the params-only fit path never pays for)
         gparams, gy3 = _css_errors_bwd_f(p, q, interpret, (y3, par3, zb3, e3),
-                                         g_e3, b, t, want_gy=True)
+                                         gb3, b, t, want_gy=True,
+                                         g_plane=True)
     else:
         gparams = _css_errors_bwd_f(p, q, interpret, (y3, par3, zb3, e3),
-                                    g_e3, b, t)
+                                    gb3, b, t, g_plane=True)
         gy3 = jnp.zeros(y3.shape, y3.dtype)
     return gparams, gy3, jnp.zeros(zb3.shape, zb3.dtype)
 
@@ -781,11 +785,21 @@ def _css_errors_bwd(p, q, interpret, res, g):
     return gparams, gy, jnp.zeros((b,), g.dtype)
 
 
-def _css_errors_bwd_f(p, q, interpret, res, g3, b, t, want_gy=False):
+# the panel-sized operands of the fit objective's adjoint call: y3, e3 (a
+# stage span's ``adjoint_panels``; ``css_errors``' own adjoint takes g3 too)
+CSS_ADJOINT_PANELS = 2
+
+
+def _css_errors_bwd_f(p, q, interpret, res, g3, b, t, want_gy=False,
+                      g_plane=False):
     """Adjoint core on FOLDED cotangents -> ``gparams [B, k]`` or, with
     ``want_gy``, ``(gparams, gy3)`` where ``gy3`` is the data cotangent in
     the folded layout (an extra kernel output only callers that perturb the
-    data pay for — see ``_css_ss_f_fwd``)."""
+    data pay for — see ``_css_ss_f_fwd``).  ``g3`` is the cotangent of the
+    errors as a panel (``css_errors``' rule: any cotangent) or, with
+    ``g_plane``, the plane gbar of the sum of squares' (``_css_ss_f``'s
+    rule: the kernel forms ``2 e gbar`` itself); which rule is calling is
+    all that chooses."""
     y3, par3, zb3, e3 = res
     ar, ma = _lags(p), _lags(q)
     k = par3.shape[0]
@@ -795,12 +809,13 @@ def _css_errors_bwd_f(p, q, interpret, res, g3, b, t, want_gy=False):
     if hp:
         ins = [_bs(cs, _rev(nchunk)), _bs(cs, _rev_prev(nchunk)),
                _bs(cs, _rev(nchunk)), _bs(cs, _rev_prev(nchunk)),
-               _bs(k, _fixed), _bs(1, _fixed), _bs(cs, _rev(nchunk))]
+               _bs(k, _fixed), _bs(1, _fixed)]
         args = (y3, y3, e3, e3, par3, zb3, g3)
     else:
         ins = [_bs(cs, _rev(nchunk)), _bs(cs, _rev(nchunk)),
-               _bs(k, _fixed), _bs(1, _fixed), _bs(cs, _rev(nchunk))]
+               _bs(k, _fixed), _bs(1, _fixed)]
         args = (y3, e3, par3, zb3, g3)
+    ins.append(_bs(1, _fixed) if g_plane else _bs(cs, _rev(nchunk)))
     out_specs = [_bs(k, _fixed)]
     out_shape = [jax.ShapeDtypeStruct(par3.shape, g3.dtype)]
     if want_gy:
@@ -815,7 +830,7 @@ def _css_errors_bwd_f(p, q, interpret, res, g3, b, t, want_gy=False):
             pltpu.VMEM((max(_span(ar), 1), _SUBL, _LANES), jnp.float32))
     outs = pl.pallas_call(
         functools.partial(_css_bwd_kernel, ar, ma, t, cs, nchunk, hp,
-                          want_gy),
+                          want_gy, g_plane),
         grid=(nblk, nchunk),
         in_specs=ins,
         out_specs=out_specs,
@@ -933,8 +948,11 @@ def _garch_fwd_kernel(t_limit, cs, hp, mode, *refs):
         ll_ref[0] = ll_ref[0] + acc
 
 
-def _garch_bwd_kernel(t_limit, cs, nchunk, hpv, want_gdata, *refs):
-    # ``want_gdata``: also emit the cotangents of r^2 (panel-sized) and h0
+def _garch_bwd_kernel(t_limit, cs, nchunk, hpv, want_gdata, g_plane, *refs):
+    # ``want_gdata``: also emit the cotangents of r^2 (panel-sized) and h0.
+    # ``g_plane``: ``g_ref`` is not the cotangent of h as a panel but the
+    # plane gbar of the likelihood sum's cotangent, and the kernel forms
+    # gbar d ll_t / d h_t from the r^2 and h blocks it reads
     refs = list(refs)
     r2_ref = refs.pop(0)
     r2p_ref = refs.pop(0) if hpv else None
@@ -958,6 +976,15 @@ def _garch_bwd_kernel(t_limit, cs, nchunk, hpv, want_gdata, *refs):
         if want_gdata:
             gh0_ref[0] = _ZERO()
 
+    # d ll_t / d h_t = 1/h - r^2/h^2 in two stages a step apart (see body)
+    def operands(tl):
+        ht = h_ref[tl]
+        hc = jnp.maximum(ht, 1e-12)
+        return ht, hc, hc * hc, r2_ref[tl]
+
+    def quotients(ht, hc, hh, r2):
+        return ht, 1.0 / hc, r2 / hh
+
     def body(i, carry):
         lam_next, dw, da, db = carry[:4]
         tl = cs - 1 - i
@@ -968,7 +995,24 @@ def _garch_bwd_kernel(t_limit, cs, nchunk, hpv, want_gdata, *refs):
             # r2_t feeds h_{t+1} unless t+1 is the seed (which uses h0)
             next_live = (tf + 1.0 > zb) & (t + 1 < t_limit)
             gr2_ref[tl] = jnp.where(next_live, alpha * lam_next, 0.0)
-        lam = g_ref[tl] + beta * lam_next
+        if g_plane:
+            # gbar d ll_t / d h_t (zero through the eps clamp), formed over
+            # THREE steps: this one finishes its cotangent from the
+            # quotients the step before divided, divides for the next and
+            # loads for the one after.  The divides are off the recurrence's
+            # chain (lam waits for lam_next alone) but 17 bundles deep
+            # behind a load, and a loop iteration is as long as its longest
+            # path: in one piece they doubled the step (36 bundles for the
+            # panel cotangent's 20), in stages they fill the slots it leaves
+            # empty.  (The last two steps of a chunk stage a clamped step
+            # that nothing finishes.)
+            ht, inv, quo = carry[-7:-4]
+            g = jnp.where(live & (ht >= 1e-12), g_ref[0] * (inv - quo), 0.0)
+            ahead = (quotients(*carry[-4:])
+                     + operands(jnp.maximum(tl - 2, 0)))
+        else:
+            g, ahead = g_ref[tl], ()
+        lam = g + beta * lam_next
         lam = jnp.where(live, lam, 0.0)
         seed = tf == zb
         hfar = hp_ref[cs - 1] if hpv else 0.0
@@ -982,18 +1026,20 @@ def _garch_bwd_kernel(t_limit, cs, nchunk, hpv, want_gdata, *refs):
         da = da + lam * r2p_eff
         db = db + lam * hprev
         if not want_gdata:
-            return lam, dw, da, db
-        # dead positions emit h0 directly
-        dh0 = carry[4] + jnp.where(live, 0.0, g_ref[tl])
+            return (lam, dw, da, db) + ahead
+        # dead positions emit h0 directly (the likelihood has none: its
+        # cotangent is zero there)
+        dh0 = carry[4] if g_plane else carry[4] + jnp.where(live, 0.0, g)
         # h0 enters the seed step through BOTH recursion inputs
         hp_is_h0 = tf - 1.0 < zb
         dh0 = dh0 + jnp.where(live & seed, alpha * lam, 0.0)
         dh0 = dh0 + jnp.where(live & hp_is_h0, beta * lam, 0.0)
-        return lam, dw, da, db, dh0
+        return (lam, dw, da, db, dh0) + ahead
 
     out = lax.fori_loop(
         0, cs, body, (cl_ref[0],) + (_ZERO(),) * (4 if want_gdata else 3)
-    )
+        + (quotients(*operands(cs - 1)) + operands(max(cs - 2, 0))
+           if g_plane else ()))
     cl_ref[0] = out[0]
     for r in range(3):
         gpar_ref[r] = gpar_ref[r] + out[1 + r]
@@ -1062,24 +1108,33 @@ def _garch_fwd_call_f(interpret, mode, params, f: GarchFolded, _r=None):
     return outs, par3
 
 
-def _garch_bwd_call_f(interpret, f: GarchFolded, par3, h3, g3, want_gdata):
+# the panel-sized operands of the fit objective's adjoint call: r23, h3 (a
+# stage span's ``adjoint_panels``; ``garch_variances``' adjoint takes g3 too)
+GARCH_ADJOINT_PANELS = 2
+
+
+def _garch_bwd_call_f(interpret, f: GarchFolded, par3, h3, g3, want_gdata,
+                      g_plane=False):
     """The adjoint on FOLDED operands: ``g3`` is the cotangent of the
-    variance path ``h3`` -> ``(gpar3, gr23, gh03)``, the two data
-    cotangents ``None`` unless ``want_gdata`` (two more kernel outputs, one
-    of them panel-sized)."""
+    variance path ``h3`` as a panel (``_garch_h``'s rule: any cotangent)
+    or, with ``g_plane``, the plane gbar of the likelihood sum's
+    (``_garch_ll_f``'s rule: the kernel forms ``gbar d ll / d h`` itself;
+    which rule is calling is all that chooses) -> ``(gpar3, gr23, gh03)``,
+    the two data cotangents ``None`` unless ``want_gdata`` (two more kernel
+    outputs, one of them panel-sized)."""
     _, cs, nchunk = _time_layout(f.t)
     nblk = f.r23.shape[1] // _SUBL
     hp = nchunk > 1
     if hp:
         ins = [_bs(cs, _rev(nchunk)), _bs(cs, _rev_prev(nchunk)),
                _bs(3, _fixed), _bs(1, _fixed), _bs(1, _fixed),
-               _bs(cs, _rev(nchunk)), _bs(cs, _rev_prev(nchunk)),
-               _bs(cs, _rev(nchunk))]
+               _bs(cs, _rev(nchunk)), _bs(cs, _rev_prev(nchunk))]
         args = (f.r23, f.r23, par3, f.h03, f.zb3, h3, h3, g3)
     else:
         ins = [_bs(cs, _rev(nchunk)), _bs(3, _fixed), _bs(1, _fixed),
-               _bs(1, _fixed), _bs(cs, _rev(nchunk)), _bs(cs, _rev(nchunk))]
+               _bs(1, _fixed), _bs(cs, _rev(nchunk))]
         args = (f.r23, par3, f.h03, f.zb3, h3, g3)
+    ins.append(_bs(1, _fixed) if g_plane else _bs(cs, _rev(nchunk)))
     out_specs = [_bs(3, _fixed)]
     out_shape = [jax.ShapeDtypeStruct(par3.shape, g3.dtype)]
     if want_gdata:
@@ -1087,7 +1142,8 @@ def _garch_bwd_call_f(interpret, f: GarchFolded, par3, h3, g3, want_gdata):
         out_shape += [jax.ShapeDtypeStruct(f.r23.shape, g3.dtype),
                       jax.ShapeDtypeStruct(f.h03.shape, g3.dtype)]
     outs = pl.pallas_call(
-        functools.partial(_garch_bwd_kernel, f.t, cs, nchunk, hp, want_gdata),
+        functools.partial(_garch_bwd_kernel, f.t, cs, nchunk, hp, want_gdata,
+                          g_plane),
         grid=(nblk, nchunk),
         in_specs=ins,
         out_specs=out_specs,
@@ -1177,23 +1233,20 @@ def _garch_ll_f_bwd(interpret, resid, gbar):
     zeros = jax.tree_util.tree_map(jnp.zeros_like, f)
     if isinstance(gbar, SymbolicZero):  # output provably unused
         return jnp.zeros((b, 3), h3.dtype), zeros
-    # the likelihood's cotangent is formed IN the folded layout (see
-    # _css_ss_f_bwd): gbar [B] folds to a plane that broadcasts over time,
-    # so a gradient pays no unfold / refold panel passes; padded series
-    # carry a zero gbar, and padded time is dead (its g would reach gh0)
+    # gbar [B] folds to a plane, and the plane is all the adjoint kernel is
+    # handed (see _css_ss_f_bwd): it forms the likelihood's cotangent
+    # gbar (1/h - r^2/h^2) from the r^2 and h blocks it reads anyway, so a
+    # gradient makes no panel-sized XLA pass; padded series carry a zero
+    # gbar, and padded time is dead
     gb3 = _fold(gbar[:, None].astype(h3.dtype))
-    t_idx = jnp.arange(h3.shape[0], dtype=h3.dtype)[:, None, None]
-    live = (t_idx >= f.zb3) & (t_idx < f.t)
-    hc3 = jnp.maximum(h3, 1e-12)
-    # d ll_t / d h_t = 1/h - r^2/h^2 (zero through the eps clamp)
-    g3 = jnp.where(live & (h3 >= 1e-12),
-                   gb3 * (1.0 / hc3 - f.r23 / (hc3 * hc3)), 0.0)
     gpar3, gr23, gh03 = _garch_bwd_call_f(
-        interpret, f, par3, h3, g3, marker is not None)
+        interpret, f, par3, h3, gb3, marker is not None, g_plane=True)
     if marker is None:  # params-only: the fit hot path
         return _unfold(gpar3, b), zeros
     # r^2 feeds the likelihood through the recursion AND directly
-    gr23 = gr23 + jnp.where(live, gb3 / hc3, 0.0)
+    t_idx = jnp.arange(h3.shape[0], dtype=h3.dtype)[:, None, None]
+    live = (t_idx >= f.zb3) & (t_idx < f.t)
+    gr23 = gr23 + jnp.where(live, gb3 / jnp.maximum(h3, 1e-12), 0.0)
     return _unfold(gpar3, b), dataclasses.replace(zeros, r23=gr23, h03=gh03)
 
 
@@ -1646,11 +1699,11 @@ def _hw_fwd_kernel(m, mult, save_resid, t_limit, cs, y_ref, par_ref, l0_ref,
 
 def _hw_bwd_kernel(m, mult, t_limit, cs, nchunk, hp, *refs):
     if hp:
-        (y_ref, par_ref, l0_ref, t0_ref, zb_ref, lv_ref, lvp_ref, tr_ref,
-         trp_ref, so_ref, g_ref, gpar_ref, rho_ref, clam_ref) = refs
+        (y_ref, par_ref, l0_ref, t0_ref, zb_ref, gb_ref, lv_ref, lvp_ref,
+         tr_ref, trp_ref, so_ref, e_ref, gpar_ref, rho_ref, clam_ref) = refs
     else:
-        (y_ref, par_ref, l0_ref, t0_ref, zb_ref, lv_ref, tr_ref,
-         so_ref, g_ref, gpar_ref, rho_ref, clam_ref) = refs
+        (y_ref, par_ref, l0_ref, t0_ref, zb_ref, gb_ref, lv_ref, tr_ref,
+         so_ref, e_ref, gpar_ref, rho_ref, clam_ref) = refs
         lvp_ref = trp_ref = None
     c = pl.program_id(1)
     base = (nchunk - 1 - c) * cs
@@ -1658,6 +1711,7 @@ def _hw_bwd_kernel(m, mult, t_limit, cs, nchunk, hp, *refs):
     b = par_ref[1]
     g = par_ref[2]
     zb = zb_ref[0]
+    gb = gb_ref[0]  # the SSE's cotangent: the error's is 2 e gb, formed here
 
     @pl.when(c == 0)
     def _():
@@ -1679,7 +1733,7 @@ def _hw_bwd_kernel(m, mult, t_limit, cs, nchunk, hp, *refs):
         uS = rho_ref[slot]
         uL = lamL
         uT = lamT
-        gp = jnp.where(live_err, -g_ref[tl], 0.0)
+        gp = jnp.where(live_err, -(2.0 * e_ref[tl] * gb), 0.0)
         lfar = lvp_ref[cs - 1] if hp else 0.0
         lp = jnp.where(tl - 1 >= 0, lv_ref[jnp.maximum(tl - 1, 0)], lfar)
         lp = jnp.where(t - 1 >= 0, lp, l0_ref[0])
@@ -1820,35 +1874,41 @@ def _hw_ss_f_fwd(interpret, m, mult, params, f):
             (f, par3, e3, lv3, tr3, so3))
 
 
+# the panel-sized operands of the objective's adjoint call: y3, the replay
+# trajectories lv3, tr3, so3, and e3 (a stage span's ``adjoint_panels``)
+HW_ADJOINT_PANELS = 5
+
+
 def _hw_ss_f_bwd(interpret, m, mult, resid, gbar):
     f, par3, e3, lv3, tr3, so3 = resid
     y3, l03, t03, zb3, t, b = f.y3, f.l03, f.t03, f.zb3, f.t, gbar.shape[0]
-    # the error cotangent stays IN the folded layout (see _css_ss_f_bwd):
-    # no unfold / refold panel passes per gradient; padded series carry a
-    # zero gbar, padded time a zero error
-    g3 = 2.0 * e3 * _fold(gbar[:, None].astype(e3.dtype))
+    # gbar [B] folds to a plane, and the plane is what the adjoint kernel is
+    # handed beside the errors (see _css_ss_f_bwd): it forms the error
+    # cotangent 2 e gbar itself, no panel-sized XLA pass per gradient;
+    # padded series carry a zero gbar, padded time a zero error
+    gb3 = _fold(gbar[:, None].astype(e3.dtype))
     _, cs, nchunk = _time_layout(t)
     nblk = y3.shape[1] // _SUBL
     hp = nchunk > 1
     if hp:
         ins = [_bs(cs, _rev(nchunk)), _bs(3, _fixed), _bs(1, _fixed),
-               _bs(1, _fixed), _bs(1, _fixed),
+               _bs(1, _fixed), _bs(1, _fixed), _bs(1, _fixed),
                _bs(cs, _rev(nchunk)), _bs(cs, _rev_prev(nchunk)),
                _bs(cs, _rev(nchunk)), _bs(cs, _rev_prev(nchunk)),
                _bs(cs, _rev(nchunk)), _bs(cs, _rev(nchunk))]
-        args = (y3, par3, l03, t03, zb3, lv3, lv3, tr3, tr3, so3, g3)
+        args = (y3, par3, l03, t03, zb3, gb3, lv3, lv3, tr3, tr3, so3, e3)
     else:
         ins = [_bs(cs, _rev(nchunk)), _bs(3, _fixed), _bs(1, _fixed),
-               _bs(1, _fixed), _bs(1, _fixed), _bs(cs, _rev(nchunk)),
+               _bs(1, _fixed), _bs(1, _fixed), _bs(1, _fixed),
                _bs(cs, _rev(nchunk)), _bs(cs, _rev(nchunk)),
-               _bs(cs, _rev(nchunk))]
-        args = (y3, par3, l03, t03, zb3, lv3, tr3, so3, g3)
+               _bs(cs, _rev(nchunk)), _bs(cs, _rev(nchunk))]
+        args = (y3, par3, l03, t03, zb3, gb3, lv3, tr3, so3, e3)
     gpar3 = pl.pallas_call(
         functools.partial(_hw_bwd_kernel, m, mult, t, cs, nchunk, hp),
         grid=(nblk, nchunk),
         in_specs=ins,
         out_specs=_bs(3, _fixed),
-        out_shape=jax.ShapeDtypeStruct(par3.shape, g3.dtype),
+        out_shape=jax.ShapeDtypeStruct(par3.shape, e3.dtype),
         scratch_shapes=[
             pltpu.VMEM((m, _SUBL, _LANES), jnp.float32),
             pltpu.VMEM((2, _SUBL, _LANES), jnp.float32),

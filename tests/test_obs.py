@@ -716,11 +716,13 @@ class TestStageGateSpans:
         # step over these rows, from the kernel file's own rule
         # lag_terms / lag_span: the live lag terms a CSS kernel step pays
         # (phi_1 and theta_1) and how far they reach
+        # adjoint_panels: the panel-sized operands of the objective's
+        # adjoint call, y3 and e3 (it forms the cotangent itself, ISSUE 35)
         assert s1["attrs"] == {
             "rows": 2048, "iters": int(carry.k),
             "undone": int(carry.undone),
             "series_block": pk.css_series_block(2048, 39, (1, 1, 1)),
-            "lag_terms": 2, "lag_span": 1}
+            "lag_terms": 2, "lag_span": 1, "adjoint_panels": 2}
         assert s1["attrs"]["series_block"] in (1024, 2048)
         assert all(type(s1["attrs"][k]) is int for k in ("iters", "undone"))
         assert s1["parent"] == primary.id
@@ -730,7 +732,7 @@ class TestStageGateSpans:
         if stage2:
             assert spans["fit.stage2"]["attrs"] == {
                 "rows": optim.compaction_cap(2048), "series_block": 1024,
-                "lag_terms": 2, "lag_span": 1}
+                "lag_terms": 2, "lag_span": 1, "adjoint_panels": 2}
             assert spans["fit.stage2"]["parent"] == primary.id
 
     def test_seasonal_fit_opens_the_same_spans(self, monkeypatch, tmp_path):
@@ -750,13 +752,14 @@ class TestStageGateSpans:
         spans = {s["name"]: s for s in _span_lines(p)}
         s1, s2 = spans["fit.stage1"], spans["fit.stage2"]
         assert set(s1["attrs"]) == {"rows", "iters", "undone",
-                                    "series_block", "lag_terms", "lag_span"}
+                                    "series_block", "lag_terms", "lag_span",
+                                    "adjoint_panels"}
         assert s1["attrs"]["rows"] == 2048 and s1["attrs"]["undone"] > 0
         assert s1["attrs"]["series_block"] == pk.css_series_block(
             2048, 55, ((), 0, (1, 4, 5)))
         assert s2["attrs"] == {"rows": optim.compaction_cap(2048),
                                "series_block": 1024, "lag_terms": 3,
-                               "lag_span": 5}
+                               "lag_span": 5, "adjoint_panels": 2}
         assert (s1["attrs"]["lag_terms"], s1["attrs"]["lag_span"]) == (3, 5)
         assert s1["parent"] == s2["parent"] == primary.id
 
@@ -780,6 +783,10 @@ class TestStageGateSpans:
         # every family names its objective kernel's block on both stages
         assert spans["fit.stage1"]["attrs"]["series_block"] in (1024, 2048)
         assert spans["fit.stage2"]["attrs"]["series_block"] == 1024
+        # and the panel-sized operands of its objective's adjoint call
+        panels = 5 if family == "holtwinters" else 2
+        assert all(spans[s]["attrs"]["adjoint_panels"] == panels
+                   for s in ("fit.stage1", "fit.stage2"))
         assert int(info["cap"]) == optim.compaction_cap(2048)
         assert int(info["compact_at"]) == at
         evals = np.asarray(info["ls_evals"])

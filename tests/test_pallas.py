@@ -6,7 +6,10 @@ lowering (checked manually / by the driver's bench run — the interpret and
 native paths share one kernel body).
 """
 
+import functools
+
 import jax
+import jax.extend
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -864,6 +867,123 @@ def test_hw_fit_programs_fold_outside_their_loops(monkeypatch, align_mode,
     assert ("transpose", (b, t)) in _panel_relayouts_in_loops(per_call, b * t)
 
 
+def _panel_ops(eqns, n_panel, names=("mul", "select_n", "div")):
+    """``(primitive, result shape)`` of every ``names`` equation among
+    ``eqns``, nested jaxprs included (kernel bodies aside), with a result of
+    at least ``n_panel`` elements."""
+    found = []
+    for eqn in eqns:
+        if eqn.primitive.name == "pallas_call":
+            continue
+        if eqn.primitive.name in names:
+            found += [(eqn.primitive.name, v.aval.shape)
+                      for v in eqn.outvars if v.aval.size >= n_panel]
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            found += _panel_ops(sub.eqns, n_panel, names)
+    return found
+
+
+def _objective_adjoints(jaxpr, n_panel):
+    """Every objective gradient of ``jaxpr`` at any depth, as ``(panel
+    operands of the adjoint call, panel-sized mul / select_n / div between
+    the forward call and it)``: an adjoint ``pallas_call`` is one that reads
+    a panel an earlier ``pallas_call`` of the same jaxpr wrote (the saved
+    residuals), the forward call the latest such."""
+    found, wrote = [], {}
+    for i, eqn in enumerate(jaxpr.eqns):
+        if eqn.primitive.name != "pallas_call":
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                found += _objective_adjoints(sub, n_panel)
+            continue
+        panels = {v for v in eqn.invars
+                  if not isinstance(v, jax.extend.core.Literal)
+                  and v.aval.size >= n_panel}
+        fwd = [wrote[v] for v in panels if v in wrote]
+        if fwd:
+            found.append((len(panels), _panel_ops(
+                jaxpr.eqns[max(fwd) + 1:i], n_panel)))
+        wrote.update({v: i for v in eqn.outvars if v.aval.size >= n_panel})
+    return found
+
+
+def _stage_programs(family, b, t):
+    """-> ``adjoint_panels``, then stage 1, stage 2 and the inline program
+    of a family's lazy fit as ``(fn, args, rows)``, on shapes alone."""
+    from spark_timeseries_tpu.models import garch
+    from spark_timeseries_tpu.models import holtwinters as hw
+
+    y = jax.ShapeDtypeStruct((b, t), jnp.float32)
+    if family in ("arima111", "sarima-airline4"):
+        seasonal = (0, 1, 1, 4) if family == "sarima-airline4" else None
+        order = (0, 1, 1) if seasonal else (1, 1, 1)
+        static = (order, True, "pallas-interpret", 13, 1e-4)
+        stage1 = arima._fit_stage1_program.__wrapped__(
+            *static, False, "dense", False, seasonal)
+        stage2 = arima._fit_stage2_program.__wrapped__(*static, seasonal)
+        inline = arima._fit_program.__wrapped__(
+            order, True, "css-lbfgs", *static[2:], False, "dense", False,
+            True, seasonal)
+        panels = pk.CSS_ADJOINT_PANELS
+    elif family.startswith("hw"):
+        mult = family == "hw-mult"
+        n_starts = 3 if mult else 1
+        static = (4, mult, 13, 1e-4, "pallas-interpret")
+        stage1 = hw._fit_stage1_program.__wrapped__(*static, "dense",
+                                                    n_starts)
+        stage2 = hw._fit_stage2_program.__wrapped__(*static)
+        inline = hw._fit_program.__wrapped__(*static, "dense", False, True,
+                                             n_starts)
+        panels = pk.HW_ADJOINT_PANELS
+    else:
+        static = (13, 1e-4, "pallas-interpret")
+        stage1 = garch._fit_stage1_program.__wrapped__(*static, "dense")
+        stage2 = garch._fit_stage2_program.__wrapped__(*static)
+        inline = garch._fit_program.__wrapped__(*static, "dense", False, True)
+        panels = pk.GARCH_ADJOINT_PANELS
+    aux = jax.eval_shape(stage1, y)[1]
+    start, cap = aux["starts"][0], optim.compaction_cap(b)
+    args2 = (start,) if family.startswith("hw") else (start, aux["fin"])
+    return panels, ((stage1, (y,), b), (inline, (y,), b),
+                    (stage2, args2, cap))
+
+
+@pytest.mark.parametrize("family", ["arima111", "sarima-airline4", "hw-add",
+                                    "hw-mult", "garch11"])
+def test_fit_programs_form_no_cotangent_panel(monkeypatch, family):
+    # the CPU's stand-in for "``broadcast_multiply_fusion`` /
+    # ``multiply_select_fusion`` left the device's ops" (PERF.md §6, PR 35):
+    # in stage 1, stage 2 and the inline program no panel-sized mul /
+    # select_n / div sits between an objective's forward call and its
+    # adjoint call — the kernel forms the cotangent from the plane — and
+    # the adjoint takes the panels the stage spans report
+    monkeypatch.setattr(optim, "COMPACT_MIN_BATCH", 2048)
+    b, t = 2048, 48
+    panels, programs = _stage_programs(family, b, t)
+    for fn, args, rows in programs:
+        # the differenced panel is the shortest: d = 1 + 1 + 4 at most
+        n_panel = rows * (t - 6)
+        adjoints = _objective_adjoints(jax.make_jaxpr(fn)(*args).jaxpr,
+                                       n_panel)
+        assert adjoints, "every program takes gradients"
+        assert adjoints == [(panels, [])] * len(adjoints)
+    # the detector sees what it is for: the parent's idiom, a cotangent
+    # panel formed by XLA between the two calls
+    f32 = jnp.float32
+    y3 = jnp.zeros((t, b // 128, 128), f32)
+    zb3 = jnp.zeros((1, b // 128, 128), f32)
+    par = jnp.zeros((b, 3), f32)
+
+    def parent_idiom(P):
+        (e3, css3), (_, par3, _) = pk._css_fwd_call_f(
+            1, 1, True, "both", P, y3, zb3, t)
+        return pk._css_errors_bwd_f(1, 1, True, (y3, par3, zb3, e3),
+                                    2.0 * e3 * css3, b, t)
+
+    assert _objective_adjoints(jax.make_jaxpr(parent_idiom)(par).jaxpr,
+                               b * t) == [
+        (3, [("mul", y3.shape), ("mul", y3.shape)])]
+
+
 def _hw_pin_fit(path, model_type, backend="pallas-interpret"):
     """One fit of the fit-level pin: ``inline`` (24 rows, under the
     compaction gate), ``ragged`` (the same with NaN heads and a NaN tail:
@@ -886,12 +1006,17 @@ def _hw_pin_fit(path, model_type, backend="pallas-interpret"):
     return hw.fit(jnp.asarray(y), m, model_type, backend=backend)
 
 
-def _fit_pin_digest(r):
+def _sha(*arrays):
     import hashlib
 
-    sha = lambda a: hashlib.sha256(  # noqa: E731
-        np.ascontiguousarray(np.asarray(a)).tobytes()).hexdigest()[:16]
-    return (sha(r.params), sha(r.neg_log_likelihood),
+    h = hashlib.sha256()
+    for a in arrays:
+        h.update(np.ascontiguousarray(np.asarray(a)).tobytes())
+    return h.hexdigest()[:16]
+
+
+def _fit_pin_digest(r):
+    return (_sha(r.params), _sha(r.neg_log_likelihood),
             int(np.sum(np.asarray(r.converged))),
             int(np.sum(np.asarray(r.iters))))
 
@@ -1691,10 +1816,9 @@ def test_forward_call_grid_follows_the_rule():
 
 
 def test_kernel_block_sweep_cases_trace():
-    # tools/kernel_block_sweep.py (the chip-side R sweep): every case's
+    # tools/kernel_block_sweep.py (the chip-side R sweep, and each
+    # objective's adjoint as its custom_vjp calls it): every case's
     # arguments and call trace at every width, on shapes alone
-    import functools
-
     from tools import kernel_block_sweep as sweep
 
     seen = set()
@@ -1704,7 +1828,212 @@ def test_kernel_block_sweep_cases_trace():
         for r in (1, 2, 4):
             outs = jax.eval_shape(functools.partial(call, r), *args)
             assert outs[-1].shape[1:] == (rows // 128, 128)
-            assert all(o.shape[0] in (1, tp) for o in outs)
+            # a panel or a plane; an adjoint's parameter planes, folded
+            assert all(o.shape[0] in ((3, 4) if mode == "adjoint"
+                                      else (1, tp)) for o in outs)
         seen.add((name, mode))
-    assert len(seen) == 8 and {n for n, _ in seen} == {
-        "css_neg_loglik", "hw_sse", "garch_neg_loglik"}
+    kernels = {"css_neg_loglik", "hw_sse", "garch_neg_loglik"}
+    assert {(n, m) for n, m in seen if m == "adjoint"} == {
+        (n, "adjoint") for n in kernels | {"css_seasonal_neg_loglik"}}
+    assert len(seen) == 12 and {n for n, m in seen if m != "adjoint"} == kernels
+
+
+# ---------------------------------------------------------------------------
+# The objective's cotangent is formed INSIDE the adjoint kernels (PR 35):
+# the folded objectives hand their adjoint the plane gb3, not a panel
+# ---------------------------------------------------------------------------
+
+
+def _cotangent_case(family, variant, ragged, nchunk):
+    """One small gradient of a folded fit objective -> ``(chunk, run)``:
+    ``_CHUNK_T`` to patch (``nchunk`` time chunks) and ``run() -> dict`` of
+    gradients — ``plane_*`` through the objective's ``custom_vjp`` (its
+    adjoint forms the cotangent), ``panel_*`` the same gradient composed
+    from the general-cotangent ``custom_vjp`` where the family has one
+    (``css_errors``, ``garch_variances``), ``scan_*`` the scan backend's."""
+    b, m = 40, 4
+    rng = np.random.default_rng(351)
+    w = jnp.asarray(rng.normal(size=b).astype(np.float32))
+    chunk = 64 if variant == "lagset" else 16
+    t = (chunk if nchunk == 1 else 2 * chunk) - 3
+    nv = (jnp.asarray(rng.integers(t - 4, t + 1, b), jnp.int32) if ragged
+          else jnp.full((b,), t, jnp.int32))
+    start = (t - nv).astype(jnp.float32)
+    live = jnp.arange(t)[None, :] >= start[:, None]
+    if family == "css":
+        p, q = ((), (1, 24, 25)) if variant == "lagset" else (1, 1)
+        k = 1 + len(pk._lags(p)) + len(pk._lags(q))
+        yd = jnp.where(live, jnp.asarray(
+            rng.normal(size=(b, t)).astype(np.float32)), 0.0)
+        par = jnp.asarray(rng.normal(size=(b, k)).astype(np.float32) * 0.3)
+        zb = start + pk._span(pk._lags(p))
+
+        def run():
+            y3, zb3 = pk.css_prefold(yd, (pk._span(pk._lags(p)), 0, 0), nv)
+            g_p, g_y3 = jax.grad(lambda P, Y3: jnp.sum(w * pk._css_ss_f(
+                p, q, True, t, b, P, Y3, zb3)), argnums=(0, 1))(par, y3)
+            g_only = jax.grad(lambda P: jnp.sum(w * pk._css_ss_f(
+                p, q, True, t, b, P, y3, zb3)))(par)
+            r_p, r_y = jax.grad(lambda P, Y: jnp.sum(w * jnp.sum(
+                pk.css_errors(p, q, True, P, Y, zb) ** 2, axis=1)),
+                argnums=(0, 1))(par, yd)
+            return {"plane_params": g_only, "plane_params_gy": g_p,
+                    "plane_data": pk._unfold(g_y3, b)[:, :t],
+                    "panel_params": r_p, "panel_data": r_y}
+    elif family == "garch":
+        r = jnp.where(live, _returns_panel(b, t, seed=352), 0.0)
+        par = _garch_params(b, 353)
+
+        def general(P, rv):
+            # the likelihood written over ``garch_variances``, masked and
+            # seeded as ``garch_prefold`` has it
+            rz = jnp.where(live, rv, 0.0)
+            nvf = nv.astype(rv.dtype)
+            mean = jnp.sum(rz, axis=1) / nvf
+            h0 = jnp.sum(jnp.where(live, (rz - mean[:, None]) ** 2, 0.0),
+                         axis=1) / nvf
+            hc = jnp.maximum(pk.garch_variances(P, rz, h0, start,
+                                                interpret=True), 1e-12)
+            return jnp.sum(w * jnp.sum(jnp.where(
+                live, jnp.log(2.0 * jnp.pi * hc) + rz * rz / hc, 0.0),
+                axis=1))
+
+        def run():
+            f = pk.garch_prefold(r, nv)
+            g_only = jax.grad(lambda P: jnp.sum(
+                w * pk._garch_ll_f(True, P, f)))(par)
+            g_p, g_r = jax.grad(lambda P, rv: jnp.sum(w * pk._garch_ll_f(
+                True, P, pk.garch_prefold(rv, nv))), argnums=(0, 1))(par, r)
+            r_p, r_r = jax.grad(general, argnums=(0, 1))(par, r)
+            s_p = jax.grad(lambda P: 2.0 * jnp.sum(
+                w * _scan_nll(P, r, nv)))(par)
+            return {"plane_params": g_only, "plane_params_gy": g_p,
+                    "plane_data": g_r, "panel_params": r_p,
+                    "panel_data": r_r, "scan_params": s_p}
+    else:
+        from spark_timeseries_tpu.models import holtwinters as hw
+
+        mult = variant == "mult"
+        y = jnp.where(live, _seasonal_panel(b, t, m, seed=354)
+                      + (25.0 if mult else 0.0), 0.0)
+        par = jnp.asarray(rng.uniform(0.05, 0.9, (b, 3)).astype(np.float32))
+
+        def run():
+            f = pk.hw_prefold(y, pk.hw_seeds(y, m, mult, nv))
+            g = jax.grad(lambda P: jnp.sum(
+                w * pk._hw_ss_f(True, m, mult, P, f)))(par)
+            s = jax.grad(lambda P: jnp.sum(w * jax.vmap(
+                lambda pr, v, n: hw.sse(pr, v, m, mult, n))(P, y, nv)))(par)
+            return {"plane_params": g, "scan_params": s}
+
+    return chunk, run
+
+
+def _cotangent_cases():
+    for family, variants in (("css", ("plain", "lagset")),
+                             ("garch", ("g11",)), ("hw", ("add", "mult"))):
+        for variant in variants:
+            for ragged in (False, True):
+                for nchunk in (1, 2):
+                    yield pytest.param(
+                        family, variant, ragged, nchunk,
+                        id=f"{family}-{variant}-"
+                           f"{'ragged' if ragged else 'dense'}-nchunk{nchunk}")
+
+
+# recorded on the PARENT of PR 35 (commit 63975b1: XLA formed every
+# objective's cotangent as a panel and the adjoint kernels read it back), f32
+# under this suite's jax_enable_x64, XLA:CPU of this container: the sha of
+# (the parameter gradient, and where the family has them the parameter and
+# data gradients of the data-perturbed branch — ``want_gy`` / ``want_gdata``)
+_COTANGENT_PIN = {
+    "css-plain-0-1": "83949e9651f167db",
+    "css-plain-0-2": "0442ba21effdc497",
+    "css-plain-1-1": "f82903eed5f82691",
+    "css-plain-1-2": "d01b5cf74329ae3e",
+    "css-lagset-0-1": "0b2ca0da662a82c4",
+    "css-lagset-0-2": "ff4657ac8522248f",
+    "css-lagset-1-1": "1ba1fd76c938eae9",
+    "css-lagset-1-2": "ba8134aceb9894be",
+    "garch-g11-0-1": "9794d51a0dbaf612",
+    "garch-g11-0-2": "b6a8fadac3fe0ad4",
+    "garch-g11-1-1": "7a62e4568a65c167",
+    "garch-g11-1-2": "ea2428b91ae3dd98",
+    "hw-add-0-1": "794754ac43b85e34",
+    "hw-add-0-2": "022899e4bbecafc5",
+    "hw-add-1-1": "5e3f0ec1fe2454dd",
+    "hw-add-1-2": "ea2e77ce3985d914",
+    "hw-mult-0-1": "25b747b5614beeae",
+    "hw-mult-0-2": "901837f5bd588f9d",
+    "hw-mult-1-1": "69f822208684638d",
+    "hw-mult-1-2": "cdb183f37db397a9",
+}
+# the scan backend's digest of one case there: no Pallas code in it, so it
+# tells the recording's code generator from another
+_COTANGENT_PIN_HOST = "640b9ad50e48fcd1"
+
+
+@functools.lru_cache(maxsize=None)
+def _cotangent_pin_host():
+    _, run = _cotangent_case("hw", "add", False, 1)
+    return _sha(run()["scan_params"])
+
+
+def _cotangent_digest(out):
+    return _sha(out["plane_params"], *(
+        (out["plane_params_gy"], out["plane_data"])
+        if "plane_data" in out else ()))
+
+
+@pytest.mark.parametrize("family,variant,ragged,nchunk",
+                         list(_cotangent_cases()))
+def test_objective_adjoint_forms_the_cotangent_itself(monkeypatch, family,
+                                                      variant, ragged,
+                                                      nchunk):
+    # the gradient of each folded objective through the plane-taking
+    # adjoint against the SAME gradient composed from the untouched
+    # general-cotangent path: (2 e) gb rounds alike wherever it is formed,
+    # so CSS is bit-equal in the parameters AND the data; GARCH's quotient
+    # is another expression than autodiff's, so it is close.  Holt-Winters'
+    # adjoint has one caller and no panel mode: its gradient against the
+    # scan backend's here, against the parent's digits below.
+    chunk, run = _cotangent_case(family, variant, ragged, nchunk)
+    monkeypatch.setattr(pk, "_CHUNK_T", chunk)
+    out = {k: np.asarray(v) for k, v in run().items()}
+    assert all(np.isfinite(v).all() for v in out.values())
+    assert np.abs(out["plane_params"]).max() > 0
+    if family == "css":
+        for a, b_ in (("plane_params", "panel_params"),
+                      ("plane_params_gy", "panel_params"),
+                      ("plane_data", "panel_data")):
+            assert out[a].tobytes() == out[b_].tobytes(), a
+        assert np.abs(out["plane_data"]).max() > 0
+    elif family == "garch":
+        # the params-only branch and the data-perturbed one run the same
+        # recursion adjoint on the same in-kernel cotangent
+        assert out["plane_params"].tobytes() == out[
+            "plane_params_gy"].tobytes()
+        for a, b_ in (("plane_params", "panel_params"),
+                      ("plane_data", "panel_data")):
+            scale = np.abs(out[b_]).max(axis=0, keepdims=True)
+            np.testing.assert_allclose(out[a] / scale, out[b_] / scale,
+                                       rtol=1e-6, atol=1e-6, err_msg=a)
+    scan = out.get("scan_params")
+    if scan is not None:
+        np.testing.assert_allclose(out["plane_params"], scan, rtol=2e-3,
+                                   atol=2e-3 * np.abs(scan).max())
+
+
+@pytest.mark.parametrize("family,variant,ragged,nchunk",
+                         list(_cotangent_cases()))
+def test_objective_gradient_pinned_to_the_panel_cotangent_parent(
+        monkeypatch, family, variant, ragged, nchunk):
+    # the parent's digits, bit for bit on this code generator: the parameter
+    # gradient of every folded objective and the data-perturbed branches
+    # (``want_gy``, ``want_gdata``: forecasting, ``fit_argarch``)
+    if _cotangent_pin_host() != _COTANGENT_PIN_HOST:
+        pytest.skip("another XLA:CPU code generator than the recording's")
+    chunk, run = _cotangent_case(family, variant, ragged, nchunk)
+    monkeypatch.setattr(pk, "_CHUNK_T", chunk)
+    key = f"{family}-{variant}-{int(ragged)}-{nchunk}"
+    assert _cotangent_digest(run()) == _COTANGENT_PIN[key]

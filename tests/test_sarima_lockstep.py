@@ -362,8 +362,10 @@ def test_lazy_pair_count_evals_and_the_composed_program(monkeypatch,
     assert int(info["compact_at"]) == s1["iters"] < 14
     assert s1["undone"] > 0
     # what a kernel step pays, on both stages: three live lags reaching 5
+    # and the adjoint call's panel operands, y3 and e3 (ISSUE 35)
     for attrs in (s1, s2):
         assert (attrs["lag_terms"], attrs["lag_span"]) == (3, 5)
+        assert attrs["adjoint_panels"] == pk.CSS_ADJOINT_PANELS == 2
     assert s1["series_block"] == pk.css_series_block(
         LAZY_ROWS, 55, ((), 0, (1, 4, 5)))
     # under a caller's jit the panel is a Tracer: stage 1 and stage 2 in

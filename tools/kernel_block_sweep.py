@@ -1,5 +1,6 @@
 #!/usr/bin/env python3
-"""Time the forward fit-objective kernels ALONE at forced series-block widths.
+"""Time the fit-objective kernels ALONE: the forward ones at forced
+series-block widths, the adjoints as the objectives call them.
 
     chiprun -- python tools/kernel_block_sweep.py            # on the chip
     python tools/kernel_block_sweep.py --compile-only        # here: Mosaic + VMEM
@@ -9,6 +10,10 @@ mode and panel shape of the benchmark's cells (the stage-1 chunk and the
 stage-2 compaction), R = 1, 2, 4 (``pallas_kernels.series_rows`` is bypassed
 through the call functions' private ``_r``): milliseconds a call, ns a time
 step and 1,024-series block, and whether every output is BIT-equal to R = 1's.
+The adjoints (mode ``adjoint``; the seasonal lag set ``{1, 24, 25}`` as
+``css_seasonal_neg_loglik`` beside the three) take one register of series a
+step and no ``_r``: one line each, ``r`` 1, the call the objective's
+``custom_vjp`` makes — the cotangent formed in the kernel from the plane.
 One line a case to ``chiprun_out/kernel_block_sweep.jsonl`` and to stdout.
 ``--compile-only`` compiles every case for a described v5e and runs nothing
 (no time is reported from it).
@@ -40,7 +45,8 @@ def _planes(key, n, nsub, scale=1.0, loc=0.0):
 
 
 def cases():
-    """-> (name, mode, rows, t, make_args(key), call(r, *args))."""
+    """-> (name, mode, rows, t, make_args(key), call(r, *args)); an
+    ``adjoint`` case's ``call`` ignores ``r``."""
     for rows in ROWS:
         nsub = rows // pk._LANES
 
@@ -55,6 +61,27 @@ def cases():
             yield ("css_neg_loglik", mode, rows, 999, css_args,
                    lambda r, par, y3, zb3, mode=mode: pk._css_fwd_call_f(
                        1, 1, False, mode, par, y3, zb3, 999, _r=r)[0])
+
+        def css_adj_args(key, lags, t, rows=rows, nsub=nsub):
+            # what _css_ss_f_bwd holds: the residuals of a "both" forward
+            # (any error panel times the recursion alike) and gbar
+            k1, k2, k3, k4 = jax.random.split(key, 4)
+            k = 1 + len(pk._lags(lags[0])) + len(pk._lags(lags[1]))
+            par3 = 0.1 * _planes(k2, k, nsub)
+            tp, _, _ = pk._time_layout(t)
+            return ((_planes(k1, tp, nsub), par3,
+                     jnp.ones((1, nsub, pk._LANES), jnp.float32),
+                     _planes(k3, tp, nsub), None),
+                    jax.random.normal(k4, (rows,), jnp.float32))
+
+        for name, lags, t in (("css_neg_loglik", (1, 1), 999),
+                              ("css_seasonal_neg_loglik",
+                               ((), (1, 24, 25)), 935)):
+            yield (name, "adjoint", rows, t,
+                   functools.partial(css_adj_args, lags=lags, t=t),
+                   lambda r, resid, gbar, lags=lags, t=t, rows=rows:
+                   [pk._fold(pk._css_ss_f_bwd(*lags, False, t, rows, resid,
+                                              gbar)[0])])
 
         def hw_args(key, nsub=nsub, rows=rows):
             k1, k2, k3 = jax.random.split(key, 3)
@@ -71,6 +98,19 @@ def cases():
                    lambda r, par, f, save=save: pk._hw_fwd_call_f(
                        False, 24, False, save, par, f, _r=r)[0])
 
+        def hw_adj_args(key, rows=rows, nsub=nsub):
+            k0, k1, k2, k3, k4, k5 = jax.random.split(key, 6)
+            par, f = hw_args(k0)
+            return ((f, pk._fold(par), _planes(k1, 960, nsub),
+                     _planes(k2, 960, nsub, loc=10.0),
+                     _planes(k3, 960, nsub, scale=0.1),
+                     _planes(k4, 960, nsub, scale=0.5)),
+                    jax.random.normal(k5, (rows,), jnp.float32))
+
+        yield ("hw_sse", "adjoint", rows, 960, hw_adj_args,
+               lambda r, resid, gbar: [pk._fold(pk._hw_ss_f_bwd(
+                   False, 24, False, resid, gbar)[0])])
+
         def garch_args(key, nsub=nsub, rows=rows):
             k1, k2 = jax.random.split(key)
             par = jnp.asarray([1e-6, 0.08, 0.9], jnp.float32) * (
@@ -83,6 +123,17 @@ def cases():
             yield ("garch_neg_loglik", mode, rows, 1000, garch_args,
                    lambda r, par, f, mode=mode: pk._garch_fwd_call_f(
                        False, mode, par, f, _r=r)[0])
+
+        def garch_adj_args(key, rows=rows, nsub=nsub):
+            k0, k1, k2 = jax.random.split(key, 3)
+            par, f = garch_args(k0)
+            h3 = 1e-4 * (1.0 + 0.1 * _planes(k1, 1000, nsub))
+            return ((f, pk._fold(par), h3, None),
+                    jax.random.normal(k2, (rows,), jnp.float32))
+
+        yield ("garch_neg_loglik", "adjoint", rows, 1000, garch_adj_args,
+               lambda r, resid, gbar: [pk._fold(pk._garch_ll_f_bwd(
+                   False, resid, gbar)[0])])
 
 
 def _time(fn, args, calls, reps=3):
@@ -128,7 +179,7 @@ def main():
         blocks = rows // pk._SBLK
         ref = None
         args = None if a.compile_only else make(jax.random.key(rows + t))
-        for r in a.r:
+        for r in ([1] if mode == "adjoint" else a.r):
             rec = {"kernel": name, "mode": mode, "rows": rows, "t": t, "r": r,
                    "device": ("described v5e (compile only)" if a.compile_only
                               else jax.devices()[0].device_kind)}
