@@ -181,8 +181,9 @@ def approx_aic(params, yd, order: Order, include_intercept: bool):
 # 25 of its expanded vector), the parameters reaching the kernel's planes
 # through `_seasonal_kernel_params`; a seasonal fit takes the lockstep driver
 # like a plain one (`_sarima_family`).  `fit_grid` and `models.auto`'s
-# searches stay on the scan: their fused objective zero-pads every order to
-# the grid's depth.
+# searches take the CSS grid kernels for groups of plain orders; a group
+# with a seasonal member stays on the scan, whose fused objective zero-pads
+# every order to the grid's depth.
 
 
 def _validate_seasonal(seasonal) -> Optional[Seasonal]:
@@ -773,22 +774,28 @@ def _fit_stage2_program(order, include_intercept, backend, max_iters, tol,
 # order, so a G-order search stages/prefetches/journals every chunk G times.
 # fit_grid makes the candidate grid a BATCH dimension instead of a loop: K
 # orders that share the plain differencing order d are fitted by ONE
-# compiled program — every order's AR/MA lag-coefficient vectors are
-# expanded (_expand_seasonal_poly) and zero-padded to the grid's max
-# (p+P*s, q+Q*s), the CSS objective runs as a [K]-leading-axis vmap of the
-# one _css_errors_poly scan (conditioning depth stays per-order via
-# condition_lags), and one lockstep batched L-BFGS optimizes the flattened
-# [K*B] problem.  Orders whose FULL differencing signature (d, D, s)
+# fit of the K x B CELLS (cell g*B + r is order g of row r), a
+# lockstep.Family like every other (`_grid_family`): one lockstep batched
+# L-BFGS over the flattened [K*B] problem, straggler compaction over cells,
+# the lazy stage pair and its spans on the Pallas backends.  Its ONE batched
+# objective is, for a group of plain orders on the Pallas backends, the CSS
+# grid kernels over the union lag ranges (one folded panel, every order of
+# a series block in one call, an order's missing terms zero planes); else
+# every order's AR/MA lag-coefficient vectors are expanded
+# (_expand_seasonal_poly) and zero-padded to the grid's max (p+P*s, q+Q*s)
+# and the CSS objective runs as a [K]-leading-axis vmap of the one
+# _css_errors_poly scan (conditioning depth stays per-order via
+# condition_lags).  Orders whose FULL differencing signature (d, D, s)
 # matches share one differenced panel through a per-trace cache (the
 # shared-prep half of the tentpole); variants are embedded right-aligned
 # into the group's common length so every order sees one static shape.
 #
-# The K per-order results are PACKED into the params matrix — per row,
-# per order: [params(k_max), nll, converged, iters, status] — so a fused
-# chunk rides the journal/commit/resume machinery of fit_chunked
-# unchanged (one npz shard per chunk carries the whole fusion group) and
-# models.auto demuxes per-order results after the walk.  Scan backend
-# only: the fused Pallas kernel's folded layout is per-(p, q) static.
+# The K per-order results are PACKED into the params matrix after the
+# cells' finalize — per row, per order: [params(k_max), nll, eligible,
+# converged, iters, status] — so a fused chunk rides the
+# journal/commit/resume machinery of fit_chunked unchanged (one npz shard
+# per chunk carries the whole fusion group) and models.auto demuxes
+# per-order results after the walk.
 
 GRID_PACK_COLS = 5  # nll, eligible, converged, iters, status per order
 
@@ -877,6 +884,61 @@ def _grid_coef_maps(infos, include_intercept: bool, k_max: int, p_max: int,
     return lin_c, lin_phi, quad_phi, lin_th, quad_th
 
 
+def grid_kernels_refusal(specs) -> Optional[str]:
+    """Why a fused group of ``(order, seasonal)`` specs cannot take the
+    Pallas CSS kernels, ``None`` where it can: the grid kernels run ONE
+    differenced panel under the union of dense lag ranges, so every member
+    is a plain order within the kernels' reach (same-``d`` plain orders
+    have one differencing signature, ``grid_diff_cache_keys(specs) == 1``)."""
+    from ..ops import pallas_kernels as pk
+
+    specs = tuple(specs)
+    if any(_validate_seasonal(sea) is not None for _, sea in specs):
+        # (and with it, possibly, several differencing signatures: plain
+        # orders of one d always share theirs)
+        return "the group has a seasonal member"
+    if not pk.css_structural_ok(max(int(o[0]) for o, _ in specs),
+                                max(int(o[2]) for o, _ in specs)):
+        return "an order's lags reach past the kernels' half chunk"
+    return None
+
+
+def resolve_grid_backend(backend: str, specs, dtype, n_time: int) -> str:
+    """:func:`base.resolve_backend` for a fused group: ``"auto"`` takes the
+    grid kernels where the platform and the group allow
+    (:func:`grid_kernels_refusal`), an explicit Pallas backend the group
+    cannot take is refused with the reason."""
+    why = grid_kernels_refusal(specs)
+    if backend in lockstep.PALLAS and why is not None:
+        raise ValueError(
+            f"fit_grid runs backend={backend!r} on groups of plain orders "
+            f"(one differencing signature); {why}: use backend='auto' or "
+            "'scan', or search per order (auto_fit's fuse=1)")
+    return resolve_backend(backend, dtype, n_time, structural_ok=why is None)
+
+
+def _grid_cap(cells: int, backend: str, one_signature: bool) -> Optional[int]:
+    """The fused grid's straggler cap over its ``cells`` = orders x rows.
+    On the scan a QUARTER of them, aligned to 128, from 512 cells on (the
+    cross-order skew makes the tail fat — a whole order can sit converged
+    while another runs — so leaving the full-width lockstep early buys more
+    than the compacted problem's extra width costs).  On the Pallas
+    backends a SIXTEENTH, aligned to the kernels' 1,024-series blocks, from
+    ``optim.COMPACT_MIN_BATCH`` cells on: stage 2 runs its cells one order
+    a cell, at 2.4 times stage 1's cost a cell, and to ``max_iters`` for
+    the few over-specified cells that never converge, so the chip's walk
+    is shortest where stage 1 keeps all but the last sixteenth (PERF.md
+    §6, PR 36: 5.10 / 3.60 / 3.03 / 3.16 s at a 4th / 8th / 16th / 32nd).
+    ``None``: no compaction — small grids, and groups of several
+    signatures, whose cells would each need their own panel."""
+    floor, align, div = ((optim.COMPACT_MIN_BATCH, 1024, 16)
+                         if backend in lockstep.PALLAS else (512, 128, 4))
+    if not one_signature or cells < floor:
+        return None
+    cap = -(-max(align, cells // div) // align) * align
+    return cap if cap < cells else None
+
+
 def fit_grid(
     y,
     specs,
@@ -904,21 +966,24 @@ def fit_grid(
     retry-cannot-help shield.  ``models.auto`` demuxes the pack into
     per-order results.
 
-    Scan backend only (``backend`` must resolve away from pallas); the
-    optimizing CSS methods only.  Numerics match the per-order scan fits
-    up to f32 fusion differences (zero-padded coefficient slots and the
-    shared lockstep loop) — selection built on top is tested to agree
-    with the per-order search; ``fuse=1`` in ``auto_fit`` remains the
-    bitwise per-order path.
+    The fit is a ``lockstep.Family`` over the ``K x B`` CELLS (cell ``g B +
+    r`` is order ``g`` of row ``r``) driven by ``lockstep.fit`` like every
+    other: ``backend`` resolves through :func:`resolve_grid_backend` —
+    ``"auto"`` on a TPU in float32 takes the Pallas CSS grid kernels
+    (``pallas_kernels.css_grid_neg_loglik_folded``: one panel in HBM, every
+    order of a series block in one call) for groups of plain orders with one
+    differencing signature, lazy stage 1 / stage 2 with the ``fit.stage1`` /
+    ``fit.stage2`` spans counting cells; groups with a seasonal member or
+    several signatures run the ``[K]``-vmapped padded-polynomial scan, and
+    an explicit ``"pallas"`` on them raises.  The optimizing CSS methods
+    only.  Numerics match the per-order fits up to f32 fusion differences
+    (zero coefficient slots and the shared lockstep loop) — selection built
+    on top is tested to agree with the per-order search; ``fuse=1`` in
+    ``auto_fit`` remains the bitwise per-order path.
     """
     if method not in ("css-lbfgs", "css-cgd", "css-bobyqa"):
         raise ValueError(
             f"fit_grid requires an optimizing CSS method, got {method!r}")
-    if backend not in ("auto", "scan"):
-        raise ValueError(
-            f"fit_grid runs on the portable scan backend (the fused pallas "
-            f"kernel's folded layout is per-order static); got "
-            f"backend={backend!r}")
     specs = tuple((tuple(int(v) for v in o),
                    _validate_seasonal(sea)) for o, sea in specs)
     if not specs:
@@ -931,20 +996,56 @@ def fit_grid(
     yb, single = ensure_batched(y)
     if tol is None:
         tol = 1e-6 if yb.dtype == jnp.float64 else 1e-4
+    n = yb.shape[1] - d0
+    backend = resolve_grid_backend(backend, specs, yb.dtype, n)
     align_mode = resolve_align_mode(yb, align_mode)
-    run = _grid_fit_program(specs, include_intercept, max_iters, float(tol),
-                            align_mode)
-    return debatch_fit(run(yb), single, False)
+    static = (specs, include_intercept, backend, max_iters, float(tol))
+    K, bsz = len(specs), yb.shape[0]
+    p_max = max(seasonal_lag_span(o, sea)[0] for o, sea in specs)
+    q_max = max(seasonal_lag_span(o, sea)[1] for o, sea in specs)
+    from ..ops import pallas_kernels as pk
+
+    out = lockstep.fit(
+        (yb,), backend=backend, max_iters=max_iters, compact=True,
+        inline=lambda: _grid_fit_program(*static, align_mode),
+        stage1=lambda: _grid_stage1_program(*static, align_mode),
+        stage2=lambda: _grid_stage2_program(*static),
+        # stage 1 runs the K orders of every row, stage 2 one order a cell
+        series_block=lambda rows: pk.css_grid_series_block(
+            *((K, bsz) if rows == K * bsz else (1, rows)), n, p_max, q_max),
+        stage_attrs={"orders": K, "cells": K * bsz,
+                     "lag_terms": p_max + q_max,
+                     "lag_span": max(p_max, q_max),
+                     "adjoint_panels": pk.CSS_ADJOINT_PANELS},
+        cells=K * bsz,
+        cap=lambda cells: _grid_cap(cells, backend,
+                                    grid_diff_cache_keys(specs) == 1))
+    return debatch_fit(out, single, False)
 
 
-@jit_program
-def _grid_fit_program(specs, include_intercept, max_iters, tol,
-                      align_mode="general"):
-    """One compiled program per fused grid: shared align + per-(d, D, s)
-    differencing, per-order Hannan-Rissanen warm starts, the [K]-axis
-    vmapped padded-polynomial CSS objective, and one lockstep batched
-    L-BFGS over the flattened ``[K*B]`` problem."""
-    from .. import obs as _obs
+@functools.partial(jax.tree_util.register_dataclass,
+                   data_fields=["yds"], meta_fields=["cells"])
+@dataclasses.dataclass(frozen=True)
+class _GridPanels:
+    """The scan objective's data: one differenced ``[B, n]`` panel per
+    differencing signature of the group, shared by its orders through a
+    broadcast — or (``cells``) a straggler subset's ``[cap, n]`` gather,
+    each cell its own row."""
+
+    yds: tuple
+    cells: bool = False
+
+
+def _grid_family(specs, include_intercept: bool, backend: str,
+                 align_mode: Optional[str] = None):
+    """``(family, pack)`` of a fused grid: the fit of its ``K x B`` cells as
+    a :class:`lockstep.Family`, and the map from the cells' finalized
+    ``FitResult`` to the rows' packed one.  Shared align + per-``(d, D, s)``
+    differencing, per-order Hannan-Rissanen warm starts zero-padded to the
+    widest order, ONE batched objective over the flattened cells on every
+    backend: the CSS grid kernels over the union lag ranges, or the
+    ``[K]``-axis vmapped padded-polynomial scan."""
+    from ..ops import pallas_kernels as _pk
 
     infos = [_grid_spec_info(o, sea, include_intercept) for o, sea in specs]
     K = len(infos)
@@ -953,46 +1054,48 @@ def _grid_fit_program(specs, include_intercept, max_iters, tol,
     p_max = max(i["p_full"] for i in infos)
     q_max = max(i["q_full"] for i in infos)
     i0 = int(include_intercept)
-    lin_c, lin_phi, quad_phi, lin_th, quad_th = _grid_coef_maps(
-        infos, include_intercept, k_max, p_max, q_max)
     any_seasonal = any(i["seasonal"] is not None for i in infos)
-    # distinct differencing signatures -> trace-time shared-prep accounting
-    # (mirrors grid_diff_cache_keys; the obs counter records the saved
-    # differencings once per compile, like optim.stage2_compact_traces)
-    n_keys = grid_diff_cache_keys(tuple((i["order"], i["seasonal"])
-                                        for i in infos))
-    if K > n_keys:
-        _obs.counter("auto_fit.diff_cache_hits").add(K - n_keys)
+    on_kernels = backend in lockstep.PALLAS
+    interp = backend == "pallas-interpret"
+    # the groups of orders that share a differenced panel, in spec order
+    # (one per differencing signature: grid_diff_cache_keys of them)
+    by_sig: dict = {}
+    for g, info in enumerate(infos):
+        by_sig.setdefault((info["D"], info["s"]) if info["D"] else (0, 0),
+                          []).append(g)
 
-    def run(yb):
+    def prep(yb):
         bsz, t_len = yb.shape
         with jax.named_scope("arima.grid_align"):
             ya, nv0 = maybe_align(yb, align_mode)  # ragged: NaN head/tail
         n = t_len - d
-        # shared-prep cache (the tentpole's second half): ONE differencing
-        # per (d, D, s) signature across the fusion group; seasonal
-        # variants embed right-aligned into the group's common length n
-        # (the scan's n_valid masking zeroes the pad, so the embedded
-        # recursion sees the bytes a per-order fit of length n - D*s would)
-        cache = {}
-
-        def differenced(D, s):
-            key = (D, s) if D else (0, 0)
-            if key in cache:
-                return cache[key]
+        # shared-prep cache: ONE differencing per (d, D, s) signature
+        # across the fusion group; seasonal variants embed right-aligned
+        # into the group's common length n (the scan's n_valid masking
+        # zeroes the pad, so the embedded recursion sees the bytes a
+        # per-order fit of length n - D*s would)
+        def differenced(D: int, s: int):
             with jax.named_scope("arima.grid_difference"):
                 yd = jax.vmap(lambda v: _difference(v, d))(ya)
                 if D:
                     yd = jax.vmap(
                         lambda v: _difference_seasonal(v, D, s))(yd)
                     yd = jnp.pad(yd, ((0, 0), (n - yd.shape[1], 0)))
-            cache[key] = yd
             return yd
 
-        inits, oks, n_effs, nvds, yds = [], [], [], [], []
+        yds = {sig: differenced(*sig) for sig in by_sig}
+        folded = _GridPanels(tuple(yds.values()))
+        if on_kernels:
+            # ONE fold for the init sweeps and every evaluation of every
+            # order; each order conditions on its own AR depth
+            (yd,) = yds.values()
+            folded = _pk.css_grid_prefold(
+                yd, [i["p_full"] for i in infos], nv0 - d)
+
+        inits, oks, n_effs, nvds = [], [], [], []
         for info in infos:
             p, _, q = info["order"]
-            yd = differenced(info["D"], info["s"])
+            yd = yds[(info["D"], info["s"]) if info["D"] else (0, 0)]
             nvd = nv0 - info["d_full"]
             with jax.named_scope("arima.grid_init"):
                 # non-seasonal HR warm start on the (fully) differenced
@@ -1000,39 +1103,104 @@ def _grid_fit_program(specs, include_intercept, max_iters, tol,
                 # _sarima_family's prep).  Inside the ok region the
                 # embedding cannot change HR's static long-AR order m
                 # (the nvd >= 4*(p+q+1) gate pins m = p+q+1 either way).
-                base = hannan_rissanen_batched(
-                    yd, (p, 0, q), include_intercept, nvd)
+                if on_kernels and _pk.hr_structural_ok(p, q):
+                    base = _pk.hr_init(yd, (p, 0, q), include_intercept, nvd,
+                                       interpret=interp, y3=folded.y3)
+                else:
+                    base = hannan_rissanen_batched(
+                        yd, (p, 0, q), include_intercept, nvd)
                 if info["P"] + info["Q"]:
                     base = jnp.concatenate(
                         [base, jnp.zeros((bsz, info["P"] + info["Q"]),
                                          yd.dtype)], axis=1)
             # zero-pad to k_max: the objective never reads the pad, so its
             # gradient (and therefore its trajectory) stays exactly 0
-            init = jnp.pad(base, ((0, 0), (0, k_max - info["k"])))
+            inits.append(jnp.pad(base, ((0, 0), (0, k_max - info["k"]))))
             pf, qf, k = info["p_full"], info["q_full"], info["k"]
             ok = nvd >= pf + qf + max(pf + qf + 1, 1) + k + 2
-            ok = ok & (nvd >= 4 * (p + q + 1))
+            oks.append(ok & (nvd >= 4 * (p + q + 1)))
             # optimize the MEAN log-likelihood (lockstep.Prepared.scale)
-            n_eff = jnp.maximum(nvd - pf, 1).astype(yd.dtype)
-            inits.append(init)
-            oks.append(ok)
-            n_effs.append(n_eff)
+            n_effs.append(jnp.maximum(nvd - pf, 1).astype(yd.dtype))
             nvds.append(nvd)
-            yds.append(yd)
+        ne = jnp.concatenate(n_effs)
+        # what the objective reads cell by cell: valid length, effective
+        # observations, conditioning depth, order index
+        rows = (jnp.concatenate(nvds), ne,
+                jnp.repeat(jnp.asarray([i["p_full"] for i in infos],
+                                       jnp.int32), bsz),
+                jnp.repeat(jnp.arange(K, dtype=jnp.int32), bsz))
+        return lockstep.Prepared((jnp.concatenate(inits),),
+                                 jnp.concatenate(oks), ne, (), folded, rows)
 
-        def row_nll(c, phi_f, theta_f, ydr, nvr, cond_p, ner):
-            e = _css_errors_poly(c, phi_f, theta_f, ydr, n_valid=nvr,
-                                 condition_lags=cond_p)
-            css = jnp.sum(e * e)
-            sigma2 = css / ner
-            return 0.5 * ner * (jnp.log(2.0 * jnp.pi * sigma2) + 1.0)
+    # -- the Pallas objective: kernel planes [c, a_1..p_max, b_1..q_max]
+    # over the union lag ranges, an order's missing terms zero planes.  The
+    # map from a cell's packed parameters is a constant 0/1 matrix per
+    # order, gathered by the cell's order index, so a straggler subset of
+    # mixed orders is one uniform problem; its transpose (JAX's) keeps a
+    # zero plane's gradient from every parameter
+    planes = np.zeros((K, 1 + p_max + q_max, k_max), np.float32)
+    for g, info in enumerate(infos):
+        p, _, q = info["order"]
+        if include_intercept:
+            planes[g, 0, 0] = 1.0
+        for i in range(p):
+            planes[g, 1 + i, i0 + i] = 1.0
+        for j in range(q):
+            planes[g, 1 + p_max + j, i0 + p + j] = 1.0
 
-        # over rows; the panel (ydr) is per row, the conditioning depth is
-        # shared by the order
-        nll_rows = jax.vmap(row_nll, in_axes=(0, 0, 0, 0, 0, None, 0))
-        # over the leading [K] order axis of one diff-signature's stack;
-        # the shared differenced panel broadcasts instead of tiling K x B
-        nll_grid = jax.vmap(nll_rows, in_axes=(0, 0, 0, None, 0, 0, 0))
+    def kernel_objective(folded, rows):
+        _, ne, _, gcell = rows
+        sel = jnp.asarray(planes)[gcell]  # [cells, planes, k_max]
+        return lambda X: _pk.css_grid_neg_loglik_folded(
+            jnp.einsum("cjk,ck->cj", sel, X), folded, p_max, q_max, ne,
+            interpret=interp)
+
+    # -- the scan objective
+    def row_nll(c, phi_f, theta_f, ydr, nvr, cond_p, ner):
+        e = _css_errors_poly(c, phi_f, theta_f, ydr, n_valid=nvr,
+                             condition_lags=cond_p)
+        css = jnp.sum(e * e)
+        sigma2 = css / ner
+        return 0.5 * ner * (jnp.log(2.0 * jnp.pi * sigma2) + 1.0)
+
+    # over rows; the panel (ydr) is per row, the conditioning depth is
+    # shared by the order
+    nll_rows = jax.vmap(row_nll, in_axes=(0, 0, 0, 0, 0, None, 0))
+    # over the leading [K] order axis of one diff-signature's stack; the
+    # shared differenced panel broadcasts instead of tiling K x B
+    nll_grid = jax.vmap(nll_rows, in_axes=(0, 0, 0, None, 0, 0, 0))
+    lin_c, lin_phi, quad_phi, lin_th, quad_th = _grid_coef_maps(
+        infos, include_intercept, k_max, p_max, q_max)
+
+    def scan_objective(folded, rows):
+        nvd, ne, cp, gcell = rows
+        if folded.cells:
+            # a straggler subset: each cell's expanded coefficients from
+            # the per-order (linear, quadratic) constant maps
+            # (_grid_coef_maps) — gatherable by cell index, which the
+            # static-unrolled full objective is not
+            (yd_s,) = folded.yds
+            lc_s = jnp.asarray(lin_c)[gcell]
+            lphi_s = jnp.asarray(lin_phi)[gcell]
+            lth_s = jnp.asarray(lin_th)[gcell]
+            qphi_s = jnp.asarray(quad_phi)[gcell] if any_seasonal else None
+            qth_s = jnp.asarray(quad_th)[gcell] if any_seasonal else None
+            cell_nll = jax.vmap(row_nll)
+
+            def fb_s(p_sub):
+                c = jnp.einsum("ck,ck->c", lc_s, p_sub)
+                phi = jnp.einsum("cpk,ck->cp", lphi_s, p_sub)
+                th = jnp.einsum("cqk,ck->cq", lth_s, p_sub)
+                if any_seasonal:
+                    phi = phi + jnp.einsum("cpkl,ck,cl->cp", qphi_s,
+                                           p_sub, p_sub)
+                    th = th + jnp.einsum("cqkl,ck,cl->cq", qth_s,
+                                         p_sub, p_sub)
+                return cell_nll(c, phi, th, yd_s, nvd, cp, ne)
+
+            return fb_s
+        bsz = folded.yds[0].shape[0]
+        nvds, n_effs = nvd.reshape(K, bsz), ne.reshape(K, bsz)
 
         def fb(p_flat):
             pk = p_flat.reshape(K, bsz, k_max)
@@ -1062,111 +1230,48 @@ def _grid_fit_program(specs, include_intercept, max_iters, tol,
             # one vmapped objective per diff signature: the [K_sig] stack
             # shares its differenced panel via broadcast (in_axes=None)
             out = [None] * K
-            by_sig: dict = {}
-            for g, info in enumerate(infos):
-                sig = ((info["D"], info["s"]) if info["D"] else (0, 0))
-                by_sig.setdefault(sig, []).append(g)
-            for sig, gs in by_sig.items():
+            for yd, gs in zip(folded.yds, by_sig.values()):
                 nll_sig = nll_grid(
                     jnp.stack([cs[g] for g in gs]),
                     jnp.stack([phis[g] for g in gs]),
                     jnp.stack([thetas[g] for g in gs]),
-                    yds[gs[0]],
+                    yd,
                     jnp.stack([nvds[g] for g in gs]),
                     jnp.asarray([infos[g]["p_full"] for g in gs]),
                     jnp.stack([n_effs[g] for g in gs]),
                 )  # [K_sig, B]
                 for j, g in enumerate(gs):
-                    out[g] = nll_sig[j] / n_effs[g]
+                    out[g] = nll_sig[j]
             return jnp.concatenate(out)  # [K*B]
 
-        # straggler compaction over the flattened [K*B] CELL grid: the
-        # lockstep loop runs to the slowest (order, row) cell while every
-        # pass evaluates all K*B cells — with per-order convergence rates
-        # this skewed (an HR-init order can converge in 0 iterations while
-        # a neighbor runs 16), the tail would cost more than the fusion
-        # saves.  Once at most `cap` cells remain, they are gathered into
-        # one small uniform problem whose objective reconstructs each
-        # cell's expanded coefficients from the per-order (linear,
-        # quadratic) constant maps (_grid_coef_maps) — gatherable by cell
-        # index, which the static-unrolled main objective is not.
-        # Single-signature groups only: a mixed-signature gather would
-        # need per-cell panel selection; those groups stay lockstep.
-        cells = K * bsz
-        straggler_fun = None
-        cap = None
-        if n_keys == 1 and cells >= 512:
-            # cap at cells/4 (128-aligned): the cross-ORDER skew makes the
-            # tail fat (a whole order can sit converged while another
-            # runs), so exiting the full-width lockstep earlier buys more
-            # than the compacted problem's extra quarter-width costs
-            cap = -(-max(128, cells // 4) // 128) * 128
-            if cap >= cells:
-                cap = None
-        if cap is not None:
-            lc_a = jnp.asarray(lin_c)
-            lphi_a = jnp.asarray(lin_phi)
-            lth_a = jnp.asarray(lin_th)
-            qphi_a = jnp.asarray(quad_phi) if any_seasonal else None
-            qth_a = jnp.asarray(quad_th) if any_seasonal else None
-            yd0 = yds[0]
-            nvd_all = jnp.concatenate(nvds)
-            ne_all = jnp.concatenate(n_effs)
-            cp_all = jnp.concatenate([
-                jnp.full((bsz,), info["p_full"], jnp.int32)
-                for info in infos])
+        return fb
 
-            def straggler_fun(idxc):
-                gcell = idxc // bsz
-                rcell = idxc % bsz
-                lc_s = lc_a[gcell]
-                lphi_s = lphi_a[gcell]
-                lth_s = lth_a[gcell]
-                qphi_s = qphi_a[gcell] if any_seasonal else None
-                qth_s = qth_a[gcell] if any_seasonal else None
-                yd_s = yd0[rcell]
-                nvd_s = nvd_all[idxc]
-                ne_s = ne_all[idxc]
-                cp_s = cp_all[idxc]
-                cell_nll = jax.vmap(row_nll)
+    def take_rows(folded, idxc):
+        (yd,) = folded.yds
+        return _GridPanels((yd[idxc % yd.shape[0]],), cells=True)
 
-                def fb_s(p_sub):
-                    c = jnp.einsum("ck,ck->c", lc_s, p_sub)
-                    phi = jnp.einsum("cpk,ck->cp", lphi_s, p_sub)
-                    th = jnp.einsum("cqk,ck->cq", lth_s, p_sub)
-                    if any_seasonal:
-                        phi = phi + jnp.einsum("cpkl,ck,cl->cp", qphi_s,
-                                               p_sub, p_sub)
-                        th = th + jnp.einsum("cqkl,ck,cl->cq", qth_s,
-                                             p_sub, p_sub)
-                    return cell_nll(c, phi, th, yd_s, nvd_s, cp_s,
-                                    ne_s) / ne_s
+    family = lockstep.Family(
+        backend, prep, kernel_objective if on_kernels else scan_objective,
+        None, lambda x: x,
+        cap=lambda cells: _grid_cap(cells, backend, len(by_sig) == 1),
+        take=_pk.take_cells if on_kernels else take_rows)
 
-                return fb_s
-
-        with jax.named_scope("arima.grid_lbfgs"):
-            res = optim.minimize_lbfgs_batched(
-                fb, jnp.concatenate(inits), max_iters=max_iters, tol=tol,
-                straggler_fun=straggler_fun, straggler_cap=cap)
-
-        xk = res.x.reshape(K, bsz, k_max)
-        fk = res.f.reshape(K, bsz)
+    def pack(res: FitResult) -> FitResult:
+        """The cells' results -> per row, per order ``[params(k_max), nll,
+        eligible, converged, iters, status]``."""
+        bsz = res.params.shape[0] // K
+        xk = res.params.reshape(K, bsz, k_max)
+        nllk = res.neg_log_likelihood.reshape(K, bsz)
         convk = res.converged.reshape(K, bsz)
         itk = res.iters.reshape(K, bsz)
-        blocks, nlls, convs, statuses = [], [], [], []
+        statk = res.status.reshape(K, bsz)
+        blocks, nlls = [], []
         for g, info in enumerate(infos):
-            ok = oks[g]
-            colmask = jnp.arange(k_max) < info["k"]
-            params_g = jnp.where(ok[:, None] & colmask[None, :], xk[g],
-                                 jnp.nan)
-            nll_g = jnp.where(ok, fk[g] * n_effs[g], jnp.nan)
-            conv_g = convk[g] & ok
-            # status judges THIS order's own parameter columns: the
-            # k_max padding is NaN by the pack convention, and letting
-            # derive_status's finiteness check read it would flag every
-            # narrower order on the grid DIVERGED
-            status_g = derive_status(
-                ok, convk[g], jnp.where(colmask[None, :], params_g, 0.0))
+            # THIS order's own parameter columns: the k_max padding is NaN
+            # by the pack convention (the cells' status never read it: a
+            # padded slot of the optimizer's x stays exactly 0)
+            params_g = jnp.where((jnp.arange(k_max) < info["k"])[None, :],
+                                 xk[g], jnp.nan)
             # the PACK must be all-finite: the resilient runner's
             # failed-row mask requires finite(params).all(axis=-1) per
             # ROW, and the pack IS the row — NaN slots (excluded orders,
@@ -1174,22 +1279,19 @@ def _grid_fit_program(specs, include_intercept, max_iters, tol,
             # whole panel through the retry ladder.  Eligibility rides
             # as its own column; _demux_fused restores the per-order NaN
             # conventions from it and the status column.
-            elig_g = ok & jnp.isfinite(nll_g)
+            elig_g = jnp.isfinite(nllk[g])
             dt = params_g.dtype
             blocks += [jnp.where(jnp.isfinite(params_g), params_g, 0.0),
-                       jnp.where(elig_g, nll_g, 0.0)[:, None],
+                       jnp.where(elig_g, nllk[g], 0.0)[:, None],
                        elig_g.astype(dt)[:, None],
-                       conv_g.astype(dt)[:, None],
+                       convk[g].astype(dt)[:, None],
                        itk[g].astype(dt)[:, None],
-                       status_g.astype(dt)[:, None]]
-            nlls.append(jnp.where(elig_g, nll_g, jnp.nan))
-            convs.append(conv_g)
-            statuses.append(status_g)
+                       statk[g].astype(dt)[:, None]]
+            nlls.append(jnp.where(elig_g, nllk[g], jnp.nan))
         wide = jnp.concatenate(blocks, axis=1)  # [B, K*(k_max+5)]
         nll_all = jnp.stack(nlls)
         best = jnp.min(jnp.where(jnp.isnan(nll_all), jnp.inf, nll_all),
                        axis=0)
-        row_nll_out = jnp.where(jnp.isfinite(best), best, jnp.nan)
         # row-level summaries feed the DRIVER's accounting and the
         # resilient runner's per-ROW decisions — the per-order truth
         # lives in the pack.  A row's summary is its BEST outcome across
@@ -1201,13 +1303,54 @@ def _grid_fit_program(specs, include_intercept, max_iters, tol,
         # EVERY order structurally refused the row, which is when the
         # runner's retry-cannot-help shield is actually true).
         return FitResult(
-            wide, row_nll_out,
-            jnp.any(jnp.stack(convs), axis=0),
-            jnp.max(itk, axis=0),
-            jnp.min(jnp.stack(statuses), axis=0),
-        )
+            wide, jnp.where(jnp.isfinite(best), best, jnp.nan),
+            jnp.any(convk, axis=0), jnp.max(itk, axis=0),
+            jnp.min(statk, axis=0))
 
-    return run
+    return family, pack
+
+
+def _count_diff_cache_hits(specs) -> None:
+    """Trace-time shared-prep accounting: the differencings a fused group
+    saves, recorded once per compile of a program that prepares the panel
+    (like ``optim.stage2_compact_traces``)."""
+    from .. import obs as _obs
+
+    saved = len(specs) - grid_diff_cache_keys(specs)
+    if saved > 0:
+        _obs.counter("auto_fit.diff_cache_hits").add(saved)
+
+
+@jit_program
+def _grid_fit_program(specs, include_intercept, backend, max_iters, tol,
+                      align_mode="general"):
+    """The whole fused fit as one program (``lockstep.fit_program``): the
+    scan backend, and a grid too small for the lazy pair."""
+    _count_diff_cache_hits(specs)
+    family, pack = _grid_family(specs, include_intercept, backend, align_mode)
+    run = lockstep.fit_program(family, max_iters, tol)
+    return lambda yb: pack(run(yb))
+
+
+@jit_program
+def _grid_stage1_program(specs, include_intercept, backend, max_iters, tol,
+                         align_mode="general"):
+    _count_diff_cache_hits(specs)
+    family, pack = _grid_family(specs, include_intercept, backend, align_mode)
+    run = lockstep.stage1_program(family, max_iters, tol)
+
+    def run1(yb):
+        out, aux = run(yb)
+        return pack(out), aux
+
+    return run1
+
+
+@jit_program
+def _grid_stage2_program(specs, include_intercept, backend, max_iters, tol):
+    family, pack = _grid_family(specs, include_intercept, backend)
+    run = lockstep.stage2_program(family, max_iters, tol)
+    return lambda start, fin: pack(run(start, fin))
 
 
 # ---------------------------------------------------------------------------

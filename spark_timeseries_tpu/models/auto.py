@@ -581,10 +581,13 @@ def auto_fit(
     polynomial grid program (``models.arima.fit_grid``), so every chunk
     is staged/prefetched/journaled once for K orders instead of K times
     and orders sharing a ``(d, D, s)`` differencing signature difference
-    the panel once (``meta["auto_fit"]["diff_cache_hits"]``).  Fused
-    walks run the scan backend; selection over a fused group agrees with
-    the per-order search (tested) but is not bitwise (padded coefficient
-    slots, shared lockstep loop).  Resilient fused searches retry per
+    the panel once (``meta["auto_fit"]["diff_cache_hits"]``).  A fused
+    walk resolves ``backend`` like any fit (``arima.resolve_grid_backend``):
+    on a TPU a group of plain orders runs the Pallas CSS grid kernels
+    through the lockstep driver, a group with a seasonal member the scan
+    (an explicit ``"pallas"`` on it is refused before any walk starts);
+    selection over a fused group agrees with the per-order search (tested)
+    but is not bitwise (zero coefficient slots, shared lockstep loop).  Resilient fused searches retry per
     ROW, not per (row, order): the ladder fires only for rows with NO
     usable candidate (a single stubborn order neither sends the row
     through the ladder nor wipes the orders that did fit) — per-candidate
@@ -675,11 +678,6 @@ def auto_fit(
             raise ValueError(
                 f"fit kwargs {bad} are not supported by the fused grid "
                 "program; pass fuse=1 for the per-order search")
-        if fit_kwargs.get("backend", "auto") not in ("auto", "scan"):
-            raise ValueError(
-                "fused groups run on the portable scan backend; pass "
-                "fuse=1 to search per order with backend="
-                f"{fit_kwargs['backend']!r}")
     diff_cache_hits = _grid_diff_cache_hits(specs, groups)
     from ..reliability import fit_chunked
     from ..reliability import source as source_mod
@@ -693,6 +691,15 @@ def auto_fit(
             raise ValueError(
                 f"auto_fit expects [batch, time], got {values.shape}")
         b = int(values.shape[0])
+    for members in groups:
+        if len(members) > 1:
+            # a Pallas backend a fused group cannot take is refused here,
+            # before any walk starts, with fit_grid's own reason
+            arima.resolve_grid_backend(
+                fit_kwargs.get("backend", "auto"),
+                tuple((specs[g].order, specs[g].seasonal) for g in members),
+                values.dtype,
+                int(values.shape[1]) - specs[members[0]].order[1])
     nv0 = panel_n_valid(values)
     g_total = len(specs)
     t0 = time.perf_counter()

@@ -44,6 +44,14 @@ class Prepared(NamedTuple):
     rows: tuple = ()  # [B, ...] arrays that objective reads row by row
 
 
+def straggler_cap(cells: int) -> Optional[int]:
+    """The straggler cap of a batch of ``cells`` optimizer rows, ``None``
+    where the compaction does not pay: ``optim``'s rule, and every family's
+    unless it declares its own (``Family.cap``)."""
+    cap = optim.compaction_cap(cells)
+    return cap if cells >= optim.COMPACT_MIN_BATCH and cap < cells else None
+
+
 class Family(NamedTuple):
     """What a model family declares about its fit, each thing once."""
 
@@ -53,12 +61,19 @@ class Family(NamedTuple):
     # unscaled), over the whole prepared panel or a straggler subset of it
     objective: Callable[[Any, tuple], Callable]
     # (x[d], one row of Prepared.series) -> f: the portable per-series
-    # objective (the retry ladder's fallback rung, the tests' reference)
-    scan_objective: Callable
+    # objective (the retry ladder's fallback rung, the tests' reference);
+    # None: ``objective`` is the family's on the scan backend too
+    scan_objective: Optional[Callable]
     to_natural: Callable  # optimizer space [B, d] -> reported params [B, k]
     # per-row choice among the starts' results; None declares ONE start,
     # whose stage 2 finalizes in its own program
     merge: Optional[Callable] = None
+    # optimizer rows -> their straggler cap (None: no compaction).  A grid
+    # of K orders optimizes K cells a panel row under a cap of its own
+    cap: Callable[[int], Optional[int]] = straggler_cap
+    # (folded, idxc) -> the stragglers' folded pytree; None: the optimizer's
+    # rows are the panel's columns (``pallas_kernels.take_series``)
+    take: Optional[Callable] = None
 
 
 def finalize(res, ok, scale, to_natural=lambda x: x) -> FitResult:
@@ -83,11 +98,11 @@ def _mean_objective(family: Family, folded, rows, scale):
     return lambda x: fb(x) / scale
 
 
-def _stragglers(p: Prepared, idxc):
-    """The rows ``idxc`` of the pallas objective's data and scale."""
+def _stragglers(family: Family, p: Prepared, idxc):
+    """The rows ``idxc`` of the fused objective's data and scale."""
     from ..ops import pallas_kernels as pk
 
-    return (pk.take_series(p.folded, idxc),
+    return ((family.take or pk.take_series)(p.folded, idxc),
             tuple(a[idxc] for a in p.rows), p.scale[idxc])
 
 
@@ -101,14 +116,15 @@ def fit_program(family: Family, max_iters: int, tol: float,
     def run(xb, *extra):
         p = family.prep(xb, *extra)
         info = None
-        if family.backend in PALLAS:
+        if family.backend in PALLAS or family.scan_objective is None:
             fb = _mean_objective(family, p.folded, p.rows, p.scale)
-            bsz = xb.shape[0]
+            cap = family.cap(p.x0s[0].shape[0]) if compact else None
             straggler_fun = None
-            if compact and bsz >= optim.COMPACT_MIN_BATCH:
+            if cap is not None:
 
                 def straggler_fun(idxc):
-                    return _mean_objective(family, *_stragglers(p, idxc))
+                    return _mean_objective(
+                        family, *_stragglers(family, p, idxc))
 
             results = []
             for s, x0 in enumerate(p.x0s):
@@ -117,7 +133,7 @@ def fit_program(family: Family, max_iters: int, tol: float,
                 res = optim.minimize_lbfgs_batched(
                     fb, x0, max_iters=max_iters, tol=tol,
                     count_evals=counted, straggler_fun=straggler_fun,
-                    straggler_cap=optim.compaction_cap(bsz))
+                    straggler_cap=cap)
                 if counted:
                     res, info = res
                 results.append(res)
@@ -146,7 +162,7 @@ def stage1_program(family: Family, max_iters: int, tol: float,
     def run(xb, *extra):
         p = family.prep(xb, *extra)
         fb = _mean_objective(family, p.folded, p.rows, p.scale)
-        cap = optim.compaction_cap(xb.shape[0])
+        cap = family.cap(p.x0s[0].shape[0])
         results, starts = [], []
         for s, x0 in enumerate(p.x0s):
             res1, carry = optim.lbfgs_batched_stage1(
@@ -156,7 +172,7 @@ def stage1_program(family: Family, max_iters: int, tol: float,
             # pure function of its inputs, folds nothing, and keeps stable
             # shapes: ONE compiled stage 2 serves every start that needs it
             starts.append({"carry": carry, "res": res1,
-                           "sub": _stragglers(p, carry.idxc)})
+                           "sub": _stragglers(family, p, carry.idxc)})
             results.append(res1)
         out = finalize(_merged(family, results), p.ok, p.scale,
                        family.to_natural)
@@ -213,7 +229,9 @@ def fit(args: tuple, *, backend: str, compact: bool, max_iters: int,
         inline: Callable, stage1: Callable, stage2: Callable,
         merge: Optional[Callable] = None,
         series_block: Optional[Callable[[int], int]] = None,
-        stage_attrs: Optional[dict] = None):
+        stage_attrs: Optional[dict] = None,
+        cells: Optional[int] = None,
+        cap: Callable[[int], Optional[int]] = straggler_cap):
     """Fit the panel ``args[0]`` with a family's compiled programs, each
     given as a thunk that looks it up (only the programs that run are looked
     up, and stage 2 is traced and compiled only when a stage 1 leaves
@@ -223,21 +241,24 @@ def fit(args: tuple, *, backend: str, compact: bool, max_iters: int,
     objective kernel and ``stage_attrs`` what else it has to say of a
     kernel step (every family ``adjoint_panels``, the panel-sized operands
     of its objective's adjoint call; ARIMA also ``lag_terms``,
-    ``lag_span``); both are reported on the stage spans and choose nothing.
+    ``lag_span``; a grid of orders also ``orders``, ``cells``); both are
+    reported on the stage spans and choose nothing.  ``cells`` is the
+    optimizer's row count where it is not the panel's (a grid of K orders:
+    K cells a row; the spans' ``rows`` / ``undone`` then count cells) and
+    ``cap`` the family's straggler rule (``Family.cap``).
 
     The lazy pair runs on the pallas backends when the batch is concrete
-    and large enough for the compaction to pay (``optim.COMPACT_MIN_BATCH``,
-    and a cap below the batch).  ``fit.stage1`` spans the dispatch of every
+    and the family's cap says the compaction pays (:func:`straggler_cap`:
+    ``optim.COMPACT_MIN_BATCH``, and a cap below the batch).  ``fit.stage1`` spans the dispatch of every
     start's stage 1 and the host's wait for it at the first gate (the later
     starts' gates find their scalars ready); ``fit.stage2`` opens only
     around a dispatch.
     """
     xb = args[0]
-    bsz = xb.shape[0]
+    bsz = xb.shape[0] if cells is None else cells
+    cap = cap(bsz)
     if not (compact and backend in PALLAS
-            and not isinstance(xb, jax.core.Tracer)
-            and bsz >= optim.COMPACT_MIN_BATCH
-            and optim.compaction_cap(bsz) < bsz):
+            and not isinstance(xb, jax.core.Tracer) and cap is not None):
         return inline()(*args)
     run1 = stage1()
     with obs.span("fit.stage1", rows=bsz) as span:
@@ -258,7 +279,6 @@ def fit(args: tuple, *, backend: str, compact: bool, max_iters: int,
         # stage 2 shares stage 1's iteration budget, so an exhausted budget
         # skips the dispatch (the scatter of unchanged state is an identity)
         if n_undone > 0 and int(carry.k) < max_iters:
-            cap = optim.compaction_cap(bsz)
             with obs.span("fit.stage2", rows=cap,
                           **_kernel_attrs(series_block, stage_attrs, cap)):
                 res = (stage2()(start) if merge

@@ -414,16 +414,17 @@ def _css_bwd_kernel(ar, ma, t_limit, cs, nchunk, hp, want_gy, g_plane,
     base = (nchunk - 1 - c) * cs
     zb = zb_ref[0]
     k = 1 + npar + len(ma)
+    zero = _plane_zero(zb_ref)
 
     @pl.when(c == 0)
     def _():
         for j in range(max(q, 1)):
-            ca_ref[j] = _ZERO()
+            ca_ref[j] = zero
         for r in range(k):
-            gpar_ref[r] = _ZERO()
+            gpar_ref[r] = zero
         if want_gy:
             for i_ in range(max(p, 1)):
-                cap_ref[i_] = _ZERO()
+                cap_ref[i_] = zero
 
     adj_ref[:] = 2.0 * e_ref[:] * g_ref[0] if g_plane else g_ref[:]
 
@@ -485,7 +486,7 @@ def _css_bwd_kernel(ar, ma, t_limit, cs, nchunk, hp, want_gy, g_plane,
         ca_ref[jnp.clip(tl, 0, max(q - 1, 0))] = jnp.where(tl < q, a, cur)
         return tuple(new)
 
-    accs = _fori(cs, body, tuple(_ZERO() for _ in range(k)))
+    accs = _fori(cs, body, (zero,) * k)
     for r in range(k):
         gpar_ref[r] = gpar_ref[r] + accs[r]
 
@@ -860,6 +861,254 @@ def css_neg_loglik(params, yd, order: Order, include_intercept: bool,
     return css_neg_loglik_folded(params, y3, zb3, yd.shape[1], order,
                                  include_intercept, n_valid,
                                  interpret=interpret)
+
+
+# -- a GRID of K orders over one folded panel --------------------------------
+#
+# An order search fits K candidate orders to every series.  Their parameter
+# planes, masks, errors and sums are CELL arrays ``[n, K, Bp/128, 128]`` (cell
+# ``g * B + r`` is order ``g`` of series ``r``); the panel ``y3`` stays the one
+# ``[tp, Bp/128, 128]`` array and is never tiled.  The kernels are the two
+# above over the UNION lag sets of the group, an order's missing terms zero
+# planes (``models.arima`` owns the map from each order's parameters to the
+# planes, so a zero plane's gradient never reaches a parameter).  A grid step
+# takes G orders of R registers of series: its panel block is ``(cs, 8 R,
+# 128)`` whatever the order (the index map ignores it, so the G-order groups
+# of one series block re-use the resident block), its cell blocks ``(n, G,
+# 8 R, 128)``, and in the body a plane of the panel broadcasts against G
+# planes of parameters — G x R independent recurrence chains in one loop
+# iteration that load ``y`` once.  G = 1 is "the order as a grid axis", G = K
+# "K chains a time step"; :func:`css_grid_block` chooses between them by
+# VMEM and by what the chip showed best.  A straggler subset of cells is a
+# grid of ONE order over as many gathered series (:func:`take_cells`).
+
+
+@functools.partial(jax.tree_util.register_dataclass,
+                   data_fields=["y3", "zb4"], meta_fields=["t", "b", "k"])
+@dataclasses.dataclass(frozen=True)
+class CssGridFolded:
+    """A differenced panel folded once for ``k`` orders a series
+    (:func:`css_grid_prefold`): ``y3 [tp, Bp/128, 128]``, the orders'
+    first live positions ``zb4 [1, k, Bp/128, 128]``; ``t`` the panel's true
+    length and ``b`` its rows (static: they ride the treedef)."""
+
+    y3: jax.Array
+    zb4: jax.Array
+    t: int
+    b: int
+    k: int
+
+
+def _fold_cells(x2d, k: int):
+    """``[k * B, n] -> [n, k, Bp/128, 128]``: :func:`_fold` per order."""
+    kb, n = x2d.shape
+    x3 = jnp.pad(x2d.reshape(k, kb // k, n),
+                 ((0, 0), (0, _pad_to(kb // k, _SBLK)), (0, 0)))
+    return x3.transpose(2, 0, 1).reshape(n, k, -1, _LANES)
+
+
+def _unfold_cells(x4d, b: int):
+    """Inverse of :func:`_fold_cells`: ``[n, k, Bp/128, 128] -> [k * B, n]``."""
+    n, k = x4d.shape[:2]
+    return x4d.reshape(n, k, -1)[:, :, :b].transpose(1, 2, 0).reshape(k * b, n)
+
+
+def css_grid_prefold(yd, depths, n_valid=None) -> CssGridFolded:
+    """Fold a differenced panel ONCE for a grid of ``len(depths)`` orders,
+    order ``g`` conditioning on its own ``depths[g]`` steps
+    (:func:`css_prefold`'s panel, a mask start per cell)."""
+    b, n = yd.shape
+    y3, start3 = css_prefold(yd, (0, 0, 0), n_valid)
+    zb4 = jnp.stack([start3 + float(d) for d in depths], axis=1)
+    return CssGridFolded(y3, zb4, n, b, len(depths))
+
+
+def take_cells(folded: CssGridFolded, idxc) -> CssGridFolded:
+    """The cells ``idxc`` (a multiple of 1024 of them) as a grid of ONE
+    order: cell ``i`` gathers the panel's column ``i % b`` and the mask start
+    of order ``i // b`` (:func:`take_series` over cells)."""
+    rows, orders = idxc % folded.b, idxc // folded.b
+    nb = idxc.shape[0] // _LANES
+    tp = folded.y3.shape[0]
+    y3 = folded.y3.reshape(tp, -1)[:, rows].reshape(tp, nb, _LANES)
+    zb = folded.zb4.reshape(folded.k, -1)[orders, rows]
+    return CssGridFolded(y3, zb.reshape(1, 1, nb, _LANES), folded.t,
+                         idxc.shape[0], 1)
+
+
+# orders a grid step, the most the chip showed worth taking, per mode
+# (PERF.md §6, PR 36: ms a call of 9 orders over [131072, 1000] at (G, R) —
+# sum (1, 4) 7.91, (3, 4) 4.74, (9, 2) 4.94, (9, 1) 5.25; both (1, 4) 8.76,
+# (3, 2) 7.71, (9, 1) 7.58, the error panels' write at the HBM's pace;
+# adjoint, one register of series a step like the plain one, (1, 1) 34.09,
+# (3, 1) 14.31, VMEM refuses 9)
+_CSS_GRID_G = {"sum": 3, "both": 9, "adjoint": 3}
+
+
+def _css_grid_bwd_layout(ar, ma, t):
+    """The grid adjoint's blocks, as :func:`_css_fwd_layout` states the
+    forward's: the panel (and its neighbour), then the cell blocks."""
+    _, cs, nchunk = _time_layout(t)
+    hp = nchunk > 1
+    k = 1 + len(ar) + len(ma)
+    panel = [(cs, _rev(nchunk))] + ([(cs, _rev_prev(nchunk))] if hp else [])
+    # e (and its neighbour), parameters, mask, the cotangent's plane
+    ins = panel + panel + [(k, _fixed), (1, _fixed), (1, _fixed)]
+    return ins, [(k, _fixed)], [cs, max(_span(ma), 1)]
+
+
+def css_grid_block(k: int, nsub: int, layout, mode: str):
+    """``(G, R)``: the orders and the registers of series a grid step of a
+    CSS grid kernel takes — static facts only, as :func:`series_rows`.  G is
+    the largest divisor of ``k`` within ``_CSS_GRID_G[mode]`` whose blocks fit
+    VMEM at one register of series (the panel's ``len(panel)`` blocks once,
+    every other block and the scratch G times); R then the widest the forward
+    rule allows beside it (1 for the adjoint)."""
+    ins, outs, scratch = layout
+    npanel = sum(1 for _, im in ins if im is not _fixed)
+    if mode == "adjoint":
+        npanel //= 2  # the error panels are cell blocks
+
+    def fits(g, r):
+        shared = 2 * sum(n for n, _ in ins[:npanel])
+        cells = 2 * sum(n for n, _ in ins[npanel:] + outs) + sum(scratch)
+        return (shared + g * cells) * r * _TILE_BYTES <= _VMEM_BLOCK_BUDGET
+
+    g = max((d for d in range(1, k + 1)
+             if k % d == 0 and d <= _CSS_GRID_G[mode] and fits(d, 1)),
+            default=1)
+    if mode == "adjoint":
+        return g, 1
+    r = next((r for r in _R_CHOICES if r <= _CSS_R[mode]
+              and nsub % (_SUBL * r) == 0 and fits(g, r)), 1)
+    return g, r
+
+
+def css_grid_series_block(k: int, rows: int, t: int, p, q,
+                          mode: str = "sum") -> int:
+    """Series per grid step of the forward CSS grid kernel over ``k`` orders
+    of ``rows`` series: ``1024 * R`` (:func:`css_grid_block`)."""
+    return _SBLK * css_grid_block(
+        k, _nsub(rows), _css_fwd_layout(p, q, mode, t), mode)[1]
+
+
+def _grid_specs(entries, npanel, kg, g, r):
+    """BlockSpecs on the merged grid axis ``i = series block * kg + order
+    group``: the first ``npanel`` entries are the panel's (the order group
+    does not move them), the rest cell blocks of ``g`` orders."""
+    def panel(imap):
+        return lambda i, c: imap(i // kg, c)
+
+    def cell(imap):
+        def cell_map(i, c):
+            tc, blk, z = imap(i // kg, c)
+            return (tc, i % kg, blk, z)
+        return cell_map
+
+    return ([_bs(n, panel(im), r) for n, im in entries[:npanel]]
+            + [pl.BlockSpec((n, g, _SUBL * r, _LANES), cell(im))
+               for n, im in entries[npanel:]])
+
+
+def _css_grid_fwd_call(ar, ma, interpret, mode, params, f: CssGridFolded,
+                       _g=None, _r=None):
+    """The forward CSS kernel over every cell of ``f`` -> ``(outs,
+    par4)``; ``mode`` ``sum`` or ``both``.  ``_g`` / ``_r`` force the
+    block (tests and the sweep)."""
+    par4 = _fold_cells(params, f.k)
+    _, cs, nchunk = _time_layout(f.t)
+    hp = nchunk > 1
+    layout = _css_fwd_layout(ar, ma, mode, f.t)
+    tp, nsub, _ = f.y3.shape
+    g, r = css_grid_block(f.k, nsub, layout, mode)
+    g, r = _g or g, _r or r
+    ins, outs, scratch = layout
+    npanel = 2 if hp else 1
+    kg = f.k // g
+    outs4 = pl.pallas_call(
+        functools.partial(_css_fwd_kernel, ar, ma, f.t, cs, hp, mode),
+        grid=(nsub // (_SUBL * r) * kg, nchunk),
+        in_specs=_grid_specs(ins, npanel, kg, g, r),
+        out_specs=_grid_specs(outs, 0, kg, g, r),
+        out_shape=[jax.ShapeDtypeStruct(
+            (tp if im is _cur else n, f.k, nsub, _LANES), f.y3.dtype)
+            for n, im in outs],
+        scratch_shapes=[pltpu.VMEM((n, g, _SUBL * r, _LANES), jnp.float32)
+                        for n in scratch],
+        compiler_params=_VMEM_PARAMS,
+        interpret=interpret,
+    )(*((f.y3, f.y3) if hp else (f.y3,)), par4, f.zb4)
+    return outs4, par4
+
+
+def _css_grid_bwd_call(ar, ma, interpret, f: CssGridFolded, par4, e4, gb4,
+                       _g=None):
+    """The CSS adjoint over every cell -> ``gparams [k * b, planes]``: the
+    cotangent ``2 e gbar`` formed in the kernel from the plane ``gb4``."""
+    _, cs, nchunk = _time_layout(f.t)
+    hp = nchunk > 1
+    layout = _css_grid_bwd_layout(ar, ma, f.t)
+    nsub = f.y3.shape[1]
+    g = _g or css_grid_block(f.k, nsub, layout, "adjoint")[0]
+    ins, outs, scratch = layout
+    kg = f.k // g
+    gpar4 = pl.pallas_call(
+        functools.partial(_css_bwd_kernel, ar, ma, f.t, cs, nchunk, hp,
+                          False, True),
+        grid=(nsub // _SUBL * kg, nchunk),
+        in_specs=_grid_specs(ins, 2 if hp else 1, kg, g, 1),
+        out_specs=_grid_specs(outs, 0, kg, g, 1)[0],
+        out_shape=jax.ShapeDtypeStruct(par4.shape, e4.dtype),
+        scratch_shapes=[pltpu.VMEM((n, g, _SUBL, _LANES), jnp.float32)
+                        for n in scratch],
+        compiler_params=_VMEM_PARAMS,
+        interpret=interpret,
+    )(*((f.y3, f.y3, e4, e4) if hp else (f.y3, e4)), par4, f.zb4, gb4)
+    return _unfold_cells(gpar4, f.b)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1, 2))
+def _css_grid_ss(ar, ma, interpret: bool, params, f: CssGridFolded):
+    """Per-cell CSS sum of squared errors ``[k * b]`` (differentiable in
+    ``params`` only: the panel is the fit's constant).  As
+    :func:`_css_ss_f`: the value-only kernel on the primal path, errors
+    saved on the vjp path with the value accumulated in the same order."""
+    (css4,), _ = _css_grid_fwd_call(ar, ma, interpret, "sum", params, f)
+    return _unfold_cells(css4, f.b)[:, 0]
+
+
+def _css_grid_ss_fwd(ar, ma, interpret, params, f):
+    (e4, css4), par4 = _css_grid_fwd_call(ar, ma, interpret, "both", params,
+                                          f)
+    return _unfold_cells(css4, f.b)[:, 0], (f, par4, e4)
+
+
+def _css_grid_ss_bwd(ar, ma, interpret, resid, gbar):
+    f, par4, e4 = resid
+    gb4 = _fold_cells(gbar[:, None].astype(e4.dtype), f.k)
+    gparams = _css_grid_bwd_call(ar, ma, interpret, f, par4, e4, gb4)
+    return gparams, jax.tree_util.tree_map(jnp.zeros_like, f)
+
+
+_css_grid_ss.defvjp(_css_grid_ss_fwd, _css_grid_ss_bwd)
+
+
+@_scoped("pallas.css_grid_neg_loglik")
+def css_grid_neg_loglik_folded(params_k, folded: CssGridFolded, ar, ma,
+                               n_eff, *, interpret: bool = False):
+    """The concentrated CSS likelihood of every cell of a grid of orders
+    ``[k * b]``, from a panel folded once (:func:`css_grid_prefold`) or a
+    straggler subset of its cells (:func:`take_cells`).  ``params_k``:
+    ``[k * b, 1 + len(ar) + len(ma)]`` kernel planes ``[c, a.., b..]`` over
+    the group's UNION lag sets, zero where a cell's order has no such term;
+    ``n_eff [k * b]``: each cell's effective observations (its valid length
+    less its own order's AR reach).  A scope of its own, so that a trace
+    tells an order search's kernel events from a single order's."""
+    ar, ma = _lags(ar), _lags(ma)
+    _require_css_structure(ar, ma)
+    css = _css_grid_ss(ar, ma, interpret, params_k, folded)
+    sigma2 = css / n_eff
+    return 0.5 * n_eff * (jnp.log(2.0 * jnp.pi * sigma2) + 1.0)
 
 
 # ---------------------------------------------------------------------------

@@ -458,9 +458,16 @@ class TestFused:
         with pytest.raises(ValueError, match="same-d"):
             arima.fit_grid(jnp.asarray(y), (((1, 0, 0), None),
                                             ((0, 1, 1), None)))
-        with pytest.raises(ValueError, match="scan backend"):
+        # the kernels take a group of plain orders (PR 36): what is refused
+        # is a native Pallas run off the TPU, by resolve_backend as for
+        # every fit, and a group the grid kernels cannot take
+        with pytest.raises(ValueError, match="natively"):
             arima.fit_grid(jnp.asarray(y), (((1, 0, 0), None),),
                            backend="pallas")
+        with pytest.raises(ValueError, match="seasonal member"):
+            arima.fit_grid(jnp.asarray(y), (((1, 0, 0), None),
+                                            ((0, 0, 0), (1, 0, 0, 4))),
+                           backend="pallas-interpret")
         with pytest.raises(ValueError, match="at least one"):
             arima.fit_grid(jnp.asarray(y), ())
         assert arima.grid_pack_width(
@@ -478,9 +485,13 @@ class TestFused:
         with pytest.raises(ValueError, match="fuse=1"):
             auto.auto_fit(jnp.asarray(y), [(1, 0, 0), (0, 0, 1)],
                           count_evals=True)
-        with pytest.raises(ValueError, match="scan backend"):
+        with pytest.raises(ValueError, match="natively"):
             auto.auto_fit(jnp.asarray(y), [(1, 0, 0), (0, 0, 1)],
                           backend="pallas")
+        with pytest.raises(ValueError, match="seasonal member"):
+            auto.auto_fit(jnp.asarray(y),
+                          [(1, 0, 0), (0, 0, 0, (1, 0, 0, 4))],
+                          backend="pallas-interpret")
         # singleton groups never hit the fused program: pallas rides
         y2 = make_ar_panel(b=8, t=64, seed=2)
         res = auto.auto_fit(jnp.asarray(y2), [(1, 0, 0), (0, 1, 1)],

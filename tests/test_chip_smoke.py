@@ -191,6 +191,22 @@ def test_pallas_kernels_compile_for_v5e(monkeypatch):
         programs["garch stage2"] = (
             garch._fit_stage2_program(60, TOL, "pallas"),
             [garch_aux["starts"][0], garch_aux["fin"]])
+        # the fused order search: nine orders over one folded panel, the
+        # order-grouped blocks of stage 1 (T = 1000: the cell's VMEM) and
+        # stage 2's one order over the gathered cells
+        grid9 = (tuple(((p, 1, q), None) for p in range(3)
+                       for q in range(3)), True, "pallas", 60, TOL)
+        grid_s1 = arima._grid_stage1_program(*grid9, "dense")
+        programs["arima grid9 stage1"] = (grid_s1, [arg(4096, 1000)])
+        grid_aux = jax.tree_util.tree_map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                           sharding=sharding),
+            jax.eval_shape(grid_s1, arg(4096, 1000))[1])
+        programs["arima grid9 stage2"] = (
+            arima._grid_stage2_program(*grid9),
+            [grid_aux["starts"][0], grid_aux["fin"]])
+        programs["arima grid9 inline general T=2500 (multi-chunk)"] = (
+            arima._grid_fit_program(*grid9, "general"), [arg(256, 2500)])
         for name, (program, args) in programs.items():
             try:
                 program.lower(*args).compile()

@@ -763,6 +763,40 @@ class TestStageGateSpans:
         assert (s1["attrs"]["lag_terms"], s1["attrs"]["lag_span"]) == (3, 5)
         assert s1["parent"] == s2["parent"] == primary.id
 
+    def test_grid_fit_spans_count_cells(self, monkeypatch, tmp_path):
+        # a fused order search takes the same gate (ISSUE 36): its stage
+        # spans count CELLS (orders x rows) and say what one kernel call
+        # carries — the K orders, the union's lag terms and their reach
+        monkeypatch.setattr(optim, "COMPACT_MIN_BATCH", 2048)
+        rng = np.random.default_rng(3)
+        y = jnp.asarray(np.cumsum(rng.normal(size=(256, 40)),
+                                  axis=1).astype(np.float32))
+        specs = tuple(((p, 1, q), None) for p in range(3) for q in range(3))
+        fit = lambda: arima.fit_grid(  # noqa: E731
+            y, specs, backend="pallas-interpret", max_iters=14)
+        off = fit()
+        p = str(tmp_path / "ev.jsonl")
+        obs.enable(p)
+        with obs.span("fit.primary") as primary:
+            on = fit()
+        obs.disable()
+        _assert_bitwise(on, off)
+        spans = {s["name"]: s for s in _span_lines(p)}
+        s1, s2 = spans["fit.stage1"], spans["fit.stage2"]
+        cells, cap = 9 * 256, 1024  # the grid's cap: whole 1,024-cell blocks
+        static = {"orders": 9, "cells": cells, "lag_terms": 4, "lag_span": 2,
+                  "adjoint_panels": 2}
+        assert {k: v for k, v in s1["attrs"].items()
+                if k not in ("iters", "undone")} == {
+            "rows": cells, **static,
+            "series_block": pk.css_grid_series_block(9, 256, 39, 2, 2)}
+        assert 0 < s1["attrs"]["undone"] <= cap
+        assert 0 < s1["attrs"]["iters"] < 14
+        assert s2["attrs"] == {
+            "rows": cap, **static,
+            "series_block": pk.css_grid_series_block(1, cap, 39, 2, 2)}
+        assert s1["parent"] == s2["parent"] == primary.id
+
     @pytest.mark.parametrize("family", ["arima", "sarima", "holtwinters",
                                         "garch"])
     def test_count_evals_instruments_the_fit_that_runs(self, monkeypatch,

@@ -913,6 +913,22 @@ def _stage_programs(family, b, t):
     from spark_timeseries_tpu.models import holtwinters as hw
 
     y = jax.ShapeDtypeStruct((b, t), jnp.float32)
+    if family == "arima-grid3":
+        # a fused order search: 3 orders a row, so a third of the rows make
+        # the same cells; the adjoint reads the ONE panel and the cells'
+        # error panels
+        specs = (((1, 1, 0), None), ((0, 1, 1), None), ((2, 1, 2), None))
+        static = (specs, True, "pallas-interpret", 13, 1e-4)
+        y = jax.ShapeDtypeStruct((b // 2, t), jnp.float32)
+        stage1 = arima._grid_stage1_program.__wrapped__(*static, "dense")
+        aux = jax.eval_shape(stage1, y)[1]
+        cap = arima._grid_cap(3 * b // 2, "pallas-interpret", True)
+        return pk.CSS_ADJOINT_PANELS, (
+            (stage1, (y,), b // 2),
+            (arima._grid_fit_program.__wrapped__(*static, "dense"), (y,),
+             b // 2),
+            (arima._grid_stage2_program.__wrapped__(*static),
+             (aux["starts"][0], aux["fin"]), cap))
     if family in ("arima111", "sarima-airline4"):
         seasonal = (0, 1, 1, 4) if family == "sarima-airline4" else None
         order = (0, 1, 1) if seasonal else (1, 1, 1)
@@ -948,7 +964,7 @@ def _stage_programs(family, b, t):
 
 
 @pytest.mark.parametrize("family", ["arima111", "sarima-airline4", "hw-add",
-                                    "hw-mult", "garch11"])
+                                    "hw-mult", "garch11", "arima-grid3"])
 def test_fit_programs_form_no_cotangent_panel(monkeypatch, family):
     # the CPU's stand-in for "``broadcast_multiply_fusion`` /
     # ``multiply_select_fusion`` left the device's ops" (PERF.md §6, PR 35):
@@ -1821,10 +1837,23 @@ def test_kernel_block_sweep_cases_trace():
     # arguments and call trace at every width, on shapes alone
     from tools import kernel_block_sweep as sweep
 
-    seen = set()
+    seen, grid = set(), set()
     for name, mode, rows, t, make, call in sweep.cases():
         args = jax.eval_shape(make, jax.random.key(0))
         tp, _, _ = pk._time_layout(t)
+        if name == "css_grid_neg_loglik":
+            # the order search's cells: 9 orders over one panel at G orders
+            # a grid step, or one order over a quarter of the cells
+            k, b = (1, 9 * rows // 4) if mode.endswith("cells") else (9, rows)
+            for r in (1, 2, 4):
+                outs = jax.eval_shape(functools.partial(call, r), *args)
+                if mode.startswith("adjoint"):  # five planes, folded flat
+                    assert [o.shape for o in outs] == [(5, k * b // 128, 128)]
+                else:
+                    assert all(o.shape[1:] == (k, b // 128, 128)
+                               and o.shape[0] in (1, tp) for o in outs)
+            grid.add(mode)
+            continue
         for r in (1, 2, 4):
             outs = jax.eval_shape(functools.partial(call, r), *args)
             assert outs[-1].shape[1:] == (rows // 128, 128)
@@ -1832,6 +1861,8 @@ def test_kernel_block_sweep_cases_trace():
             assert all(o.shape[0] in ((3, 4) if mode == "adjoint"
                                       else (1, tp)) for o in outs)
         seen.add((name, mode))
+    assert grid == {f"{m}.{tag}" for m in ("sum", "both", "adjoint")
+                    for tag in ("g1", "g3", "g9", "cells")}
     kernels = {"css_neg_loglik", "hw_sse", "garch_neg_loglik"}
     assert {(n, m) for n, m in seen if m == "adjoint"} == {
         (n, "adjoint") for n in kernels | {"css_seasonal_neg_loglik"}}

@@ -462,7 +462,7 @@ def test_refusals_that_stay():
     with pytest.raises(ValueError, match="count_evals requires the pallas"):
         arima.fit(y, order, seasonal=seasonal, backend="scan",
                   count_evals=True)
-    with pytest.raises(ValueError, match="scan backend"):
+    with pytest.raises(ValueError, match="seasonal member"):
         arima.fit_grid(y, ((order, seasonal),), backend="pallas-interpret")
     # a lag past half a time chunk cannot take the kernels: auto resolves
     # to the scan, an explicit kernel backend is refused at the kernel

@@ -14,6 +14,11 @@ The adjoints (mode ``adjoint``; the seasonal lag set ``{1, 24, 25}`` as
 ``css_seasonal_neg_loglik`` beside the three) take one register of series a
 step and no ``_r``: one line each, ``r`` 1, the call the objective's
 ``custom_vjp`` makes — the cotangent formed in the kernel from the plane.
+The order search's grid kernels (``css_grid_neg_loglik``: 9 orders over one
+``[131072, 1000]`` panel, union lags {1, 2} on both sides) run at G = 1, 3, 9
+orders a grid step (modes ``sum.g1`` .. ``adjoint.g9``; ``ns_step_block`` is
+per ORDER there) and, as stage 2 sees them, as one order over a gathered
+subset of the cells (``.cells``: a quarter of them, flat).
 One line a case to ``chiprun_out/kernel_block_sweep.jsonl`` and to stdout.
 ``--compile-only`` compiles every case for a described v5e and runs nothing
 (no time is reported from it).
@@ -37,6 +42,7 @@ import numpy as np  # noqa: E402
 from spark_timeseries_tpu.ops import pallas_kernels as pk  # noqa: E402
 
 ROWS = (131072, 16384)
+GRID_ORDERS = 9  # p, q in 0..2
 
 
 def _planes(key, n, nsub, scale=1.0, loc=0.0):
@@ -82,6 +88,53 @@ def cases():
                    lambda r, resid, gbar, lags=lags, t=t, rows=rows:
                    [pk._fold(pk._css_ss_f_bwd(*lags, False, t, rows, resid,
                                               gbar)[0])])
+
+        # the order search's grid (PR 36): 9 orders over ONE panel at G
+        # orders a grid step (G = 1: the order as a grid axis, G = 9: nine
+        # chains a time step), and stage 2's compaction as a grid of one
+        # order over a quarter of the cells
+        def grid_fold(key, k, rows=rows):
+            b = rows if k > 1 else GRID_ORDERS * rows // 4
+            return pk.CssGridFolded(
+                _planes(key, 1000, b // pk._LANES),
+                jnp.ones((1, k, b // pk._LANES, pk._LANES), jnp.float32),
+                999, b, k)
+
+        def grid_args(key, k, rows=rows):
+            k1, k2 = jax.random.split(key)
+            f = grid_fold(k1, k)
+            par = (jnp.asarray([0.01, 0.4, 0.1, 0.3, 0.1], jnp.float32)
+                   + 0.05 * jax.random.normal(k2, (k * f.b, 5), jnp.float32))
+            return par, f
+
+        def grid_adj_args(key, k, rows=rows):
+            k1, k2, k3, k4 = jax.random.split(key, 4)
+            f = grid_fold(k1, k)
+            nsub = f.b // pk._LANES
+            return (f, 0.1 * jax.random.normal(
+                        k2, (5, k, nsub, pk._LANES), jnp.float32),
+                    jax.random.normal(k3, (1000, k, nsub, pk._LANES),
+                                      jnp.float32),
+                    jax.random.normal(k4, (1, k, nsub, pk._LANES),
+                                      jnp.float32))
+
+        if rows == ROWS[0]:
+            for k, gs in ((GRID_ORDERS, (1, 3, 9)), (1, (1,))):
+                for g in gs:
+                    tag = f"g{g}" if k > 1 else "cells"
+                    for mode in ("sum", "both"):
+                        yield ("css_grid_neg_loglik", f"{mode}.{tag}", rows,
+                               999, functools.partial(grid_args, k=k),
+                               lambda r, par, f, mode=mode, g=g:
+                               pk._css_grid_fwd_call(
+                                   (1, 2), (1, 2), False, mode, par, f,
+                                   _g=g, _r=r)[0])
+                    yield ("css_grid_neg_loglik", f"adjoint.{tag}", rows,
+                           999, functools.partial(grid_adj_args, k=k),
+                           lambda r, f, par4, e4, gb4, g=g: [pk._fold(
+                               pk._css_grid_bwd_call(
+                                   (1, 2), (1, 2), False, f, par4, e4, gb4,
+                                   _g=g))])
 
         def hw_args(key, nsub=nsub, rows=rows):
             k1, k2, k3 = jax.random.split(key, 3)
@@ -177,10 +230,14 @@ def main():
             continue
         tp, _, _ = pk._time_layout(t)
         blocks = rows // pk._SBLK
+        if mode.endswith(".cells"):  # a quarter of the grid's cells, flat
+            blocks = blocks * GRID_ORDERS // 4
+        orders = GRID_ORDERS if ".g" in mode else 1
         ref = None
         args = None if a.compile_only else make(jax.random.key(rows + t))
-        for r in ([1] if mode == "adjoint" else a.r):
+        for r in ([1] if mode.startswith("adjoint") else a.r):
             rec = {"kernel": name, "mode": mode, "rows": rows, "t": t, "r": r,
+                   "orders": orders,
                    "device": ("described v5e (compile only)" if a.compile_only
                               else jax.devices()[0].device_kind)}
             fn = jax.jit(functools.partial(call, r))
@@ -201,7 +258,8 @@ def main():
                     del host
                     s = _time(fn, args, a.calls)
                     rec["ms_call"] = s * 1e3
-                    rec["ns_step_block"] = s * 1e9 / (tp * blocks)
+                    # per order where one call carries several
+                    rec["ns_step_block"] = s * 1e9 / (tp * blocks * orders)
             except Exception as e:  # noqa: BLE001 - a refused width is a reading
                 rec["error"] = f"{type(e).__name__}: {str(e)[:300]}"
             line = json.dumps(rec)
