@@ -576,7 +576,8 @@ def fit(
         stage1=lambda: _fit_stage1_program(*static, has_init, align_mode,
                                            count_evals, seasonal),
         stage2=lambda: _fit_stage2_program(*static, seasonal),
-        series_block=lambda rows: pk.css_series_block(rows, n, (ar, 0, ma)),
+        series_block=lambda rows, mode: pk.css_series_block(
+            rows, n, (ar, 0, ma), mode),
         stage_attrs={"lag_terms": len(ar) + len(ma),
                      "lag_span": max(ar + ma, default=0),
                      "adjoint_panels": pk.CSS_ADJOINT_PANELS})
@@ -1011,8 +1012,9 @@ def fit_grid(
         stage1=lambda: _grid_stage1_program(*static, align_mode),
         stage2=lambda: _grid_stage2_program(*static),
         # stage 1 runs the K orders of every row, stage 2 one order a cell
-        series_block=lambda rows: pk.css_grid_series_block(
-            *((K, bsz) if rows == K * bsz else (1, rows)), n, p_max, q_max),
+        series_block=lambda rows, mode: pk.css_grid_series_block(
+            *((K, bsz) if rows == K * bsz else (1, rows)), n, p_max, q_max,
+            mode),
         stage_attrs={"orders": K, "cells": K * bsz,
                      "lag_terms": p_max + q_max,
                      "lag_span": max(p_max, q_max),

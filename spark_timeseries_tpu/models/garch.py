@@ -169,7 +169,8 @@ def _garch_kernel_attrs(t):
     ``series_block`` / ``stage_attrs``)."""
     from ..ops import pallas_kernels as pk
 
-    return {"series_block": lambda rows: pk.garch_series_block(rows, t),
+    return {"series_block": lambda rows, mode: pk.garch_series_block(
+                rows, t, mode),
             "stage_attrs": {"adjoint_panels": pk.GARCH_ADJOINT_PANELS}}
 
 

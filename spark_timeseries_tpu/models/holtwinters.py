@@ -185,8 +185,8 @@ def fit(
                                            count_evals),
         stage2=lambda: _fit_stage2_program(*static),
         merge=lambda: _merge_starts_program(*static),
-        series_block=lambda rows: pk.hw_series_block(
-            rows, yb.shape[1], period),
+        series_block=lambda rows, mode: pk.hw_series_block(
+            rows, yb.shape[1], period, mode),
         stage_attrs={"adjoint_panels": pk.HW_ADJOINT_PANELS})
     if count_evals:
         out = (out[0], {**out[1], "n_starts": n_starts})

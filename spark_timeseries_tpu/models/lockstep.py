@@ -213,22 +213,24 @@ def merge_program(family: Family):
 
 
 def _kernel_attrs(series_block, stage_attrs, rows: int) -> dict:
-    """What a stage span says of the objective kernel: ``series_block``,
-    the series its value-only kernel takes per grid step over ``rows``
-    series (1,024 x the ``pallas_kernels.series_rows`` of its shapes), and
-    the family's own static ``stage_attrs``; computed on the host and only
-    when the tracing plane is on."""
+    """What a stage span says of the objective kernel: ``series_block`` and
+    ``adjoint_series_block``, the series its value-only kernel and its
+    adjoint take per grid step over ``rows`` series (1,024 x the
+    ``pallas_kernels.series_rows`` of their shapes), and the family's own
+    static ``stage_attrs``; computed on the host and only when the tracing
+    plane is on."""
     if not obs.enabled():
         return {}
     block = {} if series_block is None else {
-        "series_block": series_block(rows)}
+        "series_block": series_block(rows, "sum"),
+        "adjoint_series_block": series_block(rows, "adjoint")}
     return {**block, **(stage_attrs or {})}
 
 
 def fit(args: tuple, *, backend: str, compact: bool, max_iters: int,
         inline: Callable, stage1: Callable, stage2: Callable,
         merge: Optional[Callable] = None,
-        series_block: Optional[Callable[[int], int]] = None,
+        series_block: Optional[Callable[[int, str], int]] = None,
         stage_attrs: Optional[dict] = None,
         cells: Optional[int] = None,
         cap: Callable[[int], Optional[int]] = straggler_cap):
@@ -237,8 +239,9 @@ def fit(args: tuple, *, backend: str, compact: bool, max_iters: int,
     up, and stage 2 is traced and compiled only when a stage 1 leaves
     unconverged rows).  Returns what the programs return: a ``FitResult``,
     or ``(FitResult, info)`` from programs built with ``count_evals``.
-    ``series_block`` is the family's ``rows -> series per grid step`` of its
-    objective kernel and ``stage_attrs`` what else it has to say of a
+    ``series_block`` is the family's ``(rows, mode) -> series per grid
+    step`` of its objective kernel (``mode`` ``"sum"``, the value-only call,
+    or ``"adjoint"``) and ``stage_attrs`` what else it has to say of a
     kernel step (every family ``adjoint_panels``, the panel-sized operands
     of its objective's adjoint call; ARIMA also ``lag_terms``,
     ``lag_span``; a grid of orders also ``orders``, ``cells``); both are

@@ -13,9 +13,9 @@ These kernels fuse the recursion into grid steps whose series block lives in
 VMEM: series are folded to ``[time, 8, 128]`` tiles (sublane x lane = 1024
 series per block), the natural f32 vector-register shape, so every time step
 is a handful of full-width VPU ops instead of an XLA loop iteration.  The
-forward fit-objective kernels (CSS, GARCH, Holt-Winters) take ``R`` such
-tiles per grid step (:func:`series_rows`): ``R`` independent recurrence
-chains share one loop iteration.
+fit-objective kernels (CSS, GARCH, Holt-Winters; forward and adjoint) take
+``R`` such tiles per grid step (:func:`series_rows`): ``R`` independent
+recurrence chains share one loop iteration.
 
 SERIES LENGTH IS UNBOUNDED: the grid is ``(series_block, time_chunk)`` with
 the chunk axis innermost (TPU iterates it sequentially), each chunk holding
@@ -82,15 +82,16 @@ _VMEM_PARAMS = pltpu.CompilerParams(vmem_limit_bytes=100 * 1024 * 1024)
 
 _ZERO = lambda: jnp.zeros((_SUBL, _LANES), jnp.float32)  # noqa: E731
 
-# -- the series-block width of the forward fit-objective kernels ------------
+# -- the series-block width of the fit-objective kernels ---------------------
 #
 # A recurrence kernel's time step is a chain of dependent vector ops on ONE
 # register of 1,024 series: the VPU waits out each op's latency with nothing
-# else to issue.  The forward kernels of the three fit objectives therefore
-# take R registers of series per step — blocks of ``(cs, 8 * R, 128)`` — so
-# R independent chains share one loop iteration and one basic block.  No
-# series' arithmetic or accumulation order changes, series never mix, and
-# the HBM arrays keep their layout: only the grid and the block shape differ.
+# else to issue.  The kernels of the three fit objectives, forward and
+# adjoint, therefore take R registers of series per step — blocks of ``(cs,
+# 8 * R, 128)`` — so R independent chains share one loop iteration and one
+# basic block.  No series' arithmetic or accumulation order changes, series
+# never mix, and the HBM arrays keep their layout: only the grid and the
+# block shape differ.
 _R_CHOICES = (4, 2)  # tried widest first; 1 is today's block
 _TILE_BYTES = _SBLK * 4  # one (8, 128) f32 tile
 # what one call's pipelined blocks and scratch may take of _VMEM_PARAMS'
@@ -109,12 +110,13 @@ def _vmem_bytes(layout, r: int = 1) -> int:
 
 
 def series_rows(nsub: int, layout, r_best: int) -> int:
-    """R, the vector registers of series a forward objective kernel takes
-    per time step — from static facts only: ``nsub`` (= ``Bp / 128``, the
-    folded panel's sublane rows) divisible by ``8 * R``; the call's VMEM
-    (:func:`_vmem_bytes` of its ``layout``) inside ``_VMEM_BLOCK_BUDGET``;
-    and at most ``r_best``, the width the chip showed best for this kernel
-    and mode (``_CSS_R`` / ``_GARCH_R`` / ``_HW_R``).  A 256-row serving
+    """R, the vector registers of series an objective kernel (forward or
+    adjoint) takes per time step — from static facts only: ``nsub`` (=
+    ``Bp / 128``, the folded panel's sublane rows) divisible by ``8 * R``;
+    the call's VMEM (:func:`_vmem_bytes` of its ``layout``) inside
+    ``_VMEM_BLOCK_BUDGET``; and at most ``r_best``, the width the chip
+    showed best for this kernel and mode (``_CSS_R`` / ``_GARCH_R`` /
+    ``_HW_R``, the adjoints' ``_ADJOINT_R``).  A 256-row serving
     batch, a padded retry bucket, a compaction cap that is 1,024- but not
     2,048-aligned: R = 1, today's program."""
     for r in _R_CHOICES:
@@ -122,6 +124,20 @@ def series_rows(nsub: int, layout, r_best: int) -> int:
                 and _vmem_bytes(layout, r) <= _VMEM_BLOCK_BUDGET):
             return r
     return 1
+
+
+# the width the chip showed best for each objective's ADJOINT kernel, the
+# cotangent formed from the plane (PERF.md §6, PR 37: ns a time step and
+# 1,024-series block over [131072, 1000] at R = 1 / 2 / 4, the inner loop's
+# bundles a step in brackets — CSS (1, 1) 20.29 / 11.61 / 11.22 (23 / 25 /
+# 31); the seasonal lag set {1, 24, 25} 38.34 / 20.69 / 12.70 (55 / 55 / 69); one
+# order of the grid over gathered cells 29.56 / 15.59 / 11.15 (41 / 44 / 59);
+# GARCH 16.30 / 12.46 / 11.43 (21 / 31 / 65: four chains fill every vector
+# slot and spill, and over the 16,384-row compaction read 17.66 / 13.54 /
+# 14.43); Holt-Winters 28.47 / 28.99 (34 / 40), five panels at the HBM's
+# pace on one register, R = 4 past VMEM.  The adjoints write no panel: 11.2
+# ns is 8 KB at 730 GB/s)
+_ADJOINT_R = {"css": 4, "garch": 4, "hw": 1}
 
 
 def _nsub(rows: int) -> int:
@@ -142,7 +158,8 @@ def _fori(n, body, init, unroll: int = 1):
     INDEPENDENT chain in the same iteration — :func:`series_rows`; on a v5e
     a step of the CSS / Holt-Winters / GARCH value-only kernels costs 17.6 /
     21.0 / 29.6 ns on one register of series and 22.9 / 28.2 / 35.4 ns on
-    four, PERF.md §6, PR 31.  The fill sweeps' dependency chains are one
+    four, PERF.md §6, PR 31; the CSS adjoint's 20.3 ns on one and 44.9 on
+    four, PR 37.  The fill sweeps' dependency chains are one
     select deep, and there loop machinery dominates: pass ``unroll`` > 1
     for those.)"""
 
@@ -283,6 +300,15 @@ def _rev(nchunk):  # walk time chunks last-to-first
 
 def _rev_prev(nchunk):  # previous TIME chunk while walking backward
     return lambda blk, c: (jnp.maximum(nchunk - 2 - c, 0), blk, 0)
+
+
+def _rev_panel(t):
+    """A panel operand of an adjoint call as layout entries
+    (:func:`_vmem_bytes`): its block of the time chunk, walked last to
+    first, and past one chunk the neighbour block the lag reads reach."""
+    _, cs, nchunk = _time_layout(t)
+    return ([(cs, _rev(nchunk))]
+            + ([(cs, _rev_prev(nchunk))] if nchunk > 1 else []))
 
 
 # ---------------------------------------------------------------------------
@@ -558,18 +584,22 @@ def _css_fwd_layout(p, q, mode, t):
 
 
 def css_series_block(rows: int, t: int, order: Order, mode: str = "sum") -> int:
-    """Series per grid step of the forward CSS kernel over ``rows`` series
-    of (differenced) length ``t``: ``1024 * R`` (:func:`series_rows`).
-    ``order``'s ``p`` / ``q`` may be lag sets (:func:`_lags`)."""
+    """Series per grid step of the CSS kernel over ``rows`` series of
+    (differenced) length ``t``: ``1024 * R`` (:func:`series_rows`) — of a
+    forward ``mode``, or of the fit objective's ``"adjoint"``.  ``order``'s
+    ``p`` / ``q`` may be lag sets (:func:`_lags`)."""
     p, _, q = order
-    return _SBLK * series_rows(
-        _nsub(rows), _css_fwd_layout(p, q, mode, t), _CSS_R[mode])
+    layout, best = ((_css_bwd_layout(p, q, t), _ADJOINT_R["css"])
+                    if mode == "adjoint"
+                    else (_css_fwd_layout(p, q, mode, t), _CSS_R[mode]))
+    return _SBLK * series_rows(_nsub(rows), layout, best)
 
 
-def _fwd_call(kernel, layout, r, interpret, args):
-    """One forward ``pallas_call`` over ``(cs, 8 * r, 128)`` blocks of the
-    folded operands ``args``, the panel ``[tp, nsub, 128]`` first; a
-    ``_cur`` output is a panel too, any other a few planes."""
+def _block_call(kernel, layout, r, interpret, args):
+    """One ``pallas_call`` of a forward or an adjoint kernel over ``(cs,
+    8 * r, 128)`` blocks of the folded operands ``args``, the panel ``[tp,
+    nsub, 128]`` first; an output that moves with the time chunk (any index
+    map but ``_fixed``) is a panel too, any other a few planes."""
     ins, outs, scratch = layout
     tp, nsub, _ = args[0].shape
     return pl.pallas_call(
@@ -578,7 +608,7 @@ def _fwd_call(kernel, layout, r, interpret, args):
         in_specs=[_bs(n, im, r) for n, im in ins],
         out_specs=[_bs(n, im, r) for n, im in outs],
         out_shape=[jax.ShapeDtypeStruct(
-            (tp if im is _cur else n, nsub, _LANES), args[0].dtype)
+            (n if im is _fixed else tp, nsub, _LANES), args[0].dtype)
             for n, im in outs],
         scratch_shapes=[pltpu.VMEM((n, _SUBL * r, _LANES), jnp.float32)
                         for n in scratch],
@@ -598,7 +628,7 @@ def _css_fwd_call_f(p, q, interpret, mode, params, y3, zb3, t, _r=None):
     hp = nchunk > 1
     layout = _css_fwd_layout(p, q, mode, t)
     r = _r or series_rows(y3.shape[1], layout, _CSS_R[mode])
-    outs = _fwd_call(
+    outs = _block_call(
         functools.partial(_css_fwd_kernel, _lags(p), _lags(q), t, cs, hp,
                           mode),
         layout, r, interpret, (*((y3, y3) if hp else (y3,)), par3, zb3))
@@ -673,7 +703,7 @@ def _css_ss_f_fwd(p, q, interpret, t, b, params, y3, zb3):
     return _unfold(css3, b)[:, 0], (y3_, par3, zb3_, e3, marker)
 
 
-def _css_ss_f_bwd(p, q, interpret, t, b, resid, gbar):
+def _css_ss_f_bwd(p, q, interpret, t, b, resid, gbar, _r=None):
     y3, par3, zb3, e3, marker = resid
     k = par3.shape[0]
     if isinstance(gbar, SymbolicZero):  # output provably unused
@@ -690,10 +720,10 @@ def _css_ss_f_bwd(p, q, interpret, t, b, resid, gbar):
         # data cotangent (an output the params-only fit path never pays for)
         gparams, gy3 = _css_errors_bwd_f(p, q, interpret, (y3, par3, zb3, e3),
                                          gb3, b, t, want_gy=True,
-                                         g_plane=True)
+                                         g_plane=True, _r=_r)
     else:
         gparams = _css_errors_bwd_f(p, q, interpret, (y3, par3, zb3, e3),
-                                    gb3, b, t, g_plane=True)
+                                    gb3, b, t, g_plane=True, _r=_r)
         gy3 = jnp.zeros(y3.shape, y3.dtype)
     return gparams, gy3, jnp.zeros(zb3.shape, zb3.dtype)
 
@@ -791,8 +821,25 @@ def _css_errors_bwd(p, q, interpret, res, g):
 CSS_ADJOINT_PANELS = 2
 
 
+def _css_bwd_layout(p, q, t, want_gy=False, g_plane=True):
+    """The CSS adjoint call's blocks, as :func:`_css_fwd_layout` states the
+    forward's: the panel and the error panel (each with its neighbour past
+    one chunk), parameters, mask, the cotangent — a plane, or ``css_errors``'
+    panel — and with ``want_gy`` the data cotangent's panel out."""
+    ar, ma = _lags(p), _lags(q)
+    k = 1 + len(ar) + len(ma)
+    panel = _rev_panel(t)
+    ins = panel + panel + [(k, _fixed), (1, _fixed),
+                           (1, _fixed) if g_plane else panel[0]]
+    outs = [(k, _fixed)] + ([panel[0]] if want_gy else [])
+    # the adjoint path; its carry across chunks (and the data cotangent's)
+    scratch = ([panel[0][0], max(_span(ma), 1)]
+               + ([max(_span(ar), 1)] if want_gy else []))
+    return ins, outs, scratch
+
+
 def _css_errors_bwd_f(p, q, interpret, res, g3, b, t, want_gy=False,
-                      g_plane=False):
+                      g_plane=False, _r=None):
     """Adjoint core on FOLDED cotangents -> ``gparams [B, k]`` or, with
     ``want_gy``, ``(gparams, gy3)`` where ``gy3`` is the data cotangent in
     the folded layout (an extra kernel output only callers that perturb the
@@ -800,46 +847,17 @@ def _css_errors_bwd_f(p, q, interpret, res, g3, b, t, want_gy=False,
     errors as a panel (``css_errors``' rule: any cotangent) or, with
     ``g_plane``, the plane gbar of the sum of squares' (``_css_ss_f``'s
     rule: the kernel forms ``2 e gbar`` itself); which rule is calling is
-    all that chooses."""
+    all that chooses.  ``_r`` forces the block width (tests and the sweep)."""
     y3, par3, zb3, e3 = res
-    ar, ma = _lags(p), _lags(q)
-    k = par3.shape[0]
     _, cs, nchunk = _time_layout(t)
-    nblk = y3.shape[1] // _SUBL
     hp = nchunk > 1
-    if hp:
-        ins = [_bs(cs, _rev(nchunk)), _bs(cs, _rev_prev(nchunk)),
-               _bs(cs, _rev(nchunk)), _bs(cs, _rev_prev(nchunk)),
-               _bs(k, _fixed), _bs(1, _fixed)]
-        args = (y3, y3, e3, e3, par3, zb3, g3)
-    else:
-        ins = [_bs(cs, _rev(nchunk)), _bs(cs, _rev(nchunk)),
-               _bs(k, _fixed), _bs(1, _fixed)]
-        args = (y3, e3, par3, zb3, g3)
-    ins.append(_bs(1, _fixed) if g_plane else _bs(cs, _rev(nchunk)))
-    out_specs = [_bs(k, _fixed)]
-    out_shape = [jax.ShapeDtypeStruct(par3.shape, g3.dtype)]
-    if want_gy:
-        out_specs.append(_bs(cs, _rev(nchunk)))
-        out_shape.append(jax.ShapeDtypeStruct(y3.shape, g3.dtype))
-    scratch = [
-        pltpu.VMEM((cs, _SUBL, _LANES), jnp.float32),
-        pltpu.VMEM((max(_span(ma), 1), _SUBL, _LANES), jnp.float32),
-    ]
-    if want_gy:
-        scratch.append(
-            pltpu.VMEM((max(_span(ar), 1), _SUBL, _LANES), jnp.float32))
-    outs = pl.pallas_call(
-        functools.partial(_css_bwd_kernel, ar, ma, t, cs, nchunk, hp,
-                          want_gy, g_plane),
-        grid=(nblk, nchunk),
-        in_specs=ins,
-        out_specs=out_specs,
-        out_shape=out_shape,
-        scratch_shapes=scratch,
-        compiler_params=_VMEM_PARAMS,
-        interpret=interpret,
-    )(*args)
+    layout = _css_bwd_layout(p, q, t, want_gy, g_plane)
+    r = _r or series_rows(y3.shape[1], layout, _ADJOINT_R["css"])
+    outs = _block_call(
+        functools.partial(_css_bwd_kernel, _lags(p), _lags(q), t, cs, nchunk,
+                          hp, want_gy, g_plane),
+        layout, r, interpret,
+        (*((y3, y3, e3, e3) if hp else (y3, e3)), par3, zb3, g3))
     gparams = _unfold(outs[0], b)
     if want_gy:
         return gparams, outs[1]
@@ -940,21 +958,10 @@ def take_cells(folded: CssGridFolded, idxc) -> CssGridFolded:
 # (PERF.md §6, PR 36: ms a call of 9 orders over [131072, 1000] at (G, R) —
 # sum (1, 4) 7.91, (3, 4) 4.74, (9, 2) 4.94, (9, 1) 5.25; both (1, 4) 8.76,
 # (3, 2) 7.71, (9, 1) 7.58, the error panels' write at the HBM's pace;
-# adjoint, one register of series a step like the plain one, (1, 1) 34.09,
-# (3, 1) 14.31, VMEM refuses 9)
+# adjoint (1, 1) 34.09, (3, 1) 14.31, VMEM refuses 9; PR 37, R beside G as
+# the budget allows: (1, 2) 17.84, (1, 4) 12.32, (3, 1) 14.23, (3, 2) 10.52 —
+# 73 bundles a step for six chains at 86.5 of the budget's 88 MiB)
 _CSS_GRID_G = {"sum": 3, "both": 9, "adjoint": 3}
-
-
-def _css_grid_bwd_layout(ar, ma, t):
-    """The grid adjoint's blocks, as :func:`_css_fwd_layout` states the
-    forward's: the panel (and its neighbour), then the cell blocks."""
-    _, cs, nchunk = _time_layout(t)
-    hp = nchunk > 1
-    k = 1 + len(ar) + len(ma)
-    panel = [(cs, _rev(nchunk))] + ([(cs, _rev_prev(nchunk))] if hp else [])
-    # e (and its neighbour), parameters, mask, the cotangent's plane
-    ins = panel + panel + [(k, _fixed), (1, _fixed), (1, _fixed)]
-    return ins, [(k, _fixed)], [cs, max(_span(ma), 1)]
 
 
 def css_grid_block(k: int, nsub: int, layout, mode: str):
@@ -962,8 +969,8 @@ def css_grid_block(k: int, nsub: int, layout, mode: str):
     CSS grid kernel takes — static facts only, as :func:`series_rows`.  G is
     the largest divisor of ``k`` within ``_CSS_GRID_G[mode]`` whose blocks fit
     VMEM at one register of series (the panel's ``len(panel)`` blocks once,
-    every other block and the scratch G times); R then the widest the forward
-    rule allows beside it (1 for the adjoint)."""
+    every other block and the scratch G times); R then the widest
+    :func:`series_rows`' three facts allow beside it."""
     ins, outs, scratch = layout
     npanel = sum(1 for _, im in ins if im is not _fixed)
     if mode == "adjoint":
@@ -977,19 +984,20 @@ def css_grid_block(k: int, nsub: int, layout, mode: str):
     g = max((d for d in range(1, k + 1)
              if k % d == 0 and d <= _CSS_GRID_G[mode] and fits(d, 1)),
             default=1)
-    if mode == "adjoint":
-        return g, 1
-    r = next((r for r in _R_CHOICES if r <= _CSS_R[mode]
+    r_best = _ADJOINT_R["css"] if mode == "adjoint" else _CSS_R[mode]
+    r = next((r for r in _R_CHOICES if r <= r_best
               and nsub % (_SUBL * r) == 0 and fits(g, r)), 1)
     return g, r
 
 
 def css_grid_series_block(k: int, rows: int, t: int, p, q,
                           mode: str = "sum") -> int:
-    """Series per grid step of the forward CSS grid kernel over ``k`` orders
-    of ``rows`` series: ``1024 * R`` (:func:`css_grid_block`)."""
-    return _SBLK * css_grid_block(
-        k, _nsub(rows), _css_fwd_layout(p, q, mode, t), mode)[1]
+    """Series per grid step of the CSS grid kernel over ``k`` orders of
+    ``rows`` series: ``1024 * R`` (:func:`css_grid_block`) — of a forward
+    ``mode``, or of the ``"adjoint"``."""
+    layout = (_css_bwd_layout(p, q, t) if mode == "adjoint"
+              else _css_fwd_layout(p, q, mode, t))
+    return _SBLK * css_grid_block(k, _nsub(rows), layout, mode)[1]
 
 
 def _grid_specs(entries, npanel, kg, g, r):
@@ -1042,24 +1050,26 @@ def _css_grid_fwd_call(ar, ma, interpret, mode, params, f: CssGridFolded,
 
 
 def _css_grid_bwd_call(ar, ma, interpret, f: CssGridFolded, par4, e4, gb4,
-                       _g=None):
+                       _g=None, _r=None):
     """The CSS adjoint over every cell -> ``gparams [k * b, planes]``: the
-    cotangent ``2 e gbar`` formed in the kernel from the plane ``gb4``."""
+    cotangent ``2 e gbar`` formed in the kernel from the plane ``gb4``.
+    ``_g`` / ``_r`` force the block (tests and the sweep)."""
     _, cs, nchunk = _time_layout(f.t)
     hp = nchunk > 1
-    layout = _css_grid_bwd_layout(ar, ma, f.t)
+    layout = _css_bwd_layout(ar, ma, f.t)
     nsub = f.y3.shape[1]
-    g = _g or css_grid_block(f.k, nsub, layout, "adjoint")[0]
+    g, r = css_grid_block(f.k, nsub, layout, "adjoint")
+    g, r = _g or g, _r or r
     ins, outs, scratch = layout
     kg = f.k // g
     gpar4 = pl.pallas_call(
         functools.partial(_css_bwd_kernel, ar, ma, f.t, cs, nchunk, hp,
                           False, True),
-        grid=(nsub // _SUBL * kg, nchunk),
-        in_specs=_grid_specs(ins, 2 if hp else 1, kg, g, 1),
-        out_specs=_grid_specs(outs, 0, kg, g, 1)[0],
+        grid=(nsub // (_SUBL * r) * kg, nchunk),
+        in_specs=_grid_specs(ins, 2 if hp else 1, kg, g, r),
+        out_specs=_grid_specs(outs, 0, kg, g, r)[0],
         out_shape=jax.ShapeDtypeStruct(par4.shape, e4.dtype),
-        scratch_shapes=[pltpu.VMEM((n, g, _SUBL, _LANES), jnp.float32)
+        scratch_shapes=[pltpu.VMEM((n, g, _SUBL * r, _LANES), jnp.float32)
                         for n in scratch],
         compiler_params=_VMEM_PARAMS,
         interpret=interpret,
@@ -1216,14 +1226,15 @@ def _garch_bwd_kernel(t_limit, cs, nchunk, hpv, want_gdata, g_plane, *refs):
     h0 = h0_ref[0]
     alpha = par_ref[1]
     beta = par_ref[2]
+    zero = _plane_zero(zb_ref)
 
     @pl.when(c == 0)
     def _():
-        cl_ref[0] = _ZERO()
+        cl_ref[0] = zero
         for r in range(3):
-            gpar_ref[r] = _ZERO()
+            gpar_ref[r] = zero
         if want_gdata:
-            gh0_ref[0] = _ZERO()
+            gh0_ref[0] = zero
 
     # d ll_t / d h_t = 1/h - r^2/h^2 in two stages a step apart (see body)
     def operands(tl):
@@ -1286,7 +1297,7 @@ def _garch_bwd_kernel(t_limit, cs, nchunk, hpv, want_gdata, g_plane, *refs):
         return (lam, dw, da, db, dh0) + ahead
 
     out = lax.fori_loop(
-        0, cs, body, (cl_ref[0],) + (_ZERO(),) * (4 if want_gdata else 3)
+        0, cs, body, (cl_ref[0],) + (zero,) * (4 if want_gdata else 3)
         + (quotients(*operands(cs - 1)) + operands(max(cs - 2, 0))
            if g_plane else ()))
     cl_ref[0] = out[0]
@@ -1335,10 +1346,12 @@ def _garch_fwd_layout(mode, t):
 
 
 def garch_series_block(rows: int, t: int, mode: str = "sum") -> int:
-    """Series per grid step of the forward GARCH kernel (see
+    """Series per grid step of the GARCH kernel (see
     :func:`css_series_block`)."""
-    return _SBLK * series_rows(
-        _nsub(rows), _garch_fwd_layout(mode, t), _GARCH_R[mode])
+    layout, best = ((_garch_bwd_layout(t), _ADJOINT_R["garch"])
+                    if mode == "adjoint"
+                    else (_garch_fwd_layout(mode, t), _GARCH_R[mode]))
+    return _SBLK * series_rows(_nsub(rows), layout, best)
 
 
 def _garch_fwd_call_f(interpret, mode, params, f: GarchFolded, _r=None):
@@ -1350,7 +1363,7 @@ def _garch_fwd_call_f(interpret, mode, params, f: GarchFolded, _r=None):
     hp = nchunk > 1
     layout = _garch_fwd_layout(mode, f.t)
     r = _r or series_rows(r23.shape[1], layout, _GARCH_R[mode])
-    outs = _fwd_call(
+    outs = _block_call(
         functools.partial(_garch_fwd_kernel, f.t, cs, hp, mode),
         layout, r, interpret,
         (*((r23, r23) if hp else (r23,)), par3, f.h03, f.zb3))
@@ -1362,45 +1375,39 @@ def _garch_fwd_call_f(interpret, mode, params, f: GarchFolded, _r=None):
 GARCH_ADJOINT_PANELS = 2
 
 
+def _garch_bwd_layout(t, want_gdata=False, g_plane=True):
+    """The GARCH adjoint call's blocks (see :func:`_css_bwd_layout`): the
+    squared returns and the variance path (each with its neighbour past one
+    chunk), parameters, seed, mask, the cotangent — a plane, or
+    ``garch_variances``' panel — and with ``want_gdata`` the cotangents of
+    ``r^2`` (a panel) and ``h0`` out."""
+    panel = _rev_panel(t)
+    ins = (panel + [(3, _fixed), (1, _fixed), (1, _fixed)] + panel
+           + [(1, _fixed) if g_plane else panel[0]])
+    outs = [(3, _fixed)] + ([panel[0], (1, _fixed)] if want_gdata else [])
+    return ins, outs, [1]  # scratch: lambda's carry across chunks
+
+
 def _garch_bwd_call_f(interpret, f: GarchFolded, par3, h3, g3, want_gdata,
-                      g_plane=False):
+                      g_plane=False, _r=None):
     """The adjoint on FOLDED operands: ``g3`` is the cotangent of the
     variance path ``h3`` as a panel (``_garch_h``'s rule: any cotangent)
     or, with ``g_plane``, the plane gbar of the likelihood sum's
     (``_garch_ll_f``'s rule: the kernel forms ``gbar d ll / d h`` itself;
     which rule is calling is all that chooses) -> ``(gpar3, gr23, gh03)``,
     the two data cotangents ``None`` unless ``want_gdata`` (two more kernel
-    outputs, one of them panel-sized)."""
+    outputs, one of them panel-sized).  ``_r`` forces the block width
+    (tests and the sweep)."""
     _, cs, nchunk = _time_layout(f.t)
-    nblk = f.r23.shape[1] // _SUBL
     hp = nchunk > 1
-    if hp:
-        ins = [_bs(cs, _rev(nchunk)), _bs(cs, _rev_prev(nchunk)),
-               _bs(3, _fixed), _bs(1, _fixed), _bs(1, _fixed),
-               _bs(cs, _rev(nchunk)), _bs(cs, _rev_prev(nchunk))]
-        args = (f.r23, f.r23, par3, f.h03, f.zb3, h3, h3, g3)
-    else:
-        ins = [_bs(cs, _rev(nchunk)), _bs(3, _fixed), _bs(1, _fixed),
-               _bs(1, _fixed), _bs(cs, _rev(nchunk))]
-        args = (f.r23, par3, f.h03, f.zb3, h3, g3)
-    ins.append(_bs(1, _fixed) if g_plane else _bs(cs, _rev(nchunk)))
-    out_specs = [_bs(3, _fixed)]
-    out_shape = [jax.ShapeDtypeStruct(par3.shape, g3.dtype)]
-    if want_gdata:
-        out_specs += [_bs(cs, _rev(nchunk)), _bs(1, _fixed)]
-        out_shape += [jax.ShapeDtypeStruct(f.r23.shape, g3.dtype),
-                      jax.ShapeDtypeStruct(f.h03.shape, g3.dtype)]
-    outs = pl.pallas_call(
+    layout = _garch_bwd_layout(f.t, want_gdata, g_plane)
+    r = _r or series_rows(f.r23.shape[1], layout, _ADJOINT_R["garch"])
+    outs = _block_call(
         functools.partial(_garch_bwd_kernel, f.t, cs, nchunk, hp, want_gdata,
                           g_plane),
-        grid=(nblk, nchunk),
-        in_specs=ins,
-        out_specs=out_specs,
-        out_shape=out_shape,
-        scratch_shapes=[pltpu.VMEM((1, _SUBL, _LANES), jnp.float32)],
-        compiler_params=_VMEM_PARAMS,
-        interpret=interpret,
-    )(*args)
+        layout, r, interpret,
+        (*((f.r23, f.r23) if hp else (f.r23,)), par3, f.h03, f.zb3,
+         *((h3, h3) if hp else (h3,)), g3))
     return tuple(outs) if want_gdata else (outs[0], None, None)
 
 
@@ -1476,7 +1483,7 @@ def _garch_ll_f_fwd(interpret, params, f):
     return _unfold(ll3, params.shape[0])[:, 0], (f, par3, h3, marker)
 
 
-def _garch_ll_f_bwd(interpret, resid, gbar):
+def _garch_ll_f_bwd(interpret, resid, gbar, _r=None):
     f, par3, h3, marker = resid
     b = gbar.shape[0]
     zeros = jax.tree_util.tree_map(jnp.zeros_like, f)
@@ -1489,7 +1496,7 @@ def _garch_ll_f_bwd(interpret, resid, gbar):
     # gbar, and padded time is dead
     gb3 = _fold(gbar[:, None].astype(h3.dtype))
     gpar3, gr23, gh03 = _garch_bwd_call_f(
-        interpret, f, par3, h3, gb3, marker is not None, g_plane=True)
+        interpret, f, par3, h3, gb3, marker is not None, g_plane=True, _r=_r)
     if marker is None:  # params-only: the fit hot path
         return _unfold(gpar3, b), zeros
     # r^2 feeds the likelihood through the recursion AND directly
@@ -1961,15 +1968,16 @@ def _hw_bwd_kernel(m, mult, t_limit, cs, nchunk, hp, *refs):
     g = par_ref[2]
     zb = zb_ref[0]
     gb = gb_ref[0]  # the SSE's cotangent: the error's is 2 e gb, formed here
+    zero = _plane_zero(zb_ref)
 
     @pl.when(c == 0)
     def _():
         for j in range(m):
-            rho_ref[j] = _ZERO()
-        clam_ref[0] = _ZERO()
-        clam_ref[1] = _ZERO()
+            rho_ref[j] = zero
+        clam_ref[0] = zero
+        clam_ref[1] = zero
         for r in range(3):
-            gpar_ref[r] = _ZERO()
+            gpar_ref[r] = zero
 
     def body(i, carry):
         lamL, lamT, da, db, dg = carry
@@ -2025,7 +2033,7 @@ def _hw_bwd_kernel(m, mult, t_limit, cs, nchunk, hp, *refs):
         return lamL_o, lamT_o, da, db, dg
 
     lamL, lamT, da, db, dg = lax.fori_loop(
-        0, cs, body, (clam_ref[0], clam_ref[1], _ZERO(), _ZERO(), _ZERO())
+        0, cs, body, (clam_ref[0], clam_ref[1], zero, zero, zero)
     )
     clam_ref[0] = lamL
     clam_ref[1] = lamT
@@ -2077,12 +2085,17 @@ def _hw_fwd_layout(m, save_resid, t):
 
 
 def hw_series_block(rows: int, t: int, period: int,
-                    save_resid: bool = False) -> int:
-    """Series per grid step of the forward Holt-Winters kernel (see
-    :func:`css_series_block`)."""
-    return _SBLK * series_rows(
-        _nsub(rows), _hw_fwd_layout(period, save_resid, t),
-        _HW_R[save_resid])
+                    mode: str = "sum") -> int:
+    """Series per grid step of the Holt-Winters kernel (see
+    :func:`css_series_block`); ``mode``: ``"sum"``, ``"save_resid"`` or
+    ``"adjoint"``."""
+    if mode == "adjoint":
+        layout, best = _hw_bwd_layout(period, t), _ADJOINT_R["hw"]
+    else:
+        save_resid = {"sum": False, "save_resid": True}[mode]
+        layout, best = (_hw_fwd_layout(period, save_resid, t),
+                        _HW_R[save_resid])
+    return _SBLK * series_rows(_nsub(rows), layout, best)
 
 
 def _hw_fwd_call_f(interpret, m, mult, save_resid, params, f: HWFolded,
@@ -2093,7 +2106,7 @@ def _hw_fwd_call_f(interpret, m, mult, save_resid, params, f: HWFolded,
     par3 = _fold(params)
     layout = _hw_fwd_layout(m, save_resid, f.t)
     r = _r or series_rows(f.y3.shape[1], layout, _HW_R[save_resid])
-    outs = _fwd_call(
+    outs = _block_call(
         functools.partial(_hw_fwd_kernel, m, mult, save_resid, f.t, cs),
         layout, r, interpret, (f.y3, par3, f.l03, f.t03, f.s03, f.zb3))
     return outs, par3
@@ -2128,43 +2141,36 @@ def _hw_ss_f_fwd(interpret, m, mult, params, f):
 HW_ADJOINT_PANELS = 5
 
 
-def _hw_ss_f_bwd(interpret, m, mult, resid, gbar):
+def _hw_bwd_layout(m, t):
+    """The Holt-Winters adjoint call's blocks (see :func:`_css_bwd_layout`):
+    the panel, parameters, the two seeds, mask and the cotangent's plane,
+    then the replay trajectories (level and trend with their neighbours past
+    one chunk, the season) and the errors."""
+    panel = _rev_panel(t)
+    ins = (panel[:1] + [(3, _fixed)] + [(1, _fixed)] * 4 + panel + panel
+           + panel[:1] * 2)
+    # scratch: the seasonal ring's adjoint, level / trend across chunks
+    return ins, [(3, _fixed)], [m, 2]
+
+
+def _hw_ss_f_bwd(interpret, m, mult, resid, gbar, _r=None):
     f, par3, e3, lv3, tr3, so3 = resid
-    y3, l03, t03, zb3, t, b = f.y3, f.l03, f.t03, f.zb3, f.t, gbar.shape[0]
+    b = gbar.shape[0]
     # gbar [B] folds to a plane, and the plane is what the adjoint kernel is
     # handed beside the errors (see _css_ss_f_bwd): it forms the error
     # cotangent 2 e gbar itself, no panel-sized XLA pass per gradient;
     # padded series carry a zero gbar, padded time a zero error
     gb3 = _fold(gbar[:, None].astype(e3.dtype))
-    _, cs, nchunk = _time_layout(t)
-    nblk = y3.shape[1] // _SUBL
+    _, cs, nchunk = _time_layout(f.t)
     hp = nchunk > 1
-    if hp:
-        ins = [_bs(cs, _rev(nchunk)), _bs(3, _fixed), _bs(1, _fixed),
-               _bs(1, _fixed), _bs(1, _fixed), _bs(1, _fixed),
-               _bs(cs, _rev(nchunk)), _bs(cs, _rev_prev(nchunk)),
-               _bs(cs, _rev(nchunk)), _bs(cs, _rev_prev(nchunk)),
-               _bs(cs, _rev(nchunk)), _bs(cs, _rev(nchunk))]
-        args = (y3, par3, l03, t03, zb3, gb3, lv3, lv3, tr3, tr3, so3, e3)
-    else:
-        ins = [_bs(cs, _rev(nchunk)), _bs(3, _fixed), _bs(1, _fixed),
-               _bs(1, _fixed), _bs(1, _fixed), _bs(1, _fixed),
-               _bs(cs, _rev(nchunk)), _bs(cs, _rev(nchunk)),
-               _bs(cs, _rev(nchunk)), _bs(cs, _rev(nchunk))]
-        args = (y3, par3, l03, t03, zb3, gb3, lv3, tr3, so3, e3)
-    gpar3 = pl.pallas_call(
-        functools.partial(_hw_bwd_kernel, m, mult, t, cs, nchunk, hp),
-        grid=(nblk, nchunk),
-        in_specs=ins,
-        out_specs=_bs(3, _fixed),
-        out_shape=jax.ShapeDtypeStruct(par3.shape, e3.dtype),
-        scratch_shapes=[
-            pltpu.VMEM((m, _SUBL, _LANES), jnp.float32),
-            pltpu.VMEM((2, _SUBL, _LANES), jnp.float32),
-        ],
-        compiler_params=_VMEM_PARAMS,
-        interpret=interpret,
-    )(*args)
+    layout = _hw_bwd_layout(m, f.t)
+    # ``_r`` forces the block width (tests and the sweep)
+    r = _r or series_rows(f.y3.shape[1], layout, _ADJOINT_R["hw"])
+    (gpar3,) = _block_call(
+        functools.partial(_hw_bwd_kernel, m, mult, f.t, cs, nchunk, hp),
+        layout, r, interpret,
+        (f.y3, par3, f.l03, f.t03, f.zb3, gb3,
+         *((lv3, lv3, tr3, tr3) if hp else (lv3, tr3)), so3, e3))
     # seeds and data are constants of the objective: zero cotangents
     return _unfold(gpar3, b), jax.tree_util.tree_map(jnp.zeros_like, f)
 

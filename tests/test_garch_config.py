@@ -187,7 +187,7 @@ def test_walk_emits_the_stage_spans_under_its_chunk(lazy_walk):
 
     (s1,), (s2,) = by_name["fit.stage1"], by_name["fit.stage2"]
     assert set(s1["attrs"]) == {"rows", "iters", "undone", "series_block",
-                                "adjoint_panels"}
+                                "adjoint_series_block", "adjoint_panels"}
     assert s1["attrs"]["rows"] == LAZY_ROWS
     assert all(type(s1["attrs"][k]) is int for k in ("iters", "undone"))
     # stage 1 stopped because the cap was reached, with budget left
@@ -195,9 +195,12 @@ def test_walk_emits_the_stage_spans_under_its_chunk(lazy_walk):
     assert 0 < s1["attrs"]["iters"] < 80
     # the value-only GARCH kernel's block over each stage's rows (ISSUE 31)
     assert s1["attrs"]["series_block"] == 2048
+    # and the adjoint's, by the same rule (ISSUE 37)
+    assert s1["attrs"]["adjoint_series_block"] == 2048
     # the adjoint call's panel operands: r23 and h3 (ISSUE 35)
     assert s2["attrs"] == {"rows": optim.compaction_cap(LAZY_ROWS),
-                           "series_block": 1024, "adjoint_panels": 2}
+                           "series_block": 1024,
+                           "adjoint_series_block": 1024, "adjoint_panels": 2}
     assert s1["attrs"]["adjoint_panels"] == 2
     for s in (s1, s2):
         assert list(ancestors(s))[:3] == ["fit.primary", "chunk", "walk"]
@@ -256,6 +259,8 @@ def test_lazy_spans_carry_the_gates_numbers(monkeypatch, tmp_path, fit,
         "rows": LAZY_ROWS, "iters": int(carry.k),
         "undone": int(carry.undone),
         "series_block": pk.garch_series_block(LAZY_ROWS, 96),
+        "adjoint_series_block": pk.garch_series_block(LAZY_ROWS, 96,
+                                                      "adjoint"),
         "adjoint_panels": pk.GARCH_ADJOINT_PANELS}
     assert spans["fit.stage1"]["parent"] == primary.id
     assert int(carry.undone) > 0
@@ -264,6 +269,7 @@ def test_lazy_spans_carry_the_gates_numbers(monkeypatch, tmp_path, fit,
     if stage2:
         assert spans["fit.stage2"]["attrs"] == {
             "rows": optim.compaction_cap(LAZY_ROWS), "series_block": 1024,
+            "adjoint_series_block": 1024,
             "adjoint_panels": pk.GARCH_ADJOINT_PANELS}
         assert spans["fit.stage2"]["parent"] == primary.id
 

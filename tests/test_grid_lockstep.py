@@ -143,12 +143,15 @@ def test_grid_block_rule():
     for mode, layout in (
             ("sum", pk._css_fwd_layout(2, 2, "sum", t)),
             ("both", pk._css_fwd_layout(2, 2, "both", t)),
-            ("adjoint", pk._css_grid_bwd_layout((1, 2), (1, 2), t))):
+            ("adjoint", pk._css_bwd_layout((1, 2), (1, 2), t))):
         g, r = pk.css_grid_block(9, nsub, layout, mode)
         assert 9 % g == 0 and g <= pk._CSS_GRID_G[mode]
-        assert r in (1, 2, 4) and (mode != "adjoint" or r == 1)
+        # (the adjoint: three orders of two registers, 86.5 of 88 MiB)
+        assert r in (1, 2, 4) and (mode != "adjoint" or (g, r) == (3, 2))
         # one order over a straggler subset: the plain kernels' widths
-        assert pk.css_grid_block(1, nsub, layout, mode)[0] == 1
+        assert pk.css_grid_block(1, nsub, layout, mode) == (1, {
+            "sum": pk._CSS_R["sum"], "both": pk._CSS_R["both"],
+            "adjoint": pk._ADJOINT_R["css"]}[mode])
     # a block that is not a multiple of 8 R sublane rows: R = 1
     assert pk.css_grid_block(9, 8, pk._css_fwd_layout(2, 2, "sum", t),
                              "sum")[1] == 1

@@ -718,12 +718,17 @@ class TestStageGateSpans:
         # (phi_1 and theta_1) and how far they reach
         # adjoint_panels: the panel-sized operands of the objective's
         # adjoint call, y3 and e3 (it forms the cotangent itself, ISSUE 35)
+        # adjoint_series_block: what that call takes per grid step, by the
+        # same rule (ISSUE 37)
         assert s1["attrs"] == {
             "rows": 2048, "iters": int(carry.k),
             "undone": int(carry.undone),
             "series_block": pk.css_series_block(2048, 39, (1, 1, 1)),
+            "adjoint_series_block": pk.css_series_block(
+                2048, 39, (1, 1, 1), "adjoint"),
             "lag_terms": 2, "lag_span": 1, "adjoint_panels": 2}
         assert s1["attrs"]["series_block"] in (1024, 2048)
+        assert s1["attrs"]["adjoint_series_block"] in (1024, 2048)
         assert all(type(s1["attrs"][k]) is int for k in ("iters", "undone"))
         assert s1["parent"] == primary.id
         assert s1["attrs"]["undone"] > 0
@@ -732,6 +737,7 @@ class TestStageGateSpans:
         if stage2:
             assert spans["fit.stage2"]["attrs"] == {
                 "rows": optim.compaction_cap(2048), "series_block": 1024,
+                "adjoint_series_block": 1024,
                 "lag_terms": 2, "lag_span": 1, "adjoint_panels": 2}
             assert spans["fit.stage2"]["parent"] == primary.id
 
@@ -752,13 +758,17 @@ class TestStageGateSpans:
         spans = {s["name"]: s for s in _span_lines(p)}
         s1, s2 = spans["fit.stage1"], spans["fit.stage2"]
         assert set(s1["attrs"]) == {"rows", "iters", "undone",
-                                    "series_block", "lag_terms", "lag_span",
+                                    "series_block", "adjoint_series_block",
+                                    "lag_terms", "lag_span",
                                     "adjoint_panels"}
         assert s1["attrs"]["rows"] == 2048 and s1["attrs"]["undone"] > 0
         assert s1["attrs"]["series_block"] == pk.css_series_block(
             2048, 55, ((), 0, (1, 4, 5)))
+        assert s1["attrs"]["adjoint_series_block"] == pk.css_series_block(
+            2048, 55, ((), 0, (1, 4, 5)), "adjoint")
         assert s2["attrs"] == {"rows": optim.compaction_cap(2048),
-                               "series_block": 1024, "lag_terms": 3,
+                               "series_block": 1024,
+                               "adjoint_series_block": 1024, "lag_terms": 3,
                                "lag_span": 5, "adjoint_panels": 2}
         assert (s1["attrs"]["lag_terms"], s1["attrs"]["lag_span"]) == (3, 5)
         assert s1["parent"] == s2["parent"] == primary.id
@@ -789,12 +799,16 @@ class TestStageGateSpans:
         assert {k: v for k, v in s1["attrs"].items()
                 if k not in ("iters", "undone")} == {
             "rows": cells, **static,
-            "series_block": pk.css_grid_series_block(9, 256, 39, 2, 2)}
+            "series_block": pk.css_grid_series_block(9, 256, 39, 2, 2),
+            "adjoint_series_block": pk.css_grid_series_block(
+                9, 256, 39, 2, 2, "adjoint")}
         assert 0 < s1["attrs"]["undone"] <= cap
         assert 0 < s1["attrs"]["iters"] < 14
         assert s2["attrs"] == {
             "rows": cap, **static,
-            "series_block": pk.css_grid_series_block(1, cap, 39, 2, 2)}
+            "series_block": pk.css_grid_series_block(1, cap, 39, 2, 2),
+            "adjoint_series_block": pk.css_grid_series_block(
+                1, cap, 39, 2, 2, "adjoint")}
         assert s1["parent"] == s2["parent"] == primary.id
 
     @pytest.mark.parametrize("family", ["arima", "sarima", "holtwinters",
@@ -817,6 +831,16 @@ class TestStageGateSpans:
         # every family names its objective kernel's block on both stages
         assert spans["fit.stage1"]["attrs"]["series_block"] in (1024, 2048)
         assert spans["fit.stage2"]["attrs"]["series_block"] == 1024
+        # and its adjoint's, by the kernel file's rule at each stage's rows
+        # (Holt-Winters' adjoint keeps one register of series at any size)
+        rule = _ADJOINT_BLOCKS[family]
+        cap = optim.compaction_cap(2048)
+        assert [spans[s]["attrs"]["adjoint_series_block"]
+                for s in ("fit.stage1", "fit.stage2")] == [rule(2048),
+                                                           rule(cap)]
+        assert rule(131072) == (1024 if family == "holtwinters" else
+                                1024 * pk._ADJOINT_R[
+                                    "garch" if family == "garch" else "css"])
         # and the panel-sized operands of its objective's adjoint call
         panels = 5 if family == "holtwinters" else 2
         assert all(spans[s]["attrs"]["adjoint_panels"] == panels
@@ -898,6 +922,15 @@ def _lazy_garch():
                                   max_iters=13, **kw)
 
 
+# each family's ``rows -> adjoint_series_block`` at its lazy fit's shapes
+_ADJOINT_BLOCKS = {
+    "arima": lambda rows: pk.css_series_block(rows, 39, (1, 1, 1),
+                                              "adjoint"),
+    "sarima": lambda rows: pk.css_series_block(rows, 55, ((), 0, (1, 4, 5)),
+                                               "adjoint"),
+    "holtwinters": lambda rows: pk.hw_series_block(rows, 48, 12, "adjoint"),
+    "garch": lambda rows: pk.garch_series_block(rows, 64, "adjoint"),
+}
 _LAZY_FITS = {"arima": _lazy_arima, "sarima": _lazy_sarima,
               "holtwinters": _lazy_holtwinters,
               "garch": _lazy_garch}

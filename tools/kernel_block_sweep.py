@@ -1,6 +1,6 @@
 #!/usr/bin/env python3
-"""Time the fit-objective kernels ALONE: the forward ones at forced
-series-block widths, the adjoints as the objectives call them.
+"""Time the fit-objective kernels ALONE at forced series-block widths: the
+forward ones, and the adjoints as the objectives call them.
 
     chiprun -- python tools/kernel_block_sweep.py            # on the chip
     python tools/kernel_block_sweep.py --compile-only        # here: Mosaic + VMEM
@@ -11,14 +11,15 @@ stage-2 compaction), R = 1, 2, 4 (``pallas_kernels.series_rows`` is bypassed
 through the call functions' private ``_r``): milliseconds a call, ns a time
 step and 1,024-series block, and whether every output is BIT-equal to R = 1's.
 The adjoints (mode ``adjoint``; the seasonal lag set ``{1, 24, 25}`` as
-``css_seasonal_neg_loglik`` beside the three) take one register of series a
-step and no ``_r``: one line each, ``r`` 1, the call the objective's
-``custom_vjp`` makes — the cotangent formed in the kernel from the plane.
+``css_seasonal_neg_loglik`` beside the three) are the call the objective's
+``custom_vjp`` makes — the cotangent formed in the kernel from the plane —
+at the same forced widths.
 The order search's grid kernels (``css_grid_neg_loglik``: 9 orders over one
 ``[131072, 1000]`` panel, union lags {1, 2} on both sides) run at G = 1, 3, 9
-orders a grid step (modes ``sum.g1`` .. ``adjoint.g9``; ``ns_step_block`` is
-per ORDER there) and, as stage 2 sees them, as one order over a gathered
-subset of the cells (``.cells``: a quarter of them, flat).
+orders a grid step, each at every R (modes ``sum.g1`` .. ``adjoint.g9``;
+``ns_step_block`` is per ORDER there) and, as stage 2 sees them, as one
+order over a gathered subset of the cells (``.cells``: a quarter of them,
+flat).
 One line a case to ``chiprun_out/kernel_block_sweep.jsonl`` and to stdout.
 ``--compile-only`` compiles every case for a described v5e and runs nothing
 (no time is reported from it).
@@ -51,8 +52,7 @@ def _planes(key, n, nsub, scale=1.0, loc=0.0):
 
 
 def cases():
-    """-> (name, mode, rows, t, make_args(key), call(r, *args)); an
-    ``adjoint`` case's ``call`` ignores ``r``."""
+    """-> (name, mode, rows, t, make_args(key), call(r, *args))."""
     for rows in ROWS:
         nsub = rows // pk._LANES
 
@@ -87,7 +87,7 @@ def cases():
                    functools.partial(css_adj_args, lags=lags, t=t),
                    lambda r, resid, gbar, lags=lags, t=t, rows=rows:
                    [pk._fold(pk._css_ss_f_bwd(*lags, False, t, rows, resid,
-                                              gbar)[0])])
+                                              gbar, _r=r)[0])])
 
         # the order search's grid (PR 36): 9 orders over ONE panel at G
         # orders a grid step (G = 1: the order as a grid axis, G = 9: nine
@@ -134,7 +134,7 @@ def cases():
                            lambda r, f, par4, e4, gb4, g=g: [pk._fold(
                                pk._css_grid_bwd_call(
                                    (1, 2), (1, 2), False, f, par4, e4, gb4,
-                                   _g=g))])
+                                   _g=g, _r=r))])
 
         def hw_args(key, nsub=nsub, rows=rows):
             k1, k2, k3 = jax.random.split(key, 3)
@@ -162,7 +162,7 @@ def cases():
 
         yield ("hw_sse", "adjoint", rows, 960, hw_adj_args,
                lambda r, resid, gbar: [pk._fold(pk._hw_ss_f_bwd(
-                   False, 24, False, resid, gbar)[0])])
+                   False, 24, False, resid, gbar, _r=r)[0])])
 
         def garch_args(key, nsub=nsub, rows=rows):
             k1, k2 = jax.random.split(key)
@@ -186,7 +186,7 @@ def cases():
 
         yield ("garch_neg_loglik", "adjoint", rows, 1000, garch_adj_args,
                lambda r, resid, gbar: [pk._fold(pk._garch_ll_f_bwd(
-                   False, resid, gbar)[0])])
+                   False, resid, gbar, _r=r)[0])])
 
 
 def _time(fn, args, calls, reps=3):
@@ -235,7 +235,7 @@ def main():
         orders = GRID_ORDERS if ".g" in mode else 1
         ref = None
         args = None if a.compile_only else make(jax.random.key(rows + t))
-        for r in ([1] if mode.startswith("adjoint") else a.r):
+        for r in a.r:
             rec = {"kernel": name, "mode": mode, "rows": rows, "t": t, "r": r,
                    "orders": orders,
                    "device": ("described v5e (compile only)" if a.compile_only
