@@ -1352,7 +1352,12 @@ def _grid_stage1_program(specs, include_intercept, backend, max_iters, tol,
 def _grid_stage2_program(specs, include_intercept, backend, max_iters, tol):
     family, pack = _grid_family(specs, include_intercept, backend)
     run = lockstep.stage2_program(family, max_iters, tol)
-    return lambda start, fin: pack(run(start, fin))
+
+    def run2(start, fin):
+        out, counts = run(start, fin)
+        return pack(out), counts
+
+    return run2
 
 
 # ---------------------------------------------------------------------------

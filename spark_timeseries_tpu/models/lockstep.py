@@ -186,18 +186,20 @@ def stage2_program(family: Family, max_iters: int, tol: float):
     stragglers on the compacted objective and scatter back — compiled on
     the first call where a stage 1 left unconverged rows.  A one-start
     family finalizes here; with several starts the result goes to
-    :func:`merge_program`."""
+    :func:`merge_program`.  Beside its result it returns ``(iterations run,
+    line-search trials)``, two scalars of the loop's carry that :func:`fit`
+    defers to the read-back's span when tracing is on."""
 
     def run(start, fin=None):
-        res = optim.lbfgs_batched_stage2(
+        res, counts = optim.lbfgs_batched_stage2_counted(
             _mean_objective(family, *start["sub"]), start["res"],
             start["carry"],
             max_iters=max_iters, tol=tol)
         if family.merge is not None:
-            return res
+            return res, counts
         if start["carry"].ls_hist is None:
-            return finalize(res, *fin, family.to_natural)
-        return finalize(res[0], *fin, family.to_natural), res[1]
+            return finalize(res, *fin, family.to_natural), counts
+        return (finalize(res[0], *fin, family.to_natural), res[1]), counts
 
     return run
 
@@ -256,6 +258,15 @@ def fit(args: tuple, *, backend: str, compact: bool, max_iters: int,
     start's stage 1 and the host's wait for it at the first gate (the later
     starts' gates find their scalars ready); ``fit.stage2`` opens only
     around a dispatch.
+
+    What the loops counted (tracing on only; off, the gate makes the two
+    transfers it needs, ``undone`` and ``k``, and no other): ``fit.stage1``
+    reports ``trials`` and ``iter_passes``, the starts' sums of the carry's
+    line-search trials and of ``k`` (``iters`` is their max), and ``starts``;
+    stage 2 is never waited for here, so its two scalars are handed to
+    ``obs.defer`` as device handles and land on the ``fit.readback`` span
+    that reads the result anyway (``stage2_iters``, ``stage2_trials``; 0
+    and 0 where no stage 2 was dispatched).
     """
     xb = args[0]
     bsz = xb.shape[0] if cells is None else cells
@@ -267,12 +278,21 @@ def fit(args: tuple, *, backend: str, compact: bool, max_iters: int,
     with obs.span("fit.stage1", rows=bsz) as span:
         out, aux = run1(*args)
         starts = aux["starts"]
+        if obs.enabled():
+            # what the span reports comes over beside ``undone``, not in
+            # round trips of its own after it (a scalar read is some 1 ms)
+            for s in starts:
+                s["carry"].k.copy_to_host_async()
+                s["carry"].trials.copy_to_host_async()
         # the gate: a tiny scalar sync per start
         undone = [int(s["carry"].undone) for s in starts]
         if obs.enabled():
-            span.set(iters=max(int(s["carry"].k) for s in starts),
-                     undone=sum(undone),
+            ks = [int(s["carry"].k) for s in starts]
+            span.set(iters=max(ks), undone=sum(undone), starts=len(starts),
+                     iter_passes=sum(ks),
+                     trials=sum(int(s["carry"].trials) for s in starts),
                      **_kernel_attrs(series_block, stage_attrs, bsz))
+    obs.defer(stage2_iters=0, stage2_trials=0)
     results, reran, info = [], False, None
     for start, n_undone in zip(starts, undone):
         carry, res = start["carry"], start["res"]
@@ -284,8 +304,10 @@ def fit(args: tuple, *, backend: str, compact: bool, max_iters: int,
         if n_undone > 0 and int(carry.k) < max_iters:
             with obs.span("fit.stage2", rows=cap,
                           **_kernel_attrs(series_block, stage_attrs, cap)):
-                res = (stage2()(start) if merge
-                       else stage2()(start, aux["fin"]))
+                res, (iters2, trials2) = (
+                    stage2()(start) if merge
+                    else stage2()(start, aux["fin"]))
+            obs.defer(stage2_iters=iters2, stage2_trials=trials2)
             if counted:
                 res, info = res
             reran = True

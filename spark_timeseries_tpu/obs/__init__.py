@@ -56,12 +56,17 @@ Usage::
 The walk path, root to leaf (``obs.recorder`` lists the attributes):
 ``walk`` > ``walk.open``, then per chunk ``chunk.plan``, ``chunk`` >
 ``sanitize``, ``fit.primary`` > ``fit.stage1`` / ``fit.stage2`` (the lazy
-optimizer's stage gate, with the carry's ``iters`` and ``undone``),
-``fit.readback`` (with the rows' ``iters_max`` / ``iters_sum``), the
+optimizer's stage gate, with the carry's ``iters`` and ``undone`` and what
+its loop counted: ``trials``, the line search's trials, and
+``iter_passes``, both summed over the ``starts``), ``fit.readback`` (with
+the rows' ``iters_max`` / ``iters_sum``, and stage 2's ``stage2_iters`` /
+``stage2_trials``: the stage-2 program is not waited for at its dispatch,
+so ``lockstep.fit`` hands the two device scalars to ``obs.defer`` and the
+read-back, which waits anyway, writes what ``obs.settle`` reads), the
 ladder's ``fit.rung.*``, then ``chunk.submit`` > ``commit.overlap`` on the
 committer thread, ``stage.overlap`` on the prefetcher thread; last
 ``walk.close``.  ``benchmark/span_idle.py`` splits the device's idle time
-by these names.
+by these names, ``benchmark/device_phases.py`` its busy time.
 
 Instrumented surfaces: ``reliability.fit_chunked`` / ``resilient_fit`` /
 ``sanitize`` / ``journal`` / ``watchdog`` / the pipelined ``committer``
@@ -84,11 +89,11 @@ header).
 """
 
 from . import core, memory, metrics, promsink, recorder, tracing
-from .core import (NULL_SPAN, Span, counter, current_span, disable,
+from .core import (NULL_SPAN, Span, counter, current_span, defer, disable,
                    dump_failure, dump_on_failure, emit_metrics, enable,
                    enable_from_env, enabled, event, first_dispatch, gauge,
-                   histogram, last_crash_dump, snapshot, span, span_link,
-                   span_scope, stream_path, summary, walk_span)
+                   histogram, last_crash_dump, settle, snapshot, span,
+                   span_link, span_scope, stream_path, summary, walk_span)
 from .memory import PeakMemory, peak_memory, register_staging_pool
 from .metrics import Counter, Gauge, Histogram, MetricsRegistry
 from .promsink import PromTextfileSink
@@ -113,6 +118,7 @@ __all__ = [
     "counter",
     "current_span",
     "current_trace",
+    "defer",
     "disable",
     "dump_failure",
     "dump_on_failure",
@@ -131,6 +137,7 @@ __all__ = [
     "promsink",
     "recorder",
     "register_staging_pool",
+    "settle",
     "snapshot",
     "span",
     "span_link",

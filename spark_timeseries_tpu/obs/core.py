@@ -56,6 +56,7 @@ __all__ = [
     "Span",
     "counter",
     "current_span",
+    "defer",
     "disable",
     "dump_failure",
     "dump_on_failure",
@@ -68,6 +69,7 @@ __all__ = [
     "gauge",
     "histogram",
     "last_crash_dump",
+    "settle",
     "snapshot",
     "span",
     "span_link",
@@ -446,6 +448,62 @@ def span_scope(link: Optional[tuple]):
         yield
     finally:
         _TLS.base = prev
+
+
+# -- deferred device scalars -------------------------------------------------
+
+
+_DEFERRED_MAX = 32  # handles a name keeps unread before they are folded
+
+
+def defer(**device_scalars) -> None:
+    """Keep device scalars for a span that closes later on THIS thread
+    (``lockstep.fit`` does not wait for stage 2; ``fit.readback`` does):
+    :func:`settle` turns them into host integers.  Repeated names sum.  A
+    handle that can (a ``jax.Array``) starts its copy to the host here,
+    behind its program and without waiting.
+    Disabled plane -> returns at once, its arguments untouched."""
+    st = _STATE
+    if not st.enabled:
+        return
+    pending = getattr(_TLS, "deferred", None)
+    if pending is None or pending[0] != st.run_id:
+        pending = _TLS.deferred = (st.run_id, {})
+    for name, handle in device_scalars.items():
+        # the copy starts now and rides behind the program: settling, after
+        # the caller's own blocking reads, then waits for nothing
+        start = getattr(handle, "copy_to_host_async", None)
+        if start is not None:
+            start()
+        handles = pending[1].setdefault(name, [])
+        handles.append(handle)
+        if len(handles) > _DEFERRED_MAX:
+            # nobody settles (a fit outside ``resilient_fit``): fold what
+            # has piled up, programs long finished, so that nothing grows
+            handles[:] = [sum(int(h) for h in handles)]
+
+
+def settle() -> dict:
+    """``{name: int}`` of what this thread deferred since the last call,
+    then nothing pending: a read waits for its program and its copy, so
+    call it where the thread has waited anyway.  A caller
+    that only wants nothing left over from a fit that raised drops the
+    result.  Empty when nothing was deferred; handles of a run that has
+    been disabled since are dropped unread."""
+    pending = getattr(_TLS, "deferred", None)
+    if pending is None:
+        return {}
+    _TLS.deferred = None
+    st = _STATE
+    if not st.enabled or pending[0] != st.run_id:
+        return {}
+    out = {}
+    for name, handles in pending[1].items():
+        try:
+            out[name] = sum(int(h) for h in handles)
+        except Exception:  # noqa: BLE001 - a failed program's scalar
+            pass
+    return out
 
 
 # -- run summary / failure dumps --------------------------------------------

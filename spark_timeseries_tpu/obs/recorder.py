@@ -62,9 +62,14 @@ Schema v3 (ISSUE 25) gives every ``span`` line three top-level fields
 The walk path's spans, root to leaf: ``walk`` (attrs ``rows``,
 ``chunk_rows``, ``lanes``, ``journaled``) > ``walk.open``, then per chunk
 ``chunk.plan`` (``lo``, ``hi``), ``chunk`` > ``sanitize``, ``fit.primary``
-> ``fit.stage1`` (``rows``, ``iters``, ``undone``) and ``fit.stage2``
-(``rows``), ``fit.readback`` (``rows``, ``iters_max``, ``iters_sum``,
-``failed``), the ladder's ``fit.rung.*``, then ``chunk.submit`` (``lo``,
+> ``fit.stage1`` (``rows``, ``iters``, ``undone``, and what the lockstep
+loop's carry counted over the ``starts``: ``trials``, the line search's
+trials, and ``iter_passes``, the sum of the starts' iterations where
+``iters`` is their max) and ``fit.stage2`` (``rows``), ``fit.readback``
+(``rows``, ``iters_max``, ``iters_sum``, ``failed``, and on the lazy
+optimizer path ``stage2_iters`` / ``stage2_trials``: the iterations and
+line-search trials of the chunk's stage-2 programs, 0 and 0 where none was
+dispatched), the ladder's ``fit.rung.*``, then ``chunk.submit`` (``lo``,
 ``hi``) > ``commit.overlap`` on the committer thread; ``stage.overlap`` on
 the prefetcher thread under its ``chunk``; last ``walk.close``.
 

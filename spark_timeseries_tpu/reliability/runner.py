@@ -198,6 +198,7 @@ def resilient_fit(
         if "align_mode" in _accepted_kwargs(fit_fn, {"align_mode": None}):
             fit_kwargs = {**fit_kwargs, "align_mode": align_hint}
 
+    obs.settle()  # nothing pending from a fit that raised before its read-back
     with obs.span("fit.primary", rows=b):
         res = fit_fn(y_clean, **fit_kwargs)
     # fit.readback: the first host read of the result waits for the device,
@@ -216,9 +217,10 @@ def resilient_fit(
         if obs.enabled():
             # what the lockstep optimizer spent: every row of the chunk
             # rides along for iters_max iterations, iters_sum of them useful
+            # (and what stage 2's loops counted: lockstep.fit deferred it)
             readback.set(iters_max=int(iters.max(initial=0)),
                          iters_sum=int(iters.sum()),
-                         failed=int(failed.sum()))
+                         failed=int(failed.sum()), **obs.settle())
     # ladder size cap: rows past the cap skip the ladder entirely (they
     # stay in ``failed`` and are flagged DIVERGED below), bounding the
     # worst-case ladder cost on mass-non-convergence panels

@@ -393,7 +393,7 @@ def _make_step_b(fb, *, m, dtype, tol, ftol, max_linesearch, c1):
                                     max_linesearch=max_linesearch, c1=c1)
 
     def step(carry):
-        state, iters, ls_hist = carry
+        state, iters, ls_hist, trials = carry
         done = state.converged | state.failed
         with jax.named_scope("optim.lbfgs_batched.two_loop"):
             direction = -_two_loop_b(
@@ -474,7 +474,7 @@ def _make_step_b(fb, *, m, dtype, tol, ftol, max_linesearch, c1):
         iters = jnp.where(done, iters, state.k + 1)
         if ls_hist is not None:
             ls_hist = ls_hist.at[state.k].set(n_ls)
-        return new_state, iters, ls_hist
+        return new_state, iters, ls_hist, trials + n_ls
 
     return step
 
@@ -483,8 +483,10 @@ def _lockstep(fun_batched, x0, cap, count_evals, *, max_iters, history, tol,
               ftol, max_linesearch, c1):
     """Run the lockstep loop from ``x0`` until the budget is spent or at
     most ``cap`` rows remain unconverged (``cap=None``: until none does)
-    -> ``(state, iters, ls_hist)``; ``ls_hist`` is ``None`` unless
-    ``count_evals``."""
+    -> ``(state, iters, ls_hist, trials)``; ``ls_hist`` is ``None`` unless
+    ``count_evals``, ``trials`` is always there: the line search's trials
+    summed over the iterations run, one ``int32`` scalar of the carry (the
+    stage gate reports it, ``models/lockstep.py``)."""
     bsz, _ = x0.shape
     dtype = x0.dtype
     if ftol is None:
@@ -498,7 +500,7 @@ def _lockstep(fun_batched, x0, cap, count_evals, *, max_iters, history, tol,
     step_full = _make_step_b(fun_batched, **knobs)
 
     def cond_full(carry):
-        state, _, _ = carry
+        state = carry[0]
         undone = ~(state.converged | state.failed)
         if cap is None:
             live = jnp.any(undone)
@@ -507,7 +509,8 @@ def _lockstep(fun_batched, x0, cap, count_evals, *, max_iters, history, tol,
         # keep lockstepping only while the stragglers outnumber the cap
         return (state.k < max_iters) & (n_undone > cap)
 
-    return lax.while_loop(cond_full, step_full, (init, iters0, ls0))
+    return lax.while_loop(cond_full, step_full,
+                          (init, iters0, ls0, jnp.zeros((), jnp.int32)))
 
 
 def _result_b(state, iters):
@@ -583,7 +586,7 @@ def minimize_lbfgs_batched(
             **knobs)
         return lbfgs_batched_stage2(
             straggler_fun(carry.idxc), res1, carry, **knobs)
-    final, iters, ls_hist = _lockstep(
+    final, iters, ls_hist, _ = _lockstep(
         fun_batched, x0, None, count_evals, **knobs)
     result = _result_b(final, iters)
     if not count_evals:
@@ -613,7 +616,9 @@ class StragglerCarry(NamedTuple):
     to repack the objective's data for the compacted problem.  ``undone``
     and ``k`` are the host-checkable dispatch gate: stage 2 is worth
     dispatching iff ``undone > 0`` and ``k < max_iters`` (the shared budget
-    — see the truncation contract in :func:`lbfgs_batched_stage2`).
+    — see the truncation contract in :func:`lbfgs_batched_stage2`);
+    ``trials`` rides beside them: the line search's trials summed over
+    stage 1's ``k`` iterations, what the gate reports when tracing is on.
     ``ls_hist`` is the pass accounting of ``count_evals`` (``None`` when
     off: no leaf, so the compiled programs are those of an uncounted fit)."""
 
@@ -623,6 +628,7 @@ class StragglerCarry(NamedTuple):
     iters: jax.Array  # [bsz] per-row iteration counts at stage-1 exit
     undone: jax.Array  # [] int32 unconverged-row count at stage-1 exit
     k: jax.Array  # [] int32 stage-1 exit iteration
+    trials: jax.Array  # [] int32 linesearch evals summed over stage 1
     ls_hist: "jax.Array | None" = None  # [max_iters] int32 linesearch evals
 
 
@@ -667,7 +673,7 @@ def lbfgs_batched_stage1(
         raise ValueError(
             f"straggler_cap {cap} must be < batch {bsz} (an uncompacted fit "
             "has no stage 2 to defer — use minimize_lbfgs_batched)")
-    stage1, iters, ls_hist = _lockstep(
+    stage1, iters, ls_hist, trials = _lockstep(
         fun_batched, x0, cap, count_evals, max_iters=max_iters,
         history=history, tol=tol, ftol=ftol, max_linesearch=max_linesearch,
         c1=c1)
@@ -691,7 +697,7 @@ def lbfgs_batched_stage1(
     result = _result_b(stage1, iters)
     carry = StragglerCarry(state=sub, idx=idx, idxc=idxc, iters=iters,
                            undone=jnp.sum(undone1).astype(jnp.int32),
-                           k=stage1.k, ls_hist=ls_hist)
+                           k=stage1.k, trials=trials, ls_hist=ls_hist)
     return result, carry
 
 
@@ -726,6 +732,19 @@ def lbfgs_batched_stage2(
     unchanged by the scatter.  Any change that gives stage 2 its OWN budget
     must first make the gather lossless.
     """
+    return lbfgs_batched_stage2_counted(
+        fun_sub_batched, full, carry, max_iters=max_iters, history=history,
+        tol=tol, ftol=ftol, max_linesearch=max_linesearch, c1=c1)[0]
+
+
+def lbfgs_batched_stage2_counted(fun_sub_batched, full, carry, *, max_iters,
+                                 history=8, tol=1e-6, ftol=None,
+                                 max_linesearch=20, c1=1e-4):
+    """:func:`lbfgs_batched_stage2` -> ``(what it returns, (iters, trials))``:
+    beside the result the two ``int32`` scalars the stage-2 loop counted,
+    the iterations it ran (``k_final - carry.k``) and its line search's
+    trials from 0 — what ``models/lockstep.py`` defers to the read-back's
+    span when tracing is on, and drops otherwise."""
     dtype = carry.state.x.dtype
     if ftol is None:
         ftol = 1e-9 if dtype == jnp.float64 else 1e-6
@@ -742,13 +761,15 @@ def lbfgs_batched_stage2(
         max_linesearch=max_linesearch, c1=c1)
 
     def cond_sub(c):
-        state, _, _ = c
+        state = c[0]
         return (state.k < stage2_max_iters) & jnp.any(
             ~(state.converged | state.failed))
 
-    sub_f, sub_iters, ls_hist = lax.while_loop(
+    sub_f, sub_iters, ls_hist, trials = lax.while_loop(
         cond_sub, step_sub,
-        (carry.state, carry.iters[carry.idxc], carry.ls_hist))
+        (carry.state, carry.iters[carry.idxc], carry.ls_hist,
+         jnp.zeros((), jnp.int32)))
+    counts = (sub_f.k - carry.k, trials)
     put = lambda a, s: a.at[carry.idx].set(s, mode="drop")
     result = LBFGSResult(
         x=put(full.x, sub_f.bx),
@@ -759,8 +780,8 @@ def lbfgs_batched_stage2(
         grad_norm=put(full.grad_norm, _rownorm(sub_f.bg)),
     )
     if ls_hist is None:
-        return result
-    return result, pass_info(carry, ls_hist)
+        return result, counts
+    return (result, pass_info(carry, ls_hist)), counts
 
 
 def batched_minimize(

@@ -186,7 +186,8 @@ def test_walk_emits_the_stage_spans_under_its_chunk(lazy_walk):
             yield s["name"]
 
     (s1,), (s2,) = by_name["fit.stage1"], by_name["fit.stage2"]
-    assert set(s1["attrs"]) == {"rows", "iters", "undone", "series_block",
+    assert set(s1["attrs"]) == {"rows", "iters", "undone", "starts",
+                                "iter_passes", "trials", "series_block",
                                 "adjoint_series_block", "adjoint_panels"}
     assert s1["attrs"]["rows"] == LAZY_ROWS
     assert all(type(s1["attrs"][k]) is int for k in ("iters", "undone"))
@@ -208,6 +209,11 @@ def test_walk_emits_the_stage_spans_under_its_chunk(lazy_walk):
     (readback,) = by_name["fit.readback"]
     assert readback["attrs"]["iters_max"] > s1["attrs"]["iters"]
     assert readback["attrs"]["iters_max"] == int(np.max(res.iters))
+    # and counted its own iterations and trials (ISSUE 38)
+    assert readback["attrs"]["stage2_iters"] \
+        == readback["attrs"]["iters_max"] - s1["attrs"]["iters"]
+    assert readback["attrs"]["stage2_trials"] \
+        >= readback["attrs"]["stage2_iters"]
 
 
 @pytest.mark.parametrize("fit,max_iters,stage2", [
@@ -257,7 +263,8 @@ def test_lazy_spans_carry_the_gates_numbers(monkeypatch, tmp_path, fit,
 
     assert spans["fit.stage1"]["attrs"] == {
         "rows": LAZY_ROWS, "iters": int(carry.k),
-        "undone": int(carry.undone),
+        "undone": int(carry.undone), "starts": 1,
+        "iter_passes": int(carry.k), "trials": int(carry.trials),
         "series_block": pk.garch_series_block(LAZY_ROWS, 96),
         "adjoint_series_block": pk.garch_series_block(LAZY_ROWS, 96,
                                                       "adjoint"),
@@ -305,7 +312,7 @@ def test_stage1_hands_stage2_its_stragglers_folded(monkeypatch, ragged):
     assert np.array_equal(np.asarray(got.zb3), np.asarray(want.zb3))
     np.testing.assert_allclose(np.asarray(got.h03), np.asarray(want.h03),
                                rtol=1e-6)
-    out = garch._fit_stage2_program(*static)(start, aux["fin"])
+    out, _counts = garch._fit_stage2_program(*static)(start, aux["fin"])
     fit = garch.fit(y, backend="pallas-interpret")
     for a, b in zip(out, fit):
         assert np.array_equal(np.asarray(a), np.asarray(b), equal_nan=True)

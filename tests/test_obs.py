@@ -333,6 +333,75 @@ class TestFailureDump:
 # ---------------------------------------------------------------------------
 
 
+class TestDeferredScalars:
+    """``obs.defer`` / ``obs.settle`` (ISSUE 38): device scalars kept for a
+    span that closes later on the same thread."""
+
+    class Untouchable:
+        def __getattribute__(self, name):
+            raise AssertionError(f"a disabled defer touched .{name}")
+
+    def test_disabled_defer_returns_before_its_arguments(self):
+        assert obs.defer(stage2_iters=self.Untouchable()) is None
+        assert obs.settle() == {}
+
+    def test_repeated_names_sum_and_settle_empties(self):
+        obs.enable()
+        obs.defer(a=1, b=jnp.int32(2))
+        obs.defer(a=jnp.asarray(3, jnp.int32))
+        got = obs.settle()
+        assert got == {"a": 4, "b": 2}
+        assert all(type(v) is int for v in got.values())
+        assert obs.settle() == {}
+
+    def test_unsettled_handles_are_folded_not_piled_up(self):
+        # a fit outside resilient_fit defers and nobody settles: the pile
+        # stays bounded and the sum exact
+        from spark_timeseries_tpu.obs import core
+
+        obs.enable()
+        for _ in range(3 * core._DEFERRED_MAX + 5):
+            obs.defer(a=jnp.int32(2))
+        assert len(core._TLS.deferred[1]["a"]) <= core._DEFERRED_MAX
+        assert obs.settle() == {"a": 2 * (3 * core._DEFERRED_MAX + 5)}
+
+    def test_pending_is_the_threads_own_and_dies_with_the_run(self):
+        import threading
+
+        obs.enable()
+        obs.defer(a=1)
+        seen = []
+        t = threading.Thread(target=lambda: seen.append(obs.settle()))
+        t.start()
+        t.join()
+        assert seen == [{}]
+        obs.disable()  # the handles are dropped unread
+        obs.enable()
+        assert obs.settle() == {}
+
+    def test_a_raising_fit_leaves_nothing_for_the_next_chunk(self, tmp_path):
+        calls = []
+
+        def fit(y, **kw):
+            calls.append(1)
+            if len(calls) == 1:
+                obs.defer(stage2_iters=5, stage2_trials=40)
+                raise RuntimeError("boom")
+            return arima.fit(y, (1, 0, 0), max_iters=5)
+
+        p = str(tmp_path / "ev.jsonl")
+        obs.enable(p)
+        y = _ar_panel(b=4)
+        with pytest.raises(RuntimeError):
+            rel.resilient_fit(fit, y, ladder=())
+        rel.resilient_fit(fit, y, ladder=())
+        obs.disable()
+        back = [s for s in _span_lines(p) if s["name"] == "fit.readback"]
+        assert len(back) == 1
+        assert set(back[0]["attrs"]) == {"rows", "iters_max", "iters_sum",
+                                         "failed"}
+
+
 class TestInstrumentation:
     def test_ladder_counters_count_attempts_and_rescues(self):
         y = _ar_panel(b=8)
@@ -720,16 +789,21 @@ class TestStageGateSpans:
         # adjoint call, y3 and e3 (it forms the cotangent itself, ISSUE 35)
         # adjoint_series_block: what that call takes per grid step, by the
         # same rule (ISSUE 37)
+        # trials / iter_passes / starts: what the lockstep loop's carry
+        # counted, summed over the starts (ISSUE 38)
         assert s1["attrs"] == {
             "rows": 2048, "iters": int(carry.k),
-            "undone": int(carry.undone),
+            "undone": int(carry.undone), "starts": 1,
+            "iter_passes": int(carry.k), "trials": int(carry.trials),
             "series_block": pk.css_series_block(2048, 39, (1, 1, 1)),
             "adjoint_series_block": pk.css_series_block(
                 2048, 39, (1, 1, 1), "adjoint"),
             "lag_terms": 2, "lag_span": 1, "adjoint_panels": 2}
         assert s1["attrs"]["series_block"] in (1024, 2048)
         assert s1["attrs"]["adjoint_series_block"] in (1024, 2048)
-        assert all(type(s1["attrs"][k]) is int for k in ("iters", "undone"))
+        assert all(type(s1["attrs"][k]) is int
+                   for k in ("iters", "undone", "trials", "iter_passes"))
+        assert s1["attrs"]["trials"] >= s1["attrs"]["iters"]
         assert s1["parent"] == primary.id
         assert s1["attrs"]["undone"] > 0
         assert (s1["attrs"]["iters"] < max_iters) == stage2
@@ -757,7 +831,8 @@ class TestStageGateSpans:
         _assert_bitwise(on, off)
         spans = {s["name"]: s for s in _span_lines(p)}
         s1, s2 = spans["fit.stage1"], spans["fit.stage2"]
-        assert set(s1["attrs"]) == {"rows", "iters", "undone",
+        assert set(s1["attrs"]) == {"rows", "iters", "undone", "starts",
+                                    "iter_passes", "trials",
                                     "series_block", "adjoint_series_block",
                                     "lag_terms", "lag_span",
                                     "adjoint_panels"}
@@ -797,13 +872,17 @@ class TestStageGateSpans:
         static = {"orders": 9, "cells": cells, "lag_terms": 4, "lag_span": 2,
                   "adjoint_panels": 2}
         assert {k: v for k, v in s1["attrs"].items()
-                if k not in ("iters", "undone")} == {
-            "rows": cells, **static,
+                if k not in ("iters", "undone", "iter_passes",
+                             "trials")} == {
+            "rows": cells, "starts": 1, **static,
             "series_block": pk.css_grid_series_block(9, 256, 39, 2, 2),
             "adjoint_series_block": pk.css_grid_series_block(
                 9, 256, 39, 2, 2, "adjoint")}
         assert 0 < s1["attrs"]["undone"] <= cap
         assert 0 < s1["attrs"]["iters"] < 14
+        # the grid's line search runs until its slowest CELL accepts
+        assert s1["attrs"]["iter_passes"] == s1["attrs"]["iters"] \
+            <= s1["attrs"]["trials"]
         assert s2["attrs"] == {
             "rows": cap, **static,
             "series_block": pk.css_grid_series_block(1, cap, 39, 2, 2),
@@ -849,6 +928,84 @@ class TestStageGateSpans:
         assert int(info["compact_at"]) == at
         evals = np.asarray(info["ls_evals"])
         assert evals[:at].min() >= 1 and evals[at:].any()
+
+    @pytest.mark.parametrize("family", ["arima", "sarima", "holtwinters",
+                                        "garch"])
+    def test_loop_counts_equal_count_evals_sums(self, monkeypatch, tmp_path,
+                                                family):
+        # ISSUE 38: the carry's scalar is count_evals's history summed —
+        # stage 1's on the gate's span, stage 2's (deferred at its dispatch,
+        # never waited for there) on the read-back's
+        monkeypatch.setattr(optim, "COMPACT_MIN_BATCH", 2048)
+        fit = _LAZY_FITS[family]()
+        plain, info = fit(count_evals=True)
+        evals, at = np.asarray(info["ls_evals"]), int(info["compact_at"])
+        p = str(tmp_path / "ev.jsonl")
+        obs.enable(p)
+        res = rel.resilient_fit(lambda _y, **kw: fit(**kw),
+                                jnp.zeros((2048, 2)), sanitize=False,
+                                ladder=())
+        obs.disable()
+        assert np.array_equal(res.iters, np.asarray(plain.iters))
+        spans = {s["name"]: s for s in _span_lines(p)}
+        s1, back = spans["fit.stage1"]["attrs"], spans["fit.readback"]["attrs"]
+        assert s1["starts"] == 1 and s1["iter_passes"] == s1["iters"] == at
+        assert s1["trials"] == int(evals[:at].sum())
+        assert back["stage2_trials"] == int(evals[at:].sum()) > 0
+        assert back["stage2_iters"] == back["iters_max"] - at > 0
+        assert all(type(back[k]) is int
+                   for k in ("stage2_iters", "stage2_trials"))
+        out = subprocess.run(
+            [sys.executable, os.path.join(_ROOT, "tools", "obs_report.py"),
+             p, "--check"], capture_output=True, text=True, timeout=120)
+        assert out.returncode == 0, out.stderr
+
+    def test_no_stage2_reads_back_zero_and_zero(self, lazy, tmp_path):
+        # the budget ends in stage 1 (8 iterations): the lazy path ran, so
+        # the read-back says that no stage 2 did; nothing stays pending
+        y, _ = lazy
+        p = str(tmp_path / "ev.jsonl")
+        obs.enable(p)
+        rel.resilient_fit(arima.fit, y, order=(1, 1, 1), max_iters=8,
+                          backend="pallas-interpret", ladder=())
+        assert obs.settle() == {}
+        obs.disable()
+        spans = {s["name"]: s for s in _span_lines(p)}
+        assert "fit.stage2" not in spans and "fit.stage1" in spans
+        back = spans["fit.readback"]["attrs"]
+        assert (back["stage2_iters"], back["stage2_trials"]) == (0, 0)
+
+    def test_gate_with_tracing_off_reads_undone_and_k_only(self, lazy):
+        # ISSUE 38: off, the gate makes the parent's transfers (undone, and
+        # k where a stage 2 may follow) and not one more; on, it reads the
+        # carry's trials — here a leaf that refuses to be read
+        y, seen = lazy
+        fit = lambda: arima.fit(y, (1, 1, 1), max_iters=8,  # noqa: E731
+                                backend="pallas-interpret")
+        want = fit()
+
+        class Unread:
+            def __int__(self):
+                raise AssertionError("the gate read carry.trials")
+
+            __index__ = __array__ = copy_to_host_async = __int__
+
+        real = arima._fit_stage1_program  # the fixture's spy
+
+        def blind(*static):
+            def run1(*args):
+                out, aux = real(*static)(*args)
+                (start,) = aux["starts"]
+                carry = start["carry"]._replace(trials=Unread())
+                return out, {**aux, "starts": ({**start, "carry": carry},)}
+
+            return run1
+
+        arima._fit_stage1_program = blind  # the fixture's patch undoes it
+        _assert_bitwise(fit(), want)
+        obs.enable()
+        with pytest.raises(AssertionError, match="carry.trials"):
+            fit()
 
     def test_fit_under_a_callers_jit_is_the_composed_program(
             self, monkeypatch, tmp_path):

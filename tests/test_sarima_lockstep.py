@@ -412,7 +412,8 @@ def test_stage1_hands_stage2_its_stragglers_folded(monkeypatch, ragged):
     assert np.array_equal(np.asarray(folded.y3), np.asarray(want[0]))
     assert np.array_equal(np.asarray(folded.zb3), np.asarray(want[1]))
     assert np.array_equal(np.asarray(rows[0]), np.asarray(nvd[idxc]))
-    out = arima._fit_stage2_program(*static, seasonal)(start, aux["fin"])
+    out, _counts = arima._fit_stage2_program(*static, seasonal)(
+        start, aux["fin"])
     fit = arima.fit(y, order, seasonal=seasonal, backend="pallas-interpret",
                     max_iters=14)
     _assert_bitwise(out, fit)
