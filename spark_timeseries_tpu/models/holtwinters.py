@@ -186,8 +186,8 @@ def fit(
         stage2=lambda: _fit_stage2_program(*static),
         merge=lambda: _merge_starts_program(*static),
         series_block=lambda rows, mode: pk.hw_series_block(
-            rows, yb.shape[1], period, mode),
-        stage_attrs={"adjoint_panels": pk.HW_ADJOINT_PANELS})
+            rows, yb.shape[1], period, mode, multiplicative),
+        stage_attrs={"adjoint_panels": pk.HW_ADJOINT_PANELS[multiplicative]})
     if count_evals:
         out = (out[0], {**out[1], "n_starts": n_starts})
     return debatch_fit(out, single, count_evals)

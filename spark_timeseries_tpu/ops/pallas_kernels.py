@@ -134,10 +134,14 @@ def series_rows(nsub: int, layout, r_best: int) -> int:
 # order of the grid over gathered cells 29.56 / 15.59 / 11.15 (41 / 44 / 59);
 # GARCH 16.30 / 12.46 / 11.43 (21 / 31 / 65: four chains fill every vector
 # slot and spill, and over the 16,384-row compaction read 17.66 / 13.54 /
-# 14.43); Holt-Winters 28.47 / 28.99 (34 / 40), five panels at the HBM's
-# pace on one register, R = 4 past VMEM.  The adjoints write no panel: 11.2
-# ns is 8 KB at 730 GB/s)
-_ADJOINT_R = {"css": 4, "garch": 4, "hw": 1}
+# 14.43).  Holt-Winters by ``mult`` (PR 43, over [131072, 960]): the
+# additive adjoint reads one panel, 20.93 / 11.60 / 9.01 (30 / 33 / 51), at
+# R = 4 on the vector slots and no longer on the HBM (13.2 against 12.9 at
+# R = 2 over the 16,384-row compaction); the multiplicative replay
+# 31.80 / 27.63, five panels and R = 4 past VMEM — kept at 1, the program
+# it was measured with.  The adjoints write no panel: 11.2 ns is 8 KB at 730
+# GB/s)
+_ADJOINT_R = {"css": 4, "garch": 4, "hw": {False: 4, True: 1}}
 
 
 def _nsub(rows: int) -> int:
@@ -1876,31 +1880,58 @@ def ewma_sse(alpha, x, n_valid=None, *, interpret: bool = False):
 # The seasonal ring lives in a [m, 8, 128] VMEM scratch and persists across
 # time chunks.  Seeds (L_0, T_0, ring init) are computed OUTSIDE the kernel
 # from the first two valid seasons — they depend on the data only, so the
-# adjoint propagates to the three smoothing parameters alone.  Reverse pass
-# replays saved (L, T, S_old) trajectories with a ring of seasonal adjoints.
-# Additive (gp = -[live-err] gbar_t):
+# adjoint propagates to the three smoothing parameters alone.
+#
+# ADDITIVE: the forward saves ONE panel and the adjoint reads ONE.  With
+#   r_t = y_t - (L_{t-1} + T_{t-1} + S_t)
+# the raw one-step error of a live step (zero outside [zb, t_limit); e_t is
+# r_t on the live-err steps and 0 in the first season after zb), the update
+# is L_t = L_{t-1} + T_{t-1} + a r_t, so every data-dependent factor of the
+# reverse pass is a multiple of r_t and its state recursion has coefficients
+# constant in (a, b, g) — no trajectory, no y, no seed, no neighbour chunk:
+#   gp        = -2 r_t gbar on live-err steps, else 0
 #   vL        = uL + b uT - g uS
-#   da       += (y_t - S_t - L_{t-1} - T_{t-1}) vL
-#   db       += (L_t - L_{t-1} - T_{t-1}) uT
-#   dg       += (y_t - L_t - S_t) uS
+#   da       += r_t vL            (y_t - S_t - L_{t-1} - T_{t-1} = r_t)
+#   db       += a r_t uT          (L_t - L_{t-1} - T_{t-1}       = a r_t)
+#   dg       += (1-a) r_t uS      (y_t - L_t - S_t               = (1-a) r_t)
+#                                  summed as r_t uS, times (1-a) a chunk
 #   uL'       = -b uT + (1-a) vL + gp
 #   uT'       = (1-b) uT + (1-a) vL + gp
 #   rho[slot] = (1-g) uS - a vL + gp
-# Multiplicative replaces the pred/level/seasonal partials with the product
-# and quotient rules (S_t gp into the level/trend adjoints, (L+T) gp into
-# the ring, -a y/S^2 and -g y/L^2 quotient terms, eps-clamp subgradients).
+# with rho a ring of seasonal adjoints.  (Three panel moves a gradient — y
+# read and r written by the forward, r read by the adjoint — where the
+# replay below moves ten; PERF.md §6, PR 43.  WHICH of the algebraically
+# equal forms these sums take is not free: in f32 the objective is jagged
+# where a series' alpha is tiny (L += a r is then a few ulp of L), the
+# gradient's last place decides where about 1% of a million fits stop, and
+# of some twenty equal forms measured on the chip — three last-place variants
+# of the replay's own among them — every one but this left 1-6 rows of the
+# benchmark's million with an exhausted line search, on the retry ladder.)
+#
+# MULTIPLICATIVE keeps the replay: its forward saves (e, L, T, S_old) and the
+# reverse pass reads them beside y — the product and quotient rules (S_t gp
+# into the level/trend adjoints, (L+T) gp into the ring, -a y/S^2 and
+# -g y/L^2 quotient terms, eps-clamp subgradients) need y, S_old, L_t and
+# L_{t-1} + T_{t-1}, the last two past one chunk from the neighbour block.
+# The two part on the STATIC ``mult`` the kernels are specialised by.
 # Level/trend carries cross chunks through 1-slot scratches; both rings
 # (seasonal state forward, seasonal adjoint backward) persist untouched.
 
 
 def _hw_fwd_kernel(m, mult, save_resid, t_limit, cs, y_ref, par_ref, l0_ref,
                    t0_ref, s0_ref, zb_ref, *refs):
-    if save_resid:  # vjp path: trajectories for the adjoint + the SSE,
-        # accumulated in the same in-kernel order as the primal variant
+    # vjp path: what the adjoint reads — the raw errors alone (additive) or
+    # the errors and the replay trajectories (multiplicative) — + the SSE,
+    # accumulated in the same in-kernel order as the primal variant.
+    # Primal path (linesearch evals): ONLY the per-series SSE leaves the
+    # kernel — the error/trajectory stores are the HBM bill
+    r_ref = e_ref = lv_ref = tr_ref = so_ref = None
+    if save_resid and mult:
         e_ref, lv_ref, tr_ref, so_ref, ss_ref, seas_ref, clt_ref = refs
-    else:  # primal path (linesearch evals): ONLY the per-series SSE leaves
-        ss_ref, seas_ref, clt_ref = refs  # the kernel — the error/trajectory
-        e_ref = lv_ref = tr_ref = so_ref = None  # stores are the HBM bill
+    elif save_resid:
+        r_ref, ss_ref, seas_ref, clt_ref = refs
+    else:
+        ss_ref, seas_ref, clt_ref = refs
     c = pl.program_id(1)
     base = c * cs
     a = par_ref[0]
@@ -1935,11 +1966,14 @@ def _hw_fwd_kernel(m, mult, save_resid, t_limit, cs, y_ref, par_ref, l0_ref,
             nl = a * (yt - s) + (1.0 - a) * lt_sum
             snew = g * (yt - nl) + (1.0 - g) * s
         nt = b * (nl - level) + (1.0 - b) * trend
-        e = jnp.where(live_err, yt - pred, 0.0)
+        err = yt - pred
+        e = jnp.where(live_err, err, 0.0)
         nl_o = jnp.where(live, nl, level)
         nt_o = jnp.where(live, nt, trend)
         seas_ref[slot] = jnp.where(live, snew, s)
-        if save_resid:
+        if r_ref is not None:
+            r_ref[tl] = jnp.where(live, err, 0.0)
+        elif save_resid:
             e_ref[tl] = e
             so_ref[tl] = s
             lv_ref[tl] = nl_o
@@ -1954,13 +1988,15 @@ def _hw_fwd_kernel(m, mult, save_resid, t_limit, cs, y_ref, par_ref, l0_ref,
 
 
 def _hw_bwd_kernel(m, mult, t_limit, cs, nchunk, hp, *refs):
-    if hp:
+    lvp_ref = trp_ref = None
+    if not mult:  # the raw errors are all the additive reverse pass reads
+        r_ref, par_ref, zb_ref, gb_ref, gpar_ref, rho_ref, clam_ref = refs
+    elif hp:
         (y_ref, par_ref, l0_ref, t0_ref, zb_ref, gb_ref, lv_ref, lvp_ref,
          tr_ref, trp_ref, so_ref, e_ref, gpar_ref, rho_ref, clam_ref) = refs
     else:
         (y_ref, par_ref, l0_ref, t0_ref, zb_ref, gb_ref, lv_ref, tr_ref,
          so_ref, e_ref, gpar_ref, rho_ref, clam_ref) = refs
-        lvp_ref = trp_ref = None
     c = pl.program_id(1)
     base = (nchunk - 1 - c) * cs
     a = par_ref[0]
@@ -1990,17 +2026,17 @@ def _hw_bwd_kernel(m, mult, t_limit, cs, nchunk, hp, *refs):
         uS = rho_ref[slot]
         uL = lamL
         uT = lamT
-        gp = jnp.where(live_err, -(2.0 * e_ref[tl] * gb), 0.0)
-        lfar = lvp_ref[cs - 1] if hp else 0.0
-        lp = jnp.where(tl - 1 >= 0, lv_ref[jnp.maximum(tl - 1, 0)], lfar)
-        lp = jnp.where(t - 1 >= 0, lp, l0_ref[0])
-        tfar = trp_ref[cs - 1] if hp else 0.0
-        tp_ = jnp.where(tl - 1 >= 0, tr_ref[jnp.maximum(tl - 1, 0)], tfar)
-        tp_ = jnp.where(t - 1 >= 0, tp_, t0_ref[0])
-        so = so_ref[tl]
-        lt = lv_ref[tl]
-        yt = y_ref[tl]
         if mult:
+            gp = jnp.where(live_err, -(2.0 * e_ref[tl] * gb), 0.0)
+            lfar = lvp_ref[cs - 1] if hp else 0.0
+            lp = jnp.where(tl - 1 >= 0, lv_ref[jnp.maximum(tl - 1, 0)], lfar)
+            lp = jnp.where(t - 1 >= 0, lp, l0_ref[0])
+            tfar = trp_ref[cs - 1] if hp else 0.0
+            tp_ = jnp.where(tl - 1 >= 0, tr_ref[jnp.maximum(tl - 1, 0)], tfar)
+            tp_ = jnp.where(t - 1 >= 0, tp_, t0_ref[0])
+            so = so_ref[tl]
+            lt = lv_ref[tl]
+            yt = y_ref[tl]
             sc = jnp.maximum(so, 1e-12)
             ltc = jnp.maximum(lt, 1e-12)
             # eps-clamp subgradients: no flow through a clamped denominator
@@ -2016,14 +2052,17 @@ def _hw_bwd_kernel(m, mult, t_limit, cs, nchunk, hp, *refs):
                 - a * (yt / (sc * sc)) * vL * s_pass
                 + (lp + tp_) * gp
             )
+            db_t = (lt - lp - tp_) * uT
         else:
+            rt = r_ref[tl]
+            gp = jnp.where(live_err, -(2.0 * rt * gb), 0.0)
             vL = uL + b * uT - g * uS
-            da_t = (yt - so - lp - tp_) * vL
-            dg_t = (yt - lt - so) * uS
+            da_t = rt * vL
+            db_t = a * rt * uT
+            dg_t = rt * uS  # its factor (1 - a) after the loop
             new_lamL = -b * uT + (1.0 - a) * vL + gp
             new_lamT = (1.0 - b) * uT + (1.0 - a) * vL + gp
             rho_new = (1.0 - g) * uS - a * vL + gp
-        db_t = (lt - lp - tp_) * uT
         da = da + jnp.where(live, da_t, 0.0)
         db = db + jnp.where(live, db_t, 0.0)
         dg = dg + jnp.where(live, dg_t, 0.0)
@@ -2039,7 +2078,7 @@ def _hw_bwd_kernel(m, mult, t_limit, cs, nchunk, hp, *refs):
     clam_ref[1] = lamT
     gpar_ref[0] = gpar_ref[0] + da
     gpar_ref[1] = gpar_ref[1] + db
-    gpar_ref[2] = gpar_ref[2] + dg
+    gpar_ref[2] = gpar_ref[2] + (dg if mult else (1.0 - a) * dg)
 
 
 @functools.partial(
@@ -2067,34 +2106,38 @@ class HWFolded:
         return take_series(self, idxc)
 
 
-# by ``save_resid``; see _CSS_R: value-only 2.58 / 1.43 / 0.87 ms over
-# [131072, 960]; save_resid moves 2.5 GB at the HBM's pace whatever the
-# width (3.65 / 3.58 ms) and its ten buffers do not fit VMEM at R = 4
-_HW_R = {False: 4, True: 2}
+# by ``save_resid``, then ``mult``; see _CSS_R: value-only 2.58 / 1.43 / 0.87
+# ms over [131072, 960]; the additive save_resid writes one panel, 2.68 / 1.55
+# / 1.53 ms (1 GB at the HBM's pace from R = 2 on); the multiplicative one
+# writes four, 4.84 / 3.61 ms, and its ten buffers do not fit VMEM at R = 4
+# (PERF.md §6, PR 43)
+_HW_R = {False: {False: 4, True: 4}, True: {False: 4, True: 2}}
 
 
-def _hw_fwd_layout(m, save_resid, t):
+def _hw_fwd_layout(m, mult, save_resid, t):
     """The forward Holt-Winters call's blocks (see
     :func:`_css_fwd_layout`)."""
     _, cs, _ = _time_layout(t)
     ins = [(cs, _cur), (3, _fixed), (1, _fixed), (1, _fixed), (m, _fixed),
            (1, _fixed)]
-    # e + the replay trajectories for the adjoint; the per-series SSE
-    outs = ([(cs, _cur)] * 4 if save_resid else []) + [(1, _fixed)]
-    return ins, outs, [m, 2]  # scratch: the seasonal ring, level / trend
+    # what the adjoint reads — the raw errors, or the errors and the three
+    # replay trajectories — then the per-series SSE
+    saved = [(cs, _cur)] * (4 if mult else 1) if save_resid else []
+    # scratch: the seasonal ring, level / trend
+    return ins, saved + [(1, _fixed)], [m, 2]
 
 
-def hw_series_block(rows: int, t: int, period: int,
-                    mode: str = "sum") -> int:
+def hw_series_block(rows: int, t: int, period: int, mode: str = "sum",
+                    mult: bool = False) -> int:
     """Series per grid step of the Holt-Winters kernel (see
     :func:`css_series_block`); ``mode``: ``"sum"``, ``"save_resid"`` or
-    ``"adjoint"``."""
+    ``"adjoint"``, of the additive or the multiplicative model."""
     if mode == "adjoint":
-        layout, best = _hw_bwd_layout(period, t), _ADJOINT_R["hw"]
+        layout, best = _hw_bwd_layout(period, mult, t), _ADJOINT_R["hw"][mult]
     else:
         save_resid = {"sum": False, "save_resid": True}[mode]
-        layout, best = (_hw_fwd_layout(period, save_resid, t),
-                        _HW_R[save_resid])
+        layout, best = (_hw_fwd_layout(period, mult, save_resid, t),
+                        _HW_R[save_resid][mult])
     return _SBLK * series_rows(_nsub(rows), layout, best)
 
 
@@ -2104,8 +2147,8 @@ def _hw_fwd_call_f(interpret, m, mult, save_resid, params, f: HWFolded,
     # folded per call; the panel and its seeds arrive in kernel layout
     _, cs, _ = _time_layout(f.t)
     par3 = _fold(params)
-    layout = _hw_fwd_layout(m, save_resid, f.t)
-    r = _r or series_rows(f.y3.shape[1], layout, _HW_R[save_resid])
+    layout = _hw_fwd_layout(m, mult, save_resid, f.t)
+    r = _r or series_rows(f.y3.shape[1], layout, _HW_R[save_resid][mult])
     outs = _block_call(
         functools.partial(_hw_fwd_kernel, m, mult, save_resid, f.t, cs),
         layout, r, interpret, (f.y3, par3, f.l03, f.t03, f.s03, f.zb3))
@@ -2119,58 +2162,67 @@ def _hw_ss_f(interpret: bool, m: int, mult: bool, params, f: HWFolded):
 
     Primal (no-gradient) path: sum-only kernel — a linesearch objective
     evaluation pays one panel read and no error/trajectory stores.  The vjp
-    path saves the errors and replay trajectories, all folded, and reuses
-    the hand-derived adjoint.  The unfolded API (:func:`hw_sse_seeded`) is
-    a thin fold-then-delegate wrapper: ONE forward call, ONE adjoint.
+    path saves what the hand-derived adjoint reads, folded: the raw errors
+    (additive), or the errors and the replay trajectories (multiplicative).
+    The unfolded API (:func:`hw_sse_seeded`) is a thin fold-then-delegate
+    wrapper: ONE forward call, ONE adjoint.
     """
     (ss3,), _ = _hw_fwd_call_f(interpret, m, mult, False, params, f)
     return _unfold(ss3, params.shape[0])[:, 0]
 
 
 def _hw_ss_f_fwd(interpret, m, mult, params, f):
-    (e3, lv3, tr3, so3, ss3), par3 = _hw_fwd_call_f(
-        interpret, m, mult, True, params, f)
+    # additive: (r3, ss3); multiplicative: (e3, lv3, tr3, so3, ss3)
+    (*saved, ss3), par3 = _hw_fwd_call_f(interpret, m, mult, True, params, f)
     # the value is accumulated in the same in-kernel order as the primal
     # variant — see _css_ss_f: mixed accumulation orders stall rows
-    return (_unfold(ss3, params.shape[0])[:, 0],
-            (f, par3, e3, lv3, tr3, so3))
+    return _unfold(ss3, params.shape[0])[:, 0], (f, par3, *saved)
 
 
-# the panel-sized operands of the objective's adjoint call: y3, the replay
-# trajectories lv3, tr3, so3, and e3 (a stage span's ``adjoint_panels``)
-HW_ADJOINT_PANELS = 5
+# the panel-sized operands of the objective's adjoint call, by ``mult`` (a
+# stage span's ``adjoint_panels``): the raw errors r3 alone, or y3, the
+# replay trajectories lv3, tr3, so3, and e3
+HW_ADJOINT_PANELS = {False: 1, True: 5}
 
 
-def _hw_bwd_layout(m, t):
-    """The Holt-Winters adjoint call's blocks (see :func:`_css_bwd_layout`):
-    the panel, parameters, the two seeds, mask and the cotangent's plane,
-    then the replay trajectories (level and trend with their neighbours past
-    one chunk, the season) and the errors."""
+def _hw_bwd_layout(m, mult, t):
+    """The Holt-Winters adjoint call's blocks (see :func:`_css_bwd_layout`).
+    Additive: the raw errors, parameters, mask and the cotangent's plane.
+    Multiplicative: the panel, parameters, the two seeds, mask and the
+    plane, then the replay trajectories (level and trend with their
+    neighbours past one chunk, the season) and the errors."""
     panel = _rev_panel(t)
-    ins = (panel[:1] + [(3, _fixed)] + [(1, _fixed)] * 4 + panel + panel
-           + panel[:1] * 2)
+    if mult:
+        ins = (panel[:1] + [(3, _fixed)] + [(1, _fixed)] * 4 + panel + panel
+               + panel[:1] * 2)
+    else:
+        ins = panel[:1] + [(3, _fixed)] + [(1, _fixed)] * 2
     # scratch: the seasonal ring's adjoint, level / trend across chunks
     return ins, [(3, _fixed)], [m, 2]
 
 
 def _hw_ss_f_bwd(interpret, m, mult, resid, gbar, _r=None):
-    f, par3, e3, lv3, tr3, so3 = resid
+    f, par3, *saved = resid
     b = gbar.shape[0]
     # gbar [B] folds to a plane, and the plane is what the adjoint kernel is
     # handed beside the errors (see _css_ss_f_bwd): it forms the error
     # cotangent 2 e gbar itself, no panel-sized XLA pass per gradient;
     # padded series carry a zero gbar, padded time a zero error
-    gb3 = _fold(gbar[:, None].astype(e3.dtype))
+    gb3 = _fold(gbar[:, None].astype(par3.dtype))
     _, cs, nchunk = _time_layout(f.t)
     hp = nchunk > 1
-    layout = _hw_bwd_layout(m, f.t)
+    if mult:
+        e3, lv3, tr3, so3 = saved
+        args = (f.y3, par3, f.l03, f.t03, f.zb3, gb3,
+                *((lv3, lv3, tr3, tr3) if hp else (lv3, tr3)), so3, e3)
+    else:
+        args = (*saved, par3, f.zb3, gb3)
+    layout = _hw_bwd_layout(m, mult, f.t)
     # ``_r`` forces the block width (tests and the sweep)
-    r = _r or series_rows(f.y3.shape[1], layout, _ADJOINT_R["hw"])
+    r = _r or series_rows(f.y3.shape[1], layout, _ADJOINT_R["hw"][mult])
     (gpar3,) = _block_call(
         functools.partial(_hw_bwd_kernel, m, mult, f.t, cs, nchunk, hp),
-        layout, r, interpret,
-        (f.y3, par3, f.l03, f.t03, f.zb3, gb3,
-         *((lv3, lv3, tr3, tr3) if hp else (lv3, tr3)), so3, e3))
+        layout, r, interpret, args)
     # seeds and data are constants of the objective: zero cotangents
     return _unfold(gpar3, b), jax.tree_util.tree_map(jnp.zeros_like, f)
 

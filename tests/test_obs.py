@@ -914,17 +914,18 @@ class TestStageGateSpans:
         assert spans["fit.stage1"]["attrs"]["series_block"] in (1024, 2048)
         assert spans["fit.stage2"]["attrs"]["series_block"] == 1024
         # and its adjoint's, by the kernel file's rule at each stage's rows
-        # (Holt-Winters' adjoint keeps one register of series at any size)
+        # (Holt-Winters' additive adjoint reads one panel and takes its own
+        # entry of the table)
         rule = _ADJOINT_BLOCKS[family]
         cap = optim.compaction_cap(2048)
         assert [spans[s]["attrs"]["adjoint_series_block"]
                 for s in ("fit.stage1", "fit.stage2")] == [rule(2048),
                                                            rule(cap)]
-        assert rule(131072) == (1024 if family == "holtwinters" else
-                                1024 * pk._ADJOINT_R[
-                                    "garch" if family == "garch" else "css"])
+        assert rule(131072) == 1024 * (
+            pk._ADJOINT_R["hw"][False] if family == "holtwinters" else
+            pk._ADJOINT_R["garch" if family == "garch" else "css"])
         # and the panel-sized operands of its objective's adjoint call
-        panels = 5 if family == "holtwinters" else 2
+        panels = 1 if family == "holtwinters" else 2
         assert all(spans[s]["attrs"]["adjoint_panels"] == panels
                    for s in ("fit.stage1", "fit.stage2"))
         assert int(info["cap"]) == optim.compaction_cap(2048)

@@ -949,7 +949,7 @@ def _stage_programs(family, b, t):
         stage2 = hw._fit_stage2_program.__wrapped__(*static)
         inline = hw._fit_program.__wrapped__(*static, "dense", False, True,
                                              n_starts)
-        panels = pk.HW_ADJOINT_PANELS
+        panels = pk.HW_ADJOINT_PANELS[mult]
     else:
         static = (13, 1e-4, "pallas-interpret")
         stage1 = garch._fit_stage1_program.__wrapped__(*static, "dense")
@@ -1000,6 +1000,35 @@ def test_fit_programs_form_no_cotangent_panel(monkeypatch, family):
         (3, [("mul", y3.shape), ("mul", y3.shape)])]
 
 
+def test_hw_additive_gradient_moves_one_panel_each_way():
+    # ISSUE 43: the additive ``save_resid`` forward writes ONE panel-sized
+    # output (the raw one-step errors) and the adjoint call reads ONE
+    # panel-sized operand (it); the multiplicative replay keeps its four and
+    # five.  The value a gradient pass returns is the value-only call's
+    b, t, m = 1024, 29, 4
+    par = jnp.asarray(np.random.default_rng(92).uniform(
+        0.05, 0.9, (b, 3)).astype(np.float32))
+    for mult, wrote, read in ((False, 1, 1), (True, 4, 5)):
+        y = _seasonal_panel(b, t, m, seed=91) + (25.0 if mult else 0.0)
+        f = pk.hw_prefold(y, pk.hw_seeds(y, m, mult, None))
+        sse = functools.partial(pk._hw_ss_f, True, m, mult)
+        ones = jnp.ones((b,), jnp.float32)
+        jaxpr = jax.make_jaxpr(
+            lambda P: jax.vjp(lambda q: sse(q, f), P)[1](ones))(par)
+        fwd, adj = [e for e in jaxpr.eqns if e.primitive.name == "pallas_call"]
+        n_panel = f.y3.size
+        assert sum(v.aval.size >= n_panel for v in fwd.outvars) == wrote
+        assert sum(v.aval.size >= n_panel for v in adj.invars) == read
+        assert read == pk.HW_ADJOINT_PANELS[mult]
+        # what the forward saved is all the adjoint reads of that size, but
+        # the multiplicative panel itself
+        assert ({v for v in adj.invars if v.aval.size >= n_panel}
+                - set(fwd.outvars) == ({fwd.invars[0]} if mult else set()))
+        value, _ = jax.vjp(lambda q: sse(q, f), par)
+        assert np.asarray(value).tobytes() == np.asarray(sse(par, f)).tobytes()
+        assert np.isfinite(np.asarray(value)).all()
+
+
 def _hw_pin_fit(path, model_type, backend="pallas-interpret"):
     """One fit of the fit-level pin: ``inline`` (24 rows, under the
     compaction gate), ``ragged`` (the same with NaN heads and a NaN tail:
@@ -1039,13 +1068,19 @@ def _fit_pin_digest(r):
 
 # recorded on the PARENT of PR 26 (commit 31c2558: the objective folded the
 # panel on every call), f32 under this suite's jax_enable_x64, XLA:CPU of
-# this container
+# this container.  The three ``-additive`` entries were RE-RECORDED by PR 43
+# on the same host (``_HW_PIN_HOST`` matched): the additive adjoint forms
+# ``a r_t`` where the replay formed ``L_t - L_{t-1} - T_{t-1}``, the
+# gradient moves in its last place and a fit takes another step here and
+# there (the parent's: inline 22442193b2ba36d9 / 3aacc24fed3e51c3 / 24 / 179,
+# ragged 9227f7f8898068d1 / 70b6f731343fb122 / 24 / 179, lazy
+# be22edd45bafdb3b / 2c89d60dc07122c1 / 2015 / 22905)
 _HW_PIN = {  # params sha, objective sha, rows converged, sum of iters
-    "inline-additive": ("22442193b2ba36d9", "3aacc24fed3e51c3", 24, 179),
+    "inline-additive": ("e73277ec52d2363e", "48b6170cf3f8339b", 24, 178),
     "inline-multiplicative": ("b7fb28aff75e13cd", "9df77601ac081e4e", 24, 177),
-    "ragged-additive": ("9227f7f8898068d1", "70b6f731343fb122", 24, 179),
+    "ragged-additive": ("c3e03379797cd457", "fff87034ea54a730", 24, 178),
     "ragged-multiplicative": ("425b60129f439f96", "2aaefd5267317d00", 24, 180),
-    "lazy-additive": ("be22edd45bafdb3b", "2c89d60dc07122c1", 2015, 22905),
+    "lazy-additive": ("91b2073e4dd0292f", "ef962b3a455abed9", 2014, 22871),
     "lazy-multiplicative": ("21be488db7502e0b", "0e15cce7da45c373", 2048, 19054),
 }
 # the scan backend's digest of inline-additive there: no Pallas code in it,
@@ -1720,7 +1755,7 @@ def _block_width_runner(family, mode, mult, ragged, nchunk):
             outs, par3 = pk._hw_fwd_call_f(True, m, mult, save, par, f, _r=r)
             if not save:
                 return list(outs)
-            gpar, _ = pk._hw_ss_f_bwd(True, m, mult, (f, par3, *outs[:4]),
+            gpar, _ = pk._hw_ss_f_bwd(True, m, mult, (f, par3, *outs[:-1]),
                                       gbar)
             return list(outs) + [gpar]
 
@@ -1832,7 +1867,7 @@ def _adjoint_width_runner(case):
         outs, par3 = pk._hw_fwd_call_f(True, m, mult, True, par, f)
 
         def run(r):
-            return [pk._hw_ss_f_bwd(True, m, mult, (f, par3, *outs[:4]),
+            return [pk._hw_ss_f_bwd(True, m, mult, (f, par3, *outs[:-1]),
                                     gbar, _r=r)[0]]
 
     return widths, run
@@ -1876,16 +1911,21 @@ def test_adjoint_block_width_is_bit_equal(monkeypatch, case):
      lambda: pk._css_fwd_layout(1, 1, "both", 999)),
     ("garch-stage2", lambda: pk.garch_series_block(16384, 1000),
      lambda: pk._garch_fwd_layout("sum", 1000)),
-    # HW save_resid: 1 input + 4 outputs, 10 buffers of 3.9 MB: never 4
+    # HW save_resid, additive: 1 input + 1 output (the raw errors), four
+    # buffers of 3.9 MB a register of series; the multiplicative replay's
+    # 1 input + 4 outputs are ten: never 4
     ("hw-save-resid",
      lambda: pk.hw_series_block(131072, 960, 24, "save_resid"),
-     lambda: pk._hw_fwd_layout(24, True, 960)),
+     lambda: pk._hw_fwd_layout(24, False, True, 960)),
+    ("hw-mult-save-resid",
+     lambda: pk.hw_series_block(131072, 960, 24, "save_resid", True),
+     lambda: pk._hw_fwd_layout(24, True, True, 960)),
     # the ring is m x 4 KB x R, thrice (input twice, scratch once)
     ("hw-m1024-T4096", lambda: pk.hw_series_block(131072, 4096, 1024),
-     lambda: pk._hw_fwd_layout(1024, False, 4096)),
+     lambda: pk._hw_fwd_layout(1024, False, False, 4096)),
     ("hw-m1024-T4096-save",
      lambda: pk.hw_series_block(131072, 4096, 1024, "save_resid"),
-     lambda: pk._hw_fwd_layout(1024, True, 4096)),
+     lambda: pk._hw_fwd_layout(1024, False, True, 4096)),
     # series past one chunk: the _prev neighbour doubles the input buffers
     ("css-T4096-both",
      lambda: pk.css_series_block(131072, 4096, (1, 1, 1), "both"),
@@ -1928,9 +1968,14 @@ def test_adjoint_block_width_is_bit_equal(monkeypatch, case):
     ("adjoint-garch-T4096",
      lambda: pk.garch_series_block(131072, 4096, "adjoint"),
      lambda: pk._garch_bwd_layout(4096)),
-    # Holt-Winters' adjoint runs at the HBM's pace on one register
+    # Holt-Winters' additive adjoint reads one panel and takes the table's
+    # width; the multiplicative replay's five run at the HBM's pace on one
+    # register
     ("adjoint-hw", lambda: pk.hw_series_block(131072, 960, 24, "adjoint"),
-     lambda: pk._hw_bwd_layout(24, 960)),
+     lambda: pk._hw_bwd_layout(24, False, 960)),
+    ("adjoint-hw-mult",
+     lambda: pk.hw_series_block(131072, 960, 24, "adjoint", True),
+     lambda: pk._hw_bwd_layout(24, True, 960)),
     # the order search: stage 2's one order over the cap's gathered cells
     # takes the plain rule's width, stage 1's nine orders what fits beside G
     ("adjoint-grid-stage2",
@@ -1946,8 +1991,11 @@ def test_series_block_rule_on_shapes(what, block, layout):
     r = sb // pk._SBLK
     assert sb == r * pk._SBLK and r in (1, 2, 4)
     if what in ("serving-256", "ladder-1-row", "cap-3072",
-                "adjoint-serving-256", "adjoint-ladder-1-row", "adjoint-hw"):
+                "adjoint-serving-256", "adjoint-ladder-1-row",
+                "adjoint-hw-mult"):
         assert r == 1
+    if what == "adjoint-hw":
+        assert r == pk._ADJOINT_R["hw"][False]
     if what.startswith(("adjoint-arima", "adjoint-seasonal")):
         assert r == pk._ADJOINT_R["css"]
     if what.startswith("adjoint-garch-") and what[14:] in ("chunk", "stage2"):
@@ -1964,6 +2012,8 @@ def test_series_block_rule_on_shapes(what, block, layout):
     if what == "cap-2048-takes-2":
         assert r == min(2, pk._CSS_R["sum"])
     if what == "hw-save-resid":
+        assert r == pk._HW_R[True][False]
+    if what == "hw-mult-save-resid":
         assert r <= 2
     if layout is not None:
         assert pk._vmem_bytes(layout(), r) <= pk._VMEM_BLOCK_BUDGET
@@ -1974,10 +2024,12 @@ def test_series_block_rule_on_shapes(what, block, layout):
             best = {"arima-chunk": pk._CSS_R["sum"],
                     "arima-chunk-both": pk._CSS_R["both"],
                     "css-T4096-both": pk._CSS_R["both"],
-                    "hw-save-resid": pk._HW_R[True],
-                    "hw-m1024-T4096": pk._HW_R[False],
-                    "hw-m1024-T4096-save": pk._HW_R[True],
-                    "adjoint-hw": pk._ADJOINT_R["hw"],
+                    "hw-save-resid": pk._HW_R[True][False],
+                    "hw-mult-save-resid": pk._HW_R[True][True],
+                    "hw-m1024-T4096": pk._HW_R[False][False],
+                    "hw-m1024-T4096-save": pk._HW_R[True][False],
+                    "adjoint-hw": pk._ADJOINT_R["hw"][False],
+                    "adjoint-hw-mult": pk._ADJOINT_R["hw"][True],
                     "adjoint-grid-stage2": pk._ADJOINT_R["css"],
                     **{f"adjoint-{k}": pk._ADJOINT_R[k.split("-")[0]]
                        for k in ("css-want-gy", "garch-want-gdata",
@@ -2090,7 +2142,7 @@ def test_kernel_block_sweep_cases_trace():
             outs = jax.eval_shape(functools.partial(call, r), *args)
             assert outs[-1].shape[1:] == (rows // 128, 128)
             # a panel or a plane; an adjoint's parameter planes, folded
-            assert all(o.shape[0] in ((3, 4) if mode == "adjoint"
+            assert all(o.shape[0] in ((3, 4) if mode.startswith("adjoint")
                                       else (1, tp)) for o in outs)
         seen.add((name, mode))
     assert grid == {f"{m}.{tag}" for m in ("sum", "both", "adjoint")
@@ -2098,7 +2150,11 @@ def test_kernel_block_sweep_cases_trace():
     kernels = {"css_neg_loglik", "hw_sse", "garch_neg_loglik"}
     assert {(n, m) for n, m in seen if m == "adjoint"} == {
         (n, "adjoint") for n in kernels | {"css_seasonal_neg_loglik"}}
-    assert len(seen) == 12 and {n for n, m in seen if m != "adjoint"} == kernels
+    # Holt-Winters' additive calls, and a row of the multiplicative replay
+    assert {m for n, m in seen if n == "hw_sse"} == {
+        "sum", "save_resid", "adjoint", "save_resid.mult", "adjoint.mult"}
+    assert len(seen) == 14 and {n for n, m in seen
+                                if not m.startswith("adjoint")} == kernels
 
 
 # ---------------------------------------------------------------------------
@@ -2208,7 +2264,19 @@ def _cotangent_cases():
 # objective's cotangent as a panel and the adjoint kernels read it back), f32
 # under this suite's jax_enable_x64, XLA:CPU of this container: the sha of
 # (the parameter gradient, and where the family has them the parameter and
-# data gradients of the data-perturbed branch — ``want_gy`` / ``want_gdata``)
+# data gradients of the data-perturbed branch — ``want_gy`` / ``want_gdata``).
+# The four ``hw-add-*`` digests were RE-RECORDED by PR 43 on the same host
+# (``_COTANGENT_PIN_HOST``, the scan's gradient, matched): the additive
+# adjoint reads the raw errors alone and forms ``a r_t`` where the replay
+# formed ``L_t - L_{t-1} - T_{t-1}`` (and ``r_t``, ``(1 - a) r_t`` for the
+# other two factors, the last as ``(1 - a) sum(r_t uS)``) — equal
+# algebraically, another rounding in the last place; against the scan they
+# hold the tolerance of the test above.  The digests also hold the FORM of
+# those sums: which equal form it is decides whether a row of the
+# benchmark's million exhausts its line search (PERF.md §6, PR 43) (the
+# parent's: 794754ac43b85e34, 022899e4bbecafc5, 5e3f0ec1fe2454dd,
+# ea2e77ce3985d914).  Every ``hw-mult-*``, ``css-*`` and ``garch-*`` digest
+# is the recording's
 _COTANGENT_PIN = {
     "css-plain-0-1": "83949e9651f167db",
     "css-plain-0-2": "0442ba21effdc497",
@@ -2222,10 +2290,10 @@ _COTANGENT_PIN = {
     "garch-g11-0-2": "b6a8fadac3fe0ad4",
     "garch-g11-1-1": "7a62e4568a65c167",
     "garch-g11-1-2": "ea2428b91ae3dd98",
-    "hw-add-0-1": "794754ac43b85e34",
-    "hw-add-0-2": "022899e4bbecafc5",
-    "hw-add-1-1": "5e3f0ec1fe2454dd",
-    "hw-add-1-2": "ea2e77ce3985d914",
+    "hw-add-0-1": "71098cec072acb50",
+    "hw-add-0-2": "3205a7a9b39bb4d4",
+    "hw-add-1-1": "44876bd5d08fb223",
+    "hw-add-1-2": "1bd57283ca296e35",
     "hw-mult-0-1": "25b747b5614beeae",
     "hw-mult-0-2": "901837f5bd588f9d",
     "hw-mult-1-1": "69f822208684638d",

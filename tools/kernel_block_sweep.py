@@ -5,7 +5,8 @@ forward ones, and the adjoints as the objectives call them.
     chiprun -- python tools/kernel_block_sweep.py            # on the chip
     python tools/kernel_block_sweep.py --compile-only        # here: Mosaic + VMEM
 
-For each kernel (CSS ARIMA(1,1,1), Holt-Winters additive m=24, GARCH(1,1)),
+For each kernel (CSS ARIMA(1,1,1), Holt-Winters additive m=24 — and the
+multiplicative replay as ``save_resid.mult`` / ``adjoint.mult`` — GARCH(1,1)),
 mode and panel shape of the benchmark's cells (the stage-1 chunk and the
 stage-2 compaction), R = 1, 2, 4 (``pallas_kernels.series_rows`` is bypassed
 through the call functions' private ``_r``): milliseconds a call, ns a time
@@ -145,24 +146,33 @@ def cases():
                 _planes(k1, 960, nsub, loc=10.0), 10.0 * one, 0.0 * one,
                 _planes(k3, 24, nsub, scale=0.5), 0.0 * one, 960)
 
-        for save in (False, True):
-            yield ("hw_sse", "save_resid" if save else "sum", rows, 960,
-                   hw_args,
-                   lambda r, par, f, save=save: pk._hw_fwd_call_f(
-                       False, 24, False, save, par, f, _r=r)[0])
+        # additive: the save_resid forward writes ONE panel (the raw errors)
+        # and the adjoint reads it alone; ``.mult`` keeps a row of the
+        # multiplicative replay (four panels written, five read)
+        for mode, mult, save in (("sum", False, False),
+                                 ("save_resid", False, True),
+                                 ("save_resid.mult", True, True)):
+            yield ("hw_sse", mode, rows, 960, hw_args,
+                   lambda r, par, f, save=save, mult=mult:
+                   pk._hw_fwd_call_f(False, 24, mult, save, par, f, _r=r)[0])
 
-        def hw_adj_args(key, rows=rows, nsub=nsub):
+        def hw_adj_args(key, mult, rows=rows, nsub=nsub):
+            # what _hw_ss_f_bwd holds: (f, par3, r3), or the replay's
+            # (f, par3, e3, lv3, tr3, so3), and gbar
             k0, k1, k2, k3, k4, k5 = jax.random.split(key, 6)
             par, f = hw_args(k0)
-            return ((f, pk._fold(par), _planes(k1, 960, nsub),
-                     _planes(k2, 960, nsub, loc=10.0),
-                     _planes(k3, 960, nsub, scale=0.1),
-                     _planes(k4, 960, nsub, scale=0.5)),
+            replay = (_planes(k2, 960, nsub, loc=10.0),
+                      _planes(k3, 960, nsub, scale=0.1),
+                      _planes(k4, 960, nsub, scale=0.5)) if mult else ()
+            return ((f, pk._fold(par), _planes(k1, 960, nsub), *replay),
                     jax.random.normal(k5, (rows,), jnp.float32))
 
-        yield ("hw_sse", "adjoint", rows, 960, hw_adj_args,
-               lambda r, resid, gbar: [pk._fold(pk._hw_ss_f_bwd(
-                   False, 24, False, resid, gbar, _r=r)[0])])
+        for mode, mult in (("adjoint", False), ("adjoint.mult", True)):
+            yield ("hw_sse", mode, rows, 960,
+                   functools.partial(hw_adj_args, mult=mult),
+                   lambda r, resid, gbar, mult=mult: [pk._fold(
+                       pk._hw_ss_f_bwd(False, 24, mult, resid, gbar,
+                                       _r=r)[0])])
 
         def garch_args(key, nsub=nsub, rows=rows):
             k1, k2 = jax.random.split(key)
