@@ -281,9 +281,12 @@ def _select_best_start(starts):
        alpha+beta+gamma; basins sit far apart in parameter space, so this
        comparison is float-noise-robust), ties to the earliest start.
 
+    Returns ``lockstep.Family.merge``'s pair: the merged result and the
+    rows whose choice is NOT the first start's, one ``int32`` (what the
+    later starts bought; ``None`` from one start, which chooses nothing).
     """
     if len(starts) == 1:
-        return starts[0]
+        return starts[0], None
     res = starts[0]
     xs = jnp.stack([r.x for r in starts])  # [S, B, 3]
     fs = jnp.stack([jnp.nan_to_num(r.f, nan=jnp.inf, posinf=jnp.inf)
@@ -309,7 +312,7 @@ def _select_best_start(starts):
     }
     if hasattr(res, "grad_norm"):
         merged["grad_norm"] = take("grad_norm")
-    return res._replace(**merged)
+    return res._replace(**merged), jnp.sum(sel != 0, dtype=jnp.int32)
 
 
 def forecast(params, y, period: int, n_future: int, model_type: str = "additive"):

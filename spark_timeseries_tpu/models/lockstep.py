@@ -12,6 +12,7 @@ compiled stage-1 / stage-2 pair, the gate between the two stages, and the
 
 from __future__ import annotations
 
+import contextlib
 from typing import Any, Callable, NamedTuple, Optional
 
 import jax
@@ -69,8 +70,9 @@ class Family(NamedTuple):
     # None: ``objective`` is the family's on the scan backend too
     scan_objective: Optional[Callable]
     to_natural: Callable  # optimizer space [B, d] -> reported params [B, k]
-    # per-row choice among the starts' results; None declares ONE start,
-    # whose stage 2 finalizes in its own program
+    # results -> (per-row choice among the starts' results, the rows whose
+    # choice is not the first start's: one int32, None from one start);
+    # None declares ONE start, whose stage 2 finalizes in its own program
     merge: Optional[Callable] = None
     # optimizer rows -> their straggler cap (None: no compaction).  A grid
     # of K orders optimizes K cells a panel row under a cap of its own
@@ -94,7 +96,9 @@ def finalize(res, ok, scale, to_natural=lambda x: x) -> FitResult:
 
 
 def _merged(family: Family, results):
-    return family.merge(results) if family.merge else results[0]
+    """``(merged result, rows a later start won)``: ``Family.merge``'s
+    pair, or the one start's result and ``None``."""
+    return family.merge(results) if family.merge else (results[0], None)
 
 
 def _mean_objective(family: Family, folded, rows, scale):
@@ -179,7 +183,7 @@ def fit_program(family: Family, max_iters: int, tol: float,
                 optim.batched_minimize(mean_scan, x0, (*p.series, p.scale),
                                        max_iters=max_iters, tol=tol)
                 for x0 in p.x0s]
-        out = finalize(_merged(family, results), p.ok, p.scale,
+        out = finalize(_merged(family, results)[0], p.ok, p.scale,
                        family.to_natural)
         return (out, info) if count_evals else out
 
@@ -191,7 +195,10 @@ def stage1_program(family: Family, max_iters: int, tol: float,
     """Stage 1 of the lazily compiled compact fit: the prep and, per start,
     the lockstep loop with the straggler early exit, its line search's tail
     on the stragglers' objective -> the finalized as-if-done result and
-    ``{"starts": (per start: carry, res, sub), "fin": (ok, scale)}``.
+    ``{"starts": (per start: carry, res, sub), "fin": (ok, scale)}``; where
+    several starts were merged also ``"merge_switched"``, the rows whose
+    merged result is not the first start's (one ``int32`` that :func:`fit`
+    defers to the read-back's span when tracing is on and never reads).
     Pallas backends only (:func:`fit` holds the gate)."""
 
     def run(xb, *extra):
@@ -210,9 +217,11 @@ def stage1_program(family: Family, max_iters: int, tol: float,
             starts.append({"carry": carry, "res": res1,
                            "sub": _stragglers(family, p, carry.idxc)})
             results.append(res1)
-        out = finalize(_merged(family, results), p.ok, p.scale,
-                       family.to_natural)
-        return out, {"starts": tuple(starts), "fin": (p.ok, p.scale)}
+        merged, switched = _merged(family, results)
+        aux = {"starts": tuple(starts), "fin": (p.ok, p.scale)}
+        if switched is not None:
+            aux["merge_switched"] = switched
+        return finalize(merged, p.ok, p.scale, family.to_natural), aux
 
     return run
 
@@ -241,11 +250,12 @@ def stage2_program(family: Family, max_iters: int, tol: float):
 
 
 def merge_program(family: Family):
-    """Re-merge the per-start results after a stage 2 ran."""
+    """Re-merge the per-start results after a stage 2 ran -> the finalized
+    result and ``Family.merge``'s count of the rows a later start won."""
 
     def run(results, fin):
-        return finalize(family.merge(list(results)), *fin,
-                        family.to_natural)
+        merged, switched = family.merge(list(results))
+        return finalize(merged, *fin, family.to_natural), switched
 
     return run
 
@@ -310,6 +320,17 @@ def fit(args: tuple, *, backend: str, compact: bool, max_iters: int,
     ``obs.defer`` as device handles and land on the ``fit.readback`` span
     that reads the result anyway (``stage2_iters``, ``stage2_trials``; 0
     and 0 where no stage 2 was dispatched).
+
+    A family of several starts (``len(aux["starts"]) > 1``; a one-start
+    family's span lines are as they were) also reports, tracing on only:
+    on ``fit.stage1`` ``undone_by_start`` and ``iters_by_start`` (the gate's
+    own reads, per start; ``undone`` stays their sum, ``iters`` their max);
+    on each ``fit.stage2`` ``start``, the 0-based start it finishes; a
+    ``fit.merge`` span (``starts``) around the dispatch of the re-merge that
+    follows a stage 2; and on ``fit.readback`` ``merge_switched``, the rows
+    whose merged result is not the first start's — the one ``int32`` of the
+    program whose result is RETURNED (stage 1's merge, or the re-merge that
+    replaced it), deferred once a fit and never read here.
     """
     xb = args[0]
     bsz = xb.shape[0] if cells is None else cells
@@ -321,6 +342,7 @@ def fit(args: tuple, *, backend: str, compact: bool, max_iters: int,
     with obs.span("fit.stage1", rows=bsz) as span:
         out, aux = run1(*args)
         starts = aux["starts"]
+        several = len(starts) > 1
         if obs.enabled():
             # what the span reports comes over beside ``undone``, not in
             # round trips of its own after it (a scalar read is some 1 ms)
@@ -335,10 +357,12 @@ def fit(args: tuple, *, backend: str, compact: bool, max_iters: int,
             span.set(iters=max(ks), undone=sum(undone), starts=len(starts),
                      iter_passes=sum(ks), trials=sum(trials),
                      tail_trials=sum(tail),
+                     **({"undone_by_start": tuple(undone),
+                         "iters_by_start": ks} if several else {}),
                      **_kernel_attrs(series_block, stage_attrs, bsz))
     obs.defer(stage2_iters=0, stage2_trials=0)
     results, reran, info = [], False, None
-    for start, n_undone in zip(starts, undone):
+    for i, (start, n_undone) in enumerate(zip(starts, undone)):
         carry, res = start["carry"], start["res"]
         counted = carry.ls_hist is not None
         if counted:
@@ -347,6 +371,7 @@ def fit(args: tuple, *, backend: str, compact: bool, max_iters: int,
         # skips the dispatch (the scatter of unchanged state is an identity)
         if n_undone > 0 and int(carry.k) < max_iters:
             with obs.span("fit.stage2", rows=cap,
+                          **({"start": i} if several else {}),
                           **_kernel_attrs(series_block, stage_attrs, cap)):
                 res, (iters2, trials2) = (
                     stage2()(start) if merge
@@ -356,6 +381,14 @@ def fit(args: tuple, *, backend: str, compact: bool, max_iters: int,
                 res, info = res
             reran = True
         results.append(res)
-    if reran:
-        out = merge()(tuple(results), aux["fin"]) if merge else results[0]
+    switched = aux.get("merge_switched")
+    if reran and merge:
+        # a one-start family's "merge" is its finalize: no span of its own
+        with obs.span("fit.merge", starts=len(starts)) if several \
+                else contextlib.nullcontext():
+            out, switched = merge()(tuple(results), aux["fin"])
+    elif reran:
+        out = results[0]
+    if switched is not None:
+        obs.defer(merge_switched=switched)
     return out if info is None else (out, info)

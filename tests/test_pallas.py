@@ -1000,6 +1000,53 @@ def test_fit_programs_form_no_cotangent_panel(monkeypatch, family):
         (3, [("mul", y3.shape), ("mul", y3.shape)])]
 
 
+# What each stage program computes, as a dataflow DAG (``_dag_hash``), on
+# the parent of PR 44 (``git archive e3e06aa``, the same shapes, this
+# container's jax): that PR gave a several-start family's merge a second
+# return value and its stage-1 program one more output, and a ONE-start
+# family's programs had to stay what they were — ARIMA, the seasonal orders,
+# the order grid, GARCH and the additive Holt-Winters, whose "merge" is its
+# finalize.  The multiplicative model's stage 1 gains exactly the
+# ``merge_switched`` leaf: every other output is the parent's.  A PR that
+# means to change a program re-records its line.
+_PARENT_DAG = {
+    ("arima111", "stage1"): "7638fd7c49350acf",
+    ("arima111", "inline"): "619ba31e5ca33f0d",
+    ("arima111", "stage2"): "db7b40ae1b1950c5",
+    ("sarima-airline4", "stage1"): "d496a5a60103e978",
+    ("sarima-airline4", "inline"): "e89b410f8d8ac809",
+    ("sarima-airline4", "stage2"): "584758d6364c5372",
+    ("hw-add", "stage1"): "31a63398f6c929bc",
+    ("hw-add", "inline"): "2c21ba1b533f9c39",
+    ("hw-add", "stage2"): "05e3ef9b497efa76",
+    ("garch11", "stage1"): "8c8d22c437eea059",
+    ("garch11", "inline"): "fd2ddb045539d854",
+    ("garch11", "stage2"): "716af38572ef7c22",
+    ("arima-grid3", "stage1"): "8f73780391495fd1",
+    ("arima-grid3", "inline"): "705ce5312a4620d1",
+    ("arima-grid3", "stage2"): "5aeb3b8446744b3c",
+    ("hw-mult", "stage1"): "6284d0f464699f72",
+    ("hw-mult", "inline"): "7eb401bf999fa167",
+    ("hw-mult", "stage2"): "edfa229a270e3d3c",
+}
+
+
+@pytest.mark.parametrize("family,program", sorted(_PARENT_DAG))
+def test_stage_programs_are_the_parents_dataflow(monkeypatch, family,
+                                                 program):
+    from _dag_hash import dag_hash
+
+    monkeypatch.setattr(optim, "COMPACT_MIN_BATCH", 2048)
+    _, programs = _stage_programs(family, 2048, 48)
+    fn, args, _ = programs[("stage1", "inline", "stage2").index(program)]
+    paths = [jax.tree_util.keystr(path) for path, _ in
+             jax.tree_util.tree_flatten_with_path(
+                 jax.eval_shape(fn, *args))[0]]
+    new = [i for i, path in enumerate(paths) if "merge_switched" in path]
+    assert len(new) == ((family, program) == ("hw-mult", "stage1"))
+    assert dag_hash(fn, *args, drop=new) == _PARENT_DAG[family, program]
+
+
 def test_hw_additive_gradient_moves_one_panel_each_way():
     # ISSUE 43: the additive ``save_resid`` forward writes ONE panel-sized
     # output (the raw one-step errors) and the adjoint call reads ONE
