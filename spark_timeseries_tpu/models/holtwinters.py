@@ -187,6 +187,9 @@ def fit(
         merge=lambda: _merge_starts_program(*static),
         series_block=lambda rows, mode: pk.hw_series_block(
             rows, yb.shape[1], period, mode, multiplicative),
+        # a gradient's forward writes 1 panel and its adjoint reads 1
+        # (additive: the raw errors), or 2 written and 3 read
+        # (multiplicative: the old season and L + T, read beside y)
         stage_attrs={"adjoint_panels": pk.HW_ADJOINT_PANELS[multiplicative]})
     if count_evals:
         out = (out[0], {**out[1], "n_starts": n_starts})

@@ -95,8 +95,12 @@ _ZERO = lambda: jnp.zeros((_SUBL, _LANES), jnp.float32)  # noqa: E731
 _R_CHOICES = (4, 2)  # tried widest first; 1 is today's block
 _TILE_BYTES = _SBLK * 4  # one (8, 128) f32 tile
 # what one call's pipelined blocks and scratch may take of _VMEM_PARAMS'
-# 100 MB; the rest is Mosaic's own (its internal scratch, spilled vregs)
-_VMEM_BLOCK_BUDGET = 88 * 1024 * 1024
+# 100 MB; the rest is Mosaic's own (its internal scratch, spilled vregs).
+# 88 MiB until PR 45: three panel blocks of 960 steps at R = 4 count 90.7 /
+# 91.4 MiB (the multiplicative Holt-Winters adjoint and save_resid forward),
+# compile for the v5e and run bit-equal to R = 1 (PERF.md §6, PR 45); of the
+# cells' other calls none changes its width between the two budgets
+_VMEM_BLOCK_BUDGET = 92 * 1024 * 1024
 
 
 def _vmem_bytes(layout, r: int = 1) -> int:
@@ -137,11 +141,11 @@ def series_rows(nsub: int, layout, r_best: int) -> int:
 # 14.43).  Holt-Winters by ``mult`` (PR 43, over [131072, 960]): the
 # additive adjoint reads one panel, 20.93 / 11.60 / 9.01 (30 / 33 / 51), at
 # R = 4 on the vector slots and no longer on the HBM (13.2 against 12.9 at
-# R = 2 over the 16,384-row compaction); the multiplicative replay
-# 31.80 / 27.63, five panels and R = 4 past VMEM — kept at 1, the program
-# it was measured with.  The adjoints write no panel: 11.2 ns is 8 KB at 730
-# GB/s)
-_ADJOINT_R = {"css": 4, "garch": 4, "hw": {False: 4, True: 1}}
+# R = 2 over the 16,384-row compaction); the multiplicative adjoint (PR 45)
+# reads three panels, 44.38 / 23.46 / 18.20 (55 / 68 / 104): 12 KB a step and
+# block at 675 GB/s, and 46.25 / 26.19 / 22.58 over the compaction.  The
+# adjoints write no panel: 11.2 ns is 8 KB at 730 GB/s)
+_ADJOINT_R = {"css": 4, "garch": 4, "hw": {False: 4, True: 4}}
 
 
 def _nsub(rows: int) -> int:
@@ -1900,34 +1904,69 @@ def ewma_sse(alpha, x, n_valid=None, *, interpret: bool = False):
 #   rho[slot] = (1-g) uS - a vL + gp
 # with rho a ring of seasonal adjoints.  (Three panel moves a gradient — y
 # read and r written by the forward, r read by the adjoint — where the
-# replay below moves ten; PERF.md §6, PR 43.  WHICH of the algebraically
-# equal forms these sums take is not free: in f32 the objective is jagged
-# where a series' alpha is tiny (L += a r is then a few ulp of L), the
+# replay of saved trajectories moved ten; PERF.md §6, PR 43.  WHICH of the
+# algebraically equal forms these sums take is not free: in f32 the objective
+# is jagged where a series' alpha is tiny (L += a r is a few ulp of L), the
 # gradient's last place decides where about 1% of a million fits stop, and
 # of some twenty equal forms measured on the chip — three last-place variants
 # of the replay's own among them — every one but this left 1-6 rows of the
 # benchmark's million with an exhausted line search, on the retry ladder.)
 #
-# MULTIPLICATIVE keeps the replay: its forward saves (e, L, T, S_old) and the
-# reverse pass reads them beside y — the product and quotient rules (S_t gp
-# into the level/trend adjoints, (L+T) gp into the ring, -a y/S^2 and
-# -g y/L^2 quotient terms, eps-clamp subgradients) need y, S_old, L_t and
-# L_{t-1} + T_{t-1}, the last two past one chunk from the neighbour block.
-# The two part on the STATIC ``mult`` the kernels are specialised by.
+# MULTIPLICATIVE: the forward saves TWO panels and the adjoint reads THREE.
+# Write P_t = L_{t-1} + T_{t-1} (the forward's ``lt_sum``) and S_t for the
+# ring's value before the step.  Every quantity of the reverse pass is a
+# function of (y_t, S_t, P_t) and the three parameters:
+#   e_t  = y_t - P_t S_t                  the forward's own ``yt - pred``
+#   L_t  = a y_t / max(S_t, eps) + (1-a) P_t      the forward's own ``nl``
+#          (:func:`_hw_mult_level`, ONE expression for both kernels: the
+#          recomputed level is the forward's bit for bit, so the clamp's
+#          subgradient ``l_pass = [L_t >= eps]`` is the forward's too)
+#   sc   = max(S_t, eps), ltc = max(L_t, eps), s_pass = [S_t >= eps]
+#   gp   = -2 e_t gbar on live-err steps, else 0
+#   ys   = y_t / sc, ys2 = ys / sc, yl = y_t / ltc, yl2 = yl / ltc
+#   vL   = uL + b uT - g yl2 uS l_pass
+#   da  += (ys - P_t) vL
+#   db  += (L_t - P_t) uT
+#   dg  += (yl - S_t) uS
+#   uL'  = -b uT + (1-a) vL + S_t gp
+#   uT'  = (1-b) uT + (1-a) vL + S_t gp
+#   rho[slot] = (1-g) uS - a ys2 vL s_pass + P_t gp
+# L_{t-1} and T_{t-1} are never needed apart, so no seed and, past one time
+# chunk, no neighbour block is an operand.  (Six panel moves a gradient — y
+# read and S, P written by the forward, y, S, P read by the adjoint — where
+# the replay of (e, L, T, S_old) moved ten: four written, five read;
+# PERF.md §6, PR 45.  No step is recomputed and no recursion inverted.  Of
+# the equal forms: ``e_t`` and ``L_t`` stay the forward's expressions, and
+# ``da`` / ``db`` take ``P_t`` where the replay subtracted ``L_{t-1}`` and
+# ``T_{t-1}`` one after the other.  The squares' quotients are written
+# ``(y / sc) / sc``, not ``y / (sc sc)``: Mosaic divides by a refined
+# reciprocal of the DENOMINATOR, so the second form pays four reciprocals a
+# step and the first two — 131 bundles a step for four registers against
+# 104, 2.83 ms against 2.24 over [131072, 960], where three panels at the
+# HBM's pace are 2.2.  Both forms left no row of the benchmark's 524,288 on
+# the retry ladder in any run.)
+# The two models part on the STATIC ``mult`` the kernels are specialised by.
 # Level/trend carries cross chunks through 1-slot scratches; both rings
 # (seasonal state forward, seasonal adjoint backward) persist untouched.
 
 
+def _hw_mult_level(a, yt, s, lt_sum):
+    """The multiplicative level update ``L_t`` from ``(y_t, S_t, L_{t-1} +
+    T_{t-1})``: the forward kernel steps with it and the adjoint kernel
+    recomputes the level by it, so the two agree bit for bit."""
+    return a * yt / jnp.maximum(s, 1e-12) + (1.0 - a) * lt_sum
+
+
 def _hw_fwd_kernel(m, mult, save_resid, t_limit, cs, y_ref, par_ref, l0_ref,
                    t0_ref, s0_ref, zb_ref, *refs):
-    # vjp path: what the adjoint reads — the raw errors alone (additive) or
-    # the errors and the replay trajectories (multiplicative) — + the SSE,
+    # vjp path: what the adjoint reads beside y — the raw errors alone
+    # (additive) or the old season and L + T (multiplicative) — + the SSE,
     # accumulated in the same in-kernel order as the primal variant.
     # Primal path (linesearch evals): ONLY the per-series SSE leaves the
-    # kernel — the error/trajectory stores are the HBM bill
-    r_ref = e_ref = lv_ref = tr_ref = so_ref = None
+    # kernel — the residual stores are the HBM bill
+    r_ref = so_ref = p_ref = None
     if save_resid and mult:
-        e_ref, lv_ref, tr_ref, so_ref, ss_ref, seas_ref, clt_ref = refs
+        so_ref, p_ref, ss_ref, seas_ref, clt_ref = refs
     elif save_resid:
         r_ref, ss_ref, seas_ref, clt_ref = refs
     else:
@@ -1959,7 +1998,7 @@ def _hw_fwd_kernel(m, mult, save_resid, t_limit, cs, y_ref, par_ref, l0_ref,
         lt_sum = level + trend
         if mult:
             pred = lt_sum * s
-            nl = a * yt / jnp.maximum(s, 1e-12) + (1.0 - a) * lt_sum
+            nl = _hw_mult_level(a, yt, s, lt_sum)
             snew = g * yt / jnp.maximum(nl, 1e-12) + (1.0 - g) * s
         else:
             pred = lt_sum + s
@@ -1974,10 +2013,8 @@ def _hw_fwd_kernel(m, mult, save_resid, t_limit, cs, y_ref, par_ref, l0_ref,
         if r_ref is not None:
             r_ref[tl] = jnp.where(live, err, 0.0)
         elif save_resid:
-            e_ref[tl] = e
             so_ref[tl] = s
-            lv_ref[tl] = nl_o
-            tr_ref[tl] = nt_o
+            p_ref[tl] = lt_sum
         return nl_o, nt_o, acc + e * e
 
     level, trend, acc = _fori(
@@ -1987,16 +2024,12 @@ def _hw_fwd_kernel(m, mult, save_resid, t_limit, cs, y_ref, par_ref, l0_ref,
     ss_ref[0] = ss_ref[0] + acc
 
 
-def _hw_bwd_kernel(m, mult, t_limit, cs, nchunk, hp, *refs):
-    lvp_ref = trp_ref = None
-    if not mult:  # the raw errors are all the additive reverse pass reads
+def _hw_bwd_kernel(m, mult, t_limit, cs, nchunk, *refs):
+    if mult:  # the panel, the old season and L + T
+        (y_ref, par_ref, zb_ref, gb_ref, so_ref, p_ref, gpar_ref, rho_ref,
+         clam_ref) = refs
+    else:  # the raw errors are all the additive reverse pass reads
         r_ref, par_ref, zb_ref, gb_ref, gpar_ref, rho_ref, clam_ref = refs
-    elif hp:
-        (y_ref, par_ref, l0_ref, t0_ref, zb_ref, gb_ref, lv_ref, lvp_ref,
-         tr_ref, trp_ref, so_ref, e_ref, gpar_ref, rho_ref, clam_ref) = refs
-    else:
-        (y_ref, par_ref, l0_ref, t0_ref, zb_ref, gb_ref, lv_ref, tr_ref,
-         so_ref, e_ref, gpar_ref, rho_ref, clam_ref) = refs
     c = pl.program_id(1)
     base = (nchunk - 1 - c) * cs
     a = par_ref[0]
@@ -2027,32 +2060,33 @@ def _hw_bwd_kernel(m, mult, t_limit, cs, nchunk, hp, *refs):
         uL = lamL
         uT = lamT
         if mult:
-            gp = jnp.where(live_err, -(2.0 * e_ref[tl] * gb), 0.0)
-            lfar = lvp_ref[cs - 1] if hp else 0.0
-            lp = jnp.where(tl - 1 >= 0, lv_ref[jnp.maximum(tl - 1, 0)], lfar)
-            lp = jnp.where(t - 1 >= 0, lp, l0_ref[0])
-            tfar = trp_ref[cs - 1] if hp else 0.0
-            tp_ = jnp.where(tl - 1 >= 0, tr_ref[jnp.maximum(tl - 1, 0)], tfar)
-            tp_ = jnp.where(t - 1 >= 0, tp_, t0_ref[0])
             so = so_ref[tl]
-            lt = lv_ref[tl]
+            lt_sum = p_ref[tl]
             yt = y_ref[tl]
+            # the error and the level in the forward's own expressions
+            gp = jnp.where(live_err, -(2.0 * (yt - lt_sum * so) * gb), 0.0)
+            lt = _hw_mult_level(a, yt, so, lt_sum)
             sc = jnp.maximum(so, 1e-12)
             ltc = jnp.maximum(lt, 1e-12)
             # eps-clamp subgradients: no flow through a clamped denominator
             s_pass = (so >= 1e-12).astype(jnp.float32)
             l_pass = (lt >= 1e-12).astype(jnp.float32)
-            vL = uL + b * uT - g * (yt / (ltc * ltc)) * uS * l_pass
-            da_t = (yt / sc - lp - tp_) * vL
-            dg_t = (yt / ltc - so) * uS
+            # two reciprocals a step, not four: y / sc^2 as (y / sc) / sc
+            ys = yt / sc
+            ys2 = ys / sc
+            yl = yt / ltc
+            yl2 = yl / ltc
+            vL = uL + b * uT - g * yl2 * uS * l_pass
+            da_t = (ys - lt_sum) * vL
+            dg_t = (yl - so) * uS
             new_lamL = -b * uT + (1.0 - a) * vL + so * gp
             new_lamT = (1.0 - b) * uT + (1.0 - a) * vL + so * gp
             rho_new = (
                 (1.0 - g) * uS
-                - a * (yt / (sc * sc)) * vL * s_pass
-                + (lp + tp_) * gp
+                - a * ys2 * vL * s_pass
+                + lt_sum * gp
             )
-            db_t = (lt - lp - tp_) * uT
+            db_t = (lt - lt_sum) * uT
         else:
             rt = r_ref[tl]
             gp = jnp.where(live_err, -(2.0 * rt * gb), 0.0)
@@ -2108,10 +2142,12 @@ class HWFolded:
 
 # by ``save_resid``, then ``mult``; see _CSS_R: value-only 2.58 / 1.43 / 0.87
 # ms over [131072, 960]; the additive save_resid writes one panel, 2.68 / 1.55
-# / 1.53 ms (1 GB at the HBM's pace from R = 2 on); the multiplicative one
-# writes four, 4.84 / 3.61 ms, and its ten buffers do not fit VMEM at R = 4
-# (PERF.md §6, PR 43)
-_HW_R = {False: {False: 4, True: 4}, True: {False: 4, True: 2}}
+# / 1.53 ms (1 GB at the HBM's pace from R = 2 on; PERF.md §6, PR 43); the
+# multiplicative one writes two, 4.73 / 2.86 / 2.23 ms: two divisions deep a
+# step, it waits out their latency until four chains share it, and 1.5 GB at
+# 2.23 ms is the HBM's pace (0.63 / 0.48 / 0.47-0.52 ms over the 16,384-row
+# compaction; PERF.md §6, PR 45)
+_HW_R = {False: {False: 4, True: 4}, True: {False: 4, True: 4}}
 
 
 def _hw_fwd_layout(m, mult, save_resid, t):
@@ -2120,9 +2156,9 @@ def _hw_fwd_layout(m, mult, save_resid, t):
     _, cs, _ = _time_layout(t)
     ins = [(cs, _cur), (3, _fixed), (1, _fixed), (1, _fixed), (m, _fixed),
            (1, _fixed)]
-    # what the adjoint reads — the raw errors, or the errors and the three
-    # replay trajectories — then the per-series SSE
-    saved = [(cs, _cur)] * (4 if mult else 1) if save_resid else []
+    # what the adjoint reads beside the panel — the raw errors, or the old
+    # season and L + T: 1 / 2 panels written — then the per-series SSE
+    saved = [(cs, _cur)] * (2 if mult else 1) if save_resid else []
     # scratch: the seasonal ring, level / trend
     return ins, saved + [(1, _fixed)], [m, 2]
 
@@ -2163,7 +2199,8 @@ def _hw_ss_f(interpret: bool, m: int, mult: bool, params, f: HWFolded):
     Primal (no-gradient) path: sum-only kernel — a linesearch objective
     evaluation pays one panel read and no error/trajectory stores.  The vjp
     path saves what the hand-derived adjoint reads, folded: the raw errors
-    (additive), or the errors and the replay trajectories (multiplicative).
+    (additive: 1 panel written, 1 read), or the old season and ``L + T``
+    (multiplicative: 2 written, 3 read, the panel itself the third).
     The unfolded API (:func:`hw_sse_seeded`) is a thin fold-then-delegate
     wrapper: ONE forward call, ONE adjoint.
     """
@@ -2172,7 +2209,7 @@ def _hw_ss_f(interpret: bool, m: int, mult: bool, params, f: HWFolded):
 
 
 def _hw_ss_f_fwd(interpret, m, mult, params, f):
-    # additive: (r3, ss3); multiplicative: (e3, lv3, tr3, so3, ss3)
+    # additive: (r3, ss3); multiplicative: (so3, p3, ss3)
     (*saved, ss3), par3 = _hw_fwd_call_f(interpret, m, mult, True, params, f)
     # the value is accumulated in the same in-kernel order as the primal
     # variant — see _css_ss_f: mixed accumulation orders stall rows
@@ -2180,23 +2217,20 @@ def _hw_ss_f_fwd(interpret, m, mult, params, f):
 
 
 # the panel-sized operands of the objective's adjoint call, by ``mult`` (a
-# stage span's ``adjoint_panels``): the raw errors r3 alone, or y3, the
-# replay trajectories lv3, tr3, so3, and e3
-HW_ADJOINT_PANELS = {False: 1, True: 5}
+# stage span's ``adjoint_panels``): the raw errors r3 alone, or y3, the old
+# season so3 and p3 = L + T
+HW_ADJOINT_PANELS = {False: 1, True: 3}
 
 
 def _hw_bwd_layout(m, mult, t):
     """The Holt-Winters adjoint call's blocks (see :func:`_css_bwd_layout`).
-    Additive: the raw errors, parameters, mask and the cotangent's plane.
-    Multiplicative: the panel, parameters, the two seeds, mask and the
-    plane, then the replay trajectories (level and trend with their
-    neighbours past one chunk, the season) and the errors."""
-    panel = _rev_panel(t)
-    if mult:
-        ins = (panel[:1] + [(3, _fixed)] + [(1, _fixed)] * 4 + panel + panel
-               + panel[:1] * 2)
-    else:
-        ins = panel[:1] + [(3, _fixed)] + [(1, _fixed)] * 2
+    Additive: the raw errors, parameters, mask and the cotangent's plane:
+    1 panel read.  Multiplicative: the panel, parameters, mask and the
+    plane, then the old season and ``L + T``: 3 read.  No lag reaches past
+    the step, so a series past one chunk brings no neighbour block."""
+    panel = _rev_panel(t)[:1]
+    ins = panel + [(3, _fixed)] + [(1, _fixed)] * 2 + (panel * 2 if mult
+                                                        else [])
     # scratch: the seasonal ring's adjoint, level / trend across chunks
     return ins, [(3, _fixed)], [m, 2]
 
@@ -2210,18 +2244,14 @@ def _hw_ss_f_bwd(interpret, m, mult, resid, gbar, _r=None):
     # padded series carry a zero gbar, padded time a zero error
     gb3 = _fold(gbar[:, None].astype(par3.dtype))
     _, cs, nchunk = _time_layout(f.t)
-    hp = nchunk > 1
-    if mult:
-        e3, lv3, tr3, so3 = saved
-        args = (f.y3, par3, f.l03, f.t03, f.zb3, gb3,
-                *((lv3, lv3, tr3, tr3) if hp else (lv3, tr3)), so3, e3)
-    else:
-        args = (*saved, par3, f.zb3, gb3)
+    # additive: r3 alone; multiplicative: y3, then so3 and p3
+    panels = (f.y3, *saved) if mult else saved
+    args = (panels[0], par3, f.zb3, gb3, *panels[1:])
     layout = _hw_bwd_layout(m, mult, f.t)
     # ``_r`` forces the block width (tests and the sweep)
     r = _r or series_rows(f.y3.shape[1], layout, _ADJOINT_R["hw"][mult])
     (gpar3,) = _block_call(
-        functools.partial(_hw_bwd_kernel, m, mult, f.t, cs, nchunk, hp),
+        functools.partial(_hw_bwd_kernel, m, mult, f.t, cs, nchunk),
         layout, r, interpret, args)
     # seeds and data are constants of the objective: zero cotangents
     return _unfold(gpar3, b), jax.tree_util.tree_map(jnp.zeros_like, f)

@@ -6,6 +6,7 @@ lowering (checked manually / by the driver's bench run — the interpret and
 native paths share one kernel body).
 """
 
+import dataclasses
 import functools
 
 import jax
@@ -745,6 +746,86 @@ def test_hw_multiplicative_sse_and_grad_matches_scan():
     np.testing.assert_allclose(np.asarray(g_got), np.asarray(g_ref), rtol=1e-3, atol=1e-2)
 
 
+def _hw_mult_hard_case(case):
+    """A few multiplicative rows the two-panel forward and its adjoint
+    (ISSUE 45) have to hold: ``past-one-chunk`` (T > 1024: level, trend and
+    both rings cross a time chunk, and no neighbour block is read any more),
+    ``eps-clamp`` (one hour of every day structurally ZERO, so that slot's
+    seasonal factor is 0 from its seed on — ``s_pass`` 0 there — and with
+    ``alpha`` = 1 the level is 0 after it — ``l_pass`` 0 from the RECOMPUTED
+    level) -> ``(y, params, m)``."""
+    rng = np.random.default_rng(451)
+    if case == "past-one-chunk":
+        b, t, m = 3, 1100, 24
+        y = _seasonal_panel(b, t, m, seed=452) + 25.0
+        par = rng.uniform(0.05, 0.6, (b, 3))
+    else:
+        b, t, m = 6, 64, 4
+        y = np.array(_seasonal_panel(b, t, m, seed=453)) + 25.0
+        y[:, 2::m] = 0.0
+        par = rng.uniform(0.05, 0.9, (b, 3))
+        par[:2, 0] = 1.0  # nl = y / s: 0 at the zero hour
+        par[1:3, 2] = 1.0  # snew = y / nl
+    return jnp.asarray(y), jnp.asarray(par.astype(np.float32)), m
+
+
+@pytest.mark.parametrize("case", ["past-one-chunk", "eps-clamp"])
+def test_hw_multiplicative_two_panel_gradient_matches_scan(case):
+    from spark_timeseries_tpu.models import holtwinters as hw
+
+    y, params, m = _hw_mult_hard_case(case)
+
+    def scan(P):
+        return jax.vmap(lambda pr, v: hw.sse(pr, v, m, True))(P, y)
+
+    def pal(P):
+        return pk.hw_sse(P, y, m, True, interpret=True)
+
+    ref, got = np.asarray(scan(params)), np.asarray(pal(params))
+    assert np.isfinite(ref).all() and (ref > 0).all()
+    np.testing.assert_allclose(got, ref, rtol=5e-4)
+    w = jnp.asarray(1.0 / ref)  # every row's gradient at its own scale
+    g_ref = np.asarray(jax.grad(lambda P: jnp.sum(w * scan(P)))(params))
+    g_got = np.asarray(jax.grad(lambda P: jnp.sum(w * pal(P)))(params))
+    assert np.isfinite(g_got).all() and np.abs(g_ref).max() > 0
+    np.testing.assert_allclose(g_got, g_ref, rtol=2e-3,
+                               atol=2e-3 * np.abs(g_ref).max())
+    if case == "eps-clamp":
+        # the clamps are AT WORK in these rows: the zero hour's factor is
+        # under eps at every visit, and alpha = 1 leaves a level of 0
+        f = pk.hw_prefold(y, pk.hw_seeds(y, m, True, None))
+        (so3, p3, _), _ = pk._hw_fwd_call_f(True, m, True, True, params, f)
+        so, p = (np.asarray(pk._unfold(x, y.shape[0])) for x in (so3, p3))
+        assert (so[:, 2::m] < 1e-12).all() and (so[:, 1::m] > 0.1).all()
+        lt = np.asarray(pk._hw_mult_level(params[:, :1], y, so, p))
+        assert (lt[:2, 2::m] < 1e-12).all() and (lt[3:] > 1.0).all()
+
+
+@pytest.mark.parametrize("case", ["past-one-chunk", "eps-clamp"])
+def test_hw_multiplicative_recomputed_level_is_the_forwards(monkeypatch,
+                                                            case):
+    # ISSUE 45: the adjoint recomputes L_t from (y_t, S_t, P_t = L_{t-1} +
+    # T_{t-1}) by the forward's own expression, and the clamp's subgradient
+    # hangs on it.  With beta = 0 and a zero trend seed the trend stays an
+    # exact 0, so the forward's carried level — what the replay saved as
+    # ``lv3`` — IS the next step's saved P: L_t = P_{t+1} bit for bit, and
+    # the recomputation from the two saved panels has to reproduce it
+    y, params, m = _hw_mult_hard_case(case)
+    if case == "past-one-chunk":  # two chunks, a short interpreted loop
+        monkeypatch.setattr(pk, "_CHUNK_T", 16)
+        y = y[:, :29]
+    b, t = y.shape
+    params = params.at[:, 1].set(0.0)
+    f = pk.hw_prefold(y, pk.hw_seeds(y, m, True, None))
+    f = dataclasses.replace(f, t03=jnp.zeros_like(f.t03))
+    (so3, p3, _), par3 = pk._hw_fwd_call_f(True, m, True, True, params, f)
+    assert pk._time_layout(t)[2] == (2 if case == "past-one-chunk" else 1)
+    lt3 = jax.jit(pk._hw_mult_level)(par3[0], f.y3, so3, p3)
+    lt, p = (np.asarray(x)[:t] for x in (lt3, p3))
+    assert np.isfinite(lt).all() and np.abs(lt).max() > 1.0
+    assert lt[:-1].tobytes() == p[1:].tobytes()
+
+
 @pytest.mark.parametrize("mult", [False, True])
 def test_hw_ragged_sse_and_grad_matches_scan(mult):
     from spark_timeseries_tpu.models import holtwinters as hw
@@ -1008,7 +1089,11 @@ def test_fit_programs_form_no_cotangent_panel(monkeypatch, family):
 # the order grid, GARCH and the additive Holt-Winters, whose "merge" is its
 # finalize.  The multiplicative model's stage 1 gains exactly the
 # ``merge_switched`` leaf: every other output is the parent's.  A PR that
-# means to change a program re-records its line.
+# means to change a program re-records its line: PR 45 moved the three
+# ``hw-mult`` lines (its parent's: 6284d0f464699f72, 7eb401bf999fa167,
+# edfa229a270e3d3c) — the multiplicative ``save_resid`` forward writes two
+# panels where it wrote four and the adjoint call takes three panel
+# operands and no seed where it took five and two — and no other.
 _PARENT_DAG = {
     ("arima111", "stage1"): "7638fd7c49350acf",
     ("arima111", "inline"): "619ba31e5ca33f0d",
@@ -1025,9 +1110,9 @@ _PARENT_DAG = {
     ("arima-grid3", "stage1"): "8f73780391495fd1",
     ("arima-grid3", "inline"): "705ce5312a4620d1",
     ("arima-grid3", "stage2"): "5aeb3b8446744b3c",
-    ("hw-mult", "stage1"): "6284d0f464699f72",
-    ("hw-mult", "inline"): "7eb401bf999fa167",
-    ("hw-mult", "stage2"): "edfa229a270e3d3c",
+    ("hw-mult", "stage1"): "7a3e4b9d7f71986d",
+    ("hw-mult", "inline"): "0a6f1ae43d2ee7ac",
+    ("hw-mult", "stage2"): "72b9c939507db870",
 }
 
 
@@ -1050,12 +1135,14 @@ def test_stage_programs_are_the_parents_dataflow(monkeypatch, family,
 def test_hw_additive_gradient_moves_one_panel_each_way():
     # ISSUE 43: the additive ``save_resid`` forward writes ONE panel-sized
     # output (the raw one-step errors) and the adjoint call reads ONE
-    # panel-sized operand (it); the multiplicative replay keeps its four and
-    # five.  The value a gradient pass returns is the value-only call's
+    # panel-sized operand (it).  ISSUE 45: the multiplicative forward writes
+    # TWO (the old season, L + T) and its adjoint reads THREE (them and the
+    # panel) where the replay wrote four and read five.  The value a
+    # gradient pass returns is the value-only call's
     b, t, m = 1024, 29, 4
     par = jnp.asarray(np.random.default_rng(92).uniform(
         0.05, 0.9, (b, 3)).astype(np.float32))
-    for mult, wrote, read in ((False, 1, 1), (True, 4, 5)):
+    for mult, wrote, read in ((False, 1, 1), (True, 2, 3)):
         y = _seasonal_panel(b, t, m, seed=91) + (25.0 if mult else 0.0)
         f = pk.hw_prefold(y, pk.hw_seeds(y, m, mult, None))
         sse = functools.partial(pk._hw_ss_f, True, m, mult)
@@ -1121,14 +1208,23 @@ def _fit_pin_digest(r):
 # gradient moves in its last place and a fit takes another step here and
 # there (the parent's: inline 22442193b2ba36d9 / 3aacc24fed3e51c3 / 24 / 179,
 # ragged 9227f7f8898068d1 / 70b6f731343fb122 / 24 / 179, lazy
-# be22edd45bafdb3b / 2c89d60dc07122c1 / 2015 / 22905)
+# be22edd45bafdb3b / 2c89d60dc07122c1 / 2015 / 22905).  The three
+# ``-multiplicative`` entries were RE-RECORDED by PR 45 on the same host: its
+# adjoint recomputes the error and the level from ``y``, the old season and
+# ``P = L + T`` and subtracts ``P`` where the replay subtracted ``L_{t-1}``
+# and ``T_{t-1}`` one after the other, the gradient moves in its last place
+# and a fit that stops at its noise floor stops elsewhere — of the 24 inline
+# rows one takes 37 iterations for 6 and one 16 for 8, to objectives within
+# 2e-2 of the parent's either way (the parent's: inline b7fb28aff75e13cd /
+# 9df77601ac081e4e / 24 / 177, ragged 425b60129f439f96 / 2aaefd5267317d00 /
+# 24 / 180, lazy 21be488db7502e0b / 0e15cce7da45c373 / 2048 / 19054)
 _HW_PIN = {  # params sha, objective sha, rows converged, sum of iters
     "inline-additive": ("e73277ec52d2363e", "48b6170cf3f8339b", 24, 178),
-    "inline-multiplicative": ("b7fb28aff75e13cd", "9df77601ac081e4e", 24, 177),
+    "inline-multiplicative": ("b5f2e565689ee170", "b765f1abfad164a7", 24, 212),
     "ragged-additive": ("c3e03379797cd457", "fff87034ea54a730", 24, 178),
-    "ragged-multiplicative": ("425b60129f439f96", "2aaefd5267317d00", 24, 180),
+    "ragged-multiplicative": ("32a5fc407d060400", "a61cbcaeafb08b0a", 24, 208),
     "lazy-additive": ("91b2073e4dd0292f", "ef962b3a455abed9", 2014, 22871),
-    "lazy-multiplicative": ("21be488db7502e0b", "0e15cce7da45c373", 2048, 19054),
+    "lazy-multiplicative": ("2e75e1260fdc952f", "61fd9a4b5cea9f24", 2048, 19043),
 }
 # the scan backend's digest of inline-additive there: no Pallas code in it,
 # so it tells the recording's code generator from another
@@ -1959,8 +2055,8 @@ def test_adjoint_block_width_is_bit_equal(monkeypatch, case):
     ("garch-stage2", lambda: pk.garch_series_block(16384, 1000),
      lambda: pk._garch_fwd_layout("sum", 1000)),
     # HW save_resid, additive: 1 input + 1 output (the raw errors), four
-    # buffers of 3.9 MB a register of series; the multiplicative replay's
-    # 1 input + 4 outputs are ten: never 4
+    # buffers of 3.9 MB a register of series; the multiplicative model's
+    # 1 input + 2 outputs (ISSUE 45) are six: 91.4 MiB at R = 4
     ("hw-save-resid",
      lambda: pk.hw_series_block(131072, 960, 24, "save_resid"),
      lambda: pk._hw_fwd_layout(24, False, True, 960)),
@@ -2016,13 +2112,17 @@ def test_adjoint_block_width_is_bit_equal(monkeypatch, case):
      lambda: pk.garch_series_block(131072, 4096, "adjoint"),
      lambda: pk._garch_bwd_layout(4096)),
     # Holt-Winters' additive adjoint reads one panel and takes the table's
-    # width; the multiplicative replay's five run at the HBM's pace on one
-    # register
+    # width; the multiplicative one reads three (ISSUE 45), 90.7 MiB at
+    # R = 4, and takes it too; a series past one chunk brings no neighbour
+    # block, 96 MiB of panel blocks at R = 4: two
     ("adjoint-hw", lambda: pk.hw_series_block(131072, 960, 24, "adjoint"),
      lambda: pk._hw_bwd_layout(24, False, 960)),
     ("adjoint-hw-mult",
      lambda: pk.hw_series_block(131072, 960, 24, "adjoint", True),
      lambda: pk._hw_bwd_layout(24, True, 960)),
+    ("adjoint-hw-mult-T4096",
+     lambda: pk.hw_series_block(131072, 4096, 24, "adjoint", True),
+     lambda: pk._hw_bwd_layout(24, True, 4096)),
     # the order search: stage 2's one order over the cap's gathered cells
     # takes the plain rule's width, stage 1's nine orders what fits beside G
     ("adjoint-grid-stage2",
@@ -2038,17 +2138,19 @@ def test_series_block_rule_on_shapes(what, block, layout):
     r = sb // pk._SBLK
     assert sb == r * pk._SBLK and r in (1, 2, 4)
     if what in ("serving-256", "ladder-1-row", "cap-3072",
-                "adjoint-serving-256", "adjoint-ladder-1-row",
-                "adjoint-hw-mult"):
+                "adjoint-serving-256", "adjoint-ladder-1-row"):
         assert r == 1
     if what == "adjoint-hw":
         assert r == pk._ADJOINT_R["hw"][False]
+    if what == "adjoint-hw-mult":
+        assert r == pk._ADJOINT_R["hw"][True]
     if what.startswith(("adjoint-arima", "adjoint-seasonal")):
         assert r == pk._ADJOINT_R["css"]
     if what.startswith("adjoint-garch-") and what[14:] in ("chunk", "stage2"):
         assert r == pk._ADJOINT_R["garch"]
     if what in ("adjoint-css-want-gy", "adjoint-garch-want-gdata",
-                "adjoint-css-T4096", "adjoint-garch-T4096"):
+                "adjoint-css-T4096", "adjoint-garch-T4096",
+                "adjoint-hw-mult-T4096"):
         assert r == 2
     if what == "adjoint-grid-stage2":
         assert sb == pk.css_series_block(73728, 999, (2, 1, 2), "adjoint")
@@ -2061,7 +2163,7 @@ def test_series_block_rule_on_shapes(what, block, layout):
     if what == "hw-save-resid":
         assert r == pk._HW_R[True][False]
     if what == "hw-mult-save-resid":
-        assert r <= 2
+        assert r == pk._HW_R[True][True]
     if layout is not None:
         assert pk._vmem_bytes(layout(), r) <= pk._VMEM_BLOCK_BUDGET
         assert pk._VMEM_BLOCK_BUDGET < pk._VMEM_PARAMS.vmem_limit_bytes
@@ -2077,6 +2179,7 @@ def test_series_block_rule_on_shapes(what, block, layout):
                     "hw-m1024-T4096-save": pk._HW_R[True][False],
                     "adjoint-hw": pk._ADJOINT_R["hw"][False],
                     "adjoint-hw-mult": pk._ADJOINT_R["hw"][True],
+                    "adjoint-hw-mult-T4096": pk._ADJOINT_R["hw"][True],
                     "adjoint-grid-stage2": pk._ADJOINT_R["css"],
                     **{f"adjoint-{k}": pk._ADJOINT_R[k.split("-")[0]]
                        for k in ("css-want-gy", "garch-want-gdata",
@@ -2197,7 +2300,7 @@ def test_kernel_block_sweep_cases_trace():
     kernels = {"css_neg_loglik", "hw_sse", "garch_neg_loglik"}
     assert {(n, m) for n, m in seen if m == "adjoint"} == {
         (n, "adjoint") for n in kernels | {"css_seasonal_neg_loglik"}}
-    # Holt-Winters' additive calls, and a row of the multiplicative replay
+    # Holt-Winters' additive calls, and the multiplicative model's pair
     assert {m for n, m in seen if n == "hw_sse"} == {
         "sum", "save_resid", "adjoint", "save_resid.mult", "adjoint.mult"}
     assert len(seen) == 14 and {n for n, m in seen
@@ -2322,8 +2425,14 @@ def _cotangent_cases():
 # those sums: which equal form it is decides whether a row of the
 # benchmark's million exhausts its line search (PERF.md §6, PR 43) (the
 # parent's: 794754ac43b85e34, 022899e4bbecafc5, 5e3f0ec1fe2454dd,
-# ea2e77ce3985d914).  Every ``hw-mult-*``, ``css-*`` and ``garch-*`` digest
-# is the recording's
+# ea2e77ce3985d914).  The four ``hw-mult-*`` digests were RE-RECORDED by PR
+# 45 on the same host: the multiplicative adjoint reads ``y``, the old
+# season and ``P = L + T``, recomputes the error and the level in the
+# forward's own expressions and forms ``y / sc - P`` and ``L - P`` where the
+# replay formed ``y / sc - L_{t-1} - T_{t-1}`` and ``L - L_{t-1} - T_{t-1}``
+# (the parent's: 25b747b5614beeae, 901837f5bd588f9d, 69f822208684638d,
+# cdb183f37db397a9).  Every ``css-*`` and ``garch-*`` digest is the
+# recording's
 _COTANGENT_PIN = {
     "css-plain-0-1": "83949e9651f167db",
     "css-plain-0-2": "0442ba21effdc497",
@@ -2341,10 +2450,10 @@ _COTANGENT_PIN = {
     "hw-add-0-2": "3205a7a9b39bb4d4",
     "hw-add-1-1": "44876bd5d08fb223",
     "hw-add-1-2": "1bd57283ca296e35",
-    "hw-mult-0-1": "25b747b5614beeae",
-    "hw-mult-0-2": "901837f5bd588f9d",
-    "hw-mult-1-1": "69f822208684638d",
-    "hw-mult-1-2": "cdb183f37db397a9",
+    "hw-mult-0-1": "2d070198e90dce8a",
+    "hw-mult-0-2": "1db70821a6c1eb10",
+    "hw-mult-1-1": "c078bb7d569516c7",
+    "hw-mult-1-2": "f2dd645fd48e2971",
 }
 # the scan backend's digest of one case there: no Pallas code in it, so it
 # tells the recording's code generator from another

@@ -6,7 +6,7 @@ forward ones, and the adjoints as the objectives call them.
     python tools/kernel_block_sweep.py --compile-only        # here: Mosaic + VMEM
 
 For each kernel (CSS ARIMA(1,1,1), Holt-Winters additive m=24 — and the
-multiplicative replay as ``save_resid.mult`` / ``adjoint.mult`` — GARCH(1,1)),
+multiplicative model's as ``save_resid.mult`` / ``adjoint.mult`` — GARCH(1,1)),
 mode and panel shape of the benchmark's cells (the stage-1 chunk and the
 stage-2 compaction), R = 1, 2, 4 (``pallas_kernels.series_rows`` is bypassed
 through the call functions' private ``_r``): milliseconds a call, ns a time
@@ -147,8 +147,9 @@ def cases():
                 _planes(k3, 24, nsub, scale=0.5), 0.0 * one, 960)
 
         # additive: the save_resid forward writes ONE panel (the raw errors)
-        # and the adjoint reads it alone; ``.mult`` keeps a row of the
-        # multiplicative replay (four panels written, five read)
+        # and the adjoint reads it alone; ``.mult`` is the multiplicative
+        # model's pair (the old season and L + T written, the panel and the
+        # two read)
         for mode, mult, save in (("sum", False, False),
                                  ("save_resid", False, True),
                                  ("save_resid.mult", True, True)):
@@ -157,15 +158,15 @@ def cases():
                    pk._hw_fwd_call_f(False, 24, mult, save, par, f, _r=r)[0])
 
         def hw_adj_args(key, mult, rows=rows, nsub=nsub):
-            # what _hw_ss_f_bwd holds: (f, par3, r3), or the replay's
-            # (f, par3, e3, lv3, tr3, so3), and gbar
-            k0, k1, k2, k3, k4, k5 = jax.random.split(key, 6)
+            # what _hw_ss_f_bwd holds: (f, par3, r3), or the multiplicative
+            # (f, par3, so3, p3), and gbar
+            k0, k1, k2, k3 = jax.random.split(key, 4)
             par, f = hw_args(k0)
-            replay = (_planes(k2, 960, nsub, loc=10.0),
-                      _planes(k3, 960, nsub, scale=0.1),
-                      _planes(k4, 960, nsub, scale=0.5)) if mult else ()
-            return ((f, pk._fold(par), _planes(k1, 960, nsub), *replay),
-                    jax.random.normal(k5, (rows,), jnp.float32))
+            saved = ((_planes(k1, 960, nsub, scale=0.1, loc=1.0),
+                      _planes(k2, 960, nsub, loc=10.0)) if mult
+                     else (_planes(k1, 960, nsub),))
+            return ((f, pk._fold(par), *saved),
+                    jax.random.normal(k3, (rows,), jnp.float32))
 
         for mode, mult in (("adjoint", False), ("adjoint.mult", True)):
             yield ("hw_sse", mode, rows, 960,
