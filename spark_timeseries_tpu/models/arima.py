@@ -585,16 +585,49 @@ def fit(
 
 
 @functools.partial(jax.tree_util.register_dataclass,
-                   data_fields=["y3", "zb3"], meta_fields=["t"])
+                   data_fields=["y3", "zb3", "y_rows"], meta_fields=["t"])
 @dataclasses.dataclass(frozen=True)
 class _CssFolded:
     """A differenced panel in the CSS kernel layout
     (``pallas_kernels.css_prefold``); ``t`` is its true length (static: it
-    rides the treedef through a ``jit`` boundary)."""
+    rides the treedef through a ``jit`` boundary); ``y_rows`` the same panel
+    ``pallas_kernels.series_major``, what the stragglers are gathered from
+    (``None`` on a gathered subset)."""
 
     y3: jax.Array
     zb3: jax.Array
     t: int
+    y_rows: Optional[jax.Array] = None
+
+    @classmethod
+    def of(cls, y, order: Order, nvd, lags) -> "_CssFolded":
+        """The aligned panel ``y`` folded FIRST and differenced at ``lags``
+        in that layout: the differences are shifts of the folded panel's
+        major axis, and the init sweeps and every optimizer evaluation
+        share the result."""
+        from ..ops import pallas_kernels as pk
+
+        y3, zb3 = pk.css_prefold(y, order, nvd, lags=lags)
+        return cls(y3, zb3, y.shape[1] - sum(lags), pk.series_major(y3))
+
+    def take(self, idxc) -> "_CssFolded":
+        """The series ``idxc`` (``lockstep.Family.take``)."""
+        from ..ops import pallas_kernels as pk
+
+        return _CssFolded(pk.take_rows(self.y_rows, idxc),
+                          pk.take_series(self.zb3, idxc), self.t)
+
+
+def _row_major(yb, align_mode, d: int, D: int = 0, s: int = 0):
+    """``(yd, nvd)``: the aligned panel differenced row by row, and its
+    valid lengths — what the scan objective and
+    :func:`hannan_rissanen_batched` read.  The Pallas backends form the same
+    differences in the folded layout (``pallas_kernels.css_prefold``), and
+    nothing panel-sized there reads this one."""
+    ya, nv0 = maybe_align(yb, align_mode)  # ragged: NaN head/tail
+    yd = jax.vmap(
+        lambda v: _difference_seasonal(_difference(v, d), D, s))(ya)
+    return yd, nv0 - d - D * s
 
 
 def _css_family(order: Order, include_intercept: bool, backend: str,
@@ -604,28 +637,31 @@ def _css_family(order: Order, include_intercept: bool, backend: str,
 
     p, d, q = order
     k = _n_params(order, include_intercept)
+    on_kernels = backend in lockstep.PALLAS
     interp = backend == "pallas-interpret"
 
     def prep(yb, init_params=None):
+        bsz, n = yb.shape[0], yb.shape[1] - d
+        series, folded = (), ()
         with jax.named_scope("arima.align_and_difference"):
-            ya, nv0 = maybe_align(yb, align_mode)  # ragged: NaN head/tail
-            yd = jax.vmap(lambda v: _difference(v, d))(ya)
-            nvd = nv0 - d  # valid length after differencing
-        folded = ()
-        if backend in lockstep.PALLAS:
-            # the init sweeps and every optimizer evaluation share this
-            # layout
-            folded = _CssFolded(*_pk.css_prefold(yd, order, nvd),
-                                yd.shape[1])
+            if on_kernels:
+                ya, nv0 = maybe_align(yb, align_mode)  # ragged: NaN head/tail
+                nvd = nv0 - d  # valid length after differencing
+                folded = _CssFolded.of(ya, order, nvd, (1,) * d)
+            else:
+                series = _row_major(yb, align_mode, d)
+                nvd = series[1]
         with jax.named_scope("arima.hannan_rissanen_init"):
             if has_init:
-                init = jnp.broadcast_to(init_params, (yd.shape[0], k))
-            elif backend in lockstep.PALLAS and _pk.hr_structural_ok(p, q):
+                init = jnp.broadcast_to(init_params, (bsz, k))
+            elif on_kernels and _pk.hr_structural_ok(p, q):
                 # fused two-sweep moment kernels: same normal equations,
                 # ~15x less HBM traffic than the shifted-reduce construction
-                init = _pk.hr_init(yd, order, include_intercept, nvd,
-                                   interpret=interp, y3=folded.y3)
+                init = _pk.hr_init_folded(folded.y3, bsz, n, order,
+                                          include_intercept, nvd,
+                                          interpret=interp)
             else:
+                yd = (series or _row_major(yb, align_mode, d))[0]
                 init = hannan_rissanen_batched(yd, order, include_intercept,
                                                nvd)
         # too-short series cannot be fit: need lags + a few dof.  The gate
@@ -638,8 +674,8 @@ def _css_family(order: Order, include_intercept: bool, backend: str,
             # nvd >= 4*(p+q+1) ensures m would be p+q+1 either way, keeping
             # padded and trimmed inits identical inside the supported region
             ok = ok & (nvd >= 4 * (p + q + 1))
-        n_eff = jnp.maximum(nvd - p, 1).astype(yd.dtype)
-        return lockstep.Prepared((init,), ok, n_eff, (yd, nvd), folded,
+        n_eff = jnp.maximum(nvd - p, 1).astype(yb.dtype)
+        return lockstep.Prepared((init,), ok, n_eff, series, folded,
                                  (nvd,), uniform=align_mode == "dense")
 
     def objective(folded, rows):
@@ -653,7 +689,7 @@ def _css_family(order: Order, include_intercept: bool, backend: str,
         return css_neg_loglik(pr, yv, order, include_intercept, n)
 
     return lockstep.Family(backend, prep, objective, scan_objective,
-                           lambda x: x)
+                           lambda x: x, take=_CssFolded.take)
 
 
 def _sarima_family(order: Order, seasonal: Seasonal, include_intercept: bool,
@@ -673,35 +709,40 @@ def _sarima_family(order: Order, seasonal: Seasonal, include_intercept: bool,
     k = _n_params_seasonal(order, seasonal, include_intercept)
     p_full, q_full, d_full = seasonal_lag_span(order, seasonal)
     ar, ma = seasonal_lag_sets(order, seasonal)
+    on_kernels = backend in lockstep.PALLAS
     interp = backend == "pallas-interpret"
 
     def prep(yb, init_params=None):
+        bsz, n = yb.shape[0], yb.shape[1] - d_full
+        series, folded = (), ()
         with jax.named_scope("arima.sarima_align_and_difference"):
-            ya, nv0 = maybe_align(yb, align_mode)  # ragged: NaN head/tail
-            yd = jax.vmap(
-                lambda v: _difference_seasonal(_difference(v, d), D, s))(ya)
-            nvd = nv0 - d_full  # valid length after both differencings
-        folded = ()
-        if backend in lockstep.PALLAS:
-            folded = _CssFolded(
-                *_pk.css_prefold(yd, (p_full, 0, q_full), nvd), yd.shape[1])
+            if on_kernels:
+                ya, nv0 = maybe_align(yb, align_mode)  # ragged: NaN head/tail
+                nvd = nv0 - d_full  # valid length after both differencings
+                folded = _CssFolded.of(ya, (p_full, 0, q_full), nvd,
+                                       (1,) * d + (s,) * D)
+            else:
+                series = _row_major(yb, align_mode, d, D, s)
+                nvd = series[1]
         with jax.named_scope("arima.sarima_init"):
             if has_init:
-                init = jnp.broadcast_to(init_params, (yd.shape[0], k))
+                init = jnp.broadcast_to(init_params, (bsz, k))
             else:
-                if backend in lockstep.PALLAS and _pk.hr_structural_ok(p, q):
-                    base = _pk.hr_init(yd, (p, 0, q), include_intercept, nvd,
-                                       interpret=interp, y3=folded.y3)
+                if on_kernels and _pk.hr_structural_ok(p, q):
+                    base = _pk.hr_init_folded(folded.y3, bsz, n, (p, 0, q),
+                                              include_intercept, nvd,
+                                              interpret=interp)
                 else:
+                    yd = (series or _row_major(yb, align_mode, d, D, s))[0]
                     base = hannan_rissanen_batched(
                         yd, (p, 0, q), include_intercept, nvd)
                 init = jnp.concatenate(
-                    [base, jnp.zeros((yd.shape[0], P + Q), yd.dtype)], axis=1)
+                    [base, jnp.zeros((bsz, P + Q), yb.dtype)], axis=1)
         ok = nvd >= p_full + q_full + max(p_full + q_full + 1, 1) + k + 2
         if not has_init:
             ok = ok & (nvd >= 4 * (p + q + 1))
-        n_eff = jnp.maximum(nvd - p_full, 1).astype(yd.dtype)
-        return lockstep.Prepared((init,), ok, n_eff, (yd, nvd), folded,
+        n_eff = jnp.maximum(nvd - p_full, 1).astype(yb.dtype)
+        return lockstep.Prepared((init,), ok, n_eff, series, folded,
                                  (nvd,), uniform=align_mode == "dense")
 
     def objective(folded, rows):
@@ -716,7 +757,7 @@ def _sarima_family(order: Order, seasonal: Seasonal, include_intercept: bool,
                                  n)
 
     return lockstep.Family(backend, prep, objective, scan_objective,
-                           lambda x: x)
+                           lambda x: x, take=_CssFolded.take)
 
 
 def _family(order: Order, seasonal: Optional[Seasonal], *args):
@@ -738,7 +779,9 @@ def _fit_program(order: Order, include_intercept: bool, method: str,
 
     def run(yb, init_params=None):
         prepared = family.prep(yb, init_params)
-        (init,), ok, (yd, nvd) = prepared.x0s, prepared.ok, prepared.series
+        (init,), ok = prepared.x0s, prepared.ok
+        # the start's likelihood is the scan's on every backend
+        yd, nvd = prepared.series or _row_major(yb, align_mode, order[1])
         nll = jax.vmap(
             lambda pr, v, n: css_neg_loglik(pr, v, order, include_intercept, n)
         )(init, yd, nvd)
@@ -1059,12 +1102,14 @@ def _grid_family(specs, include_intercept: bool, backend: str,
     any_seasonal = any(i["seasonal"] is not None for i in infos)
     on_kernels = backend in lockstep.PALLAS
     interp = backend == "pallas-interpret"
+    def sig(info):  # an order's differencing signature beyond the shared d
+        return (info["D"], info["s"]) if info["D"] else (0, 0)
+
     # the groups of orders that share a differenced panel, in spec order
     # (one per differencing signature: grid_diff_cache_keys of them)
     by_sig: dict = {}
     for g, info in enumerate(infos):
-        by_sig.setdefault((info["D"], info["s"]) if info["D"] else (0, 0),
-                          []).append(g)
+        by_sig.setdefault(sig(info), []).append(g)
 
     def prep(yb):
         bsz, t_len = yb.shape
@@ -1076,6 +1121,7 @@ def _grid_family(specs, include_intercept: bool, backend: str,
         # into the group's common length n (the scan's n_valid masking
         # zeroes the pad, so the embedded recursion sees the bytes a
         # per-order fit of length n - D*s would)
+        @functools.cache
         def differenced(D: int, s: int):
             with jax.named_scope("arima.grid_difference"):
                 yd = jax.vmap(lambda v: _difference(v, d))(ya)
@@ -1085,19 +1131,19 @@ def _grid_family(specs, include_intercept: bool, backend: str,
                     yd = jnp.pad(yd, ((0, 0), (n - yd.shape[1], 0)))
             return yd
 
-        yds = {sig: differenced(*sig) for sig in by_sig}
-        folded = _GridPanels(tuple(yds.values()))
         if on_kernels:
-            # ONE fold for the init sweeps and every evaluation of every
-            # order; each order conditions on its own AR depth
-            (yd,) = yds.values()
+            # plain orders of one d (grid_kernels_refusal): ONE fold for the
+            # init sweeps and every evaluation of every order, differenced
+            # in the folded layout as a plain fit's panel is; each order
+            # conditions on its own AR depth
             folded = _pk.css_grid_prefold(
-                yd, [i["p_full"] for i in infos], nv0 - d)
+                ya, [i["p_full"] for i in infos], nv0 - d, lags=(1,) * d)
+        else:
+            folded = _GridPanels(tuple(differenced(*sig) for sig in by_sig))
 
         inits, oks, n_effs, nvds = [], [], [], []
         for info in infos:
             p, _, q = info["order"]
-            yd = yds[(info["D"], info["s"]) if info["D"] else (0, 0)]
             nvd = nv0 - info["d_full"]
             with jax.named_scope("arima.grid_init"):
                 # non-seasonal HR warm start on the (fully) differenced
@@ -1106,15 +1152,17 @@ def _grid_family(specs, include_intercept: bool, backend: str,
                 # embedding cannot change HR's static long-AR order m
                 # (the nvd >= 4*(p+q+1) gate pins m = p+q+1 either way).
                 if on_kernels and _pk.hr_structural_ok(p, q):
-                    base = _pk.hr_init(yd, (p, 0, q), include_intercept, nvd,
-                                       interpret=interp, y3=folded.y3)
+                    base = _pk.hr_init_folded(folded.y3, bsz, n, (p, 0, q),
+                                              include_intercept, nvd,
+                                              interpret=interp)
                 else:
                     base = hannan_rissanen_batched(
-                        yd, (p, 0, q), include_intercept, nvd)
+                        differenced(*sig(info)), (p, 0, q),
+                        include_intercept, nvd)
                 if info["P"] + info["Q"]:
                     base = jnp.concatenate(
                         [base, jnp.zeros((bsz, info["P"] + info["Q"]),
-                                         yd.dtype)], axis=1)
+                                         yb.dtype)], axis=1)
             # zero-pad to k_max: the objective never reads the pad, so its
             # gradient (and therefore its trajectory) stays exactly 0
             inits.append(jnp.pad(base, ((0, 0), (0, k_max - info["k"]))))
@@ -1122,7 +1170,7 @@ def _grid_family(specs, include_intercept: bool, backend: str,
             ok = nvd >= pf + qf + max(pf + qf + 1, 1) + k + 2
             oks.append(ok & (nvd >= 4 * (p + q + 1)))
             # optimize the MEAN log-likelihood (lockstep.Prepared.scale)
-            n_effs.append(jnp.maximum(nvd - pf, 1).astype(yd.dtype))
+            n_effs.append(jnp.maximum(nvd - pf, 1).astype(yb.dtype))
             nvds.append(nvd)
         ne = jnp.concatenate(n_effs)
         # what the objective reads cell by cell: valid length, effective

@@ -60,7 +60,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
-from typing import Tuple
+from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -284,6 +284,24 @@ def take_series(folded, idxc):
     return jax.tree_util.tree_map(
         lambda x3: x3.reshape(x3.shape[0], -1)[:, idxc].reshape(
             x3.shape[0], nb, _LANES), folded)
+
+
+def series_major(x3d):
+    """A folded panel's series as ROWS ``[Bp, n]``, materialised once, ahead
+    of the loops that gather stragglers out of it (:func:`take_rows`).  A
+    column gather of the folded panel costs a transpose of the WHOLE panel
+    per call — XLA's TPU gather wants its slices minor — and inside a
+    lockstep loop nothing hoists it (PERF.md §6, PR 46: until then the
+    row-major differencing's by-product served, unasked)."""
+    return jax.lax.optimization_barrier(
+        _unfold(x3d, x3d.shape[1] * _LANES))
+
+
+def take_rows(y_rows, idxc):
+    """The series ``idxc`` (a multiple of 1024 of them) of a panel kept
+    :func:`series_major`, folded: :func:`take_series`' columns of its
+    ``y3``, read as rows."""
+    return _fold(y_rows[idxc])
 
 
 def _bs(n0: int, imap, r: int = 1):
@@ -739,25 +757,51 @@ def _css_ss_f_bwd(p, q, interpret, t, b, resid, gbar, _r=None):
 _css_ss_f.defvjp(_css_ss_f_fwd, _css_ss_f_bwd, symbolic_zeros=True)
 
 
-def css_prefold(yd, order: Order, n_valid=None):
-    """Fold a differenced panel into the CSS kernel layout ONCE ->
-    ``(y3, zb3)`` for :func:`css_neg_loglik_folded`.
+def css_prefold(y, order: Order, n_valid=None, *, lags=()):
+    """Fold a panel into the CSS kernel layout ONCE -> ``(y3, zb3)`` for
+    :func:`css_neg_loglik_folded`, differencing it on the way at ``lags``.
 
     The fit objective runs hundreds of evaluations inside one
     ``lax.while_loop``; folding outside the loop keeps the [B, T]
     zero-mask + layout transpose off every evaluation (XLA does not
     reliably hoist them out of the loop body).  ``order``'s ``p`` may be a
     lag set: the conditioning depth is its largest lag.
+
+    The panel is folded FIRST.  With time the major axis, the differences
+    still to take — ``lags`` in order, ``(1,) * d + (s,) * D`` of an
+    undifferenced panel — are shifts of whole ``(8, 128)`` tiles, and they,
+    the ``t >= start`` mask and the zeros of the kernel's padded tail are
+    ONE pass over the folded panel: the f32 subtractions that ``v[k:] -
+    v[:-k]`` forms lag after lag on a row, on the same pairs, so an already
+    differenced panel is the ``lags=()`` case and not a second path.
+    ``n_valid`` counts the DIFFERENCED series' live tail.
     """
     p = _span(_lags(order[0]))
-    b, n = yd.shape
-    nv = jnp.full((b,), n, yd.dtype) if n_valid is None else n_valid.astype(yd.dtype)
+    b, n = y.shape[0], y.shape[1] - sum(lags)
+    nv = jnp.full((b,), n, y.dtype) if n_valid is None else n_valid.astype(y.dtype)
     start = n - nv
-    t_idx = jnp.arange(n, dtype=yd.dtype)
-    ydz = jnp.where(t_idx[None, :] >= start[:, None], yd, 0.0)
     tp, _, _ = _time_layout(n)
-    y3 = _fold(jnp.pad(ydz, ((0, 0), (0, tp - n))))
-    zb3 = _fold((start + p).astype(yd.dtype)[:, None])
+    # what the v5e compiler makes of it (PERF.md §6, PR 46): without the
+    # barrier it moves every lag's slice above the transpose and transposes
+    # the panel once a slice; and it fuses no producer into a ``pad``, so a
+    # padded result is a pass of its own — the tail's rows read the first
+    # row instead (any row that exists: the mask zeroes them)
+    y3 = jax.lax.optimization_barrier(_fold(y))
+    if tp > n:
+        y3 = jnp.concatenate(
+            [y3, jnp.broadcast_to(y3[:1], (tp - n, *y3.shape[1:]))])
+
+    def diff(lags, off):
+        # rows off .. off + tp of the panel differenced at ``lags``, as one
+        # expression over slices of the folded panel
+        if not lags:
+            return y3[off:off + tp]
+        return diff(lags[:-1], off + lags[-1]) - diff(lags[:-1], off)
+
+    t_idx = jnp.arange(tp, dtype=y.dtype)[:, None, None]
+    live = (t_idx >= _fold(start[:, None])) & (t_idx < n)
+    y3 = jnp.where(live, diff(tuple(lags), 0), 0.0)
+    zb3 = _fold((start + p).astype(y.dtype)[:, None])
     return y3, zb3
 
 
@@ -910,19 +954,23 @@ def css_neg_loglik(params, yd, order: Order, include_intercept: bool,
 
 
 @functools.partial(jax.tree_util.register_dataclass,
-                   data_fields=["y3", "zb4"], meta_fields=["t", "b", "k"])
+                   data_fields=["y3", "zb4", "y_rows"],
+                   meta_fields=["t", "b", "k"])
 @dataclasses.dataclass(frozen=True)
 class CssGridFolded:
     """A differenced panel folded once for ``k`` orders a series
     (:func:`css_grid_prefold`): ``y3 [tp, Bp/128, 128]``, the orders'
     first live positions ``zb4 [1, k, Bp/128, 128]``; ``t`` the panel's true
-    length and ``b`` its rows (static: they ride the treedef)."""
+    length and ``b`` its rows (static: they ride the treedef); ``y_rows``
+    the panel :func:`series_major`, what :func:`take_cells` gathers from
+    (``None`` on a gathered subset: nothing is gathered from it again)."""
 
     y3: jax.Array
     zb4: jax.Array
     t: int
     b: int
     k: int
+    y_rows: Optional[jax.Array] = None
 
 
 def _fold_cells(x2d, k: int):
@@ -939,14 +987,14 @@ def _unfold_cells(x4d, b: int):
     return x4d.reshape(n, k, -1)[:, :, :b].transpose(1, 2, 0).reshape(k * b, n)
 
 
-def css_grid_prefold(yd, depths, n_valid=None) -> CssGridFolded:
-    """Fold a differenced panel ONCE for a grid of ``len(depths)`` orders,
-    order ``g`` conditioning on its own ``depths[g]`` steps
-    (:func:`css_prefold`'s panel, a mask start per cell)."""
-    b, n = yd.shape
-    y3, start3 = css_prefold(yd, (0, 0, 0), n_valid)
+def css_grid_prefold(y, depths, n_valid=None, *, lags=()) -> CssGridFolded:
+    """Fold a panel ONCE for a grid of ``len(depths)`` orders, order ``g``
+    conditioning on its own ``depths[g]`` steps (:func:`css_prefold`'s
+    panel, differenced at its ``lags``, and a mask start per cell)."""
+    b, n = y.shape[0], y.shape[1] - sum(lags)
+    y3, start3 = css_prefold(y, (0, 0, 0), n_valid, lags=lags)
     zb4 = jnp.stack([start3 + float(d) for d in depths], axis=1)
-    return CssGridFolded(y3, zb4, n, b, len(depths))
+    return CssGridFolded(y3, zb4, n, b, len(depths), series_major(y3))
 
 
 def take_cells(folded: CssGridFolded, idxc) -> CssGridFolded:
@@ -955,10 +1003,9 @@ def take_cells(folded: CssGridFolded, idxc) -> CssGridFolded:
     of order ``i // b`` (:func:`take_series` over cells)."""
     rows, orders = idxc % folded.b, idxc // folded.b
     nb = idxc.shape[0] // _LANES
-    tp = folded.y3.shape[0]
-    y3 = folded.y3.reshape(tp, -1)[:, rows].reshape(tp, nb, _LANES)
     zb = folded.zb4.reshape(folded.k, -1)[orders, rows]
-    return CssGridFolded(y3, zb.reshape(1, 1, nb, _LANES), folded.t,
+    return CssGridFolded(take_rows(folded.y_rows, rows),
+                         zb.reshape(1, 1, nb, _LANES), folded.t,
                          idxc.shape[0], 1)
 
 
@@ -2624,45 +2671,51 @@ def hr_structural_ok(p: int, q: int) -> bool:
     return 0 <= p <= 8 and 0 <= q <= 8
 
 
-@_scoped("pallas.hr_init")
 def hr_init(yd, order: Order, include_intercept: bool, n_valid=None, *,
-            interpret: bool = False, y3=None):
+            interpret: bool = False):
     """Batched Hannan-Rissanen startup values ``[B, k]`` on fused kernels.
 
     Matches ``models.arima.hannan_rissanen_batched`` (identical weighted
     normal equations and ridge stabilization) in two panel sweeps: stage-1
     AR(m) moments -> solve -> stage-2 moments with on-the-fly residuals ->
     solve.  ``yd``: differenced panel with the invalid prefix zeroed.
-
-    ``y3``: optionally the already-folded panel (:func:`css_prefold`'s
-    first output — its extra zero at ``start - 1`` is never read by a
-    weighted row), so one fit folds the panel exactly once.
     """
+    b, t = yd.shape
+    tp, _, _ = _time_layout(t)
+    return hr_init_folded(_fold(jnp.pad(yd, ((0, 0), (0, tp - t)))), b, t,
+                          order, include_intercept, n_valid,
+                          interpret=interpret)
+
+
+@_scoped("pallas.hr_init")
+def hr_init_folded(y3, b: int, t: int, order: Order, include_intercept: bool,
+                   n_valid=None, *, interpret: bool = False):
+    """:func:`hr_init` from the already-folded panel of ``b`` series of
+    true length ``t`` (:func:`css_prefold`'s first output — its extra zero
+    at ``start - 1`` is never read by a weighted row), so one fit folds the
+    panel exactly once and nothing reads it row-major."""
     p, _, q = order
     if not hr_structural_ok(p, q):
         raise ValueError(f"fused HR kernel supports p, q <= 8 (got {p}, {q})")
-    b, t = yd.shape
     n = t
     m = min(p + q + 1, max(n // 4, 1))
     nv = jnp.full((b,), n, jnp.int32) if n_valid is None else n_valid
-    zb = (n - nv).astype(yd.dtype)
-    tp, cs, nchunk = _time_layout(t)
-    if y3 is None:
-        y3 = _fold(jnp.pad(yd, ((0, 0), (0, tp - t))))
+    zb = (n - nv).astype(y3.dtype)
+    _, cs, nchunk = _time_layout(t)
     zb3 = _fold(zb[:, None])
     nblk = y3.shape[1] // _SUBL
 
     acc1 = _hr_moments(y3, zb3, t, cs, nchunk, nblk, m, 0, True, m, 0, None,
                        interpret)
-    beta1 = _solve_moments(_unfold(acc1, b), m + 1, yd.dtype)  # [B, m+1]
+    beta1 = _solve_moments(_unfold(acc1, b), m + 1, y3.dtype)  # [B, m+1]
 
     ncols2 = int(include_intercept) + p + q
     if ncols2 == 0:
-        return jnp.zeros((b, 0), yd.dtype)
+        return jnp.zeros((b, 0), y3.dtype)
     beta3 = _fold(beta1)
     acc2 = _hr_moments(y3, zb3, t, cs, nchunk, nblk, p, q, include_intercept,
                        m + q, m, beta3, interpret)
-    return _solve_moments(_unfold(acc2, b), ncols2, yd.dtype)
+    return _solve_moments(_unfold(acc2, b), ncols2, y3.dtype)
 
 
 # ---------------------------------------------------------------------------

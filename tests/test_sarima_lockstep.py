@@ -82,8 +82,11 @@ VALUE_RTOL, GRAD_TOL = 1e-5, 1e-3
 def assert_kernel_is_the_scan(x, y, order, seasonal, mode="dense"):
     fam = arima._sarima_family(order, seasonal, True, "pallas-interpret",
                                False, mode)
-    prepared = fam.prep(jnp.asarray(y, jnp.float32))
-    yd, nvd = prepared.series
+    y = jnp.asarray(y, jnp.float32)
+    prepared = fam.prep(y)
+    # the scan's rows: since ISSUE 46 the kernels' prep forms none of its own
+    assert prepared.series == ()
+    yd, nvd = arima._row_major(y, mode, order[1], seasonal[1], seasonal[3])
     f32, g32 = kernel_value_and_grad(x, fam, prepared)
     f64, g64 = scan_value_and_grad(x, yd, nvd, order, seasonal)
     ok = np.asarray(prepared.ok)
@@ -141,7 +144,7 @@ def test_ragged_rows_over_two_chunks(monkeypatch, order, seasonal):
         random_params(rng, 1024, order, seasonal), y, order, seasonal,
         mode="general")
     assert pk._time_layout(prepared.folded.t)[2] >= 2
-    assert len(set(np.asarray(prepared.series[1]).tolist())) >= 4
+    assert len(set(np.asarray(prepared.rows[0]).tolist())) >= 4
 
 
 def test_dense_lag_sets_are_the_dense_kernel_bit_for_bit():
