@@ -46,6 +46,18 @@ def _release_compiled_programs():
     jax.clear_caches()
 
 
+@pytest.fixture()
+def plane_off():
+    """The telemetry plane disabled before and after a test (enable() builds
+    a fresh registry, so state cannot bleed between tests either way); the
+    telemetry files ask for it module-wide (``pytestmark``)."""
+    from spark_timeseries_tpu import obs
+
+    obs.disable()
+    yield
+    obs.disable()
+
+
 @pytest.fixture(scope="session")
 def cpu_devices():
     devs = jax.devices()

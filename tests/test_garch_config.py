@@ -13,6 +13,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from _obs_helpers import _span_lines
 from benchmark import generators, manifest
 from benchmark.processes import garch11_returns
 from benchmark.reference import check
@@ -33,12 +34,6 @@ def panel(rows, n_time, seed=5):
     return generators.build_panel(
         garch11_returns.rows, CONFIG["process"], {}, seed, jax.devices()[:1],
         rows, n_time, rows, CONFIG["population_seed"])
-
-
-def _span_lines(path):
-    with open(path, encoding="utf-8") as f:
-        events = [json.loads(line) for line in f]
-    return [e for e in events if e.get("kind") == "span"]
 
 
 # -- (a) the reference is the model's likelihood ------------------------------

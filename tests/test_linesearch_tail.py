@@ -8,6 +8,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from _pallas_helpers import _dist_parity
 from spark_timeseries_tpu.models import arima, garch, lockstep
 from spark_timeseries_tpu.models import holtwinters as hw
 from spark_timeseries_tpu.utils import optim
@@ -185,8 +186,6 @@ def test_stage1_with_the_tail_is_stage1_without_it(monkeypatch, name):
     # only where XLA fuses the [cap]-row pass as it fuses the batch's — the
     # CPU does for GARCH, and differs in the last place elsewhere), not to
     # bitwise equality
-    from test_pallas import _dist_parity
-
     monkeypatch.setattr(optim, "COMPACT_MIN_BATCH", 2048)
     family, y = _FAMILIES[name]()
     out, carry = _stage1(family, y, monkeypatch, tail=True)
