@@ -47,6 +47,11 @@ class Prepared(NamedTuple):
     # dense panel: every length is the panel's), which XLA folds into the
     # batch's objective as constants (:func:`_straggler_fun`)
     uniform: bool = False
+    # [B, ...] arrays ``Family.to_natural`` takes beside the optimizer's
+    # point: a row's own change of variables (a regression's coefficients
+    # as offsets from its least-squares start in units of its residual's
+    # scale, so that the optimizer's relative tests see O(1) parameters)
+    natural: tuple = ()
 
 
 def straggler_cap(cells: int) -> Optional[int]:
@@ -69,7 +74,9 @@ class Family(NamedTuple):
     # objective (the retry ladder's fallback rung, the tests' reference);
     # None: ``objective`` is the family's on the scan backend too
     scan_objective: Optional[Callable]
-    to_natural: Callable  # optimizer space [B, d] -> reported params [B, k]
+    # optimizer space [B, d] (and ``Prepared.natural``) -> reported params
+    # [B, k]
+    to_natural: Callable
     # results -> (per-row choice among the starts' results, the rows whose
     # choice is not the first start's: one int32, None from one start);
     # None declares ONE start, whose stage 2 finalizes in its own program
@@ -82,10 +89,10 @@ class Family(NamedTuple):
     take: Optional[Callable] = None
 
 
-def finalize(res, ok, scale, to_natural=lambda x: x) -> FitResult:
+def finalize(res, ok, scale, natural=(), to_natural=lambda x: x) -> FitResult:
     """Optimizer result -> FitResult: natural-space params (NaN where the
     row could not be fit) and the unscaled objective."""
-    params = jnp.where(ok[:, None], to_natural(res.x), jnp.nan)
+    params = jnp.where(ok[:, None], to_natural(res.x, *natural), jnp.nan)
     return FitResult(
         params,
         jnp.where(ok, res.f * scale, jnp.nan),
@@ -184,7 +191,7 @@ def fit_program(family: Family, max_iters: int, tol: float,
                                        max_iters=max_iters, tol=tol)
                 for x0 in p.x0s]
         out = finalize(_merged(family, results)[0], p.ok, p.scale,
-                       family.to_natural)
+                       p.natural, family.to_natural)
         return (out, info) if count_evals else out
 
     return run
@@ -195,7 +202,8 @@ def stage1_program(family: Family, max_iters: int, tol: float,
     """Stage 1 of the lazily compiled compact fit: the prep and, per start,
     the lockstep loop with the straggler early exit, its line search's tail
     on the stragglers' objective -> the finalized as-if-done result and
-    ``{"starts": (per start: carry, res, sub), "fin": (ok, scale)}``; where
+    ``{"starts": (per start: carry, res, sub), "fin": (ok, scale,
+    natural)}``; where
     several starts were merged also ``"merge_switched"``, the rows whose
     merged result is not the first start's (one ``int32`` that :func:`fit`
     defers to the read-back's span when tracing is on and never reads).
@@ -218,10 +226,11 @@ def stage1_program(family: Family, max_iters: int, tol: float,
                            "sub": _stragglers(family, p, carry.idxc)})
             results.append(res1)
         merged, switched = _merged(family, results)
-        aux = {"starts": tuple(starts), "fin": (p.ok, p.scale)}
+        fin = (p.ok, p.scale, p.natural)
+        aux = {"starts": tuple(starts), "fin": fin}
         if switched is not None:
             aux["merge_switched"] = switched
-        return finalize(merged, p.ok, p.scale, family.to_natural), aux
+        return finalize(merged, *fin, family.to_natural), aux
 
     return run
 

@@ -609,13 +609,16 @@ def _css_fwd_layout(p, q, mode, t):
     return ins, outs, scratch
 
 
-def css_series_block(rows: int, t: int, order: Order, mode: str = "sum") -> int:
+def css_series_block(rows: int, t: int, order: Order, mode: str = "sum",
+                     want_gy: bool = False) -> int:
     """Series per grid step of the CSS kernel over ``rows`` series of
     (differenced) length ``t``: ``1024 * R`` (:func:`series_rows`) — of a
-    forward ``mode``, or of the fit objective's ``"adjoint"``.  ``order``'s
-    ``p`` / ``q`` may be lag sets (:func:`_lags`)."""
+    forward ``mode``, or of the fit objective's ``"adjoint"`` (``want_gy``:
+    the adjoint that also writes the data cotangent's panel, a fit whose
+    panel depends on its parameters).  ``order``'s ``p`` / ``q`` may be lag
+    sets (:func:`_lags`)."""
     p, _, q = order
-    layout, best = ((_css_bwd_layout(p, q, t), _ADJOINT_R["css"])
+    layout, best = ((_css_bwd_layout(p, q, t, want_gy), _ADJOINT_R["css"])
                     if mode == "adjoint"
                     else (_css_fwd_layout(p, q, mode, t), _CSS_R[mode]))
     return _SBLK * series_rows(_nsub(rows), layout, best)
@@ -803,6 +806,32 @@ def css_prefold(y, order: Order, n_valid=None, *, lags=()):
     y3 = jnp.where(live, diff(tuple(lags), 0), 0.0)
     zb3 = _fold((start + p).astype(y.dtype)[:, None])
     return y3, zb3
+
+
+def design_plane(x, coef):
+    """``x @ coef'`` as a folded panel ``[tp, Bp/128, 128]``: a design ``x
+    [tp, k]`` SHARED by every series times per-series coefficients ``coef
+    [B, k]``.  The product's ``[tp, Bp]`` result is the kernel layout as it
+    stands (time major, series on the lanes), so a regression's fitted
+    values meet a folded panel with no relayout and no ``[B, T, k]`` array;
+    differentiated, its transpose is ``x' @ g`` over a folded cotangent.
+    ``HIGHEST``: a default-precision f32 product on the TPU is one bfloat16
+    pass.  The einsum and not a 2-D ``dot`` with a reshape: on the chip the
+    residual ``y3 - design_plane`` over ``[131072, 960]`` takes 3.07 ms
+    this way (the panel's read and write alone 1.55, and the default
+    precision no less: the layout, not the MXU, is what it pays) and 4.77 ms
+    through ``[tp, Bp]``, whose tiles are not the folded panel's (PERF.md
+    §6, PR 49)."""
+    return jnp.einsum("tk,kns->tns", x, _fold(coef),
+                      precision=lax.Precision.HIGHEST)
+
+
+def design_project(w, y3, b: int):
+    """``(w @ y)' [B, k]`` for ``w [k, tp]`` shared by every series and a
+    folded panel ``y3``: with ``w = (x'x)^-1 x'`` the least-squares
+    coefficients of all ``b`` series in one product."""
+    return _unfold(jnp.einsum("kt,tns->kns", w, y3,
+                              precision=lax.Precision.HIGHEST), b)
 
 
 @_scoped("pallas.css_neg_loglik")

@@ -896,12 +896,17 @@ def fit_chunked(
         lane_journals = (
             [journal_mod.ShardJournalView(j, journals) for j in journals]
             if elastic else list(journals))
+    # one lane, journaled and pipelined, no sink: the committer fills the
+    # result arrays chunk by chunk and walk.close takes them whole
+    assembly = (plan_mod.ResultAssembly(b)
+                if (lane_journals is not None and pipeline and sink is None
+                    and len(lane_specs) == 1 and not elastic) else None)
     runners = [
         LaneRunner(plan, spec, fit_fn, fit_kwargs, vals,
                    journal=(lane_journals[i] if lane_journals is not None
                             else None),
                    deadline=deadline, tele=tele, fit_key=fit_key,
-                   sink=sink)
+                   sink=sink, assembly=assembly)
         for i, (spec, (_sid, _lo, _hi, _dev, vals))
         in enumerate(zip(lane_specs, lanes))
     ] if not elastic else None
@@ -1040,8 +1045,11 @@ def fit_chunked(
                     np.asarray(p.converged), np.asarray(p.iters),
                     _piece_status(p))
 
-        mats = [_mat(p) for _, _, p in pieces]
-        if mats:
+        taken = assembly.take(pieces) if assembly is not None else None
+        mats = [_mat(p) for _, _, p in pieces] if taken is None else []
+        if taken is not None:
+            params, nll, conv, iters, status = taken
+        elif mats:
             params = np.concatenate([m[0] for m in mats])
             nll = np.concatenate([m[1] for m in mats])
             conv = np.concatenate([m[2] for m in mats])

@@ -104,7 +104,8 @@ class ChunkCommitter:
     def __init__(self, journal, fetch: Callable[[object], dict], *,
                  depth: int = 2, probe: Optional[Callable] = None,
                  status_counts: Optional[Callable] = None,
-                 on_commit: Optional[Callable] = None):
+                 on_commit: Optional[Callable] = None,
+                 on_fetch: Optional[Callable] = None):
         self._journal = journal
         self._fetch = fetch
         self._probe = probe
@@ -113,6 +114,10 @@ class ChunkCommitter:
         # is durable, with the fetched host arrays — the sink's own write
         # failure surfaces through the same worker-error machinery
         self._on_commit = on_commit
+        # result-assembly hook (plan.ResultAssembly): called with the fetched
+        # host arrays BEFORE the journal write, whose own device read (the
+        # chunk fingerprint) queues behind the next chunk's compute
+        self._on_fetch = on_fetch
         self.depth = max(1, int(depth))
         self._q: queue.Queue = queue.Queue(maxsize=self.depth)
         self._lock = threading.Lock()
@@ -149,6 +154,8 @@ class ChunkCommitter:
         with obs.span("commit.overlap", parent=item.link, lo=item.lo,
                       hi=item.hi):
             arrays = self._fetch(item.piece)
+            if self._on_fetch is not None:
+                self._on_fetch(item.lo, item.hi, arrays)
             info = dict(item.info)
             if self._probe is not None:
                 pm = self._probe()

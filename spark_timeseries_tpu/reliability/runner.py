@@ -31,6 +31,7 @@ from __future__ import annotations
 import inspect
 from typing import Callable, NamedTuple, Optional, Sequence
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -55,7 +56,9 @@ class ResilientFitResult(NamedTuple):
     """Batched fit output with per-row status and run metadata.
 
     Field layout extends ``models.base.FitResult``; arrays are host-side
-    (the ladder assembles rows across several device programs).
+    (the ladder assembles rows across several device programs).  ``params``
+    of a call in which no row was rewritten is the device read-back's own
+    buffer and read-only: copy it before writing into it.
     """
 
     params: np.ndarray  # [batch, k]
@@ -103,8 +106,13 @@ def _failed_mask(res) -> np.ndarray:
     params = np.asarray(res.params)
     nll = np.asarray(res.neg_log_likelihood)
     conv = np.asarray(res.converged)
-    finite = np.isfinite(params).all(axis=-1) & np.isfinite(nll)
-    return ~(conv & finite)
+    finite = np.isfinite(params)
+    # a reduction along a short last axis walks the rows one by one (four
+    # times the flat one's time on a [131072, 33] chunk): by rows only if
+    # the flat one finds something
+    rows_finite = np.isfinite(nll) if finite.all() \
+        else finite.all(axis=-1) & np.isfinite(nll)
+    return ~(conv & rows_finite)
 
 
 def _structurally_excluded(res) -> np.ndarray:
@@ -112,6 +120,13 @@ def _structurally_excluded(res) -> np.ndarray:
     if res.status is None:
         return np.zeros(np.asarray(res.converged).shape, bool)
     return np.asarray(res.status) == FitStatus.EXCLUDED
+
+
+@jax.jit
+def _scatter_rows(params, slots, values):
+    """``params`` with ``values[i]`` at row ``slots[i]``; a slot past the
+    last row is dropped (a rung's pad rows and the rows it did not rescue)."""
+    return params.at[slots].set(values.astype(params.dtype), mode="drop")
 
 
 def _recoverable_oom(e: BaseException) -> bool:
@@ -204,7 +219,17 @@ def resilient_fit(
     # fit.readback: the first host read of the result waits for the device,
     # and the next chunk is not dispatched before these passes are done
     with obs.span("fit.readback", rows=b) as readback:
-        params = np.array(res.params)
+        # the read-back's own buffer, read-only: a second copy of a wide
+        # result is 14-16 ms on the driver's thread with the device idle
+        # (17 MB; PERF.md §6, PR 49).  Rows a rung rescues are written into
+        # the DEVICE's array and the whole read back once more (6 ms, and
+        # the same kind of buffer as an untouched chunk's); a host copy is
+        # made only for the DIVERGED mark and for a fit that returns host
+        # arrays
+        params = np.asarray(res.params)
+        params_dev = res.params if isinstance(res.params, jax.Array) else None
+        if params is res.params:  # a host array is the fit's own: as before
+            params = params.copy()
         nll = np.array(res.neg_log_likelihood)
         conv = np.array(res.converged)
         iters = np.array(res.iters)
@@ -273,7 +298,13 @@ def resilient_fit(
         rescued = idx[~sub_failed]
         if rescued.size:
             keep = np.nonzero(~sub_failed)[0]
-            params[rescued] = np.asarray(sub.params)[keep]
+            if params_dev is not None:
+                slots = np.full(cap, b, np.int32)
+                slots[keep] = rescued
+                params_dev = _scatter_rows(params_dev, jnp.asarray(slots),
+                                           jnp.asarray(sub.params))
+            else:
+                params[rescued] = np.asarray(sub.params)[keep]
             nll[rescued] = np.asarray(sub.neg_log_likelihood)[keep]
             conv[rescued] = np.asarray(sub.converged)[keep]
             iters[rescued] = np.asarray(sub.iters)[keep]
@@ -288,9 +319,14 @@ def resilient_fit(
         obs.counter(f"ladder.{rung.name}.attempted").add(int(idx.size))
         obs.counter(f"ladder.{rung.name}.rescued").add(int(rescued.size))
 
+    if params_dev is not None and params_dev is not res.params:
+        params = np.asarray(params_dev)  # the rescued rows in their places
+
     # survivors of every rung: flag DIVERGED and refuse to hand back
     # non-finite params as if they were estimates
     if failed.any():
+        if not params.flags.writeable:
+            params = params.copy()
         params[failed] = np.nan
         nll[failed] = np.nan
         conv[failed] = False

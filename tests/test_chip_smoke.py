@@ -205,6 +205,26 @@ def test_pallas_kernels_compile_for_v5e(monkeypatch):
         programs["arima grid9 stage2"] = (
             arima._grid_stage2_program(*grid9),
             [grid_aux["starts"][0], grid_aux["fin"]])
+        # the shared-design regression: both design products beside the
+        # CSS kernels, the adjoint with its data cotangent (R = 2 by VMEM)
+        from spark_timeseries_tpu.models import regression_arima as ra
+
+        design = [arg(960, 31), arg(31, 960),
+                  arg(31, 1 + ra._UNIT_LAGS)]
+        shared = ((1, 0, 1), "pallas", 60, TOL)
+        shared_s1 = ra._shared_stage1_program(*shared, "dense")
+        programs["harmonic arma stage1 T=960 k=31"] = (
+            shared_s1, [arg(4096, 960), *design])
+        shared_aux = jax.tree_util.tree_map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                           sharding=sharding),
+            jax.eval_shape(shared_s1, arg(4096, 960), *design)[1])
+        programs["harmonic arma stage2"] = (
+            ra._shared_stage2_program(*shared),
+            [shared_aux["starts"][0], shared_aux["fin"]])
+        programs["harmonic arma inline general"] = (
+            ra._shared_fit_program(*shared, "general", True),
+            [arg(B, 960), *design])
         programs["arima grid9 inline general T=2500 (multi-chunk)"] = (
             arima._grid_fit_program(*grid9, "general"), [arg(256, 2500)])
         for name, (program, args) in programs.items():

@@ -299,6 +299,178 @@ class TestDeterministicDrain:
 
 
 # ---------------------------------------------------------------------------
+# the result is assembled on the committer thread, the read-back copies on
+# write (ISSUE 49: a wide result's host passes left the driver's thread)
+# ---------------------------------------------------------------------------
+
+
+def _chunk_arrays(n, k=3, dtype=np.float32, fill=1.0):
+    return {"params": np.full((n, k), fill, dtype),
+            "nll": np.full(n, fill, dtype),
+            "converged": np.ones(n, bool),
+            "iters": np.full(n, 3, np.int32),
+            "status": np.zeros(n, np.int8)}
+
+
+class TestResultAssembly:
+    @pytest.fixture()
+    def taken(self, monkeypatch):
+        """What every ``ResultAssembly.take`` of the test returned."""
+        from spark_timeseries_tpu.reliability import plan as plan_mod
+
+        seen = []
+        real = plan_mod.ResultAssembly.take
+
+        def spy(self, pieces):
+            out = real(self, pieces)
+            seen.append(out is not None)
+            return out
+
+        monkeypatch.setattr(plan_mod.ResultAssembly, "take", spy)
+        return seen
+
+    @pytest.mark.parametrize("resilient", [False, True])
+    def test_journaled_pipelined_walk_takes_it_bitwise(self, tmp_path, taken,
+                                                       resilient):
+        y = _ar_panel()
+        plain = _fit(y, resilient=resilient)  # no journal: concatenated
+        assert taken == []
+        res = _fit(y, str(tmp_path / "j"), resilient=resilient)
+        assert taken == [True]
+        _assert_bitwise(res, plain)
+        for f in ("params", "neg_log_likelihood", "converged", "iters",
+                  "status"):
+            a, b = getattr(res, f), getattr(plain, f)
+            assert a.dtype == b.dtype and a.shape == b.shape
+            assert a.flags.writeable and a.flags.c_contiguous
+
+    @pytest.mark.parametrize("how", ["resumed", "serial", "timeout"])
+    def test_any_other_walk_concatenates(self, tmp_path, taken, how):
+        y = _ar_panel()
+        d = str(tmp_path / "j")
+        if how == "resumed":
+            first = _fit(y, d)
+            res = _fit(y, d)
+            _assert_bitwise(res, first)
+            assert taken == [True, False]
+        elif how == "serial":
+            res = _fit(y, d, pipeline=False)
+            _assert_bitwise(res, _fit(y))
+            assert taken == []  # no committer, no assembly
+        else:
+            res = _fit(y, d, job_budget_s=0.0)
+            assert res.meta["status_counts"]["TIMEOUT"] == 32
+            assert taken == [False]
+
+    @pytest.mark.parametrize("fault", ["none", "missing", "stale", "width",
+                                       "dtype", "short"])
+    def test_take_hands_over_only_the_walks_own_pieces(self, fault):
+        from spark_timeseries_tpu.reliability.plan import ResultAssembly
+
+        asm = ResultAssembly(24)
+        pieces = [(0, 8, None), (8, 16, None), (16, 24, None)]
+        for lo, hi, _ in pieces:
+            if fault == "missing" and lo == 8:
+                continue
+            arrays = _chunk_arrays(hi - lo, fill=float(lo))
+            if fault == "width" and lo == 16:
+                arrays["params"] = np.zeros((hi - lo, 4), np.float32)
+            if fault == "dtype" and lo == 16:
+                arrays["nll"] = arrays["nll"].astype(np.float64)
+            asm.place(lo, hi, arrays)
+        if fault == "stale":  # a rolled-back chunk's boundaries
+            asm.place(8, 12, _chunk_arrays(4))
+        if fault == "short":
+            pieces = pieces[:2]
+        out = asm.take(pieces)
+        if fault != "none":
+            assert out is None
+            return
+        params, nll, conv, iters, status = out
+        np.testing.assert_array_equal(params[:, 0], np.repeat([0., 8., 16.], 8))
+        np.testing.assert_array_equal(nll, np.repeat([0., 8., 16.], 8))
+        assert params.shape == (24, 3) and conv.all() and status.dtype == np.int8
+
+    @pytest.mark.parametrize("bad", ["none", "nan_param", "inf_param",
+                                     "nan_nll", "not_converged"])
+    def test_failed_mask_is_the_by_row_rule(self, bad):
+        from spark_timeseries_tpu.models.base import FitResult
+        from spark_timeseries_tpu.reliability.runner import _failed_mask
+
+        rng = np.random.default_rng(3)
+        params = rng.normal(size=(64, 33)).astype(np.float32)
+        nll = rng.normal(size=64).astype(np.float32)
+        conv = np.ones(64, bool)
+        if bad == "nan_param":
+            params[5, 32] = np.nan
+        elif bad == "inf_param":
+            params[[7, 9], 0] = [np.inf, -np.inf]
+        elif bad == "nan_nll":
+            nll[11] = np.nan
+        elif bad == "not_converged":
+            conv[13] = False
+        want = ~(conv & np.isfinite(params).all(axis=-1) & np.isfinite(nll))
+        got = _failed_mask(FitResult(params, nll, conv, np.zeros(64, np.int32)))
+        np.testing.assert_array_equal(got, want)
+        assert got.sum() == {"none": 0, "inf_param": 2}.get(bad, 1)
+
+    @pytest.mark.parametrize("rewritten", [False, True])
+    def test_readback_copies_params_on_write_only(self, rewritten):
+        y = jnp.asarray(_ar_panel(b=8))
+        direct = arima.fit(y, order=(1, 0, 0), max_iters=25)
+
+        def fit(yb, **kw):
+            r = arima.fit(yb, **kw)
+            if rewritten:  # one row the empty ladder marks DIVERGED
+                r = r._replace(converged=r.converged.at[2].set(False))
+            return r
+
+        res = rel.resilient_fit(fit, y, order=(1, 0, 0), max_iters=25,
+                                ladder=())
+        failed = ~np.asarray(res.converged)
+        assert failed.sum() == int(rewritten)
+        assert res.params.flags.writeable == rewritten
+        assert np.isnan(res.params[failed]).all()
+        assert (res.status[failed] == FitStatus.DIVERGED).all()
+        np.testing.assert_array_equal(res.params[~failed],
+                                      np.asarray(direct.params)[~failed])
+
+
+    @pytest.mark.parametrize("host_fit", [False, True])
+    def test_rescued_rows_are_scattered_on_the_device(self, host_fit):
+        """A rung's rows land in the device's array (read back once more:
+        the read-only buffer again); a fit that returns host arrays keeps
+        the host scatter.  Either way the rows are the rung's own."""
+        y = jnp.asarray(_ar_panel(b=16))
+        direct = arima.fit(y, order=(1, 0, 0), max_iters=25)
+        flagged = [3, 11]
+
+        def fit(yb, **kw):
+            r = arima.fit(yb, **kw)
+            if yb.shape[0] == 16:  # the primary fit leaves two rows undone
+                r = r._replace(converged=r.converged.at[
+                    jnp.asarray(flagged)].set(False))
+            if host_fit:
+                r = r._replace(params=np.asarray(r.params))
+            return r
+
+        rung = rel.RetryRung("retry", int(FitStatus.RETRIED), {}, 0.0)
+        res = rel.resilient_fit(fit, y, order=(1, 0, 0), max_iters=25,
+                                ladder=(rung,))
+        assert res.meta["ladder"][0]["rescued"] == 2
+        assert (res.status[flagged] == FitStatus.RETRIED).all()
+        assert res.converged.all()
+        assert res.params.flags.writeable == host_fit
+        sub = arima.fit(y[jnp.asarray(flagged + [3] * 6)], order=(1, 0, 0),
+                        max_iters=25)
+        np.testing.assert_array_equal(res.params[flagged],
+                                      np.asarray(sub.params)[:2])
+        others = np.setdiff1d(np.arange(16), flagged)
+        np.testing.assert_array_equal(res.params[others],
+                                      np.asarray(direct.params)[others])
+
+
+# ---------------------------------------------------------------------------
 # knob surfaces: panel.fit, compat fit_model
 # ---------------------------------------------------------------------------
 
