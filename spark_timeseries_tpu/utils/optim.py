@@ -27,6 +27,7 @@ from typing import Callable, NamedTuple, Tuple
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.experimental.layout import Layout, with_layout_constraint
 
 from .. import obs
 
@@ -91,6 +92,8 @@ class LBFGSResult(NamedTuple):
 
 
 class _State(NamedTuple):
+    # per series as commented; the batched loops add the batch as the LAST
+    # axis of every field but ``k`` (``x [d, B]``, ``s_hist [m, d, B]``)
     k: jax.Array
     x: jax.Array
     f: jax.Array
@@ -308,35 +311,115 @@ def minimize_lbfgs(
     )
 
 
-_rownorm = lambda v: jnp.linalg.norm(v, axis=-1)
-_rowdot = lambda a, b: jnp.sum(a * b, axis=-1)
-_two_loop_b = jax.vmap(_two_loop, in_axes=(0, 0, 0, 0, None, None))
+# -- the batched half: the ROWS ride the last axis ---------------------------
+#
+# Every per-row array of the lockstep loops has the batch on its LAST axis:
+# a vector of the state is ``[d, B]``, the history ``[m, d, B]``, ``rho_hist``
+# ``[m, B]``, a scalar a row ``[B]`` as ever.  On the chip the last axis is the
+# lanes, so a dot over ``d`` is a multiply-add down the major axis and a ring
+# slot ``hist[i]`` one contiguous ``[d, B]`` plane, whatever ``d`` is.  Written
+# ``[B, m, d]`` the v5e compiler made that choice itself at d = 3 and not at
+# d = 33, where it padded every vector 33 -> 128 lanes and reduced across them
+# (PERF.md §6, PR 50).  Objectives keep their interface: ``fb`` takes ``[B,
+# d]`` (:func:`_rows_last` turns one), results are ``[B, d]``.
+
+_rownorm = lambda v: jnp.linalg.norm(v, axis=0)
+_rowdot = lambda a, b: jnp.sum(a * b, axis=0)
+
+
+def _pin(tree):
+    """Every array of ``tree`` with two axes or more, held in the layout it
+    is written in (the last axis minor: the rows on the lanes).  The shape
+    alone does not say it: XLA assigns physical layouts freely, and with
+    ``[d, B]`` written it still chose ``d`` minor at d = 33 — the row gathers
+    and the ``[B, d]`` results want it so, and the preference spread through
+    the loop's carry (PERF.md §6, PR 50)."""
+    def pin(a):
+        if a.ndim < 2:
+            return a
+        return with_layout_constraint(
+            a, Layout(major_to_minor=tuple(range(a.ndim))))
+
+    return jax.tree_util.tree_map(pin, tree)
+
+
+def _rows_last(fb):
+    """``fb(x[B, d])`` as a function of ``x[d, B]``.  A kernel objective
+    folds ``[B, d]`` into ``[d, B/128, 128]`` planes by a transpose of its
+    own, so the two cancel and no ``[B, d]`` array is formed."""
+    return lambda xt: fb(xt.T)
+
+
+def _take_last(arrays, idxc):
+    """``[a[..., idxc] for a in arrays]`` over arrays with the batch on
+    their last axis, as the ROW gather of :func:`take_rows` (on the chip a
+    gather along the lanes is a transpose of its whole operand per call; of
+    rows it costs by the index): one table with the rows first, gathered,
+    and each piece turned back."""
+    taken = take_rows([jnp.moveaxis(a, -1, 0) for a in _pin(tuple(arrays))],
+                      idxc)
+    return [jnp.moveaxis(a, 0, -1) for a in taken]
+
+
+def _two_loop_b(g, s_hist, y_hist, rho_hist, k, m):
+    """:func:`_two_loop` over a batch, written for the batch: ``g [d, B]``,
+    ``s_hist`` / ``y_hist [m, d, B]``, ``rho_hist [m, B]`` -> ``H g [d,
+    B]``.  The same ring, masks and ``gamma``; ``alpha``, ``beta`` and
+    ``valid`` are ``[B]`` and broadcast along the planes."""
+    idx = (k - 1 - jnp.arange(m)) % m  # newest -> oldest
+
+    q = g
+    alphas = []
+    for j in range(m):
+        i = idx[j]
+        valid = rho_hist[i] > 0.0
+        alpha = jnp.where(valid, rho_hist[i] * _rowdot(s_hist[i], q), 0.0)
+        q = q - alpha * y_hist[i] * valid
+        alphas.append(alpha)
+
+    newest = idx[0]
+    sy = _rowdot(s_hist[newest], y_hist[newest])
+    yy = _rowdot(y_hist[newest], y_hist[newest])
+    gamma = jnp.where((rho_hist[newest] > 0.0) & (yy > 0.0), sy / yy, 1.0)
+    r = gamma * q
+
+    for j in reversed(range(m)):
+        i = idx[j]
+        valid = rho_hist[i] > 0.0
+        beta = jnp.where(valid, rho_hist[i] * _rowdot(y_hist[i], r), 0.0)
+        r = r + (alphas[j] - beta) * s_hist[i] * valid
+    return r
 
 
 def _make_vg_b(fb):
-    """Batched value-and-grad with the non-finite guard rows carry."""
+    """Batched value-and-grad of ``fb(x[B, d])`` at ``x[d, B]`` -> ``(f[B],
+    g[d, B])`` with the non-finite guard rows carry."""
+    fbt = _rows_last(fb)
 
     def vg(x):
-        f, pullback = jax.vjp(fb, x)
+        f, pullback = jax.vjp(fbt, x)
         (g,) = pullback(jnp.ones_like(f))
-        bad = ~jnp.isfinite(f) | ~jnp.all(jnp.isfinite(g), axis=-1)
-        return jnp.where(bad, jnp.inf, f), jnp.where(bad[:, None], 0.0, g)
+        bad = ~jnp.isfinite(f) | ~jnp.all(jnp.isfinite(g), axis=0)
+        return jnp.where(bad, jnp.inf, f), jnp.where(bad, 0.0, g)
 
     return vg
 
 
 def _init_state_b(vg, x0, m, tol):
+    """The loop's first state from ``x0 [B, d]`` (every per-row field with
+    its rows last)."""
     bsz, d = x0.shape
     dtype = x0.dtype
+    x0 = x0.T
     f0, g0 = vg(x0)
     return _State(
         k=jnp.zeros((), jnp.int32),
         x=x0,
         f=f0,
         g=g0,
-        s_hist=jnp.zeros((bsz, m, d), dtype),
-        y_hist=jnp.zeros((bsz, m, d), dtype),
-        rho_hist=jnp.zeros((bsz, m), dtype),
+        s_hist=jnp.zeros((m, d, bsz), dtype),
+        y_hist=jnp.zeros((m, d, bsz), dtype),
+        rho_hist=jnp.zeros((m, bsz), dtype),
         converged=(_rownorm(g0) < tol) & jnp.isfinite(f0),
         failed=jnp.isinf(f0),
         tprev=jnp.ones((bsz,), dtype),
@@ -401,11 +484,14 @@ def _make_linesearch_b(fb, *, ftol, max_linesearch, c1, tail_fun=None,
     39) a row at the noise floor can interpolate another step."""
 
     def backtrack(fb, x, f, direction, gd, eps, carry, searching):
+        # ``fb`` over ``[n, d]``, ``x`` and ``direction`` ``[d, n]``;
         # ``searching(ok)``: whether the rows still to accept are worth
         # another trial at this width
+        fbt = _rows_last(fb)
+
         def body(carry):
             t, ok, j = carry
-            fnew = fb(x + t[:, None] * direction)
+            fnew = fbt(x + t * direction)
             fnew = jnp.where(jnp.isfinite(fnew), fnew, jnp.inf)
             ok_new = ok | (fnew <= f + c1 * t * gd + eps)
             tq = -gd * t * t / (2.0 * (fnew - f - gd * t))
@@ -448,12 +534,12 @@ def _make_linesearch_b(fb, *, ftol, max_linesearch, c1, tail_fun=None,
         t, ok, j = backtrack(fb, x, f, direction, gd, eps, (t0, done, 0),
                              lambda ok: jnp.sum(~ok) > cap)
         idx, idxc = _undone_indices(~ok, cap)
-        *sub, t_sub = take_rows((x, f, direction, gd, eps, t), idxc)
+        *sub, t_sub = _take_last((x, f, direction, gd, eps, t), idxc)
         # a fill slot enters as accepted; past the budget (more than cap
         # rows left at j == max_linesearch) the tail runs no trial and the
         # scatter writes back what it gathered
         t_sub, ok_sub, n_ls = backtrack(
-            tail_fun(idxc), *sub, (t_sub, idx >= x.shape[0], j),
+            tail_fun(idxc), *sub, (t_sub, idx >= t.shape[0], j),
             lambda ok: jnp.any(~ok))
         put = lambda a, sub: a.at[idx].set(sub, mode="drop")
         return put(t, t_sub), put(ok, ok_sub), n_ls, n_ls - j
@@ -480,7 +566,7 @@ def _make_step_b(fb, *, m, dtype, tol, ftol, max_linesearch, c1,
                 state.k, m
             )
         descent = _rowdot(state.g, direction) < 0.0
-        direction = jnp.where(descent[:, None], direction, -state.g)
+        direction = jnp.where(descent, direction, -state.g)
 
         # rows with no curvature history step along raw steepest
         # descent, whose scale is arbitrary: bound their first trial
@@ -489,7 +575,7 @@ def _make_step_b(fb, *, m, dtype, tol, ftol, max_linesearch, c1,
         # objective pass, so a straggler row that keeps needing tiny
         # steps must not re-pay the whole backtrack from t=1 every
         # iteration
-        has_hist = jnp.any(state.rho_hist > 0.0, axis=-1)
+        has_hist = jnp.any(state.rho_hist > 0.0, axis=0)
         t0 = jnp.where(
             has_hist & descent,
             jnp.minimum(1.0, 4.0 * state.tprev),
@@ -498,7 +584,7 @@ def _make_step_b(fb, *, m, dtype, tol, ftol, max_linesearch, c1,
         with jax.named_scope("optim.lbfgs_batched.linesearch"):
             t, ok, n_ls, n_tail = linesearch(
                 state.x, state.f, state.g, direction, done, t0)
-        x_new = state.x + t[:, None] * direction
+        x_new = state.x + t * direction
         with jax.named_scope("optim.lbfgs_batched.value_and_grad"):
             f_new, g_new = vg_fb(x_new)
 
@@ -515,18 +601,16 @@ def _make_step_b(fb, *, m, dtype, tol, ftol, max_linesearch, c1,
         # the per-series minimize_lbfgs: a step rejected at the
         # re-evaluation must not poison the curvature history
         good_pair = (sy > 1e-10) & accept
-        upd = lambda hist, v: hist.at[:, slot].set(
-            jnp.where(good_pair[:, None], v, hist[:, slot])
+        # a ring slot is one plane of the major axis
+        upd = lambda hist, v: hist.at[slot].set(
+            jnp.where(good_pair, v, hist[slot])
         )
         s_hist = upd(state.s_hist, s)
         y_hist = upd(state.y_hist, y)
-        rho_hist = state.rho_hist.at[:, slot].set(
-            jnp.where(good_pair, 1.0 / jnp.maximum(sy, 1e-30),
-                      state.rho_hist[:, slot])
-        )
-        x_out = jnp.where(accept[:, None], x_new, state.x)
+        rho_hist = upd(state.rho_hist, 1.0 / jnp.maximum(sy, 1e-30))
+        x_out = jnp.where(accept, x_new, state.x)
         f_out = jnp.where(accept, f_new, state.f)
-        g_out = jnp.where(accept[:, None], g_new, state.g)
+        g_out = jnp.where(accept, g_new, state.g)
         conv = state.converged | (
             _rownorm(g_out) < tol * jnp.maximum(1.0, _rownorm(x_out))
         )
@@ -535,7 +619,7 @@ def _make_step_b(fb, *, m, dtype, tol, ftol, max_linesearch, c1,
             & (state.f - f_new <= ftol * jnp.maximum(1.0, jnp.abs(f_new)))
         )
         better = f_out < state.bf
-        new_state = _State(
+        new_state = _pin(_State(
             k=state.k + 1,
             x=x_out,
             f=f_out,
@@ -546,10 +630,10 @@ def _make_step_b(fb, *, m, dtype, tol, ftol, max_linesearch, c1,
             converged=conv,
             failed=state.failed | (~ok & ~conv & ~done),
             tprev=jnp.where(accept, t, state.tprev),
-            bx=jnp.where(better[:, None], x_out, state.bx),
+            bx=jnp.where(better, x_out, state.bx),
             bf=jnp.where(better, f_out, state.bf),
-            bg=jnp.where(better[:, None], g_out, state.bg),
-        )
+            bg=jnp.where(better, g_out, state.bg),
+        ))
         iters = jnp.where(done, iters, state.k + 1)
         if ls_hist is not None:
             ls_hist = ls_hist.at[state.k].set(n_ls)
@@ -600,7 +684,7 @@ def _lockstep(fun_batched, x0, cap, count_evals, *, max_iters, history, tol,
 def _result_b(state, iters):
     """(x, f, grad_norm) all refer to the best-seen iterate per row."""
     return LBFGSResult(
-        x=state.bx,
+        x=state.bx.T,
         f=state.bf,
         converged=state.converged & jnp.isfinite(state.bf),
         iters=iters,
@@ -714,7 +798,7 @@ class StragglerCarry(NamedTuple):
     ``ls_hist`` is the pass accounting of ``count_evals`` (``None`` when
     off: no leaf, so the compiled programs are those of an uncounted fit)."""
 
-    state: _State  # compacted [cap, ...] optimizer state
+    state: _State  # compacted optimizer state, the cap rows LAST ([d, cap])
     idx: jax.Array  # [cap] scatter indices (fill = bsz: dropped)
     idxc: jax.Array  # [cap] clamped gather indices
     iters: jax.Array  # [bsz] per-row iteration counts at stage-1 exit
@@ -749,7 +833,7 @@ def lbfgs_batched_stage1(
     """Stage 1 of the compacted batched L-BFGS, as a standalone traceable.
 
     Runs the lockstep loop until at most ``straggler_cap`` rows remain
-    unconverged, then gathers the straggler state into the ``[cap, ...]``
+    unconverged, then gathers the straggler state into the ``[..., cap]``
     layout and returns ``(result_as_if_done, carry)``.  When no rows remain
     unconverged the result IS the final answer (stage 2 would run zero
     iterations and scatter the state back unchanged); otherwise the caller
@@ -789,8 +873,9 @@ def lbfgs_batched_stage1(
     # more than cap rows undone this size=cap gather drops the excess: see
     # the truncation contract in lbfgs_batched_stage2.
     idx, idxc = _undone_indices(undone1, cap)
-    # every per-row field of the state (all but ``k``, the first)
-    sub = _State(stage1.k, *take_rows(stage1[1:], idxc))
+    # every per-row field of the state (all but ``k``, the first): the
+    # history is turned rows-first ONCE here for the gather, outside the loop
+    sub = _State(stage1.k, *_take_last(stage1[1:], idxc))
     result = _result_b(stage1, iters)
     carry = StragglerCarry(state=sub, idx=idx, idxc=idxc, iters=iters,
                            undone=jnp.sum(undone1).astype(jnp.int32),
@@ -870,7 +955,7 @@ def lbfgs_batched_stage2_counted(fun_sub_batched, full, carry, *, max_iters,
     counts = (sub_f.k - carry.k, trials)
     put = lambda a, s: a.at[carry.idx].set(s, mode="drop")
     result = LBFGSResult(
-        x=put(full.x, sub_f.bx),
+        x=put(full.x, sub_f.bx.T),
         f=put(full.f, sub_f.bf),
         converged=put(full.converged,
                       sub_f.converged & jnp.isfinite(sub_f.bf)),

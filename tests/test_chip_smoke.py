@@ -11,10 +11,12 @@ compile-only v5e topology — run that one before editing
 
 import json
 import os
+import re
 import subprocess
 import sys
 import types
 
+import numpy as np
 import pytest
 
 import jax
@@ -83,33 +85,128 @@ def test_smoke_rehearsal_end_to_end(tmp_path):
     assert all(ln["claim"] is None for ln in lines[:-1])
 
 
-@pytest.mark.slow
-def test_pallas_kernels_compile_for_v5e(monkeypatch):
-    """Every kernel family lowers through Mosaic and compiles for a
-    compile-only ``v5e:2x2`` topology — no chip needed, none taken."""
-    from jax._src import xla_bridge
+@pytest.fixture(scope="module")
+def v5e_chip():
+    """One chip of a compile-only ``v5e:2x2`` topology as a sharding — no
+    chip needed, none taken; the tests that ask for it are skipped where
+    libtpu cannot describe one.  Described HERE and in no other test file:
+    a file is what an xdist worker takes, and the library is one process's
+    at a time."""
     from jax.experimental import topologies
     from jax.sharding import SingleDeviceSharding
-
-    from spark_timeseries_tpu.models import arima, ewma, garch
-    from spark_timeseries_tpu.models import holtwinters as hw
-    from spark_timeseries_tpu.ops import pallas_kernels as pk
 
     # building the topology loads libtpu, which by default takes the
     # machine's libtpu lockfile for the life of the process: on a host WITH
     # a chip no other process could then open it.  With this variable the
     # load takes no lock (checked on a v5e host, PR 22: a second process
     # ran on the chip while this one held a compiled kernel).
-    monkeypatch.setenv("ALLOW_MULTIPLE_LIBTPU_LOAD", "1")
-    try:
-        topo = topologies.get_topology_desc(platform="tpu",
-                                            topology_name="v5e:2x2")
-    except Exception as e:  # noqa: BLE001 - no libtpu: nothing to compile for
-        reason = f"compile-only v5e topology unavailable: {e!r}"
-        print(reason)
-        pytest.skip(reason)
-    sharding = SingleDeviceSharding(topo.devices[0])
+    with pytest.MonkeyPatch.context() as patch:
+        patch.setenv("ALLOW_MULTIPLE_LIBTPU_LOAD", "1")
+        try:
+            topo = topologies.get_topology_desc(platform="tpu",
+                                                topology_name="v5e:2x2")
+        except Exception as e:  # noqa: BLE001 - no libtpu: nothing to compile for
+            reason = f"compile-only v5e topology unavailable: {e!r}"
+            print(reason)
+            pytest.skip(reason)
     assert topo.devices[0].device_kind == "TPU v5 lite"
+    return SingleDeviceSharding(topo.devices[0])
+
+
+_SHAPE = re.compile(r"\b[a-z]+\d+\[([\d,]*)\]\{([\d,]*)[^}]*\}")
+
+
+def _shapes(hlo_line):
+    """Every array shape a line of compiled text names, as ``(text, dims,
+    minor axis)``: ``f32[33,131072]{1,0:T(8,128)}`` -> ``(33, 131072), 1``."""
+    return [(found.group(0),
+             tuple(int(n) for n in found.group(1).split(",") if n),
+             int(found.group(2).split(",")[0]) if found.group(2) else None)
+            for found in _SHAPE.finditer(hlo_line)]
+
+
+def _in_loop_lines(hlo_text):
+    """The instructions of every computation a ``while`` of the compiled
+    text runs, body and condition and what they call."""
+    comps, name = {}, None
+    for line in hlo_text.splitlines():
+        head = re.match(r"(?:ENTRY\s+)?%?([\w.\-]+)\s*\(.*\)\s*->.*\{\s*$",
+                        line)
+        if head and not line.startswith(" "):
+            name = head.group(1)
+            comps[name] = []
+        elif name is not None:
+            comps[name].append(line)
+    called = re.compile(r"(?:calls|body|condition|to_apply)=%?([\w.\-]+)")
+    todo = [c for lines in comps.values() for ln in lines if " while(" in ln
+            for c in re.findall(r"(?:body|condition)=%?([\w.\-]+)", ln)]
+    seen = set()
+    while todo:
+        comp = todo.pop()
+        if comp in seen or comp not in comps:
+            continue
+        seen.add(comp)
+        todo += [c for ln in comps[comp] for c in called.findall(ln)]
+    assert seen, "the program has a loop"
+    return [ln for comp in seen for ln in comps[comp]]
+
+
+@pytest.mark.parametrize("d", [33, 3])
+def test_lockstep_state_keeps_its_rows_on_the_lanes(v5e_chip, d):
+    """PERF.md §6, PR 50, as a test: ``optim._lockstep`` with the line
+    search's tail over a quadratic at ``[131072, d]``, compiled for the v5e.
+    Inside its loops no array with 131,072 rows has ``d`` as its minor axis
+    (at d = 33 that is 33 padded to 128 lanes and every dot over ``d`` a
+    reduction across them: 0.36 of the harmonic cell's window before PR
+    50), and no history-sized ``copy`` / ``transpose`` runs there.  The
+    shape the state is WRITTEN in does not decide it — with ``[m, d, B]``
+    written and unpinned the compiler chose the parent's layout again — so
+    the day somebody routes the state back through ``[B, m, d]``, or takes
+    ``optim._pin`` away, this fails on a CPU."""
+    from spark_timeseries_tpu.utils import optim
+
+    rows, m, cap = 131072, 8, 16384
+
+    def run(x0):
+        # one ill-scaled bowl for every row: the objective brings no
+        # ``[B, d]`` data of its own into the loop, so every wide array
+        # there is the optimizer's
+        scales = jnp.logspace(-1.0, 1.0, d, dtype=jnp.float32)
+        fb = lambda x: jnp.sum(scales * x * x - x, axis=-1)  # noqa: E731
+        return optim._lockstep(
+            fb, x0, cap, False, max_iters=60, history=m, tol=1e-4, ftol=None,
+            max_linesearch=20, c1=1e-4, tail_fun=lambda idxc: fb)
+
+    arg = jax.ShapeDtypeStruct((rows, d), jnp.float32, sharding=v5e_chip)
+    with jax.enable_x64(False):  # as on the chip
+        text = jax.jit(run).lower(arg).compile().as_text()
+    lines = _in_loop_lines(text)
+    wide = {text: (dims, minor) for ln in lines
+            for text, dims, minor in _shapes(ln)
+            if rows in dims and len(dims) > 1}
+    assert any(dims == (m, d, rows) for dims, _ in wide.values()), (
+        "the history is in the loop")
+    on_lanes = sorted(text for text, (dims, minor) in wide.items()
+                      if dims[minor] == d)
+    assert not on_lanes, f"d = {d} is the minor axis of {on_lanes}"
+    moved = [ln.strip()[:200] for ln in lines
+             if re.search(r"= \S+ (?:copy|transpose)\(", ln)
+             and any(np.prod(dims) >= m * d * rows
+                     for _, dims, _ in _shapes(ln.split("(")[0]))]
+    assert not moved, f"history-sized relayouts in the loop: {moved}"
+
+
+@pytest.mark.slow
+def test_pallas_kernels_compile_for_v5e(v5e_chip):
+    """Every kernel family lowers through Mosaic and compiles for a
+    compile-only ``v5e:2x2`` topology — no chip needed, none taken."""
+    from jax._src import xla_bridge
+
+    from spark_timeseries_tpu.models import arima, ewma, garch
+    from spark_timeseries_tpu.models import holtwinters as hw
+    from spark_timeseries_tpu.ops import pallas_kernels as pk
+
+    sharding = v5e_chip
 
     def arg(*shape):
         return jax.ShapeDtypeStruct(shape, jnp.float32, sharding=sharding)

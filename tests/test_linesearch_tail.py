@@ -18,16 +18,18 @@ KNOBS = dict(ftol=1e-6, max_linesearch=20, c1=1e-4)
 
 def _parent_linesearch(fb, *, ftol, max_linesearch, c1):
     """The one-loop line search as it stood before the tail (PR 38's
-    ``utils/optim.py::_make_linesearch_b``), kept here as the reference."""
+    ``utils/optim.py::_make_linesearch_b``), kept here as the reference;
+    since PR 50 over ``x``, ``g`` and ``direction`` as ``[d, B]``, the rows
+    on the last axis as the optimizer's loops hold them."""
     from jax import lax
 
     def linesearch(x, f, g, direction, done, t0):
-        gd = jnp.sum(g * direction, axis=-1)
+        gd = jnp.sum(g * direction, axis=0)
         eps = ftol * jnp.maximum(1.0, jnp.abs(f))
 
         def body(carry):
             t, ok, j = carry
-            fnew = fb(x + t[:, None] * direction)
+            fnew = fb((x + t * direction).T)
             fnew = jnp.where(jnp.isfinite(fnew), fnew, jnp.inf)
             ok_new = ok | (fnew <= f + c1 * t * gd + eps)
             tq = -gd * t * t / (2.0 * (fnew - f - gd * t))
@@ -60,7 +62,8 @@ def _quadratics(bsz=64):
     fun = lambda x, a: 0.5 * a * jnp.sum(x * x, axis=-1)  # noqa: E731
     f, g = fun(x, a), a[:, None] * x
     done = jnp.zeros((bsz,), bool).at[7].set(True)
-    return fun, a, (x, f, g, -g, done, jnp.ones((bsz,), jnp.float32))
+    # the line search takes its vectors with the rows LAST
+    return fun, a, (x.T, f, g.T, -g.T, done, jnp.ones((bsz,), jnp.float32))
 
 
 def test_tail_search_is_the_one_loop_search_at_the_tails_width():

@@ -294,3 +294,89 @@ class TestLazyStage2Split:
                                    rtol=0, atol=0)
         np.testing.assert_array_equal(np.asarray(res1.iters),
                                       np.asarray(got.iters))
+
+
+# -- the batched optimizer against the per-series one (ISSUE 50) --------------
+#
+# ``minimize_lbfgs_batched`` holds its state with the rows on the last axis
+# (``x [d, B]``, the history ``[m, d, B]``) and runs a two-loop recursion
+# written for the batch; ``jax.vmap(minimize_lbfgs)`` is the per-series
+# algorithm it has to be, at a narrow and at a wide ``d``.
+
+
+def _rows_problem(kind, d, bsz=256, seed=0):
+    """``(row objective f(x[..., d], *data), data [B, ...], x0 [B, d])``: an
+    ill-scaled quadratic (curvatures log-uniform over 0.1..10 per row and
+    coordinate) or a Rosenbrock-like chain with per-row constants."""
+    rng = np.random.default_rng(seed)
+    if kind == "quadratic":
+        data = (np.exp(rng.uniform(np.log(0.1), np.log(10.0), (bsz, d))),
+                rng.normal(size=(bsz, d)))
+        row = lambda x, a, c: 0.5 * jnp.sum(a * (x - c) ** 2, axis=-1)  # noqa: E731
+    else:
+        data = (rng.uniform(0.5, 2.0, (bsz, 1)),
+                rng.uniform(0.5, 1.5, (bsz, 1)))
+        row = lambda x, b, c: jnp.sum(  # noqa: E731
+            b * (x[..., 1:] - x[..., :-1] ** 2) ** 2
+            + (c - x[..., :-1]) ** 2, axis=-1)
+    data = tuple(jnp.asarray(a.astype(np.float32)) for a in data)
+    return row, data, jnp.zeros((bsz, d), jnp.float32)
+
+
+_ROWS_CASES = [(kind, d) for kind in ("quadratic", "rosenbrock")
+               for d in (3, 33)]
+
+
+@pytest.mark.parametrize("kind,d", _ROWS_CASES)
+def test_batched_is_the_vmapped_per_series_optimizer(kind, d):
+    # the f32 tolerance, stated: the two are other compiled programs and sum
+    # a row's d products in another order, so a row at the edge of a
+    # stopping test may take another iteration — ``converged`` is equal on
+    # every row, ``iters`` on 19 rows of 20 and never 8 apart, ``f`` to 1e-3
+    # and ``x`` to 2e-2 (5e-3 on 99 rows of 100) of optima of order one
+    row, data, x0 = _rows_problem(kind, d)
+    knobs = dict(max_iters=200, tol=1e-4)
+    got = jax.jit(lambda x0: optim.minimize_lbfgs_batched(
+        lambda x: row(x, *data), x0, **knobs))(x0)
+    ref = jax.jit(jax.vmap(lambda x, *r: optim.minimize_lbfgs(
+        lambda p: row(p, *r), x, **knobs)))(x0, *data)
+    assert got.x.shape == (256, d) and got.grad_norm.shape == (256,)
+    assert bool(jnp.all(ref.converged))
+    np.testing.assert_array_equal(np.asarray(got.converged),
+                                  np.asarray(ref.converged))
+    apart = np.abs(np.asarray(got.iters) - np.asarray(ref.iters))
+    assert (apart == 0).mean() >= 0.95 and apart.max() < 8
+    np.testing.assert_allclose(np.asarray(got.f), np.asarray(ref.f),
+                               rtol=1e-3, atol=1e-3)
+    dx = np.abs(np.asarray(got.x) - np.asarray(ref.x)).max(axis=-1)
+    assert dx.max() < 2e-2 and np.quantile(dx, 0.99) < 5e-3
+
+
+@pytest.mark.parametrize("kind,d", _ROWS_CASES)
+def test_two_stages_are_the_one_stage_fit_on_the_same_rows(kind, d):
+    # stage 1 -> compaction -> stage 2, run apart as a model fit runs them,
+    # against the uncompacted loop: the gather moves rows, it computes
+    # nothing, so on the CPU the results are the same bits; the carried
+    # state has its rows LAST, the history a ring of ``[d, cap]`` planes
+    row, data, x0 = _rows_problem(kind, d)
+    knobs = dict(max_iters=200, tol=1e-4)
+    m, cap = 8, 32
+    fun = lambda x: row(x, *data)  # noqa: E731
+    sub_fun = lambda idxc: (  # noqa: E731
+        lambda x: row(x, *(a[idxc] for a in data)))
+    ref = optim.minimize_lbfgs_batched(fun, x0, **knobs)
+    res1, carry = optim.lbfgs_batched_stage1(
+        fun, x0, straggler_cap=cap, tail_fun=sub_fun, **knobs)
+    assert 0 < int(carry.undone) <= cap and int(carry.k) < 200
+    state = carry.state
+    assert state.s_hist.shape == state.y_hist.shape == (m, d, cap)
+    assert state.rho_hist.shape == (m, cap)
+    assert all(getattr(state, name).shape == (d, cap)
+               for name in ("x", "g", "bx", "bg"))
+    assert all(getattr(state, name).shape == (cap,)
+               for name in ("f", "bf", "tprev", "converged", "failed"))
+    assert res1.x.shape == (256, d)
+    got = optim.lbfgs_batched_stage2(sub_fun(carry.idxc), res1, carry,
+                                     **knobs)
+    for a, b in zip(ref, got):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
