@@ -36,11 +36,14 @@ u_t = (1 + theta(L)) e_t``::
 
 The residual plane ``X @ beta'`` is ``[T, B]``, which IS the CSS kernels'
 folded layout, so the design enters the objective as two matrix products a
-gradient — ``u3 = y3 - X @ beta'`` before the forward kernel, ``dS/dbeta = -X'
-@ g_u`` after the adjoint's data cotangent ``g_u = dS/du`` — and never as a
-``[B, T, k]`` array.  Start: ``beta0 = (X'X)^-1 X'y`` (one Cholesky for all
-rows, on the host; one product a call), ``(phi0, theta0)`` Hannan-Rissanen on
-``u(beta0)``; the optimizer moves each coefficient in its own unit at that
+gradient — ``u = y - X @ beta'`` and ``dS/dbeta = -X' @ g_u`` over the
+adjoint's data cotangent ``g_u = dS/du`` — and never as a ``[B, T, k]``
+array; both are formed in VMEM INSIDE the CSS kernel calls, which take ``X``
+and the coefficient planes as operands (``pallas_kernels``' CSS section), so
+no panel-sized product runs beside them.  Start: ``beta0 = (X'X)^-1 X'y``
+(one Cholesky for all rows, on the host; one product a call), ``(phi0,
+theta0)`` Hannan-Rissanen on ``u(beta0)`` (that residual a panel, from the
+same kernel); the optimizer moves each coefficient in its own unit at that
 start, its standard error under the start's error filter, from the columns'
 autocovariances and never from a filtered column.
 """
@@ -158,13 +161,14 @@ _predict_batched = jax.jit(jax.vmap(lambda pr, Xv: _design(Xv) @ pr[:-1]))
 # ---------------------------------------------------------------------------
 
 # the panel-sized operands and results a gradient pays FOR THE DESIGN, beside
-# the CSS pair's own (``pallas_kernels.CSS_ADJOINT_PANELS``): the residual
-# ``u3 = y3 - X @ beta'`` reads ``y3`` and writes ``u3``, the adjoint call
-# writes the data cotangent ``g_u`` and ``X' @ g_u`` reads it.  A stage span's
-# ``xreg_panel_moves``; tests/test_regression_arma.py holds it to the traced
-# programs.  Forming ``u_t`` and ``X' g_u`` inside the CSS kernels would make
-# it 0 (ROADMAP.md, speed queue).
-XREG_PANEL_MOVES = 4
+# the CSS pair's own (``pallas_kernels.CSS_ADJOINT_PANELS``): the both-mode
+# forward call writes the residual ``u3`` it formed beside its errors, and
+# the adjoint reads it where a plain fit's reads ``y3``.  (4 in the
+# composition this replaced, PR 49: ``y3`` in and ``u3`` out of an XLA
+# residual, the data cotangent ``g_u`` out of the adjoint and into ``X' @
+# g_u``.)  A stage span's ``xreg_panel_moves``; tests/test_regression_arma.py
+# holds it to the traced programs.
+XREG_PANEL_MOVES = 1
 
 _NOT_WRITTEN = (
     "fit_shared fits ONE design X [time, k] shared by every row with "
@@ -189,17 +193,6 @@ class _DesignFolded:
     x: jax.Array
     t: int
     y_rows: Optional[jax.Array] = None
-
-    def residual(self, beta):
-        """``u3 = y3 - x @ beta'``, folded: what the CSS kernels read.
-        Written as ``y3 + x @ (-beta)'``: the sign is taken on the ``[B, k]``
-        coefficients, so the adjoint's panel ``g_u`` reaches the transposed
-        product as it is (negated after the subtraction, XLA negates the
-        panel in a pass of its own: 3% of the cell's window on the chip,
-        PERF.md §6, PR 49)."""
-        from ..ops import pallas_kernels as pk
-
-        return self.y3 + pk.design_plane(self.x, -beta)
 
     def take(self, idxc) -> "_DesignFolded":
         """The series ``idxc`` (``lockstep.Family.take``); the design is
@@ -246,10 +239,9 @@ def _shared_family(order, backend: str, align_mode: Optional[str] = None,
                    x=None) -> lockstep.Family:
     """The shared-design fit, as ``arima._css_family`` states the plain one:
     ONE fold, the least-squares start by one product, Hannan-Rissanen on its
-    residual, the CSS kernels on ``u3 = y3 - x @ beta'`` (their data
-    cotangent carries the coefficients' gradient through the product's
-    transpose), the same mathematics on ``lax.scan`` as the portable
-    objective.
+    residual, the CSS kernels on ``(y3, x, beta)`` (they form ``u = y - x @
+    beta'`` and the coefficients' gradient ``-x' dS/du`` in VMEM), the same
+    mathematics on ``lax.scan`` as the portable objective.
 
     The optimizer's point is ``[b, phi, theta]`` with ``beta = beta0 +
     units b``: a row's coefficients as offsets from its least-squares start
@@ -300,7 +292,10 @@ def _shared_family(order, backend: str, align_mode: Optional[str] = None,
                                        n, pk.series_major(y3))
                 beta0 = pk.design_project(jnp.pad(w, ((0, 0), (0, pad))), y3,
                                           bsz)
-                u3 = folded.residual(beta0)
+                # the residual as a PANEL, once a chunk: Hannan-Rissanen's
+                # sweeps read it (the objective's calls form it in VMEM)
+                u3 = pk.css_design_residual(y3, folded.x, beta0, n,
+                                            interpret=interp)
             if not on_kernels or not pk.hr_structural_ok(p, q):
                 beta0 = jnp.dot(yb, w.T, precision=highest)
                 u = yb - jnp.dot(beta0, x.T, precision=highest)
@@ -312,8 +307,9 @@ def _shared_family(order, backend: str, align_mode: Optional[str] = None,
                 arma0 = arima.hannan_rissanen_batched(u, order, False, nvd)
         with jax.named_scope("regression.coefficient_units"):
             if on_kernels:
-                nll0 = pk.css_neg_loglik_folded(arma0, u3, zb3, n, order,
-                                                False, nvd, interpret=interp)
+                nll0 = pk.css_neg_loglik_folded(
+                    arma0, y3, zb3, n, order, False, nvd,
+                    design=(folded.x, beta0), interpret=interp)
             else:
                 nll0 = jax.vmap(lambda a, v, m: arima.css_neg_loglik(
                     a, v, order, False, m))(arma0, u, nvd)
@@ -332,8 +328,9 @@ def _shared_family(order, backend: str, align_mode: Optional[str] = None,
         nvd, beta0, units = rows
         k = folded.x.shape[1]
         return lambda P: pk.css_neg_loglik_folded(
-            P[:, k:], folded.residual(coefficients(P[:, :k], beta0, units)),
-            folded.zb3, folded.t, order, False, nvd, interpret=interp)
+            P[:, k:], folded.y3, folded.zb3, folded.t, order, False, nvd,
+            design=(folded.x, coefficients(P[:, :k], beta0, units)),
+            interpret=interp)
 
     def scan_objective(pr, data):
         yv, n, beta0, units = data
@@ -471,7 +468,7 @@ def _fit_design(y, design, design_attrs: dict, order, backend, max_iters, tol,
         stage1=lambda: _shared_stage1_program(*static, align_mode),
         stage2=lambda: _shared_stage2_program(*static),
         series_block=lambda rows, mode: pk.css_series_block(
-            rows, n, order, mode, want_gy=True),
+            rows, n, order, mode, design=k),
         stage_attrs={"xreg_columns": k, "xreg_panel_moves": XREG_PANEL_MOVES,
                      "lag_terms": p + q, "lag_span": max(p, q),
                      "adjoint_panels": pk.CSS_ADJOINT_PANELS})

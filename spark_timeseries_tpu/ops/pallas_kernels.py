@@ -108,9 +108,12 @@ def _vmem_bytes(layout, r: int = 1) -> int:
     width ``r``: every input and output block twice (the pipeline's double
     buffer, the ``_prev`` neighbour blocks included), scratch once.  ``ins``
     / ``outs``: ``(leading size, index map)`` pairs of ``(n, 8 r, 128)``
-    blocks; ``scratch``: leading sizes."""
+    blocks (an entry with a third element states its own block shape, and
+    counts as ``n`` tiles a register all the same: :func:`_design_block`);
+    ``scratch``: leading sizes."""
     ins, outs, scratch = layout
-    return (2 * sum(n for n, _ in ins + outs) + sum(scratch)) * r * _TILE_BYTES
+    return ((2 * sum(e[0] for e in ins + outs) + sum(scratch))
+            * r * _TILE_BYTES)
 
 
 def series_rows(nsub: int, layout, r_best: int) -> int:
@@ -362,9 +365,35 @@ def _rev_panel(t):
 # the backward carries the adjoints of the first max(M) positions of the
 # next-later chunk (scratch; max(A) more for the data cotangent) and
 # accumulates the k parameter gradients in the revisited output block.
+#
+# A SHARED DESIGN (``nx`` > 0 columns; regression with ARMA errors): the
+# series the recurrence runs on is ``y_t - x_t' beta``, ``x [tp, nx]`` the
+# same for every series and ``beta`` per series — ``nx`` more parameter
+# planes after the ``k`` above, and the design's rows of the time chunk one
+# more operand.  The forward forms that residual in VMEM BEFORE its serial
+# loop, a product on the MXU a sublane row of the block: ``x_chunk [cs, nx] @
+# beta[:, n, :] [nx, 128]`` is ``[cs, 128]`` with time on the sublanes, the
+# layout a sublane-strided slice ``ref[:, n, :]`` of a ``(cs, 8 R, 128)``
+# block reads and writes as it stands, so ``u[:, n, :] = y[:, n, :] -
+# product`` and nothing is turned; the loop then reads ``u`` where it read
+# ``y``.  "sum" keeps ``u`` in scratch; "both" writes it out beside the
+# errors, and the adjoint reads ``(u3, e3)`` as it reads ``(y3, e3)`` of a
+# plain fit; mode "u" stops after the residual and writes it alone (the
+# start's Hannan-Rissanen sweep reads that panel).  The adjoint forms no
+# data cotangent ``g_t = dS/du_t = al_t - sum_{i in A} a_i al_{t+i}`` at
+# all: its loop leaves the final ``al_t`` in its scratch, and since
+#   sum_t x_t g_t = sum_s (x_s - sum_{i in A} a_i x_{s-i}) al_s,
+# AFTER the loop ``[-x_chunk, x_chunk shifted by each i in A]' @ al`` — ONE
+# transposed product a sublane row, the same slices, the shifts taken of the
+# WHOLE design outside the call (so nothing crosses a chunk) — lands in the
+# ``nx`` planes' rows of the revisited gradient block, the lag blocks scaled
+# by the series' own ``a_i``.  Both kernels take the chunk's products in
+# slabs of at most 64 steps, a loop (:func:`_design_slab`).  Every product
+# is f32 at ``HIGHEST`` (six bfloat16 passes of the MXU: PRECISION.md).
+# ``nx`` = 0 is the plain kernel, equation for equation.
 
 
-def _css_fwd_kernel(ar, ma, t_limit, cs, hp, mode, *refs):
+def _css_fwd_kernel(ar, ma, t_limit, cs, hp, mode, *refs, nx=0):
     # mode "e":    errors out (the css_errors vjp building block)
     # mode "sum":  ONLY the per-series sum of squares leaves the kernel
     #              (linesearch evaluations: the [B, T] error write + re-read
@@ -375,18 +404,26 @@ def _css_fwd_kernel(ar, ma, t_limit, cs, hp, mode, *refs):
     # mode "tail": ONLY the last q errors leave the kernel (the forecast
     #              carry rebuild: a read-only pass over y instead of a full
     #              [B, T] error write the caller immediately discards)
+    # mode "u":    (a shared design only) the residual panel alone: no
+    #              recurrence runs
     p, q = len(ar), _span(ma)  # AR planes; the MA side's reach
     refs = list(refs)
     y_ref = refs.pop(0)
-    yp_ref = refs.pop(0) if hp else None
+    # with a design the chunk before is u's, not y's: a carry (cu_ref)
+    yp_ref = refs.pop(0) if hp and not nx else None
     par_ref = refs.pop(0)
     zb_ref = refs.pop(0)
+    x_ref = refs.pop(0) if nx else None
     e_ref = refs.pop(0) if mode in ("e", "both") else None
+    u_ref = refs.pop(0) if nx and mode in ("both", "u") else None  # a panel
     css_ref = refs.pop(0) if mode in ("sum", "both") else None
     tail_ref = refs.pop(0) if mode == "tail" else None
     if mode in ("sum", "tail") and q > 0:
         e_ref = refs.pop(0)  # scratch: lag reads still need recent errors
+    if nx and u_ref is None:
+        u_ref = refs.pop(0)  # scratch: u never leaves VMEM
     ce_ref = refs.pop(0)
+    cu_ref = refs.pop(0) if nx and hp and ar else None
     c = pl.program_id(1)
     base = c * cs
     zb = zb_ref[0]
@@ -400,11 +437,22 @@ def _css_fwd_kernel(ar, ma, t_limit, cs, hp, mode, *refs):
         if css_ref is not None:
             css_ref[0] = zero
 
+    if nx:
+        _design_residual(y_ref, x_ref, par_ref, 1 + p + len(ma), nx, u_ref)
+        if mode == "u":
+            return
+        y_ref = u_ref  # the recurrence reads u where it read y
+
+    def y_far(tl, i):  # y_{t-i} of the chunk before
+        if cu_ref is not None:  # slot s: u at global base - max(A) + s
+            return cu_ref[jnp.clip(_span(ar) + tl - i, 0, _span(ar) - 1)]
+        return yp_ref[jnp.clip(cs + tl - i, 0, cs - 1)] if hp else 0.0
+
     def body(tl, acc):
         t = base + tl
         pred = par_ref[0]
         for n, i in enumerate(ar, 1):
-            far = yp_ref[jnp.clip(cs + tl - i, 0, cs - 1)] if hp else 0.0
+            far = y_far(tl, i)
             yv = jnp.where(tl - i >= 0, y_ref[jnp.maximum(tl - i, 0)], far)
             pred += par_ref[n] * jnp.where(t - i >= 0, yv, 0.0)
         for n, j in enumerate(ma, 1):
@@ -440,14 +488,54 @@ def _css_fwd_kernel(ar, ma, t_limit, cs, hp, mode, *refs):
     # slot s holds e at global (base + cs) - q + s for the next chunk
     for j in range(q):
         ce_ref[j] = e_ref[cs - q + j]
+    if cu_ref is not None:
+        for j in range(_span(ar)):
+            cu_ref[j] = u_ref[cs - _span(ar) + j]
+
+
+def _design_residual(y_ref, x_ref, par_ref, k, nx, u_ref):
+    """``u = y - x_chunk @ beta`` over a block, ``beta`` the ``nx`` planes
+    after the first ``k`` of ``par_ref``: a product a sublane row, time on
+    the sublanes on both sides (the CSS section's header).  At two
+    registers of series the pass is the MXU's own time (1.0 ms of 1.07 over
+    ``[131072, 960]``: six passes of eight-row pushes against 32 of the
+    array's 128 columns), the rows' single-sublane stores hidden under it;
+    other relayouts compile to no fewer bundles (PERF.md §6, PR 51)."""
+    ts = _design_slab(y_ref.shape[0])
+
+    def slab(i, carry):
+        t = pl.ds(pl.multiple_of(i * ts, _SUBL), ts)
+        x = x_ref[t, :]
+        for n in range(y_ref.shape[1]):
+            u_ref[t, n, :] = y_ref[t, n, :] - jnp.dot(
+                x, par_ref[pl.ds(k, nx), n, :],
+                precision=lax.Precision.HIGHEST,
+                preferred_element_type=jnp.float32)
+        return carry
+
+    _fori(y_ref.shape[0] // ts, slab, 0)
+
+
+def _design_slab(cs: int) -> int:
+    """The time steps one product of the design takes: the chunk in slabs of
+    at most 64 steps, whole sublane tiles, a LOOP over them — Mosaic unrolls
+    a product's pushes, pops and row stores, and a kernel that held every
+    row's product over the whole chunk was 23.5k bundles a block before its
+    recurrence: the family's three programs 7 MB more in the compile cache
+    and 5.7 s more of a warm ``setup_s`` reading them (PERF.md §6, PR 51)."""
+    return _SUBL * max(d for d in range(1, 9) if (cs // _SUBL) % d == 0)
 
 
 def _css_bwd_kernel(ar, ma, t_limit, cs, nchunk, hp, want_gy, g_plane,
-                    *refs):
+                    *refs, nx=0):
     # p / q: the reach of each side (the carries' depth); the parameter
     # planes number one per LIVE lag.  ``g_plane``: ``g_ref`` is not the
     # cotangent of e as a panel but the plane gbar of the sum of squares'
-    # cotangent, and the kernel forms ``2 e gbar`` from the errors it reads
+    # cotangent, and the kernel forms ``2 e gbar`` from the errors it reads.
+    # ``nx``: the panel is a shared design's residual: the loop leaves the
+    # final adjoints in ``adj_ref`` and ``xs_ref' @`` them lands in ``nx``
+    # more rows of ``gpar_ref`` (``xs_ref``: ``-x_chunk`` beside its shifted
+    # blocks, the CSS section's header)
     p, q, npar = _span(ar), _span(ma), len(ar)
     refs = list(refs)
     y_ref = refs.pop(0)
@@ -457,6 +545,7 @@ def _css_bwd_kernel(ar, ma, t_limit, cs, nchunk, hp, want_gy, g_plane,
     par_ref = refs.pop(0)
     zb_ref = refs.pop(0)
     g_ref = refs.pop(0)
+    xs_ref = refs.pop(0) if nx else None
     gpar_ref = refs.pop(0)
     gy_ref = refs.pop(0) if want_gy else None
     adj_ref = refs.pop(0)
@@ -474,6 +563,8 @@ def _css_bwd_kernel(ar, ma, t_limit, cs, nchunk, hp, want_gy, g_plane,
             ca_ref[j] = zero
         for r in range(k):
             gpar_ref[r] = zero
+        if nx:
+            gpar_ref[pl.ds(k, nx)] = jnp.zeros((nx, *zero.shape), jnp.float32)
         if want_gy:
             for i_ in range(max(p, 1)):
                 cap_ref[i_] = zero
@@ -493,6 +584,8 @@ def _css_bwd_kernel(ar, ma, t_limit, cs, nchunk, hp, want_gy, g_plane,
                 0.0,
             )
         a = jnp.where(live, aval, 0.0)
+        if nx:
+            adj_ref[tl] = a  # the slot is dead (below): keep the FINAL a_t
         if want_gy:
             # adj_ref[s] for s > tl has already been read (descending walk)
             # and every theta adjustment targeting it landed before its own
@@ -541,6 +634,25 @@ def _css_bwd_kernel(ar, ma, t_limit, cs, nchunk, hp, want_gy, g_plane,
     accs = _fori(cs, body, (zero,) * k)
     for r in range(k):
         gpar_ref[r] = gpar_ref[r] + accs[r]
+    if nx:
+        ts = _design_slab(cs)
+
+        def slab(i, carry):
+            t = pl.ds(pl.multiple_of(i * ts, _SUBL), ts)
+            xs = xs_ref[t, :]
+            for n in range(zero.shape[0]):
+                d = lax.dot_general(
+                    xs, adj_ref[t, n, :], (((0,), (0,)), ((), ())),
+                    precision=lax.Precision.HIGHEST,
+                    preferred_element_type=jnp.float32)
+                g = d[:nx]
+                for m in range(1, npar + 1):
+                    g = g + par_ref[pl.ds(m, 1), n, :] * d[m * nx:(m + 1) * nx]
+                gpar_ref[pl.ds(k, nx), n, :] = (
+                    gpar_ref[pl.ds(k, nx), n, :] + g)
+            return carry
+
+        _fori(cs // ts, slab, 0)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1, 2))
@@ -586,42 +698,74 @@ def _css_fwd_call(p, q, interpret, mode, params, yd, zb):
 # 1.58 / 1.56, e 2.00 / 1.56 / 1.56, the last two at the HBM's pace; "tail"
 # writes no panel either and rides with "sum")
 _CSS_R = {"sum": 4, "both": 4, "e": 4, "tail": 4}
+# with a shared design's products in the call (PERF.md §6, PR 51: ms a call
+# over [131072, 960] and 32 columns at R = 1 / 2 / 4, the products in slabs
+# of 64 steps — sum 3.45 / 2.28 / 2.03, both 3.49 / 2.32 / 2.29 (VMEM
+# refuses 4: three panels double-buffered), u 1.58 / 1.56 / 1.58, adjoint
+# 4.06 / 2.72 / 2.21; the forward entries were filled for the products of a
+# whole chunk unrolled, 2.24 / 2.41 for sum, and "sum" at 4 has not run in
+# the cell: PERF.md §7)
+_CSS_DESIGN_R = {"sum": 2, "both": 2, "u": 2, "adjoint": 4}
 
 
-def _css_fwd_layout(p, q, mode, t):
+def _css_r_best(mode: str, nx: int) -> int:
+    if nx:
+        return _CSS_DESIGN_R[mode]
+    return _ADJOINT_R["css"] if mode == "adjoint" else _CSS_R[mode]
+
+
+def _design_block(cs, shape, imap):
+    """The shared design's rows of a time chunk as a layout entry: a 2-D
+    block ``shape`` of ``x [tp, nx]`` (or of its transpose), whatever the
+    series block; at most ``cs / 8`` tiles."""
+    return (cs // _SUBL, imap, shape)
+
+
+def _css_fwd_layout(p, q, mode, t, nx=0):
     """The forward CSS call's blocks -> ``(ins, outs, scratch)``
     (:func:`_vmem_bytes`): a parameter plane per live lag, the error carry
-    and the tail as deep as the largest MA lag."""
+    and the tail as deep as the largest MA lag; with a shared design of
+    ``nx`` columns its planes, its rows of the chunk, the residual ``u`` (a
+    scratch, or in modes "both" and "u" a panel out) and, past one chunk,
+    ``u``'s carry in the place of the panel's neighbour block."""
     ar, ma = _lags(p), _lags(q)
     q = _span(ma)
     _, cs, nchunk = _time_layout(t)
-    ins = ([(cs, _cur)] + ([(cs, _prev)] if nchunk > 1 else [])
-           + [(1 + len(ar) + len(ma), _fixed), (1, _fixed)])
+    ins = ([(cs, _cur)] + ([(cs, _prev)] if nchunk > 1 and not nx else [])
+           + [(1 + len(ar) + len(ma) + nx, _fixed), (1, _fixed)]
+           + ([_design_block(cs, (cs, nx), lambda blk, c: (c, 0))]
+              if nx else []))
     outs = []
     if mode in ("e", "both"):
+        outs.append((cs, _cur))
+    if nx and mode in ("both", "u"):
         outs.append((cs, _cur))
     if mode in ("sum", "both"):
         outs.append((1, _fixed))
     if mode == "tail":
         outs.append((max(q, 1), _fixed))
     # errors live in VMEM only; the cross-chunk error carry
-    scratch = ([cs] if mode in ("sum", "tail") and q > 0 else []) + [max(q, 1)]
+    scratch = (([cs] if mode in ("sum", "tail") and q > 0 else [])
+               + ([cs] if nx and mode not in ("both", "u") else [])
+               + [max(q, 1)]
+               + ([_span(ar)] if nx and nchunk > 1 and ar else []))
     return ins, outs, scratch
 
 
 def css_series_block(rows: int, t: int, order: Order, mode: str = "sum",
-                     want_gy: bool = False) -> int:
+                     want_gy: bool = False, design: int = 0) -> int:
     """Series per grid step of the CSS kernel over ``rows`` series of
     (differenced) length ``t``: ``1024 * R`` (:func:`series_rows`) — of a
     forward ``mode``, or of the fit objective's ``"adjoint"`` (``want_gy``:
-    the adjoint that also writes the data cotangent's panel, a fit whose
-    panel depends on its parameters).  ``order``'s ``p`` / ``q`` may be lag
-    sets (:func:`_lags`)."""
+    the adjoint that also writes the data cotangent's panel, a caller that
+    perturbs the data).  ``design``: the columns of a shared design the call
+    takes (:func:`css_neg_loglik_folded`), 0 for none.  ``order``'s ``p`` /
+    ``q`` may be lag sets (:func:`_lags`)."""
     p, _, q = order
-    layout, best = ((_css_bwd_layout(p, q, t, want_gy), _ADJOINT_R["css"])
-                    if mode == "adjoint"
-                    else (_css_fwd_layout(p, q, mode, t), _CSS_R[mode]))
-    return _SBLK * series_rows(_nsub(rows), layout, best)
+    nx = design + _pad_to(design, _SUBL)
+    layout = (_css_bwd_layout(p, q, t, want_gy, nx=nx) if mode == "adjoint"
+              else _css_fwd_layout(p, q, mode, t, nx))
+    return _SBLK * series_rows(_nsub(rows), layout, _css_r_best(mode, nx))
 
 
 def _block_call(kernel, layout, r, interpret, args):
@@ -631,10 +775,14 @@ def _block_call(kernel, layout, r, interpret, args):
     map but ``_fixed``) is a panel too, any other a few planes."""
     ins, outs, scratch = layout
     tp, nsub, _ = args[0].shape
+
+    def spec(n, imap, *shape):  # :func:`_design_block` states its own
+        return pl.BlockSpec(*shape, imap) if shape else _bs(n, imap, r)
+
     return pl.pallas_call(
         kernel,
         grid=(nsub // (_SUBL * r), tp // ins[0][0]),
-        in_specs=[_bs(n, im, r) for n, im in ins],
+        in_specs=[spec(*entry) for entry in ins],
         out_specs=[_bs(n, im, r) for n, im in outs],
         out_shape=[jax.ShapeDtypeStruct(
             (n if im is _fixed else tp, nsub, _LANES), args[0].dtype)
@@ -646,21 +794,28 @@ def _block_call(kernel, layout, r, interpret, args):
     )(*args)
 
 
-def _css_fwd_call_f(p, q, interpret, mode, params, y3, zb3, t, _r=None):
+def _css_fwd_call_f(p, q, interpret, mode, params, y3, zb3, t, _r=None,
+                    x=None):
     # pre-FOLDED entry: y3/zb3 already in kernel layout.  The fit objective
     # is evaluated hundreds of times inside one lax.while_loop, and XLA does
     # not reliably hoist the [B, T] zero-mask + fold transpose out of the
     # loop body — callers that fold once (css_prefold) skip that cost on
-    # every evaluation.  ``_r`` forces the block width (tests only).
+    # every evaluation.  ``_r`` forces the block width (tests only).  ``x
+    # [tp, nx]``: a shared design, its coefficients the last ``nx`` columns
+    # of ``params`` (the CSS section's header); "both" then returns ``(e3,
+    # u3, css3)``.
     par3 = _fold(params)  # [B, k]: trivially small
     _, cs, nchunk = _time_layout(t)
     hp = nchunk > 1
-    layout = _css_fwd_layout(p, q, mode, t)
-    r = _r or series_rows(y3.shape[1], layout, _CSS_R[mode])
+    nx = 0 if x is None else x.shape[1]
+    layout = _css_fwd_layout(p, q, mode, t, nx)
+    r = _r or series_rows(y3.shape[1], layout, _css_r_best(mode, nx))
     outs = _block_call(
         functools.partial(_css_fwd_kernel, _lags(p), _lags(q), t, cs, hp,
-                          mode),
-        layout, r, interpret, (*((y3, y3) if hp else (y3,)), par3, zb3))
+                          mode, nx=nx),
+        layout, r, interpret,
+        (*((y3, y3) if hp and not nx else (y3,)), par3, zb3,
+         *((x,) if nx else ())))
     return outs, (y3, par3, zb3)
 
 
@@ -760,6 +915,47 @@ def _css_ss_f_bwd(p, q, interpret, t, b, resid, gbar, _r=None):
 _css_ss_f.defvjp(_css_ss_f_fwd, _css_ss_f_bwd, symbolic_zeros=True)
 
 
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1, 2, 3, 4))
+def _css_ss_x(p, q, interpret: bool, t: int, b: int, params, y3, zb3, x):
+    """:func:`_css_ss_f` of the residual of a SHARED DESIGN ``x [tp, nx]``
+    (the CSS section's header): the per-series sum of squared errors of ``y
+    - x @ beta'``, ``beta`` the last ``nx`` columns of ``params``, formed in
+    the calls.  Differentiable in ``params`` alone; its rule is
+    :func:`_css_ss_f`'s with the residual ``u3`` the both-mode forward wrote
+    standing where ``y3`` stands, and the same bitwise agreement between the
+    value-only and the saving forward."""
+    (css3,), _ = _css_fwd_call_f(p, q, interpret, "sum", params, y3, zb3, t,
+                                 x=x)
+    return _unfold(css3, b)[:, 0]
+
+
+def _css_ss_x_fwd(p, q, interpret, t, b, params, y3, zb3, x):
+    if y3.perturbed or x.perturbed:
+        raise NotImplementedError(
+            "the CSS kernels differentiate a shared design's objective in "
+            "its parameters alone")
+    (e3, u3, css3), (_, par3, zb3_) = _css_fwd_call_f(
+        p, q, interpret, "both", params.value, y3.value, zb3.value, t,
+        x=x.value)
+    return _unfold(css3, b)[:, 0], (u3, par3, zb3_, e3, x.value)
+
+
+def _css_ss_x_bwd(p, q, interpret, t, b, resid, gbar, _r=None):
+    u3, par3, zb3, e3, x = resid
+    if isinstance(gbar, SymbolicZero):  # output provably unused
+        gparams = jnp.zeros((b, par3.shape[0]), e3.dtype)
+    else:
+        gparams = _css_errors_bwd_f(
+            p, q, interpret, (u3, par3, zb3, e3),
+            _fold(gbar[:, None].astype(e3.dtype)), b, t, g_plane=True, _r=_r,
+            x=x)
+    # the panel, the mask and the design are constants: no cotangent formed
+    return gparams, None, None, None
+
+
+_css_ss_x.defvjp(_css_ss_x_fwd, _css_ss_x_bwd, symbolic_zeros=True)
+
+
 def css_prefold(y, order: Order, n_valid=None, *, lags=()):
     """Fold a panel into the CSS kernel layout ONCE -> ``(y3, zb3)`` for
     :func:`css_neg_loglik_folded`, differencing it on the way at ``lags``.
@@ -826,6 +1022,31 @@ def design_plane(x, coef):
                       precision=lax.Precision.HIGHEST)
 
 
+@_scoped("pallas.css_design_residual")
+def css_design_residual(y3, x, beta, n: int, *, interpret: bool = False):
+    """``u3 = y3 - x @ beta'`` as a folded PANEL, by the CSS forward call's
+    own prologue and nothing after it (mode "u": the products on the MXU at
+    ``HIGHEST``, one panel read and one written, where the XLA residual
+    ``y3 + design_plane(x, -beta)`` took twice the time of those two moves):
+    what a start that needs the residual itself reads (Hannan-Rissanen's
+    sweeps).  ``x [tp, k]``, ``beta [B, k]``, ``n`` the true length."""
+    params, x = _beside_design(
+        jnp.zeros((beta.shape[0], 1), beta.dtype), x, beta)
+    (u3,), _ = _css_fwd_call_f(0, 0, interpret, "u", params, y3,
+                               jnp.zeros((1, *y3.shape[1:]), y3.dtype), n,
+                               x=x)
+    return u3
+
+
+def _beside_design(params_k, x, beta):
+    """-> the kernel's planes ``[params_k, beta]`` and the design, both with
+    the design's columns padded by zeros to whole sublane tiles."""
+    pad = _pad_to(x.shape[1], _SUBL)
+    return (jnp.concatenate(
+        [params_k, beta, jnp.zeros((beta.shape[0], pad), beta.dtype)],
+        axis=1), jnp.pad(x, ((0, 0), (0, pad))))
+
+
 def design_project(w, y3, b: int):
     """``(w @ y)' [B, k]`` for ``w [k, tp]`` shared by every series and a
     folded panel ``y3``: with ``w = (x'x)^-1 x'`` the least-squares
@@ -837,9 +1058,16 @@ def design_project(w, y3, b: int):
 @_scoped("pallas.css_neg_loglik")
 def css_neg_loglik_folded(params, y3, zb3, n: int, order: Order,
                           include_intercept: bool, n_valid=None, *,
-                          interpret: bool = False):
+                          design=None, interpret: bool = False):
     """Batched CSS negative log-likelihood from a pre-folded panel
-    (:func:`css_prefold`).  Matches :func:`css_neg_loglik` exactly."""
+    (:func:`css_prefold`).  Matches :func:`css_neg_loglik` exactly.
+
+    ``design = (x [tp, k], beta [B, k])``: the likelihood of the ARMA errors
+    of ``y - x @ beta'``, a design shared by every series (zero rows past
+    ``n``) and per-series coefficients — the kernels form the residual and,
+    differentiated, ``-x' dS/du`` themselves (the CSS section's header), so
+    ``beta``'s gradient comes back through ``params``' path and no
+    panel-sized product runs beside the calls."""
     p, _, q = order
     b = params.shape[0]
     if include_intercept:
@@ -848,16 +1076,20 @@ def css_neg_loglik_folded(params, y3, zb3, n: int, order: Order,
         params_k = jnp.concatenate(
             [jnp.zeros((b, 1), params.dtype), params], axis=1
         )
-    return _css_nll_f(p, q, interpret, n, params_k, y3, zb3, n_valid)
+    if design is None:
+        return _css_nll_f(p, q, interpret, n, params_k, y3, zb3, n_valid)
+    params_k, x = _beside_design(params_k, *design)
+    return _css_nll_f(p, q, interpret, n, params_k, y3, zb3, n_valid, x)
 
 
-def _css_nll_f(p, q, interpret, n, params_k, y3, zb3, n_valid):
+def _css_nll_f(p, q, interpret, n, params_k, y3, zb3, n_valid, x=None):
     """The concentrated Gaussian likelihood of the kernel's sum of squares,
     ``n_eff`` the valid length less the AR side's reach."""
     b = params_k.shape[0]
     nv = (jnp.full((b,), n, params_k.dtype) if n_valid is None
           else n_valid.astype(params_k.dtype))
-    css = _css_ss_f(p, q, interpret, n, b, params_k, y3, zb3)
+    css = (_css_ss_f(p, q, interpret, n, b, params_k, y3, zb3) if x is None
+           else _css_ss_x(p, q, interpret, n, b, params_k, y3, zb3, x))
     n_eff = nv - _span(_lags(p))
     sigma2 = css / n_eff
     return 0.5 * n_eff * (jnp.log(2.0 * jnp.pi * sigma2) + 1.0)
@@ -902,25 +1134,33 @@ def _css_errors_bwd(p, q, interpret, res, g):
 CSS_ADJOINT_PANELS = 2
 
 
-def _css_bwd_layout(p, q, t, want_gy=False, g_plane=True):
+def _css_bwd_layout(p, q, t, want_gy=False, g_plane=True, nx=0):
     """The CSS adjoint call's blocks, as :func:`_css_fwd_layout` states the
     forward's: the panel and the error panel (each with its neighbour past
     one chunk), parameters, mask, the cotangent — a plane, or ``css_errors``'
-    panel — and with ``want_gy`` the data cotangent's panel out."""
+    panel — and with ``want_gy`` the data cotangent's panel out; with a
+    shared design of ``nx`` columns (the panel is its residual ``u3``) the
+    design's rows of the chunk come in, a column block for the product
+    itself and one for each AR lag's shift, and ``nx`` more planes of
+    gradient go out."""
     ar, ma = _lags(p), _lags(q)
-    k = 1 + len(ar) + len(ma)
+    k = 1 + len(ar) + len(ma) + nx
     panel = _rev_panel(t)
+    _, cs, nchunk = _time_layout(t)
     ins = panel + panel + [(k, _fixed), (1, _fixed),
                            (1, _fixed) if g_plane else panel[0]]
+    if nx:
+        ins.append(_design_block(cs, (cs, (1 + len(ar)) * nx),
+                                 lambda blk, c: (nchunk - 1 - c, 0)))
     outs = [(k, _fixed)] + ([panel[0]] if want_gy else [])
     # the adjoint path; its carry across chunks (and the data cotangent's)
-    scratch = ([panel[0][0], max(_span(ma), 1)]
+    scratch = ([cs, max(_span(ma), 1)]
                + ([max(_span(ar), 1)] if want_gy else []))
     return ins, outs, scratch
 
 
 def _css_errors_bwd_f(p, q, interpret, res, g3, b, t, want_gy=False,
-                      g_plane=False, _r=None):
+                      g_plane=False, _r=None, x=None):
     """Adjoint core on FOLDED cotangents -> ``gparams [B, k]`` or, with
     ``want_gy``, ``(gparams, gy3)`` where ``gy3`` is the data cotangent in
     the folded layout (an extra kernel output only callers that perturb the
@@ -928,21 +1168,35 @@ def _css_errors_bwd_f(p, q, interpret, res, g3, b, t, want_gy=False,
     errors as a panel (``css_errors``' rule: any cotangent) or, with
     ``g_plane``, the plane gbar of the sum of squares' (``_css_ss_f``'s
     rule: the kernel forms ``2 e gbar`` itself); which rule is calling is
-    all that chooses.  ``_r`` forces the block width (tests and the sweep)."""
+    all that chooses.  ``_r`` forces the block width (tests and the sweep).
+    ``x [tp, nx]``: ``y3`` is a shared design's residual ``u3`` and
+    ``gparams`` carries the ``nx`` coefficients' gradient ``-x' dS/du`` in
+    its last columns, no data cotangent formed (the CSS section's header)."""
     y3, par3, zb3, e3 = res
     _, cs, nchunk = _time_layout(t)
     hp = nchunk > 1
-    layout = _css_bwd_layout(p, q, t, want_gy, g_plane)
-    r = _r or series_rows(y3.shape[1], layout, _ADJOINT_R["css"])
+    nx = 0 if x is None else x.shape[1]
+    layout = _css_bwd_layout(p, q, t, want_gy, g_plane, nx)
+    r = _r or series_rows(y3.shape[1], layout, _css_r_best("adjoint", nx))
     outs = _block_call(
         functools.partial(_css_bwd_kernel, _lags(p), _lags(q), t, cs, nchunk,
-                          hp, want_gy, g_plane),
+                          hp, want_gy, g_plane, nx=nx),
         layout, r, interpret,
-        (*((y3, y3, e3, e3) if hp else (y3, e3)), par3, zb3, g3))
+        (*((y3, y3, e3, e3) if hp else (y3, e3)), par3, zb3, g3,
+         *((_design_shifts(x, _lags(p)),) if nx else ())))
     gparams = _unfold(outs[0], b)
     if want_gy:
         return gparams, outs[1]
     return gparams
+
+
+def _design_shifts(x, ar):
+    """``[-x, x shifted down by each lag of ar] [tp, (1 + len(ar)) nx]``:
+    column block ``m`` holds ``x_{t-i}`` at row ``t`` (zero before the
+    series' start), what the adjoint's transposed products take."""
+    tp = x.shape[0]
+    return jnp.concatenate(
+        [-x] + [jnp.pad(x, ((i, 0), (0, 0)))[:tp] for i in ar], axis=1)
 
 
 css_errors.defvjp(_css_errors_fwd, _css_errors_bwd, symbolic_zeros=True)

@@ -10,6 +10,7 @@ import jax
 import jax.extend
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
 from spark_timeseries_tpu.models import arima
 from spark_timeseries_tpu.ops import pallas_kernels as pk
@@ -149,6 +150,25 @@ def _stage_programs(family, b, t):
     from spark_timeseries_tpu.models import holtwinters as hw
 
     y = jax.ShapeDtypeStruct((b, t), jnp.float32)
+    if family == "harmonic-arma":
+        # a shared design of 1 + 2 (3 + 2) columns (periods 12 and 48 at 3
+        # and 2 harmonics) beside ARMA(1,1) errors: the design, its
+        # projector and its columns' autocovariances ride as operands
+        from spark_timeseries_tpu.models import regression_arima as ra
+
+        k, f32 = 11, jnp.float32
+        args = (y, jax.ShapeDtypeStruct((t, k), f32),
+                jax.ShapeDtypeStruct((k, t), f32),
+                jax.ShapeDtypeStruct((k, 1 + ra._UNIT_LAGS), f32))
+        static = ((1, 0, 1), "pallas-interpret", 13, 1e-4)
+        stage1 = ra._shared_stage1_program.__wrapped__(*static, "dense")
+        aux = jax.eval_shape(stage1, *args)[1]
+        return pk.CSS_ADJOINT_PANELS, (
+            (stage1, args, b),
+            (ra._shared_fit_program.__wrapped__(*static, "dense", True),
+             args, b),
+            (ra._shared_stage2_program.__wrapped__(*static),
+             (aux["starts"][0], aux["fin"]), optim.compaction_cap(b)))
     if family == "arima-grid3":
         # a fused order search: 3 orders a row, so a third of the rows make
         # the same cells; the adjoint reads the ONE panel and the cells'
@@ -255,3 +275,132 @@ def _traced_fit_parity(lazy, fit, panel, **kw):
     # the same fit under a caller's jit (the panel a Tracer: stage 1 and
     # stage 2 composed in one trace) against the eager lazy pair
     _dist_parity(lazy, jax.jit(fit)(panel), **kw)
+
+
+_DESIGN_ORDER = (1, 0, 1)
+
+
+def _design_case(k, r, t, seed=0):
+    """One block of ``1024 r`` series: a level, the design's part and AR(1)
+    noise; the design's columns padded to ``nx`` (zero columns, zero
+    coefficients), its rows past ``t`` zero."""
+    b = 1024 * r
+    rng = np.random.default_rng(seed + 7 * k + t)
+    nx = k + pk._pad_to(k, 8)
+    tp = pk._time_layout(t)[0]
+    x = np.zeros((tp, nx), np.float32)
+    x[:t, :k] = rng.normal(size=(t, k))
+    beta = np.zeros((b, nx), np.float32)
+    beta[:, :k] = rng.normal(size=(b, k))
+    noise = rng.normal(size=(b, t)).astype(np.float32)
+    for i in range(1, t):
+        noise[:, i] += 0.5 * noise[:, i - 1]
+    y = jnp.asarray(10.0 + beta @ x[:t].T + noise)
+    # the coefficients the kernels are handed are NOT the generating ones
+    beta = beta + np.where(beta != 0, 0.1 * rng.normal(size=beta.shape), 0.0)
+    arma = np.column_stack([np.zeros(b), rng.uniform(0.2, 0.8, b),
+                            rng.uniform(-0.4, 0.4, b)])
+    y3, zb3 = pk.css_prefold(y, _DESIGN_ORDER)
+    return (y, y3, zb3, jnp.asarray(x), jnp.asarray(beta, jnp.float32),
+            jnp.asarray(arma, jnp.float32),
+            jnp.asarray(rng.uniform(0.5, 1.5, b), jnp.float32))
+
+
+def _close_to(got, want, rel):
+    got, want = np.asarray(got, np.float64), np.asarray(want, np.float64)
+    np.testing.assert_allclose(got, want, rtol=0,
+                               atol=rel * np.abs(want).max())
+
+
+def _check_fused_design(k, r, t):
+    """The CSS kernels with a shared design of ``k`` columns as an operand,
+    at the forced width ``r`` over ``t`` steps, against (a) the composition
+    they replace — an XLA residual ``y3 + design_plane``, the plain kernels,
+    ``x' g_u`` over the adjoint's data-cotangent panel — and (b) the float64
+    ``lax.scan`` errors (``tests/test_pallas_css_design*.py``)."""
+    y, y3, zb3, x, beta, arma, gbar = _design_case(k, r, t)
+    b, nx = y.shape[0], x.shape[1]
+    par = jnp.concatenate([arma, beta], axis=1)
+
+    # fused: "sum" and "both" give one value bit for bit (_css_ss_f's rule)
+    (css_s,), _ = pk._css_fwd_call_f(1, 1, True, "sum", par, y3, zb3, t,
+                                     _r=r, x=x)
+    (e3, u3, css_b), (_, par3, _) = pk._css_fwd_call_f(
+        1, 1, True, "both", par, y3, zb3, t, _r=r, x=x)
+    assert np.asarray(css_s).tobytes() == np.asarray(css_b).tobytes()
+    g_f = pk._css_ss_x_bwd(1, 1, True, t, b, (u3, par3, zb3, e3, x), gbar,
+                           _r=r)
+    assert g_f[0].shape == (b, 3 + nx) and g_f[1:] == (None, None, None)
+    assert not np.asarray(g_f[0])[:, 3 + k:].any()  # the padding's columns
+
+    # (a) the composition: an XLA residual, the plain kernels, x' g_u
+    u3_c = y3 + pk.design_plane(x, -beta)
+    (e3_c, css_c), (_, par3_c, _) = pk._css_fwd_call_f(
+        1, 1, True, "both", arma, u3_c, zb3, t, _r=r)
+    g_arma, g_u, *_ = pk._css_ss_f_bwd(1, 1, True, t, b,
+                                      (u3_c, par3_c, zb3, e3_c, ()), gbar,
+                                      _r=min(r, 2))
+    g_beta = -pk._unfold(jnp.einsum("tk,tns->kns", x, g_u,
+                                    precision=jax.lax.Precision.HIGHEST), b)
+    _close_to(u3, u3_c, 1e-6)
+    # the start's residual panel is the same prologue and nothing after it
+    u3_p = pk.css_design_residual(y3, x[:, :k], beta[:, :k], t,
+                                  interpret=True)
+    assert np.asarray(u3_p).tobytes() == np.asarray(u3).tobytes()
+    _close_to(e3, e3_c, 1e-5)
+    np.testing.assert_allclose(css_b, css_c, rtol=1e-5)
+    _close_to(g_f[0][:, :3], g_arma, 1e-5)
+    _close_to(g_f[0][:, 3:], g_beta, 1e-5)
+
+    # (b) the float64 scan, on a few rows of the block
+    rows = np.r_[0:4, b - 4:b]
+    y64, x64 = np.asarray(y, np.float64)[rows], np.asarray(x, np.float64)[:t]
+
+    def css64(arma_r, beta_r, y_r):
+        e = arima._css_errors(arma_r[1:], y_r - x64 @ beta_r, _DESIGN_ORDER,
+                              False)
+        return jnp.sum(e * e)
+
+    v64, (ga64, gb64) = jax.vmap(jax.value_and_grad(css64, (0, 1)))(
+        jnp.asarray(arma, jnp.float64)[rows],
+        jnp.asarray(beta, jnp.float64)[rows], jnp.asarray(y64))
+    np.testing.assert_allclose(pk._unfold(css_s, b)[rows, 0], v64, rtol=2e-4)
+    scale = np.asarray(gbar, np.float64)[rows, None]
+    _close_to(np.asarray(g_f[0])[rows, 1:3], scale * ga64[:, 1:], 2e-3)
+    _close_to(np.asarray(g_f[0])[rows, 3:], scale * gb64, 2e-3)
+
+
+def _check_design_entry(t):
+    """``css_neg_loglik_folded(design=)`` over ``t`` steps: 31 columns as they
+    come, against autodiff through the composition."""
+    # the entry pads the columns to whole sublane tiles; the likelihood and
+    # its gradient in (arma, beta); the width is the rule's
+    y, y3, zb3, x, beta, arma, _ = _design_case(31, 1, t, seed=3)
+    x, beta, arma = x[:, :31], beta[:, :31], arma[:, 1:]
+
+    def fused(a, c):
+        return jnp.sum(pk.css_neg_loglik_folded(
+            a, y3, zb3, t, _DESIGN_ORDER, False, design=(x, c),
+            interpret=True))
+
+    def composed(a, c):
+        return jnp.sum(pk.css_neg_loglik_folded(
+            a, y3 + pk.design_plane(x, -c), zb3, t, _DESIGN_ORDER, False,
+            interpret=True))
+
+    f, g = jax.value_and_grad(fused, (0, 1))(arma, beta)
+    f_c, g_c = jax.value_and_grad(composed, (0, 1))(arma, beta)
+    assert float(f) == pytest.approx(float(f_c), rel=1e-6)
+    for got, want in zip(g, g_c):
+        _close_to(got, want, 1e-5)
+    # value-only and value-and-gradient agree to the bit, row by row
+    nll = lambda a: pk.css_neg_loglik_folded(  # noqa: E731
+        a, y3, zb3, t, _DESIGN_ORDER, False, design=(x, beta),
+        interpret=True)
+    both, _ = jax.vjp(nll, arma)
+    assert np.asarray(nll(arma)).tobytes() == np.asarray(both).tobytes()
+    # the data and the design are constants of this objective
+    with pytest.raises(NotImplementedError, match="parameters alone"):
+        jax.grad(lambda v: jnp.sum(pk.css_neg_loglik_folded(
+            arma, v, zb3, t, _DESIGN_ORDER, False, design=(x, beta),
+            interpret=True)))(y3)

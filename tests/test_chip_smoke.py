@@ -302,8 +302,10 @@ def test_pallas_kernels_compile_for_v5e(v5e_chip):
         programs["arima grid9 stage2"] = (
             arima._grid_stage2_program(*grid9),
             [grid_aux["starts"][0], grid_aux["fin"]])
-        # the shared-design regression: both design products beside the
-        # CSS kernels, the adjoint with its data cotangent (R = 2 by VMEM)
+        # the shared-design regression: both design products INSIDE the CSS
+        # kernel calls since ISSUE 51 (MXU dots at HIGHEST over
+        # sublane-strided slices of the blocks); past one chunk the
+        # design's block moves with the time chunk
         from spark_timeseries_tpu.models import regression_arima as ra
 
         design = [arg(960, 31), arg(31, 960),
@@ -322,6 +324,9 @@ def test_pallas_kernels_compile_for_v5e(v5e_chip):
         programs["harmonic arma inline general"] = (
             ra._shared_fit_program(*shared, "general", True),
             [arg(B, 960), *design])
+        programs["harmonic arma inline T=2500 (multi-chunk)"] = (
+            ra._shared_fit_program(*shared, "dense", True),
+            [arg(B, 2500), arg(2500, 31), arg(31, 2500), design[2]])
         programs["arima grid9 inline general T=2500 (multi-chunk)"] = (
             arima._grid_fit_program(*grid9, "general"), [arg(256, 2500)])
         for name, (program, args) in programs.items():

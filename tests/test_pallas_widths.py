@@ -384,7 +384,8 @@ def test_kernel_block_sweep_cases_trace():
             outs = jax.eval_shape(functools.partial(call, r), *args)
             assert outs[-1].shape[1:] == (rows // 128, 128)
             # a panel or a plane; an adjoint's parameter planes, folded
-            assert all(o.shape[0] in ((3, 4) if mode.startswith("adjoint")
+            # (35 with a shared design's 32 columns beside ARMA(1,1)'s)
+            assert all(o.shape[0] in ((3, 4, 35) if mode.startswith("adjoint")
                                       else (1, tp)) for o in outs)
         seen.add((name, mode))
     assert grid == {f"{m}.{tag}" for m in ("sum", "both", "adjoint")
@@ -395,5 +396,8 @@ def test_kernel_block_sweep_cases_trace():
     # Holt-Winters' additive calls, and the multiplicative model's pair
     assert {m for n, m in seen if n == "hw_sse"} == {
         "sum", "save_resid", "adjoint", "save_resid.mult", "adjoint.mult"}
-    assert len(seen) == 14 and {n for n, m in seen
+    # the CSS calls that take a shared design as an operand (ISSUE 51)
+    assert {m for n, m in seen if m.endswith(".x")} == {
+        "sum.x", "both.x", "u.x", "adjoint.x"}
+    assert len(seen) == 18 and {n for n, m in seen
                                 if not m.startswith("adjoint")} == kernels

@@ -2,10 +2,11 @@
 ``regression_arima.fit_shared`` / ``fit_harmonic`` through ``lockstep.fit``
 — on ``lax.scan`` and on the interpreted CSS kernels against the plain
 reference's profiled optimum (``benchmark/reference/regression_arma_css.py``),
-the kernel path's gradient (the data cotangent through the product's
-transpose) against the scan's, the lazy stage pair and its spans, a journaled
-walk, what is refused, and what the traced programs move: no ``[B, T, k]``
-array, and the design's panel moves the stage spans report.  Small, seeded:
+the kernel path's gradient (since ISSUE 51 both design products inside the CSS
+kernel calls) against the scan's, the lazy stage pair and its spans, a
+journaled walk, what is refused, and what the traced programs move: no ``[B,
+T, k]`` array, no panel-sized product beside a kernel call, and the design's
+panel moves the stage spans report.  Small, seeded:
 64 x 192 with periods (12, 48) and harmonics (3, 2)."""
 
 import collections
@@ -17,7 +18,7 @@ import numpy as np
 import pytest
 
 from _obs_helpers import _assert_bitwise, _span_lines
-from _pallas_helpers import _dist_parity
+from _pallas_helpers import _dist_parity, _panel_ops, _stage_programs
 from benchmark.reference import check
 from benchmark.reference import regression_arma_css as ref
 from spark_timeseries_tpu import obs
@@ -211,10 +212,13 @@ def test_compaction_engages_and_the_spans_say_the_design(low_gate, tmp_path):
             "adjoint_panels": pk.CSS_ADJOINT_PANELS}
     assert said.items() <= stage1["attrs"].items()
     assert stage1["attrs"]["rows"] == 2048 and stage1["attrs"]["undone"] > 0
-    # the adjoint that writes the data cotangent holds a panel more in VMEM
-    # than arima's, and the rule that sizes its block knows
-    assert stage1["attrs"]["adjoint_series_block"] \
-        == pk.css_series_block(2048, 96, KW["order"], "adjoint", want_gy=True)
+    # the calls that take the design hold more in VMEM than arima's (the
+    # residual, the data cotangent), and the rule that sizes their blocks
+    # knows: both widths are the rule's WITH the design
+    for attr, mode in (("series_block", "sum"),
+                       ("adjoint_series_block", "adjoint")):
+        assert stage1["attrs"][attr] == pk.css_series_block(
+            2048, 96, KW["order"], mode, design=K)
     (stage2,) = spans["fit.stage2"]
     assert stage2["attrs"]["rows"] == optim.compaction_cap(2048)
     assert said.items() <= stage2["attrs"].items()
@@ -248,20 +252,9 @@ def test_journaled_walk_rereads_bitwise(tmp_path):
 
 def _programs(b, t):
     """Stage 1, the inline program and stage 2 as ``(fn, args, rows)``, on
-    shapes alone (``_pallas_helpers._stage_programs``' way)."""
-    f32 = jnp.float32
-    args = (jax.ShapeDtypeStruct((b, t), f32),
-            jax.ShapeDtypeStruct((t, K), f32),
-            jax.ShapeDtypeStruct((K, t), f32),
-            jax.ShapeDtypeStruct((K, 1 + ra._UNIT_LAGS), f32))
-    static = (KW["order"], "pallas-interpret", 13, 1e-4)
-    stage1 = ra._shared_stage1_program.__wrapped__(*static, "dense")
-    aux = jax.eval_shape(stage1, *args)[1]
-    return ((stage1, args, b),
-            (ra._shared_fit_program.__wrapped__(*static, "dense", True), args,
-             b),
-            (ra._shared_stage2_program.__wrapped__(*static),
-             (aux["starts"][0], aux["fin"]), optim.compaction_cap(b)))
+    shapes alone (``_pallas_helpers._stage_programs``' family)."""
+    assert K == 11  # the helper's design is this file's
+    return _stage_programs("harmonic-arma", b, t)[1]
 
 
 def _largest(jaxpr):
@@ -278,11 +271,15 @@ def _largest(jaxpr):
 
 def _design_moves(jaxpr, n_panel):
     """Per objective gradient of ``jaxpr`` at any depth, the panel-sized
-    operands and results the DESIGN adds around the CSS pair: the residual
-    the two calls read is ``add(panel, product)`` (the panel in, the residual
-    out: the product fuses into it), and the one panel the adjoint writes
-    beside its parameter gradients is read by ONE product and nothing else
-    (out, and in: no panel-sized ``neg`` between them)."""
+    operands and results the DESIGN adds around the CSS pair, counted against
+    a plain fit's pair (the forward reads the panel and writes the errors, the
+    adjoint reads both and writes planes): here the both-mode forward writes
+    ONE panel more, the residual it formed, and the adjoint reads it where a
+    plain fit's reads the panel.  No panel-sized ``dot_general`` or ``add``
+    feeds a CSS ``pallas_call`` or reads one's result (the composition's
+    residual and its ``x' g_u``), and a value-only call (the line search's)
+    reads the ONE panel and writes none — or, the start's once a program,
+    the residual alone for the Hannan-Rissanen kernels."""
     big = lambda v: (not isinstance(v, jax.extend.core.Literal)  # noqa: E731
                      and v.aval.size >= n_panel)
     made = {v: eqn for eqn in jaxpr.eqns for v in eqn.outvars}
@@ -296,19 +293,25 @@ def _design_moves(jaxpr, n_panel):
             for sub in jax.core.jaxprs_in_params(eqn.params):
                 found += _design_moves(sub, n_panel)
             continue
-        panels = list(filter(big, eqn.invars))
-        if wrote & set(panels):  # the adjoint: it reads the saved errors
-            (u3,) = [v for v in panels if v not in wrote]
-            residual = made[u3]
-            y3, product = residual.invars
-            assert residual.primitive.name == "add"
-            assert made[product].primitive.name == "dot_general"
-            assert y3 not in made or made[y3].primitive.name != "dot_general"
-            (g_u,) = filter(big, eqn.outvars)
-            (reader,) = readers[g_u]
-            assert reader.primitive.name == "dot_general"
-            found.append(2 + 2)
-        wrote.update(filter(big, eqn.outvars))
+        if "_css_" not in eqn.params["jaxpr"].debug_info.func_name:
+            continue
+        panels_in = list(filter(big, eqn.invars))
+        panels_out = list(filter(big, eqn.outvars))
+        for v in panels_in:
+            assert v not in made or made[v].primitive.name not in (
+                "dot_general", "add"), made[v]
+        for v in panels_out:
+            assert not [r for r in readers[v] if r.primitive.name in (
+                "dot_general", "add", "neg")], readers[v]
+        if wrote & set(panels_in):  # the adjoint: it reads the saved errors
+            assert set(panels_in) <= wrote
+            assert not panels_out  # no data cotangent leaves the kernel
+            # beyond a plain pair's: the errors out, the panel and they in
+            found.append(len(forward_out) - 1 + len(panels_in) - 2)
+        elif len(panels_out) > 1:  # a gradient's forward: the errors, and u
+            forward_out = panels_out
+            assert len(panels_in) == 1
+        wrote.update(panels_out)
     return found
 
 
@@ -321,7 +324,9 @@ def test_programs_form_no_per_row_design_and_move_what_the_spans_say(
         assert _largest(jaxpr) < rows * t * K // 3
         moves = _design_moves(jaxpr, rows * t)
         assert moves, "every program takes gradients"
-        assert set(moves) == {ra.XREG_PANEL_MOVES}
+        assert set(moves) == {ra.XREG_PANEL_MOVES} == {1}
+        # XLA forms no panel-sized product or sum anywhere in the program
+        assert _panel_ops(jaxpr.eqns, rows * t, ("dot_general", "add")) == []
     # the fit-level statement of the same, on the portable objective
     y = jax.ShapeDtypeStruct((64, t), jnp.float32)
     scan = ra._shared_fit_program.__wrapped__(KW["order"], "scan", 13, 1e-4,

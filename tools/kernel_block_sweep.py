@@ -15,6 +15,10 @@ The adjoints (mode ``adjoint``; the seasonal lag set ``{1, 24, 25}`` as
 ``css_seasonal_neg_loglik`` beside the three) are the call the objective's
 ``custom_vjp`` makes — the cotangent formed in the kernel from the plane —
 at the same forced widths.
+The CSS calls with a SHARED DESIGN of 31 columns as an operand (PR 51: the
+residual ``u = y - x @ beta'`` and ``-x' dS/du`` formed in VMEM) ride as
+``sum.x`` / ``both.x`` / ``u.x`` (the residual panel alone) / ``adjoint.x``
+over ``[rows, 960]``.
 The order search's grid kernels (``css_grid_neg_loglik``: 9 orders over one
 ``[131072, 1000]`` panel, union lags {1, 2} on both sides) run at G = 1, 3, 9
 orders a grid step, each at every R (modes ``sum.g1`` .. ``adjoint.g9``;
@@ -89,6 +93,39 @@ def cases():
                    lambda r, resid, gbar, lags=lags, t=t, rows=rows:
                    [pk._fold(pk._css_ss_f_bwd(*lags, False, t, rows, resid,
                                               gbar, _r=r)[0])])
+
+        # a shared design of 31 columns (PR 51; [rows, 960], the columns
+        # padded to 32): the forward calls form u = y - x @ beta' in VMEM,
+        # "both" writes it beside the errors, "u" alone; the adjoint leaves
+        # its final adjoints in scratch and accumulates -x' dS/du from them
+        # (modes ``sum.x`` / ``both.x`` / ``u.x`` / ``adjoint.x``)
+        def css_x_args(key, nsub=nsub, rows=rows):
+            k1, k2, k3 = jax.random.split(key, 3)
+            par = jnp.concatenate([
+                jnp.asarray([0.0, 0.5, 0.3], jnp.float32)
+                + 0.05 * jax.random.normal(k2, (rows, 3), jnp.float32),
+                jax.random.normal(k3, (rows, 32), jnp.float32)], axis=1)
+            k4, k5 = jax.random.split(k1)
+            return (par, _planes(k4, 960, nsub),
+                    jnp.ones((1, nsub, pk._LANES), jnp.float32),
+                    jax.random.normal(k5, (960, 32), jnp.float32))
+
+        for mode in ("sum", "both", "u"):
+            yield ("css_neg_loglik", f"{mode}.x", rows, 960, css_x_args,
+                   lambda r, par, y3, zb3, x, mode=mode: pk._css_fwd_call_f(
+                       1, 1, False, mode, par, y3, zb3, 960, _r=r, x=x)[0])
+
+        def css_x_adj_args(key, nsub=nsub):
+            # _css_ss_x_bwd's residuals: a plain adjoint's, 32 more planes
+            # of parameters and the design in the marker's place
+            (u3, par3, zb3, e3, _), gbar = css_adj_args(key, (1, 1), 960)
+            k5 = jax.random.fold_in(key, 5)
+            return ((u3, jnp.concatenate([par3, _planes(k5, 32, nsub)]), zb3,
+                     e3, jax.random.normal(k5, (960, 32), jnp.float32)), gbar)
+
+        yield ("css_neg_loglik", "adjoint.x", rows, 960, css_x_adj_args,
+               lambda r, resid, gbar, rows=rows: [pk._fold(pk._css_ss_x_bwd(
+                   1, 1, False, 960, rows, resid, gbar, _r=r)[0])])
 
         # the order search's grid (PR 36): 9 orders over ONE panel at G
         # orders a grid step (G = 1: the order as a grid axis, G = 9: nine
