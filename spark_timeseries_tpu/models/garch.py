@@ -164,14 +164,21 @@ def fit(r, *, max_iters: int = 80, tol: Optional[float] = None,
     return debatch_fit(out, single, count_evals)
 
 
-def _garch_kernel_attrs(t):
+def _garch_kernel_attrs(t, mean_in_kernel=None):
     """What the stage spans say of the GARCH kernels (``lockstep.fit``'s
-    ``series_block`` / ``stage_attrs``)."""
+    ``series_block`` / ``stage_attrs``); ``mean_in_kernel`` (``None``: no
+    mean equation): of a fit with one, formed in the calls or in XLA."""
     from ..ops import pallas_kernels as pk
 
-    return {"series_block": lambda rows, mode: pk.garch_series_block(
-                rows, t, mode),
-            "stage_attrs": {"adjoint_panels": pk.GARCH_ADJOINT_PANELS}}
+    mean = {} if mean_in_kernel is None else {
+        "mean_terms": ARGARCH_MEAN_TERMS,
+        "mean_panel_moves": (ARGARCH_MEAN_PANEL_MOVES if mean_in_kernel
+                             else _XLA_RETURNS_PANEL_MOVES)}
+    block = (pk.argarch_series_block if mean_in_kernel
+             else pk.garch_series_block)
+    return {"series_block": lambda rows, mode: block(rows, t, mode),
+            "stage_attrs": {"adjoint_panels": pk.GARCH_ADJOINT_PANELS,
+                            **mean}}
 
 
 def _garch_family(backend, align_mode=None) -> lockstep.Family:
@@ -337,6 +344,19 @@ def _argarch_from_natural(params):
     return jnp.concatenate([params[:2], _from_natural(params[2:])])
 
 
+def _argarch_point(u, c0, units):
+    """The fit's optimizer space -> natural ``[c, phi, omega, alpha, beta]``:
+    ``c`` is an offset from the start's ``c0`` in ``units`` of the start's
+    residual scale (``Prepared.natural``), ``phi`` is free, the variance
+    triple goes through :func:`_to_natural`.  A free ``c`` is in the DATA's
+    units: on daily returns in decimals (``c`` ~ 5e-4, d nll / d c ~ 1e2 an
+    observation) the first line search shrank every step to nothing and the
+    relative-decrease test stopped the row at its start (PERF.md §6, PR
+    52)."""
+    return jnp.concatenate([(c0 + units * u[0])[None], u[1:2],
+                            _to_natural(u[2:])])
+
+
 def argarch_neg_log_likelihood(params, y, n_valid=None):
     """y_t = c + phi y_{t-1} + r_t with GARCH(1,1) innovations r."""
     c, phi = params[0], params[1]
@@ -352,11 +372,56 @@ def argarch_neg_log_likelihood(params, y, n_valid=None):
     return neg_log_likelihood(params[2:], r, nv - 1)
 
 
+# the mean equation's terms a kernel step (c and phi y_{t-1}: a stage span's
+# ``mean_terms``), and the panel-sized operands and results a GRADIENT pays
+# for the mean equation beside the kernel pair's own (``mean_panel_moves``):
+# none where the calls form the returns themselves (``pallas.
+# argarch_neg_loglik``, series of one time chunk; ``tests/
+# test_pallas_argarch.py`` holds the traced programs to it).  Past one chunk
+# the returns are an XLA panel and the least a gradient moves, every
+# fusion's panels counted once, is 18: the series and its shifted copy read
+# and the returns written (3); ``garch_prefold``'s two reductions, square
+# and fold (3 read, 1 written);
+# the adjoint's r^2 cotangent written (1), read with ``h3`` and rewritten
+# for the likelihood's direct part (3), unfolded (2), chained through the
+# square against the returns (3) and reduced into c and phi against the
+# shifted copy (2) — a reading of the program, not a measurement
+ARGARCH_MEAN_TERMS = 2
+ARGARCH_MEAN_PANEL_MOVES = 0
+_XLA_RETURNS_PANEL_MOVES = 18
+
+
+def _mean_in_kernel(backend, n_time: int) -> bool:
+    """Whether :func:`fit_argarch`'s objective forms its returns inside the
+    kernel calls: a Pallas backend and a series of one time chunk."""
+    from ..ops import pallas_kernels as pk
+
+    return (backend in lockstep.PALLAS
+            and pk.garch_mean_structural_ok(n_time))
+
+
 def fit_argarch(y, *, max_iters: int = 100, tol: Optional[float] = None,
                 backend: str = "auto", compact: bool = True,
                 align_mode: Optional[str] = None) -> FitResult:
     """Fit AR(1)+GARCH(1,1) -> natural params ``[batch?, 5]``
-    (reference ``ARGARCH.fitModel``).
+    (reference ``ARGARCH.fitModel``): ``y_t = c + phi y_{t-1} + r_t`` with
+    Gaussian GARCH(1,1) returns ``r``.
+
+    The fit CONDITIONS on the first valid observation: its return is in
+    neither the likelihood nor the variance seed (one return fewer than
+    observations).  The seed ``h_0`` — standing in for ``h_{-1}`` and the
+    unobserved ``r_{-1}^2`` — is the sample variance of the returns, so it
+    moves with ``phi`` (not with ``c``, which shifts the returns and their
+    mean alike) and the gradient carries that dependence.
+
+    Which path a size takes (``resolve_backend`` and the series' length
+    decide, no option does): on a Pallas backend a series of at most one
+    time chunk (1,024 steps) is folded once a fit and the returns are
+    formed inside the GARCH kernel calls (``pallas.argarch_neg_loglik``:
+    one panel read a value pass, two a gradient, none written beside the
+    variance path); a longer one builds the returns panel in XLA each pass
+    and differentiates through :func:`pallas_kernels.garch_neg_loglik`'s
+    data cotangents; the ``scan`` backend is the portable ``lax.scan``.
 
     ``compact=False`` disables straggler compaction (see :func:`fit`);
     ``align_mode`` is the static alignment hint (``base.resolve_align_mode``)
@@ -369,16 +434,19 @@ def fit_argarch(y, *, max_iters: int = 100, tol: Optional[float] = None,
     backend = resolve_backend(backend, yb.dtype, yb.shape[1])
     align_mode = resolve_align_mode(yb, align_mode)
     static = (max_iters, float(tol), backend)
+    in_kernel = _mean_in_kernel(backend, yb.shape[1])
     out = lockstep.fit(
         (yb,), backend=backend, compact=compact, max_iters=max_iters,
         inline=lambda: _fit_argarch_program(*static, compact, align_mode),
         stage1=lambda: _fit_argarch_stage1_program(*static, align_mode),
         stage2=lambda: _fit_argarch_stage2_program(*static),
-        **_garch_kernel_attrs(yb.shape[1]))
+        **_garch_kernel_attrs(yb.shape[1], in_kernel))
     return debatch(out, single)
 
 
 def _argarch_family(backend, align_mode=None) -> lockstep.Family:
+    from ..ops import pallas_kernels as pk
+
     def prep(yb):
         ya, nv = maybe_align(yb, align_mode)
 
@@ -396,36 +464,47 @@ def _argarch_family(backend, align_mode=None) -> lockstep.Family:
         c0 = mean * (1.0 - phi0)
         resid = (ya[:, 1:] - c0[:, None] - phi0[:, None] * ya[:, :-1]) * m[:, 1:]
         resid_var = jnp.sum(resid**2, axis=1) / nvf
-        nat0 = jnp.stack(
-            [
-                c0,
-                phi0,
-                0.1 * jnp.maximum(resid_var, 1e-8),
-                jnp.full_like(c0, 0.1),
-                jnp.full_like(c0, 0.8),
-            ],
-            axis=1,
-        )
-        u0 = jax.vmap(_argarch_from_natural)(nat0)
+        var0 = jnp.stack(
+            [0.1 * jnp.maximum(resid_var, 1e-8), jnp.full_like(c0, 0.1),
+             jnp.full_like(c0, 0.8)], axis=1)
+        # the optimizer's point (see _argarch_point): c at offset 0 from c0
+        units = jnp.sqrt(jnp.maximum(resid_var, 1e-30))
+        u0 = jnp.concatenate(
+            [jnp.zeros_like(c0)[:, None], phi0[:, None],
+             jax.vmap(_from_natural)(var0)], axis=1)
         n_eff = jnp.maximum(nv - 1, 1).astype(ya.dtype)
-        rows = ()
-        if backend in lockstep.PALLAS:
-            # the objective reads the NATURAL-layout panel (its residuals
-            # depend on the parameters, so nothing can be folded ahead):
-            # a straggler subset is a plain row gather of each array
+        folded, rows = (), ()
+        if _mean_in_kernel(backend, T):
+            # the returns depend on the iterate, the series does not: it is
+            # masked and folded ONCE, the kernel calls form r_t from it, and
+            # the seed variance is a quadratic in phi of three row moments —
+            # a straggler subset is a gather of the folded COLUMNS
+            folded, mom = pk.argarch_prefold(ya, nv)
+            rows = (mom, c0, units)
+        elif backend in lockstep.PALLAS:
+            # past one time chunk the returns are an XLA panel a pass, built
+            # from the NATURAL-layout series and its shifted copy (a
+            # straggler subset is a row gather of each array)
             prev = jnp.concatenate([ya[:, :1], ya[:, :-1]], axis=1)
-            rows = (ya, prev, nv)
-        return lockstep.Prepared((u0,), nv >= 12, n_eff, (ya, nv), (), rows)
+            rows = (ya, prev, nv, c0, units)
+        return lockstep.Prepared((u0,), nv >= 12, n_eff,
+                                 (ya, nv, c0, units), folded, rows,
+                                 natural=(c0, units))
 
-    def objective(_, rows):
-        from ..ops import pallas_kernels as pk
+    to_natural = jax.vmap(_argarch_point)
 
-        ya, prev, nv = rows
+    def objective(folded, rows):
+        if isinstance(folded, pk.ArgarchFolded):
+            mom, *point = rows
+            return lambda u: pk.argarch_neg_loglik_folded(
+                to_natural(u, *point), folded, mom,
+                interpret=backend == "pallas-interpret")
+        ya, prev, nv, *point = rows
         t_idx = jnp.arange(ya.shape[1])
         start = ya.shape[1] - nv
 
         def fb(u):
-            nat = jax.vmap(_argarch_to_natural)(u)
+            nat = to_natural(u, *point)
             r = ya - nat[:, 0:1] - nat[:, 1:2] * prev
             # condition on the first valid observation (see
             # argarch_neg_log_likelihood): its residual is excluded
@@ -437,11 +516,11 @@ def _argarch_family(backend, align_mode=None) -> lockstep.Family:
         return fb
 
     def scan_objective(u, data):
-        yv, n = data
-        return argarch_neg_log_likelihood(_argarch_to_natural(u), yv, n)
+        yv, n, *point = data
+        return argarch_neg_log_likelihood(_argarch_point(u, *point), yv, n)
 
     return lockstep.Family(backend, prep, objective, scan_objective,
-                           jax.vmap(_argarch_to_natural))
+                           to_natural)
 
 
 @jit_program

@@ -31,11 +31,13 @@ Like the reference — which hand-derives ``gradientLogLikelihoodCSSARMA``
 rather than relying on automatic differentiation — every kernel pair ships a
 hand-derived adjoint recursion, exposed through ``jax.custom_vjp`` so the
 batched L-BFGS driver (``utils/optim``) can differentiate the objectives
-without XLA's scan transpose.  Cotangents flow to the parameters (and for
-GARCH also to the squared returns and the variance seed, so ARGARCH's mean
-parameters differentiate exactly); everything else is a constant of the fit
-objective, so these entry points are used inside fit objectives and not
-exposed as general autodiff building blocks.
+without XLA's scan transpose.  Cotangents flow to the parameters (for GARCH
+on request also to the squared returns and the variance seed, for callers
+that build the returns themselves; ARGARCH's AR(1) mean runs INSIDE its
+own kernel pair, whose adjoint reduces the mean's two gradients beside the
+three); everything else is a constant of the fit objective, so these entry
+points are used inside fit objectives and not exposed as general autodiff
+building blocks.
 
 Everything here is optional: callers gate on :func:`supported` and fall back
 to the ``lax.scan`` implementations (same semantics, cross-checked by
@@ -1475,7 +1477,8 @@ def css_grid_neg_loglik_folded(params_k, folded: CssGridFolded, ar, ma,
 #   dL/dr2_t   = alpha * lam_{t+1}            (t+1 live and not the seed)
 #   dL/dh0     = lam_zb * (alpha + beta) + sum_{dead t} gbar_t
 # Cotangents flow to r^2 and h0 as well as the parameters so callers that
-# build the returns from model parameters (ARGARCH's AR(1) mean) get exact
+# build the returns from model parameters on their own (``garch_variances``,
+# the time-sharded fits, ``fit_argarch`` past one time chunk) get exact
 # gradients; ``zb`` is a constant of the objective.  The two data cotangents
 # cost a [B, T] write, so the adjoint emits them only when the data is
 # perturbed (symbolic_zeros on the likelihood's custom_vjp, as EWMA's ``x``
@@ -1901,6 +1904,293 @@ def garch_neg_loglik(params, r, n_valid=None, *, interpret: bool = False):
     """
     return garch_neg_loglik_folded(params, garch_prefold(r, n_valid),
                                    interpret=interpret)
+
+
+# ---------------------------------------------------------------------------
+# AR(1) + GARCH(1, 1): the GARCH recursion with its mean equation in the step
+# ---------------------------------------------------------------------------
+#
+# ARGARCH's returns depend on the iterate, its series does not: the panel
+# operand is the masked series ``y`` itself, five parameter planes ``(c, phi,
+# omega, alpha, beta)``, and each step forms its return in VMEM,
+#   r_t = y_t - c - phi * y_{t-1},
+# before the GARCH section's recursion and likelihood run on ``r_t^2``.  A
+# kernel pair of its own and not a mode of the plain one — another carry, one
+# panel where that reads two, and ``garch.fit``'s programs stay the text they
+# were — for series of ONE time chunk (``y_{t-1}`` and the carried
+# ``r_{t-1}^2`` never cross a block: no neighbour operand, no scratch).
+#
+# The forward carries the last step's ``r^2`` in registers, reads ONE panel
+# and in its value-only mode writes none.  The adjoint reads ``y`` and the
+# saved variance path, recomputes ``r_t``, runs the GARCH section's ``lam``
+# recursion under the likelihood's own cotangent ``gbar (1/h_t - r_t^2 /
+# h_t^2)`` and, with
+#   G_t = alpha * lam_{t+1} + gbar / h_t         (t live: the cotangent of
+#                                                 r_t^2, the recursion's part
+#                                                 and the likelihood's)
+# accumulates beside the three
+#   dL/dc   = -sum_t 2 r_t G_t
+#   dL/dphi = -sum_t 2 r_t G_t y_{t-1}
+# and hands back ``dL/dh0 = lam_zb (alpha + beta)`` as a plane: five
+# parameter planes and one seed plane out, two panels in, no panel out.  The
+# seed variance is the caller's (``h0`` depends on ``phi``:
+# :func:`argarch_neg_loglik_folded` forms it from three row moments in plain
+# ``jnp`` and JAX chains ``dL/dh0`` through it).  No live step is the
+# panel's first (``zb >= 1``: the fit conditions on the first valid
+# observation), so a clamped read of ``y_{t-1}`` is exact wherever it counts.
+
+
+def _argarch_fwd_kernel(t_limit, cs, mode, y_ref, par_ref, h0_ref, zb_ref,
+                        *outs):
+    # mode "sum": only the per-series Gaussian log-likelihood sum leaves the
+    # kernel (linesearch evals); "both": the variance path too, the sum
+    # accumulated in the identical order
+    h_ref = outs[0] if mode == "both" else None
+    ll_ref = outs[-1]
+    zb = zb_ref[0]
+    h0 = h0_ref[0]
+    zero = _plane_zero(zb_ref)
+
+    def body(t, carry):
+        hprev, r2p, acc = carry
+        tf = t.astype(jnp.float32)
+        r = (y_ref[t] - par_ref[0]
+             - par_ref[1] * y_ref[jnp.maximum(t - 1, 0)])
+        # the first live step seeds with h0 standing in for r_{start-1}^2
+        # (matching models.garch.variances)
+        r2p = jnp.where(tf == zb, h0, r2p)
+        h = par_ref[2] + par_ref[3] * r2p + par_ref[4] * hprev
+        live = (tf >= zb) & (t < t_limit)
+        hval = jnp.where(live, h, h0)
+        if mode == "both":
+            h_ref[t] = hval
+        hc = jnp.maximum(hval, 1e-12)
+        r2 = r * r
+        acc = acc + jnp.where(
+            live, jnp.log(2.0 * jnp.pi * hc) + r2 / hc, 0.0)
+        return hval, r2, acc
+
+    ll_ref[0] = _fori(cs, body, (h0, zero, zero))[2]
+
+
+def _argarch_bwd_kernel(t_limit, cs, y_ref, par_ref, h0_ref, zb_ref, h_ref,
+                        g_ref, gpar_ref, gh0_ref):
+    # ``g_ref``: the plane gbar of the likelihood sum's cotangent
+    zb = zb_ref[0]
+    h0 = h0_ref[0]
+    alpha = par_ref[3]
+    beta = par_ref[4]
+    zero = _plane_zero(zb_ref)
+
+    # a step's loads, and its divides: d ll_t / d h_t = 1/h - r^2/h^2 in two
+    # stages a step apart, as ``_garch_bwd_kernel`` forms it (see there why),
+    # the step's return riding along behind its square
+    def operands(t):
+        ht = h_ref[t]
+        hc = jnp.maximum(ht, 1e-12)
+        r = (y_ref[t] - par_ref[0]
+             - par_ref[1] * y_ref[jnp.maximum(t - 1, 0)])
+        return ht, hc, hc * hc, r * r, r
+
+    def quotients(ht, hc, hh, r2, r):
+        return ht, 1.0 / hc, r2 / hh, r
+
+    def body(i, carry):
+        lam_next, dw, da, db, dc, dphi, lam_seed = carry[:7]
+        (ht, inv, quo, r), staged = carry[7:11], carry[11:]
+        t = cs - 1 - i
+        tf = t.astype(jnp.float32)
+        live = (tf >= zb) & (t < t_limit)
+        g = jnp.where(live & (ht >= 1e-12), g_ref[0] * (inv - quo), 0.0)
+        lam = jnp.where(live, g + beta * lam_next, 0.0)
+        seed = tf == zb
+        # h_{t-1} and r_{t-1}^2 are the staged step's (at t = 0 a clamped
+        # step's, under a lam of 0: that step is never live)
+        r2p_eff = jnp.where(seed, h0, staged[3])
+        # r_t G_t: r_t^2 feeds h_{t+1} (a live t's successor is never the
+        # seed; past the end lam_next is 0) and the likelihood
+        rg = r * jnp.where(live, alpha * lam_next + g_ref[0] * inv, 0.0)
+        return ((lam, dw + lam, da + lam * r2p_eff, db + lam * staged[0],
+                 dc + rg, dphi + rg * y_ref[jnp.maximum(t - 1, 0)],
+                 jnp.where(seed, lam, lam_seed))
+                + quotients(*staged) + operands(jnp.maximum(t - 2, 0)))
+
+    out = lax.fori_loop(
+        0, cs, body,
+        (zero,) * 7 + quotients(*operands(cs - 1)) + operands(max(cs - 2, 0)))
+    gpar_ref[0] = -2.0 * out[4]
+    gpar_ref[1] = -2.0 * out[5]
+    for k in range(3):
+        gpar_ref[2 + k] = out[1 + k]
+    # h0 enters the seed step through BOTH recursion inputs
+    gh0_ref[0] = (alpha + beta) * out[6]
+
+
+# the widths the chip showed best for the pair (PERF.md §6, PR 52, run 5:
+# over [131072, 1000] at R = 1 / 2 / 4, sum 3.38 / 1.76 / 1.03 ms, both 3.41 /
+# 1.79 / 1.61 — a step forms r_t but carries r_{t-1}^2 where the plain one
+# loads and selects it, and is the faster of the two; the adjoint 2.03 /
+# 1.82 / 2.21, 15.89 / 14.25 / 17.24 ns a step and block, and 24.48 / 24.43 /
+# 24.82 over the 16,384-row compaction: sixteen carried planes a chain fill
+# the vector slots on ONE register, a second chain buys 10% and four spill)
+_ARGARCH_R = {"sum": 4, "both": 4, "adjoint": 2}
+
+
+def _argarch_layout(mode, t):
+    """The blocks of the pair's calls (see :func:`_css_fwd_layout`): the
+    series, the five parameter planes, seed and mask; the forward writes the
+    likelihood plane (``"both"``: the variance path too), the adjoint reads
+    the variance path and the cotangent's plane and writes five gradient
+    planes and the seed's."""
+    cs = _time_layout(t)[1]
+    ins = [(cs, _cur), (5, _fixed), (1, _fixed), (1, _fixed)]
+    if mode == "adjoint":
+        return (ins + [(cs, _cur), (1, _fixed)],
+                [(5, _fixed), (1, _fixed)], [])
+    return ins, ([(cs, _cur)] if mode == "both" else []) + [(1, _fixed)], []
+
+
+def argarch_series_block(rows: int, t: int, mode: str = "sum") -> int:
+    """Series per grid step of the ARGARCH kernels (see
+    :func:`css_series_block`)."""
+    return _SBLK * series_rows(_nsub(rows), _argarch_layout(mode, t),
+                               _ARGARCH_R[mode])
+
+
+def garch_mean_structural_ok(n_time: int) -> bool:
+    """The kernels' mean equation reads ``y_{t-1}`` and carries ``r_{t-1}^2``
+    inside ONE time chunk: series of at most ``_CHUNK_T`` steps (longer ones
+    build their returns in XLA and take :func:`garch_neg_loglik`)."""
+    return _time_layout(n_time)[2] == 1
+
+
+@functools.partial(
+    jax.tree_util.register_dataclass,
+    data_fields=["y3", "zb3"], meta_fields=["t"])
+@dataclasses.dataclass(frozen=True)
+class ArgarchFolded:
+    """A panel in the layout of the ARGARCH kernels (:func:`argarch_prefold`):
+    the masked series ``y3 [Tp, Bp/128, 128]`` and the first live RETURN's
+    position ``zb3`` (one past the first valid observation, which the fit
+    conditions on); ``t`` the true length."""
+
+    y3: jax.Array
+    zb3: jax.Array
+    t: int
+
+
+def argarch_prefold(y, n_valid=None):
+    """Mask and fold a panel ONCE for :func:`argarch_neg_loglik_folded` ->
+    ``(ArgarchFolded, mom [B, 3])``.
+
+    ``mom`` holds the second moments of the live pairs ``(y_t, y_{t-1})``,
+    ``t`` past the first valid observation, each about its own mean and over
+    their count ``n_valid - 1``: ``var y``, ``cov(y, y_prev)``, ``var
+    y_prev``.  The returns' sample variance, the recursion's seed, is a
+    quadratic in ``phi`` of these three (``c`` shifts the returns and their
+    mean alike), so no pass forms a returns panel to seed itself."""
+    if not garch_mean_structural_ok(y.shape[1]):
+        raise ValueError(
+            f"the GARCH kernels' mean equation takes series of at most "
+            f"{_CHUNK_T} steps, got {y.shape[1]}")
+    b, n = y.shape
+    nv = (jnp.full((b,), n, jnp.int32) if n_valid is None
+          else n_valid.astype(jnp.int32))
+    start = (n - nv)[:, None]
+    t_idx = jnp.arange(n)[None, :]
+    ya = jnp.where(t_idx >= start, y, 0.0)
+    pair = (t_idx[:, 1:] > start).astype(y.dtype)
+    m = jnp.maximum(nv - 1, 1).astype(y.dtype)[:, None]
+    dev = [(v - jnp.sum(v * pair, axis=1, keepdims=True) / m) * pair
+           for v in (ya[:, 1:], ya[:, :-1])]
+    mom = jnp.stack([jnp.sum(dev[i] * dev[j], axis=1)
+                     for i, j in ((0, 0), (0, 1), (1, 1))], axis=1) / m
+    tp, _, _ = _time_layout(n)
+    return ArgarchFolded(
+        _fold(jnp.pad(ya, ((0, 0), (0, tp - n)))),
+        _fold((start + 1).astype(y.dtype)), n), mom
+
+
+def _argarch_fwd_call_f(interpret, mode, params, h0, f: ArgarchFolded,
+                        _r=None):
+    # only the [B, 5] parameters and the [B] seed are folded per call
+    par3 = _fold(params)
+    h03 = _fold(h0[:, None].astype(f.y3.dtype))
+    layout = _argarch_layout(mode, f.t)
+    r = _r or series_rows(f.y3.shape[1], layout, _ARGARCH_R[mode])
+    outs = _block_call(
+        functools.partial(_argarch_fwd_kernel, f.t, _time_layout(f.t)[1],
+                          mode),
+        layout, r, interpret, (f.y3, par3, h03, f.zb3))
+    return outs, (par3, h03)
+
+
+def _argarch_bwd_call_f(interpret, f: ArgarchFolded, par3, h03, h3, g3,
+                        _r=None):
+    """The adjoint on FOLDED operands: ``g3`` the plane gbar of the
+    likelihood sum's cotangent -> ``(gpar3, gh03)``.  ``_r`` forces the
+    block width (tests and the sweep)."""
+    layout = _argarch_layout("adjoint", f.t)
+    r = _r or series_rows(f.y3.shape[1], layout, _ARGARCH_R["adjoint"])
+    return _block_call(
+        functools.partial(_argarch_bwd_kernel, f.t, _time_layout(f.t)[1]),
+        layout, r, interpret, (f.y3, par3, h03, f.zb3, h3, g3))
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
+def _argarch_ll_f(interpret: bool, params, h0, f: ArgarchFolded):
+    """:func:`_garch_ll_f` of the returns ``y_t - c - phi y_{t-1}``, formed
+    in the calls: ``params [B, 5]`` rows ``[c, phi, omega, alpha, beta]``,
+    ``h0 [B]`` the seed variance.  Differentiable in ``params`` and ``h0``
+    (the panel is a constant of the objective), with the same bitwise
+    agreement between the value-only and the saving forward."""
+    (ll3,), _ = _argarch_fwd_call_f(interpret, "sum", params, h0, f)
+    return _unfold(ll3, params.shape[0])[:, 0]
+
+
+def _argarch_ll_f_fwd(interpret, params, h0, f):
+    if f.y3.perturbed:
+        raise NotImplementedError(
+            "the ARGARCH kernels differentiate the objective in its "
+            "parameters and seed alone")
+    params, h0, f = custom_vjp_primal_tree_values((params, h0, f))
+    (h3, ll3), (par3, h03) = _argarch_fwd_call_f(interpret, "both", params,
+                                                 h0, f)
+    return _unfold(ll3, params.shape[0])[:, 0], (f, par3, h03, h3)
+
+
+def _argarch_ll_f_bwd(interpret, resid, gbar, _r=None):
+    f, par3, h03, h3 = resid
+    b = gbar.shape[0]
+    if isinstance(gbar, SymbolicZero):  # output provably unused
+        return jnp.zeros((b, 5), h3.dtype), jnp.zeros((b,), h3.dtype), None
+    gpar3, gh03 = _argarch_bwd_call_f(
+        interpret, f, par3, h03, h3, _fold(gbar[:, None].astype(h3.dtype)),
+        _r=_r)
+    # the panel and the mask are constants: no cotangent formed
+    return _unfold(gpar3, b), _unfold(gh03, b)[:, 0], None
+
+
+_argarch_ll_f.defvjp(_argarch_ll_f_fwd, _argarch_ll_f_bwd,
+                     symbolic_zeros=True)
+
+
+def argarch_neg_loglik_folded(params, folded: ArgarchFolded, mom, *,
+                              interpret: bool = False):
+    """Batched AR(1)+GARCH(1,1) Gaussian negative log-likelihood ``[B]``
+    from a pre-folded panel and its moments (:func:`argarch_prefold`) — the
+    fit-loop entry point.  Matches ``models.garch.argarch_neg_log_likelihood``
+    (vmapped) to float tolerance; differentiable in ``params [B, 5]``.
+
+    The seed variance, ``[B]``-sized and plain ``jnp``, is formed HERE each
+    pass from the moments — the returns' sample variance ``var y - 2 phi
+    cov + phi^2 var y_prev`` — and JAX chains the adjoint's ``dL/dh0``
+    through it into ``phi``."""
+    phi = params[:, 1]
+    h0 = jnp.maximum(
+        mom[:, 0] - 2.0 * phi * mom[:, 1] + phi * phi * mom[:, 2], 0.0)
+    with jax.named_scope("pallas.argarch_neg_loglik"):
+        return 0.5 * _argarch_ll_f(interpret, params, h0, folded)
 
 
 # ---------------------------------------------------------------------------

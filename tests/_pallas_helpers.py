@@ -120,6 +120,31 @@ def _panel_ops(eqns, n_panel, names=("mul", "select_n", "div")):
     return found
 
 
+def _panel_moves_in_loops(jaxpr, n_panel, in_loop=False):
+    """``(primitive, shape)`` of every operand and result of at least
+    ``n_panel`` elements that an equation inside a ``while`` of ``jaxpr``
+    takes or gives OUTSIDE the kernel calls: what a pass moves beside its
+    ``pallas_call``s.  Equations that only wrap a jaxpr (``pjit``, a custom
+    derivative's call, the loops themselves) are looked through, and the
+    line search's tail is let be: its ``gather`` (behind a ``reshape``, a
+    bitcast) takes the folded panel as its operand and reads the straggler
+    cap's columns of it."""
+    found = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name in ("pallas_call", "reshape", "gather"):
+            continue
+        subs = list(jax.core.jaxprs_in_params(eqn.params))
+        inner = in_loop or eqn.primitive.name == "while"
+        for sub in subs:
+            found += _panel_moves_in_loops(sub, n_panel, inner)
+        if in_loop and not subs:
+            found += [(eqn.primitive.name, v.aval.shape)
+                      for v in (*eqn.invars, *eqn.outvars)
+                      if not isinstance(v, jax.extend.core.Literal)
+                      and v.aval.size >= n_panel]
+    return found
+
+
 def _objective_adjoints(jaxpr, n_panel):
     """Every objective gradient of ``jaxpr`` at any depth, as ``(panel
     operands of the adjoint call, panel-sized mul / select_n / div between
@@ -206,6 +231,15 @@ def _stage_programs(family, b, t):
         inline = hw._fit_program.__wrapped__(*static, "dense", False, True,
                                              n_starts)
         panels = pk.HW_ADJOINT_PANELS[mult]
+    elif family == "argarch":
+        # the GARCH pair with the mean equation in its calls
+        static = (13, 1e-4, "pallas-interpret")
+        stage1 = garch._fit_argarch_stage1_program.__wrapped__(*static,
+                                                               "dense")
+        stage2 = garch._fit_argarch_stage2_program.__wrapped__(*static)
+        inline = garch._fit_argarch_program.__wrapped__(*static, True,
+                                                        "dense")
+        panels = pk.GARCH_ADJOINT_PANELS
     else:
         static = (13, 1e-4, "pallas-interpret")
         stage1 = garch._fit_stage1_program.__wrapped__(*static, "dense")

@@ -257,15 +257,21 @@ def test_lazy_spans_carry_the_gates_numbers(monkeypatch, tmp_path, fit,
     carry = seen[-1]
     from spark_timeseries_tpu.ops import pallas_kernels as pk
 
+    # the mean equation runs inside the kernel calls (ISSUE 52): its two
+    # terms a step, no panel moved for it, the widths its calls' own
+    mean = fit is garch.fit_argarch
+    block = pk.argarch_series_block if mean else pk.garch_series_block
+    kernel = {"adjoint_panels": pk.GARCH_ADJOINT_PANELS,
+              **({"mean_terms": 2,
+                  "mean_panel_moves": garch.ARGARCH_MEAN_PANEL_MOVES}
+                 if mean else {})}
     assert spans["fit.stage1"]["attrs"] == {
         "rows": LAZY_ROWS, "iters": int(carry.k),
         "undone": int(carry.undone), "starts": 1,
         "iter_passes": int(carry.k), "trials": int(carry.trials),
         "tail_trials": int(carry.tail_trials),
-        "series_block": pk.garch_series_block(LAZY_ROWS, 96),
-        "adjoint_series_block": pk.garch_series_block(LAZY_ROWS, 96,
-                                                      "adjoint"),
-        "adjoint_panels": pk.GARCH_ADJOINT_PANELS}
+        "series_block": block(LAZY_ROWS, 96),
+        "adjoint_series_block": block(LAZY_ROWS, 96, "adjoint"), **kernel}
     assert spans["fit.stage1"]["parent"] == primary.id
     assert int(carry.undone) > 0
     assert (int(carry.k) < max_iters) == stage2
@@ -273,8 +279,7 @@ def test_lazy_spans_carry_the_gates_numbers(monkeypatch, tmp_path, fit,
     if stage2:
         assert spans["fit.stage2"]["attrs"] == {
             "rows": optim.compaction_cap(LAZY_ROWS), "series_block": 1024,
-            "adjoint_series_block": 1024,
-            "adjoint_panels": pk.GARCH_ADJOINT_PANELS}
+            "adjoint_series_block": 1024, **kernel}
         assert spans["fit.stage2"]["parent"] == primary.id
 
 

@@ -224,7 +224,8 @@ def test_objective_gradient_pinned_to_the_panel_cotangent_parent(
         family, variant, ragged, nchunk):
     # the parent's digits, bit for bit on this code generator: the parameter
     # gradient of every folded objective and the data-perturbed branches
-    # (``want_gy``, ``want_gdata``: forecasting, ``fit_argarch``)
+    # (``want_gy``, ``want_gdata``: forecasting, ``fit_argarch`` past one
+    # time chunk)
     if _cotangent_pin_host() != _COTANGENT_PIN_HOST:
         pytest.skip("another XLA:CPU code generator than the recording's")
     key = f"{family}-{variant}-{int(ragged)}-{nchunk}"

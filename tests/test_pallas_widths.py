@@ -384,13 +384,16 @@ def test_kernel_block_sweep_cases_trace():
             outs = jax.eval_shape(functools.partial(call, r), *args)
             assert outs[-1].shape[1:] == (rows // 128, 128)
             # a panel or a plane; an adjoint's parameter planes, folded
-            # (35 with a shared design's 32 columns beside ARMA(1,1)'s)
-            assert all(o.shape[0] in ((3, 4, 35) if mode.startswith("adjoint")
+            # (35 with a shared design's 32 columns beside ARMA(1,1)'s; 5
+            # and the seed's 1 with GARCH's mean equation)
+            assert all(o.shape[0] in ((1, 3, 4, 5, 35)
+                                      if mode.startswith("adjoint")
                                       else (1, tp)) for o in outs)
         seen.add((name, mode))
     assert grid == {f"{m}.{tag}" for m in ("sum", "both", "adjoint")
                     for tag in ("g1", "g3", "g9", "cells")}
-    kernels = {"css_neg_loglik", "hw_sse", "garch_neg_loglik"}
+    kernels = {"css_neg_loglik", "hw_sse", "garch_neg_loglik",
+               "argarch_neg_loglik"}  # the GARCH pair with a mean (ISSUE 52)
     assert {(n, m) for n, m in seen if m == "adjoint"} == {
         (n, "adjoint") for n in kernels | {"css_seasonal_neg_loglik"}}
     # Holt-Winters' additive calls, and the multiplicative model's pair
@@ -399,5 +402,5 @@ def test_kernel_block_sweep_cases_trace():
     # the CSS calls that take a shared design as an operand (ISSUE 51)
     assert {m for n, m in seen if m.endswith(".x")} == {
         "sum.x", "both.x", "u.x", "adjoint.x"}
-    assert len(seen) == 18 and {n for n, m in seen
+    assert len(seen) == 21 and {n for n, m in seen
                                 if not m.startswith("adjoint")} == kernels

@@ -6,7 +6,8 @@ forward ones, and the adjoints as the objectives call them.
     python tools/kernel_block_sweep.py --compile-only        # here: Mosaic + VMEM
 
 For each kernel (CSS ARIMA(1,1,1), Holt-Winters additive m=24 — and the
-multiplicative model's as ``save_resid.mult`` / ``adjoint.mult`` — GARCH(1,1)),
+multiplicative model's as ``save_resid.mult`` / ``adjoint.mult`` — GARCH(1,1),
+and its calls with an AR(1) mean equation as ``argarch_neg_loglik``),
 mode and panel shape of the benchmark's cells (the stage-1 chunk and the
 stage-2 compaction), R = 1, 2, 4 (``pallas_kernels.series_rows`` is bypassed
 through the call functions' private ``_r``): milliseconds a call, ns a time
@@ -235,6 +236,36 @@ def cases():
         yield ("garch_neg_loglik", "adjoint", rows, 1000, garch_adj_args,
                lambda r, resid, gbar: [pk._fold(pk._garch_ll_f_bwd(
                    False, resid, gbar, _r=r)[0])])
+
+        # the recursion with a mean equation in the step (PR 52: the returns
+        # y_t - c - phi y_{t-1} formed in VMEM; five planes a side, the
+        # seed's cotangent out)
+        def argarch_args(key, nsub=nsub, rows=rows):
+            k0, k1 = jax.random.split(key)
+            par, f = garch_args(k0)
+            mean = jnp.asarray([5e-4, 0.1], jnp.float32) * (
+                1.0 + 0.05 * jax.random.normal(k1, (rows, 2), jnp.float32))
+            return (jnp.concatenate([mean, par], axis=1),
+                    pk._unfold(f.h03, rows)[:, 0], pk.ArgarchFolded(
+                        0.01 * _planes(k1, 1000, nsub), 1.0 + f.zb3, 1000))
+
+        for mode in ("sum", "both"):
+            yield ("argarch_neg_loglik", mode, rows, 1000, argarch_args,
+                   lambda r, par, h0, f, mode=mode: pk._argarch_fwd_call_f(
+                       False, mode, par, h0, f, _r=r)[0])
+
+        def argarch_adj_args(key, rows=rows, nsub=nsub):
+            k0, k1, k2 = jax.random.split(key, 3)
+            par, h0, f = argarch_args(k0)
+            h3 = 1e-4 * (1.0 + 0.1 * _planes(k1, 1000, nsub))
+            return ((f, pk._fold(par), pk._fold(h0[:, None]), h3),
+                    jax.random.normal(k2, (rows,), jnp.float32))
+
+        yield ("argarch_neg_loglik", "adjoint", rows, 1000, argarch_adj_args,
+               lambda r, resid, gbar: [
+                   pk._fold(g.reshape(g.shape[0], -1))
+                   for g in pk._argarch_ll_f_bwd(False, resid, gbar,
+                                                 _r=r)[:2]])
 
 
 def _time(fn, args, calls, reps=3):
