@@ -164,22 +164,26 @@ def warmstart_fit(aug, *, model, n_time, k, model_kwargs=()):
     warm start rides any chunking/sharding/streaming, exactly like the
     forecast pack.  Non-finite inits (a failed previous-window row) are
     zeroed, the model's own cold-ish default, mirroring the winners
-    refit (``models.auto._refit_basin``).  Run with ``resilient=False``:
-    the sanitizer must not touch param columns.
+    refit (``models.auto._refit_basin``; a GARCH row at ``omega`` 0 is no
+    GARCH point and takes ``garch.fit``'s moment start).  Run with
+    ``resilient=False``: the sanitizer must not touch param columns.
     """
     from ..models import arima as _arima
+    from ..models import garch as _garch
 
     cfg = dict(model_kwargs)
     aug = jnp.asarray(aug)
     y = aug[:, :int(n_time)]
     init = aug[:, int(n_time):int(n_time) + int(k)]
     init = jnp.where(jnp.isfinite(init), init, 0.0)
-    if model != "arima":
-        raise ValueError(
-            f"warm-started refits need a fit with init_params= "
-            f"(arima family); got {model!r}")
-    order = tuple(cfg.pop("order"))
-    return _arima.fit(y, order=order, init_params=init, **cfg)
+    if model == "arima":
+        order = tuple(cfg.pop("order"))
+        return _arima.fit(y, order=order, init_params=init, **cfg)
+    if model == "garch":
+        return _garch.fit(y, init_params=init, **cfg)
+    raise ValueError(
+        f"warm-started refits need a fit with init_params= "
+        f"(arima family, garch); got {model!r}")
 
 
 def _derive_base_seed(fingerprint: str) -> int:

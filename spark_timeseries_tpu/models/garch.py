@@ -130,8 +130,18 @@ def neg_log_likelihood(params, r, n_valid=None):
 
 def fit(r, *, max_iters: int = 80, tol: Optional[float] = None,
         backend: str = "auto", count_evals: bool = False,
-        compact: bool = True, align_mode: Optional[str] = None) -> FitResult:
+        compact: bool = True, align_mode: Optional[str] = None,
+        init_params: Optional[jax.Array] = None) -> FitResult:
     """Fit GARCH(1,1) per series -> natural params ``[batch?, 3]``.
+
+    ``init_params`` (natural ``[omega, alpha, beta]``, ``[3]`` or
+    ``[batch, 3]``: what an earlier ``FitResult.params`` holds) starts each
+    row there instead of at the moment start (``omega = 0.1 var``, ``alpha``
+    0.1, ``beta`` 0.8) — the retry ladder's continuation and a warm refit's
+    start.  A row whose init is not finite or not a GARCH point (``omega <=
+    0``, a negative ``alpha`` or ``beta``, ``alpha + beta >= 1``) takes the
+    moment start, so a batch may mix both.  Without the keyword the
+    programs are the ones that have no such operand.
 
     ``count_evals=True`` (pallas backend only) returns ``(FitResult, info)``
     with the optimizer's pass-accounting dict (``utils.optim``); the fit
@@ -154,11 +164,17 @@ def fit(r, *, max_iters: int = 80, tol: Optional[float] = None,
     require_pallas_for_count_evals(count_evals, backend)
     align_mode = resolve_align_mode(rb, align_mode)
     static = (max_iters, float(tol), backend)
+    # the programs with the start as an operand are named only where one
+    # is given: without it the lookups are what they were
+    args, init = (rb,), ()
+    if init_params is not None:
+        args, init = (rb, jnp.asarray(init_params)), (True,)
     out = lockstep.fit(
-        (rb,), backend=backend, compact=compact, max_iters=max_iters,
+        args, backend=backend, compact=compact, max_iters=max_iters,
         inline=lambda: _fit_program(*static, align_mode, count_evals,
-                                    compact),
-        stage1=lambda: _fit_stage1_program(*static, align_mode, count_evals),
+                                    compact, *init),
+        stage1=lambda: _fit_stage1_program(*static, align_mode, count_evals,
+                                           *init),
         stage2=lambda: _fit_stage2_program(*static),
         **_garch_kernel_attrs(rb.shape[1]))
     return debatch_fit(out, single, count_evals)
@@ -181,10 +197,22 @@ def _garch_kernel_attrs(t, mean_in_kernel=None):
                             **mean}}
 
 
-def _garch_family(backend, align_mode=None) -> lockstep.Family:
+def _start_from(init, nat0):
+    """``init`` ``[B, 3]`` natural where a row's is a GARCH point (finite,
+    ``omega > 0``, ``alpha, beta >= 0``, ``alpha + beta < 1``: what
+    :func:`_from_natural`'s clips keep finite), the start ``nat0`` where it
+    is not."""
+    omega, alpha, beta = init[:, 0], init[:, 1], init[:, 2]
+    usable = (jnp.all(jnp.isfinite(init), axis=1) & (omega > 0.0)
+              & (alpha >= 0.0) & (beta >= 0.0) & (alpha + beta < 1.0))
+    return jnp.where(usable[:, None], init, nat0)
+
+
+def _garch_family(backend, align_mode=None,
+                  has_init: bool = False) -> lockstep.Family:
     from ..ops import pallas_kernels as pk
 
-    def prep(rb):
+    def prep(rb, init_params=None):
         ra, nv = maybe_align(rb, align_mode)
         # moment-ish start: omega = 0.1*var, alpha = 0.1, beta = 0.8
         var0 = jax.vmap(_masked_var)(ra, nv)
@@ -192,6 +220,10 @@ def _garch_family(backend, align_mode=None) -> lockstep.Family:
             [0.1 * jnp.maximum(var0, 1e-10), jnp.full_like(var0, 0.1),
              jnp.full_like(var0, 0.8)], axis=1
         )
+        if has_init:
+            nat0 = _start_from(
+                jnp.broadcast_to(init_params, nat0.shape).astype(nat0.dtype),
+                nat0)
         u0 = jax.vmap(_from_natural)(nat0)
         n_eff = jnp.maximum(nv, 1).astype(ra.dtype)
         folded = ()
@@ -215,16 +247,18 @@ def _garch_family(backend, align_mode=None) -> lockstep.Family:
 
 @jit_program
 def _fit_program(max_iters, tol, backend, align_mode="general",
-                 count_evals=False, compact=True):
-    return lockstep.fit_program(_garch_family(backend, align_mode),
-                                max_iters, tol, count_evals, compact)
+                 count_evals=False, compact=True, has_init=False):
+    return lockstep.fit_program(
+        _garch_family(backend, align_mode, has_init), max_iters, tol,
+        count_evals, compact)
 
 
 @jit_program
 def _fit_stage1_program(max_iters, tol, backend, align_mode="general",
-                        count_evals=False):
-    return lockstep.stage1_program(_garch_family(backend, align_mode),
-                                   max_iters, tol, count_evals)
+                        count_evals=False, has_init=False):
+    return lockstep.stage1_program(
+        _garch_family(backend, align_mode, has_init), max_iters, tol,
+        count_evals)
 
 
 @jit_program

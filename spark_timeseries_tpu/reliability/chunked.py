@@ -1122,10 +1122,14 @@ def fit_chunked(
     rung_totals: dict = {}
     for _, _, p in pieces:
         for r in (getattr(p, "meta", None) or {}).get("ladder", ()):
+            # ``continued`` / ``iters``: rows that entered a rung at the
+            # end point of the attempt before it, and the rungs' lockstep
+            # iterations (each call's largest), summed over the chunks
             agg = rung_totals.setdefault(
-                r["rung"], {"attempted": 0, "rescued": 0})
-            agg["attempted"] += r["attempted"]
-            agg["rescued"] += r["rescued"]
+                r["rung"], {"attempted": 0, "rescued": 0, "continued": 0,
+                            "iters": 0})
+            for key in agg:
+                agg[key] += r.get(key, 0)
     if rung_totals:
         meta["ladder_totals"] = rung_totals
 

@@ -13,14 +13,26 @@ gather/re-fit/scatter ladder:
    gathered into a small padded batch (the host-side analog of the
    straggler compaction in ``utils.optim`` — ``optim.retry_cap`` bounds
    the distinct compiled shapes) and re-fit with a larger iteration budget
-   and, where the model supports ``init_params``, a deterministically
-   perturbed init.
+   (at least twice the primary's: its ``max_iters`` keyword, else the fit's
+   own default, read from its signature).
 4. **Fallback rung**: rows still failing are re-fit on the conservative
    path — portable ``scan`` backend (no Pallas), no straggler compaction,
    largest budget.  ``utils.linalg.ridge_solve`` independently falls back
    from the unpivoted Cholesky to ``jnp.linalg.solve`` for non-SPD rows.
 5. Rows that survive nothing are marked ``DIVERGED`` (NaN params, flagged)
    instead of silently propagating NaNs into downstream aggregates.
+
+What a rung STARTS a row from, where the model takes ``init_params`` (a
+model that does not starts every rung at its own start): a row that ran
+the budget of the attempt before the rung OUT and left a finite point —
+``iters`` at that attempt's ``max_iters``, parameters and objective finite
+— is **continued**: it enters at that point (the primary's end point for
+the first rung, the first rung's for the second), unperturbed, so a rung
+finishes a fit instead of repeating it.  Any other failed row (stopped
+before its budget at a stalled line search, non-finite) starts from the
+primary's best-seen point, deterministically perturbed: the point it
+stopped at is where it got stuck.  The choice reads the row's own
+read-back and nothing else.
 
 Per-row outcomes are reported as :class:`~.status.FitStatus` codes;
 ``meta`` records what every rung attempted and recovered.
@@ -72,14 +84,20 @@ class ResilientFitResult(NamedTuple):
 def default_ladder(fit_fn: Callable, base_iters: Optional[int] = None) -> tuple:
     """The standard two-rung ladder, filtered to what ``fit_fn`` accepts.
 
-    Rung 1 (``RETRIED``) re-fits with a LARGER iteration budget (at least
-    double the primary fit's ``base_iters`` when known) and a small
-    perturbed init; rung 2 (``FALLBACK``) escalates to the portable scan
-    backend with compaction disabled and a larger budget still.  Models
-    without a ``backend``/``max_iters`` knob simply get whichever
-    overrides their signature supports.
+    Rung 1 (``RETRIED``) re-fits with a LARGER iteration budget, at least
+    double the primary fit's; rung 2 (``FALLBACK``) escalates to the
+    portable scan backend with compaction disabled and a budget of at least
+    four times it.  The primary's budget is ``base_iters`` (the caller's
+    ``max_iters`` keyword) and otherwise the fit's own default, read from
+    its signature (:func:`_default_max_iters`: GARCH's 80 gives 160 / 320);
+    60 is assumed only where neither says.  What a rung STARTS from is
+    :func:`resilient_fit`'s to decide, row by row: the end point of the
+    attempt before it for a row that ran out of budget, else a start
+    perturbed by the rung's ``perturb``.  Models without a ``backend`` /
+    ``max_iters`` knob simply get whichever overrides their signature
+    supports.
     """
-    base = int(base_iters) if base_iters else 60
+    base = int(base_iters or _default_max_iters(fit_fn) or 60)
     return (
         RetryRung("retry", int(FitStatus.RETRIED),
                   {"max_iters": max(120, 2 * base)}, perturb=0.05),
@@ -90,11 +108,30 @@ def default_ladder(fit_fn: Callable, base_iters: Optional[int] = None) -> tuple:
     )
 
 
+def _signature_parameters(fit_fn: Callable):
+    """``fit_fn``'s parameters by name; ``None`` where it has no signature
+    to read (builtins / C callables)."""
+    try:
+        return inspect.signature(fit_fn).parameters
+    except (TypeError, ValueError):
+        return None
+
+
+def _default_max_iters(fit_fn: Callable) -> Optional[int]:
+    """The iteration budget ``fit_fn`` runs with when no ``max_iters``
+    keyword is passed: its signature's default (a ``functools.partial``'s
+    bound value included), ``None`` where it has none."""
+    param = (_signature_parameters(fit_fn) or {}).get("max_iters")
+    if param is None or isinstance(param.default, bool) \
+            or not isinstance(param.default, int):
+        return None
+    return int(param.default)
+
+
 def _accepted_kwargs(fit_fn: Callable, kwargs: dict) -> dict:
     """Drop overrides the fit's signature does not accept."""
-    try:
-        params = inspect.signature(fit_fn).parameters
-    except (TypeError, ValueError):  # builtins / C callables: pass through
+    params = _signature_parameters(fit_fn)
+    if params is None:  # builtins / C callables: pass through
         return dict(kwargs)
     if any(p.kind is inspect.Parameter.VAR_KEYWORD for p in params.values()):
         return dict(kwargs)
@@ -120,6 +157,18 @@ def _structurally_excluded(res) -> np.ndarray:
     if res.status is None:
         return np.zeros(np.asarray(res.converged).shape, bool)
     return np.asarray(res.status) == FitStatus.EXCLUDED
+
+
+def _ran_out_of_budget(params, nll, iters, budget) -> np.ndarray:
+    """Of an attempt's failed rows, those the BUDGET stopped at a usable
+    point: ``iters`` reached the attempt's ``max_iters`` (``None``: the fit
+    has no such knob, so no row can tell) with parameters and objective
+    finite.  A row the optimizer gave up on before that (a stalled line
+    search) or a non-finite one is not among them."""
+    if budget is None:
+        return np.zeros(np.shape(iters), bool)
+    return ((np.asarray(iters) >= budget) & np.isfinite(nll)
+            & np.isfinite(params).all(axis=-1))
 
 
 @jax.jit
@@ -159,17 +208,25 @@ def resilient_fit(
     still come back ``EXCLUDED`` via their own status output).  ``ladder``
     overrides :func:`default_ladder`; an empty ladder means failed rows go
     straight to ``DIVERGED``.  ``seed`` drives the deterministic init
-    perturbation of retry rungs.
+    perturbation of retry rungs (a rung whose ``perturb`` is 0 hands its
+    rows no start at all, so it neither perturbs nor continues).
 
     COST NOTE: every non-converged row enters the ladder, and the default
     fallback rung re-fits on the portable ``scan`` backend — much slower
-    per row than the fused path.  A panel where a sizable fraction of rows
-    legitimately fails to converge within budget can therefore spend far
-    longer in the ladder than in the primary fit.  For latency-critical
-    serving, bound the ladder with ``max_retry_rows`` (rows beyond the cap
-    skip the ladder and are flagged ``DIVERGED`` directly, ladder rungs
-    recorded in ``meta`` either way), pass a custom ``ladder`` without the
-    scan rung, or ``ladder=()`` to disable retries entirely.
+    per row than the fused path (one row of a million through it from the
+    fit's own start was a fifth of a GARCH walk's device time, PERF.md §6,
+    PR 53).  Where the fit takes ``init_params`` a row that merely ran out
+    of budget is CONTINUED from the point it reached (module docstring), so
+    a rung pays for the remainder of its fit; a fit without the keyword
+    pays each rung from its start, and the rungs' budgets are multiples of
+    the primary's (``default_ladder``).  A panel where a sizable fraction
+    of rows legitimately fails to converge within budget can therefore
+    spend far longer in the ladder than in the primary fit.  For
+    latency-critical serving, bound the ladder with ``max_retry_rows``
+    (rows beyond the cap skip the ladder and are flagged ``DIVERGED``
+    directly, ladder rungs recorded in ``meta`` either way), pass a custom
+    ``ladder`` without the scan rung, or ``ladder=()`` to disable retries
+    entirely.
 
     An ``align_mode=`` entry in ``fit_kwargs`` (the chunk driver's static
     alignment plan) is forwarded to ``fit_fn`` only when its signature
@@ -268,6 +325,12 @@ def resilient_fit(
     supports_init = "init_params" in _accepted_kwargs(
         fit_fn, {"init_params": None}
     )
+    # the attempt BEFORE the rung at hand: its iteration budget, and what it
+    # read back for the rows still failing (rows, params, nll, iters) —
+    # ``None`` while that attempt is the primary fit, whose read-back is the
+    # batch's own arrays
+    budget = fit_kwargs.get("max_iters") or _default_max_iters(fit_fn)
+    left = None
 
     for depth, rung in enumerate(rungs):
         idx = np.nonzero(retryable)[0]
@@ -280,6 +343,7 @@ def resilient_fit(
         pad_idx = optim.gather_pad_indices(idx, cap)
         y_sub = y_clean[jnp.asarray(pad_idx)]
         kw = {**fit_kwargs, **rung.kwargs}
+        continued = np.zeros(idx.size, bool)
         if supports_init and rung.perturb:
             # deterministic perturbed init: best-seen params of the failed
             # rows, jittered relative to their own magnitude
@@ -288,13 +352,28 @@ def resilient_fit(
             jitter = rung.perturb * (1.0 + np.abs(base)) * rng.standard_normal(
                 base.shape
             )
+            start = base + jitter
+            # ... but a row that ran its budget out and left a usable point
+            # is CONTINUED: it enters at the end point of the attempt before
+            # this rung, unperturbed, and so does every pad slot that
+            # repeats it (a lockstep bucket runs as long as its slowest row)
+            at = (params[idx], nll[idx], iters[idx]) if left is None else \
+                tuple(a[np.searchsorted(left[0], idx)] for a in left[1:])
+            continued = _ran_out_of_budget(*at, budget)
+            if continued.any():
+                slot = np.searchsorted(idx, pad_idx)
+                start = np.where(continued[slot][:, None], at[0][slot], start)
             kw["init_params"] = jnp.asarray(
-                (base + jitter).astype(y_clean.dtype)  # no host round-trip for dtype
+                start.astype(y_clean.dtype)  # no host round-trip for dtype
             )
         kw = _accepted_kwargs(fit_fn, kw)
-        with obs.span(f"fit.rung.{rung.name}", rows=int(idx.size), cap=cap):
+        with obs.span(f"fit.rung.{rung.name}", rows=int(idx.size), cap=cap,
+                      continued=int(continued.sum())) as span:
             sub = fit_fn(y_sub, **kw)
-        sub_failed = _failed_mask(sub)[: idx.size]
+            sub_failed = _failed_mask(sub)[: idx.size]
+            sub_iters = np.asarray(sub.iters)[: idx.size]
+            rung_iters = int(sub_iters.max(initial=0))
+            span.set(iters=rung_iters, rescued=int((~sub_failed).sum()))
         rescued = idx[~sub_failed]
         if rescued.size:
             keep = np.nonzero(~sub_failed)[0]
@@ -307,13 +386,19 @@ def resilient_fit(
                 params[rescued] = np.asarray(sub.params)[keep]
             nll[rescued] = np.asarray(sub.neg_log_likelihood)[keep]
             conv[rescued] = np.asarray(sub.converged)[keep]
-            iters[rescued] = np.asarray(sub.iters)[keep]
+            iters[rescued] = sub_iters[keep]
             status[rescued] = np.maximum(status[rescued], rung.status)
             failed[rescued] = False
             retryable[rescued] = False
+        # what this rung leaves for the next to continue from
+        budget = kw.get("max_iters")
+        still = np.nonzero(sub_failed)[0]
+        left = (idx[still], np.asarray(sub.params)[still],
+                np.asarray(sub.neg_log_likelihood)[still], sub_iters[still])
         rung_meta.append({
             "rung": rung.name, "depth": depth,
             "attempted": int(idx.size), "rescued": int(rescued.size),
+            "continued": int(continued.sum()), "iters": rung_iters,
             "kwargs": {k: v for k, v in rung.kwargs.items()},
         })
         obs.counter(f"ladder.{rung.name}.attempted").add(int(idx.size))
