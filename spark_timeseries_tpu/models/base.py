@@ -41,10 +41,21 @@ def jit_program(builder):
     ``compile_cache.hit`` / ``compile_cache.miss``): per-order program
     reuse is the auto-fit search's perf core (ISSUE 9), and the hit rate
     makes that reuse measurable instead of assumed.
+
+    The built function takes its BUILDER's qualified name
+    (``arima._fit_stage1_program``) before ``jax.jit``: most builders
+    return a closure called ``run``, and jax names a program — its HLO
+    module, and the ``program`` of ``compile_cache``'s build log — after
+    the function it was handed.  The name is no part of the dataflow.
     """
-    cached = functools.lru_cache(maxsize=512)(
-        lambda *static: jax.jit(builder(*static))
-    )
+    name = f"{builder.__module__.rpartition('.')[2]}.{builder.__qualname__}"
+
+    def build(*static):
+        fn = builder(*static)
+        fn.__name__ = name
+        return jax.jit(fn)
+
+    cached = functools.lru_cache(maxsize=512)(build)
     # lookup + hit/miss classification are one atomic step: sharded lane
     # threads call fit concurrently, and an unsynchronized cache_info()
     # delta would misattribute another thread's hit to this thread's
@@ -59,6 +70,7 @@ def jit_program(builder):
     def get(*static):
         from ..utils import compile_cache as _cc
 
+        _cc.listen()  # the build log: whatever this program compiles to
         with lock:
             before = cached.cache_info().hits
             out = cached(*map(norm, static))

@@ -10,10 +10,11 @@ This package is the missing plane, zero-dependency and **off by default**
 and leaves fit results bitwise-identical to the uninstrumented code:
 
 - :mod:`.core` — nested wall/process-time **spans**
-  (``obs.span("chunk", lo=...)``), first-dispatch tagging that separates
-  trace+compile time from steady-state execute time, run summaries, and
-  failure dumps; ``profile=True`` mirrors spans into ``jax.profiler``
-  annotations.  Every span line carries its identity (schema v3, ISSUE
+  (``obs.span("chunk", lo=...)``), the ``program.build`` span that
+  separates trace + lower + compile (or cache-read) time from steady-state
+  execute time (``obs.closed_span``, fed by ``utils.compile_cache``'s build
+  log, ISSUE 54), run summaries, and failure dumps; ``profile=True``
+  mirrors spans into ``jax.profiler`` annotations.  Every span line carries its identity (schema v3, ISSUE
   25): ``id`` (one per-run counter), ``parent`` (the span that caused it,
   across threads too: ``span_link()`` at a hand-over, ``span(...,
   parent=link)`` or ``span_scope(link)`` on the other side) and ``walk``
@@ -68,13 +69,24 @@ committer thread, ``stage.overlap`` on the prefetcher thread; last
 ``walk.close``.  ``benchmark/span_idle.py`` splits the device's idle time
 by these names, ``benchmark/device_phases.py`` its busy time.
 
+Beside the tree, wherever an executable is built: ``program.build``
+(``program``, ``thread``, ``trace_s``, ``lower_s``, ``backend_s``,
+``cache``, ``retrieval_s``, ``compiled_s``), written when the build closes
+with the innermost span open on the BUILDING thread as ``parent`` —
+``fit.stage1``, ``fit.stage2``, ``fit.rung.retry``, ``sanitize``,
+``stage.overlap``, ``commit.overlap`` — so the idle time that span is given
+has a line saying why; the ``chunk`` around it reads ``phase``
+``compile+execute`` with ``builds`` / ``build_s``.  ``obs.enable`` first
+writes the builds the process made before it (true ``t0``, no parent):
+``benchmark/setup_builds.py`` splits ``setup_s`` by them.
+
 Instrumented surfaces: ``reliability.fit_chunked`` / ``resilient_fit`` /
 ``sanitize`` / ``journal`` / ``watchdog`` / the pipelined ``committer``
 (queue-depth gauge, per-commit ``commit.overlap`` spans, hidden-commit
 counter), ``TimeSeriesPanel.fit`` / ``map_series``, the compat
 ``fit_model`` wrappers, ``utils.optim``'s straggler-compaction stage, the
 time-sharded ``ops.seqparallel`` ``sp_*_fit`` entry points (``sp_fit``
-spans with compile/execute first-dispatch tagging), and
+spans tagged ``compile+execute`` / ``execute`` from the build log), and
 ``parallel.mesh.shard_series``.
 
 Elastic lane supervision (ISSUE 11, ``reliability.plan.LaneSupervisor``)
@@ -89,11 +101,11 @@ header).
 """
 
 from . import core, memory, metrics, promsink, recorder, tracing
-from .core import (NULL_SPAN, Span, counter, current_span, defer, disable,
-                   dump_failure, dump_on_failure, emit_metrics, enable,
-                   enable_from_env, enabled, event, first_dispatch, gauge,
-                   histogram, last_crash_dump, settle, snapshot, span,
-                   span_link, span_scope, stream_path, summary, walk_span)
+from .core import (NULL_SPAN, Span, closed_span, counter, current_span, defer,
+                   disable, dump_failure, dump_on_failure, emit_metrics,
+                   enable, enable_from_env, enabled, event, gauge, histogram,
+                   last_crash_dump, settle, snapshot, span, span_link,
+                   span_scope, stream_path, summary, walk_span)
 from .memory import PeakMemory, peak_memory, register_staging_pool
 from .metrics import Counter, Gauge, Histogram, MetricsRegistry
 from .promsink import PromTextfileSink
@@ -114,6 +126,7 @@ __all__ = [
     "SCHEMA_VERSION",
     "Span",
     "TraceContext",
+    "closed_span",
     "core",
     "counter",
     "current_span",
@@ -127,7 +140,6 @@ __all__ = [
     "enable_from_env",
     "enabled",
     "event",
-    "first_dispatch",
     "gauge",
     "histogram",
     "last_crash_dump",

@@ -12,11 +12,19 @@ arrays (the bitwise-invariance contract ``tests/test_obs.py`` enforces).
 
 Spans nest per thread (the watchdog dispatches fits on worker threads, and
 a worker's spans must not splice into the driver thread's stack) and
-measure wall clock plus process CPU time.  ``first_dispatch()`` lets the
-chunk driver label the first dispatch of each (fit, shape) pair as
-``compile+execute`` — in JAX the first call of a shape pays trace+compile,
-steady-state calls pay execute only, and conflating the two is the classic
-way to misread a cold chunk as a regression.
+measure wall clock plus process CPU time.  In JAX the first call of a shape
+pays trace + lower + compile (or the persistent cache's read), steady-state
+calls pay execute only, and conflating the two is the classic way to misread
+a cold chunk as a regression.  So every executable the process builds is a
+``program.build`` span (:func:`closed_span`, written by
+``utils.compile_cache``'s build log when the build closes: which program,
+traced / lowered / read from the cache / compiled, for how long; its
+``parent`` is the innermost span open on the BUILDING thread), and the chunk
+driver labels a ``chunk`` ``compile+execute`` where a build closed on its
+thread inside it, with ``builds`` and ``build_s``.  The log is kept with the
+plane off too, and :func:`enable` first writes what it already holds, with
+each build's true ``t0`` and no parent: a stream enabled in a resident
+process still says what the process built and when.
 
 Every span has an identity (schema v3): ``id``, an integer from one
 per-run counter; ``parent``, the id of the span open beneath it on the same
@@ -54,6 +62,7 @@ from .recorder import SCHEMA_VERSION, FlightRecorder
 
 __all__ = [
     "Span",
+    "closed_span",
     "counter",
     "current_span",
     "defer",
@@ -65,7 +74,6 @@ __all__ = [
     "enable_from_env",
     "enabled",
     "event",
-    "first_dispatch",
     "gauge",
     "histogram",
     "last_crash_dump",
@@ -82,7 +90,7 @@ __all__ = [
 
 class _State:
     __slots__ = ("enabled", "run_id", "metrics", "recorder", "annotation",
-                 "crash_dump_dir", "seen_programs", "last_crash",
+                 "crash_dump_dir", "last_crash",
                  "crash_seq", "last_dumped_error", "span_ids", "walk_ids")
 
     def __init__(self):
@@ -93,7 +101,6 @@ class _State:
         # jax.profiler.TraceAnnotation while enable(profile=True), else None
         self.annotation = None
         self.crash_dump_dir = None
-        self.seen_programs = set()
         self.last_crash = None
         self.crash_seq = 0
         self.last_dumped_error = None
@@ -138,11 +145,21 @@ def enable(jsonl_path: Optional[str] = None, *, ring_size: int = 4096,
         _STATE.span_ids = itertools.count(1)
         _STATE.walk_ids = itertools.count(1)
         _STATE.crash_dump_dir = crash_dump_dir
-        _STATE.seen_programs = set()
         _STATE.crash_seq = 0
         _STATE.last_crash = None
         _STATE.last_dumped_error = None
-        _STATE.enabled = True
+        from ..utils import compile_cache
+
+        # what the process built before this run, then the plane on, with
+        # the log held: a build that closes meanwhile waits and is written
+        # live, so each is written once
+        with compile_cache.builds_held() as built:
+            for b in built:
+                attrs = {k: v for k, v in b.items()
+                         if k not in ("t0", "wall_s")}
+                _emit_closed(_STATE.recorder, "program.build", b["t0"],
+                             b["wall_s"], None, 0, attrs)
+            _STATE.enabled = True
         tracing.set_plane(True)
         return _STATE.run_id
 
@@ -254,19 +271,6 @@ def stream_path() -> Optional[str]:
     if not st.enabled or rec is None:
         return None
     return rec.jsonl_path
-
-
-def first_dispatch(key) -> bool:
-    """True exactly once per ``key`` per run — the chunk driver keys on
-    (fit identity, chunk shape, dtype) to tag trace+compile dispatches."""
-    st = _STATE
-    if not st.enabled:
-        return False
-    with _LOCK:
-        if key in st.seen_programs:
-            return False
-        st.seen_programs.add(key)
-        return True
 
 
 # -- spans -------------------------------------------------------------------
@@ -406,6 +410,36 @@ def span(name: str, parent: Optional[tuple] = None, **attrs):
     if not _STATE.enabled:
         return NULL_SPAN
     return Span(name, attrs, link=parent)
+
+
+def closed_span(name: str, t0: float, wall_s: float, **attrs) -> None:
+    """Write a span that has ALREADY closed: ``t0`` (``time.time()`` at its
+    start) and ``wall_s`` are the caller's measurement, taken after the
+    fact (``utils.compile_cache`` learns of a build when jax reports its
+    end).  ``parent`` / ``walk`` are those of the innermost span open on the
+    CALLING thread, which for a build is the building thread.  No process
+    time (nobody read the clock at its start) and no profiler annotation (an
+    annotation cannot be opened in the past; the parent's places it on the
+    device trace's clock).  Disabled plane -> returns at once."""
+    st = _STATE
+    rec = st.recorder  # local capture vs a concurrent disable()
+    if not st.enabled or rec is None:
+        return
+    stack = getattr(_TLS, "stack", None)
+    _emit_closed(rec, name, t0, wall_s, _inherited_link(),
+                 len(stack) if stack else 0, attrs)
+
+
+def _emit_closed(rec, name, t0, wall_s, link, depth, attrs) -> None:
+    ev = {"kind": "span", "name": name, "t0": t0, "wall_s": wall_s,
+          "depth": depth, "id": next(_STATE.span_ids),
+          "parent": link[0] if link else None}
+    if link and link[1] is not None:
+        ev["walk"] = link[1]
+    if attrs:
+        ev["attrs"] = attrs
+    rec.emit(ev)
+    _STATE.metrics.histogram(f"span.{name}").observe(wall_s)
 
 
 def walk_span(**attrs):

@@ -71,7 +71,23 @@ optimizer path ``stage2_iters`` / ``stage2_trials``: the iterations and
 line-search trials of the chunk's stage-2 programs, 0 and 0 where none was
 dispatched), the ladder's ``fit.rung.*``, then ``chunk.submit`` (``lo``,
 ``hi``) > ``commit.overlap`` on the committer thread; ``stage.overlap`` on
-the prefetcher thread under its ``chunk``; last ``walk.close``.
+the prefetcher thread under its ``chunk``; last ``walk.close``.  A
+``chunk`` carries ``phase`` (``compile+execute`` where a build closed on its
+thread inside it, else ``execute``), ``builds`` and ``build_s``.
+
+``program.build`` (ISSUE 54) is a span of another making: one per executable
+the process builds or loads, written by ``utils.compile_cache``'s build log
+through ``obs.closed_span`` once jax has reported the build's end.  ``t0``
+is ``time.time()`` at the build's outermost trace (else its first event),
+``wall_s`` runs to the backend's end; ``attrs``: ``program`` (jax's name
+for it: ``arima._fit_stage1_program``, ``_probe``, ``dynamic_slice``),
+``thread``, ``trace_s``, ``lower_s``, ``backend_s``, ``cache`` (``hit`` /
+``miss`` / ``off``), ``retrieval_s`` (hits, else null), ``compiled_s``.
+``parent`` / ``walk`` are those of the innermost span open on the BUILDING
+thread.  It has no ``process_s`` (nobody read that clock at its start) and
+no profiler annotation.  ``obs.enable`` first writes the builds the process
+made BEFORE the run — ``parent`` null, no ``walk``, and a ``t0`` that
+precedes the ``meta`` line's ``ts``, which is legal for this name alone.
 
 v2 lines (no ``id``) stay readable: ``--check`` takes a span line with or
 without the identity, and FAILS one whose ``id`` / ``parent`` / ``walk`` is

@@ -24,6 +24,7 @@ tool; these exist for series too long for one chip's HBM.
 
 from __future__ import annotations
 
+import contextlib
 import functools
 from typing import Dict, Tuple
 
@@ -34,25 +35,30 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from .. import obs
 from ..parallel.mesh import SERIES_AXIS, TIME_AXIS
+from ..utils import compile_cache
 
 Order = Tuple[int, int, int]
 
 
-def _sp_fit_span(model: str, mesh: Mesh, values, **knobs):
+@contextlib.contextmanager
+def _sp_fit_span(model: str, values):
     """Telemetry span for one time-sharded fit dispatch (ROADMAP: span
-    coverage for the sharded fit paths).  Mirrors the chunk driver's
-    first-dispatch tagging: the first dispatch of a (model, mesh, shape,
-    dtype, knobs) tuple pays JAX trace+compile (the ``lru_cache``d
-    ``_sp_*_fit_program`` builders trace on first use), later dispatches
-    execute a cached program.  Free no-op when the plane is disabled."""
-    phase = None
-    if obs.enabled():
-        key = ("sp_fit", model, tuple(mesh.shape.items()),
-               tuple(values.shape), str(values.dtype),
-               tuple(sorted(knobs.items())))
-        phase = "compile+execute" if obs.first_dispatch(key) else "execute"
-    return obs.span("sp_fit", model=model, keys=int(values.shape[0]),
-                    n_time=int(values.shape[1]), phase=phase)
+    coverage for the sharded fit paths).  Tagged as the chunk driver tags
+    its ``chunk``, from ``compile_cache``'s build log: ``compile+execute``
+    where a build closed on this thread inside the dispatch (the
+    ``lru_cache``d ``_sp_*_fit_program`` builders trace on first use), with
+    ``builds`` / ``build_s``; ``execute`` where it ran loaded programs.
+    Free no-op when the plane is disabled."""
+    if not obs.enabled():
+        yield
+        return
+    mark = compile_cache.thread_builds()
+    with obs.span("sp_fit", model=model, keys=int(values.shape[0]),
+                  n_time=int(values.shape[1])) as sp:
+        try:
+            yield
+        finally:
+            sp.set(**compile_cache.built_since(mark))
 
 
 # ---------------------------------------------------------------------------
@@ -670,7 +676,7 @@ def sp_ewma_fit(mesh: Mesh, values: jax.Array, *, max_iters: int = 40,
     """
     if tol is None:  # same dtype-dependent default as models.ewma.fit
         tol = 1e-8 if values.dtype == jnp.float64 else 1e-4
-    with _sp_fit_span("ewma", mesh, values, max_iters=max_iters, tol=tol):
+    with _sp_fit_span("ewma", values):
         return _sp_ewma_fit_program(
             mesh, values.shape[1], max_iters, float(tol)
         )(values)
@@ -738,7 +744,7 @@ def sp_garch_fit(mesh: Mesh, values: jax.Array, *, max_iters: int = 80,
     """
     if tol is None:  # same dtype-dependent default as models.garch.fit
         tol = 1e-7 if values.dtype == jnp.float64 else 1e-4
-    with _sp_fit_span("garch", mesh, values, max_iters=max_iters, tol=tol):
+    with _sp_fit_span("garch", values):
         return _sp_garch_fit_program(
             mesh, values.shape[1], max_iters, float(tol)
         )(values)
@@ -836,7 +842,7 @@ def sp_argarch_fit(mesh: Mesh, values: jax.Array, *, max_iters: int = 100,
     """
     if tol is None:  # same dtype-dependent default as models.garch.fit_argarch
         tol = 1e-7 if values.dtype == jnp.float64 else 1e-4
-    with _sp_fit_span("argarch", mesh, values, max_iters=max_iters, tol=tol):
+    with _sp_fit_span("argarch", values):
         return _sp_argarch_fit_program(
             mesh, values.shape[1], max_iters, float(tol)
         )(values)
@@ -927,8 +933,7 @@ def sp_arima_fit(mesh: Mesh, values: jax.Array, order: Order = (1, 1, 1), *,
     """
     if tol is None:  # same dtype-dependent default as models.arima.fit
         tol = 1e-6 if values.dtype == jnp.float64 else 1e-4
-    with _sp_fit_span("arima", mesh, values, order=tuple(order),
-                      max_iters=max_iters, tol=tol):
+    with _sp_fit_span("arima", values):
         return _sp_arima_fit_program(
             mesh, values.shape[1], tuple(order), max_iters, float(tol)
         )(values)

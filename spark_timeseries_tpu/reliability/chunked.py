@@ -347,9 +347,10 @@ def fit_chunked(
     (everything up to the lanes' start), the lanes' chunks and
     ``walk.close`` (merge, assembly, manifest); each chunk dispatch runs
     under an
-    ``obs.span("chunk")`` whose first dispatch per (fit, shape, dtype) is
-    tagged ``compile+execute`` (JAX pays trace+compile there) and the rest
-    ``execute``; backoffs, timeouts, and per-row status totals feed the
+    ``obs.span("chunk")`` tagged ``compile+execute`` where an executable
+    was built on its thread inside it (JAX pays trace + lower + compile, or
+    the persistent cache's read, there: ``builds`` / ``build_s``, each build
+    a ``program.build`` span) and ``execute`` otherwise; backoffs, timeouts, and per-row status totals feed the
     metrics registry; the committer reports a ``committer.queue_depth``
     gauge, per-commit ``commit.overlap`` spans, and a
     ``committer.hidden_commit_ms`` counter; and the per-run summary —
@@ -838,19 +839,6 @@ def fit_chunked(
     # land in whichever delta window is open (XLA dispatch cannot be
     # cancelled, so this is inherent to abandonment, and data-quality only)
     counters0 = (obs.snapshot() or {}).get("counters") if tele else None
-    # compile-affecting identity of this fit config, computed ONCE: the
-    # first dispatch per (config, chunk-rows) pays JAX trace+compile, and a
-    # later job with the same shape but different static config (order,
-    # max_iters, backend, ladder) compiles anew — reuse the journal's
-    # config_hash (fit identity + every kwarg + driver knobs) so the
-    # compile-identity ingredients live in ONE place
-    fit_key = journal_mod.config_hash(
-        fit_fn, fit_kwargs,
-        extra={"resilient": resilient, "policy": policy,
-               "ladder": "default" if ladder is None else repr(ladder),
-               "time": t_len, "dtype": str(panel_dtype)},
-    ) if tele else None
-
     # -- the plan, then its lanes -------------------------------------------
     lane_specs = tuple(LaneSpec(sid, slo, shi, dev)
                        for (sid, slo, shi, dev, _vals) in lanes)
@@ -905,8 +893,8 @@ def fit_chunked(
         LaneRunner(plan, spec, fit_fn, fit_kwargs, vals,
                    journal=(lane_journals[i] if lane_journals is not None
                             else None),
-                   deadline=deadline, tele=tele, fit_key=fit_key,
-                   sink=sink, assembly=assembly)
+                   deadline=deadline, tele=tele, sink=sink,
+                   assembly=assembly)
         for i, (spec, (_sid, _lo, _hi, _dev, vals))
         in enumerate(zip(lane_specs, lanes))
     ] if not elastic else None
@@ -941,7 +929,7 @@ def fit_chunked(
                 [(spec, vals) for spec, (_s, _l, _h, _d, vals)
                  in zip(lane_specs, lanes)],
                 journals=lane_journals, deadline=deadline, tele=tele,
-                fit_key=fit_key, restage=_restage)
+                restage=_restage)
             results, elastic_meta = supervisor.run()
         elif len(runners) == 1:
             results = [runners[0].run()]

@@ -74,6 +74,7 @@ import jax
 import numpy as np
 
 from .. import obs
+from ..utils import compile_cache
 from . import committer as committer_mod
 from . import prefetcher as prefetcher_mod
 from . import source as source_mod
@@ -412,7 +413,7 @@ class LaneRunner:
 
     def __init__(self, plan: ExecutionPlan, spec: LaneSpec, fit_fn: Callable,
                  fit_kwargs: dict, values, *, journal=None, deadline=None,
-                 tele: bool = False, fit_key=None, sink=None,
+                 tele: bool = False, sink=None,
                  assembly: Optional[ResultAssembly] = None):
         self.plan = plan
         self.spec = spec
@@ -426,7 +427,6 @@ class LaneRunner:
         self.sink = sink
         self.deadline = deadline or watchdog_mod.Deadline(plan.job_budget_s)
         self.tele = tele
-        self.fit_key = fit_key
         # obs attrs tagged with the shard id ONLY for sharded plans: the
         # single-lane walk's spans/events/meta stay byte-identical to the
         # pre-plan driver.  A grid-placed plan (auto-fit order search)
@@ -846,26 +846,34 @@ class LaneRunner:
                     jax.block_until_ready(out)
                 return out
 
-            phase = None
-            if tele:
-                # first dispatch of this (fit config, chunk rows) pays JAX
-                # trace+compile; later dispatches of the same shape execute
-                # a cached program — the split BENCH scraped ad hoc, now
-                # recorded per chunk (a backoff-halved chunk is a NEW shape
-                # = new compile).  Keyed per SHARD: executables are cached
-                # per device placement, so every lane's first chunk pays
-                # its own compile, not just the first lane to dispatch
-                phase = ("compile+execute"
-                         if obs.first_dispatch(
-                             (self.fit_key, self.spec.shard_id, hi - lo))
-                         else "execute")
-            sp = obs.span("chunk", lo=lo, hi=hi, phase=phase, **self.tag)
+            phase, built = None, {}
+
+            def counted():
+                # what the thread that RUNS the chunk (this one, or the
+                # watchdog's worker under a budget) builds inside it, from
+                # the build log: a first dispatch pays trace + lower +
+                # compile or the cache's read, a later one of the same shape
+                # executes a loaded program (a backoff-halved chunk is a NEW
+                # shape, and every lane's device has executables of its own)
+                mark = compile_cache.thread_builds()
+                try:
+                    return run_chunk()
+                finally:
+                    built.update(compile_cache.built_since(mark))
+
+            sp = obs.span("chunk", lo=lo, hi=hi, **self.tag)
             t0 = time.perf_counter()
             try:
                 with sp:
-                    piece = watchdog_mod.call_with_deadline(
-                        run_chunk, plan.chunk_budget_s,
-                        label=f"chunk rows [{lo}, {hi})")
+                    try:
+                        piece = watchdog_mod.call_with_deadline(
+                            counted if tele else run_chunk,
+                            plan.chunk_budget_s,
+                            label=f"chunk rows [{lo}, {hi})")
+                    finally:
+                        if built:  # not of a worker the watchdog abandoned
+                            phase = built["phase"]
+                            sp.set(**built)
             except watchdog_mod.DeadlineExceeded:
                 err = self._drain_for_journal_write()
                 if err is not None:
@@ -1106,7 +1114,7 @@ class LaneSupervisor:
     def __init__(self, plan: ExecutionPlan, fit_fn: Callable,
                  fit_kwargs: dict, lanes: Sequence[tuple], *,
                  journals: Optional[Sequence] = None, deadline=None,
-                 tele: bool = False, fit_key=None,
+                 tele: bool = False,
                  restage: Optional[Callable] = None):
         self.plan = plan
         self.fit_fn = fit_fn
@@ -1115,7 +1123,6 @@ class LaneSupervisor:
         self.journals = list(journals) if journals is not None else None
         self.deadline = deadline or watchdog_mod.Deadline(plan.job_budget_s)
         self.tele = tele
-        self.fit_key = fit_key
         self.restage = restage
 
         self.queue = WorkQueue()
@@ -1280,8 +1287,7 @@ class LaneSupervisor:
                     hi = span_hi
                 runner = LaneRunner(plan, spec, self.fit_fn,
                                     self.fit_kwargs, vals, journal=jour,
-                                    deadline=self.deadline, tele=self.tele,
-                                    fit_key=self.fit_key)
+                                    deadline=self.deadline, tele=self.tele)
                 with cond:
                     self._active[sid] = runner
                 self._state(sid, "active")

@@ -109,7 +109,10 @@ def _alias_break_copy(arr):
         import jax
         import jax.numpy as jnp
 
-        _copy_fn = jax.jit(lambda x: jnp.copy(x))
+        def alias_break_copy(x):  # a name for the build log, not <lambda>
+            return jnp.copy(x)
+
+        _copy_fn = jax.jit(alias_break_copy)
     return _copy_fn(arr)
 
 

@@ -63,7 +63,7 @@ class TestDisabled:
         assert obs.summary() is None
         obs.event("e", x=1)  # swallowed, no recorder exists
         obs.emit_metrics()
-        assert not obs.first_dispatch(("k",))
+        obs.closed_span("program.build", 0.0, 1.0, program="p")  # swallowed
 
     def test_disabled_fit_adds_no_meta_and_no_manifest_block(self, tmp_path):
         d = str(tmp_path / "j")
@@ -160,7 +160,9 @@ class TestInvariance:
 
 class TestAcceptance:
     def test_journaled_8_chunk_fit_full_telemetry_surface(self, tmp_path):
-        y = _ar_panel()  # 32 rows / chunk_rows=4 -> 8 chunks
+        # 32 rows / chunk_rows=4 -> 8 chunks, of a length no other test of
+        # this process fits: the first chunk's programs are built under it
+        y = _ar_panel(t=97)
         jsonl = str(tmp_path / "ev.jsonl")
         ck = str(tmp_path / "journal")
         obs.enable(jsonl)
@@ -210,7 +212,7 @@ class TestAcceptance:
         assert "chunk" in out.stdout and "counters:" in out.stdout
 
     def test_inspect_journal_prints_telemetry(self, tmp_path):
-        y = _ar_panel(b=8)
+        y = _ar_panel(b=8, t=98)  # a length of its own: chunk 0 builds
         ck = str(tmp_path / "journal")
         obs.enable()
         rel.fit_chunked(arima.fit, y, chunk_rows=4, checkpoint_dir=ck,
@@ -237,8 +239,7 @@ class TestSpansAndMetrics:
             with obs.span("inner", k=1):
                 pass
         obs.disable()
-        lines = [json.loads(l) for l in open(p)]
-        spans = [l for l in lines if l["kind"] == "span"]
+        spans = _span_lines(p)
         assert [s["name"] for s in spans] == ["inner", "outer"]
         assert spans[0]["depth"] == 1 and spans[1]["depth"] == 0
         assert spans[0]["attrs"] == {"k": 1}
@@ -278,12 +279,6 @@ class TestSpansAndMetrics:
         pm = obs.peak_memory()
         assert pm.bytes and pm.bytes > 0
         assert pm.source in ("device", "host_rss")
-
-    def test_first_dispatch_once_per_key(self):
-        obs.enable()
-        assert obs.first_dispatch(("k", 1))
-        assert not obs.first_dispatch(("k", 1))
-        assert obs.first_dispatch(("k", 2))
 
 
 class TestFailureDump:

@@ -519,7 +519,10 @@ class TestReviewHardening:
         """Executables are cached per device placement, so EVERY lane's
         first chunk pays its own compile — the telemetry must tag one
         compile+execute chunk per shard, not one per walk."""
-        y = _ar_panel(b=64)  # 16 chunks over 8 lanes: 2 per lane
+        # 16 chunks over 8 lanes, 2 per lane, of a length no other test of
+        # this process fits: what the tag reads is the build log, and a
+        # shape already built is loaded, not built
+        y = _ar_panel(b=64, t=91)
         obs.enable(str(tmp_path / "ev.jsonl"))
         try:
             res = rel.fit_chunked(ewma.fit, y, chunk_rows=4, resilient=False,

@@ -55,6 +55,8 @@ import sys
 
 KINDS = ("meta", "span", "event", "metrics")
 CHUNK_PHASES = ("compile+execute", "execute", "resumed", "timeout")
+BUILD_SPAN = "program.build"
+BUILD_CACHE = ("hit", "miss", "off")
 MEM_SOURCES = ("device", "host_rss")
 
 
@@ -144,6 +146,26 @@ def validate_span_identity(i: int, ev: dict, seen: set, errors: list) -> None:
                       f"{ev['walk']!r}")
 
 
+def validate_build(i: int, ev: dict, errors: list) -> None:
+    """A ``program.build`` line's attributes (``utils.compile_cache``'s build
+    log): which program, the three stretches of the build inside its wall,
+    and what the persistent cache did."""
+    attrs = ev.get("attrs") or {}
+    if not isinstance(attrs.get("program"), str) or not attrs["program"]:
+        errors.append(f"line {i}: program.build names no program")
+    if attrs.get("cache") not in BUILD_CACHE:
+        errors.append(f"line {i}: program.build cache is not one of "
+                      f"{BUILD_CACHE}: {attrs.get('cache')!r}")
+    parts = [attrs.get(f) for f in ("trace_s", "lower_s", "backend_s")]
+    if not all(isinstance(v, (int, float)) and v >= 0 for v in parts):
+        errors.append(f"line {i}: program.build trace_s / lower_s / "
+                      f"backend_s invalid: {parts!r}")
+    elif isinstance(ev.get("wall_s"), (int, float)) \
+            and sum(parts) > ev["wall_s"] + 1e-3:
+        errors.append(f"line {i}: program.build parts {parts!r} exceed its "
+                      f"wall_s {ev['wall_s']!r}")
+
+
 def validate_events(events, errors) -> list:
     """Schema check (see obs.recorder docstring); appends to ``errors``."""
     if not events and not errors:
@@ -169,10 +191,20 @@ def validate_events(events, errors) -> list:
             validate_span_identity(i, ev, span_ids, errors)
             if not isinstance(ev.get("name"), str):
                 errors.append(f"line {i}: span missing name")
-            for f in ("wall_s", "process_s"):
+            # a ``program.build`` line (ISSUE 54) is written once the build
+            # has closed, from jax's own report of it: nobody read the
+            # process clock at its start, so it carries no ``process_s``;
+            # and ``obs.enable`` first writes the builds the process made
+            # BEFORE the run, so its ``t0`` may precede the ``meta`` line's
+            # ``ts`` (no other span's does, and a reader that takes the meta
+            # line for the stream's start must not) — legal for this name
+            built = ev.get("name") == BUILD_SPAN
+            for f in ("wall_s",) if built else ("wall_s", "process_s"):
                 v = ev.get(f)
                 if not isinstance(v, (int, float)) or v < 0:
                     errors.append(f"line {i}: span {f} invalid: {v!r}")
+            if built:
+                validate_build(i, ev, errors)
             if not isinstance(ev.get("depth"), int) or ev["depth"] < 0:
                 errors.append(f"line {i}: span depth invalid")
         elif kind == "event":
@@ -1456,9 +1488,10 @@ def _render(s: dict) -> None:
             attrs = ev.get("attrs") or {}
             attrs_s = " ".join(f"{k}={v}" for k, v in attrs.items())
             if ev["kind"] == "span":
+                cpu = (f"cpu {ev['process_s']:8.4f}s" if "process_s" in ev
+                       else " " * 13)  # a program.build line has none
                 print(f"{pad}{off:9.3f}  {indent}{ev['name']:<24} "
-                      f"wall {ev['wall_s']:9.4f}s  cpu {ev['process_s']:8.4f}s"
-                      f"  {attrs_s}")
+                      f"wall {ev['wall_s']:9.4f}s  {cpu}  {attrs_s}")
             else:
                 print(f"{pad}{off:9.3f}  {indent}* {ev['name']:<22} {attrs_s}")
 
