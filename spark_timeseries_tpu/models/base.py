@@ -236,7 +236,8 @@ def align_mode_on_host(yb) -> str:
     if isinstance(yb, jax.core.Tracer):
         return "general"
     key = id(yb)
-    hit = _align_mode_cache.get(key)
+    with _align_mode_lock:
+        hit = _align_mode_cache.get(key)
     if hit is not None and hit[0]() is yb:
         return hit[1]
     # each probe is a device round-trip (host sync); counted so drivers can
@@ -251,22 +252,27 @@ def align_mode_on_host(yb) -> str:
         ref = weakref.ref(yb)
     except TypeError:  # not weak-referenceable (e.g. plain numpy scalarlike)
         return mode
-    if len(_align_mode_cache) >= 256:
-        # drop entries whose array has been collected first; only if the
-        # cache is genuinely full of LIVE arrays fall back to FIFO eviction
-        # of the oldest insertions (dicts preserve insertion order) — a
-        # process cycling many panels must not lose every cached mode at
-        # once (ADVICE r4)
-        dead = [k for k, (r, _) in _align_mode_cache.items() if r() is None]
-        for k in dead:
-            del _align_mode_cache[k]
-        while len(_align_mode_cache) >= 256:
-            del _align_mode_cache[next(iter(_align_mode_cache))]
-    _align_mode_cache[key] = (ref, mode)
+    with _align_mode_lock:
+        if len(_align_mode_cache) >= 256:
+            # drop entries whose array has been collected first; only if
+            # the cache is genuinely full of LIVE arrays fall back to FIFO
+            # eviction of the oldest insertions (dicts preserve insertion
+            # order) — a process cycling many panels must not lose every
+            # cached mode at once (ADVICE r4)
+            dead = [k for k, (r, _) in _align_mode_cache.items()
+                    if r() is None]
+            for k in dead:
+                del _align_mode_cache[k]
+            while len(_align_mode_cache) >= 256:
+                del _align_mode_cache[next(iter(_align_mode_cache))]
+        _align_mode_cache[key] = (ref, mode)
     return mode
 
 
 _align_mode_cache: dict = {}  # id(array) -> (weakref, mode)
+# fits run on several threads of one process (sharded lanes, a lane's chunk
+# fitted ahead): the eviction scan must not meet another thread's insert
+_align_mode_lock = threading.Lock()
 
 
 @jax.jit  # module-level: one compile per shape, not per call

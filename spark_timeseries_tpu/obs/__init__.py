@@ -105,7 +105,8 @@ from .core import (NULL_SPAN, Span, closed_span, counter, current_span, defer,
                    disable, dump_failure, dump_on_failure, emit_metrics,
                    enable, enable_from_env, enabled, event, gauge, histogram,
                    last_crash_dump, settle, snapshot, span, span_link,
-                   span_scope, stream_path, summary, walk_span)
+                   span_scope, stream_path, summary, take_deferred,
+                   walk_span)
 from .memory import PeakMemory, peak_memory, register_staging_pool
 from .metrics import Counter, Gauge, Histogram, MetricsRegistry
 from .promsink import PromTextfileSink
@@ -156,6 +157,7 @@ __all__ = [
     "span_scope",
     "stream_path",
     "summary",
+    "take_deferred",
     "trace_for_request",
     "trace_from_wire",
     "trace_scope",

@@ -517,17 +517,28 @@ def defer(**device_scalars) -> None:
             handles[:] = [sum(int(h) for h in handles)]
 
 
-def settle() -> dict:
-    """``{name: int}`` of what this thread deferred since the last call,
-    then nothing pending: a read waits for its program and its copy, so
-    call it where the thread has waited anyway.  A caller
+def take_deferred():
+    """What this thread has deferred since its last :func:`settle`, taken
+    off the thread UNREAD (``None`` where there is nothing): the handles of
+    a fit whose read-back runs on another thread (a chunk fitted ahead of
+    its walk, ``reliability/plan.py``), for that thread's ``settle(taken)``."""
+    pending = getattr(_TLS, "deferred", None)
+    _TLS.deferred = None
+    return pending
+
+
+def settle(taken=None) -> dict:
+    """``{name: int}`` of what this thread deferred since the last call
+    (or of ``taken``, another thread's :func:`take_deferred`), then nothing
+    pending: a read waits for its program and its copy, so call it where
+    the thread has waited anyway.  A caller
     that only wants nothing left over from a fit that raised drops the
     result.  Empty when nothing was deferred; handles of a run that has
     been disabled since are dropped unread."""
-    pending = getattr(_TLS, "deferred", None)
+    pending = taken if taken is not None else getattr(_TLS, "deferred", None)
+    _TLS.deferred = None
     if pending is None:
         return {}
-    _TLS.deferred = None
     st = _STATE
     if not st.enabled or pending[0] != st.run_id:
         return {}

@@ -32,7 +32,11 @@ same guarantees at row granularity:
 - :mod:`.prefetcher` — :class:`ChunkPrefetcher`: the input half of the
   pipeline — a bounded background stager that materializes chunk N+1's
   device slice while chunk N computes (stage ∥ compute ∥ commit), with
-  driver-controlled invalidation on OOM backoff and rollback.
+  driver-controlled invalidation on OOM backoff and rollback; and the
+  owner of the ONE fit a lane keeps in flight ahead of its walk (chunk
+  N+1's probe and programs queued behind chunk N's while the driver reads
+  chunk N back; taken at its turn if the walk's decision is the
+  prediction, dropped otherwise).
 - :mod:`.source` — :class:`ChunkSource`: where the panel's rows live —
   device array (today's path), host ``np.ndarray``, or an npz shard
   directory — so ``fit_chunked(fit_fn, as_source(...))`` walks panels

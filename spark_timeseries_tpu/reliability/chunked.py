@@ -34,9 +34,11 @@ single-writer, shard-before-manifest, in-order protocol while the driver
 thread is already slicing and dispatching the next chunk; a background
 :class:`~.prefetcher.ChunkPrefetcher` stages chunk N+1's device slice
 while chunk N computes, under a static align-mode plan computed once per
-walk.  The steady state is stage N+1 ∥ compute N ∥ commit N−1, results
-are bitwise-identical to ``pipeline=False``, and ``meta["pipeline"]``
-reports how much commit and staging wall the overlap hid.
+walk, and keeps ONE chunk's fit in flight ahead of the chunk the driver is
+finishing.  The steady state is stage N+2 ∥ fit N+1 ∥ read back N ∥
+commit N−1, results are bitwise-identical to ``pipeline=False``, and
+``meta["pipeline"]`` reports how much commit and staging wall the overlap
+hid and how many chunks came from a fit ahead.
 
 **Host-resident panels** (ISSUE 7): everything above assumed the panel
 resident in device memory before the walk began.  Passing a
@@ -216,6 +218,42 @@ def fit_chunked(
     (``staging_wall_s`` / ``hidden_staging_s`` /
     ``input_overlap_efficiency``) and the combined
     ``end_to_end_overlap_efficiency``.
+
+    **The fit, one chunk ahead** (what ``pipeline`` / ``prefetch_depth``
+    hold in flight since ISSUE 56: "staged" is "sliced and fitted"): a
+    chunk's fit has two halves — the probe and the dispatch of its
+    programs (a lazy optimizer's stage gate is waited for there), then
+    the read-back and the ladder.  Wherever the walk stages slices, the
+    prefetcher's fit-ahead thread runs the FIRST half of chunk N+1 while
+    the driver is at chunk N's turn: its probe at once (on the device's
+    queue it lies behind chunk N's stage 1), its programs once chunk N's
+    last program has been dispatched — never before it, the queue is first
+    in, first out — so the device runs chunk N+1's stage 1 while the driver
+    reads chunk N back, runs its ladder, hands it to the committer and
+    plans the next turn.  At that turn the driver TAKES the dispatched fit
+    if the span it decides — ``(lo, hi)``, the chunk size, the align hint —
+    is the one the fit assumed, and otherwise drops it (waited out, its
+    device arrays released) and fits serially: an OOM backoff, a committer
+    rollback, a steal, the job deadline and a boundary the prediction
+    missed all do.  Commits stay in walk order, ``chunk_rows_after`` is
+    captured at submit as before, and every chunk is bit for bit the
+    serial walk's.  Nothing is fitted ahead with ``pipeline=False`` or
+    ``prefetch_depth=0``, under ``chunk_budget_s`` (the watchdog's budget
+    bounds ONE chunk's compute), of a whole-span chunk, a chunk the journal
+    holds or a forced recompute, and behind a chunk whose own dispatch
+    BUILT a program (a process's first chunk of a shape); a build on the
+    driver's thread during a chunk's second half (a rung's first program)
+    first lets the fit in flight make its last dispatch.  A
+    ``RESOURCE_EXHAUSTED`` of the fit ahead is no OOM event of the walk:
+    the lane fits nothing ahead for the rest of the walk and the chunk is
+    fitted at its turn, where the backoff ladder sees what it saw before.
+    COST: two chunks' working sets are on the device at once — the next
+    chunk's slice, its sanitized copy and its programs' buffers beside
+    this chunk's result, about one chunk of the panel more at the peak
+    (PERF.md §5: ``peak_hbm_gb``).  ``meta["pipeline"]`` gains
+    ``fits_ahead`` (fits started ahead) and ``fits_ahead_taken`` (chunks
+    whose result came from one); each is a ``fit.ahead`` span on its
+    thread, parent-linked to the ``chunk`` that started it.
 
     **Static align-mode plan**: when ``fit_fn`` accepts the ``align_mode``
     hint (every bundled model fit does — ``models.base.resolve_align_mode``),
@@ -1240,6 +1278,10 @@ def _pipeline_meta(results, sharded: bool) -> Optional[dict]:
             "staged_hits": sum(s.hits for _, s, _ in pfs),
             "staged_misses": sum(s.misses for _, s, _ in pfs),
             "staged_invalidated": sum(s.invalidated for _, s, _ in pfs),
+            # the fit kept one chunk ahead of the walk (prefetcher.fit_ahead):
+            # fits started ahead, and those whose result the walk took
+            "fits_ahead": sum(s.fits_ahead for _, s, _ in pfs),
+            "fits_ahead_taken": sum(s.fits_ahead_taken for _, s, _ in pfs),
             "staging_wall_s": round(staging_wall, 6),
             "staging_blocked_s": round(
                 sum(s.blocked_s for _, s, _ in pfs), 6),

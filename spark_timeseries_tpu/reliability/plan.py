@@ -79,7 +79,7 @@ from . import committer as committer_mod
 from . import prefetcher as prefetcher_mod
 from . import source as source_mod
 from . import watchdog as watchdog_mod
-from .runner import resilient_fit
+from .runner import begin_fit, finish_fit
 from .status import FitStatus, STATUS_DTYPE, status_counts
 
 __all__ = [
@@ -383,7 +383,9 @@ class LaneResult(NamedTuple):
 
 
 class LaneRunner:
-    """One prefetch → compute → commit lane over one contiguous row span.
+    """One prefetch → compute → commit lane over one contiguous row span,
+    ONE chunk's fit kept in flight ahead of the chunk being finished where
+    the walk stages slices (:meth:`_fit_ahead`, :meth:`_take_ahead`).
 
     This IS the former ``fit_chunked`` loop, verbatim in behavior: the
     single-lane plan reproduces the PR 1–5 driver (same chunk boundaries,
@@ -479,6 +481,13 @@ class LaneRunner:
             self.prefetcher = prefetcher_mod.ChunkPrefetcher(
                 panel, depth=plan.prefetch_depth)
 
+        # the fit kept in flight one chunk ahead (prefetcher.fit_ahead): off
+        # for the rest of the walk once a fit ahead met RESOURCE_EXHAUSTED —
+        # two chunks' working sets do not fit, and that is no backoff
+        self._ahead_off = False
+        # this turn: a fit ahead was taken, or is in flight beside the chunk
+        self._beside = False
+
         self.pieces: list = []
         self.oom_events: list = []
         self.timeout_events: list = []
@@ -553,7 +562,9 @@ class LaneRunner:
         if self.prefetcher is not None:
             # staged predictions past the split belong to the thief now;
             # dropping ALL staged slices is conservative but safe (a kept
-            # span degrades to an inline slice — a miss, never a wrong one)
+            # span degrades to an inline slice — a miss, never a wrong one);
+            # the fit ahead too: the walk's next turn waits it out
+            self.prefetcher.cancel_fit("steal")
             self.prefetcher.invalidate()
         return split, hi
 
@@ -585,6 +596,7 @@ class LaneRunner:
         and a freed staged buffer is exactly the HBM the retry needs."""
         plan = self.plan
         if self.prefetcher is not None:
+            self.prefetcher.drop_fit("oom")
             self.prefetcher.invalidate()
         self.oom_events.append({
             "at_row": at_row, "chunk_rows": rows,
@@ -610,6 +622,10 @@ class LaneRunner:
         walk re-enters at the failed row — the pipelined twin of the
         fit-time backoff.  Returns the (lo, chunk) to continue from."""
         e, flo, fhi = err
+        if self.prefetcher is not None:
+            # the walk rewinds: what was fitted ahead of it is of a walk
+            # that no longer is (waited out, its arrays released)
+            self.prefetcher.drop_fit("rollback")
         if not is_resource_exhausted(e):
             raise e
         new_chunk = self._record_oom(flo, fhi - flo, e)
@@ -656,6 +672,138 @@ class LaneRunner:
         if self.committer is None:
             return None
         return self.committer.drain(raise_pending=False)
+
+    # -- the chunk body: values, first half, second half ----------------------
+
+    def _values(self, lo: int, hi: int, chunk: int):
+        """This chunk's input values, and the next spans' staging scheduled.
+
+        The whole-span chunk hands the lane's array through untouched (a
+        slice would be a fresh device buffer — an extra HBM copy, and a
+        miss in the per-array-identity align-mode cache callers pre-warm);
+        sliced chunks come from the prefetcher when the staged prediction
+        matched.  A staged slice can be queued behind an ABANDONED
+        (timed-out) computation, so the wait on it must be bounded by the
+        same budget as the compute it feeds — and a staging-time
+        RESOURCE_EXHAUSTED surfaces here, through the watchdog, into the
+        same backoff ladder as a fit-time one.  A source-backed lane never
+        hands ``values`` through: a whole-span chunk still stages H2D (the
+        panel lives in host RAM/disk, not on device)."""
+        spec = self.spec
+        if lo == spec.lo and hi == spec.hi and not self._from_source:
+            vals = self.values
+        elif self.prefetcher is not None:
+            vals = self.prefetcher.take(lo, hi)
+        else:
+            vals = self._slice(lo, hi)
+        if self.prefetcher is not None:
+            # stage the next spans now (up to depth ahead — take() just
+            # freed this chunk's slot), so they materialize while this
+            # chunk computes (and, for resilient fits, while the ladder
+            # blocks on host work)
+            nlo = hi
+            for _ in range(self.prefetcher.depth):
+                nxt = self._next_span(nlo, chunk)
+                if nxt is None:
+                    break
+                self.prefetcher.schedule(*nxt)
+                nlo = nxt[1]
+        return vals
+
+    def _begin(self, vals, not_before=None):
+        """The chunk's fit to its last dispatch, on whichever thread runs
+        it: the probe and the primary fit of a resilient plan
+        (``runner.begin_fit``), the fit itself of a plain one."""
+        plan = self.plan
+        if plan.resilient:
+            return begin_fit(self.fit_fn, vals, policy=plan.policy,
+                             not_before=not_before, **self.fit_kwargs)
+        if not_before is not None:
+            not_before()
+        return self.fit_fn(vals, **self.fit_kwargs)
+
+    def _finish(self, begun):
+        """The rest of the chunk's fit, on the thread that runs the chunk:
+        the read-back and the ladder of a resilient plan."""
+        plan = self.plan
+        if plan.resilient:
+            # a build and a fit in flight do not share the interpreter: a
+            # rung that has to BUILD a program lets the fit ahead make its
+            # last dispatch before it traces (one chunk's wall, in the one
+            # chunk of a process that builds a rung)
+            pf = self.prefetcher
+            with compile_cache.before_build(
+                    pf.fit_dispatched if pf is not None else None):
+                return finish_fit(self.fit_fn, begun, ladder=plan.ladder)
+        if plan.chunk_budget_s is not None:
+            # with a deadline armed the budget must cover the device
+            # computation, not just its async dispatch — block here,
+            # INSIDE the watchdog window
+            # lint: host-sync(deliberate watchdog barrier)
+            jax.block_until_ready(begun)
+        return begun
+
+    # -- one chunk's fit in flight ahead of the walk (ISSUE 56) ---------------
+
+    def _ahead_key(self, lo: int, hi: int, chunk: int) -> tuple:
+        """What a fit ahead assumed of the walk, and what the walk's turn
+        compares: the span, the chunk size and the align hint."""
+        return lo, hi, chunk, self.fit_kwargs.get("align_mode")
+
+    def _fit_ahead(self, nlo: int, chunk: int, after=None) -> None:
+        """Start the fit of the span the walk visits after ``nlo`` on the
+        prefetcher's fit-ahead thread, behind ``after`` (the fit ahead this
+        turn is taking, if it is still running).  What decides it is what
+        the walk knows: a staging prefetcher (``pipeline``,
+        ``prefetch_depth``, a sliced walk), no chunk budget (the watchdog's
+        budget bounds ONE chunk's compute, and a fit queued behind another
+        would be charged for it), no RESOURCE_EXHAUSTED of a fit ahead in
+        this walk, and a next span the prediction names (not the lane's end,
+        not a chunk the journal holds, no forced recompute)."""
+        if (self.prefetcher is None or self._ahead_off
+                or self.plan.chunk_budget_s is not None):
+            return
+        nxt = self._next_span(nlo, chunk)
+        if nxt is None or nlo in self.lost_boundaries:
+            return
+        lo, hi = nxt
+
+        self.prefetcher.fit_ahead(
+            self._ahead_key(lo, hi, chunk),
+            lambda: self._values(lo, hi, chunk), self._begin, after)
+        self._beside = True
+
+    def _take_ahead(self, lo: int, hi: int, chunk: int, built: dict):
+        """This turn's chunk as its fit ahead dispatched it, or None: there
+        is none, the walk's decision is not the prediction, or it came to
+        nothing.  While the driver waits for it the NEXT span's fit is
+        already started behind it: its probe fills this chunk's stage gate,
+        its stage 1 follows this chunk's stage 2.  A RESOURCE_EXHAUSTED of
+        the fit ahead is not an OOM event of the walk — two chunks' working
+        sets were in flight: the lane fits nothing ahead from here on and
+        the chunk is fitted at its turn; any other exception is the chunk's
+        own and is raised here, at its turn."""
+        pf = self.prefetcher
+        slot = pf.take_fit(self._ahead_key(lo, hi, chunk)) \
+            if pf is not None else None
+        if slot is None:
+            return None
+        self._beside = True
+        if not slot.came_to_nothing():  # else this turn fits the chunk alone
+            self._fit_ahead(hi, chunk, after=slot)
+        begun, err = pf.wait_fit(slot)
+        if slot.taken:
+            if slot.built.get("builds"):  # built on ITS thread, in this chunk
+                built.update(slot.built)
+            return begun
+        # the fit started behind it has given up with it
+        pf.drop_fit(slot.dropped_for or "error")
+        if err is not None:
+            if not is_resource_exhausted(err):
+                raise err
+            self._ahead_off = True
+            pf.invalidate()
+        return None
 
     # -- the walk ------------------------------------------------------------
 
@@ -765,6 +913,8 @@ class LaneRunner:
                     if hi > self._busy_hi:
                         self._busy_hi = hi
                 if deadline.exceeded():
+                    if self.prefetcher is not None:
+                        self.prefetcher.drop_fit("deadline")
                     err = self._drain_for_journal_write()
                     if err is not None:
                         lo, self.chunk = self._rollback(err)
@@ -793,73 +943,58 @@ class LaneRunner:
                     continue
                 plan_sp.set(hi=hi)
 
+            phase, built = None, {}
+
             def run_chunk(lo=lo, hi=hi, chunk=self.chunk):
                 # lo/hi/chunk are DEFAULT-ARG SNAPSHOTS, not closure reads:
                 # a watchdog-abandoned thread keeps running after the driver
                 # has mutated the loop variables, and it must keep operating
                 # on ITS chunk's span — never take() the live chunk's staged
-                # slice or slice a torn lo/hi pair mid-update.
-                # acquire this chunk's values INSIDE the watchdog window:
-                # the whole-span chunk hands the lane's array through
-                # untouched (a slice would be a fresh device buffer — an
-                # extra HBM copy, and a miss in the per-array-identity
-                # align-mode cache callers pre-warm); sliced chunks come
-                # from the prefetcher when the staged prediction matched.
-                # A staged slice can be queued behind an ABANDONED
-                # (timed-out) computation, so the wait on it must be
-                # bounded by the same budget as the compute it feeds — and
-                # a staging-time RESOURCE_EXHAUSTED surfaces here, through
-                # the watchdog, into the same backoff ladder as a fit-time
-                # one.  A source-backed lane never hands `values` through:
-                # a whole-span chunk still stages H2D (the panel lives in
-                # host RAM/disk, not on device).
-                if lo == spec.lo and hi == spec.hi and not self._from_source:
-                    vals = self.values
-                elif self.prefetcher is not None:
-                    vals = self.prefetcher.take(lo, hi)
-                else:
-                    vals = self._slice(lo, hi)
-                if self.prefetcher is not None:
-                    # stage the next spans now (up to depth ahead — take()
-                    # just freed this chunk's slot), so they materialize
-                    # while this chunk computes (and, for resilient fits,
-                    # while the ladder blocks on host work)
-                    nlo = hi
-                    for _ in range(self.prefetcher.depth):
-                        nxt = self._next_span(nlo, chunk)
-                        if nxt is None:
-                            break
-                        self.prefetcher.schedule(*nxt)
-                        nlo = nxt[1]
-                if plan.resilient:
-                    return resilient_fit(
-                        fit_fn, vals, policy=plan.policy, ladder=plan.ladder,
-                        **fit_kwargs)
-                out = fit_fn(vals, **fit_kwargs)
-                if plan.chunk_budget_s is not None:
-                    # with a deadline armed the budget must cover the device
-                    # computation, not just its async dispatch — block here,
-                    # INSIDE the watchdog window
-                    # the watchdog must bound the computation itself,
-                    # not just its async dispatch:
-                    # lint: host-sync(deliberate watchdog barrier)
-                    jax.block_until_ready(out)
-                return out
-
-            phase, built = None, {}
-
-            def counted():
-                # what the thread that RUNS the chunk (this one, or the
-                # watchdog's worker under a budget) builds inside it, from
-                # the build log: a first dispatch pays trace + lower +
+                # slice or slice a torn lo/hi pair mid-update.  The chunk's
+                # values are acquired INSIDE the watchdog window (_values).
+                # What the thread that RUNS the chunk (this one, or the
+                # watchdog's worker under a budget) builds inside it comes
+                # from the build log: a first dispatch pays trace + lower +
                 # compile or the cache's read, a later one of the same shape
                 # executes a loaded program (a backoff-halved chunk is a NEW
                 # shape, and every lane's device has executables of its own)
                 mark = compile_cache.thread_builds()
+                self._beside = False
                 try:
-                    return run_chunk()
+                    begun = self._take_ahead(lo, hi, chunk, built)
+                    if begun is None:
+                        begun = self._begin(self._values(lo, hi, chunk))
+                        # the lane fits ahead of a chunk only if that
+                        # chunk's own dispatch built nothing: chunk 0 of a
+                        # process's first walk builds the stage programs,
+                        # and a second thread calling the same un-built jit
+                        # would trace and lower them again
+                        if not compile_cache.built_since(mark)["builds"]:
+                            self._fit_ahead(hi, chunk)
+                    return self._finish(begun)
+                except Exception as e:  # noqa: BLE001 - filtered just below
+                    if not (self._beside and is_resource_exhausted(e)):
+                        raise
                 finally:
-                    built.update(compile_cache.built_since(mark))
+                    mine = compile_cache.built_since(mark)
+                    if built:  # a taken fit's builds, on its own thread
+                        mine.update(
+                            phase="compile+execute",
+                            builds=mine["builds"] + built["builds"],
+                            build_s=round(
+                                mine["build_s"] + built["build_s"], 6))
+                    built.update(mine)
+                # RESOURCE_EXHAUSTED with a second chunk's working set in
+                # flight is no OOM event of the walk: that fit is waited
+                # out and dropped, the lane fits nothing ahead from here
+                # on, and the chunk is fitted once more, alone — outside
+                # the handler, so the exception, its traceback and the
+                # frames (device arrays) they hold are gone before it
+                begun = None
+                self._ahead_off = True
+                self.prefetcher.drop_fit("oom")
+                return self._finish(
+                    self._begin(self._values(lo, hi, chunk)))
 
             sp = obs.span("chunk", lo=lo, hi=hi, **self.tag)
             t0 = time.perf_counter()
@@ -867,8 +1002,7 @@ class LaneRunner:
                 with sp:
                     try:
                         piece = watchdog_mod.call_with_deadline(
-                            counted if tele else run_chunk,
-                            plan.chunk_budget_s,
+                            run_chunk, plan.chunk_budget_s,
                             label=f"chunk rows [{lo}, {hi})")
                     finally:
                         if built:  # not of a worker the watchdog abandoned

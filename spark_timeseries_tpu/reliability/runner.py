@@ -52,7 +52,8 @@ from ..utils import optim
 from .sanitize import sanitize as _sanitize
 from .status import STATUS_DTYPE, FitStatus, status_counts
 
-__all__ = ["RetryRung", "ResilientFitResult", "default_ladder", "resilient_fit"]
+__all__ = ["BegunFit", "RetryRung", "ResilientFitResult", "begin_fit",
+           "default_ladder", "finish_fit", "resilient_fit"]
 
 
 class RetryRung(NamedTuple):
@@ -79,6 +80,20 @@ class ResilientFitResult(NamedTuple):
     iters: np.ndarray  # [batch]
     status: np.ndarray  # [batch] int8 FitStatus codes
     meta: dict
+
+
+class BegunFit(NamedTuple):
+    """A resilient fit between its two halves: probed and DISPATCHED
+    (:func:`begin_fit`), not yet read back (:func:`finish_fit`).  Holds the
+    device arrays of one chunk; whoever drops it frees them."""
+
+    single: bool  # the caller's panel was one series
+    y_clean: jax.Array  # the sanitized panel: what a rung gathers from
+    status: np.ndarray  # the sanitizer's per-row codes
+    san_meta: dict
+    fit_kwargs: dict  # as the primary fit got them (align hint resolved)
+    res: object  # the primary fit's result, device arrays
+    deferred: object  # ``obs.take_deferred()`` of the dispatching thread
 
 
 def default_ladder(fit_fn: Callable, base_iters: Optional[int] = None) -> tuple:
@@ -242,6 +257,26 @@ def resilient_fit(
     sanitization changes the panel's NaN pattern — the alignment mode, and
     with it the compiled program, is chosen per panel.)
     """
+    return finish_fit(
+        fit_fn,
+        begin_fit(fit_fn, y, policy=policy, sanitize=sanitize, **fit_kwargs),
+        ladder=ladder, max_retry_rows=max_retry_rows, seed=seed)
+
+
+@obs.dump_on_failure("resilient_fit", unless=_recoverable_oom)
+def begin_fit(fit_fn: Callable, y, *, policy: str = "impute",
+              sanitize: bool = True,
+              not_before: Optional[Callable[[], None]] = None,
+              **fit_kwargs) -> BegunFit:
+    """The first half of :func:`resilient_fit`: the sanitizer's probe and
+    the primary fit's dispatch, to the last program it queues (a lazy
+    optimizer's stage gate is waited for in here; the result is not).
+    ``not_before()`` is called between the two, the probe read and nothing
+    of the fit dispatched yet: a lane that fits one chunk ahead
+    (``plan.LaneRunner``) waits there for the chunk before this one to have
+    queued ITS last program, so the device's first-in first-out queue never
+    holds this chunk's stage 1 before that chunk's stage 2.  Whatever it
+    raises leaves this function with nothing dispatched."""
     yb = jnp.asarray(y)
     single = yb.ndim == 1
     if single:
@@ -270,11 +305,31 @@ def resilient_fit(
         if "align_mode" in _accepted_kwargs(fit_fn, {"align_mode": None}):
             fit_kwargs = {**fit_kwargs, "align_mode": align_hint}
 
+    if not_before is not None:
+        not_before()
     obs.settle()  # nothing pending from a fit that raised before its read-back
     with obs.span("fit.primary", rows=b):
         res = fit_fn(y_clean, **fit_kwargs)
-    # fit.readback: the first host read of the result waits for the device,
-    # and the next chunk is not dispatched before these passes are done
+    # what the fit deferred to its read-back's span goes with the result:
+    # the read-back may run on another thread (a chunk fitted ahead)
+    return BegunFit(single, y_clean, status, san_meta, fit_kwargs, res,
+                    obs.take_deferred())
+
+
+@obs.dump_on_failure("resilient_fit", unless=_recoverable_oom)
+def finish_fit(fit_fn: Callable, begun: BegunFit, *,
+               ladder: Optional[Sequence[RetryRung]] = None,
+               max_retry_rows: Optional[int] = None,
+               seed: int = 0) -> ResilientFitResult:
+    """The second half of :func:`resilient_fit`: the read-back of what
+    :func:`begin_fit` dispatched, the retry ladder over its failed rows and
+    the ``DIVERGED`` mark, on the calling thread."""
+    single, y_clean, status, san_meta, fit_kwargs, res, deferred = begun
+    b = y_clean.shape[0]
+    # fit.readback: the first host read of the result waits for the device.
+    # On a lane that fits one chunk ahead (plan.LaneRunner) the next chunk's
+    # programs are queued behind it already; on any other the next chunk is
+    # not dispatched before these passes are done
     with obs.span("fit.readback", rows=b) as readback:
         # the read-back's own buffer, read-only: a second copy of a wide
         # result is 14-16 ms on the driver's thread with the device idle
@@ -302,7 +357,7 @@ def resilient_fit(
             # (and what stage 2's loops counted: lockstep.fit deferred it)
             readback.set(iters_max=int(iters.max(initial=0)),
                          iters_sum=int(iters.sum()),
-                         failed=int(failed.sum()), **obs.settle())
+                         failed=int(failed.sum()), **obs.settle(deferred))
     # ladder size cap: rows past the cap skip the ladder entirely (they
     # stay in ``failed`` and are flagged DIVERGED below), bounding the
     # worst-case ladder cost on mass-non-convergence panels

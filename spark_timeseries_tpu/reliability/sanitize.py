@@ -99,6 +99,11 @@ def sanitize(y, policy: str = "impute") -> SanitizeReport:
 
 def _sanitize_timed(yb, policy: str) -> SanitizeReport:
     y1, had_inf, interior_nan, constant, all_nan = _probe(yb)
+    # the four masks come over together, not in four round trips one after
+    # another (1 ms each behind a busy device: the next dispatch waits for
+    # the last of them)
+    for mask in (had_inf, interior_nan, constant, all_nan):
+        mask.copy_to_host_async()
     had_inf = np.asarray(had_inf)
     interior_nan = np.asarray(interior_nan)
     constant = np.asarray(constant)

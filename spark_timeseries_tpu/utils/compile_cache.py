@@ -60,8 +60,9 @@ import contextlib
 import os
 import threading as _threading
 
-__all__ = ["builds", "builds_held", "built_since", "configure", "listen",
-           "note_hit", "note_miss", "program_cache_stats", "thread_builds"]
+__all__ = ["before_build", "builds", "builds_held", "built_since",
+           "configure", "listen", "note_hit", "note_miss",
+           "program_cache_stats", "thread_builds"]
 
 _ENV_VAR = "JAX_COMPILATION_CACHE_DIR"
 _DEFAULT_DIR = os.path.join(
@@ -134,6 +135,24 @@ def note_miss() -> None:
     from .. import obs
 
     obs.counter("compile_cache.miss").inc()
+
+
+@contextlib.contextmanager
+def before_build(first):
+    """Inside the block, ``first()`` runs on THIS thread each time it is
+    about to build a program — at the start of its outermost trace, the
+    moment jax reports it (:func:`_on_scalar`), before any of the seconds of
+    Python that hold the interpreter.  A lane that fits one chunk ahead lets
+    the fit in flight reach its last dispatch there
+    (``reliability/plan.py``): a fit's thread and a build share one
+    interpreter badly (PERF.md §6, PR 56).  A dispatch that finds its
+    executable built traces nothing and calls nothing."""
+    prev = getattr(_pending, "before_build", None)
+    _pending.before_build = first
+    try:
+        yield
+    finally:
+        _pending.before_build = prev
 
 
 def program_cache_stats() -> dict:
@@ -213,7 +232,12 @@ def _on_scalar(event, value, **_) -> None:
     # jax reports an interval's START as a scalar: how deep this thread is
     # in traces and lowerings (an inner jit is traced inside its caller's)
     if event == _TRACE or event == _LOWER:
-        _pending.depth = getattr(_pending, "depth", 0) + 1
+        depth = getattr(_pending, "depth", 0)
+        if depth == 0 and event == _TRACE:
+            first = getattr(_pending, "before_build", None)
+            if first is not None:
+                first()  # an outermost trace is about to start: before_build
+        _pending.depth = depth + 1
 
 
 def _on_time_span(event, start, end, fun_name="", **_) -> None:

@@ -274,8 +274,12 @@ class TestPrefetchedWalk:
                 self._arr, self._fail = arr, fail_lo
 
             def __getitem__(self, key):
-                if isinstance(key, slice) and key.start == self._fail:
-                    self._fail = None  # fail once, then recover
+                # a slice of the full chunk width does not fit there, a
+                # halved one does: whoever stages it first (the staging
+                # worker for the fit ahead, whose RESOURCE_EXHAUSTED is no
+                # event of the walk, then the chunk's own turn) meets it
+                if isinstance(key, slice) and key.start == self._fail \
+                        and key.stop - key.start == 8:
                     raise RuntimeError(
                         "RESOURCE_EXHAUSTED: simulated staging OOM")
                 return self._arr[key]
